@@ -24,9 +24,6 @@ from sparkucx_tpu.transport.tpu import TpuShuffleCluster
 
 
 def main() -> None:
-    from sparkucx_tpu.parallel.mesh import apply_platform_env
-
-    apply_platform_env()  # honor JAX_PLATFORMS even under vendor site hooks
     import jax
 
     n = min(2, len(jax.devices()))

@@ -21,9 +21,6 @@ from sparkucx_tpu.ops.sort import SortSpec, oracle_sort, run_distributed_sort
 
 
 def main() -> None:
-    from sparkucx_tpu.parallel.mesh import apply_platform_env
-
-    apply_platform_env()  # honor JAX_PLATFORMS even under vendor site hooks
     import jax
 
     n = min(4, len(jax.devices()))

@@ -94,9 +94,6 @@ def transitive_closure(mesh, n: int) -> None:
 
 
 def main() -> None:
-    from sparkucx_tpu.parallel.mesh import apply_platform_env
-
-    apply_platform_env()  # honor JAX_PLATFORMS even under vendor site hooks
     import jax
 
     n = min(4, len(jax.devices()))

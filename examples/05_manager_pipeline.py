@@ -19,9 +19,6 @@ import numpy as np
 
 
 def main() -> None:
-    from sparkucx_tpu.parallel.mesh import apply_platform_env
-
-    apply_platform_env()  # honor JAX_PLATFORMS even under vendor site hooks
     import jax
 
     from sparkucx_tpu.config import TpuShuffleConf

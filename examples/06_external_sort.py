@@ -1,6 +1,6 @@
 """Out-of-core TeraSort: sorting a dataset larger than device capacity.
 
-The single-chip HBM envelope is ~32M 100 B rows (docs/PERF.md); the
+One chip's HBM bounds the rows a single device sort can hold; the
 "TeraSort 10GB" workload (BASELINE configs[1]) exceeds it.  run_external_sort
 chains full-capacity device sorts — one compiled function reused across
 batches — and merges the sorted runs on the host, moving only (key, index)
@@ -21,9 +21,6 @@ from sparkucx_tpu.ops.sort import SortSpec, oracle_sort, run_external_sort
 
 
 def main() -> None:
-    from sparkucx_tpu.parallel.mesh import apply_platform_env
-
-    apply_platform_env()  # honor JAX_PLATFORMS even under vendor site hooks
     import jax
 
     n = min(4, len(jax.devices()))

@@ -18,10 +18,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
 
-from sparkucx_tpu.parallel.mesh import apply_platform_env  # noqa: E402
-
-apply_platform_env()
-
 from sparkucx_tpu.ops.exchange import make_mesh  # noqa: E402
 from sparkucx_tpu.ops.tc import TcSpec, oracle_tc, run_transitive_closure  # noqa: E402
 

@@ -178,9 +178,8 @@ class Block(ABC):
         same consistent-at-capture semantics as ``get_memory_block``.
         Subclasses should override where a stable view is possible
         (BytesBlock: the payload array; FileBackedBlock: a cached read-only
-        mmap): materializing a fresh buffer per fetch was the measured wall
-        of the peer-serving path (allocation + copy + page faults per
-        request, docs/PERF.md peer row)."""
+        mmap): materializing a fresh buffer per fetch costs an allocation, a
+        copy and page faults per request on the peer-serving path."""
         return None
 
     def close(self) -> None:

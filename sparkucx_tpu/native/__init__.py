@@ -1,14 +1,20 @@
 """ctypes bindings for the native arena (the jucx/nvkv replacement).
 
-Builds ``libtpushuffle.so`` from ``arena.cpp`` on first import (g++, cached next
-to the source; rebuilt when the source is newer).  Everything degrades
-gracefully: if no compiler is available the pure-Python paths keep working and
-``native_available()`` returns False — native code accelerates, it never gates.
+Builds ``libtpushuffle-<key>.so`` from ``arena.cpp`` on first import (g++,
+cached next to the source).  ``<key>`` hashes the source text and the compiler
+command, so a library left over from different source or flags — the checkout
+is copied between machines as it stands on disk, ignored files included — is
+never loaded: a changed key is a different file name, which is built here.
+If no compiler is available the pure-Python paths keep working,
+``native_available()`` returns False and ``build_error()`` says why; callers
+that report host-side numbers print both, since which path ran decides them.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,10 +24,11 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "arena.cpp")
-_SO = os.path.join(_DIR, "libtpushuffle.so")
+_CXX = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread"]
 _LOCK = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
+_built_here = False
 
 
 class TsSegment(ctypes.Structure):
@@ -32,35 +39,53 @@ class TsSegment(ctypes.Structure):
     ]
 
 
-def _build() -> Optional[str]:
-    cmd = [
-        "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-        _SRC, "-o", _SO,
-    ]
+def _so_path() -> str:
+    """Library path keyed on what it is built from (source bytes + command)."""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_DIR, f"libtpushuffle-{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> Optional[str]:
+    # build under a private name and rename: a concurrent process either sees
+    # no library (and builds its own) or a complete one, never a partial file
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
+        proc = subprocess.run(
+            _CXX + [_SRC, "-o", tmp], capture_output=True, text=True, timeout=120
+        )
+        if proc.returncode != 0:
+            return f"g++ failed: {proc.stderr[-2000:]}"
+        os.replace(tmp, so)
     except (OSError, subprocess.TimeoutExpired) as e:
         return f"build failed: {e}"
-    if proc.returncode != 0:
-        return f"g++ failed: {proc.stderr[-2000:]}"
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    for stale in glob.glob(os.path.join(_DIR, "libtpushuffle*.so")):
+        if stale != so:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
     return None
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _build_error
+    global _lib, _build_error, _built_here
     with _LOCK:
         if _lib is not None or _build_error is not None:
             return _lib
-        needs_build = not os.path.exists(_SO) or (
-            os.path.exists(_SRC) and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-        )
-        if needs_build:
-            err = _build()
+        so = _so_path()
+        if not os.path.exists(so):
+            err = _build(so)
             if err is not None:
                 _build_error = err
                 return None
+            _built_here = True
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError as e:
             _build_error = str(e)
             return None
@@ -96,6 +121,13 @@ def native_available() -> bool:
 def build_error() -> Optional[str]:
     _load()
     return _build_error
+
+
+def built_here() -> bool:
+    """True when this process compiled the library (False: a library with the
+    current key was already on disk, or the build failed)."""
+    _load()
+    return _built_here
 
 
 def _as_np(addr: int, size: int) -> np.ndarray:

@@ -37,9 +37,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.lax import ragged_all_to_all
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkucx_tpu.ops._compat import ragged_all_to_all, shard_map
 from sparkucx_tpu.ops.exchange import exclusive_cumsum, gather_rows, ragged_params
 
 

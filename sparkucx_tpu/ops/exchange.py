@@ -34,8 +34,9 @@ Protocol (mirrors the reference's two-phase metadata+data design):
      the ragged path produces.  This is also the path the driver's virtual-CPU
      ``dryrun_multichip`` executes.
    * ``impl='local'`` (TPU, n=1 only): the degenerate single-executor superstep
-     is a device-local prefix copy, which the Pallas DMA gather streams ~3x
-     faster than ragged_all_to_all's single-device lowering (docs/PERF.md).
+     is a device-local prefix copy, so it runs as ONE Pallas DMA gather rather
+     than through ragged_all_to_all's single-device lowering (no device rate
+     for either is on record yet — root PERF.md).
 
    All lowerings produce identical receive buffers over the valid (sized)
    prefix, so every layer above is implementation-agnostic; rows past the
@@ -56,21 +57,22 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.lax import ragged_all_to_all
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from sparkucx_tpu.ops._compat import ragged_all_to_all, shard_map
 
 
 def exclusive_cumsum(x, axis: int = -1, xp=jnp):
     return xp.cumsum(x, axis=axis) - x
 
 
-#: Lane-width band where XLA:TPU lowers a row gather ~4x slower than adjacent
-#: widths (mapped empirically on v5e: 8/16/24 lanes and >=100 are fast,
-#: 25..32 fall off a tiling cliff — docs/PERF.md).  Gathers whose width lands
-#: in the band are chunked into <=24-lane column slices, each of which lowers
-#: on the fast path; chunking a fast width makes it WORSE (W=100 chunked
-#: measured 3x slower), hence the band guard rather than chunking everything.
+#: Lane-width band where XLA:TPU lowers a row gather markedly slower than
+#: adjacent widths (8/16/24 lanes and >=100 take the fast path, 25..32 do
+#: not).  Gathers whose width lands in the band are chunked into <=24-lane
+#: column slices, each of which lowers on the fast path; chunking a fast
+#: width makes it worse, hence the band guard rather than chunking
+#: everything.  (An observation from before the current measurement record;
+#: to be re-checked when the sort cell exists — ROADMAP queue 1 item 6.)
 SLOW_GATHER_LANES = (25, 32)
 _GATHER_CHUNK = 24
 
@@ -127,10 +129,8 @@ class ExchangeSpec:
         """'auto' -> the fastest lowering the backend executes:
 
         * TPU, n == 1: ``'local'`` — the collective degenerates to a device-
-          local prefix copy, and ``ragged_all_to_all``'s single-device lowering
-          streams that copy at only ~175 GB/s HBM r+w where the Pallas DMA
-          gather sustains ~525 (docs/PERF.md roofline table), so the DMA kernel
-          IS the exchange here;
+          local prefix copy, which is exactly what the Pallas DMA gather does,
+          so the DMA kernel IS the exchange here;
         * TPU, n > 1: ``'ragged'`` (the ICI collective — network-bound, where
           the local-copy inefficiency is irrelevant);
         * CPU: ``'dense'`` (XLA:CPU has no ragged_all_to_all kernel).
@@ -256,9 +256,7 @@ def _build_local_exchange(mesh: Mesh, spec: ExchangeSpec):
 
     Same contract as the collective lowerings EXCEPT rows past the received
     total are UNSPECIFIED (the collective paths zero them; every consumer
-    slices by ``recv_sizes``, which the transports already do).  Roughly 3x
-    the single-device throughput of ragged_all_to_all's local-copy lowering
-    (~525 vs ~175 GB/s HBM r+w — docs/PERF.md)."""
+    slices by ``recv_sizes``, which the transports already do)."""
     from sparkucx_tpu.ops.pallas_kernels import build_block_gather
 
     gather = build_block_gather(1, spec.recv_rows, impl="dma")

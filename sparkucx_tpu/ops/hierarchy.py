@@ -34,9 +34,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkucx_tpu.ops._compat import shard_map
 from sparkucx_tpu.ops.exchange import ExchangeSpec, exclusive_cumsum
 
 

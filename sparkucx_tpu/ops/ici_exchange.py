@@ -71,9 +71,9 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkucx_tpu.ops._compat import shard_map
 from sparkucx_tpu.ops.exchange import (
     ExchangeSpec,
     build_exchange,

@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sparkucx_tpu.ops._compat import tpu_compiler_params
 
 # Pipelining depth of the dynamic-DMA path: how many block copies may be in
 # flight at once (the numIoThreads analogue, UcxShuffleConf.scala:66-71).
@@ -177,7 +176,7 @@ def _pallas_gather(kernel, interpret: bool, out_rows: int, starts, counts, outs,
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[sem_shape],
         ),
-        compiler_params=tpu_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(starts, counts, outs, src)
     return out[:out_rows]
@@ -367,7 +366,7 @@ def _pallas_scatter(kernel, interpret: bool, out_rows: int, starts, counts, outs
             scratch_shapes=[sem_shape],
         ),
         input_output_aliases={4: 0},
-        compiler_params=tpu_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(starts, counts, outs, src, dst)
     return out[:out_rows]
@@ -650,7 +649,7 @@ def ring_exchange_grid(
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id
         ),
         interpret=interpret,
@@ -795,7 +794,7 @@ def ring_combine_grid(
             pltpu.VMEM((num_groups, 1), jnp.int32),
             pltpu.VMEM((slot_rows, lane), data.dtype),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id
         ),
         interpret=interpret,
@@ -928,7 +927,7 @@ def fused_scatter_ring_grid(
             ],
         ),
         input_output_aliases={4: 1},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id
         ),
         interpret=interpret,

@@ -1,12 +1,13 @@
 """Device LSD radix sort with a fused key+payload scatter (Pallas TPU).
 
-Why this exists (docs/PERF.md "sort floor"): XLA's sort primitive runs at
-~23 M keys/s on a v5e chip (2M uint32 keys ≈ 87 ms — compare/lane-shuffle
-bound, three orders of magnitude off bandwidth), and the payload permutation
-gather runs at ~4-5 GB/s, so argsort+gather caps the device TeraSort step at
-~21 M rows/s.  The only fast data-movement primitive measured on this chip is
-the DMA engine on *contiguous segments* (137-265 GB/s, ops/pallas_kernels.py)
-— so a faster sort must move rows in segments, never through an XLA gather.
+Why this exists: the 'single' sort path is one XLA ``argsort`` plus a payload
+permutation gather — a compare/lane-shuffle-bound sort and a scattered-read
+gather, neither of which moves rows the way the chip moves data fastest.  The
+data-movement primitive this package otherwise relies on is the DMA engine on
+*contiguous segments* (ops/pallas_kernels.py) — so a faster sort should move
+rows in segments, never through an XLA gather.  (No device rate for either
+path is on record yet — root PERF.md; ROADMAP queue 1 item 6 decides whether
+this kernel stays.)
 
 This module is that sort: least-significant-digit radix over the uint32 key
 (lane 0 of the fused row, bitcast — the same key-travels-with-payload layout
@@ -59,7 +60,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sparkucx_tpu.ops._compat import tpu_compiler_params
 
 #: Digit width per pass.  4 bits = 16 buckets x 8 passes: the widest digit
 #: whose per-(tile, bucket) DMA segments stay large (tile_rows/16 rows) while
@@ -72,7 +72,7 @@ NUM_PASSES = 32 // BITS
 
 def _default_tile_rows() -> int:
     """Rows per kernel tile, overridable via SPARKUCX_RADIX_TILE for on-chip
-    tuning sweeps (scripts/hw_session.sh) — the trade is DMA segment size
+    tuning sweeps — the trade is DMA segment size
     (tile/16 rows per bucket) vs VMEM footprint and per-tile search width.
     A malformed or out-of-range value must not torch a scarce hardware
     window with an import-time traceback: warn and fall back to 8192."""
@@ -295,7 +295,7 @@ def _radix_pass(rows: jnp.ndarray, shift: int, tile_rows: int, interpret: bool):
                 pltpu.SemaphoreType.DMA((NUM_BUCKETS,)),
             ],
         ),
-        compiler_params=tpu_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(dests, rows)
 

@@ -32,9 +32,9 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkucx_tpu.ops._compat import shard_map
 from sparkucx_tpu.ops.columnar import (
     ColumnarSpec,
     columnar_body,

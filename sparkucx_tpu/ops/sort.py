@@ -37,9 +37,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkucx_tpu.ops._compat import shard_map
 from sparkucx_tpu.ops.columnar import (
     ColumnarSpec,
     columnar_shard_dense,
@@ -80,7 +80,8 @@ class SortSpec:
         backend), else 'ragged' on TPU / 'dense' elsewhere.  'radix' swaps the
         n=1 local sort for the Pallas LSD radix kernel (ops/radix.py) whose
         scatter moves key+payload together by segment DMA — the explicit
-        opt-in for beating the XLA argsort+gather floor (docs/PERF.md)."""
+        opt-in meant to beat the XLA argsort+gather path (never yet run on a
+        chip — root PERF.md)."""
         if self.impl != "auto":
             return self
         if self.num_executors == 1 and self.recv_capacity >= self.capacity:
@@ -188,8 +189,7 @@ def _sort_body_single(spec: SortSpec, keys: jnp.ndarray, payload: jnp.ndarray, n
 
     The distributed body would sort locally, self-exchange ~100 B/row, and
     sort the (recv_capacity-padded) receive buffer again — twice the sort and
-    a pointless copy; halving that gives ~2x, and measurement chaining on top
-    shows ~21 M rows/s on a v5e chip (docs/PERF.md, sort row + floor note)."""
+    a pointless copy."""
     nv = num_valid[0]
     idx = jnp.arange(spec.capacity, dtype=jnp.int32)
     keys = jnp.where(idx < nv, keys, KEY_MAX)
@@ -209,8 +209,8 @@ def _sort_body_single(spec: SortSpec, keys: jnp.ndarray, payload: jnp.ndarray, n
 def _sort_body_radix(spec: SortSpec, keys, payload, num_valid, *, interpret: bool):
     """n=1 path with the Pallas LSD radix sort (ops/radix.py): key and payload
     fuse into one row tile and move TOGETHER by segment DMA each pass —
-    no XLA argsort, no permutation gather (the two measured walls of the
-    'single' path, docs/PERF.md sort-floor analysis)."""
+    no XLA argsort, no permutation gather (the two halves of the 'single'
+    path's time)."""
     from sparkucx_tpu.ops.radix import radix_sort_rows
 
     nv = num_valid[0]
@@ -441,7 +441,7 @@ def run_external_sort(
     in device batches of ``num_executors * capacity`` rows (one compiled sort
     reused across batches), then the sorted runs are merged on the host.
 
-    The single-chip envelope is ~32M 100 B rows in HBM (docs/PERF.md); this
+    One chip's HBM bounds the rows a single device sort can hold; this
     driver is how the "TeraSort 10GB" workload (BASELINE.json configs[1])
     runs on hardware that can't hold the dataset: the device does the
     O(N log N) work per batch, the host does log2(runs) linear merge passes.

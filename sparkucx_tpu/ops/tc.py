@@ -31,9 +31,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkucx_tpu.ops._compat import shard_map
 from sparkucx_tpu.ops.columnar import ColumnarSpec
 from sparkucx_tpu.ops.relational import exchange_keyed_rows, expand_matches, padded_keys
 from sparkucx_tpu.ops.sort import KEY_MAX

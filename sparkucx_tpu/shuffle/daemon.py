@@ -408,9 +408,9 @@ class DaemonClient:
 def main(argv=None) -> None:
     import argparse
 
-    from sparkucx_tpu.parallel.mesh import apply_platform_env
+    from sparkucx_tpu.utils.compile_cache import enable_compile_cache
 
-    apply_platform_env()
+    enable_compile_cache()
     p = argparse.ArgumentParser(prog="sparkucx-tpu-daemon")
     p.add_argument("--port", type=int, default=1338)  # the reference's DPU port
     p.add_argument("--host", default="127.0.0.1")

@@ -153,14 +153,20 @@ class _ShuffleState:
         #: Bytes currently charged against the owning tenant's HBM quota
         #: (region allocations + restaged rounds, minus disk-tier demotions).
         self.tenant_charged = 0  #: guarded by the owning store's _lock
+        #: Latched by remove_shuffle/close before the staging is released.  A
+        #: reader that resolved this state before the removal must get a clean
+        #: refusal, so the lazy ``staging`` property never re-allocates (and
+        #: never serves fresh zeros as block bytes) once this is set.
+        self.removed = False  #: guarded by self._lock
 
     @property
     def staging(self) -> Optional[np.ndarray]:
-        """Host staging buffer, allocated on first touch.  Device-staged
-        shuffles never read this property, so the buffer is never allocated
-        for them — the observable form of the tentpole's "no host round trip"
-        guarantee (``HbmBlockStore.host_staging_allocated``)."""
-        if self._staging is None:
+        """Host staging buffer, allocated on first touch; None once the
+        shuffle was removed.  Device-staged shuffles never read this property,
+        so the buffer is never allocated for them — the observable form of
+        the tentpole's "no host round trip" guarantee
+        (``HbmBlockStore.host_staging_allocated``)."""
+        if self._staging is None and not self.removed:
             self._staging = np.zeros(
                 len(self.peer_ranges) * self.region_size, dtype=np.uint8
             )
@@ -658,10 +664,11 @@ class HbmBlockStore:
         can see a staging mapping that is about to be munmapped."""
         with self._lock:
             st = self._shuffles.pop(shuffle_id, None)
-            if st is not None and st.staging_closer is not None:
-                st.staging = None
-                st.staging_closer()
             if st is not None:
+                st.removed = True
+                if st.staging_closer is not None:
+                    st.staging = None
+                    st.staging_closer()
                 self._release_spill(st)
                 self._release_tenant(st, st.tenant_charged)
             for key in [k for k in self._replicas if k[0] == shuffle_id]:
@@ -686,6 +693,7 @@ class HbmBlockStore:
             self._replicas.clear()
             self._replica_bytes = 0
             for st in states:
+                st.removed = True
                 if st.staging_closer is not None:
                     st.staging = None
                     st.staging_closer()
@@ -974,9 +982,8 @@ class HbmBlockStore:
 
         lane = st.alignment // 4
         total_rows = len(st.peer_ranges) * (st.region_size // st.alignment)
-        dst = jnp.zeros((total_rows, lane), dtype=jnp.int32)
-        if self.device is not None:
-            dst = jax.device_put(dst, self.device)
+        # allocated on this executor's device, never staged through device 0
+        dst = jnp.zeros((total_rows, lane), dtype=jnp.int32, device=self.device)
         pending = st.device_pending
         if not pending:
             return dst
@@ -1499,7 +1506,12 @@ class HbmBlockStore:
                         flat = np.asarray(rows).reshape(-1).view(np.uint8)
                         body += flat[: e.length].tobytes()
                     else:
-                        body += st.staging[e.offset : e.offset + e.length].tobytes()
+                        staging = st.staging
+                        if staging is None:
+                            raise TransportError(
+                                f"shuffle {shuffle_id} staging already released"
+                            )
+                        body += staging[e.offset : e.offset + e.length].tobytes()
                 if entries:
                     out.append((rnd, entries, bytes(body)))
         return out

@@ -67,21 +67,12 @@ class SpmdShuffleExecutor:
         import jax
         from jax.sharding import Mesh
 
-        from sparkucx_tpu.parallel.mesh import apply_platform_env
-
-        apply_platform_env()
         if coordinator_address is not None:
             # Must run before anything touches the XLA backend (including
             # jax.process_count()); tolerate an already-initialized service.
             from jax._src import distributed as _dist
 
             if _dist.global_state.client is None:
-                if (jax.config.jax_platforms or "").startswith("cpu"):
-                    # CPU multi-controller (tests, dryruns) needs the gloo
-                    # collectives backend picked before the client exists.
-                    from sparkucx_tpu.ops._compat import enable_cpu_cross_process_collectives
-
-                    enable_cpu_cross_process_collectives()
                 jax.distributed.initialize(
                     coordinator_address, num_processes=num_processes, process_id=process_id
                 )

@@ -56,12 +56,8 @@ from sparkucx_tpu.core.operation import (
 )
 from sparkucx_tpu.core.transport import ExecutorId, ShuffleTransport
 from sparkucx_tpu.parallel.membership import ClusterMembership
-from sparkucx_tpu.parallel.mesh import surviving_submesh
-from sparkucx_tpu.ops.exchange import (
-    bucket_send_rows,
-    make_mesh,
-    rebucket_slots,
-)
+from sparkucx_tpu.parallel.mesh import executor_mesh, surviving_submesh
+from sparkucx_tpu.ops.exchange import bucket_send_rows, rebucket_slots
 from sparkucx_tpu.ops.planner import PlanContext, PlanSignals, make_planner
 from sparkucx_tpu.ops.skew import (
     chunk_size_rows,
@@ -151,7 +147,9 @@ class TpuShuffleCluster:
     ) -> None:
         self.conf = conf or TpuShuffleConf()
         n = num_executors or self.conf.num_executors
-        self.mesh = mesh if mesh is not None else make_mesh(n, self.conf.mesh_axis_name)
+        # ICI-neighbour order where the backend exposes chip coords (TPU);
+        # backend order otherwise (the CPU test mesh)
+        self.mesh = mesh if mesh is not None else executor_mesh(n, self.conf.mesh_axis_name)
         self.num_executors = int(self.mesh.devices.size)
         devices = list(self.mesh.devices.reshape(-1))
         self.transports: List[TpuShuffleTransport] = [
@@ -245,6 +243,19 @@ class TpuShuffleCluster:
         with open(path, "w") as f:
             _json.dump({"traceEvents": merged, "displayTimeUnit": "ms"}, f)
         return len(merged)
+
+    def executed_lowerings(self) -> Dict[str, List[str]]:
+        """The lowering of every executable this cluster has compiled, by
+        kind — what ran (``fn.spec.impl`` for exchanges, ``fn.impl`` for block
+        gathers), not what the conf asked for.  The chip smoke asserts on it."""
+        out: Dict[str, List[str]] = {"exchange": [], "gather": []}
+        with self._lock:
+            for key, fn in self._exchange_cache.items():
+                if key[0] == "gather":
+                    out["gather"].append(fn.impl)
+                else:
+                    out["exchange"].append(fn.spec.impl)
+        return out
 
     def metrics_text(self) -> str:
         """The cluster registry's Prometheus exposition (collective-plane
@@ -614,7 +625,9 @@ class TpuShuffleCluster:
                         # bounded despite data-dependent reassembled rows
                         dshard = pad_rows_pow2(jnp.concatenate(pieces), xp=jnp)
                     else:
-                        dshard = jnp.zeros((1, lane), dtype=parts[0][2][j].dtype)
+                        dshard = jnp.zeros(
+                            (1, lane), dtype=parts[0][2][j].dtype, device=devices[j]
+                        )
                     dev_shards.append(dshard)
             used = int(logical.sum())
             return shards, logical, dev_shards, (used, nchunks * n * bucketed - used)

@@ -92,10 +92,6 @@ class TestColumnarShuffle:
             assert int(np.asarray(counts).sum()) == N * CAP
 
     def test_ragged_lowering(self, mesh):
-        from sparkucx_tpu.ops._compat import HAS_RAGGED_ALL_TO_ALL
-
-        if not HAS_RAGGED_ALL_TO_ALL:
-            pytest.skip("jax.lax.ragged_all_to_all absent on this JAX (< 0.5)")
         spec = ColumnarSpec(
             num_executors=N, capacity=CAP, recv_capacity=N * CAP, width=W, impl="ragged"
         )

@@ -124,10 +124,6 @@ class TestRaggedLowering:
     def test_ragged_lowers_to_stablehlo(self, mesh):
         # XLA:CPU can't execute ragged-all-to-all, but tracing/lowering must work —
         # this pins the TPU path's graph without TPU hardware.
-        from sparkucx_tpu.ops._compat import HAS_RAGGED_ALL_TO_ALL
-
-        if not HAS_RAGGED_ALL_TO_ALL:
-            pytest.skip("jax.lax.ragged_all_to_all absent on this JAX (< 0.5)")
         spec = _spec(impl="ragged")
         fn = build_exchange(mesh, spec)
         data = jax.ShapeDtypeStruct((N * spec.send_rows, LANE), np.int32)

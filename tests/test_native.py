@@ -105,6 +105,34 @@ def test_version():
     assert native._load().ts_version() == 1
 
 
+def test_source_change_forces_rebuild(tmp_path, monkeypatch):
+    """The library is keyed on arena.cpp's contents, not its mtime: a stale
+    .so copied along with the checkout is never loaded for different source."""
+    import shutil
+
+    src = tmp_path / "arena.cpp"
+    shutil.copy(native._SRC, src)
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+
+    def fresh_load():
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_built_here", False)
+        assert native._load() is not None, native.build_error()
+        return native._so_path(), native.built_here()
+
+    first, built = fresh_load()
+    assert built and os.path.exists(first)
+    # same source again (even with a newer mtime): loaded as is
+    os.utime(src)
+    assert fresh_load() == (first, False)
+    # one changed byte: a different library is built, the stale one removed
+    src.write_text(src.read_text() + "\n// changed\n")
+    second, built = fresh_load()
+    assert built and second != first
+    assert os.path.exists(second) and not os.path.exists(first)
+
+
 class TestShmStore:
     def test_store_with_shm_staging(self):
         from sparkucx_tpu.config import TpuShuffleConf

@@ -259,10 +259,6 @@ class TestRaggedLowering:
     def test_ragged_impl_lowers_on_cpu_mesh(self):
         # compile-time trace check: the ragged path must build a valid HLO even
         # where no CPU kernel exists to run it
-        from sparkucx_tpu.ops._compat import HAS_RAGGED_ALL_TO_ALL
-
-        if not HAS_RAGGED_ALL_TO_ALL:
-            pytest.skip("jax.lax.ragged_all_to_all absent on this JAX (< 0.5)")
         n, slot_rows = 8, 4
         spec = ExchangeSpec(
             num_executors=n,

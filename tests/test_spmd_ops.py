@@ -27,8 +27,6 @@ CHILD = textwrap.dedent(
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     pid = int(sys.argv[1]); coord = sys.argv[2]
-    from sparkucx_tpu.ops._compat import enable_cpu_cross_process_collectives
-    enable_cpu_cross_process_collectives()
     jax.distributed.initialize(coord, num_processes=2, process_id=pid)
     assert len(jax.devices()) == 4, jax.devices()
 
@@ -90,8 +88,6 @@ CHILD_COMBINE = textwrap.dedent(
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     pid = int(sys.argv[1]); coord = sys.argv[2]
-    from sparkucx_tpu.ops._compat import enable_cpu_cross_process_collectives
-    enable_cpu_cross_process_collectives()
     jax.distributed.initialize(coord, num_processes=2, process_id=pid)
     assert len(jax.devices()) == 4, jax.devices()
 
