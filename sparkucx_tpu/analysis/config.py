@@ -36,6 +36,11 @@ from __future__ import annotations
 #:   backing (memmap vs RAM).  Both stay underscored: admission and tier
 #:   state are store internals, not writer/eviction API.  Reviewed with the
 #:   multi-tenant service PR.
+#: - hbm_store.py ``._write_stats``: same-file friend once more — at its
+#:   commit MapWriter adds its blocks, bytes and copy time to the store's
+#:   map-side write counters (the ``store`` metrics family) inside the
+#:   store-lock section that records the commit.  Reviewed with the
+#:   layer-spans tracing PR.
 #: - service/tenants.py ``._gate``: ``Tenant`` is a same-file data holder of
 #:   its ``TenantRegistry`` — the registry lazily creates the per-tenant
 #:   CreditGate under its own lock; exposing the slot publicly would invite
@@ -85,6 +90,7 @@ ALLOWLIST = {
     ("store/hbm_store.py", "private-access", "._rollover"),  # also ._rollover_device
     ("store/hbm_store.py", "private-access", "._charge_tenant"),
     ("store/hbm_store.py", "private-access", "._staging"),
+    ("store/hbm_store.py", "private-access", "._write_stats"),
     ("service/tenants.py", "private-access", "._gate"),
     ("core/block.py", "private-access", "._mmap"),
     ("shuffle/daemon.py", "private-access", "._sendmsg_all"),

@@ -10,8 +10,9 @@ pins the scheme statically:
 * every ``sample(<family>, <name>, ...)`` literal: family matches
   ``[a-z][a-z0-9]*`` and name fragments match snake_case (f-string name
   templates are checked on their constant fragments);
-* every ``counter_dict_provider(<family>, ...)`` literal family likewise
-  (that adapter stamps the family onto a whole accessor's counters);
+* every ``counter_dict_provider(<family>, ...)`` and
+  ``labelled_counter_provider(<family>, ...)`` literal family likewise
+  (those adapters stamp the family onto a whole accessor's counters);
 * families used in code ⊆ families documented in the OBSERVABILITY.md
   table (rows shaped ``| `fam` | ...``), and documented families ⊆ used —
   both directions, so the doc can neither lag nor advertise ghosts.
@@ -102,7 +103,7 @@ def metrics_naming_pass(program: Program) -> List[Finding]:
                                 f"metric name fragment {bad[0]!r} is not "
                                 f"snake_case — exported rows must parse as "
                                 f"{METRIC_PREFIX}_<family>_<name>")))
-            elif callee == "counter_dict_provider" and node.args:
+            elif callee in ("counter_dict_provider", "labelled_counter_provider") and node.args:
                 fam = _str_arg(node.args[0])
                 if fam is not None:
                     used_families.setdefault(fam, (rel, node.lineno))
@@ -146,7 +147,7 @@ def metrics_naming_pass(program: Program) -> List[Finding]:
             for fam in sorted(documented - set(used_families)):
                 findings.append(Finding(OBS_METRICS_MODULE, 1, PASS, (
                     f"{TRACE_DOC} documents metric family '{fam}' but no "
-                    f"sample()/counter_dict_provider() site registers it — "
+                    f"sample()/counter-provider site registers it — "
                     f"prune the stale row or restore the family")))
 
     findings.sort(key=lambda f: (f.path, f.line, f.message))

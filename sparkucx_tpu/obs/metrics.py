@@ -19,7 +19,7 @@ Naming scheme (docs/OBSERVABILITY.md): ``sparkucx_tpu_<family>_<metric>``
 with snake_case metric names and labels for dimensions (``executor``,
 ``lane``, ``kind``, ``app``...).  Families mirror the subsystems: ``wire``,
 ``replica``, ``compress``, ``elastic``, ``eviction``, ``store``, ``tenant``,
-``reader``, ``ops``, ``obs`` (the plane's own health: ring drops).
+``reader``, ``ops``, ``daemon``, ``obs`` (the plane's own health: ring drops).
 
 Lock discipline: ``_lock`` guards only the provider list and is never held
 while a provider runs — providers take their subsystems' own locks (store
@@ -235,6 +235,26 @@ def wire_lane_provider(fn: Callable[[], Iterable[Mapping]]) -> Provider:
                 kind = "gauge" if name.endswith("p99_ns") else "counter"
                 suffix = "" if name.endswith("p99_ns") else "_total"
                 out.append(sample("wire", f"{name}{suffix}", value, lab, kind=kind))
+        return out
+
+    return provide
+
+
+def labelled_counter_provider(
+    family: str, label: str, fn: Callable[[], Iterable[Mapping[str, object]]]
+) -> Provider:
+    """Adapt an accessor that returns one flat counter dict per value of a
+    dimension (the store's per-executor write counters, the daemon's per-op
+    frame counters): the ``label`` key of each dict becomes that label, every
+    other numeric key a ``<name>_total`` counter row."""
+
+    def provide() -> List[MetricSample]:
+        out: List[MetricSample] = []
+        for row in fn():
+            lab = {label: row[label]}
+            for name, value in row.items():
+                if name != label and isinstance(value, (int, float)):
+                    out.append(sample(family, f"{name}_total", value, lab, kind="counter"))
         return out
 
     return provide

@@ -200,6 +200,7 @@ def _gather_sizes(spec: ExchangeSpec, size_row: jnp.ndarray):
 gather_size_matrix = _gather_sizes
 
 
+@jax.named_scope("exchange_ragged")
 def _exchange_shard_ragged(spec: ExchangeSpec, data: jnp.ndarray, size_row: jnp.ndarray):
     """Slot-region staging -> ragged_all_to_all over rows -> tight sender-major recv.
 
@@ -222,6 +223,7 @@ def _exchange_shard_ragged(spec: ExchangeSpec, data: jnp.ndarray, size_row: jnp.
     return out, recv_sizes[None, :]
 
 
+@jax.named_scope("exchange_dense")
 def _exchange_shard_dense(spec: ExchangeSpec, data: jnp.ndarray, size_row: jnp.ndarray):
     """Slot staging -> tiled all_to_all -> row-gather compaction.
 
@@ -264,7 +266,8 @@ def _build_local_exchange(mesh: Mesh, spec: ExchangeSpec):
     def local_fn(data, size_matrix):
         zero = jnp.zeros(1, dtype=jnp.int32)
         counts = size_matrix[0, :1].astype(jnp.int32)
-        recv = gather(zero, counts, zero, data)
+        with jax.named_scope("exchange_local"):
+            recv = gather(zero, counts, zero, data)
         return recv, size_matrix
 
     sharding = NamedSharding(mesh, P(spec.axis_name, None))

@@ -154,6 +154,7 @@ def _gather_tiled_kernel(starts_ref, counts_ref, outs_ref, src_ref, out_ref, sem
     jax.lax.fori_loop(0, num_blocks, block_body, 0)
 
 
+@jax.named_scope("block_gather")
 def _pallas_gather(kernel, interpret: bool, out_rows: int, starts, counts, outs, src):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -178,10 +179,12 @@ def _pallas_gather(kernel, interpret: bool, out_rows: int, starts, counts, outs,
         ),
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
+        name="block_gather_dma" if kernel is _gather_dma_kernel else "block_gather_tiled",
     )(starts, counts, outs, src)
     return out[:out_rows]
 
 
+@jax.named_scope("block_gather")
 def _xla_gather(out_rows: int, starts, counts, outs, src):
     """Portable lowering: map each output row to its source row.
 
@@ -227,12 +230,14 @@ def build_block_gather(
     if impl is None:
         impl = "dma" if jax.devices()[0].platform == "tpu" else "xla"
     if impl == "xla":
-        fn = jax.jit(functools.partial(_xla_gather, out_rows))
+        f = functools.partial(_xla_gather, out_rows)
     elif impl in ("dma", "tiled"):
         kernel = _gather_dma_kernel if impl == "dma" else _gather_tiled_kernel
-        fn = jax.jit(functools.partial(_pallas_gather, kernel, interpret, out_rows))
+        f = functools.partial(_pallas_gather, kernel, interpret, out_rows)
     else:
         raise ValueError(f"unknown impl {impl!r}")
+    f.__name__ = "block_gather"  # the executable is jit_block_gather, not jit__unknown
+    fn = jax.jit(f)
     fn.impl = impl
     return fn
 
@@ -333,6 +338,7 @@ def _scatter_tiled_kernel(starts_ref, counts_ref, outs_ref, src_ref, dst_ref, ou
     jax.lax.fori_loop(0, num_blocks, block_body, 0)
 
 
+@jax.named_scope("block_scatter")
 def _pallas_scatter(kernel, interpret: bool, out_rows: int, starts, counts, outs, src, dst):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -368,10 +374,12 @@ def _pallas_scatter(kernel, interpret: bool, out_rows: int, starts, counts, outs
         input_output_aliases={4: 0},
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
+        name="block_scatter_dma" if kernel is _scatter_dma_kernel else "block_scatter_tiled",
     )(starts, counts, outs, src, dst)
     return out[:out_rows]
 
 
+@jax.named_scope("block_scatter")
 def _xla_scatter(window: int, out_rows: int, starts, counts, outs, src, dst):
     """Portable lowering: one masked ``dynamic_update_slice`` window per block.
 
@@ -438,6 +446,7 @@ def build_block_scatter(
     # Donating dst turns the aliasing into a true in-place append; on CPU
     # donation is unimplemented and would warn every call, so gate it.
     donate = (4,) if jax.devices()[0].platform == "tpu" else ()
+    f.__name__ = "block_scatter"  # the executable is jit_block_scatter, not jit__unknown
     fn = jax.jit(f, donate_argnums=donate)
     fn.impl = impl
     return fn
@@ -653,6 +662,7 @@ def ring_exchange_grid(
             has_side_effects=True, collective_id=collective_id
         ),
         interpret=interpret,
+        name="ring_exchange",
     )(data)
 
 
@@ -798,6 +808,7 @@ def ring_combine_grid(
             has_side_effects=True, collective_id=collective_id
         ),
         interpret=interpret,
+        name="ring_combine",
     )(data)
 
 
@@ -931,6 +942,7 @@ def fused_scatter_ring_grid(
             has_side_effects=True, collective_id=collective_id
         ),
         interpret=interpret,
+        name="fused_scatter_ring",
     )(starts, counts, outs, packed, staging)
 
 

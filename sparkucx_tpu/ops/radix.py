@@ -297,6 +297,7 @@ def _radix_pass(rows: jnp.ndarray, shift: int, tile_rows: int, interpret: bool):
         ),
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
+        name="radix_bin_pass",
     )(dests, rows)
 
 

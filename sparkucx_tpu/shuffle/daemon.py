@@ -27,6 +27,13 @@ Shutdown            23  —
 ==================  ==  =======================================================
 
 Every control op gets an ``Ack`` (id 24) with ``{ok, error?, ...result}``.
+
+Telemetry: every served frame is counted per op (``frames``, ``body_bytes``,
+``serve_ns`` from the frame header's arrival to the reply sent, ``ack_ns`` the
+reply's send, from its start to the frame's end; always on) — the ``daemon``
+family of the cluster's metrics registry.  A span ``daemon.<op>`` over the
+``serve_ns`` interval is recorded only while full tracing is on
+(``TRACER.enabled``), not under the flight recorder's ``recording``.
 """
 
 from __future__ import annotations
@@ -34,7 +41,8 @@ from __future__ import annotations
 import json
 import socket
 import threading
-from typing import Dict, Optional, Tuple
+from time import perf_counter_ns
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -46,6 +54,7 @@ from sparkucx_tpu.core.definitions import (
     pack_frame,
     pack_frame_prefix,
 )
+from sparkucx_tpu.obs.metrics import labelled_counter_provider
 from sparkucx_tpu.service.reactor import Reactor
 from sparkucx_tpu.shuffle.manager import TpuShuffleManager
 from sparkucx_tpu.transport.peer import (
@@ -56,6 +65,7 @@ from sparkucx_tpu.transport.peer import (
     recv_frame,
     unpack_batch_fetch_req,
 )
+from sparkucx_tpu.utils.trace import TRACER
 import struct
 
 _TAG = struct.Struct("<Q")
@@ -78,6 +88,22 @@ class DaemonOp:
     METRICS = 26
 
 
+#: op id -> the name it is counted and traced under (``daemon.<name>``)
+OP_NAMES = {
+    DaemonOp.CREATE_SHUFFLE: "create_shuffle",
+    DaemonOp.OPEN_MAP_WRITER: "open_map_writer",
+    DaemonOp.WRITE_PARTITION: "write_partition",
+    DaemonOp.COMMIT_MAP: "commit_map",
+    DaemonOp.RUN_EXCHANGE: "run_exchange",
+    DaemonOp.REMOVE_SHUFFLE: "remove_shuffle",
+    DaemonOp.STATS: "stats",
+    DaemonOp.SHUTDOWN: "shutdown",
+    DaemonOp.EXPORT_TRACE: "export_trace",
+    DaemonOp.METRICS: "metrics",
+    int(AmId.FETCH_BLOCK_REQ): "fetch_block",
+}
+
+
 def _frame(op: int, header: dict, body: bytes = b"") -> bytes:
     # reuse the AM frame layout with op ids beyond the AM enum
     payload = json.dumps(header).encode()
@@ -88,7 +114,11 @@ def _read_frame(sock) -> Optional[Tuple[int, dict, bytes]]:
     hdr = recv_exact(sock, FRAME_HEADER_SIZE)
     if hdr is None:
         return None
-    op, hlen, blen = struct.unpack("<IQQ", hdr)
+    return _read_frame_rest(sock, *struct.unpack("<IQQ", hdr))
+
+
+def _read_frame_rest(sock, op: int, hlen: int, blen: int) -> Optional[Tuple[int, dict, bytes]]:
+    """The JSON header and body of the frame whose fixed header said so."""
     if hlen + blen > MAX_FRAME_BYTES:
         raise ValueError(f"frame too large ({hlen + blen} B)")
     header = recv_exact(sock, hlen) if hlen else b""
@@ -123,7 +153,15 @@ class ShuffleDaemon:
         self._writers: Dict[int, object] = {}  #: guarded by self._lock
         self._streams: Dict[Tuple[int, int], object] = {}  #: guarded by self._lock
         self._next_writer = 0  #: guarded by self._lock
+        #: per-op frame counters, always on: op id -> [frames, body_bytes,
+        #: serve_ns, ack_ns]; the ``daemon`` family of the cluster's registry
+        self._op_stats: Dict[int, List[int]] = {}  #: guarded by self._lock
         self._lock = threading.Lock()
+        #: ``t_ack``: when the calling thread last began to send a reply
+        self._tls = threading.local()
+        self.manager.cluster.metrics.register(
+            "daemon", labelled_counter_provider("daemon", "op", self.op_stats)
+        )
         # Serving plane: thread-per-connection by default; with
         # server.workers set (or tenants.enabled) the shared reactor holds
         # every idle client in one selector and serves frames from a bounded
@@ -160,30 +198,80 @@ class ShuffleDaemon:
         conn.setblocking(True)
         self._reactor.add_connection(conn, self._serve_step)
 
+    def op_stats(self) -> List[Dict[str, object]]:
+        """One row of counters per op served so far (the ``daemon`` family)."""
+        rows: Dict[str, List[int]] = {}
+        with self._lock:
+            for op, counts in self._op_stats.items():
+                row = rows.setdefault(OP_NAMES.get(op, "unknown"), [0, 0, 0, 0])
+                for i, value in enumerate(counts):
+                    row[i] += value
+        return [
+            {"op": name, "frames": f, "body_bytes": b, "serve_ns": s, "ack_ns": a}
+            for name, (f, b, s, a) in sorted(rows.items())
+        ]
+
     def _ack(self, conn, ok: bool, body: bytes = b"", **extra) -> None:
-        conn.sendall(_frame(DaemonOp.ACK, {"ok": ok, **extra}, body))
+        frame = _frame(DaemonOp.ACK, {"ok": ok, **extra}, body)
+        self._tls.t_ack = perf_counter_ns()  # ack_ns runs from here to the frame's end
+        conn.sendall(frame)
 
     def _serve_step(self, conn: socket.socket) -> bool:
         """Read + dispatch exactly one frame; True keeps the connection.
         The unit of work for both serving planes — the per-connection threads
-        loop over it, the reactor re-arms the connection after each True."""
+        loop over it, the reactor re-arms the connection after each True.
+
+        The frame is on the clock from its fixed header's arrival to the
+        reply sent (``serve_ns``; span ``daemon.<op>`` under full tracing
+        only — one a frame would cost every untraced run and flush the
+        flight recorder's tail); waiting for the next frame's header is
+        outside.  This runs once a block: it reads the clock three times and
+        adds four ints, and anything more shows in the job's time."""
         if not self._running:
             return False
         try:
-            frame = _read_frame(conn)
-            if frame is None:
+            hdr = recv_exact(conn, FRAME_HEADER_SIZE)
+            if hdr is None:
                 return False
-            op, meta, body = frame
-            try:
-                self._dispatch(conn, op, meta, body)
-            except Exception as e:
-                self._ack(conn, False, error=f"{type(e).__name__}: {e}")
+            t0 = perf_counter_ns()
+            op, hlen, body_bytes = struct.unpack("<IQQ", hdr)
+            if TRACER.enabled:
+                with TRACER.span("daemon." + OP_NAMES.get(op, "unknown")):
+                    served = self._serve_frame(conn, op, hlen, body_bytes)
+            else:
+                served = self._serve_frame(conn, op, hlen, body_bytes)
+            if not served:
+                return False
+            t1 = perf_counter_ns()
+            t_ack = getattr(self._tls, "t_ack", 0)
+            with self._lock:
+                row = self._op_stats.get(op)
+                if row is None:
+                    row = self._op_stats[op] = [0, 0, 0, 0]
+                row[0] += 1
+                row[1] += body_bytes
+                row[2] += t1 - t0
+                if t_ack > t0:
+                    row[3] += t1 - t_ack
             return True
         except (OSError, ValueError):
             # dead socket or an unparseable/oversized frame: drop THIS
             # connection, keep serving others (the endpoint-eviction policy,
             # UcxWorkerWrapper.scala:248-253)
             return False
+
+    def _serve_frame(self, conn: socket.socket, op: int, hlen: int, blen: int) -> bool:
+        """The rest of the frame whose fixed header said so, dispatched and
+        answered; False when the peer went away mid-frame."""
+        frame = _read_frame_rest(conn, op, hlen, blen)
+        if frame is None:
+            return False
+        op, meta, body = frame
+        try:
+            self._dispatch(conn, op, meta, body)
+        except Exception as e:
+            self._ack(conn, False, error=f"{type(e).__name__}: {e}")
+        return True
 
     def _serve(self, conn: socket.socket) -> None:
         try:
@@ -294,6 +382,7 @@ class ShuffleDaemon:
         reply_hdr = _TAG.pack(tag) + _COUNT.pack(len(bids)) + blob
         total = sum(p.nbytes for p in parts)
         prefix = pack_frame_prefix(AmId.FETCH_BLOCK_REQ_ACK, reply_hdr, total)
+        self._tls.t_ack = perf_counter_ns()  # the reply is this op's ack
         if hasattr(conn, "sendmsg"):
             BlockServer._sendmsg_all(conn, [prefix] + parts)
         else:

@@ -39,8 +39,10 @@ from __future__ import annotations
 import shutil
 import threading
 import time
+from time import perf_counter_ns
 import weakref
 from bisect import bisect_right
+from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -216,6 +218,11 @@ class MapWriter:
         self._open_reduce: Optional[int] = None
         self._chunks: List[bytes] = []
         self._written = 0
+        #: ns this writer spent copying payload (``bytes(data)`` in ``write``,
+        #: the staging copy in ``close_partition``); joins the store's
+        #: ``copy_ns`` counter at ``commit``
+        self._copy_ns = 0
+        self._counted = False  # this writer's blocks are in the store's counters
         #: First-commit-wins task-retry semantics: when a successful commit for
         #: this map already exists, the retry attempt's writes are swallowed and
         #: commit() returns the existing table — the reference's atomic
@@ -245,7 +252,12 @@ class MapWriter:
                 f"whole region ({self._state.region_size} B) — raise stagingCapacity"
             )
         if not self._discard:
-            self._chunks.append(bytes(data))
+            if type(data) is bytes:  # bytes(data) would hand it back: no copy to time
+                self._chunks.append(data)
+            else:
+                t0 = perf_counter_ns()
+                self._chunks.append(bytes(data))
+                self._copy_ns += perf_counter_ns() - t0
         self._written += len(data)
 
     def close_partition(self) -> None:
@@ -280,9 +292,11 @@ class MapWriter:
                     self._store._rollover(st)
                 start = peer * st.region_size + int(st.region_used[peer])
                 pos = start
+                t0 = perf_counter_ns()
                 for chunk in self._chunks:
                     st.staging[pos : pos + len(chunk)] = np.frombuffer(chunk, dtype=np.uint8)
                     pos += len(chunk)
+                self._copy_ns += perf_counter_ns() - t0
                 st.blocks[(self.map_id, reduce_id)] = _BlockEntry(
                     offset=start, length=self._written, padded=padded, round=st.round
                 )
@@ -375,12 +389,22 @@ class MapWriter:
             raise TransportError("commit with open partition")
         st = self._state
         parts, rounds = [], []
+        blocks = 0
         for r in range(st.num_reducers):
             e = st.blocks.get((self.map_id, r))
             parts.append((e.offset, e.length) if e is not None else (0, 0))
             rounds.append(e.round if e is not None else 0)
+            blocks += e is not None
         with self._store._lock:
             st.committed_maps.add(self.map_id)
+            # once a writer; a retry's table is the first attempt's, counted then
+            if not (self._discard or self._counted):
+                self._counted = True
+                counters = self._store._write_stats
+                counters["staged_blocks"] += blocks
+                counters["staged_bytes"] += sum(length for _, length in parts)
+                counters["copy_ns"] += self._copy_ns
+        self._copy_ns = 0
         return MapperInfo(
             st.shuffle_id, self.map_id, tuple(parts),
             tuple(rounds) if any(rounds) else None,
@@ -557,6 +581,17 @@ class HbmBlockStore:
         self._spill_holder: Dict[str, Optional[str]] = {"dir": None}  #: guarded by self._lock
         self._spill_finalizer = weakref.finalize(self, _purge_spill_dir, self._spill_holder)
         self._spill_bytes = 0  #: guarded by self._lock
+        #: Map-side write counters (the ``store`` metrics family): plain ints,
+        #: always on, bumped once a map task at its commit (``staged_*`` from
+        #: its block table, ``copy_ns`` from the clock round each block's copy)
+        #: and once a staging round (``rollovers``, ``spilled_bytes``, ``*_ns``).
+        #: ``rollover_ns`` includes the ``spill_ns`` of the round it spilled;
+        #: ``spill_ns`` also counts the eviction manager's demotions.
+        #: guarded by self._lock
+        self._write_stats: Dict[str, int] = dict.fromkeys(
+            ("staged_blocks", "staged_bytes", "rollovers", "spilled_bytes",
+             "rollover_ns", "spill_ns", "copy_ns"), 0
+        )
         #: Optional TenantRegistry (service/tenants.py).  When set, shuffles
         #: created with an ``app_id`` are admission-checked: region
         #: allocations charge the tenant's HBM quota and over-quota writes
@@ -825,39 +860,66 @@ class HbmBlockStore:
                 "pressure_bytes": self._pressure_locked(),
             }
 
+    def write_stats(self) -> Dict[str, int]:
+        """The map-side write counters plus this store's ``executor`` id —
+        one row of the ``store`` metrics family."""
+        with self._lock:
+            return {"executor": self.executor_id, **self._write_stats}
+
     def _rollover(self, st: _ShuffleState) -> None:
-        """Snapshot the current staging epoch and start a fresh round (caller
-        holds self._lock).
+        """Snapshot the current staging epoch and start a fresh round
+        (caller holds self._lock).
 
         With ``conf.spill_to_disk`` (default) the completed round moves to an
         ``np.memmap`` file and its RAM is released — the capacity-beyond-memory
         tier the reference gets from DPU-attached NVMe (NvkvHandler.scala:
         160-242); ``read_block``/``block_staging_view``/``seal`` serve spilled
         rounds through the memmap transparently.  With it off, the round stays
-        as a RAM snapshot (bounded by host memory)."""
-        snap = st.staging
-        if self.conf.spill_to_disk:
-            snap = self._spill_round(st, snap)
-        st.prev_rounds.append((snap, st.region_used))
-        st.staging = np.zeros_like(st.staging)
-        st.region_used = np.zeros_like(st.region_used)
-        st.round += 1
+        as a RAM snapshot (bounded by host memory).
+
+        Span ``store.rollover`` (once a staging round); its child
+        ``store.spill`` is the disk tier, so its self time is the fresh
+        staging buffer and the bookkeeping."""
+        with self._rollover_span(st):
+            snap = st.staging
+            if self.conf.spill_to_disk:
+                snap = self._spill_round(st, snap)
+            st.prev_rounds.append((snap, st.region_used))
+            st.staging = np.zeros_like(st.staging)
+            st.region_used = np.zeros_like(st.region_used)
+            st.round += 1
+
+    @contextmanager
+    def _rollover_span(self, st: _ShuffleState):
+        """Span ``store.rollover`` and the ``rollovers`` / ``rollover_ns``
+        counters round one rollover's body (caller holds self._lock)."""
+        t0 = perf_counter_ns()
+        with span(
+            "store.rollover", shuffle_id=st.shuffle_id, round=st.round,
+            executor=self.executor_id, bytes=int(st.region_used.sum()),
+        ):
+            yield
+        self._write_stats["rollovers"] += 1
+        self._write_stats["rollover_ns"] += perf_counter_ns() - t0
 
     def _rollover_device(self, st: _ShuffleState) -> None:
         """Device-round analogue of ``_rollover``: materialize the full round
         in HBM via the scatter kernel, pull it D2H ONCE as the round snapshot
         (the spill boundary is where a host copy is unavoidable — HBM cannot
         hold every round), and continue in a fresh device round (caller holds
-        self._lock).  The lazy host staging buffer stays unallocated."""
-        payload = self._materialize_device_round(st)
-        snap = np.asarray(payload).reshape(-1).view(np.uint8)
-        if self.conf.spill_to_disk:
-            snap = self._spill_round(st, snap)
-        st.prev_rounds.append((snap, st.region_used))
-        st.region_used = np.zeros_like(st.region_used)
-        st.device_pending = []
-        st.device_blocks = {}
-        st.round += 1
+        self._lock).  The lazy host staging buffer stays unallocated.  Same
+        ``store.rollover`` span and counters as ``_rollover``; its self time
+        here is the scatter kernel and the D2H."""
+        with self._rollover_span(st):
+            payload = self._materialize_device_round(st)
+            snap = np.asarray(payload).reshape(-1).view(np.uint8)
+            if self.conf.spill_to_disk:
+                snap = self._spill_round(st, snap)
+            st.prev_rounds.append((snap, st.region_used))
+            st.region_used = np.zeros_like(st.region_used)
+            st.device_pending = []
+            st.device_blocks = {}
+            st.round += 1
 
     def _spill_round(
         self,
@@ -875,7 +937,8 @@ class HbmBlockStore:
         bytes actually staged, not to stagingCapacity.
 
         Defaults spill the LIVE round (rollover); the eviction manager passes
-        ``round_idx``/``region_used`` to demote an already-completed round."""
+        ``round_idx``/``region_used`` to demote an already-completed round.
+        Span ``store.spill`` covers file create + region copies + flush."""
         import os
         import tempfile
 
@@ -898,15 +961,22 @@ class HbmBlockStore:
                 f"{nbytes} B round > spillDiskCap {cap} B"
             )
         path = os.path.join(self._spill_dir, f"s{st.shuffle_id}_r{round_idx}.bin")
-        mm = np.memmap(path, dtype=np.uint8, mode="w+", shape=staging.shape)
-        for p in range(len(st.peer_ranges)):
-            used = int(region_used[p])
-            if used:
-                start = p * st.region_size
-                mm[start : start + used] = staging[start : start + used]
-        mm.flush()
+        t0 = perf_counter_ns()
+        with span(
+            "store.spill", shuffle_id=st.shuffle_id, round=round_idx,
+            executor=self.executor_id, bytes=nbytes,
+        ):
+            mm = np.memmap(path, dtype=np.uint8, mode="w+", shape=staging.shape)
+            for p in range(len(st.peer_ranges)):
+                used = int(region_used[p])
+                if used:
+                    start = p * st.region_size
+                    mm[start : start + used] = staging[start : start + used]
+            mm.flush()
         st.spill_files.append((path, nbytes))
         self._spill_bytes += nbytes
+        self._write_stats["spilled_bytes"] += nbytes
+        self._write_stats["spill_ns"] += perf_counter_ns() - t0
         return mm
 
     def _unspill_file(self, st: _ShuffleState, path: str) -> None:

@@ -49,7 +49,7 @@ from sparkucx_tpu.transport.executor import (
 from sparkucx_tpu.transport.peer import PeerTransport
 from sparkucx_tpu.utils.logging import get_logger
 from sparkucx_tpu.utils.stats import StatsAggregator
-from sparkucx_tpu.utils.trace import TRACER, instant, merge_events
+from sparkucx_tpu.utils.trace import TRACER, instant, merge_events, span
 
 logger = get_logger("transport.spmd")
 
@@ -312,36 +312,51 @@ class SpmdShuffleExecutor:
             async dispatch — SPMD order is preserved because every process
             submits the same plan's sub-rounds in the same order, whatever
             the depth)."""
-            if rnd < len(rounds):
-                payload, sizes = rounds[rnd]
-                sub_sizes = chunk_size_rows(sizes, chunk, q)
-                if isinstance(payload, jax.Array):
-                    # Sealed straight onto the device (device staging or the
-                    # single-round host seal): relocate/slice on-device, no
-                    # host round trip; device_put is then a no-op pin.  A
-                    # single-shot plan whose bucket equals the staging slot
-                    # donates the sealed payload as-is (historical fast path).
-                    piece = (
-                        payload
-                        if plan.single_shot and q == staging_slot
-                        else slice_subround(payload, n, chunk, q, xp=jnp)
-                    )
+            round_bytes = bucketed * lane * 4
+            with span(
+                "exchange.assemble",
+                shuffle_id=shuffle_id, round=rnd, chunk=chunk, bytes=round_bytes,
+            ):
+                if rnd < len(rounds):
+                    payload, sizes = rounds[rnd]
+                    sub_sizes = chunk_size_rows(sizes, chunk, q)
+                    if isinstance(payload, jax.Array):
+                        # Sealed straight onto the device (device staging or
+                        # the single-round host seal): relocate/slice
+                        # on-device, no host round trip; device_put is then a
+                        # no-op pin.  A single-shot plan whose bucket equals
+                        # the staging slot donates the sealed payload as-is
+                        # (historical fast path).
+                        piece = (
+                            payload
+                            if plan.single_shot and q == staging_slot
+                            else slice_subround(payload, n, chunk, q, xp=jnp)
+                        )
+                    else:
+                        piece = slice_subround(np.asarray(payload), n, chunk, q)
                 else:
-                    piece = slice_subround(np.asarray(payload), n, chunk, q)
-            else:
-                piece = np.zeros((bucketed, lane), dtype=np.int32)
-                sub_sizes = np.zeros(n, dtype=np.int32)
-            local_payload = jax.device_put(piece, self.device)
-            local_sizes = jax.device_put(
-                np.reshape(np.asarray(sub_sizes), (1, n)).astype(np.int32), self.device
-            )
-            data = jax.make_array_from_single_device_arrays(
-                (n * bucketed, lane), data_sharding, [local_payload]
-            )
-            size_mat = jax.make_array_from_single_device_arrays(
-                (n, n), sizes_sharding, [local_sizes]
-            )
-            recv, rs = fn(data, size_mat)
+                    piece = np.zeros((bucketed, lane), dtype=np.int32)
+                    sub_sizes = np.zeros(n, dtype=np.int32)
+            # the time the device_put calls hold this lane, not the DMA
+            with span(
+                "exchange.h2d",
+                shuffle_id=shuffle_id, round=rnd, chunk=chunk, bytes=round_bytes + n * 4,
+            ):
+                local_payload = jax.device_put(piece, self.device)
+                local_sizes = jax.device_put(
+                    np.reshape(np.asarray(sub_sizes), (1, n)).astype(np.int32), self.device
+                )
+                data = jax.make_array_from_single_device_arrays(
+                    (n * bucketed, lane), data_sharding, [local_payload]
+                )
+                size_mat = jax.make_array_from_single_device_arrays(
+                    (n, n), sizes_sharding, [local_sizes]
+                )
+            with span(
+                "exchange.collective",
+                shuffle_id=shuffle_id, round=rnd, chunk=chunk, rows=bucketed,
+            ):
+                recv, rs = fn(data, size_mat)
             my_recv = next(
                 s.data for s in recv.addressable_shards if s.device == self.device
             )

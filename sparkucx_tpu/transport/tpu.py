@@ -77,6 +77,7 @@ from sparkucx_tpu.transport.executor import (
 from sparkucx_tpu.obs.metrics import (
     MetricsRegistry,
     counter_dict_provider,
+    labelled_counter_provider,
     stats_aggregator_provider,
     tracer_provider,
 )
@@ -196,6 +197,12 @@ class TpuShuffleCluster:
             "elastic", counter_dict_provider("elastic", self._elastic_snapshot)
         )
         self.metrics.register("obs", tracer_provider(TRACER))
+        self.metrics.register(
+            "store",
+            labelled_counter_provider(
+                "store", "executor", lambda: [t.store.write_stats() for t in self.transports]
+            ),
+        )
         self.recorder = FlightRecorder(
             TRACER,
             postmortem_dir=self.conf.obs_postmortem_dir or None,
@@ -480,7 +487,11 @@ class TpuShuffleCluster:
             """One sub-round's assemble + H2D + collective dispatch + async
             D2H kick-off.  Everything here is JAX async dispatch: this
             sub-round's collective is still in flight when the next one
-            assembles."""
+            assembles.  Three child spans of ``exchange.pipeline.submit``,
+            once a round and chunk: ``exchange.assemble`` (host zeros + slot
+            copies, or the device-pieces array), ``exchange.h2d`` (the time
+            the two ``device_put`` calls hold this lane — NOT the DMA, which
+            is asynchronous) and ``exchange.collective`` (the dispatch)."""
             faults.check("exchange.submit", shuffle_id=shuffle_id, round=rnd)
             if self.membership.epoch != epoch0:
                 if plan.single_shot:
@@ -503,33 +514,48 @@ class TpuShuffleCluster:
                     payloads.append(None)
                     size_rows.append(np.zeros(n, dtype=np.int32))
             sub_sizes = np.stack([chunk_size_rows(sr, chunk, q) for sr in size_rows])
-            if all(isinstance(p, jax.Array) for p in payloads):
-                # Shards were sealed straight onto their executors' devices —
-                # assemble the global array without any host round-trip.
-                if plan.single_shot and q == staging_slot:
-                    # bucket == staging slot: donate the sealed payloads as-is
-                    # (the historical single-shot no-copy fast path)
-                    pieces = payloads
+            round_bytes = n * bucketed * self.row_bytes
+            on_device = all(isinstance(p, jax.Array) for p in payloads)
+            with span(
+                "exchange.assemble",
+                shuffle_id=shuffle_id, round=rnd, chunk=chunk, bytes=round_bytes,
+            ):
+                if on_device:
+                    # Shards were sealed straight onto their executors' devices
+                    # — assemble the global array without any host round-trip.
+                    if plan.single_shot and q == staging_slot:
+                        # bucket == staging slot: donate the sealed payloads
+                        # as-is (the historical single-shot no-copy fast path)
+                        pieces = payloads
+                    else:
+                        # slot relocation / chunk-window slice on each device
+                        pieces = [slice_subround(p, n, chunk, q, xp=jnp) for p in payloads]
+                    data = jax.make_array_from_single_device_arrays(
+                        (n * bucketed, lane), data_sharding, pieces
+                    )
                 else:
-                    # slot relocation / chunk-window slice on each device
-                    pieces = [slice_subround(p, n, chunk, q, xp=jnp) for p in payloads]
-                data = jax.make_array_from_single_device_arrays(
-                    (n * bucketed, lane), data_sharding, pieces
+                    host = np.zeros((n * bucketed, lane), dtype=np.int32)
+                    for i, p in enumerate(payloads):
+                        if p is not None:
+                            # mixed host/device rounds pay one D2H here, same as
+                            # the historical assemble (allowlisted host-sync cost)
+                            arr = np.asarray(p) if isinstance(p, jax.Array) else p
+                            host[i * bucketed : (i + 1) * bucketed] = slice_subround(
+                                arr, n, chunk, q
+                            )
+            # What this span measures is the time the two device_put calls
+            # hold the submit lane (runtime staging copy + enqueue), not the
+            # DMA: the transfer itself is asynchronous and is no XLA op.
+            with span(
+                "exchange.h2d",
+                shuffle_id=shuffle_id, round=rnd, chunk=chunk,
+                bytes=(0 if on_device else round_bytes) + sub_sizes.size * 4,
+            ):
+                if not on_device:
+                    data = jax.device_put(host, data_sharding)
+                size_mat = jax.device_put(
+                    sub_sizes.astype(np.int32), NamedSharding(self.mesh, P(ax, None))
                 )
-            else:
-                host = np.zeros((n * bucketed, lane), dtype=np.int32)
-                for i, p in enumerate(payloads):
-                    if p is not None:
-                        # mixed host/device rounds pay one D2H here, same as
-                        # the historical assemble (allowlisted host-sync cost)
-                        arr = np.asarray(p) if isinstance(p, jax.Array) else p
-                        host[i * bucketed : (i + 1) * bucketed] = slice_subround(
-                            arr, n, chunk, q
-                        )
-                data = jax.device_put(host, data_sharding)
-            size_mat = jax.device_put(
-                sub_sizes.astype(np.int32), NamedSharding(self.mesh, P(ax, None))
-            )
             with span(
                 "exchange.collective",
                 shuffle_id=shuffle_id, round=rnd, chunk=chunk, rows=bucketed,

@@ -22,8 +22,6 @@ class StatsSummary:
     ops: int = 0
     bytes: int = 0
     total_ns: int = 0
-    min_ns: Optional[int] = None
-    max_ns: Optional[int] = None
     p50_ns: Optional[int] = None
     p99_ns: Optional[int] = None
     #: exchange staging occupancy (ops/skew.py telemetry): rows that carried
@@ -34,10 +32,6 @@ class StatsSummary:
     @property
     def mean_ns(self) -> float:
         return self.total_ns / self.ops if self.ops else 0.0
-
-    @property
-    def throughput_gbps(self) -> float:
-        return self.bytes / self.total_ns if self.total_ns else 0.0  # bytes/ns == GB/s
 
     @property
     def padding_fraction(self) -> float:
@@ -122,8 +116,6 @@ class StatsAggregator:
                 ops=ops,
                 bytes=self._bytes[kind],
                 total_ns=self._total_ns[kind],
-                min_ns=samples[0] if samples else None,
-                max_ns=samples[-1] if samples else None,
                 p50_ns=samples[len(samples) // 2] if samples else None,
                 p99_ns=samples[min(len(samples) - 1, int(len(samples) * 0.99))] if samples else None,
                 used_rows=used,

@@ -28,7 +28,11 @@ The obs plane (PR 14) grew this into a distributed tracer:
   full tracing is off, so a postmortem bundle always has a trace tail;
   ``enabled`` additionally lights up the env-var export path.  Both off means
   the module-level ``span()`` returns a shared no-op — no dict build, no
-  generator frame — the hot submit lane's fast path.
+  generator frame — the hot submit lane's fast path.  A cluster's
+  ``FlightRecorder`` turns ``recording`` on, so in a served process every
+  ``span()`` is live whether or not anyone traces: sites that fire once a
+  block or frame check ``TRACER.enabled`` themselves (a span under full
+  tracing only, as the daemon's ``daemon.<op>``) or keep plain counters.
 * ``current_context()`` exposes the innermost open span for wire pickup and
   ``activate()``/``remote_context()`` re-parent server-side work under it.
 """
@@ -299,9 +303,6 @@ class Tracer:
             out = list(itertools.islice(reversed(self._events), n))
         out.reverse()
         return out
-
-    def to_json(self) -> str:
-        return json.dumps({"traceEvents": self.events, "displayTimeUnit": "ms"})
 
     def export(self, path: str) -> int:
         """Write the chrome trace file; returns the event count."""

@@ -1,0 +1,415 @@
+"""The spans and counters inside the three host intervals that were opaque:
+the map-side write (``store.rollover`` / ``store.spill`` and the ``store``
+metrics family), the round's submit lane (``exchange.assemble`` /
+``exchange.h2d`` / ``exchange.collective`` under ``exchange.pipeline.submit``)
+and the daemon's side of a frame (``daemon.<op>`` and the ``daemon`` family).
+
+Counts and nesting on the CPU mesh; no duration here is a rate."""
+
+import collections
+import re
+
+import numpy as np
+import pytest
+
+from sparkucx_tpu.config import TpuShuffleConf
+from sparkucx_tpu.shuffle.daemon import DaemonClient, ShuffleDaemon
+from sparkucx_tpu.transport.tpu import TpuShuffleCluster
+from sparkucx_tpu.utils.trace import TRACER
+
+SUBMIT_CHILDREN = ("exchange.assemble", "exchange.h2d", "exchange.collective")
+
+
+@pytest.fixture
+def tracer():
+    """The process-wide tracer, cleared; back to what it was afterwards."""
+    enabled, recording = TRACER.enabled, TRACER.recording
+    TRACER.clear()
+    yield TRACER
+    TRACER.enabled, TRACER.recording = enabled, recording
+    TRACER.clear()
+
+
+def run_shuffle(cluster, shuffle_id, mappers, reducers, block_bytes):
+    """One shuffle through the store and the exchange; returns the bytes written."""
+    cluster.create_shuffle(shuffle_id, mappers, reducers)
+    written = 0
+    for m in range(mappers):
+        t = cluster.transport(cluster.meta(shuffle_id).map_owner[m])
+        w = t.store.map_writer(shuffle_id, m)
+        for r in range(reducers):
+            data = np.full(block_bytes, (m * reducers + r) % 251, np.uint8).tobytes()
+            w.write_partition(r, data)
+            written += len(data)
+        t.commit_block(w.commit().pack())
+    cluster.run_exchange(shuffle_id)
+    return written
+
+
+def spans(tracer, *names):
+    return [e for e in tracer.events if e["ph"] == "X" and (not names or e["name"] in names)]
+
+
+def inside(child, parent):
+    return parent["ts"] <= child["ts"] and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
+
+
+def family(text, name):
+    """``{(metric, label value): value}`` of one family of a Prometheus text."""
+    rows = re.findall(rf'^sparkucx_tpu_{name}_(\w+)\{{\w+="([^"]+)"\}} (\S+)$', text, re.MULTILINE)
+    return {(metric, label): float(value) for metric, label, value in rows}
+
+
+@pytest.fixture
+def multi_round(tracer):
+    """Two executors, 4 MiB of staging each, 8 mappers x 2 reducers x 900 KB:
+    every executor rolls its staging over, with full tracing on."""
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 22, block_alignment=128, num_executors=2)
+    cluster = TpuShuffleCluster(conf, num_executors=2)
+    tracer.enable()
+    written = run_shuffle(cluster, 0, mappers=8, reducers=2, block_bytes=900_000)
+    tracer.disable()
+    return cluster, written
+
+
+def test_one_rollover_span_per_rollover_per_executor(multi_round, tracer):
+    cluster, _ = multi_round
+    rollovers = spans(tracer, "store.rollover")
+    by_executor = collections.Counter(e["args"]["executor"] for e in rollovers)
+    for t in cluster.transports:
+        counted = t.store.write_stats()["rollovers"]
+        assert counted >= 1 and by_executor[t.executor_id] == counted
+    for e in rollovers:
+        assert set(e["args"]) == {"shuffle_id", "round", "executor", "bytes"} and e["args"]["bytes"] > 0
+    # rounds are numbered from 0 per executor, one span each
+    for t in cluster.transports:
+        rounds = sorted(e["args"]["round"] for e in rollovers if e["args"]["executor"] == t.executor_id)
+        assert rounds == list(range(len(rounds)))
+
+
+def test_spill_is_the_child_of_its_rollover(multi_round, tracer):
+    rollovers, spills = spans(tracer, "store.rollover"), spans(tracer, "store.spill")
+    assert len(spills) == len(rollovers)  # the default disk tier: every rollover spills once
+    by_id = {e["span_id"]: e for e in rollovers}
+    for spill in spills:
+        parent = by_id[spill["parent_id"]]
+        assert inside(spill, parent)
+        assert spill["args"]["round"] == parent["args"]["round"]
+        assert spill["args"]["bytes"] == parent["args"]["bytes"]
+
+
+def test_no_spill_span_without_the_disk_tier(tracer):
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128,
+                          num_executors=2, spill_to_disk=False)
+    cluster = TpuShuffleCluster(conf, num_executors=2)
+    tracer.enable()
+    run_shuffle(cluster, 0, mappers=4, reducers=2, block_bytes=300_000)
+    assert spans(tracer, "store.rollover") and not spans(tracer, "store.spill")
+    stats = cluster.transports[0].store.write_stats()
+    assert stats["rollovers"] >= 1 and stats["spilled_bytes"] == 0 and stats["spill_ns"] == 0
+
+
+def test_submit_lane_children_nest_and_cover_the_parent(multi_round, tracer):
+    submits = spans(tracer, "exchange.pipeline.submit")
+    assert len(submits) >= 2  # a multi-round shuffle
+    children = spans(tracer, *SUBMIT_CHILDREN)
+    covered = 0.0
+    for submit in submits:
+        mine = [c for c in children if c["parent_id"] == submit["span_id"]]
+        assert sorted(c["name"] for c in mine) == sorted(SUBMIT_CHILDREN)
+        assert all(inside(c, submit) for c in mine)
+        # in the lane's order, none overlapping
+        mine.sort(key=lambda c: c["ts"])
+        assert [c["name"] for c in mine] == list(SUBMIT_CHILDREN)
+        assert all(a["ts"] + a["dur"] <= b["ts"] for a, b in zip(mine, mine[1:]))
+        assert {c["args"]["round"] for c in mine} == {submit["args"]["round"]}
+        covered += sum(c["dur"] for c in mine)
+    assert covered >= 0.9 * sum(s["dur"] for s in submits)
+    assert len(children) == 3 * len(submits)  # none outside a submit lane
+
+
+@pytest.mark.parametrize("name", ["exchange.assemble", "exchange.h2d"])
+def test_submit_lane_children_carry_the_rounds_bytes(multi_round, tracer, name):
+    cluster, _ = multi_round
+    for e in spans(tracer, name):
+        assert set(e["args"]) == {"shuffle_id", "round", "chunk", "bytes"}
+        # a round is every executor's whole staging bucket, padding included
+        assert e["args"]["bytes"] >= 2 * (1 << 22)
+
+
+def test_store_family_counts_what_was_written(multi_round):
+    cluster, written = multi_round
+    rows = family(cluster.metrics_text(), "store")
+    executors = {label for _, label in rows}
+    assert executors == {"0", "1"}
+    assert sum(rows[("staged_bytes_total", e)] for e in executors) == written
+    assert sum(rows[("staged_blocks_total", e)] for e in executors) == 8 * 2
+    for e in executors:
+        stats = cluster.transports[int(e)].store.write_stats()
+        assert rows[("rollovers_total", e)] == stats["rollovers"] >= 1
+        assert rows[("spilled_bytes_total", e)] == stats["spilled_bytes"] > 0
+        # the spill is inside the rollover; the copies were timed
+        assert 0 < rows[("spill_ns_total", e)] <= rows[("rollover_ns_total", e)]
+        assert rows[("copy_ns_total", e)] > 0
+
+
+def test_a_retry_attempt_stages_and_counts_nothing(tracer):
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=1)
+    cluster = TpuShuffleCluster(conf, num_executors=1)
+    store = cluster.transports[0].store
+    cluster.create_shuffle(0, 1, 1)
+    first = store.map_writer(0, 0)
+    first.write_partition(0, b"x" * 1000)
+    first.commit()
+    before = store.write_stats()
+    retry = store.map_writer(0, 0)
+    assert retry.is_retry_discard
+    retry.write_partition(0, b"y" * 1000)
+    retry.commit()
+    after = store.write_stats()
+    assert before["staged_blocks"] == after["staged_blocks"] == 1
+    assert before["staged_bytes"] == after["staged_bytes"] == 1000
+
+
+def test_concurrent_writers_lose_no_count():
+    """More writer threads than cores on one store, the interpreter switching
+    often: every block and byte is counted, none twice."""
+    import sys
+    import threading
+
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=1)
+    cluster = TpuShuffleCluster(conf, num_executors=1)
+    store = cluster.transports[0].store
+    writers, blocks, size = 16, 40, 3000  # 1.9 MB through 1 MiB of staging: rollovers too
+    cluster.create_shuffle(0, writers, blocks)
+    errors = []
+
+    def work(map_id):
+        try:
+            w = store.map_writer(0, map_id)
+            for r in range(blocks):
+                w.write_partition(r, bytes([map_id]) * size)
+            w.commit()
+        except Exception as e:  # surfaced below: a thread's exception is otherwise lost
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(m,)) for m in range(writers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(t.is_alive() for t in threads)
+    stats = store.write_stats()
+    assert stats["staged_blocks"] == writers * blocks and stats["staged_bytes"] == writers * blocks * size
+    assert stats["rollovers"] == store.num_rounds(0) - 1 >= 1
+    assert stats["spilled_bytes"] > 0 and 0 < stats["spill_ns"] <= stats["rollover_ns"] and stats["copy_ns"] > 0
+
+
+# -- the daemon's side of a frame ------------------------------------------
+
+FRAMES = 24  # blocks a daemon job writes: 4 mappers x 6 reducers
+
+
+def daemon_job(client, shuffle_id, block_bytes=700):
+    """One job over the socket; returns the bytes written."""
+    mappers, reducers = 4, FRAMES // 4
+    client.create_shuffle(shuffle_id, mappers, reducers)
+    written = 0
+    for m in range(mappers):
+        writer = client.open_map_writer(shuffle_id, m)
+        for r in range(reducers):
+            data = bytes([(m * reducers + r) % 251]) * block_bytes
+            client.write_partition(writer, r, data)
+            written += len(data)
+        client.commit_map(writer)
+    client.run_exchange(shuffle_id)
+    client.remove_shuffle(shuffle_id)
+    return written
+
+
+@pytest.fixture
+def daemon():
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=1)
+    served = ShuffleDaemon(conf, num_executors=1, port=0)
+    client = DaemonClient(served.address)
+    yield served, client
+    client.close()
+    served.close()
+
+
+def test_daemon_family_counts_the_frames_sent(daemon, tracer):
+    served, client = daemon
+    written = daemon_job(client, 0)
+    text = client.metrics_text()  # op 26, over the same socket
+    rows = family(text, "daemon")
+    assert rows[("frames_total", "write_partition")] == FRAMES
+    assert rows[("body_bytes_total", "write_partition")] == written
+    assert rows[("frames_total", "open_map_writer")] == rows[("frames_total", "commit_map")] == 4
+    for op in ("create_shuffle", "run_exchange", "remove_shuffle"):
+        assert rows[("frames_total", op)] == 1
+    for (metric, op), value in rows.items():
+        if metric == "serve_ns_total":
+            # the ack's send is part of the frame's time on the clock
+            assert 0 < rows[("ack_ns_total", op)] <= value
+    # the daemon's store counted the same blocks
+    store = family(text, "store")
+    assert store[("staged_blocks_total", "0")] == FRAMES and store[("staged_bytes_total", "0")] == written
+
+
+def test_concurrent_connections_lose_no_frame_count(daemon, tracer):
+    """One serving thread a connection, one counter table: four clients at
+    once count four jobs' frames."""
+    import threading
+
+    served, first = daemon
+    clients = [first] + [DaemonClient(served.address) for _ in range(3)]
+    errors = []
+
+    def work(client, shuffle_id):
+        try:
+            daemon_job(client, shuffle_id)
+        except Exception as e:  # surfaced below
+            errors.append(e)
+
+    try:
+        threads = [threading.Thread(target=work, args=(c, i)) for i, c in enumerate(clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not errors and not any(t.is_alive() for t in threads)
+        rows = {row["op"]: row for row in served.op_stats()}
+        assert rows["write_partition"]["frames"] == 4 * FRAMES
+        assert rows["write_partition"]["body_bytes"] == 4 * FRAMES * 700
+        assert rows["commit_map"]["frames"] == 16 and rows["run_exchange"]["frames"] == 4
+    finally:
+        for c in clients[1:]:
+            c.close()
+
+
+def test_daemon_spans_only_under_full_tracing(daemon, tracer):
+    served, client = daemon
+    assert tracer.recording and not tracer.enabled  # the cluster's flight recorder
+    daemon_job(client, 0)
+    assert not [e for e in spans(tracer) if e["name"].startswith("daemon.")]
+    tracer.clear()
+    tracer.enable()
+    daemon_job(client, 1)
+    tracer.disable()
+    names = collections.Counter(e["name"] for e in spans(tracer) if e["name"].startswith("daemon."))
+    assert names["daemon.write_partition"] == FRAMES
+    assert names["daemon.open_map_writer"] == names["daemon.commit_map"] == 4
+    assert names["daemon.run_exchange"] == 1
+    # the exchange the daemon ran for the client is inside its frame
+    [frame] = spans(tracer, "daemon.run_exchange")
+    [superstep] = spans(tracer, "exchange.superstep")
+    assert inside(superstep, frame) and superstep["parent_id"] == frame["span_id"]
+
+
+@pytest.mark.parametrize("blocks", [8, 64])
+def test_recording_alone_pays_nothing_per_block(tracer, blocks):
+    """Untraced is not off: the flight recorder keeps ``recording`` on.  A
+    one-round job then adds the two new submit-lane children per round and
+    chunk to the ring, and nothing per block."""
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=2)
+    cluster = TpuShuffleCluster(conf, num_executors=2)
+    assert tracer.recording and not tracer.enabled
+    tracer.clear()
+    run_shuffle(cluster, 0, mappers=blocks // 2, reducers=2, block_bytes=500)
+    names = collections.Counter(e["name"] for e in spans(tracer))
+    submits = names["exchange.pipeline.submit"]
+    assert submits == 1  # one round, one chunk
+    assert names["exchange.assemble"] == names["exchange.h2d"] == names["exchange.collective"] == submits
+    assert not [n for n in names if n.startswith(("store.", "daemon."))]
+    # the same events whatever the number of blocks
+    assert sum(names.values()) == sum(1 for _ in names), names
+
+
+def test_recording_alone_pays_nothing_per_frame(daemon, tracer):
+    served, client = daemon
+    assert tracer.recording and not tracer.enabled
+    tracer.clear()
+    daemon_job(client, 0)
+    first = collections.Counter(e["name"] for e in spans(tracer))
+    tracer.clear()
+    daemon_job(client, 1, block_bytes=70)
+    second = collections.Counter(e["name"] for e in spans(tracer))
+    assert first == second and max(first.values()) == 1  # one of each, none per frame
+    assert first["exchange.assemble"] == first["exchange.h2d"] == 1
+
+
+# -- names on the device ----------------------------------------------------
+
+
+def test_exchange_and_gather_bodies_trace_under_a_named_scope():
+    """What a device trace calls the operations: the scope is in every
+    operation's ``op_name``; the jitted functions keep their names."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparkucx_tpu.ops.exchange import ExchangeSpec, build_exchange, make_mesh
+    from sparkucx_tpu.ops.pallas_kernels import build_block_gather, build_block_scatter
+
+    fn = build_exchange(make_mesh(2), ExchangeSpec(num_executors=2, send_rows=16, recv_rows=16, impl="dense"))
+    text = fn.lower(jax.ShapeDtypeStruct((32, 128), jnp.int32),
+                    jax.ShapeDtypeStruct((2, 2), jnp.int32)).as_text(debug_info=True)
+    assert "module @jit__exchange_shard_dense" in text and "exchange_dense/all_to_all" in text
+    plan = jax.ShapeDtypeStruct((4,), jnp.int32)
+    rows = jax.ShapeDtypeStruct((64, 128), jnp.int32)
+    gather = build_block_gather(4, 64, impl="xla").lower(plan, plan, plan, rows).as_text(debug_info=True)
+    assert "module @jit_block_gather" in gather and "jit(block_gather)/block_gather/gather" in gather
+    scatter = build_block_scatter(4, 64, impl="xla").lower(plan, plan, plan, rows, rows).as_text(debug_info=True)
+    assert "module @jit_block_scatter" in scatter and "jit(block_scatter)/block_scatter/" in scatter
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The chip's compiler, for a chip that is described and not attached."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.mark.parametrize("chips, impl, module, operation", [
+    (1, "local", "jit_local_fn", "%block_gather_dma"),
+    (4, "ragged", "jit__exchange_shard_ragged", "%ragged_all_to_all"),
+])
+def test_compiled_for_the_chip_the_exchange_keeps_its_names(v5e, chips, impl, module, operation):
+    """At a 64 MiB round for the v5e: the module names ``exchange_roofline``
+    finds its executables by, the kernel's name on its custom call (was
+    ``%_unknown_``), and the scope in the operation's ``op_name``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from sparkucx_tpu.ops.exchange import ExchangeSpec, build_exchange
+
+    mesh = Mesh(np.array(v5e.devices[:chips]), ("ex",))
+    rows = (64 << 20) // 512
+    fn = build_exchange(mesh, ExchangeSpec(num_executors=chips, send_rows=rows, recv_rows=rows, impl=impl))
+    sharding = NamedSharding(mesh, P("ex", None))
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        text = fn.lower(
+            jax.ShapeDtypeStruct((chips * rows, 128), jnp.int32, sharding=sharding),
+            jax.ShapeDtypeStruct((chips, chips), jnp.int32, sharding=sharding),
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert text.startswith(f"HloModule {module},")
+    [line] = [l for l in text.splitlines() if l.lstrip().startswith(operation + ".")]
+    assert f"exchange_{impl}/" in re.search(r'op_name="([^"]*)"', line).group(1)
+    if impl == "local":
+        assert 'custom_call_target="tpu_custom_call"' in line and "block_gather/block_gather_dma" in line
