@@ -54,9 +54,13 @@ from __future__ import annotations
 #:   lane — that overlap is the whole point of RoundPipeline.  Blocking in a
 #:   SUBMIT stage is the real bug this pass exists to catch, and submit-stage
 #:   findings are never allowlisted wholesale.
-#: - spmd.py ``_submit``: ``np.asarray(payload)`` sits on the host-payload
-#:   branch (the ``isinstance(payload, jax.Array)`` arm above it device_puts
-#:   instead); asarray over an ndarray is a free view, not a device sync.
+#: - spmd.py / tpu.py ``_submit``: ``np.asarray(payload)`` sits on the
+#:   host-payload branch (the ``isinstance(payload, jax.Array)`` arm above it
+#:   keeps the piece on its device instead); asarray over an ndarray is a
+#:   free view that strips the spill tier's ``np.memmap`` subclass, not a
+#:   device sync.  tpu.py's mixed host/device round no longer pulls a
+#:   device-sealed payload to the host: no ``np.asarray`` of a ``jax.Array``
+#:   is left in either submit lane.
 #:   (The retired per-variant engines' ``_assemble``/``_submit_quota``
 #:   entries were pruned with PR 13 — the unified plan executor replaced
 #:   them.)

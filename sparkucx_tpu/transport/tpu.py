@@ -487,11 +487,19 @@ class TpuShuffleCluster:
             """One sub-round's assemble + H2D + collective dispatch + async
             D2H kick-off.  Everything here is JAX async dispatch: this
             sub-round's collective is still in flight when the next one
-            assembles.  Three child spans of ``exchange.pipeline.submit``,
-            once a round and chunk: ``exchange.assemble`` (host zeros + slot
-            copies, or the device-pieces array), ``exchange.h2d`` (the time
-            the two ``device_put`` calls hold this lane — NOT the DMA, which
-            is asynchronous) and ``exchange.collective`` (the dispatch)."""
+            assembles.  The round's global send array is made from one
+            device-resident piece per executor, never from a global host
+            buffer.  Three child spans of ``exchange.pipeline.submit``, once
+            a round and chunk: ``exchange.assemble`` (choosing and slicing
+            each executor's piece: a view of its sealed round wherever the
+            plan's slot is the staging slot, else the one copy
+            ``slice_subround`` makes), ``exchange.h2d`` (the time the
+            per-executor ``device_put`` calls and the global array's
+            construction hold this lane — NOT the DMA, which is asynchronous)
+            and ``exchange.collective`` (the dispatch).  Counters
+            ``exchange.assemble``: ``direct_bytes`` (host bytes handed to
+            ``device_put`` as views of a sealed round) and ``copied_bytes``
+            (host bytes that went through a pad / chunk-window copy first)."""
             faults.check("exchange.submit", shuffle_id=shuffle_id, round=rnd)
             if self.membership.epoch != epoch0:
                 if plan.single_shot:
@@ -514,48 +522,67 @@ class TpuShuffleCluster:
                     payloads.append(None)
                     size_rows.append(np.zeros(n, dtype=np.int32))
             sub_sizes = np.stack([chunk_size_rows(sr, chunk, q) for sr in size_rows])
-            round_bytes = n * bucketed * self.row_bytes
-            on_device = all(isinstance(p, jax.Array) for p in payloads)
+            direct_bytes = copied_bytes = 0
             with span(
                 "exchange.assemble",
-                shuffle_id=shuffle_id, round=rnd, chunk=chunk, bytes=round_bytes,
+                shuffle_id=shuffle_id, round=rnd, chunk=chunk,
+                bytes=n * bucketed * self.row_bytes,
             ):
-                if on_device:
-                    # Shards were sealed straight onto their executors' devices
-                    # — assemble the global array without any host round-trip.
-                    if plan.single_shot and q == staging_slot:
-                        # bucket == staging slot: donate the sealed payloads
-                        # as-is (the historical single-shot no-copy fast path)
-                        pieces = payloads
+                pieces = []
+                for p in payloads:
+                    if p is None:
+                        piece = None  # a zero piece, made on its device below
+                    elif isinstance(p, jax.Array):
+                        # Sealed straight onto its executor's device: donate
+                        # as-is when the bucket is the staging slot (the
+                        # historical single-shot no-copy fast path), else
+                        # relocate / slice the chunk window on that device.
+                        if plan.single_shot and q == staging_slot:
+                            piece = p
+                        else:
+                            piece = slice_subround(p, n, chunk, q, xp=jnp)
                     else:
-                        # slot relocation / chunk-window slice on each device
-                        pieces = [slice_subround(p, n, chunk, q, xp=jnp) for p in payloads]
-                    data = jax.make_array_from_single_device_arrays(
-                        (n * bucketed, lane), data_sharding, pieces
-                    )
-                else:
-                    host = np.zeros((n * bucketed, lane), dtype=np.int32)
-                    for i, p in enumerate(payloads):
-                        if p is not None:
-                            # mixed host/device rounds pay one D2H here, same as
-                            # the historical assemble (allowlisted host-sync cost)
-                            arr = np.asarray(p) if isinstance(p, jax.Array) else p
-                            host[i * bucketed : (i + 1) * bucketed] = slice_subround(
-                                arr, n, chunk, q
-                            )
-            # What this span measures is the time the two device_put calls
-            # hold the submit lane (runtime staging copy + enqueue), not the
-            # DMA: the transfer itself is asynchronous and is no XLA op.
+                        # np.asarray strips the spill tier's np.memmap
+                        # subclass without copying: the runtime sees a plain
+                        # ndarray.
+                        host = np.asarray(p)
+                        piece = slice_subround(host, n, chunk, q)
+                        if np.may_share_memory(piece, host):
+                            direct_bytes += piece.nbytes
+                        else:  # a pad or a strided chunk window: the one copy
+                            copied_bytes += piece.nbytes
+                    pieces.append(piece)
+            self.stats.record_counters(
+                "exchange.assemble", direct_bytes=direct_bytes, copied_bytes=copied_bytes
+            )
+            # What this span measures is the time the device_put calls hold
+            # the submit lane (runtime staging copy + enqueue), not the DMA:
+            # the transfer itself is asynchronous and is no XLA op.
             with span(
                 "exchange.h2d",
                 shuffle_id=shuffle_id, round=rnd, chunk=chunk,
-                bytes=(0 if on_device else round_bytes) + sub_sizes.size * 4,
+                bytes=direct_bytes + copied_bytes + sub_sizes.size * 4,
             ):
-                if not on_device:
-                    data = jax.device_put(host, data_sharding)
-                size_mat = jax.device_put(
-                    sub_sizes.astype(np.int32), NamedSharding(self.mesh, P(ax, None))
+                for i, piece in enumerate(pieces):
+                    if piece is None:
+                        # Made on the device, no host bytes; and fresh every
+                        # submit, because the exchange donates its argument 0
+                        # when send_rows == recv_rows: a cached zero piece
+                        # would be invalidated by the first round to use it.
+                        pieces[i] = jnp.zeros(
+                            (bucketed, lane), dtype=jnp.int32, device=devices[i]
+                        )
+                    elif not isinstance(piece, jax.Array):
+                        # The runtime may read a view of the sealed round
+                        # after device_put returns.  That is safe: sealed
+                        # rounds are immutable until remove_shuffle
+                        # (HbmBlockStore.seal), and run_exchange returns only
+                        # after every round has drained.
+                        pieces[i] = jax.device_put(piece, devices[i])
+                data = jax.make_array_from_single_device_arrays(
+                    (n * bucketed, lane), data_sharding, pieces
                 )
+                size_mat = jax.device_put(sub_sizes.astype(np.int32), data_sharding)
             with span(
                 "exchange.collective",
                 shuffle_id=shuffle_id, round=rnd, chunk=chunk, rows=bucketed,

@@ -241,3 +241,153 @@ class TestHierarchicalCluster:
     def test_invalid_factorization_rejected(self):
         with pytest.raises(ValueError, match="divisible"):
             TpuShuffleConf().replace(num_executors=8, num_slices=3)
+
+
+class TestRoundAssembly:
+    """The round's global send array is made from one piece per executor, each
+    put onto its own device: a view of the sealed round under the default
+    conf, a zero piece made on the device for an executor with fewer rounds,
+    and never a global host buffer.  Two executors, 1 MiB of staging each;
+    counts and bytes on the CPU mesh, no rate."""
+
+    ROUND_BYTES = 2 << 20  # two executors' staging buckets
+    BLOCK = 150_000
+
+    # mappers on executor 0 and on executor 1 -> their staging rounds
+    ONE_DEVICE_SEALED = (6, 1)  # 2 rounds and 1: the single round seals onto its device
+    BOTH_SPILL = (9, 4)  # 3 rounds and 2: memmap views, then the live buffers
+
+    @staticmethod
+    def _cluster(**conf):
+        conf = TpuShuffleConf(
+            staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=2, **conf
+        )
+        return TpuShuffleCluster(conf, num_executors=2)
+
+    def _stage(self, cluster, shuffle_id, mappers, rng):
+        """Write ``mappers[e]`` map outputs on executor ``e`` (two blocks of
+        150 KB each) and commit; the exchange is left to the caller."""
+        owners = [0] * mappers[0] + [1] * mappers[1]
+        meta = cluster.create_shuffle(shuffle_id, len(owners), 2, map_owner=owners)
+        oracle = {}
+        for m, owner in enumerate(owners):
+            t = cluster.transport(owner)
+            w = t.store.map_writer(shuffle_id, m)
+            for r in range(2):
+                payload = rng.integers(0, 256, size=self.BLOCK - 7 * m - r, dtype=np.uint8).tobytes()
+                oracle[(m, r)] = payload
+                w.write_partition(r, payload)
+            t.commit_block(w.commit().pack())
+        return meta, oracle
+
+    @staticmethod
+    def _read_back(cluster, shuffle_id, meta, oracle):
+        for r in range(2):
+            consumer = meta.owner_of_reduce(r)
+            t = cluster.transport(consumer)
+            maps = sorted(m for m, rr in oracle if rr == r)
+            bids = [ShuffleBlockId(shuffle_id, m, r) for m in maps]
+            bufs = [_buf(1 << 18) for _ in maps]
+            reqs = t.fetch_blocks_by_block_ids(consumer, bids, bufs, [None] * len(maps))
+            while not all(q.completed() for q in reqs):
+                t.progress()
+            for m, req, buf in zip(maps, reqs, bufs):
+                res = req.wait(1)
+                assert res.status == OperationStatus.SUCCESS, str(res.error)
+                assert buf.host_view()[: buf.size].tobytes() == oracle[(m, r)], (m, r)
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    @pytest.mark.parametrize("mappers", [ONE_DEVICE_SEALED, BOTH_SPILL])
+    def test_unequal_round_counts_read_back_exact(self, rng, mappers, depth):
+        cluster = self._cluster(pipeline_depth=depth)
+        meta, oracle = self._stage(cluster, 0, mappers, rng)
+        rounds = [t.store.num_rounds(0) for t in cluster.transports]
+        assert rounds[0] > rounds[1] >= 1  # executor 1 contributes None at the end
+        cluster.run_exchange(0)
+        assert len(meta.recv_sizes) == rounds[0]
+        self._read_back(cluster, 0, meta, oracle)
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_exchange_never_writes_into_a_sealed_round(self, rng, monkeypatch, depth):
+        """On the CPU ``device_put`` may alias host memory and the exchange
+        donates its input: the sealed rounds (memmap and live), which the
+        pull fallback reads afterwards, hold the same bits after the
+        exchange as when they were sealed."""
+        cluster = self._cluster(pipeline_depth=depth)
+        self._stage(cluster, 0, self.BOTH_SPILL, rng)
+        at_seal = {}
+        for t in cluster.transports:
+            def seal(shuffle_id, store=t.store, real=t.store.seal):
+                out = real(shuffle_id)
+                at_seal[store.executor_id] = [np.array(p) for p, _ in out]
+                return out
+
+            monkeypatch.setattr(t.store, "seal", seal)
+        cluster.run_exchange(0)
+        kinds = set()
+        for t in cluster.transports:
+            sealed = t.store._state(0).sealed_payload
+            assert len(sealed) == len(at_seal[t.executor_id]) >= 2
+            for before, after in zip(at_seal[t.executor_id], sealed):
+                kinds.add(type(after))
+                np.testing.assert_array_equal(np.asarray(after), before)
+        assert kinds == {np.memmap, np.ndarray}
+
+    @pytest.mark.parametrize(
+        "mappers, host_pieces", [(ONE_DEVICE_SEALED, 2), (BOTH_SPILL, 5)]
+    )
+    def test_default_conf_puts_views_and_copies_nothing(self, rng, mappers, host_pieces):
+        cluster = self._cluster()
+        self._stage(cluster, 0, mappers, rng)
+        cluster.run_exchange(0)
+        counters = cluster.stats.counters("exchange.assemble")
+        # every host-resident sealed round, whole, and nothing for the
+        # device-sealed round or the None of the executor with fewer rounds
+        assert counters == {"direct_bytes": host_pieces << 20, "copied_bytes": 0}
+
+    def test_quota_chunked_plan_copies_each_window_once(self, rng):
+        cluster = self._cluster(slot_quota_rows=1024)  # 4 windows of a 4,096-row slot
+        meta, oracle = self._stage(cluster, 0, self.BOTH_SPILL, rng)
+        cluster.run_exchange(0)
+        counters = cluster.stats.counters("exchange.assemble")
+        assert counters["copied_bytes"] > 0
+        # a strided window at n=2 is never a view; every host byte is put once
+        assert counters["direct_bytes"] == 0 and counters["copied_bytes"] <= 5 << 20
+        self._read_back(cluster, 0, meta, oracle)
+
+    def test_submit_allocates_no_global_host_buffer(self, rng, monkeypatch):
+        """tracemalloc's peak between the start of a submit and its
+        collective dispatch stays under a quarter of the round's bytes: no
+        array of ``n * bucketed`` rows is allocated on the host."""
+        import tracemalloc
+
+        from sparkucx_tpu.testing import faults
+
+        cluster = self._cluster(pipeline_depth=1)  # serial: no drain thread allocating beside it
+        self._stage(cluster, 0, self.BOTH_SPILL, rng)
+        start, peaks = [], []
+
+        def at_submit(**_ctx):
+            tracemalloc.reset_peak()
+            start.append(tracemalloc.get_traced_memory()[0])
+
+        real = cluster._exchange_fn
+
+        def recording(*args, **kwargs):
+            fn = real(*args, **kwargs)
+
+            def dispatch(data, size_mat):
+                peaks.append(tracemalloc.get_traced_memory()[1] - start[-1])
+                return fn(data, size_mat)
+
+            return dispatch
+
+        monkeypatch.setattr(cluster, "_exchange_fn", recording)
+        tracemalloc.start()
+        try:
+            with faults.injected_faults(("exchange.submit", at_submit)):
+                cluster.run_exchange(0)
+        finally:
+            tracemalloc.stop()
+        assert len(peaks) == len(start) == 3  # one submit a round
+        assert max(peaks) < self.ROUND_BYTES // 4, peaks
