@@ -25,6 +25,7 @@ instantiates to run shuffles.  API mirrors Spark's ``ShuffleManager`` SPI —
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Callable, Dict, List, Optional
 
@@ -83,19 +84,16 @@ class TpuShuffleManager:
         _, num_reducers, meta = self._dims(shuffle_id)
         owner = meta.map_owner[map_id]
         transport = self.cluster.transport(owner)
-        writer = TpuShuffleMapOutputWriter(
-            transport.store, transport, shuffle_id, map_id, num_reducers
+        # a hook, not a wrapper assigned over the writer's own method: that
+        # closure held the writer and the writer held it, so every map task's
+        # handle (and through it the shuffle's staging) waited for the
+        # interpreter's full collection
+        return TpuShuffleMapOutputWriter(
+            transport.store, transport, shuffle_id, map_id, num_reducers,
+            on_commit=functools.partial(
+                self.resolvers[owner].on_map_committed, shuffle_id, map_id, num_reducers
+            ),
         )
-        resolver = self.resolvers[owner]
-        orig_commit = writer.commit_all_partitions
-
-        def commit_and_register():
-            lengths = orig_commit()
-            resolver.on_map_committed(shuffle_id, map_id, num_reducers)
-            return lengths
-
-        writer.commit_all_partitions = commit_and_register
-        return writer
 
     def get_reader(
         self,

@@ -23,7 +23,7 @@ from __future__ import annotations
 import random
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Iterable, Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -38,7 +38,7 @@ from sparkucx_tpu.core.operation import (
 )
 from sparkucx_tpu.core.transport import ExecutorId, ShuffleTransport
 from sparkucx_tpu.memory.pool import MemoryPool
-from sparkucx_tpu.utils.trace import TRACER, instant
+from sparkucx_tpu.utils.trace import TRACER, instant, span
 
 #: The fail-fast arm of the failure taxonomy (docs/API.md "Failure
 #: semantics", machine-checked by analysis ERROR_TAXONOMY): faults every
@@ -141,6 +141,20 @@ class BlockFetchResult:
             buf.close()
 
 
+class DeviceRead(NamedTuple):
+    """What ``TpuShuffleReader.read_device`` returns: one reduce task's blocks
+    on its executor's device."""
+
+    #: (rows, lane) int32 ``jax.Array`` on the owning executor's device; rows
+    #: that no entry of ``table`` covers are unspecified
+    packed: Any
+    #: (B, 2) int64 — per block of ``block_ids``, its starting row in
+    #: ``packed`` and its true byte length
+    table: np.ndarray
+    #: the blocks, in (reduce, map) order
+    block_ids: List[ShuffleBlockId]
+
+
 def default_deserializer(payload: bytes) -> Iterable[Any]:
     """Record stream per block (the Spark serializer-stream analogue).
 
@@ -234,7 +248,12 @@ class TpuShuffleReader:
         self.deserializer = deserializer
         self.aggregator = aggregator
         self.key_ordering = key_ordering
-        self.sender_of = sender_of or (lambda m: self.executor_id)
+        if sender_of is None:
+            # binds the id, not the reader: a lambda over ``self`` would put
+            # every reader in a reference cycle, and with it the shuffle
+            # state its ``block_sizes`` closure holds
+            sender_of = lambda m, _local=executor_id: _local  # noqa: E731
+        self.sender_of = sender_of
         self.fetch_retries = max(0, fetch_retries)
         self.memory_budget = memory_budget
         self.spill_dir = spill_dir
@@ -851,6 +870,35 @@ class TpuShuffleReader:
         )
 
     # -- record pipeline ---------------------------------------------------
+
+    def read_device(self) -> DeviceRead:
+        """This task's blocks read ON THE DEVICE: every non-empty block of its
+        partition range, in (reduce, map) order, gathered into one packed
+        ``jax.Array`` on the owning executor's device — the bytes never visit
+        the host (for a consumer that runs on the chip; ``fetch_blocks`` and
+        ``read`` are the host forms).  Needs the received shards retained in
+        HBM (``conf.keep_device_recv``) and a range this reader's executor
+        owns: the transport raises its typed ``TransportError`` otherwise.
+        No retry, failover or hedge applies — the blocks are local after the
+        exchange — so the fault counters of ``metrics`` stay 0; blocks and
+        bytes are counted.  Span ``read.device``, once a task."""
+        fetch = getattr(self.transport, "fetch_blocks_device", None)
+        if fetch is None:
+            raise TransportError(
+                f"{type(self.transport).__name__} has no device-resident fetch"
+            )
+        bids = self._block_ids()
+        with TRACER.executor_scope(self.executor_id), span(
+            "read.device", shuffle_id=self.shuffle_id,
+            reduce_id=self.start_partition, blocks=len(bids),
+        ) as ctx:
+            packed, table = fetch(bids, shuffle_id=self.shuffle_id)
+            if ctx is not None:
+                row_bytes = int(packed.shape[1]) * 4
+                ctx.args["rows"] = int((-(-table[:, 1] // row_bytes)).sum())
+        self.metrics.remote_blocks_fetched += len(bids)
+        self.metrics.remote_bytes_read += int(table[:, 1].sum())
+        return DeviceRead(packed, table, bids)
 
     def read(self) -> Iterator[Any]:
         """deserialize -> combine -> sort (UcxShuffleReader.scala:137-199).

@@ -14,7 +14,7 @@ partition-lengths array Spark's scheduler expects (``MapOutputCommitMessage``).
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -108,11 +108,15 @@ class TpuShuffleMapOutputWriter:
         shuffle_id: int,
         map_id: int,
         num_partitions: int,
+        on_commit: Optional[Callable[[], None]] = None,
     ) -> None:
         self.shuffle_id = shuffle_id
         self.map_id = map_id
         self.num_partitions = num_partitions
         self._transport = transport
+        #: called once, after the commit shipped (the manager registers the
+        #: map's blocks with its resolver here)
+        self._on_commit = on_commit
         self._conf = store.conf
         #: public: the friend writer/stream classes above drive this handle
         self.map_writer: MapWriter = store.map_writer(shuffle_id, map_id)
@@ -171,6 +175,8 @@ class TpuShuffleMapOutputWriter:
         info = self.map_writer.commit()
         self._transport.commit_block(info.pack())
         self._committed = True
+        if self._on_commit is not None:
+            self._on_commit()
         return self._partition_lengths.copy()
 
     def abort(self, error: Optional[BaseException] = None) -> None:

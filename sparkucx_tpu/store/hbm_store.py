@@ -36,12 +36,14 @@ Key design departures (TPU-first, each replacing a reference POC shortcut):
 
 from __future__ import annotations
 
+import functools
 import shutil
 import threading
 import time
 from time import perf_counter_ns
 import weakref
 from bisect import bisect_right
+from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -59,6 +61,27 @@ from sparkucx_tpu.core.operation import (
 from sparkucx_tpu.service.eviction import ServeCache
 from sparkucx_tpu.testing import faults
 from sparkucx_tpu.utils.trace import span
+
+
+#: the largest host buffer ``seal`` hands to one ``device_put``: the default
+#: staging capacity, so a default-conf round is one put as ever; a larger
+#: single round is put in pieces of this size (``HbmBlockStore._put_round``)
+SEAL_PUT_PIECE_BYTES = 64 << 20
+#: pieces whose transfer may be outstanding before the next is put: what HBM
+#: holds beside the round itself while it is being put
+SEAL_PUT_PIECES_IN_FLIGHT = 2
+
+
+@functools.lru_cache(maxsize=None)
+def _update_rows_fn():
+    """``fn(buf, piece, at)``: ``buf`` with ``piece`` written at row ``at``;
+    ``buf`` is donated, so the update is in place."""
+    import jax
+
+    def update_rows(buf, piece, at):
+        return jax.lax.dynamic_update_slice(buf, piece, (at, 0))
+
+    return jax.jit(update_rows, donate_argnums=0)
 
 
 def default_peer_ranges(num_reducers: int, num_peers: int) -> List[Tuple[int, int]]:
@@ -84,6 +107,16 @@ def _purge_spill_dir(holder: Dict[str, Optional[str]]) -> None:
     if path is not None:
         shutil.rmtree(path, ignore_errors=True)
         holder["dir"] = None
+
+
+def _device_nbytes(array) -> int:
+    """Bytes of HBM behind ``array``: 0 for a host array, and for a device
+    array already donated to an exchange."""
+    import jax
+
+    if isinstance(array, jax.Array) and not array.is_deleted():
+        return int(array.nbytes)
+    return 0
 
 
 @dataclass
@@ -587,10 +620,13 @@ class HbmBlockStore:
         #: and once a staging round (``rollovers``, ``spilled_bytes``, ``*_ns``).
         #: ``rollover_ns`` includes the ``spill_ns`` of the round it spilled;
         #: ``spill_ns`` also counts the eviction manager's demotions.
+        #: ``released_device_bytes``: HBM a removed shuffle gave back at its
+        #: removal (its device-sealed round here; its received shards, which
+        #: the cluster counts through ``count_released_device``).
         #: guarded by self._lock
         self._write_stats: Dict[str, int] = dict.fromkeys(
             ("staged_blocks", "staged_bytes", "rollovers", "spilled_bytes",
-             "rollover_ns", "spill_ns", "copy_ns"), 0
+             "rollover_ns", "spill_ns", "copy_ns", "released_device_bytes"), 0
         )
         #: Optional TenantRegistry (service/tenants.py).  When set, shuffles
         #: created with an ``app_id`` are admission-checked: region
@@ -701,9 +737,20 @@ class HbmBlockStore:
             st = self._shuffles.pop(shuffle_id, None)
             if st is not None:
                 st.removed = True
+                # The live staging round and the device-sealed payload are
+                # released HERE, not at the interpreter's next collection: a
+                # writer or reader handle may keep the state object reachable
+                # long after (a 4 GiB buffer and 4 GiB of HBM a shuffle under
+                # a one-round conf).  ``removed`` is latched first, so a
+                # reader that resolved the state before gets the same clean
+                # refusal as on the shm arm.
+                st.staging = None
                 if st.staging_closer is not None:
-                    st.staging = None
                     st.staging_closer()
+                self._write_stats["released_device_bytes"] += sum(
+                    _device_nbytes(payload) for payload in st.sealed_payload or ()
+                )
+                st.sealed_payload = None
                 self._release_spill(st)
                 self._release_tenant(st, st.tenant_charged)
             for key in [k for k in self._replicas if k[0] == shuffle_id]:
@@ -865,6 +912,13 @@ class HbmBlockStore:
         one row of the ``store`` metrics family."""
         with self._lock:
             return {"executor": self.executor_id, **self._write_stats}
+
+    def count_released_device(self, array) -> None:
+        """Count a device array a removed shuffle lets go of (its received
+        shard here, which the cluster holds) as ``released_device_bytes``."""
+        nbytes = _device_nbytes(array)
+        with self._lock:
+            self._write_stats["released_device_bytes"] += nbytes
 
     def _rollover(self, st: _ShuffleState) -> None:
         """Snapshot the current staging epoch and start a fresh round
@@ -1151,9 +1205,14 @@ class HbmBlockStore:
             else:
                 payload = st.staging.view(np.int32).reshape(-1, lane)
                 if device_put_here:
-                    import jax
-
-                    payload = jax.device_put(payload, self.device)
+                    # the time the calls hold this thread (the runtime's
+                    # staging of the source), NOT the DMA: the transfer is
+                    # asynchronous and shows as the exchange drain's wait
+                    with span(
+                        "store.seal_put", shuffle_id=shuffle_id,
+                        executor=self.executor_id, bytes=int(payload.nbytes),
+                    ):
+                        payload = self._put_round(payload, st)
             out.append((payload, final_sizes))
             st.sealed_payload = [p for p, _ in out]
         # Replication hook, outside the lock: the sealed rounds are now
@@ -1162,6 +1221,45 @@ class HbmBlockStore:
         if cb is not None:
             cb(shuffle_id)
         return out
+
+    def _put_round(self, payload: np.ndarray, st: _ShuffleState):
+        """The single sealed round onto ``self.device``.  A round of up to
+        ``SEAL_PUT_PIECE_BYTES`` is one ``device_put``, as ever.  A larger
+        one goes in pieces of that size, ``SEAL_PUT_PIECES_IN_FLIGHT`` at a
+        time, into a zeroed device buffer that each update donates back (in
+        place: HBM holds the round once and a few pieces): ONE
+        ``device_put`` of 4 GiB ran at 0.30 GiB/s on a v5e host where 1 GiB
+        and less run at 4.9 (PERF.md section 6, PR 27).  A piece no region's used
+        prefix reaches is not put at all: it is zeros on the host and stays
+        zeros on the device."""
+        import jax
+        import jax.numpy as jnp
+
+        rows, lane = payload.shape
+        piece_rows = SEAL_PUT_PIECE_BYTES // (lane * 4)
+        if rows <= piece_rows:
+            return jax.device_put(payload, self.device)
+        region_rows = st.region_size // st.alignment
+        used_rows = -(-st.region_used // st.alignment)
+        update = _update_rows_fn()
+        buf = jnp.zeros((rows, lane), dtype=jnp.int32, device=self.device)
+        in_flight: deque = deque()
+        for at in range(0, rows, piece_rows):
+            end = min(at + piece_rows, rows)
+            first, last = at // region_rows, (end - 1) // region_rows
+            # used rows of region p lie in [p * region_rows, p * region_rows + used_rows[p])
+            if not any(
+                p * region_rows + int(used_rows[p]) > at for p in range(first, last + 1)
+            ):
+                continue
+            if len(in_flight) == SEAL_PUT_PIECES_IN_FLIGHT:
+                # the host runs ahead of the transfers: unchecked, every piece
+                # would sit in HBM beside the round (3 GiB more at 4 GiB)
+                in_flight.popleft().block_until_ready()
+            piece = jax.device_put(payload[at:end], self.device)
+            buf = update(buf, piece, np.int32(at))
+            in_flight.append(piece)
+        return buf
 
     def num_rounds(self, shuffle_id: int) -> int:
         st = self._state(shuffle_id)
@@ -1414,8 +1512,9 @@ class HbmBlockStore:
         ev = self.eviction
         if ev is not None:
             ev.on_access(shuffle_id, e.round)
-        if st.sealed:
-            payload = st.sealed_payload[e.round]
+        sealed = st.sealed_payload  # one read: remove_shuffle may clear it
+        if sealed is not None:
+            payload = sealed[e.round]
             if not (hasattr(payload, "is_deleted") and payload.is_deleted()):
                 flat = np.asarray(payload).reshape(-1).view(np.uint8)
                 return flat[e.offset : e.offset + e.length].tobytes()

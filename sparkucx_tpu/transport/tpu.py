@@ -168,6 +168,15 @@ class TpuShuffleCluster:
         #: 'memmap'), charged against conf.spill_disk_cap_bytes like the
         #: store's staging spill; the drain worker charges, teardown refunds
         self._recv_spill_bytes = 0  #: guarded by self._lock
+        #: Device-read counters (the ``deviceread`` metrics family), one row
+        #: an executor: plain ints, always on, bumped once a
+        #: ``fetch_blocks_to_device`` call (a reduce task's ``read_device()``),
+        #: never a block.  ``gathers`` is gather dispatches (one a staging
+        #: round the task's blocks lie in), ``rows`` payload rows gathered.
+        self._device_read_stats: List[Dict[str, int]] = [
+            dict.fromkeys(("tasks", "blocks", "rows", "bytes", "gathers", "locate_ns"), 0)
+            for _ in range(self.num_executors)
+        ]  #: guarded by self._lock
         #: Liveness/epoch layer.  Always constructed (it is just bookkeeping);
         #: with elastic.enabled=false nothing ever reports a death through it,
         #: the epoch stays 0, and every code path below is byte-identical to
@@ -202,6 +211,10 @@ class TpuShuffleCluster:
             labelled_counter_provider(
                 "store", "executor", lambda: [t.store.write_stats() for t in self.transports]
             ),
+        )
+        self.metrics.register(
+            "deviceread",
+            labelled_counter_provider("deviceread", "executor", self.device_read_stats),
         )
         self.recorder = FlightRecorder(
             TRACER,
@@ -264,6 +277,12 @@ class TpuShuffleCluster:
                     out["exchange"].append(fn.spec.impl)
         return out
 
+    def device_read_stats(self) -> List[Dict[str, int]]:
+        """The device-read counters, one row an executor (its ``executor`` id
+        in the row) — the ``deviceread`` metrics family."""
+        with self._lock:
+            return [{"executor": e, **row} for e, row in enumerate(self._device_read_stats)]
+
     def metrics_text(self) -> str:
         """The cluster registry's Prometheus exposition (collective-plane
         surfaces; per-executor wire surfaces are each peer's METRICS_PULL)."""
@@ -302,32 +321,44 @@ class TpuShuffleCluster:
         return meta
 
     def remove_shuffle(self, shuffle_id: int) -> None:
-        with self._lock:
-            meta = self._meta.pop(shuffle_id, None)
-        if meta is not None:
-            import os
-
-            meta.recv_shards = None  # drop memmap views before unlinking
-            for path, size in meta.recv_spill_paths:
-                try:
-                    os.unlink(path)
-                    freed = True
-                except FileNotFoundError:
-                    freed = True  # already gone: the bytes are not on disk
-                except OSError:
-                    freed = False  # still on disk: keep it charged
-                if freed:
-                    with self._lock:
-                        self._recv_spill_bytes -= size
+        self.drop_meta(shuffle_id)
         for t in self.transports:
             t.store.remove_shuffle(shuffle_id)
 
     def drop_meta(self, shuffle_id: int) -> None:
-        """Forget cluster-level metadata only — for callers whose resolvers
-        already removed the per-store state (the unregisterShuffle split,
-        CommonUcxShuffleManager.scala:103-106)."""
+        """Forget the cluster-level state of a shuffle and release what it
+        holds — for callers whose resolvers remove the per-store state
+        themselves (the unregisterShuffle split,
+        CommonUcxShuffleManager.scala:103-106).  The received shards are
+        released HERE, not at the interpreter's next collection: the HBM
+        copies (``recv_device``, gigabytes a shuffle under
+        ``keep_device_recv``) and the host or memmap copies are dropped from
+        the meta object, which a reader's closure may keep alive long after;
+        the HBM bytes are counted as ``released_device_bytes`` of the owning
+        executor's ``store`` family."""
         with self._lock:
-            self._meta.pop(shuffle_id, None)
+            meta = self._meta.pop(shuffle_id, None)
+        if meta is None:
+            return
+        import os
+
+        recv_device, meta.recv_device = meta.recv_device, None
+        for rnd in recv_device or ():
+            for t, shard in zip(self.transports, rnd):
+                t.store.count_released_device(shard)
+        del recv_device
+        meta.recv_shards = None  # drop memmap views before unlinking
+        for path, size in meta.recv_spill_paths:
+            try:
+                os.unlink(path)
+                freed = True
+            except FileNotFoundError:
+                freed = True  # already gone: the bytes are not on disk
+            except OSError:
+                freed = False  # still on disk: keep it charged
+            if freed:
+                with self._lock:
+                    self._recv_spill_bytes -= size
 
     def commit_mapper(self, info: MapperInfo) -> None:
         """AM id 2 sink — the cluster is the 'daemon' holding the commit table."""
@@ -1133,7 +1164,17 @@ class TpuShuffleCluster:
         with self._lock:
             fn = self._exchange_cache.get(key)
             if fn is None:
-                fn = build_block_gather(b, r, impl=impl)
+                gather = build_block_gather(b, r, impl=impl)
+
+                # One dispatch a gather: the (3, B) plan goes in whole, as the
+                # host array it is, and is split inside the executable —
+                # uploading it and slicing it on the device first was four
+                # dispatches more, most of a small gather's host time.
+                def block_gather(plan, src):
+                    return gather(plan[0], plan[1], plan[2], src)
+
+                fn = jax.jit(block_gather)  # the executable stays jit_block_gather
+                fn.impl = gather.impl
                 self._exchange_cache[key] = fn
         return fn, b, r
 
@@ -1152,9 +1193,18 @@ class TpuShuffleCluster:
         thread pool (ops/pallas_kernels.py).
 
         Returns ``(packed, entries)``: ``packed`` is a (rows, lane) int32
-        ``jax.Array`` (rows past the packed total are unspecified); ``entries``
-        is (B, 2) int64 — per requested block, its starting ROW in ``packed``
-        and its true byte length.  Requires ``conf.keep_device_recv``.
+        ``jax.Array`` whose row count is the gather's power-of-two bucket, not
+        the blocks' total (a slice to the total would build one executable
+        per distinct total; rows no entry covers are unspecified);
+        ``entries`` is (B, 2) int64 — per requested block, its starting ROW
+        in ``packed`` and its true byte length.  Blocks of one staging round
+        are packed back to back in request order; a later round's blocks
+        start at the next bucket boundary.  Requires ``conf.keep_device_recv``.
+
+        Two spans, once a call: ``read.device.locate`` (block table -> the
+        (3, B) gather plan of every round, host only) and
+        ``fetch.device_gather`` (one dispatch a round, the plan its argument;
+        the gather itself is asynchronous).  Counter family ``deviceread{executor}``, once a call.
         """
         meta = self.meta(shuffle_id)
         if not meta.exchanged:
@@ -1162,12 +1212,26 @@ class TpuShuffleCluster:
         if meta.recv_device is None:
             raise TransportError("device shards not retained (conf.keep_device_recv=false)")
 
+        t0 = time.perf_counter_ns()
+        with span("read.device.locate", shuffle_id=shuffle_id, blocks=len(block_ids)):
+            entries, plans, rows = self._plan_device_fetch(meta, consumer, shuffle_id, block_ids, impl)
+        locate_ns = time.perf_counter_ns() - t0
         with span("fetch.device_gather", shuffle_id=shuffle_id, blocks=len(block_ids)):
-            return self._fetch_blocks_to_device(meta, consumer, shuffle_id, block_ids, impl)
+            packed = self._gather_plans(meta, consumer, plans)
+        with self._lock:
+            counters = self._device_read_stats[consumer]
+            counters["tasks"] += 1
+            counters["blocks"] += len(block_ids)
+            counters["rows"] += rows
+            counters["bytes"] += int(entries[:, 1].sum())
+            counters["gathers"] += len(plans)
+            counters["locate_ns"] += locate_ns
+        return packed, entries
 
-    def _fetch_blocks_to_device(self, meta, consumer, shuffle_id, block_ids, impl):
-        import jax.numpy as jnp
-
+    def _plan_device_fetch(self, meta, consumer, shuffle_id, block_ids, impl):
+        """Host half of a device fetch: every block located in ``consumer``'s
+        received shards, and one gather plan a staging round — ``(round, fn,
+        (3, B) starts/counts/outs)``.  Returns (entries, plans, payload rows)."""
         located = []  # (round, src_row, rows) per request
         for bid in block_ids:
             if bid.shuffle_id != shuffle_id:
@@ -1175,9 +1239,8 @@ class TpuShuffleCluster:
             located.append(self._locate_rows(meta, consumer, bid.map_id, bid.reduce_id))
 
         entries = np.zeros((len(located), 2), dtype=np.int64)
-        lane = self.row_bytes // 4
-        segments = []
-        base = 0
+        plans = []
+        base = rows = 0
         for rnd in sorted({r for r, _, c in located if c}):
             idxs = [i for i, (r, _, c) in enumerate(located) if r == rnd and c]
             starts = np.asarray([located[i][1] for i in idxs], dtype=np.int32)
@@ -1187,7 +1250,7 @@ class TpuShuffleCluster:
             for i, o in zip(idxs, outs):
                 bid = block_ids[i]
                 entries[i] = (base + int(o), meta.mapper_infos[bid.map_id].partitions[bid.reduce_id][1])
-            fn, b_pad, _ = self._gather_fn(impl, len(idxs), total)
+            fn, b_pad, r_pad = self._gather_fn(impl, len(idxs), total)
             pad = b_pad - len(idxs)
             if pad:
                 starts = np.pad(starts, (0, pad))
@@ -1196,18 +1259,28 @@ class TpuShuffleCluster:
                 # the xla lowering's searchsorted needs outs+counts non-
                 # decreasing; the Pallas lowerings skip zero-count blocks.
                 outs = np.pad(outs, (0, pad), constant_values=total)
-            src = meta.recv_device[rnd][consumer]
-            dev = src.device
-            # One (3, B) H2D upload for the whole gather plan instead of three
-            # tiny per-array transfers; split back on device (views, no copy).
-            plan = jax.device_put(np.stack([starts, counts, outs]), dev)
-            packed = fn(plan[0], plan[1], plan[2], src)
-            segments.append(packed[:total])
-            base += total
+            plans.append((rnd, fn, np.stack([starts, counts, outs])))
+            # the next round's blocks start where this round's bucket ends:
+            # the segments are concatenated whole, never sliced to ``total``
+            base += r_pad
+            rows += total
+        return entries, plans, rows
+
+    def _gather_plans(self, meta, consumer, plans):
+        """Device half: one gather dispatch a round, its (3, B) plan an
+        argument of the call.  Every shape here is a power-of-two bucket, so
+        tasks of different totals share their executables."""
+        import jax.numpy as jnp
+
+        segments = []
+        for rnd, fn, plan in plans:
+            segments.append(fn(plan, meta.recv_device[rnd][consumer]))
         if not segments:
-            return jnp.zeros((0, lane), dtype=jnp.int32), entries
-        packed_all = segments[0] if len(segments) == 1 else jnp.concatenate(segments, axis=0)
-        return packed_all, entries
+            return jnp.zeros(
+                (0, self.row_bytes // 4), dtype=jnp.int32,
+                device=self.transports[consumer].device,
+            )
+        return segments[0] if len(segments) == 1 else jnp.concatenate(segments, axis=0)
 
 
 class TpuShuffleTransport(ShuffleTransport):
@@ -1337,15 +1410,21 @@ class TpuShuffleTransport(ShuffleTransport):
         return requests
 
     def fetch_blocks_device(
-        self, block_ids: Sequence[ShuffleBlockId], impl: Optional[str] = None
+        self,
+        block_ids: Sequence[ShuffleBlockId],
+        impl: Optional[str] = None,
+        shuffle_id: Optional[int] = None,
     ) -> Tuple[object, np.ndarray]:
         """Device-resident batch fetch: pack these blocks into one HBM buffer on
         this executor's device (see ``TpuShuffleCluster.fetch_blocks_to_device``).
-        All blocks must be from one shuffle."""
-        if not block_ids:
-            raise ValueError("no block ids")
-        sid = block_ids[0].shuffle_id
-        return self.cluster.fetch_blocks_to_device(self.executor_id, sid, block_ids, impl=impl)
+        All blocks must be from one shuffle; ``shuffle_id`` names it for a
+        request that may be empty (``TpuShuffleReader.read_device`` on a
+        reducer no mapper wrote to)."""
+        if shuffle_id is None:
+            if not block_ids:
+                raise ValueError("no block ids")
+            shuffle_id = block_ids[0].shuffle_id
+        return self.cluster.fetch_blocks_to_device(self.executor_id, shuffle_id, block_ids, impl=impl)
 
     def progress(self) -> None:
         """Poll outstanding async work (non-blocking).  Post-exchange fetches

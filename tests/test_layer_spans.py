@@ -320,11 +320,21 @@ def test_recording_alone_pays_nothing_per_block(tracer, blocks):
     cluster = TpuShuffleCluster(conf, num_executors=2)
     assert tracer.recording and not tracer.enabled
     tracer.clear()
-    run_shuffle(cluster, 0, mappers=blocks // 2, reducers=2, block_bytes=500)
-    names = collections.Counter(e["name"] for e in spans(tracer))
+    # The ring is the process's own: an earlier test's cluster may still run
+    # threads that write to it.  Count this shuffle's events — those that name
+    # it, and those under its superstep's trace.
+    sid = 7000 + blocks
+    run_shuffle(cluster, sid, mappers=blocks // 2, reducers=2, block_bytes=500)
+    [superstep] = [e for e in spans(tracer, "exchange.superstep") if e["args"]["shuffle_id"] == sid]
+    names = collections.Counter(
+        e["name"] for e in spans(tracer)
+        if e["trace_id"] == superstep["trace_id"] or e.get("args", {}).get("shuffle_id") == sid
+    )
     submits = names["exchange.pipeline.submit"]
     assert submits == 1  # one round, one chunk
     assert names["exchange.assemble"] == names["exchange.h2d"] == names["exchange.collective"] == submits
+    # a one-round job is put on the chip at its seal: once an executor, not a block
+    assert names.pop("store.seal_put") == 2
     assert not [n for n in names if n.startswith(("store.", "daemon."))]
     # the same events whatever the number of blocks
     assert sum(names.values()) == sum(1 for _ in names), names
@@ -341,6 +351,97 @@ def test_recording_alone_pays_nothing_per_frame(daemon, tracer):
     second = collections.Counter(e["name"] for e in spans(tracer))
     assert first == second and max(first.values()) == 1  # one of each, none per frame
     assert first["exchange.assemble"] == first["exchange.h2d"] == 1
+
+
+# -- the device read and the single-round seal -------------------------------
+
+READ_CHILDREN = ("read.device.locate", "fetch.device_gather")
+
+
+def device_job(manager, shuffle_id, mappers, reducers, block_bytes=900):
+    manager.register_shuffle(shuffle_id, mappers, reducers)
+    for m in range(mappers):
+        writer = manager.get_writer(shuffle_id, m)
+        for r in range(reducers):
+            with writer.get_partition_writer(r).open_stream() as stream:
+                stream.write(bytes([(m * reducers + r) % 251]) * block_bytes)
+        writer.commit_all_partitions()
+    manager.run_exchange(shuffle_id)
+
+
+@pytest.fixture
+def device_manager():
+    from sparkucx_tpu.shuffle.manager import TpuShuffleManager
+
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=2,
+                          keep_device_recv=True, host_recv_mode="device")
+    with TpuShuffleManager(conf, num_executors=2) as manager:
+        yield manager
+
+
+def test_device_read_spans_nest_once_a_task(device_manager, tracer):
+    mappers, reducers = 5, 6
+    device_job(device_manager, 0, mappers, reducers)
+    tracer.enable()
+    for r in range(reducers):
+        device_manager.get_reader(0, r, r + 1).read_device()
+    tracer.disable()
+    tasks = spans(tracer, "read.device")
+    assert sorted(e["args"]["reduce_id"] for e in tasks) == list(range(reducers))  # once a task
+    children = spans(tracer, *READ_CHILDREN)
+    assert len(children) == 2 * reducers  # none outside a task, none per block
+    meta = device_manager.cluster.meta(0)
+    for task in tasks:
+        assert set(task["args"]) == {"shuffle_id", "reduce_id", "blocks", "rows"}
+        assert task["args"]["blocks"] == mappers and task["args"]["rows"] == mappers * -(-900 // 128)
+        assert task["eid"] == meta.owner_of_reduce(task["args"]["reduce_id"])
+        mine = sorted((c for c in children if c["parent_id"] == task["span_id"]), key=lambda c: c["ts"])
+        assert [c["name"] for c in mine] == list(READ_CHILDREN)  # the plan, then its upload and the dispatch
+        assert all(inside(c, task) for c in mine) and mine[0]["ts"] + mine[0]["dur"] <= mine[1]["ts"]
+        assert all(c["args"] == {"shuffle_id": 0, "blocks": mappers} for c in mine)
+
+
+@pytest.mark.parametrize("blocks", [4, 48])
+def test_recording_alone_a_device_read_pays_nothing_per_block(device_manager, tracer, blocks):
+    """Three spans a task under the flight recorder, whatever the blocks."""
+    assert tracer.recording and not tracer.enabled
+    sid = 7100 + blocks
+    device_job(device_manager, sid, mappers=blocks, reducers=2, block_bytes=300)
+    tracer.clear()
+    device_manager.get_reader(sid, 0, 1).read_device()
+    names = collections.Counter(
+        e["name"] for e in spans(tracer) if e.get("args", {}).get("shuffle_id") == sid
+    )
+    assert names == {"read.device": 1, "read.device.locate": 1, "fetch.device_gather": 1}
+
+
+def test_seal_put_is_inside_the_seal_once_an_executor(device_manager, tracer):
+    tracer.enable()
+    device_job(device_manager, 0, mappers=4, reducers=4)
+    tracer.disable()
+    [seal] = spans(tracer, "exchange.seal")
+    puts = spans(tracer, "store.seal_put")
+    assert sorted(e["args"]["executor"] for e in puts) == [0, 1]  # one round: one put an executor
+    for put in puts:
+        assert put["parent_id"] == seal["span_id"] and inside(put, seal)
+        assert put["args"] == {"shuffle_id": 0, "executor": put["args"]["executor"], "bytes": 1 << 20}
+
+
+def test_no_seal_put_span_when_rounds_seal_on_the_host(multi_round, tracer):
+    """Several rounds stay host-resident at the seal; the exchange puts them,
+    a round at a time (``exchange.h2d``)."""
+    assert spans(tracer, "exchange.seal") and not spans(tracer, "store.seal_put")
+
+
+def test_released_device_bytes_joins_the_store_family(device_manager):
+    device_job(device_manager, 0, mappers=4, reducers=4)
+    cluster = device_manager.cluster
+    held = {e: sum(int(rnd[e].nbytes) for rnd in cluster.meta(0).recv_device) for e in (0, 1)}
+    assert all(family(cluster.metrics_text(), "store")[("released_device_bytes_total", str(e))] == 0 for e in held)
+    device_manager.unregister_shuffle(0)
+    rows = family(cluster.metrics_text(), "store")
+    for e, nbytes in held.items():
+        assert rows[("released_device_bytes_total", str(e))] >= nbytes > 0
 
 
 # -- names on the device ----------------------------------------------------
