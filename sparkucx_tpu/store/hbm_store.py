@@ -305,6 +305,11 @@ class MapWriter:
             # (retryable ResourceExhaustedError) with nothing allocated
             self._store.check_memory_pressure("close_partition", padded)
             with self._store._lock:
+                if st.sealed:
+                    # a writer opened before the seal: the sealed rounds are
+                    # immutable (zero-copy views, the runtime's H2D source),
+                    # and a rollover here would zero the buffer they alias
+                    raise TransportError(f"shuffle {st.shuffle_id} already sealed")
                 if st.device_mode:
                     raise TransportError(
                         f"shuffle {st.shuffle_id} already has device-staged rounds — "
@@ -617,7 +622,9 @@ class HbmBlockStore:
         #: Map-side write counters (the ``store`` metrics family): plain ints,
         #: always on, bumped once a map task at its commit (``staged_*`` from
         #: its block table, ``copy_ns`` from the clock round each block's copy)
-        #: and once a staging round (``rollovers``, ``spilled_bytes``, ``*_ns``).
+        #: and once a staging round (``rollovers``, ``spilled_bytes``, ``*_ns``;
+        #: ``recycled_rounds``: host rollovers that kept their buffer, and
+        #: ``zeroed_bytes``: what they set back to zero in it).
         #: ``rollover_ns`` includes the ``spill_ns`` of the round it spilled;
         #: ``spill_ns`` also counts the eviction manager's demotions.
         #: ``released_device_bytes``: HBM a removed shuffle gave back at its
@@ -626,7 +633,8 @@ class HbmBlockStore:
         #: guarded by self._lock
         self._write_stats: Dict[str, int] = dict.fromkeys(
             ("staged_blocks", "staged_bytes", "rollovers", "spilled_bytes",
-             "rollover_ns", "spill_ns", "copy_ns", "released_device_bytes"), 0
+             "rollover_ns", "spill_ns", "copy_ns", "released_device_bytes",
+             "recycled_rounds", "zeroed_bytes"), 0
         )
         #: Optional TenantRegistry (service/tenants.py).  When set, shuffles
         #: created with an ``app_id`` are admission-checked: region
@@ -921,26 +929,41 @@ class HbmBlockStore:
             self._write_stats["released_device_bytes"] += nbytes
 
     def _rollover(self, st: _ShuffleState) -> None:
-        """Snapshot the current staging epoch and start a fresh round
+        """Snapshot the current staging epoch and start the next round
         (caller holds self._lock).
 
         With ``conf.spill_to_disk`` (default) the completed round moves to an
-        ``np.memmap`` file and its RAM is released — the capacity-beyond-memory
-        tier the reference gets from DPU-attached NVMe (NvkvHandler.scala:
-        160-242); ``read_block``/``block_staging_view``/``seal`` serve spilled
-        rounds through the memmap transparently.  With it off, the round stays
-        as a RAM snapshot (bounded by host memory).
+        ``np.memmap`` file — the capacity-beyond-memory tier the reference
+        gets from DPU-attached NVMe (NvkvHandler.scala:160-242);
+        ``read_block``/``block_staging_view``/``seal`` serve spilled rounds
+        through the memmap transparently — and the RAM buffer STAYS as the
+        next round's staging: every used byte of it is in the memmap, so each
+        region's used prefix is set back to zero on pages that are resident
+        (``recycled_rounds``, ``zeroed_bytes``) and nothing is allocated, as
+        the reference recycles its registered buffers (MemoryPool.scala:
+        117-138).  What every consumer relies on holds exactly: a round starts
+        as all zeros, so rows past a region's used count and the pad bytes of
+        a block are zeros in whatever leaves the host.  With the disk tier
+        off the round stays as a RAM snapshot (bounded by host memory) and the
+        next round takes a new buffer of untouched zero pages (``np.zeros``:
+        calloc, no second pass over them).
 
         Span ``store.rollover`` (once a staging round); its child
-        ``store.spill`` is the disk tier, so its self time is the fresh
-        staging buffer and the bookkeeping."""
+        ``store.spill`` is the disk tier, so its self time is the zeroing of
+        the used prefixes and the bookkeeping."""
         with self._rollover_span(st):
-            snap = st.staging
+            staging, used = st.staging, st.region_used
             if self.conf.spill_to_disk:
-                snap = self._spill_round(st, snap)
-            st.prev_rounds.append((snap, st.region_used))
-            st.staging = np.zeros_like(st.staging)
-            st.region_used = np.zeros_like(st.region_used)
+                st.prev_rounds.append((self._spill_round(st, staging), used))
+                for p in np.flatnonzero(used):
+                    start = int(p) * st.region_size
+                    staging[start : start + int(used[p])] = 0
+                self._write_stats["recycled_rounds"] += 1
+                self._write_stats["zeroed_bytes"] += int(used.sum())
+            else:
+                st.prev_rounds.append((staging, used))
+                st.staging = np.zeros(staging.shape, staging.dtype)
+            st.region_used = np.zeros(len(used), dtype=used.dtype)
             st.round += 1
 
     @contextmanager
@@ -1543,12 +1566,22 @@ class HbmBlockStore:
     def block_staging_view(
         self, shuffle_id: int, map_id: int, reduce_id: int
     ) -> Optional[Tuple[np.ndarray, int, int]]:
-        """Zero-copy serving handle: (host staging uint8 array, offset, length)
-        for a staged block, or None when unknown.  Staging is append-only and
-        retained until ``remove_shuffle`` (it is the shuffle's backing store),
-        so the view stays valid for the shuffle's lifetime even after the seal
-        donated the device copy — this is what the batch reply's native gather
-        (``ts_batch_copy``) reads from."""
+        """Serving handle: (host staging uint8 array, offset, length) for a
+        staged block, or None when unknown — what the batch reply's native
+        gather (``ts_batch_copy``) and the vectored reply read from.
+
+        Zero-copy wherever the bytes can no longer change: a COMPLETED round
+        (its RAM snapshot or memmap is never written again) and the live
+        round of a SEALED shuffle (no writer can be opened and
+        ``close_partition`` refuses one opened before the seal, so nothing
+        rolls or appends; staging is retained until ``remove_shuffle`` as the
+        shuffle's backing store, and a holder of the view keeps the array
+        alive past that).  The live round of a shuffle that is NOT yet sealed
+        is handed out as a private copy taken under the store lock: a
+        rollover keeps the buffer and zeroes it for the next round
+        (``_rollover``), so a view into it would read the next round's bytes.
+        shm-backed staging is always a private copy (``remove_shuffle`` may
+        munmap it once the lock is released)."""
         st = self._state(shuffle_id)
         e = st.blocks.get((map_id, reduce_id))
         if e is None:
@@ -1557,7 +1590,8 @@ class HbmBlockStore:
         if ev is not None:
             ev.on_access(shuffle_id, e.round)
         with self._lock:
-            if e.round >= len(st.prev_rounds) and st.device_mode:
+            live = e.round >= len(st.prev_rounds)
+            if live and st.device_mode:
                 rows = st.device_blocks.get((map_id, reduce_id))
                 if rows is None:
                     return None
@@ -1565,16 +1599,10 @@ class HbmBlockStore:
                 # block (the device array can be superseded by a rollover).
                 flat = np.array(np.asarray(rows).reshape(-1).view(np.uint8)[: e.length])
                 return flat, 0, e.length
-            staging = (
-                st.prev_rounds[e.round][0] if e.round < len(st.prev_rounds) else st.staging
-            )
+            staging = st.staging if live else st.prev_rounds[e.round][0]
             if staging is None:
                 return None
-            if st.staging_closer is not None:
-                # shm-backed staging can be munmapped by remove_shuffle at any
-                # time after we release the lock — hand out a private copy, not
-                # a view into the mapping (private ndarray staging is safe: a
-                # rollover replaces the reference, never the array contents).
+            if st.staging_closer is not None or (live and not st.sealed):
                 return np.array(staging[e.offset : e.offset + e.length]), 0, e.length
         return staging, e.offset, e.length
 

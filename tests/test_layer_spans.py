@@ -107,6 +107,10 @@ def test_no_spill_span_without_the_disk_tier(tracer):
     assert spans(tracer, "store.rollover") and not spans(tracer, "store.spill")
     stats = cluster.transports[0].store.write_stats()
     assert stats["rollovers"] >= 1 and stats["spilled_bytes"] == 0 and stats["spill_ns"] == 0
+    # a round that stays in RAM is its own buffer: nothing recycled, nothing zeroed
+    assert stats["recycled_rounds"] == 0 and stats["zeroed_bytes"] == 0
+    rows = family(cluster.metrics_text(), "store")
+    assert rows[("recycled_rounds_total", "0")] == 0 and rows[("zeroed_bytes_total", "0")] == 0
 
 
 def test_submit_lane_children_nest_and_cover_the_parent(multi_round, tracer):
@@ -147,6 +151,9 @@ def test_store_family_counts_what_was_written(multi_round):
     for e in executors:
         stats = cluster.transports[int(e)].store.write_stats()
         assert rows[("rollovers_total", e)] == stats["rollovers"] >= 1
+        # the default disk tier: every rollover kept its buffer and zeroed what it spilled
+        assert rows[("recycled_rounds_total", e)] == stats["recycled_rounds"] == stats["rollovers"]
+        assert rows[("zeroed_bytes_total", e)] == stats["zeroed_bytes"] == stats["spilled_bytes"]
         assert rows[("spilled_bytes_total", e)] == stats["spilled_bytes"] > 0
         # the spill is inside the rollover; the copies were timed
         assert 0 < rows[("spill_ns_total", e)] <= rows[("rollover_ns_total", e)]
@@ -206,7 +213,7 @@ def test_concurrent_writers_lose_no_count():
     assert not errors and not any(t.is_alive() for t in threads)
     stats = store.write_stats()
     assert stats["staged_blocks"] == writers * blocks and stats["staged_bytes"] == writers * blocks * size
-    assert stats["rollovers"] == store.num_rounds(0) - 1 >= 1
+    assert stats["rollovers"] == stats["recycled_rounds"] == store.num_rounds(0) - 1 >= 1
     assert stats["spilled_bytes"] > 0 and 0 < stats["spill_ns"] <= stats["rollover_ns"] and stats["copy_ns"] > 0
 
 

@@ -257,6 +257,139 @@ class TestDiskSpillTier:
         assert leftovers == []
 
 
+class TestRolloverKeepsItsBuffer:
+    """At a host-staged rollover with the disk tier on, the spilled round's
+    RAM buffer is the next round's staging, its used prefixes set back to
+    zero; with the tier off the round IS the RAM snapshot and the next round
+    takes a new buffer.  Either way a round starts as all zeros."""
+
+    REGION = 8192
+    #: share of each region a round fills: falling, so a later round leaves
+    #: bytes of an earlier one under rows it never writes unless they were zeroed
+    FILLS = (0.97, 0.88, 0.78, 0.68, 0.58)
+
+    def _store(self, tmp_path, spill, regions):
+        return HbmBlockStore(
+            TpuShuffleConf(
+                staging_capacity_per_executor=regions * self.REGION,
+                block_alignment=ALIGN,
+                spill_to_disk=spill,
+                spill_dir=str(tmp_path),
+            )
+        )
+
+    def _drive(self, s, regions):
+        """Two map tasks a round, one block a region each: the first block of
+        a round (just over half a region) cannot fit what the round before
+        left free, so it rolls.  Returns {round: expected payload} built from
+        the commit tables alone (zeros, then each block's bytes at its offset)
+        and the (round, live staging buffer) seen after every map task."""
+        rng = np.random.default_rng(28)
+        s.create_shuffle(0, 2 * len(self.FILLS), regions, peer_ranges=default_peer_ranges(regions, regions))
+        expected = {k: np.zeros(regions * self.REGION, dtype=np.uint8) for k in range(len(self.FILLS))}
+        live = []
+        for k, fill in enumerate(self.FILLS):
+            for half in range(2):
+                m = 2 * k + half
+                w = s.map_writer(0, m)
+                blocks = []
+                for p in range(regions):
+                    first = self.REGION // 2 + 1 + 2 * int(rng.integers(0, 40))  # odd: never a multiple of ALIGN
+                    length = first if half == 0 else int(fill * self.REGION) - first - 2 * int(rng.integers(0, 40))
+                    assert length % ALIGN
+                    blocks.append(rng.integers(1, 256, size=length, dtype=np.uint8).tobytes())
+                    w.write_partition(p, blocks[-1])
+                info = w.commit()
+                for p, data in enumerate(blocks):
+                    off, ln = info.partitions[p]
+                    assert ln == len(data) and info.round_of(p) == k
+                    expected[k][off : off + ln] = np.frombuffer(data, dtype=np.uint8)
+                live.append((k, s._state(0).staging))
+        return expected, live
+
+    @pytest.mark.parametrize("regions", [1, 4])
+    @pytest.mark.parametrize("spill", [True, False], ids=["disk-tier", "ram-snapshots"])
+    def test_every_round_is_zeros_but_for_its_blocks(self, tmp_path, spill, regions):
+        s = self._store(tmp_path, spill, regions)
+        expected, live = self._drive(s, regions)
+        st = s._state(0)
+        rollovers = len(self.FILLS) - 1
+        assert s.num_rounds(0) == rollovers + 1 >= 5
+        useds = [used for _, used in st.prev_rounds] + [st.region_used]
+        # the point of the traffic: every region of a later round is used less
+        assert all((b < a).all() for a, b in zip(useds, useds[1:]))
+        stats = s.write_stats()
+        assert stats["rollovers"] == rollovers
+        first = live[0][1]
+        if spill:
+            assert all(np.shares_memory(buf, first) for _, buf in live)
+            assert all(isinstance(p, np.memmap) for p, _ in st.prev_rounds)
+            assert stats["recycled_rounds"] == rollovers
+            assert stats["zeroed_bytes"] == stats["spilled_bytes"] == sum(int(u.sum()) for u in useds[:-1])
+        else:
+            assert stats["recycled_rounds"] == stats["zeroed_bytes"] == 0
+            buffers = [p for p, _ in st.prev_rounds] + [st.staging]
+            assert not any(
+                np.shares_memory(a, b) for i, a in enumerate(buffers) for b in buffers[i + 1 :]
+            )
+            # and each was the live buffer of its own round only
+            assert all(np.shares_memory(buf, buffers[k]) for k, buf in live)
+        sealed = s.seal(0)
+        assert len(sealed) == rollovers + 1
+        for k, (payload, sizes) in enumerate(sealed):
+            flat = np.asarray(payload).reshape(-1).view(np.uint8)
+            assert flat.size == expected[k].size
+            # no stale byte past a used count or in a block's pad
+            assert np.array_equal(flat, expected[k]), f"round {k}"
+            assert (sizes.astype(np.int64) * ALIGN == useds[k]).all()
+        s.close()
+
+    def test_a_view_of_the_unsealed_live_round_is_a_private_copy(self, tmp_path):
+        s = self._store(tmp_path, True, 1)
+        s.create_shuffle(0, 4, 1)
+        first = b"a" * 1001
+        w = s.map_writer(0, 0)
+        w.write_partition(0, first)
+        w.commit()
+        buf = s._state(0).staging
+        arr, off, ln = s.block_staging_view(0, 0, 0)
+        assert not np.shares_memory(arr, buf)
+        assert bytes(arr[off : off + ln]) == first
+        # the rollover keeps the buffer, zeroes it, and the next round's first
+        # block lands on the same bytes
+        w = s.map_writer(0, 1)
+        w.write_partition(0, b"b" * (self.REGION - 100))
+        w.commit()
+        assert s.num_rounds(0) == 2 and np.shares_memory(s._state(0).staging, buf)
+        assert bytes(buf[:1001]) == b"b" * 1001
+        assert bytes(arr[off : off + ln]) == first
+        assert s.read_block(0, 0, 0) == first
+        s.seal(0)
+        # sealed: nothing can roll or append, so the live round is zero-copy
+        arr, off, ln = s.block_staging_view(0, 1, 0)
+        assert np.shares_memory(arr, buf) and bytes(arr[off : off + ln]) == b"b" * ln
+        # and a completed round is served from its memmap, as before
+        arr, off, ln = s.block_staging_view(0, 0, 0)
+        assert isinstance(arr, np.memmap) and bytes(arr[off : off + ln]) == first
+        s.close()
+
+    @pytest.mark.parametrize("length", [100, REGION - 100], ids=["fits", "would-roll"])
+    def test_a_writer_opened_before_the_seal_is_refused_after_it(self, tmp_path, length):
+        s = self._store(tmp_path, True, 1)
+        s.create_shuffle(0, 2, 1)
+        w = s.map_writer(0, 0)
+        w.write_partition(0, b"a" * 1001)
+        w.commit()
+        late = s.map_writer(0, 1)
+        (payload, _sizes), = s.seal(0)
+        before = np.array(payload)
+        with pytest.raises(TransportError, match="sealed"):
+            late.write_partition(0, b"z" * length)
+        assert s.num_rounds(0) == 1 and s.write_stats()["rollovers"] == 0
+        assert np.array_equal(np.asarray(payload), before)
+        s.close()
+
+
 class TestAlignmentAndLayout:
     def test_blocks_aligned(self, store):
         store.create_shuffle(0, 2, 2, peer_ranges=default_peer_ranges(2, 1))
