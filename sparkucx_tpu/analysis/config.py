@@ -103,8 +103,8 @@ ALLOWLIST = {
     ("store/hbm_store.py", "cache-hygiene", "'out_rows'"),
 }
 
-#: Public-surface contract: these classes must keep these methods.  Transports,
-#: writers, and the perf harness are wired to them by name across layers, and
+#: Public-surface contract: these classes must keep these methods.  Transports
+#: and writers are wired to them by name across layers, and
 #: the device-staging path (ISSUE 2) made several of them load-bearing surface
 #: — a rename here fails the analyzer before it fails at runtime in another
 #: layer.  (Migrated from scripts/lint_private_access.py.)

@@ -93,8 +93,7 @@ class TpuShuffleConf:
     wire_streams: int = 1
     #: Chunk frame payload size for striped replies.  Smaller chunks spread
     #: a single hot reply across lanes sooner; larger chunks cut per-frame
-    #: syscall + header overhead.  4 MiB is the measured knee on loopback
-    #: (1 MiB loses ~15% to per-frame overhead; see docs/PERF.md).
+    #: syscall + header overhead.
     wire_chunk_bytes: int = 4 << 20
     #: Reduce-side fetch credit budget in bytes: the reader keeps issuing
     #: fetch windows while their expected reply bytes fit the budget, so many

@@ -98,13 +98,6 @@ class CombineSpec:
         """Total lanes of one exchange row: key + payload + count."""
         return 1 + self.payload_width + 1
 
-    @property
-    def acc_bytes(self) -> int:
-        """Accumulator bytes per device — the O(groups) quantity that
-        replaces the O(rows) recv staging (also mirrored host-side by
-        ``PlanContext.combine_acc_bytes`` for the planner)."""
-        return self.num_groups * (self.width * np.dtype(self.dtype).itemsize + 4)
-
     def validate(self) -> None:
         if self.num_groups <= 0:
             raise ValueError("num_groups must be positive")

@@ -220,8 +220,7 @@ def simulate_ring(schedule: RingSchedule):
 
 
 def step_occupancy(schedule: RingSchedule) -> List[Tuple[int, int]]:
-    """Per-superstep (used, idle) link-direction slots per device — the
-    span telemetry the 'ici' benchmark mode records via StatsAggregator."""
+    """Per-superstep (used, idle) link-direction slots per device."""
     return [(len(step), 2 - len(step)) for step in schedule.steps]
 
 

@@ -88,9 +88,9 @@ class ExchangePlan:
       ``hedge_ms`` — the serve/wire-plane tiers chosen for this shuffle's
       traffic (fetch striping, page codec, lossy aggregation quantization,
       hedged-fetch delay).  The collective executor never quantizes shuffle
-      bytes (payloads are exact); these fields parameterize the fetch path,
-      the aggregation plane, and the bench harness, and land in the per-
-      shuffle ``exchange.plan`` trace event.
+      bytes (payloads are exact); these fields parameterize the fetch path
+      and the aggregation plane, and land in the per-shuffle
+      ``exchange.plan`` trace event.
     * ``combine`` — the receive-side compute-in-exchange tier for partial
       grouped aggregations (``'off' | 'dense' | 'sorted'``).  ``dense`` folds
       every landed window into a fixed per-group accumulator inside the

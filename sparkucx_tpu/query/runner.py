@@ -99,9 +99,6 @@ class QueryRunner:
             "stale_invalidations": 0,
         }
         self._counters_lock = threading.Lock()
-        #: optional observer fn(stage_name, op, ms) — the perf harness taps
-        #: per-stage latency here without scraping the trace plane
-        self.on_stage = None
         metrics = getattr(manager.cluster, "metrics", None)
         if metrics is not None:
             metrics.register(f"query:{app_id}", counter_dict_provider("query", self._snapshot))
@@ -152,8 +149,6 @@ class QueryRunner:
                 self._bump("stages")
                 ms = (time.perf_counter() - t0) * 1e3
                 instant("query.stage", app=self.app_id, stage=st.name, op=st.op, ms=ms)
-                if self.on_stage is not None:
-                    self.on_stage(st.name, st.op, ms)
         finally:
             for sid in ephemeral:
                 self.manager.unregister_shuffle(sid)

@@ -6,7 +6,7 @@ out-of-repo daemon: the plugin (``spark.shuffle.manager`` =
 (CommonUcxShuffleManager.scala:84-89, Definitions.scala:22-29).  This module is
 that daemon, TPU-side: a standalone process hosting a ``TpuShuffleManager`` and
 serving a framed protocol any host engine can speak — the JVM shim under
-``jvm/`` (the ``spark.shuffle.manager`` entry point), the benchmark CLI, or
+``jvm/`` (the ``spark.shuffle.manager`` entry point), ``benchmark/run.py``, or
 tests.
 
 Protocol: the data-plane messages are exactly AM ids 0-4 (handshake, commit,

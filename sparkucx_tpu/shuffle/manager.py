@@ -2,7 +2,7 @@
 
 Counterpart of ``UcxShuffleManager`` + ``CommonUcxShuffleManager``
 (compat/spark_3_0/UcxShuffleManager.scala:25-80, CommonUcxShuffleManager.scala:37-124):
-the single object a host engine (Spark via the JVM shim, or the benchmark CLI)
+the single object a host engine (Spark via the JVM shim, or ``benchmark/run.py``)
 instantiates to run shuffles.  API mirrors Spark's ``ShuffleManager`` SPI —
 ``register_shuffle`` / ``get_writer`` / ``get_reader`` / ``unregister_shuffle`` /
 ``stop`` — with the fork's staged-store components wired in the same places:

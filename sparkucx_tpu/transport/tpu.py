@@ -184,8 +184,8 @@ class TpuShuffleCluster:
         self.membership = ClusterMembership(
             range(self.num_executors), self.conf.membership_suspect_after_ms
         )
-        #: degraded-mode recovery telemetry (perf/benchmark.py `elastic` mode
-        #: and the chaos tests read this)
+        #: degraded-mode recovery telemetry (the chaos tests and the metrics
+        #: registry read this)
         self.elastic_stats = {
             "recoveries": 0,
             "last_recovery_ms": 0.0,
@@ -267,7 +267,8 @@ class TpuShuffleCluster:
     def executed_lowerings(self) -> Dict[str, List[str]]:
         """The lowering of every executable this cluster has compiled, by
         kind — what ran (``fn.spec.impl`` for exchanges, ``fn.impl`` for block
-        gathers), not what the conf asked for.  The chip smoke asserts on it."""
+        gathers), not what the conf asked for.  ``benchmark/`` holds a run's
+        ``correct`` to it."""
         out: Dict[str, List[str]] = {"exchange": [], "gather": []}
         with self._lock:
             for key, fn in self._exchange_cache.items():

@@ -1,8 +1,8 @@
 """Persistent XLA compilation cache placement for process entry points.
 
-Called from ``main``-level code only (``chip_smoke.py``, ``bench.py``, the
-daemon and benchmark CLIs, the smoke script) — never at library import, so an
-embedding application keeps its own cache policy.
+Called from ``main``-level code only (the daemon CLI and
+``scripts/tpu_smoke.py``) — never at library import, so an embedding
+application keeps its own cache policy.
 
 The directory is part of every cache key, so it must not move between runs:
 ``JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself; nothing here

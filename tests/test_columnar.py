@@ -51,16 +51,35 @@ def _oracle(rows, owners, n, cap):
 
 
 class TestColumnarShuffle:
-    def test_random_vs_oracle(self, mesh, fn, rng):
-        rows = rng.normal(size=(N * CAP, W)).astype(np.float32)
-        owners = rng.integers(0, N, size=N * CAP).astype(np.int32)
+    @pytest.mark.parametrize(
+        "n, cap, width, recv_cap, impl",
+        [
+            (N, CAP, W, N * CAP, "dense"),
+            # 4,096 rows of 128 B over four executors, 2x balanced headroom,
+            # the lowering left to the platform
+            (4, 1024, 32, 2048, "auto"),
+        ],
+        ids=["512_rows_of_64B_over_8", "4096_rows_of_128B_over_4"],
+    )
+    def test_random_vs_oracle(self, rng, n, cap, width, recv_cap, impl):
+        mesh = make_mesh(n)
+        fn = build_columnar_shuffle(
+            mesh,
+            ColumnarSpec(
+                num_executors=n, capacity=cap, recv_capacity=recv_cap, width=width, impl=impl
+            ),
+        )
+        assert fn.spec.impl == "dense"
+        rows = rng.normal(size=(n * cap, width)).astype(np.float32)
+        owners = rng.integers(0, n, size=n * cap).astype(np.int32)
         recv, counts = fn(*_place(mesh, rows, owners))
         recv, counts = np.asarray(recv), np.asarray(counts)
-        expected = _oracle(rows, owners, N, CAP)
-        for j in range(N):
+        assert int(counts.sum()) == n * cap
+        expected = _oracle(rows, owners, n, cap)
+        for j in range(n):
             total = int(counts[j].sum())
-            got = recv[j * fn.spec.recv_capacity : j * fn.spec.recv_capacity + total]
-            want = np.stack(expected[j]) if expected[j] else np.zeros((0, W), np.float32)
+            got = recv[j * recv_cap : j * recv_cap + total]
+            want = np.stack(expected[j]) if expected[j] else np.zeros((0, width), np.float32)
             assert got.shape == want.shape
             assert np.array_equal(got, want), f"receiver {j}"
 

@@ -6,6 +6,7 @@ import pytest
 from sparkucx_tpu.config import TpuShuffleConf
 from sparkucx_tpu.core.block import ShuffleBlockId
 from sparkucx_tpu.shuffle.daemon import DaemonClient, DaemonOp, ShuffleDaemon
+from sparkucx_tpu.shuffle.reader import default_deserializer
 
 
 @pytest.fixture(scope="module")
@@ -50,6 +51,41 @@ class TestDaemonFlow:
         for bid, blk in zip(bids, blocks):
             assert blk == oracle[(bid.map_id, bid.reduce_id)]
         client.remove_shuffle(SID)
+
+    def test_groupbytest_records_over_the_socket_equal_the_plain_groupby(self, groupbytest):
+        """The upstream gate job's record shape through a daemon at the
+        default conf, the client side speaking sockets only: every committed
+        length, every served block and every group is the reference's."""
+        records = groupbytest.records(4)
+        daemon = ShuffleDaemon(TpuShuffleConf(), num_executors=2, port=0)
+        try:
+            client = DaemonClient(daemon.address)
+            client.create_shuffle(0, records.num_mappers, records.reducers)
+            for m, parts in enumerate(records.blocks):
+                writer = client.open_map_writer(0, m)
+                for r, payload in parts:
+                    client.write_partition(writer, r, payload)
+                written = dict(parts)
+                assert client.commit_map(writer).tolist() == [
+                    len(written.get(r, b"")) for r in range(records.reducers)
+                ]
+            client.run_exchange(0)
+            checks = []
+            for r in range(records.reducers):
+                check = records.check(r, full=True)
+                bids = [ShuffleBlockId(0, m, r) for m in records.mappers_of(r)]
+                for payload in client.fetch_blocks(bids):
+                    assert payload is not None
+                    for key, value in default_deserializer(payload):
+                        check.add(key, value)
+                assert check.ok(), f"reduce task {r} differs from the plain GroupBy"
+                checks.append(check)
+            assert records.complete(checks)
+            assert set(daemon.manager.cluster.executed_lowerings()["exchange"]) == {"dense"}
+            client.remove_shuffle(0)
+            client.close()
+        finally:
+            daemon.close()
 
     def test_error_propagation(self, client):
         with pytest.raises(RuntimeError, match="unknown shuffle|KeyError"):

@@ -106,6 +106,39 @@ def test_read_device_equals_read_and_the_records_written(executors, staging, rou
         assert mgr.get_reader(0, 3, 4).read_device().packed.shape == (0, ALIGN // 4)
 
 
+def test_fetch_blocks_device_over_an_executors_whole_share_equals_the_plain_groupby(groupbytest):
+    """GroupByTest's records held on the device (one round, received shards
+    kept), then every block an executor owns gathered in ONE
+    ``fetch_blocks_device``: the packed buffer stays on that executor's
+    device, only the platform's own gather ran, and what it holds is the
+    plain GroupBy's."""
+    from sparkucx_tpu.core.block import ShuffleBlockId
+    from sparkucx_tpu.shuffle.reader import default_deserializer
+
+    records, n = groupbytest.records(4), 2
+    with TpuShuffleManager(device_conf(8 << 20, n), num_executors=n) as mgr:
+        groupbytest.write_and_exchange(mgr, 0, records)
+        cluster = mgr.cluster
+        meta = cluster.meta(0)
+        assert len(meta.recv_sizes) == 1  # the whole share is one gather source
+        checks = [records.check(r, full=True) for r in range(records.reducers)]
+        for e in range(n):
+            transport = cluster.transport(e)
+            assert all(rnd[e].devices() == {transport.device} for rnd in meta.recv_device)
+            start, end = meta.peer_ranges[e]
+            bids = [ShuffleBlockId(0, m, r) for r in range(start, end) for m in records.mappers_of(r)]
+            packed, entries = transport.fetch_blocks_device(bids)
+            assert packed.devices() == {transport.device}
+            host = np.asarray(packed).reshape(-1).view(np.uint8)
+            for (row, length), bid in zip(entries.tolist(), bids):
+                at = row * cluster.row_bytes
+                for key, value in default_deserializer(memoryview(host[at : at + length])):
+                    checks[bid.reduce_id].add(key, value)
+        assert all(c.ok() for c in checks) and records.complete(checks)
+        ran = cluster.executed_lowerings()
+        assert set(ran["exchange"]) == {"dense"} and set(ran["gather"]) == {"xla"}
+
+
 def test_a_range_of_several_partitions_is_one_packed_buffer():
     with TpuShuffleManager(device_conf(1 << 20, 2), num_executors=2) as mgr:
         want = write_job(mgr, 0, 5, 8, seed=7, skip=skip)

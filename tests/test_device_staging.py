@@ -152,6 +152,36 @@ class TestSealPayloads:
         assert stats["host_staging_allocated"] is False
         assert stats["device_mode"] is True
 
+    def test_host_and_device_staged_writes_seal_to_the_same_payload(self):
+        """One map task, two partitions of 4,096 bytes, written -> committed
+        -> sealed -> removed on ONE store, shuffle after shuffle: the sealed
+        round of the host byte path and of the device path hold the same
+        bytes, and the device path keeps its payload off the host."""
+        rng = np.random.default_rng(0)
+        conf = _conf(True, 1, 1 << 20)
+        conf.spill_to_disk = False
+        stores = {impl: HbmBlockStore(conf, device=jax.devices()[0]) for impl in ("host", "device")}
+        for sid in range(2):  # the second shuffle reuses what the first built
+            blocks = [rng.integers(0, 256, size=4096, dtype=np.uint8).tobytes() for _ in range(2)]
+            sealed = {}
+            for impl, store in stores.items():
+                store.create_shuffle(sid, 1, 2)
+                w = store.map_writer(sid, 0)
+                for r, block in enumerate(blocks):
+                    if impl == "host":
+                        w.write_partition(r, block)
+                    else:
+                        w.write_partition_device(r, _rows_for(block), length=len(block))
+                info = w.commit()
+                payload, sizes = store.seal(sid)[-1]
+                assert store.host_staging_allocated(sid) == (impl == "host")
+                sealed[impl] = (np.asarray(payload).tobytes(), np.asarray(sizes), info.partitions)
+                store.remove_shuffle(sid)
+            assert sealed["host"][0] == sealed["device"][0]
+            np.testing.assert_array_equal(sealed["host"][1], sealed["device"][1])
+            assert sealed["host"][2] == sealed["device"][2]
+            assert sealed["host"][0][: 2 * 4096] == blocks[0] + blocks[1]
+
     def test_read_block_serves_device_round(self):
         store = _standalone_store()
         w = store.map_writer(0, 0)
@@ -229,15 +259,6 @@ class TestWriterLayer:
         assert info.partitions[0][1] == 513
         assert store.read_block(0, 0, 0) == b"m" * 513
         assert store.read_block(0, 0, 2) == b"n" * 64
-
-
-class TestWriteBenchmark:
-    def test_measure_write_reports_both_impls(self):
-        from sparkucx_tpu.perf.benchmark import measure_write
-
-        res = measure_write(2, 4096, iterations=1)
-        assert set(res) == {"host", "device"}
-        assert all(v > 0 for v in res.values())
 
 
 class TestReaderZeroCopy:

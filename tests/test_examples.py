@@ -4,8 +4,11 @@ The examples are the user-facing walkthroughs (examples/README.md); running
 them end-to-end keeps the documented surface honest the same way the
 integration gate keeps the daemon protocol honest."""
 
+import importlib.util
 import os
 import pathlib
+import re
+import shlex
 import subprocess
 import sys
 
@@ -23,6 +26,21 @@ def test_readme_lists_every_script():
     readme = (EXAMPLES_DIR / "README.md").read_text()
     for script in SCRIPTS:
         assert script in readme, f"examples/README.md does not mention {script}"
+
+
+def test_every_command_of_the_readme_names_something_that_exists():
+    """README's ``bash`` block is what a new owner runs first: each ``python``
+    line's script or ``-m`` module, and each ``bash`` line's script, is there."""
+    root = EXAMPLES_DIR.parent
+    block = "\n".join(re.findall(r"```bash\n(.*?)```", (root / "README.md").read_text(), re.S))
+    commands = [shlex.split(line, comments=True) for line in block.splitlines()]
+    commands = [c for c in commands if c and c[0] in ("python", "python3", "bash")]
+    assert len(commands) >= 8
+    for argv in commands:
+        if argv[1] == "-m":
+            assert importlib.util.find_spec(argv[2]) is not None, argv
+        else:
+            assert (root / argv[1]).is_file(), argv
 
 
 @pytest.mark.parametrize("script", SCRIPTS)

@@ -120,7 +120,7 @@ class TestHarness:
 # ---------------------------------------------------------------------------
 
 
-def _stage(t, shuffle_id, num_mappers, num_reducers, seed=0):
+def _stage(t, shuffle_id, num_mappers, num_reducers, seed=0, block_bytes=200):
     """Stage deterministic random blocks on executor ``t``; returns
     {(map, reduce): payload}."""
     rng = np.random.default_rng(seed)
@@ -129,7 +129,7 @@ def _stage(t, shuffle_id, num_mappers, num_reducers, seed=0):
     for m in range(num_mappers):
         w = t.store.map_writer(shuffle_id, m)
         for r in range(num_reducers):
-            data = rng.integers(0, 256, size=200 + 37 * (m + r), dtype=np.uint8).tobytes()
+            data = rng.integers(0, 256, size=block_bytes + 37 * (m + r), dtype=np.uint8).tobytes()
             payloads[(m, r)] = data
             w.write_partition(r, data)
         w.commit()
@@ -261,32 +261,34 @@ def _reader(transport, payloads, num_mappers, num_reducers, executors, **kw):
 
 
 class TestExecutorLossChaos:
-    def _run(self, kill: bool):
+    def _run(self, mappers, reducers, block_bytes, kill_after):
         """Stage on executor 1 (replica -> executor 2), read from executor 0;
-        with ``kill``, executor 1 dies after the first block is consumed."""
+        executor 1 dies once ``kill_after`` blocks are consumed (None: never)."""
         ts = _cluster(3, replication_factor=1, wire_timeout_ms=5000)
         try:
-            payloads = _stage(ts[1], 0, 2, 3, seed=42)
+            payloads = _stage(ts[1], 0, mappers, reducers, seed=42, block_bytes=block_bytes)
             ts[1].store.seal(0)
             assert ts[1].replication_wait(0, timeout=10.0)
-            reader = _reader(ts[0], payloads, 2, 3, executors=[0, 1, 2])
+            reader = _reader(ts[0], payloads, mappers, reducers, executors=[0, 1, 2])
             got = {}
-            it = reader.fetch_blocks()
-            first = next(it)
-            got[(first.block_id.map_id, first.block_id.reduce_id)] = bytes(first.data)
-            first.release()
-            if kill:
-                faults.kill_executor(ts[1])  # SIGKILL stand-in, mid-traffic
-            for blk in it:
+            for blk in reader.fetch_blocks():
                 got[(blk.block_id.map_id, blk.block_id.reduce_id)] = bytes(blk.data)
                 blk.release()
+                if len(got) == kill_after:
+                    faults.kill_executor(ts[1])  # SIGKILL stand-in, mid-traffic
+            assert kill_after is not None or got == payloads
             return got, reader.metrics
         finally:
             _close_all(ts)
 
-    def test_kill_mid_superstep_bit_identical(self):
-        baseline, base_metrics = self._run(kill=False)
-        chaotic, metrics = self._run(kill=True)
+    @pytest.mark.parametrize(
+        "mappers, reducers, block_bytes, kill_after",
+        [(2, 3, 200, 1), (1, 4, 128 << 10, 2)],
+        ids=["six_small_blocks_killed_after_the_first", "four_128k_blocks_killed_at_half"],
+    )
+    def test_kill_mid_superstep_bit_identical(self, mappers, reducers, block_bytes, kill_after):
+        baseline, base_metrics = self._run(mappers, reducers, block_bytes, None)
+        chaotic, metrics = self._run(mappers, reducers, block_bytes, kill_after)
         assert chaotic == baseline  # bit-identical output despite the kill
         assert base_metrics.failovers == 0
         assert metrics.failovers >= 1  # replicas actually served

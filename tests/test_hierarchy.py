@@ -22,32 +22,43 @@ SLOT = 16
 LANE = 32  # 128-byte rows keep the test light
 
 
-def _spec():
+def _spec(slot=SLOT, lane=LANE):
     return ExchangeSpec(
-        num_executors=N, send_rows=N * SLOT, recv_rows=N * SLOT, lane=LANE, impl="dense"
+        num_executors=N, send_rows=N * slot, recv_rows=N * slot, lane=lane, impl="dense"
     )
 
 
-def _random_inputs(rng):
-    spec = _spec()
-    data = rng.integers(-(2**31), 2**31 - 1, size=(N * spec.send_rows, LANE), dtype=np.int32)
-    sizes = rng.integers(0, SLOT + 1, size=(N, N), dtype=np.int32)
+def _random_inputs(rng, slot=SLOT, lane=LANE):
+    spec = _spec(slot, lane)
+    data = rng.integers(-(2**31), 2**31 - 1, size=(N * spec.send_rows, lane), dtype=np.int32)
+    sizes = rng.integers(0, slot + 1, size=(N, N), dtype=np.int32)
     return spec, data, sizes
 
 
 class TestHierarchicalExchange:
-    def test_bit_identical_to_flat(self, rng):
-        spec, data, sizes = _random_inputs(rng)
+    @pytest.mark.parametrize(
+        "slot, lane, supersteps",
+        # the second case: 64 KiB slots of 512-byte rows (the deployment's row
+        # width), the received buffer fed back as the next superstep's send
+        [(SLOT, LANE, 1), (128, 128, 2)],
+        ids=["2KiB_slots", "64KiB_slots_chained"],
+    )
+    def test_bit_identical_to_flat(self, rng, slot, lane, supersteps):
+        spec, data, sizes = _random_inputs(rng, slot, lane)
 
         flat_mesh = make_mesh(N)
         flat = build_exchange(flat_mesh, spec)
         sh = NamedSharding(flat_mesh, P("ex", None))
-        f_recv, f_sizes = flat(jax.device_put(data, sh), jax.device_put(sizes, sh))
+        f_recv, f_size_mat = jax.device_put(data, sh), jax.device_put(sizes, sh)
+        for _ in range(supersteps):
+            f_recv, f_sizes = flat(f_recv, f_size_mat)
 
         hmesh = make_hierarchical_mesh(S, C)
         hier = build_hierarchical_exchange(hmesh, spec)
         hsh = NamedSharding(hmesh, P(("dcn", "ici"), None))
-        h_recv, h_sizes = hier(jax.device_put(data, hsh), jax.device_put(sizes, hsh))
+        h_recv, h_size_mat = jax.device_put(data, hsh), jax.device_put(sizes, hsh)
+        for _ in range(supersteps):
+            h_recv, h_sizes = hier(h_recv, h_size_mat)
 
         assert np.array_equal(np.asarray(f_sizes), np.asarray(h_sizes))
         assert np.array_equal(np.asarray(f_recv), np.asarray(h_recv))

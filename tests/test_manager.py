@@ -110,6 +110,50 @@ class TestGroupByFlow:
         assert m.fetch_wait_ns >= 0
 
 
+class TestGroupByTestAtTheDefaultConf:
+    """The upstream gate job's record shape through the manager at the conf a
+    user gets by default, against the plain GroupBy: what executed is the
+    platform's own lowering, and a warm manager builds nothing new."""
+
+    @pytest.fixture(scope="class")
+    def warm(self, groupbytest):
+        from benchmark.counters import CompileCounter
+
+        compiles = CompileCounter()
+        with TpuShuffleManager(TpuShuffleConf(), num_executors=2) as mgr:
+            self._job(groupbytest, mgr, 0, groupbytest.records(4))
+            yield mgr, compiles
+
+    @staticmethod
+    def _job(groupbytest, mgr, shuffle_id, records):
+        groupbytest.write_and_exchange(mgr, shuffle_id, records)
+        checks = []
+        for r in range(records.reducers):
+            check = records.check(r, full=True)
+            reader = mgr.get_reader(shuffle_id, r, r + 1)
+            for key, value in reader.read():
+                check.add(key, value)
+            metrics = reader.metrics
+            assert (metrics.blocks_retried, metrics.failovers, metrics.fetch_timeouts) == (0, 0, 0)
+            assert check.ok(), f"reduce task {r} differs from the plain GroupBy"
+            checks.append(check)
+        assert records.complete(checks)
+        mgr.unregister_shuffle(shuffle_id)
+
+    def test_only_the_platforms_own_lowering_executed(self, warm):
+        mgr, compiles = warm
+        ran = mgr.cluster.executed_lowerings()
+        assert set(ran["exchange"]) == {"dense"}  # 'local' / 'ragged' on the chip
+        assert ran["gather"] == []  # the host read gathers nothing on the device
+        assert compiles.since()["compiles"] >= 1
+
+    def test_a_second_smaller_shuffle_compiles_nothing(self, warm, groupbytest):
+        mgr, compiles = warm
+        before = compiles.snapshot()
+        self._job(groupbytest, mgr, 1, groupbytest.records(2, seed=30))
+        assert compiles.since(before)["compiles"] == 0
+
+
 class TestTeraSortFlow:
     def test_terasort_style_global_sort(self, manager, rng):
         """TeraSort shape (BASELINE.md config: 'TeraSort 10GB'): range-partition

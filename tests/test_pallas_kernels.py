@@ -73,6 +73,24 @@ class TestGatherLowering:
         out = np.asarray(fn(starts, counts, outs, src))
         assert np.array_equal(out[:total], _oracle(src, starts, counts))
 
+    def test_auto_lowering_serves_repeated_dispatches(self):
+        """The reply-packing shape: six 64 KiB blocks at every other slot of
+        the source, packed by the platform's own lowering ('xla' here), the
+        one executable dispatched twice over different sources."""
+        rows_each = (64 << 10) // ROW
+        plan = [(2 * i * rows_each * ROW, rows_each * ROW) for i in range(6)]
+        starts, counts, outs, total = pack_plan(plan, ROW)
+        assert total == 6 * rows_each
+        fn = build_block_gather(len(plan), total)
+        assert fn.impl == "xla"
+        rng = np.random.default_rng(0)
+        for _ in range(2):
+            big = jax.numpy.asarray(
+                rng.integers(-100, 100, size=(12 * rows_each, LANE), dtype=np.int32)
+            )
+            out = np.asarray(fn(starts, counts, outs, big))
+            assert np.array_equal(out[:total], _oracle(big, starts, counts))
+
     def test_pack_plan_rejects_misaligned(self):
         with pytest.raises(ValueError, match="aligned"):
             pack_plan([(ROW + 1, ROW)], ROW)

@@ -1,21 +1,27 @@
-"""Smoke tests for the perf benchmark CLI (UcxPerfBenchmark analogue)."""
+"""The perf CLI (UcxPerfBenchmark analogue: server and client) and the
+hardware acceptance smoke on the CI mesh."""
 
 import re
 import threading
 import time
 
+import numpy as np
 import pytest
 
+from sparkucx_tpu.config import TpuShuffleConf
+from sparkucx_tpu.core.block import MemoryBlock, ShuffleBlockId
+from sparkucx_tpu.core.operation import OperationStatus
 from sparkucx_tpu.perf import benchmark
+from sparkucx_tpu.transport.peer import PeerTransport
+
+BLOCKS, SIZE = 4, 64 << 10
 
 
-def test_client_server_roundtrip(capsys):
-    # server in a daemon thread (it loops forever; we only need it serving) on
-    # an ephemeral port, read back from the banner run_server prints once
-    # every block is registered
-    args_srv = benchmark._parse_args(["server", "-a", "127.0.0.1:0", "-n", "4", "-s", "64k"])
-    srv = threading.Thread(target=benchmark.run_server, args=(args_srv,), daemon=True)
-    srv.start()
+def _serve(capsys, argv, entry):
+    """Start the server (it loops forever; a daemon thread is enough) on an
+    ephemeral port and return the address from the banner it prints once
+    every block is registered."""
+    threading.Thread(target=entry, args=(argv,), daemon=True).start()
     # generous: on a loaded single-core CI box the server thread can starve
     # behind the suite's subprocesses for several seconds
     deadline = time.monotonic() + 30
@@ -25,63 +31,60 @@ def test_client_server_roundtrip(capsys):
         time.sleep(0.05)
     m = re.search(r"blocks on (\S+:\d+)", banner)
     assert m, f"server did not come up: {banner!r}"
-    benchmark.run_client(
-        benchmark._parse_args(
-            ["client", "-a", m.group(1), "-n", "4", "-s", "64k", "-i", "2", "-o", "2"]
-        )
-    )
+    return m.group(1)
+
+
+def _read_back(address):
+    """Every served block's bytes, fetched by a transport of the test's own."""
+    client = PeerTransport(TpuShuffleConf(), executor_id=7)
+    client.add_executor(0, address.encode())
+    try:
+        bufs = [MemoryBlock(np.zeros(SIZE, dtype=np.uint8), size=SIZE) for _ in range(BLOCKS)]
+        bids = [ShuffleBlockId(0, 0, i) for i in range(BLOCKS)]
+        reqs = client.fetch_blocks_by_block_ids(0, bids, bufs, [None] * BLOCKS)
+        while not all(r.completed() for r in reqs):
+            client.progress()
+            client.wait_for_activity(0.002)
+        assert all(r.wait(1).status == OperationStatus.SUCCESS for r in reqs)
+        return [b.host_view()[:SIZE].tobytes() for b in bufs]
+    finally:
+        client.close()
+
+
+@pytest.mark.parametrize(
+    "case", ["synthetic", "file_backed", "two_threads", "main_dispatch"]
+)
+def test_client_server_roundtrip(case, capsys, tmp_path):
+    """The reference's two modes as its README runs them: a server of -n
+    blocks of -s bytes (synthetic, or -f file-backed), a client of -t threads
+    fetching the whole set -i times, -o in flight."""
+    via_main = case == "main_dispatch"
+    threads = 2 if case == "two_threads" else 1
+    srv = ["server", "-a", "127.0.0.1:0", "-n", str(BLOCKS), "-s", "64k"]
+    if case == "file_backed":
+        content = np.random.default_rng(5).integers(0, 256, BLOCKS * SIZE, dtype=np.uint8)
+        (tmp_path / "blocks.bin").write_bytes(content.tobytes())
+        srv += ["-f", str(tmp_path / "blocks.bin")]
+    run = lambda argv: benchmark.run_server(benchmark._parse_args(argv))
+    address = _serve(capsys, srv, benchmark.main if via_main else run)
+    cli = ["client", "-a", address, "-n", str(BLOCKS), "-s", "64k", "-i", "2", "-o", "2",
+           "-t", str(threads)]
+    if via_main:
+        benchmark.main(cli)
+    else:
+        benchmark.run_client(benchmark._parse_args(cli))
     out = capsys.readouterr().out
-    assert "Mb/s" in out
-    assert out.count("iter") >= 2
-
-
-def test_superstep_mode(capsys):
-    benchmark.run_superstep(
-        benchmark._parse_args(
-            ["superstep", "-s", "64k", "-i", "2", "-o", "2", "--executors", "4"]
-        )
-    )
-    out = capsys.readouterr().out
-    assert "impl=dense" in out  # CPU mesh resolves to the portable lowering
-    assert out.count("GB/s") == 2
-
-
-def test_failover_mode(capsys):
-    # executor-loss sub-metric: steady vs primary-killed-at-50% loopback fetch
-    benchmark.run_failover(
-        benchmark._parse_args(["failover", "-n", "4", "-s", "128k", "-i", "1"])
-    )
-    out = capsys.readouterr().out
-    assert "failover: steady" in out
-    assert "recovery" in out
-    assert "failovers" in out
-
-
-def test_elastic_mode(capsys):
-    # degraded-recovery sub-metric: full-mesh exchange vs killed-mid-superstep
-    # shrink/restage/re-run (bit-identical asserted inside the measurement)
-    benchmark.run_elastic(
-        benchmark._parse_args(["elastic", "--executors", "4", "-s", "4k", "-i", "1"])
-    )
-    out = capsys.readouterr().out
-    assert "elastic: steady" in out
-    assert "killed mid-superstep" in out
-    assert "recovery" in out
-    assert "mesh 4 -> 2" in out
-
-
-def test_tenants_mode(capsys):
-    # multi-tenant serving plane: N concurrent apps streaming their own
-    # tenant-namespaced blocks back through the shared-selector reactor
-    benchmark.run_tenants(
-        benchmark._parse_args(
-            ["tenants", "--apps", "3", "-n", "4", "-s", "64k", "-i", "1"]
-        )
-    )
-    out = capsys.readouterr().out
-    assert "tenants: 3 apps" in out
-    assert "fairness" in out and "p99 fetch" in out
-    assert out.count("GB/s,") >= 3  # one per-app line per registered app
+    # every thread fetched the whole set in every iteration
+    for tid in range(threads):
+        got = re.findall(rf"\[thread {tid}\] iter (\d): (\d+) bytes", out)
+        assert got == [("0", str(BLOCKS * SIZE)), ("1", str(BLOCKS * SIZE))], out
+    blocks = _read_back(address)
+    if case == "file_backed":
+        # block i is the file's i-th -s-sized segment, byte for byte
+        raw = content.tobytes()
+        assert blocks == [raw[i * SIZE : (i + 1) * SIZE] for i in range(BLOCKS)]
+    else:
+        assert len(set(blocks)) == BLOCKS  # BLOCKS distinct synthetic blocks
 
 
 def test_cli_flags_match_reference():
@@ -93,83 +96,12 @@ def test_cli_flags_match_reference():
     assert (args.iterations, args.outstanding, args.reports, args.threads) == (3, 4, 5, 6)
 
 
-def test_gather_mode(capsys):
-    benchmark.run_gather(
-        benchmark._parse_args(["gather", "-n", "6", "-s", "64k", "-i", "2", "-o", "2"])
-    )
-    out = capsys.readouterr().out
-    assert "impl=xla" in out  # CPU resolves to the portable lowering
-    assert out.count("GB/s") == 2
-
-
-def test_gather_mode_tiled_interpret(capsys):
-    # the Pallas tiled lowering runs compiled only on TPU; 'tiled' through the
-    # CLI would need interpret mode, so just check flag plumbing
-    args = benchmark._parse_args(["gather", "--impl", "dma"])
-    assert args.impl == "dma"
-
-
-def test_sort_mode(capsys):
-    benchmark.run_sort(
-        benchmark._parse_args(
-            ["sort", "-n", "4096", "-i", "2", "--executors", "4"]
-        )
-    )
-    out = capsys.readouterr().out
-    assert "rows/s" in out and out.count("iter") == 2
-
-
-def test_groupby_mode(capsys):
-    benchmark.run_groupby(
-        benchmark._parse_args(
-            ["groupby", "-n", "4096", "-i", "2", "-o", "2", "--executors", "4",
-             "--keys", "64"]
-        )
-    )
-    out = capsys.readouterr().out
-    assert "rows/s" in out and out.count("iter") == 2
-
-
-def test_sort_external_mode(capsys):
-    benchmark.run_sort(
-        benchmark._parse_args(
-            ["sort", "-n", "8192", "-i", "1", "--executors", "2", "--batches", "4"]
-        )
-    )
-    out = capsys.readouterr().out
-    assert "external-sorted" in out and "4 device batches" in out
-
-
-def test_join_mode(capsys):
-    benchmark.run_join(
-        benchmark._parse_args(
-            ["join", "-n", "4096", "-i", "2", "-o", "2", "--executors", "4"]
-        )
-    )
-    out = capsys.readouterr().out
-    assert "rows/s" in out and out.count("iter") == 2
-
-
-def test_columnar_mode(capsys):
-    benchmark.run_columnar(
-        benchmark._parse_args(
-            ["columnar", "-n", "4096", "-s", "128", "-i", "2", "-o", "2",
-             "--executors", "4"]
-        )
-    )
-    out = capsys.readouterr().out
-    assert "impl=dense" in out  # CPU resolves to the portable lowering
-    assert out.count("GB/s") == 2
-
-
-def test_superstep_hierarchical_mode(capsys):
-    benchmark.run_superstep(
-        benchmark._parse_args(
-            ["superstep", "-s", "64k", "-i", "1", "-o", "2", "--executors", "8", "--slices", "2"]
-        )
-    )
-    out = capsys.readouterr().out
-    assert out.count("GB/s") == 1
+def test_unknown_mode_refused(capsys):
+    """Only the reference's two modes exist; the parser refuses another."""
+    with pytest.raises(SystemExit) as e:
+        benchmark.main(["superstep", "-s", "64k"])
+    assert e.value.code == 2
+    assert "invalid choice: 'superstep'" in capsys.readouterr().err
 
 
 def test_tpu_smoke_script(tmp_path):

@@ -14,8 +14,10 @@ from sparkucx_tpu.ops.relational import (
     JoinSpec,
     build_grouped_aggregate,
     build_hash_join,
+    hash_owners_host,
     oracle_aggregate,
     oracle_join,
+    plan_join_capacities,
     run_grouped_aggregate,
 )
 
@@ -132,6 +134,43 @@ class TestGroupedAggregate:
         for k, v in zip(want_k, want_v):
             np.testing.assert_allclose(rows[int(k)][0], v, rtol=1e-6)
 
+    @pytest.mark.parametrize("partial", [False, True], ids=["full_rows", "map_side_partials"])
+    def test_groupbytest_rows_at_exact_receive_capacity(self, rng, partial):
+        """GroupByTest's shape (100 B rows: key + 24 summed lanes), 4,096 rows
+        on 64 keys over four executors, the receive buffers sized EXACTLY
+        from the host twin of the placement hash: the device must place every
+        key where the host said it would, or a shard overflows."""
+        n, cap, width = 4, 1024, 24
+        keys = rng.integers(0, 64, size=n * cap).astype(np.uint32)
+        values = rng.integers(-50, 50, size=(n * cap, width), dtype=np.int64).astype(np.int32)
+        if partial:  # a sender ships one row per key it holds
+            per_owner = np.zeros(n, np.int64)
+            for s in range(n):
+                held = np.unique(keys[s * cap : (s + 1) * cap])
+                np.add.at(per_owner, hash_owners_host(held, n), 1)
+        else:
+            per_owner = np.bincount(hash_owners_host(keys, n), minlength=n)
+        mesh4 = make_mesh(n)
+        fn = build_grouped_aggregate(
+            mesh4,
+            AggregateSpec(
+                num_executors=n, capacity=cap, recv_capacity=int(per_owner.max()),
+                aggs=("sum",) * width, partial=partial,
+            ),
+        )
+        gk, gv, gc, ng, rt = fn(*_agg_inputs(mesh4, keys, values, np.full(n, cap, np.int32)))
+        np.testing.assert_array_equal(np.asarray(rt).reshape(-1), per_owner)
+        ng, gc = np.asarray(ng), np.asarray(gc).reshape(n, -1)
+        assert sum(int(gc[j, : ng[j]].sum()) for j in range(n)) == n * cap  # no row dropped
+        want_k, want_v, want_c = oracle_aggregate(keys, values, ("sum",) * width)
+        assert int(ng.sum()) == len(want_k) == 64
+        gk = np.asarray(gk).reshape(n, -1)
+        gv = np.asarray(gv).reshape(n, gk.shape[1], width)
+        got = {int(gk[j, g]): (gv[j, g], int(gc[j, g])) for j in range(n) for g in range(ng[j])}
+        for k, v, c in zip(want_k, want_v, want_c):
+            np.testing.assert_array_equal(got[int(k)][0], v)
+            assert got[int(k)][1] == c
+
     def test_spec_validation(self, mesh):
         with pytest.raises(ValueError, match="unknown aggregation"):
             AggregateSpec(
@@ -214,6 +253,52 @@ class TestHashJoin:
         assert cnt.sum() == N * CAP  # every probe row found its unique build row
         for k, b, _ in rows:
             assert b == (k, 7 * k)
+
+    @pytest.mark.parametrize(
+        "join_type",
+        ["inner", "left_outer", "left_semi", "left_anti", "right_outer", "full_outer"],
+    )
+    def test_pk_fk_half_the_probes_hit(self, rng, join_type):
+        """TPC-H's plan shape over four executors: 1,024 dimension rows with
+        unique keys, 4,096 fact rows of which about half reference one, every
+        capacity taken exactly from ``plan_join_capacities``.  Each arm emits
+        the row count set logic gives, and no buffer overflows."""
+        n, pcap, bcap = 4, 1024, 256
+        nb = n * bcap
+        bk = rng.permutation(nb).astype(np.uint32)
+        pk = rng.integers(0, 2 * nb, size=n * pcap, dtype=np.uint64).astype(np.uint32)
+        brecv, precv, out_cap = plan_join_capacities(bk, pk, n, join_type=join_type)
+        hits = int(np.isin(pk, bk).sum())
+        unreferenced = int((~np.isin(bk, pk)).sum())
+        assert 0 < hits < n * pcap
+        want = {
+            "inner": hits,
+            "left_outer": n * pcap,  # misses null-extend
+            "left_semi": hits,  # unique build keys: one row a hit
+            "left_anti": n * pcap - hits,
+            "right_outer": hits + unreferenced,
+            "full_outer": n * pcap + unreferenced,
+        }[join_type]
+        mesh4 = make_mesh(n)
+        fn = build_hash_join(
+            mesh4,
+            JoinSpec(
+                num_executors=n,
+                build_capacity=bcap, build_recv_capacity=brecv, build_width=8,
+                probe_capacity=pcap, probe_recv_capacity=precv, probe_width=16,
+                out_capacity=out_cap, join_type=join_type,
+            ),
+        )
+        out = fn(
+            _keys_sh(mesh4, bk), _rows_sh(mesh4, np.zeros((nb, 8), np.int32)),
+            _keys_sh(mesh4, np.full(n, bcap, np.int32)),
+            _keys_sh(mesh4, pk), _rows_sh(mesh4, np.zeros((n * pcap, 16), np.int32)),
+            _keys_sh(mesh4, np.full(n, pcap, np.int32)),
+        )
+        counts, recv_totals = np.asarray(out[3]), np.asarray(out[4]).reshape(n, 2)
+        assert (recv_totals[:, 0] <= brecv).all() and (recv_totals[:, 1] <= precv).all()
+        assert (counts <= out_cap).all()
+        assert int(counts.sum()) == want
 
     def test_disjoint_keys_empty_result(self, fn, mesh, rng):
         bk = rng.integers(0, 100, size=N * CAP, dtype=np.uint64).astype(np.uint32)

@@ -33,6 +33,22 @@ from sparkucx_tpu.utils.stats import StatsAggregator
 N_EXEC = 4
 
 
+def zipf_size_matrix(executors: int, max_peer_rows: int, alpha: float) -> np.ndarray:
+    """A deterministic Zipf-skewed exchange size matrix: ``sizes[i, j]`` rows
+    from sender i to destination j follow ``(rank + 1) ** -alpha`` scaled so
+    each sender's hottest lane is ``max_peer_rows`` (min 1 row), with the rank
+    order permuted per sender (seeded) so the hot destination varies — the
+    shape real shuffle workloads take (TPC-DS/TPC-H are Zipf-skewed)."""
+    n = executors
+    rng = np.random.default_rng(0)
+    weights = (np.arange(1, n + 1, dtype=np.float64)) ** (-alpha)
+    base = np.maximum(1, np.round(max_peer_rows * weights / weights[0])).astype(np.int64)
+    sizes = np.empty((n, n), dtype=np.int32)
+    for i in range(n):
+        sizes[i] = base[rng.permutation(n)]
+    return sizes
+
+
 # ----------------------------------------------------------------------
 # planner geometry (pure host, no mesh)
 
@@ -73,8 +89,6 @@ class TestPlanExchange:
         """The acceptance geometry: on a Zipf-skewed matrix whose hottest lane
         sits just past a pow2 boundary, the quota plan stages (and, dense,
         wires) strictly fewer rows than the single-shot pow2 bucket."""
-        from sparkucx_tpu.perf.benchmark import zipf_size_matrix
-
         n = 8
         sizes = zipf_size_matrix(n, 2200, 1.2)
         assert int(sizes.max()) == 2200

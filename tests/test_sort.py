@@ -156,6 +156,27 @@ class TestDistributedSort:
         valid_keys = np.concatenate([keys[j * CAP : j * CAP + nvalid[j]] for j in range(N)])
         np.testing.assert_array_equal(got, np.sort(valid_keys))
 
+    def test_terasort_rows_over_four_executors(self, rng):
+        """TeraSort's row (uint32 key + 24 int32 lanes = 100 B) at 4,096 rows
+        over four executors, the lowering left to the platform ('dense' on
+        this mesh): no row dropped, keys and payload in the oracle's order."""
+        n, cap, width = 4, 1024, 24
+        mesh4 = make_mesh(n)
+        fn4 = build_distributed_sort(
+            mesh4, SortSpec(num_executors=n, capacity=cap, recv_capacity=2 * cap, width=width)
+        )
+        assert fn4.spec.impl == "dense"
+        keys = rng.permutation(n * cap).astype(np.uint32)
+        payload = keys[:, None].astype(np.int32) + np.arange(width, dtype=np.int32)
+        ko, po, cnt = fn4(*_place(mesh4, keys, payload, np.full(n, cap, np.int32)))
+        cnt = np.asarray(cnt)
+        assert int(cnt.sum()) == n * cap
+        ko = np.asarray(ko).reshape(n, -1)
+        po = np.asarray(po).reshape(n, ko.shape[1], width)
+        want_k, want_p = oracle_sort(keys, payload)
+        np.testing.assert_array_equal(np.concatenate([ko[j, : cnt[j]] for j in range(n)]), want_k)
+        np.testing.assert_array_equal(np.concatenate([po[j, : cnt[j]] for j in range(n)]), want_p)
+
     def test_single_executor_mesh(self):
         mesh1 = make_mesh(1)
         spec = SortSpec(num_executors=1, capacity=64, recv_capacity=64, width=1, impl="dense")
@@ -285,16 +306,22 @@ class TestRunDistributedSort:
 class TestExternalSort:
     """Out-of-core driver: device-batch sorts + stable host merge."""
 
-    def test_multi_batch_vs_oracle(self, rng):
+    @pytest.mark.parametrize(
+        "n, cap, total, width",
+        [
+            (4, 200, 5 * 4 * 200 + 37, 3),  # 6 runs, ragged tail
+            (2, 1024, 8192, 24),  # TeraSort's 100 B rows in exactly 4 full batches
+        ],
+        ids=["six_ragged_runs", "terasort_rows_four_full_batches"],
+    )
+    def test_multi_batch_vs_oracle(self, rng, n, cap, total, width):
         from sparkucx_tpu.ops.exchange import make_mesh
         from sparkucx_tpu.ops.sort import SortSpec, oracle_sort, run_external_sort
 
-        n, cap = 4, 200
-        total = 5 * n * cap + 37  # 6 runs, ragged tail
         keys = rng.integers(0, 1 << 32, size=total, dtype=np.uint64).astype(np.uint32)
-        payload = rng.integers(-99, 99, size=(total, 3), dtype=np.int32)
+        payload = rng.integers(-99, 99, size=(total, width), dtype=np.int32)
         spec = SortSpec(
-            num_executors=n, capacity=cap, recv_capacity=2 * cap, width=3, impl="dense"
+            num_executors=n, capacity=cap, recv_capacity=2 * cap, width=width, impl="dense"
         )
         sk, sp = run_external_sort(make_mesh(n), spec, keys, payload)
         ok, op = oracle_sort(keys, payload)

@@ -571,9 +571,13 @@ class TestSpillDirLifecycle:
         s.close()
         assert not os.path.exists(d2)
 
-    def test_no_leftover_spill_dirs_in_tempdir(self):
+    def test_no_leftover_spill_dirs_in_tempdir(self, monkeypatch, tmp_path):
         import os
         import tempfile
+
+        # a temp dir of the test's own: in the shared one, other xdist workers
+        # make and remove spill dirs between the two listings
+        monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
 
         def leftovers():
             return {

@@ -646,6 +646,55 @@ class TestWireMultiTenant:
                 c.close()
             srv.close()
 
+    def test_three_apps_stream_concurrently_each_its_own_bytes(self):
+        """Three applications drain their own four 64 KiB blocks at the same
+        time, each through its own client and a reader on the tenant-LOCAL
+        shuffle id 0: every one gets its own bytes, whole and in order."""
+        apps = [f"app-{i}" for i in range(3)]
+        size = 64 << 10
+        payload_of = lambda app, r: (app.encode() + b":%d:" % r) * (size // 8)
+        srv, reg, addr, oracle = _tenant_server(apps, payload_of, num_blocks=4, workers=3)
+        clients = []
+        try:
+            clients = [
+                _tenant_client(addr, app, executor_id=20 + i) for i, app in enumerate(apps)
+            ]
+            got = {}
+
+            def drain(c):
+                reader = TpuShuffleReader(
+                    c,
+                    executor_id=c.executor_id,
+                    shuffle_id=0,
+                    start_partition=0,
+                    end_partition=4,
+                    num_mappers=1,
+                    block_sizes=lambda m, r: size,
+                    max_blocks_per_request=1,
+                    sender_of=lambda m: 1,
+                    fetch_deadline_ms=10_000,
+                )
+                blocks = {}
+                for blk in reader.fetch_blocks():
+                    blocks[blk.block_id.reduce_id] = bytes(blk.data)
+                    blk.release()
+                got[c.app_id] = blocks
+
+            threads = [threading.Thread(target=drain, args=(c,)) for c in clients]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(60)
+            assert got == oracle
+            # serving charged no tenant: each still holds what it staged
+            assert {app: u["used_bytes"] for app, u in reg.stats().items()} == {
+                app: 4 * size for app in apps
+            }
+        finally:
+            for c in clients:
+                c.close()
+            srv.close()
+
     def test_unknown_tenant_fails_typed_over_wire(self):
         srv, reg, addr, oracle = _tenant_server(["app-a"], lambda a, r: b"x" * 100)
         ghost = None
