@@ -69,6 +69,17 @@ class TpuShuffleConf:
     prealloc_buffers: Dict[int, int] = field(default_factory=dict)
     min_buffer_size: int = 4096
     min_allocation_size: int = 1 << 20
+    #: Host RAM a store may hold as completed staging rounds of live shuffles
+    #: and as round buffers kept for the next shuffle (store/hbm_store.py
+    #: ``_rollover``): while they fit, a completed round's buffer is handed
+    #: on as it is — no copy to the disk tier — and ``remove_shuffle`` gives a
+    #: shuffle's round buffers, zeroed, to a store-level free list that the
+    #: next shuffle's rounds are taken from, so up to this much stays held
+    #: after removal until ``close()``.  Past it rounds go to the disk tier
+    #: (``spill_to_disk``) and buffers are released.  A store bounds it by an
+    #: eighth of ``MemAvailable`` at its creation (``stats()``:
+    #: ``ram_budget_bytes``).  0 = no RAM tier and no free list: every
+    #: rollover spills and every buffer is released at removal.
     max_host_pool_bytes: int = 1 << 31
 
     # transport / workers (L3)
@@ -283,9 +294,14 @@ class TpuShuffleConf:
     shm_namespace: str = "sparkucx_tpu"
     #: Disk round tier — the capacity-beyond-RAM role of the reference's
     #: DPU-attached NVMe (NvkvHandler.scala:160-242).  When a staging round
-    #: rolls over, the completed round is written to an ``np.memmap`` file and
-    #: its RAM is released, so a shuffle larger than host memory streams
-    #: through bounded staging.  ``spill_dir=None`` -> a per-store temp dir.
+    #: rolls over and the store's RAM rounds have reached
+    #: ``max_host_pool_bytes``, the completed round is written to an
+    #: ``np.memmap`` file and its buffer becomes the next round's, so a
+    #: shuffle larger than host memory streams through bounded staging; under
+    #: that budget rounds stay in RAM.  False = never spill: every round stays
+    #: in RAM, bounded by host memory alone (buffers still come from and go
+    #: to the free list, within the budget).  ``spill_dir=None`` -> a
+    #: per-store temp dir, made when the first round spills.
     spill_to_disk: bool = True
     spill_dir: Optional[str] = None
     #: Total on-disk spill budget per store; 0 = unbounded.  Counts staged
@@ -584,6 +600,7 @@ class TpuShuffleConf:
             ("exchange.fusedCombine", "exchange_fused_combine", lambda v: str(v).lower() == "true"),
             ("partialAggregation", "partial_aggregation", lambda v: str(v).lower() == "true"),
             ("hostRecvMode", "host_recv_mode", str),
+            ("memory.maxHostPoolBytes", "max_host_pool_bytes", parse_size),
             ("spillToDisk", "spill_to_disk", lambda v: str(v).lower() == "true"),
             ("spillDir", "spill_dir", str),
             ("spillDiskCap", "spill_disk_cap_bytes", parse_size),
@@ -621,6 +638,8 @@ class TpuShuffleConf:
             raise ValueError("block_alignment must be a multiple of 4 (int32 exchange lanes)")
         if self.min_buffer_size <= 0:
             raise ValueError("min_buffer_size must be positive")
+        if self.max_host_pool_bytes < 0:
+            raise ValueError("max_host_pool_bytes must be >= 0 (0 = no RAM tier of rounds)")
         if self.max_blocks_per_request <= 0:
             raise ValueError("max_blocks_per_request must be positive")
         if self.num_executors <= 0:

@@ -37,7 +37,9 @@ Key design departures (TPU-first, each replacing a reference POC shortcut):
 from __future__ import annotations
 
 import functools
+import gc
 import shutil
+import sys
 import threading
 import time
 from time import perf_counter_ns
@@ -109,6 +111,29 @@ def _purge_spill_dir(holder: Dict[str, Optional[str]]) -> None:
         holder["dir"] = None
 
 
+def _mem_available_bytes() -> Optional[int]:
+    """``MemAvailable`` of ``/proc/meminfo``; None where there is none."""
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                if line.startswith("MemAvailable:"):
+                    return int(line.split()[1]) * 1024
+    except (OSError, ValueError, IndexError):
+        pass
+    return None
+
+
+def _ram_round_budget(conf: TpuShuffleConf) -> int:
+    """Bytes of host RAM a store may hold as completed staging rounds and as
+    recycled round buffers: ``conf.max_host_pool_bytes``, bounded by an eighth
+    of the host's ``MemAvailable`` at store creation (a host has at most eight
+    chips, each with a store) so that a small host is not overrun.  0 = no RAM
+    tier."""
+    budget = max(int(conf.max_host_pool_bytes), 0)
+    available = _mem_available_bytes() if budget else None
+    return budget if available is None else min(budget, available // 8)
+
+
 def _device_nbytes(array) -> int:
     """Bytes of HBM behind ``array``: 0 for a host array, and for a device
     array already donated to an exchange."""
@@ -142,8 +167,12 @@ class _ShuffleState:
         alignment: int,
         staging: Optional[np.ndarray] = None,
         staging_closer=None,
+        alloc: Callable[[int], np.ndarray] = functools.partial(np.zeros, dtype=np.uint8),
     ) -> None:
         self.shuffle_id = shuffle_id
+        #: ``alloc(nbytes)``: an all-zero uint8 round buffer — the owning
+        #: store's ``_take_round_buffer`` (its free list, then ``np.zeros``)
+        self._alloc = alloc
         self.num_mappers = num_mappers
         self.num_reducers = num_reducers
         self.peer_ranges = peer_ranges
@@ -174,7 +203,10 @@ class _ShuffleState:
         #: the reference windows with maxBlocksPerRequest/numOutstanding
         #: (SURVEY.md section 5.7) applied to the bulk-synchronous plane.
         self.round = 0
-        self.prev_rounds: List[Tuple[np.ndarray, np.ndarray]] = []  # (staging, region_used)
+        #: (staging, region_used) of each completed round: the round's own RAM
+        #: buffer, or its ``np.memmap`` on the disk tier; ``staging`` is None
+        #: once ``remove_shuffle`` took a RAM round's buffer back
+        self.prev_rounds: List[Tuple[Optional[np.ndarray], np.ndarray]] = []
         #: (path, nbytes) of rounds spilled to the disk tier (conf.spill_to_disk)
         self.spill_files: List[Tuple[str, int]] = []
         self.region_used = np.zeros(n, dtype=np.int64)
@@ -202,9 +234,7 @@ class _ShuffleState:
         the tentpole's "no host round trip" guarantee
         (``HbmBlockStore.host_staging_allocated``)."""
         if self._staging is None and not self.removed:
-            self._staging = np.zeros(
-                len(self.peer_ranges) * self.region_size, dtype=np.uint8
-            )
+            self._staging = self._alloc(len(self.peer_ranges) * self.region_size)
         return self._staging
 
     @staging.setter
@@ -623,19 +653,34 @@ class HbmBlockStore:
         #: always on, bumped once a map task at its commit (``staged_*`` from
         #: its block table, ``copy_ns`` from the clock round each block's copy)
         #: and once a staging round (``rollovers``, ``spilled_bytes``, ``*_ns``;
-        #: ``recycled_rounds``: host rollovers that kept their buffer, and
-        #: ``zeroed_bytes``: what they set back to zero in it).
+        #: ``ram_rounds``: rollovers whose round stayed in RAM;
+        #: ``recycled_rounds``: host rollovers that spilled and kept their
+        #: buffer, and ``zeroed_bytes``: what they set back to zero in it).
         #: ``rollover_ns`` includes the ``spill_ns`` of the round it spilled;
         #: ``spill_ns`` also counts the eviction manager's demotions.
         #: ``released_device_bytes``: HBM a removed shuffle gave back at its
         #: removal (its device-sealed round here; its received shards, which
         #: the cluster counts through ``count_released_device``).
+        #: The free list of round buffers: ``pool_hits`` / ``pool_misses``
+        #: (round buffers taken from it / allocated), ``pool_dropped_busy``
+        #: (buffers of a removed or demoted round not taken back because
+        #: something still referred to them) and the gauge ``pool_held_bytes``
+        #: (what the free list holds now).
         #: guarded by self._lock
         self._write_stats: Dict[str, int] = dict.fromkeys(
             ("staged_blocks", "staged_bytes", "rollovers", "spilled_bytes",
              "rollover_ns", "spill_ns", "copy_ns", "released_device_bytes",
-             "recycled_rounds", "zeroed_bytes"), 0
+             "recycled_rounds", "zeroed_bytes", "ram_rounds", "pool_hits",
+             "pool_misses", "pool_dropped_busy", "pool_held_bytes"), 0
         )
+        #: RAM tier of completed rounds (``_rollover``): capacity bytes of the
+        #: RAM rounds live shuffles hold plus the free list never exceed
+        #: ``_ram_budget`` while the disk tier is on; 0 = every rollover spills
+        self._ram_budget = _ram_round_budget(self.conf)
+        self._ram_round_bytes = 0  #: guarded by self._lock
+        #: buffer size -> all-zero round buffers of removed shuffles and
+        #: demoted rounds, that nothing else refers to (``_recycle_rounds``)
+        self._free_rounds: Dict[int, List[np.ndarray]] = {}  #: guarded by self._lock
         #: Optional TenantRegistry (service/tenants.py).  When set, shuffles
         #: created with an ``app_id`` are admission-checked: region
         #: allocations charge the tenant's HBM quota and over-quota writes
@@ -731,6 +776,7 @@ class HbmBlockStore:
                 self.conf.block_alignment,
                 staging=staging,
                 staging_closer=closer,
+                alloc=self._take_round_buffer,
             )
             self._shuffles[shuffle_id].app_id = app_id
             pending = self._pending_infos.pop(shuffle_id, [])
@@ -745,20 +791,21 @@ class HbmBlockStore:
             st = self._shuffles.pop(shuffle_id, None)
             if st is not None:
                 st.removed = True
-                # The live staging round and the device-sealed payload are
-                # released HERE, not at the interpreter's next collection: a
-                # writer or reader handle may keep the state object reachable
-                # long after (a 4 GiB buffer and 4 GiB of HBM a shuffle under
-                # a one-round conf).  ``removed`` is latched first, so a
-                # reader that resolved the state before gets the same clean
-                # refusal as on the shm arm.
-                st.staging = None
+                # The live staging round, the RAM rounds and the device-sealed
+                # payload are released HERE, not at the interpreter's next
+                # collection: a writer or reader handle may keep the state
+                # object reachable long after (a 4 GiB buffer and 4 GiB of HBM
+                # a shuffle under a one-round conf).  ``removed`` is latched
+                # first, so a reader that resolved the state before gets the
+                # same clean refusal as on the shm arm.
+                rounds = self._detach_host_rounds(st)
                 if st.staging_closer is not None:
                     st.staging_closer()
                 self._write_stats["released_device_bytes"] += sum(
                     _device_nbytes(payload) for payload in st.sealed_payload or ()
                 )
                 st.sealed_payload = None
+                self._recycle_rounds(rounds)
                 self._release_spill(st)
                 self._release_tenant(st, st.tenant_charged)
             for key in [k for k in self._replicas if k[0] == shuffle_id]:
@@ -794,6 +841,9 @@ class HbmBlockStore:
             # _release_spill only handles the empty-dir case).
             _purge_spill_dir(self._spill_holder)
             self._spill_bytes = 0
+            self._free_rounds.clear()
+            self._write_stats["pool_held_bytes"] = 0
+            self._ram_round_bytes = 0
 
     def _charge_tenant(self, st: _ShuffleState, nbytes: int) -> None:
         """Admission check at allocation time (caller holds self._lock): claim
@@ -929,42 +979,164 @@ class HbmBlockStore:
             self._write_stats["released_device_bytes"] += nbytes
 
     def _rollover(self, st: _ShuffleState) -> None:
-        """Snapshot the current staging epoch and start the next round
+        """Hand the completed staging epoch on and start the next round
         (caller holds self._lock).
 
-        With ``conf.spill_to_disk`` (default) the completed round moves to an
-        ``np.memmap`` file — the capacity-beyond-memory tier the reference
-        gets from DPU-attached NVMe (NvkvHandler.scala:160-242);
+        While the store's round buffers fit its RAM budget
+        (``conf.max_host_pool_bytes``; ``_admit_ram_round``) the round's
+        buffer itself becomes the ``prev_rounds`` entry — no copy, no file —
+        and the next round takes a buffer from the store's free list, which
+        ``remove_shuffle`` gives a removed shuffle's round buffers back to
+        (``ram_rounds``, ``pool_hits``); only a miss allocates, as the
+        reference recycles its registered buffers (MemoryPool.scala:117-138).
+
+        Past the budget, with ``conf.spill_to_disk`` (default), the completed
+        round moves to an ``np.memmap`` file — the capacity-beyond-memory tier
+        the reference gets from DPU-attached NVMe (NvkvHandler.scala:160-242);
         ``read_block``/``block_staging_view``/``seal`` serve spilled rounds
         through the memmap transparently — and the RAM buffer STAYS as the
         next round's staging: every used byte of it is in the memmap, so each
         region's used prefix is set back to zero on pages that are resident
-        (``recycled_rounds``, ``zeroed_bytes``) and nothing is allocated, as
-        the reference recycles its registered buffers (MemoryPool.scala:
-        117-138).  What every consumer relies on holds exactly: a round starts
-        as all zeros, so rows past a region's used count and the pad bytes of
-        a block are zeros in whatever leaves the host.  With the disk tier
-        off the round stays as a RAM snapshot (bounded by host memory) and the
-        next round takes a new buffer of untouched zero pages (``np.zeros``:
-        calloc, no second pass over them).
+        (``recycled_rounds``, ``zeroed_bytes``) and nothing is allocated.
+        With the disk tier off every round stays in RAM, bounded by host
+        memory alone.
+
+        What every consumer relies on holds exactly on each arm: a round
+        starts as all zeros (a buffer of the free list was zeroed when it was
+        taken back, a new one is ``np.zeros``), so rows past a region's used
+        count and the pad bytes of a block are zeros in whatever leaves the
+        host.
 
         Span ``store.rollover`` (once a staging round); its child
-        ``store.spill`` is the disk tier, so its self time is the zeroing of
-        the used prefixes and the bookkeeping."""
+        ``store.spill`` fires only on the disk arm, where the rollover's self
+        time is the zeroing of the used prefixes; on the RAM arm it is
+        bookkeeping."""
         with self._rollover_span(st):
             staging, used = st.staging, st.region_used
-            if self.conf.spill_to_disk:
+            if self._admit_ram_round(staging.nbytes, reuse=True):
+                st.prev_rounds.append((staging, used))
+                self._ram_round_bytes += staging.nbytes
+                st.staging = self._take_round_buffer(staging.nbytes)
+                self._write_stats["ram_rounds"] += 1
+            else:
                 st.prev_rounds.append((self._spill_round(st, staging), used))
                 for p in np.flatnonzero(used):
                     start = int(p) * st.region_size
                     staging[start : start + int(used[p])] = 0
                 self._write_stats["recycled_rounds"] += 1
                 self._write_stats["zeroed_bytes"] += int(used.sum())
-            else:
-                st.prev_rounds.append((staging, used))
-                st.staging = np.zeros(staging.shape, staging.dtype)
             st.region_used = np.zeros(len(used), dtype=used.dtype)
             st.round += 1
+
+    # -- RAM rounds and the free list of round buffers ---------------------
+
+    def _admit_ram_round(self, nbytes: int, reuse: bool) -> bool:
+        """Whether a completed round whose buffer is ``nbytes`` stays in RAM
+        (caller holds self._lock).  With the disk tier off: always (bounded by
+        host memory, as ever).  With it on: while the RAM rounds of live
+        shuffles and the free list, this round counted, fit ``_ram_budget`` —
+        with ``reuse`` the next round's buffer leaves the free list if one of
+        this size is there; otherwise the free list (buffers of other sizes)
+        is let go before a live round is sent to disk — and, where a watermark
+        is set, while
+        the next round, full, and one more block of its size would not cross
+        it: that is what ``check_memory_pressure`` sees before the next
+        rollover, and an unsealed shuffle's RAM rounds are not the eviction
+        manager's to demote, so nothing else would bring the pressure down
+        again."""
+        if not self.conf.spill_to_disk:
+            return True
+        if self._ram_round_bytes + nbytes > self._ram_budget:
+            return False
+        marks = [w for w in (self.conf.store_soft_watermark, self.conf.store_hard_watermark) if w > 0]
+        if marks and self._pressure_locked() + 2 * nbytes > min(marks):
+            return False
+        held = self._write_stats["pool_held_bytes"]
+        if not (reuse and self._free_rounds.get(nbytes)) and (
+            self._ram_round_bytes + held + nbytes > self._ram_budget
+        ):
+            self._free_rounds.clear()
+            self._write_stats["pool_held_bytes"] = 0
+        return True
+
+    def _take_round_buffer(self, nbytes: int) -> np.ndarray:
+        """An all-zero uint8 buffer for a staging round (caller holds
+        self._lock, as every path that touches a shuffle's lazy ``staging``
+        does): from the free list when it holds one of this size (pages the
+        process already holds), else ``np.zeros`` (calloc: untouched pages)."""
+        free = self._free_rounds.get(nbytes)
+        if free:
+            self._write_stats["pool_hits"] += 1
+            self._write_stats["pool_held_bytes"] -= nbytes
+            return free.pop()
+        self._write_stats["pool_misses"] += 1
+        return np.zeros(nbytes, dtype=np.uint8)
+
+    def _detach_host_rounds(self, st: _ShuffleState) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """Take a removed shuffle's private host round buffers out of its
+        state (caller holds self._lock): every RAM round of ``prev_rounds``
+        (its entry keeps the used counts and loses the buffer) and the live
+        staging.  Returns them as ``(buffer, region_used)`` for
+        ``_recycle_rounds``.  Disk-tier entries stay (``_release_spill``), shm
+        staging is only dropped (its closer unmaps it)."""
+        rounds = []
+        for i, (snap, used) in enumerate(st.prev_rounds):
+            if snap is not None and not isinstance(snap, np.memmap):
+                self._ram_round_bytes -= snap.nbytes
+                st.prev_rounds[i] = (None, used)
+                rounds.append((snap, used))
+        live, st.staging = st._staging, None
+        if live is not None and st.staging_closer is None and not isinstance(live, np.memmap):
+            rounds.append((live, st.region_used))
+        return rounds
+
+    def _recycle_rounds(self, rounds: List[Tuple[np.ndarray, np.ndarray]]) -> None:
+        """Give round buffers that no state holds any more to the free list
+        (caller holds self._lock, and NO other name for any of the buffers;
+        ``rounds`` is emptied).  Decided from what the store observes of each:
+
+        * only a buffer that owns its memory is kept (a D2H snapshot of a
+          device round is a view of the runtime's copy; shm staging never
+          gets here);
+        * only while the free list and the RAM rounds fit ``_ram_budget`` — a
+          buffer larger than the budget (a one-round 4 GiB staging) is
+          released as ever;
+        * a buffer something else still refers to — a ``block_staging_view``,
+          a sealed round in the exchange's hands, a ``jax.device_put`` that
+          aliases the host array on the CPU backend — is NEVER taken: it is
+          left to the collector as ever, and counted (``pool_dropped_busy``).
+          A view of an owner array holds the owner as its ``base``, so the
+          owner's reference count sees every such holder.
+
+        A buffer taken has each region's used prefix set back to zero here, on
+        pages that are resident: the free list holds only all-zero buffers."""
+        stats = self._write_stats
+        collected = False
+        while rounds:
+            buf, used = rounds.pop()
+            nbytes = buf.nbytes
+            if not buf.flags.owndata:
+                continue
+            if self._ram_round_bytes + stats["pool_held_bytes"] + nbytes > self._ram_budget:
+                continue
+            # (2 = only ``buf`` + getrefcount's argument, as in core/block.py)
+            if sys.getrefcount(buf) > 2 and not collected:
+                # The runtime parks the reference it held on the host source
+                # of a finished transfer until its own next call or the next
+                # collection (jax issue 14882: a callback of ``gc``).  A
+                # young-generation pass makes it let go of what it is done
+                # with; what it still needs stays referenced.
+                collected = True
+                gc.collect(0)
+            if sys.getrefcount(buf) > 2:
+                stats["pool_dropped_busy"] += 1
+                continue
+            region = nbytes // len(used)
+            for p in np.flatnonzero(used):
+                start = int(p) * region
+                buf[start : start + int(used[p])] = 0
+            self._free_rounds.setdefault(nbytes, []).append(buf)
+            stats["pool_held_bytes"] += nbytes
 
     @contextmanager
     def _rollover_span(self, st: _ShuffleState):
@@ -984,13 +1156,19 @@ class HbmBlockStore:
         in HBM via the scatter kernel, pull it D2H ONCE as the round snapshot
         (the spill boundary is where a host copy is unavoidable — HBM cannot
         hold every round), and continue in a fresh device round (caller holds
-        self._lock).  The lazy host staging buffer stays unallocated.  Same
+        self._lock).  The lazy host staging buffer stays unallocated.  The
+        snapshot stays in RAM or goes to the disk tier by ``_rollover``'s own
+        decision (``_admit_ram_round``); it is the runtime's copy, so nothing
+        of it is taken from or given to the free list.  Same
         ``store.rollover`` span and counters as ``_rollover``; its self time
         here is the scatter kernel and the D2H."""
         with self._rollover_span(st):
             payload = self._materialize_device_round(st)
             snap = np.asarray(payload).reshape(-1).view(np.uint8)
-            if self.conf.spill_to_disk:
+            if self._admit_ram_round(snap.nbytes, reuse=False):
+                self._ram_round_bytes += snap.nbytes
+                self._write_stats["ram_rounds"] += 1
+            else:
                 snap = self._spill_round(st, snap)
             st.prev_rounds.append((snap, st.region_used))
             st.region_used = np.zeros_like(st.region_used)
@@ -1204,6 +1382,8 @@ class HbmBlockStore:
         """
         st = self._state(shuffle_id)
         with self._lock:
+            if st.removed:  # resolved before a removal that took its rounds
+                raise TransportError(f"unknown shuffle {shuffle_id}")
             if st.sealed:
                 raise TransportError(f"shuffle {shuffle_id} already sealed")
             lane = st.alignment // 4
@@ -1402,7 +1582,8 @@ class HbmBlockStore:
     def demote_round(self, shuffle_id: int, round_idx: int) -> Optional[str]:
         """Move one sealed round ONE tier down: ``hbm -> host`` (drop the
         device payload, keep/snapshot the host bytes) or ``host -> disk``
-        (``_spill_round`` memmap, RAM released, tenant quota bytes returned).
+        (``_spill_round`` memmap, the RAM buffer to the store's free list
+        unless something still refers to it, tenant quota bytes returned).
         Returns the transition performed, or None when nothing moved (unknown
         round, unsealed shuffle, already on disk, shm staging, or
         spill_to_disk off).  ``read_block``/``block_staging_view`` keep
@@ -1431,11 +1612,14 @@ class HbmBlockStore:
             if st.staging_closer is not None:
                 return None  # shm staging is shared with other processes
             nbytes = self._round_nbytes(st, round_idx)
+            freed = []  # the RAM buffer the memmap replaces, for the free list
             if round_idx < len(st.prev_rounds):
                 snap, used = st.prev_rounds[round_idx]
                 mm = self._spill_round(st, snap, round_idx, used)
                 st.prev_rounds[round_idx] = (mm, used)
                 st.sealed_payload[round_idx] = mm.view(np.int32).reshape(-1, lane)
+                self._ram_round_bytes -= snap.nbytes
+                freed.append((snap, used))
             elif st.device_mode:
                 host = st.sealed_payload[round_idx]
                 flat = np.asarray(host).reshape(-1).view(np.uint8)
@@ -1446,6 +1630,9 @@ class HbmBlockStore:
                 mm = self._spill_round(st, snap, round_idx, st.region_used)
                 st.staging = mm
                 st.sealed_payload[round_idx] = mm.view(np.int32).reshape(-1, lane)
+                freed.append((snap, st.region_used))
+            snap = None  # _recycle_rounds wants the list's the only name left
+            self._recycle_rounds(freed)
             self._release_tenant(st, nbytes)
             return "host->disk"
 
@@ -1480,6 +1667,7 @@ class HbmBlockStore:
                     mm, used = st.prev_rounds[round_idx]
                     arr = np.array(mm)
                     st.prev_rounds[round_idx] = (arr, used)
+                    self._ram_round_bytes += arr.nbytes
                     if st.sealed:
                         st.sealed_payload[round_idx] = arr.view(np.int32).reshape(-1, lane)
                 elif st.device_mode:
@@ -1692,6 +1880,10 @@ class HbmBlockStore:
                         continue
                     if rnd < len(st.prev_rounds):
                         staging = st.prev_rounds[rnd][0]
+                        if staging is None:
+                            raise TransportError(
+                                f"shuffle {shuffle_id} staging already released"
+                            )
                         body += staging[e.offset : e.offset + e.length].tobytes()
                     elif st.device_mode:
                         rows = st.device_blocks.get((m, r))
@@ -1824,6 +2016,7 @@ class HbmBlockStore:
                 for _index, arr in rounds.values()
             )
         return {
+            "ram_budget_bytes": self._ram_budget,
             "replica_bytes": replica_bytes,
             "num_blocks": len(st.blocks),
             "bytes_staged": int(sum(e.length for e in st.blocks.values())),

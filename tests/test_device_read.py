@@ -241,14 +241,19 @@ def test_device_read_family_counts_once_a_task():
 
 
 @pytest.mark.parametrize("executors", [1, 4])
-def test_a_removed_shuffle_holds_no_device_array_and_no_staging_without_a_collection(executors):
+@pytest.mark.parametrize("budget", [(1 << 20) - 1, 1 << 31], ids=["buffer-over-the-budget", "default-budget"])
+def test_a_removed_shuffle_holds_no_device_array_and_no_staging_without_a_collection(executors, budget):
     """What the deployment holds a shuffle — the sealed round and the received
     shards in HBM, the host staging buffer — is released at
-    ``unregister_shuffle``, with the collector off."""
+    ``unregister_shuffle``, with the collector off.  A staging buffer larger
+    than the store's RAM budget (the HBM-held job's 4 GiB round) is freed
+    there and then; one that fits is the store's alone from then on, all
+    zeros, on its free list."""
     gc.collect()
     gc.disable()
     try:
-        with TpuShuffleManager(device_conf(1 << 20, executors), num_executors=executors) as mgr:
+        conf = device_conf(1 << 20, executors, max_host_pool_bytes=budget)
+        with TpuShuffleManager(conf, num_executors=executors) as mgr:
             before = {id(a) for a in jax.live_arrays()}
             for sid in (0, 1):
                 write_job(mgr, sid, 5, 8, seed=sid)
@@ -263,7 +268,12 @@ def test_a_removed_shuffle_holds_no_device_array_and_no_staging_without_a_collec
                     mgr.get_reader(sid, r, r + 1).read_device()  # readers, and their results, come and go
                 mgr.unregister_shuffle(sid)
                 assert [a.shape for a in jax.live_arrays() if id(a) not in before] == []
-                assert [ref() for ref in staging] == [None] * executors
+                for ref, t in zip(staging, mgr.cluster.transports):
+                    free = t.store._free_rounds.get(1 << 20, [])
+                    if budget < 1 << 20:
+                        assert ref() is None and not free
+                    else:
+                        assert [id(buf) for buf in free] == [id(ref())] and not ref().any()
                 assert [ref() for ref in states] == [None] * executors
                 now = sum(t.store.write_stats()["released_device_bytes"] for t in mgr.cluster.transports)
                 assert now - released >= held_bytes > 0

@@ -41,8 +41,9 @@ def _rows_for(payload: bytes):
     return jnp.asarray(buf.view(np.int32).reshape(-1, LANE))
 
 
-def _conf(device: bool, n: int, cap: int, mode: str = "array") -> TpuShuffleConf:
+def _conf(device: bool, n: int, cap: int, mode: str = "array", **kw) -> TpuShuffleConf:
     return TpuShuffleConf(
+        **kw,
         staging_capacity_per_executor=cap,
         block_alignment=ALIGN,
         num_executors=n,
@@ -53,10 +54,10 @@ def _conf(device: bool, n: int, cap: int, mode: str = "array") -> TpuShuffleConf
     )
 
 
-def _exchange(device: bool, n: int, M: int, R: int, cap: int, mode: str = "array"):
+def _exchange(device: bool, n: int, M: int, R: int, cap: int, mode: str = "array", **kw):
     """Write rng(7) payloads (0-3000 bytes, uneven) through the chosen path,
     commit, exchange.  Same seed both paths -> byte-identical input stream."""
-    cluster = TpuShuffleCluster(_conf(device, n, cap, mode), num_executors=n)
+    cluster = TpuShuffleCluster(_conf(device, n, cap, mode, **kw), num_executors=n)
     meta = cluster.create_shuffle(0, M, R)
     rng = np.random.default_rng(7)
     oracle, infos = {}, {}
@@ -110,15 +111,25 @@ class TestDeviceWriteBitIdentity:
         writers = {host_meta.map_owner[m] for m in range(8)}
         assert all(host_c.transport(e).store.host_staging_allocated(0) for e in writers)
 
-    def test_uneven_multi_round_rollover(self):
+    @pytest.mark.parametrize(
+        "tier, conf", [("disk", {"max_host_pool_bytes": 0}), ("host", {})], ids=["disk-tier", "ram-tier"]
+    )
+    def test_uneven_multi_round_rollover(self, tier, conf):
         # cap=16384 with ~12KB of uneven payloads per mapper forces D2H
         # rollovers mid-write; rounds must reassemble bit-identically and the
         # host staging buffer must STILL never be allocated (rollover snapshots
-        # are standalone D2H copies, not the staging buffer)
+        # are standalone D2H copies, not the staging buffer) — with the
+        # snapshots spilled (no RAM tier) and kept in RAM (the default budget)
         n, M, R, cap = 2, 4, 4, 8192
-        host_c, _, oracle, host_infos = _exchange(False, n, M, R, cap)
-        dev_c, dev_meta, _, dev_infos = _exchange(True, n, M, R, cap)
+        host_c, _, oracle, host_infos = _exchange(False, n, M, R, cap, **conf)
+        dev_c, dev_meta, _, dev_infos = _exchange(True, n, M, R, cap, **conf)
         assert dev_c.transport(0).store.num_rounds(0) >= 2
+        for c in (host_c, dev_c):
+            store = c.transport(0).store
+            assert {store.round_tier(0, k) for k in range(store.num_rounds(0) - 1)} == {tier}
+        stats = dev_c.transport(0).store.write_stats()
+        assert stats["ram_rounds"] == (stats["rollovers"] if tier == "host" else 0)
+        assert stats["pool_hits"] == stats["pool_misses"] == 0  # a device round takes no host buffer
         for m in range(M):
             assert dev_infos[m].partitions == host_infos[m].partitions
         for m in range(M):
@@ -128,6 +139,15 @@ class TestDeviceWriteBitIdentity:
                 assert bytes(d_view) == oracle[(m, r)]
         for e in range(n):
             assert not dev_c.transport(e).store.host_staging_allocated(0)
+        # a removed device shuffle's snapshots are the runtime's copies: none
+        # is kept; a host shuffle's RAM rounds are the next shuffle's
+        dev_c.remove_shuffle(0)
+        host_c.remove_shuffle(0)
+        for c in (dev_c, host_c):
+            for t in c.transports:
+                assert t.store._ram_round_bytes == 0
+                held = t.store.write_stats()["pool_held_bytes"]
+                assert (held > 0) == (c is host_c and tier == "host")
 
 
 def _standalone_store(device_staging: bool = True) -> HbmBlockStore:

@@ -60,15 +60,24 @@ def family(text, name):
     return {(metric, label): float(value) for metric, label, value in rows}
 
 
-@pytest.fixture
-def multi_round(tracer):
+#: where a completed round goes: ``disk-tier`` has no RAM tier of rounds
+#: (``max_host_pool_bytes=0``: every rollover spills once, the store of before
+#: it), ``ram-tier`` is the default conf, whose budget holds every round here
+TIERS = {"disk-tier": {"max_host_pool_bytes": 0}, "ram-tier": {}}
+
+
+@pytest.fixture(params=list(TIERS))
+def multi_round(request, tracer):
     """Two executors, 4 MiB of staging each, 8 mappers x 2 reducers x 900 KB:
-    every executor rolls its staging over, with full tracing on."""
-    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 22, block_alignment=128, num_executors=2)
+    every executor rolls its staging over, with full tracing on.  Once with
+    every completed round on the disk tier, once with every one in RAM."""
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 22, block_alignment=128, num_executors=2,
+                          **TIERS[request.param])
     cluster = TpuShuffleCluster(conf, num_executors=2)
     tracer.enable()
     written = run_shuffle(cluster, 0, mappers=8, reducers=2, block_bytes=900_000)
     tracer.disable()
+    cluster.tier = request.param
     return cluster, written
 
 
@@ -88,8 +97,13 @@ def test_one_rollover_span_per_rollover_per_executor(multi_round, tracer):
 
 
 def test_spill_is_the_child_of_its_rollover(multi_round, tracer):
+    cluster, _ = multi_round
     rollovers, spills = spans(tracer, "store.rollover"), spans(tracer, "store.spill")
-    assert len(spills) == len(rollovers)  # the default disk tier: every rollover spills once
+    if cluster.tier == "ram-tier":  # under the budget the disk arm never runs
+        assert rollovers and not spills
+        assert all(t.store._spill_dir is None for t in cluster.transports)
+        return
+    assert len(spills) == len(rollovers)  # no RAM tier: every rollover spills once
     by_id = {e["span_id"]: e for e in rollovers}
     for spill in spills:
         parent = by_id[spill["parent_id"]]
@@ -151,13 +165,30 @@ def test_store_family_counts_what_was_written(multi_round):
     for e in executors:
         stats = cluster.transports[int(e)].store.write_stats()
         assert rows[("rollovers_total", e)] == stats["rollovers"] >= 1
-        # the default disk tier: every rollover kept its buffer and zeroed what it spilled
-        assert rows[("recycled_rounds_total", e)] == stats["recycled_rounds"] == stats["rollovers"]
         assert rows[("zeroed_bytes_total", e)] == stats["zeroed_bytes"] == stats["spilled_bytes"]
-        assert rows[("spilled_bytes_total", e)] == stats["spilled_bytes"] > 0
-        # the spill is inside the rollover; the copies were timed
-        assert 0 < rows[("spill_ns_total", e)] <= rows[("rollover_ns_total", e)]
-        assert rows[("copy_ns_total", e)] > 0
+        assert rows[("pool_misses_total", e)] == stats["pool_misses"] == stats["rollovers"] + 1 - stats["recycled_rounds"]
+        assert rows[("pool_hits_total", e)] == rows[("pool_dropped_busy_total", e)] == 0
+        assert rows[("pool_held_bytes_total", e)] == stats["pool_held_bytes"] == 0  # nothing removed yet
+        if cluster.tier == "disk-tier":
+            # every rollover kept its buffer and zeroed what it spilled
+            assert rows[("recycled_rounds_total", e)] == stats["recycled_rounds"] == stats["rollovers"]
+            assert rows[("spilled_bytes_total", e)] == stats["spilled_bytes"] > 0
+            assert rows[("ram_rounds_total", e)] == stats["ram_rounds"] == 0
+            # the spill is inside the rollover
+            assert 0 < rows[("spill_ns_total", e)] <= rows[("rollover_ns_total", e)]
+        else:
+            # every round was handed on as it was: nothing spilled, kept or zeroed
+            assert rows[("ram_rounds_total", e)] == stats["ram_rounds"] == stats["rollovers"]
+            assert rows[("recycled_rounds_total", e)] == rows[("spilled_bytes_total", e)] == 0
+            assert rows[("spill_ns_total", e)] == 0 < rows[("rollover_ns_total", e)]
+        assert rows[("copy_ns_total", e)] > 0  # the copies were timed
+    cluster.remove_shuffle(0)
+    rows = family(cluster.metrics_text(), "store")
+    for e in executors:
+        stats = cluster.transports[int(e)].store.write_stats()
+        rounds = stats["rollovers"] + 1
+        held = 0 if cluster.tier == "disk-tier" else (rounds - stats["pool_dropped_busy"]) * (1 << 22)
+        assert rows[("pool_held_bytes_total", e)] == stats["pool_held_bytes"] == held
 
 
 def test_a_retry_attempt_stages_and_counts_nothing(tracer):
@@ -178,13 +209,15 @@ def test_a_retry_attempt_stages_and_counts_nothing(tracer):
     assert before["staged_bytes"] == after["staged_bytes"] == 1000
 
 
-def test_concurrent_writers_lose_no_count():
+@pytest.mark.parametrize("tier", list(TIERS))
+def test_concurrent_writers_lose_no_count(tier):
     """More writer threads than cores on one store, the interpreter switching
     often: every block and byte is counted, none twice."""
     import sys
     import threading
 
-    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=1)
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=1,
+                          **TIERS[tier])
     cluster = TpuShuffleCluster(conf, num_executors=1)
     store = cluster.transports[0].store
     writers, blocks, size = 16, 40, 3000  # 1.9 MB through 1 MiB of staging: rollovers too
@@ -213,8 +246,14 @@ def test_concurrent_writers_lose_no_count():
     assert not errors and not any(t.is_alive() for t in threads)
     stats = store.write_stats()
     assert stats["staged_blocks"] == writers * blocks and stats["staged_bytes"] == writers * blocks * size
-    assert stats["rollovers"] == stats["recycled_rounds"] == store.num_rounds(0) - 1 >= 1
-    assert stats["spilled_bytes"] > 0 and 0 < stats["spill_ns"] <= stats["rollover_ns"] and stats["copy_ns"] > 0
+    assert stats["rollovers"] == store.num_rounds(0) - 1 >= 1 and stats["copy_ns"] > 0
+    if tier == "disk-tier":
+        assert stats["recycled_rounds"] == stats["rollovers"] and stats["ram_rounds"] == 0
+        assert stats["spilled_bytes"] > 0 and 0 < stats["spill_ns"] <= stats["rollover_ns"]
+    else:
+        assert stats["ram_rounds"] == stats["rollovers"] and stats["recycled_rounds"] == 0
+        assert stats["spilled_bytes"] == stats["spill_ns"] == 0 < stats["rollover_ns"]
+        assert stats["pool_misses"] == store.num_rounds(0)
 
 
 # -- the daemon's side of a frame ------------------------------------------

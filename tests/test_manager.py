@@ -154,6 +154,55 @@ class TestGroupByTestAtTheDefaultConf:
         assert compiles.since(before)["compiles"] == 0
 
 
+class TestRoundBuffersFromJobToJob:
+    """Two multi-round GroupByTest jobs back to back through one manager at the
+    default conf but for a small staging capacity: every completed round stays
+    in RAM, and the second job's every round is written in a buffer the
+    removed first job gave back."""
+
+    @staticmethod
+    def _pool(mgr):
+        return [t.store.write_stats() for t in mgr.cluster.transports]
+
+    @pytest.mark.parametrize("executors", [1, 4])
+    def test_the_second_job_allocates_nothing(self, groupbytest, executors):
+        conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20)
+        with TpuShuffleManager(conf, num_executors=executors) as mgr:
+            rounds = []
+            for shuffle_id, seed in enumerate((29, 31)):
+                before = self._pool(mgr)
+                records = groupbytest.records(4, seed=seed)
+                groupbytest.write_and_exchange(mgr, shuffle_id, records)
+                rounds.append([t.store.num_rounds(shuffle_id) for t in mgr.cluster.transports])
+                tiers = {
+                    t.store.round_tier(shuffle_id, k)
+                    for t, n in zip(mgr.cluster.transports, rounds[-1]) for k in range(n)
+                }
+                assert tiers == {"host"}
+                checks = []
+                for r in range(records.reducers):
+                    check = records.check(r, full=True)
+                    for key, value in mgr.get_reader(shuffle_id, r, r + 1).read():
+                        check.add(key, value)
+                    assert check.ok(), f"reduce task {r} differs from the plain GroupBy"
+                    checks.append(check)
+                assert records.complete(checks)
+                mgr.unregister_shuffle(shuffle_id)
+                after = self._pool(mgr)
+                taken = [
+                    (b["pool_hits"] - a["pool_hits"], b["pool_misses"] - a["pool_misses"])
+                    for a, b in zip(before, after)
+                ]
+                if shuffle_id == 0:
+                    assert taken == [(0, n) for n in rounds[0]]
+                else:
+                    assert rounds[1] == rounds[0] and min(rounds[1]) > 1
+                    assert taken == [(n, 0) for n in rounds[1]]
+                for b, n in zip(after, rounds[-1]):
+                    assert b["spilled_bytes"] == 0 and b["ram_rounds"] == b["rollovers"]
+                    assert b["pool_dropped_busy"] == 0 and b["pool_held_bytes"] == n * (1 << 20)
+
+
 class TestTeraSortFlow:
     def test_terasort_style_global_sort(self, manager, rng):
         """TeraSort shape (BASELINE.md config: 'TeraSort 10GB'): range-partition

@@ -105,6 +105,11 @@ class TestWriteReadback:
         assert store.read_block(4, 0, 0) == b""
 
 
+#: ``max_host_pool_bytes`` values at which a 4096-byte round never stays in
+#: RAM: every rollover reaches the disk tier, the store of before the RAM tier
+NO_RAM_ROUNDS = pytest.mark.parametrize("budget", [0, 4095], ids=["no-ram-tier", "under-one-round"])
+
+
 class TestDiskSpillTier:
     """Completed staging rounds move to np.memmap files (the capacity-beyond-RAM
     role of the reference's DPU-attached NVMe, NvkvHandler.scala:160-242), so a
@@ -122,7 +127,8 @@ class TestDiskSpillTier:
             oracle[(m, 0)] = payload
         return oracle
 
-    def test_rounds_spill_to_memmap_and_read_back(self, tmp_path):
+    @NO_RAM_ROUNDS
+    def test_rounds_spill_to_memmap_and_read_back(self, tmp_path, budget):
         import os
 
         s = HbmBlockStore(
@@ -130,6 +136,7 @@ class TestDiskSpillTier:
                 staging_capacity_per_executor=4096,
                 block_alignment=ALIGN,
                 spill_dir=str(tmp_path),
+                max_host_pool_bytes=budget,
             )
         )
         # 8 rounds x 4096 B through a 4096 B RAM budget: 8x larger than staging
@@ -151,14 +158,19 @@ class TestDiskSpillTier:
         assert bytes(arr[off : off + ln]) == oracle[(0, 0)]
         s.remove_shuffle(0)
         assert os.listdir(str(tmp_path)) == []  # files AND subdir reclaimed
+        stats = s.write_stats()
+        assert stats["ram_rounds"] == 0 and stats["rollovers"] == stats["recycled_rounds"] == 7
+        assert stats["pool_held_bytes"] == 0 and stats["pool_hits"] == 0  # nothing kept after removal
         s.close()
 
-    def test_seal_serves_spilled_rounds(self, tmp_path):
+    @NO_RAM_ROUNDS
+    def test_seal_serves_spilled_rounds(self, tmp_path, budget):
         s = HbmBlockStore(
             TpuShuffleConf(
                 staging_capacity_per_executor=4096,
                 block_alignment=ALIGN,
                 spill_dir=str(tmp_path),
+                max_host_pool_bytes=budget,
             )
         )
         s.create_shuffle(0, 3, 1)
@@ -193,13 +205,15 @@ class TestDiskSpillTier:
         assert s.read_block(0, 0, 0) == oracle[(0, 0)]
         s.close()
 
-    def test_spill_cap_enforced(self, tmp_path):
+    @NO_RAM_ROUNDS
+    def test_spill_cap_enforced(self, tmp_path, budget):
         s = HbmBlockStore(
             TpuShuffleConf(
                 staging_capacity_per_executor=4096,
                 block_alignment=ALIGN,
                 spill_dir=str(tmp_path),
                 spill_disk_cap_bytes=2 * 4096,
+                max_host_pool_bytes=budget,
             )
         )
         s.create_shuffle(0, 4, 1)
@@ -210,11 +224,18 @@ class TestDiskSpillTier:
             w.write_partition(0, b"x" * region)
         s.close()
 
-    def test_shuffle_beyond_ram_budget_end_to_end(self, tmp_path):
+    @pytest.mark.parametrize(
+        "budget, tiers",
+        [(0, {"disk"}), (3 * 8192, {"host", "disk"}), (None, {"host"})],
+        ids=["every-round-spilled", "across-the-budget", "default-conf"],
+    )
+    def test_shuffle_beyond_ram_budget_end_to_end(self, tmp_path, budget, tiers):
         """BASELINE-shaped gate: exchange a shuffle ~10x the configured staging
         RAM budget through multi-round collectives and verify every block
         against the oracle (VERDICT round-1 item 4's done criterion,
-        scaled down via the small capacity)."""
+        scaled down via the small capacity) — with every completed round on
+        the disk tier, with the first three in RAM and the rest on disk, and
+        at the default budget, which holds them all."""
         from sparkucx_tpu.transport.tpu import TpuShuffleCluster
 
         n, M, R = 2, 6, 4
@@ -223,6 +244,7 @@ class TestDiskSpillTier:
             block_alignment=ALIGN,
             num_executors=n,
             spill_dir=str(tmp_path),
+            **({} if budget is None else {"max_host_pool_bytes": budget}),
         )
         cluster = TpuShuffleCluster(conf, num_executors=n)
         meta = cluster.create_shuffle(0, M, R)
@@ -242,6 +264,10 @@ class TestDiskSpillTier:
             t.commit_block(w.commit().pack())
         total = sum(len(v) for v in oracle.values())
         assert total > 10 * conf.staging_capacity_per_executor
+        for t in cluster.transports:
+            store = t.store
+            completed = [store.round_tier(0, k) for k in range(store.num_rounds(0) - 1)]
+            assert set(completed) == tiers and completed == sorted(completed, reverse=True)  # host, then disk
         cluster.run_exchange(0)
         for (m, r), expect in oracle.items():
             consumer = meta.owner_of_reduce(r)
@@ -258,25 +284,47 @@ class TestDiskSpillTier:
 
 
 class TestRolloverKeepsItsBuffer:
-    """At a host-staged rollover with the disk tier on, the spilled round's
-    RAM buffer is the next round's staging, its used prefixes set back to
-    zero; with the tier off the round IS the RAM snapshot and the next round
-    takes a new buffer.  Either way a round starts as all zeros."""
+    """At a host-staged rollover that reaches the disk tier, the spilled
+    round's RAM buffer is the next round's staging, its used prefixes set back
+    to zero; a round that stays in RAM (under the budget, or with the disk
+    tier off) IS its buffer and the next round takes another — a new one, or
+    one a removed shuffle gave back.  Every way a round starts as all zeros."""
 
     REGION = 8192
     #: share of each region a round fills: falling, so a later round leaves
     #: bytes of an earlier one under rows it never writes unless they were zeroed
     FILLS = (0.97, 0.88, 0.78, 0.68, 0.58)
 
-    def _store(self, tmp_path, spill, regions):
+    def _store(self, tmp_path, spill, regions, budget=0):
         return HbmBlockStore(
             TpuShuffleConf(
                 staging_capacity_per_executor=regions * self.REGION,
                 block_alignment=ALIGN,
                 spill_to_disk=spill,
                 spill_dir=str(tmp_path),
+                max_host_pool_bytes=budget,
             )
         )
+
+    def _dirty_the_free_list(self, s, regions):
+        """A shuffle of as many rounds as ``_drive`` writes, every region of
+        every round full of 0xFF, sealed, read and removed: its buffers are
+        what the next shuffle's rounds are taken from."""
+        rounds = len(self.FILLS)
+        s.create_shuffle(7, rounds, regions, peer_ranges=default_peer_ranges(regions, regions))
+        for m in range(rounds):
+            w = s.map_writer(7, m)
+            for p in range(regions):
+                w.write_partition(p, b"\xff" * self.REGION)
+            w.commit()
+        assert s.num_rounds(7) == rounds
+        # (no name for a sealed round may outlive this line: a round that is
+        # still referred to is not taken back)
+        assert all((np.asarray(payload).view(np.uint8) == 0xFF).all() for payload, _ in s.seal(7))
+        s.remove_shuffle(7)
+        stats = s.write_stats()
+        assert stats["pool_held_bytes"] == rounds * regions * self.REGION and stats["pool_dropped_busy"] == 0
+        return stats
 
     def _drive(self, s, regions):
         """Two map tasks a round, one block a region each: the first block of
@@ -308,9 +356,15 @@ class TestRolloverKeepsItsBuffer:
         return expected, live
 
     @pytest.mark.parametrize("regions", [1, 4])
-    @pytest.mark.parametrize("spill", [True, False], ids=["disk-tier", "ram-snapshots"])
-    def test_every_round_is_zeros_but_for_its_blocks(self, tmp_path, spill, regions):
-        s = self._store(tmp_path, spill, regions)
+    @pytest.mark.parametrize("arm", ["disk-tier", "ram-snapshots", "ram-tier", "pooled"])
+    def test_every_round_is_zeros_but_for_its_blocks(self, tmp_path, arm, regions):
+        """``disk-tier``: no RAM tier, every rollover spills and keeps its
+        buffer.  ``ram-snapshots``: the disk tier off.  ``ram-tier``: the
+        default budget, every round stays in RAM in a new buffer.  ``pooled``:
+        the same in buffers a removed shuffle filled with non-zero bytes."""
+        budget = 0 if arm in ("disk-tier", "ram-snapshots") else 1 << 31
+        s = self._store(tmp_path, arm != "ram-snapshots", regions, budget)
+        before = self._dirty_the_free_list(s, regions) if arm == "pooled" else s.write_stats()
         expected, live = self._drive(s, regions)
         st = s._state(0)
         rollovers = len(self.FILLS) - 1
@@ -318,22 +372,25 @@ class TestRolloverKeepsItsBuffer:
         useds = [used for _, used in st.prev_rounds] + [st.region_used]
         # the point of the traffic: every region of a later round is used less
         assert all((b < a).all() for a, b in zip(useds, useds[1:]))
-        stats = s.write_stats()
+        stats = {k: v - before[k] for k, v in s.write_stats().items()}
         assert stats["rollovers"] == rollovers
         first = live[0][1]
-        if spill:
+        if arm == "disk-tier":
             assert all(np.shares_memory(buf, first) for _, buf in live)
             assert all(isinstance(p, np.memmap) for p, _ in st.prev_rounds)
-            assert stats["recycled_rounds"] == rollovers
+            assert stats["recycled_rounds"] == rollovers and stats["ram_rounds"] == 0
             assert stats["zeroed_bytes"] == stats["spilled_bytes"] == sum(int(u.sum()) for u in useds[:-1])
         else:
-            assert stats["recycled_rounds"] == stats["zeroed_bytes"] == 0
+            assert stats["recycled_rounds"] == stats["zeroed_bytes"] == stats["spilled_bytes"] == 0
+            assert stats["ram_rounds"] == rollovers and s._spill_dir is None
             buffers = [p for p, _ in st.prev_rounds] + [st.staging]
             assert not any(
                 np.shares_memory(a, b) for i, a in enumerate(buffers) for b in buffers[i + 1 :]
             )
             # and each was the live buffer of its own round only
             assert all(np.shares_memory(buf, buffers[k]) for k, buf in live)
+            taken = (stats["pool_hits"], stats["pool_misses"])
+            assert taken == ((rollovers + 1, 0) if arm == "pooled" else (0, rollovers + 1))
         sealed = s.seal(0)
         assert len(sealed) == rollovers + 1
         for k, (payload, sizes) in enumerate(sealed):
@@ -387,6 +444,356 @@ class TestRolloverKeepsItsBuffer:
             late.write_partition(0, b"z" * length)
         assert s.num_rounds(0) == 1 and s.write_stats()["rollovers"] == 0
         assert np.array_equal(np.asarray(payload), before)
+        s.close()
+
+
+class TestRamRoundTier:
+    """Completed rounds stay in RAM — the round's own buffer, no copy — while
+    the store's round buffers fit ``conf.max_host_pool_bytes``; past it they
+    go to the disk tier as before.  ``remove_shuffle`` gives a shuffle's round
+    buffers, zeroed, to the store's free list, which the next shuffle's rounds
+    are taken from."""
+
+    ROUND = 4096
+
+    def _store(self, tmp_path, **conf):
+        return HbmBlockStore(
+            TpuShuffleConf(
+                staging_capacity_per_executor=self.ROUND,
+                block_alignment=ALIGN,
+                spill_dir=str(tmp_path),
+                **conf,
+            )
+        )
+
+    def _fill(self, s, shuffle_id, rounds, salt=0):
+        """One full-region block a round; returns {(map, 0): payload}."""
+        oracle = {}
+        for m in range(rounds):
+            payload = bytes([(salt + m) % 255 + 1]) * s._state(shuffle_id).region_size
+            w = s.map_writer(shuffle_id, m)
+            w.write_partition(0, payload)
+            w.commit()
+            oracle[(m, 0)] = payload
+        return oracle
+
+    @staticmethod
+    def _ram_round_bytes(s):
+        """Recounted from the states: what ``_ram_round_bytes`` keeps by steps."""
+        return sum(
+            snap.nbytes
+            for st in s._shuffles.values()
+            for snap, _ in st.prev_rounds
+            if snap is not None and not isinstance(snap, np.memmap)
+        )
+
+    def test_default_conf_under_the_budget_never_reaches_the_disk(self, tmp_path):
+        import os
+
+        from sparkucx_tpu.utils.trace import TRACER
+
+        s = self._store(tmp_path)
+        s.create_shuffle(0, 8, 1)
+        enabled, recording = TRACER.enabled, TRACER.recording
+        TRACER.clear()
+        TRACER.enable()
+        try:
+            oracle = self._fill(s, 0, 8)
+            names = [e["name"] for e in TRACER.events if e["ph"] == "X"]
+        finally:
+            TRACER.enabled, TRACER.recording = enabled, recording
+            TRACER.clear()
+        assert names.count("store.rollover") == 7 and "store.spill" not in names
+        assert s._spill_dir is None and os.listdir(str(tmp_path)) == []
+        assert [s.round_tier(0, k) for k in range(8)] == ["host"] * 8
+        stats = s.write_stats()
+        assert stats["rollovers"] == stats["ram_rounds"] == 7
+        assert stats["spilled_bytes"] == stats["spill_ns"] == stats["recycled_rounds"] == 0
+        for (m, r), expect in oracle.items():
+            assert s.read_block(0, m, r) == expect
+            arr, off, ln = s.block_staging_view(0, m, r)
+            assert not isinstance(arr, np.memmap) and bytes(arr[off : off + ln]) == expect
+        for m, (payload, sizes) in enumerate(s.seal(0)):
+            assert np.asarray(payload).view(np.uint8).tobytes() == oracle[(m, 0)]
+            assert int(sizes[0]) == self.ROUND // ALIGN
+        s.close()
+
+    @pytest.mark.parametrize("cap", [0, 2 * 4096], ids=["no-disk-cap", "spillDiskCap"])
+    def test_a_shuffle_that_crosses_the_budget(self, tmp_path, cap):
+        import os
+
+        s = self._store(tmp_path, max_host_pool_bytes=3 * self.ROUND, spill_disk_cap_bytes=cap)
+        s.create_shuffle(0, 8, 1)
+        assert s.stats(0)["ram_budget_bytes"] == 3 * self.ROUND
+        if cap:
+            oracle = self._fill(s, 0, 6)  # three rounds in RAM, two on disk = the cap, one live
+            with pytest.raises(TransportError, match="spill cap"):
+                s.map_writer(0, 6).write_partition(0, b"x" * self.ROUND)
+        else:
+            oracle = self._fill(s, 0, 8)
+        rounds = len(oracle)
+        tiers = [s.round_tier(0, k) for k in range(rounds)]
+        assert tiers == ["host"] * 3 + ["disk"] * (rounds - 4) + ["host"]  # early RAM, later disk, the live round
+        (spill_dir,) = os.listdir(str(tmp_path))
+        assert sorted(os.listdir(tmp_path / spill_dir)) == [f"s0_r{k}.bin" for k in range(3, rounds - 1)]
+        stats = s.write_stats()
+        assert stats["ram_rounds"] == 3 and stats["recycled_rounds"] == rounds - 4
+        assert stats["spilled_bytes"] == (rounds - 4) * self.ROUND
+        assert s._ram_round_bytes == self._ram_round_bytes(s) == 3 * self.ROUND
+        for (m, r), expect in oracle.items():  # byte-exact across both tiers
+            assert s.read_block(0, m, r) == expect
+            arr, off, ln = s.block_staging_view(0, m, r)
+            assert isinstance(arr, np.memmap) == (tiers[m] == "disk") or m == rounds - 1
+            assert bytes(arr[off : off + ln]) == expect
+        for m, (payload, _sizes) in enumerate(s.seal(0)):
+            assert np.asarray(payload).view(np.uint8).tobytes() == oracle[(m, 0)]
+        s.remove_shuffle(0)
+        assert os.listdir(str(tmp_path)) == []
+        # the three RAM rounds fit the budget again as free buffers; the
+        # fourth buffer (the live round's) would pass it
+        assert s.write_stats()["pool_held_bytes"] == 3 * self.ROUND and s._ram_round_bytes == 0
+        s.close()
+
+    def test_held_bytes_never_exceed_the_budget(self, tmp_path):
+        budget = 5 * self.ROUND
+        s = self._store(tmp_path, max_host_pool_bytes=budget)
+
+        def held():
+            total = s._ram_round_bytes + s.write_stats()["pool_held_bytes"]
+            assert s._ram_round_bytes == self._ram_round_bytes(s)
+            assert s.write_stats()["pool_held_bytes"] == sum(
+                size * len(free) for size, free in s._free_rounds.items()
+            )
+            assert total <= budget
+            return total
+
+        # rounds of two sizes, shuffles alive together, removed in another order
+        for sid, (capacity, rounds) in enumerate([(4096, 4), (8192, 3), (4096, 6), (8192, 2), (4096, 3)]):
+            s.create_shuffle(sid, rounds, 1, capacity=capacity)
+            for m in range(rounds):
+                w = s.map_writer(sid, m)
+                w.write_partition(0, bytes([sid + 1]) * capacity)
+                w.commit()
+                held()
+            if sid % 2:
+                s.remove_shuffle(sid - 1)
+                held()
+        for sid in (1, 3, 4):
+            for m in range(s._state(sid).num_mappers):
+                assert s.read_block(sid, m, 0) == bytes([sid + 1]) * s._state(sid).region_size
+            s.remove_shuffle(sid)
+            held()
+        stats = s.write_stats()
+        assert s._ram_round_bytes == 0 and 0 < stats["pool_held_bytes"] <= budget
+        assert stats["spilled_bytes"] > 0 and stats["ram_rounds"] > 0 and stats["pool_hits"] > 0
+        assert stats["pool_dropped_busy"] == 0
+        s.close()
+
+    def test_a_buffer_larger_than_the_budget_is_never_pooled(self, tmp_path):
+        s = self._store(tmp_path, max_host_pool_bytes=self.ROUND)
+        for sid in range(2):
+            s.create_shuffle(sid, 1, 1, capacity=2 * self.ROUND)  # one round, as the HBM-held job's 4 GiB
+            w = s.map_writer(sid, 0)
+            w.write_partition(0, b"k" * 2 * self.ROUND)
+            w.commit()
+            s.remove_shuffle(sid)
+            stats = s.write_stats()
+            assert stats["pool_held_bytes"] == 0 and s._free_rounds == {}
+            assert (stats["pool_hits"], stats["pool_misses"], stats["pool_dropped_busy"]) == (0, sid + 1, 0)
+        s.close()
+
+    def test_close_empties_the_free_list(self, tmp_path):
+        s = self._store(tmp_path)
+        s.create_shuffle(0, 4, 1)
+        self._fill(s, 0, 4)
+        s.remove_shuffle(0)
+        assert s.write_stats()["pool_held_bytes"] == 4 * self.ROUND and len(s._free_rounds[self.ROUND]) == 4
+        s.close()
+        assert s.write_stats()["pool_held_bytes"] == 0 and s._free_rounds == {} and s._ram_round_bytes == 0
+
+    def test_no_ram_tier_is_the_store_of_before(self, tmp_path):
+        """``max_host_pool_bytes=0``: every rollover spills into the same files,
+        the buffer is kept from round to round, and nothing outlives a removal."""
+        import os
+
+        s = self._store(tmp_path, max_host_pool_bytes=0)
+        for sid in range(3):
+            s.create_shuffle(sid, 4, 1)
+            live = s._state(sid).staging
+            oracle = self._fill(s, sid, 4, salt=sid)
+            assert np.shares_memory(s._state(sid).staging, live)
+            assert [s.round_tier(sid, k) for k in range(4)] == ["disk"] * 3 + ["host"]
+            (spill_dir,) = os.listdir(str(tmp_path))
+            assert sorted(os.listdir(tmp_path / spill_dir)) == [f"s{sid}_r{k}.bin" for k in range(3)]
+            assert all(s.read_block(sid, m, r) == expect for (m, r), expect in oracle.items())
+            del live
+            s.remove_shuffle(sid)
+            assert os.listdir(str(tmp_path)) == []
+            stats = s.write_stats()
+            assert stats["rollovers"] == stats["recycled_rounds"] == 3 * (sid + 1)
+            assert stats["spilled_bytes"] == stats["zeroed_bytes"] == 3 * (sid + 1) * self.ROUND
+            assert stats["ram_rounds"] == stats["pool_hits"] == stats["pool_held_bytes"] == 0
+            assert stats["pool_misses"] == sid + 1 and stats["pool_dropped_busy"] == 0
+        s.close()
+
+    def test_the_disk_tier_off_never_spills_and_shares_the_free_list(self, tmp_path):
+        import os
+
+        s = self._store(tmp_path, spill_to_disk=False, max_host_pool_bytes=2 * self.ROUND)
+        for sid in range(2):
+            s.create_shuffle(sid, 6, 1)
+            oracle = self._fill(s, sid, 6, salt=10 * sid)
+            # past the budget too: bounded by host memory alone, as ever
+            assert [s.round_tier(sid, k) for k in range(6)] == ["host"] * 6
+            assert s._ram_round_bytes == 5 * self.ROUND and os.listdir(str(tmp_path)) == []
+            assert all(s.read_block(sid, m, r) == expect for (m, r), expect in oracle.items())
+            s.remove_shuffle(sid)
+            stats = s.write_stats()
+            # what is kept after the removal is what the budget allows
+            assert stats["pool_held_bytes"] == 2 * self.ROUND and stats["spilled_bytes"] == 0
+            assert (stats["pool_hits"], stats["pool_misses"]) == (2 * sid, 6 + 4 * sid)
+        s.close()
+
+    def test_the_budget_is_bounded_by_the_hosts_memory(self, tmp_path, monkeypatch):
+        from sparkucx_tpu.store import hbm_store
+
+        monkeypatch.setattr(hbm_store, "_mem_available_bytes", lambda: 64 << 30)
+        s = self._store(tmp_path)  # the default key: 2 GiB, under an eighth of 64 GiB
+        s.create_shuffle(0, 1, 1)
+        assert s.stats(0)["ram_budget_bytes"] == TpuShuffleConf().max_host_pool_bytes == 1 << 31
+        monkeypatch.setattr(hbm_store, "_mem_available_bytes", lambda: 8 << 30)
+        small = self._store(tmp_path)
+        small.create_shuffle(0, 1, 1)
+        assert small.stats(0)["ram_budget_bytes"] == 1 << 30
+        monkeypatch.setattr(hbm_store, "_mem_available_bytes", lambda: None)
+        assert hbm_store._ram_round_budget(TpuShuffleConf(max_host_pool_bytes=123)) == 123
+        with pytest.raises(ValueError, match="max_host_pool_bytes"):
+            TpuShuffleConf(max_host_pool_bytes=-1).validate()
+        conf = TpuShuffleConf.from_spark_conf({"spark.shuffle.tpu.memory.maxHostPoolBytes": "512m"})
+        assert conf.max_host_pool_bytes == 512 << 20
+
+    def test_a_watermark_keeps_an_unsealed_shuffle_writable(self, tmp_path):
+        """RAM rounds count as memory pressure and an unsealed shuffle's are
+        nobody's to demote: a round stays in RAM only while the next round's
+        writes would still pass the watermark, so the job streams through the
+        disk tier as before instead of being shed for good."""
+        s = self._store(tmp_path, store_hard_watermark=4 * self.ROUND)
+        s.create_shuffle(0, 12, 1)
+        oracle = self._fill(s, 0, 12)  # no ResourceExhaustedError
+        tiers = [s.round_tier(0, k) for k in range(12)]
+        assert tiers == ["host"] * 2 + ["disk"] * 9 + ["host"]
+        assert s.memory_pressure_bytes() == 3 * self.ROUND
+        assert all(s.read_block(0, m, r) == expect for (m, r), expect in oracle.items())
+        s.close()
+
+    def test_a_demoted_round_gives_its_buffer_to_the_free_list(self, tmp_path):
+        s = self._store(tmp_path)
+        s.create_shuffle(0, 3, 1)
+        oracle = self._fill(s, 0, 3)
+        s.seal(0)  # the rounds' views go with the list: nothing else refers to them
+        assert s.demote_round(0, 0) == "host->disk" and s.round_tier(0, 0) == "disk"
+        stats = s.write_stats()
+        assert stats["pool_held_bytes"] == self.ROUND and stats["pool_dropped_busy"] == 0
+        assert not s._free_rounds[self.ROUND][0].any()
+        assert s._ram_round_bytes == self._ram_round_bytes(s) == self.ROUND
+        assert s.read_block(0, 0, 0) == oracle[(0, 0)]
+        assert s.restage_round(0, 0) and s.round_tier(0, 0) == "host"
+        assert s._ram_round_bytes == self._ram_round_bytes(s) == 2 * self.ROUND
+        assert s.read_block(0, 0, 0) == oracle[(0, 0)]
+        s.close()
+
+    def test_a_state_resolved_before_the_removal_is_refused_not_served_the_next_shuffle(self, tmp_path):
+        s = self._store(tmp_path)
+        s.create_shuffle(0, 3, 1)
+        self._fill(s, 0, 3)
+        late = s._state(0)  # what a reader or a writer handle holds
+        s.remove_shuffle(0)
+        assert late.removed and late.staging is None
+        assert [snap for snap, _ in late.prev_rounds] == [None, None]
+        s.create_shuffle(1, 3, 1)
+        self._fill(s, 1, 3, salt=100)
+        s._shuffles[0] = late  # the reader's view of the world, for one call each
+        try:
+            with pytest.raises(TransportError, match="released"):
+                s.read_block(0, 0, 0)
+            assert s.block_staging_view(0, 0, 0) is None
+            with pytest.raises(TransportError, match="unknown shuffle"):
+                s.seal(0)
+        finally:
+            del s._shuffles[0]
+        s.close()
+
+
+class TestRecycledBuffersAreNobodysElse:
+    """A round buffer that anything still refers to at ``remove_shuffle`` is
+    never given to the next shuffle: its holder keeps its bytes, the collector
+    frees it, ``pool_dropped_busy`` counts it."""
+
+    ROUND = 1 << 20  # page-aligned allocations: what a CPU backend's device_put may alias
+
+    def _store(self):
+        return HbmBlockStore(TpuShuffleConf(staging_capacity_per_executor=self.ROUND, block_alignment=ALIGN))
+
+    def _fill(self, s, shuffle_id, rounds, salt):
+        for m in range(rounds):
+            w = s.map_writer(shuffle_id, m)
+            w.write_partition(0, bytes([salt + m]) * self.ROUND)
+            w.commit()
+
+    def test_views_taken_before_the_removal_hold_their_bytes(self):
+        import jax
+
+        s = self._store()
+        s.create_shuffle(0, 4, 1)
+        self._fill(s, 0, 4, salt=1)
+        view, off, ln = s.block_staging_view(0, 0, 0)  # round 0, completed: zero-copy
+        assert np.shares_memory(view, s._state(0).prev_rounds[0][0])
+        sealed = s.seal(0)
+        on_device = jax.device_put(sealed[1][0], jax.devices()[0])  # round 1
+        sealed_round = sealed[2][0]  # round 2, as the exchange holds it
+        del sealed
+        aliased = np.shares_memory(np.asarray(on_device), s._state(0).prev_rounds[1][0])
+        s.remove_shuffle(0)
+        stats = s.write_stats()
+        # the live round was nobody's; of the three others, two (or three,
+        # where device_put aliased the host array) were somebody's
+        assert stats["pool_dropped_busy"] == 2 + aliased
+        assert stats["pool_held_bytes"] == (2 - aliased) * self.ROUND
+
+        s.create_shuffle(1, 4, 1)
+        self._fill(s, 1, 4, salt=101)  # the next shuffle fills its rounds
+        assert bytes(view[off : off + ln]) == bytes([1]) * self.ROUND
+        assert np.asarray(on_device).view(np.uint8).tobytes() == bytes([2]) * self.ROUND
+        assert np.asarray(sealed_round).view(np.uint8).tobytes() == bytes([3]) * self.ROUND
+        assert all(s.read_block(1, m, 0) == bytes([101 + m]) * self.ROUND for m in range(4))
+
+        # with the references gone the next removal recycles every buffer
+        del view, on_device, sealed_round
+        before = s.write_stats()
+        s.remove_shuffle(1)
+        after = s.write_stats()
+        assert after["pool_dropped_busy"] == before["pool_dropped_busy"]
+        assert after["pool_held_bytes"] == 4 * self.ROUND
+        assert all(not buf.any() for buf in s._free_rounds[self.ROUND])
+        s.close()
+
+    def test_a_live_sealed_round_on_the_device_is_not_recycled_under_it(self):
+        """A single-round shuffle sealed with a device: the device array may
+        alias the host staging on the CPU backend, and a reader holds it."""
+        import jax
+
+        s = HbmBlockStore(
+            TpuShuffleConf(staging_capacity_per_executor=self.ROUND, block_alignment=ALIGN),
+            device=jax.devices()[0],
+        )
+        s.create_shuffle(0, 1, 1)
+        self._fill(s, 0, 1, salt=9)
+        ((payload, _sizes),) = s.seal(0)
+        s.remove_shuffle(0)
+        s.create_shuffle(1, 1, 1)
+        self._fill(s, 1, 1, salt=77)
+        assert np.asarray(payload).view(np.uint8).tobytes() == bytes([9]) * self.ROUND
+        assert s.read_block(1, 0, 0) == bytes([77]) * self.ROUND
         s.close()
 
 
@@ -535,28 +942,32 @@ class TestSpillDirLifecycle:
             w.write_partition(0, bytes([m + 1]) * region)
             w.commit()
 
-    def _spilled_store(self):
+    def _spilled_store(self, budget):
         s = HbmBlockStore(
-            TpuShuffleConf(staging_capacity_per_executor=4096, block_alignment=ALIGN)
+            TpuShuffleConf(
+                staging_capacity_per_executor=4096, block_alignment=ALIGN, max_host_pool_bytes=budget
+            )
         )
         s.create_shuffle(0, 3, 1)
         self._fill_rounds(s, 0, 3, s._state(0).region_size)
         return s
 
-    def test_close_removes_default_tempdir(self):
+    @NO_RAM_ROUNDS
+    def test_close_removes_default_tempdir(self, budget):
         import os
 
-        s = self._spilled_store()
+        s = self._spilled_store(budget)
         d = s._spill_dir
         assert d is not None and os.path.isdir(d)
         assert os.path.basename(d).startswith("sparkucx_tpu_spill_e")
         s.close()
         assert not os.path.exists(d)
 
-    def test_remove_last_spilled_shuffle_reclaims_dir(self):
+    @NO_RAM_ROUNDS
+    def test_remove_last_spilled_shuffle_reclaims_dir(self, budget):
         import os
 
-        s = self._spilled_store()
+        s = self._spilled_store(budget)
         d = s._spill_dir
         assert d is not None and len(os.listdir(d)) == 2  # 3 rounds, 2 spilled
         s.remove_shuffle(0)
@@ -571,7 +982,8 @@ class TestSpillDirLifecycle:
         s.close()
         assert not os.path.exists(d2)
 
-    def test_no_leftover_spill_dirs_in_tempdir(self, monkeypatch, tmp_path):
+    @NO_RAM_ROUNDS
+    def test_no_leftover_spill_dirs_in_tempdir(self, monkeypatch, tmp_path, budget):
         import os
         import tempfile
 
@@ -587,6 +999,7 @@ class TestSpillDirLifecycle:
             }
 
         before = leftovers()
-        s = self._spilled_store()
+        s = self._spilled_store(budget)
+        assert leftovers() != before
         s.close()
         assert leftovers() == before

@@ -154,12 +154,21 @@ class TestConcurrentShuffle:
 ALIGN = 128
 
 
+#: where completed rounds of 4096 B go: all to the disk tier (no RAM tier, the
+#: store of before it), the first three in RAM and the rest to disk, all in RAM
+ROUND_TIERS = {
+    "disk-tier": {"max_host_pool_bytes": 0},
+    "across-the-budget": {"max_host_pool_bytes": 3 * 4096},
+    "ram-tier": {},
+}
+
+
 class TestDiskTierConcurrency:
     """Pull-fallback reads racing ``_rollover`` and ``remove_shuffle`` across
-    many spill rounds (VERDICT r4 task 7).  Every payload is a single
-    map-distinctive byte repeated over the whole region, so ANY torn read —
-    bytes from two rounds, a half-zeroed epoch swap, a recycled buffer —
-    shows up as a wrong byte, not a flaky length."""
+    many rounds, on the disk tier and in RAM (VERDICT r4 task 7).  Every
+    payload is a single map-distinctive byte repeated over the whole region,
+    so ANY torn read — bytes from two rounds, a half-zeroed epoch swap, a
+    recycled buffer — shows up as a wrong byte, not a flaky length."""
 
     def _store(self, tmp_path, **kw):
         conf = TpuShuffleConf(
@@ -174,11 +183,12 @@ class TestDiskTierConcurrency:
     def _pattern(m):
         return bytes([(m % 250) + 1])
 
-    def test_reads_race_rollover_across_rounds(self, tmp_path):
+    @pytest.mark.parametrize("tier", list(ROUND_TIERS))
+    def test_reads_race_rollover_across_rounds(self, tmp_path, tier):
         """Readers hammer committed blocks while a writer forces >= 6 epoch
-        rollovers into the memmap tier; every read must return the exact
-        pattern of its round."""
-        s = self._store(tmp_path)
+        rollovers into the memmap tier, the RAM tier or both; every read must
+        return the exact pattern of its round."""
+        s = self._store(tmp_path, **ROUND_TIERS[tier])
         ROUNDS = 8
         s.create_shuffle(0, ROUNDS, 1)
         region = s.region_bytes(0)
@@ -227,16 +237,21 @@ class TestDiskTierConcurrency:
             th.join(timeout=30)
         assert not failures, failures
         assert s.num_rounds(0) >= 6, "staging never rolled over — test lost its point"
-        # rounds really went to the disk tier
-        assert any(isinstance(p, np.memmap) for p, _ in s._state(0).prev_rounds)
+        # rounds really went to the tier the case is about
+        on_disk = [isinstance(p, np.memmap) for p, _ in s._state(0).prev_rounds]
+        assert {"disk-tier": all, "across-the-budget": any, "ram-tier": lambda x: not any(x)}[tier](on_disk)
+        assert on_disk == sorted(on_disk)  # RAM first, then disk
         s.remove_shuffle(0)
         s.close()
 
-    def test_reads_race_remove_shuffle(self, tmp_path):
-        """remove_shuffle fires while readers are mid-read on spilled rounds:
-        each read returns exact bytes or a clean TransportError — never torn
-        data, never a crash.  Spill accounting drains to zero afterwards."""
-        s = self._store(tmp_path)
+    @pytest.mark.parametrize("tier", list(ROUND_TIERS))
+    def test_reads_race_remove_shuffle(self, tmp_path, tier):
+        """remove_shuffle fires while readers are mid-read on completed rounds
+        and the next shuffle at once fills its rounds — in the removed one's
+        buffers, where they stayed in RAM: each read returns exact bytes or a
+        clean TransportError — never torn data, never the next shuffle's,
+        never a crash.  Spill accounting drains to zero afterwards."""
+        s = self._store(tmp_path, **ROUND_TIERS[tier])
         ROUNDS = 5
         s.create_shuffle(0, ROUNDS, 1)
         region = s.region_bytes(0)
@@ -269,9 +284,18 @@ class TestDiskTierConcurrency:
         started.wait()
         time.sleep(0.005)  # land the removal mid-hammer
         s.remove_shuffle(0)
+        s.create_shuffle(1, ROUNDS, 1)
+        for m in range(ROUNDS):
+            w = s.map_writer(1, m)
+            w.write_partition(0, b"\xfe" * region)  # no pattern of shuffle 0
+            w.commit()
         for th in readers:
             th.join(timeout=30)
         assert not failures, failures
+        stats = s.write_stats()
+        if tier != "disk-tier":  # the next shuffle did write where the removed one had been
+            assert stats["pool_hits"] >= 3 and stats["pool_dropped_busy"] == 0
+        s.remove_shuffle(1)
         assert s._spill_bytes == 0, "spill accounting leaked after remove"
         s.close()
 
@@ -326,12 +350,13 @@ class TestDiskTierConcurrency:
         assert not failures, failures
         s.close()
 
-    def test_spill_cap_enforced_under_concurrent_writers(self, tmp_path):
+    @pytest.mark.parametrize("tier", ["disk-tier", "across-the-budget"])
+    def test_spill_cap_enforced_under_concurrent_writers(self, tmp_path, tier):
         """Writer threads race rollovers against a 2-round disk cap: the cap
         must hold (TransportError, no overshoot) and accounting must stay
         exact through the failures and the final remove."""
         cap = 2 * 4096
-        s = self._store(tmp_path, spill_disk_cap_bytes=cap)
+        s = self._store(tmp_path, spill_disk_cap_bytes=cap, **ROUND_TIERS[tier])
         M = 10
         s.create_shuffle(0, M, 1)
         region = s.region_bytes(0)

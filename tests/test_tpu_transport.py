@@ -255,7 +255,7 @@ class TestRoundAssembly:
 
     # mappers on executor 0 and on executor 1 -> their staging rounds
     ONE_DEVICE_SEALED = (6, 1)  # 2 rounds and 1: the single round seals onto its device
-    BOTH_SPILL = (9, 4)  # 3 rounds and 2: memmap views, then the live buffers
+    BOTH_SPILL = (9, 4)  # 3 rounds and 2: views of the completed rounds, then the live buffers
 
     @staticmethod
     def _cluster(**conf):
@@ -308,12 +308,17 @@ class TestRoundAssembly:
         self._read_back(cluster, 0, meta, oracle)
 
     @pytest.mark.parametrize("depth", [1, 2])
-    def test_exchange_never_writes_into_a_sealed_round(self, rng, monkeypatch, depth):
+    @pytest.mark.parametrize(
+        "tier, kinds_sealed",
+        [({"max_host_pool_bytes": 0}, {np.memmap, np.ndarray}), ({}, {np.ndarray})],
+        ids=["disk-tier", "ram-tier"],
+    )
+    def test_exchange_never_writes_into_a_sealed_round(self, rng, monkeypatch, depth, tier, kinds_sealed):
         """On the CPU ``device_put`` may alias host memory and the exchange
-        donates its input: the sealed rounds (memmap and live), which the
-        pull fallback reads afterwards, hold the same bits after the
-        exchange as when they were sealed."""
-        cluster = self._cluster(pipeline_depth=depth)
+        donates its input: the sealed rounds (memmap or RAM rounds, and the
+        live buffer), which the pull fallback reads afterwards, hold the same
+        bits after the exchange as when they were sealed."""
+        cluster = self._cluster(pipeline_depth=depth, **tier)
         self._stage(cluster, 0, self.BOTH_SPILL, rng)
         at_seal = {}
         for t in cluster.transports:
@@ -331,7 +336,7 @@ class TestRoundAssembly:
             for before, after in zip(at_seal[t.executor_id], sealed):
                 kinds.add(type(after))
                 np.testing.assert_array_equal(np.asarray(after), before)
-        assert kinds == {np.memmap, np.ndarray}
+        assert kinds == kinds_sealed
 
     @pytest.mark.parametrize(
         "mappers, host_pieces", [(ONE_DEVICE_SEALED, 2), (BOTH_SPILL, 5)]
