@@ -86,6 +86,9 @@ from __future__ import annotations
 #:   (fixed per store), not from data, so distinct values are bounded by
 #:   distinct configs.  Bucketing it would over-allocate the HBM staging
 #:   array itself rather than a transient pad.
+#: - hbm_store.py ``._stage_device``: the same friend — MapWriter's packed
+#:   device write dispatches its scatter inside the critical section that
+#:   allocates the rows (and before the rollover that an overflow forces).
 ALLOWLIST = {
     ("testing/faults.py", "private-access", "._conns"),
     ("testing/faults.py", "private-access", "._zombies"),
@@ -93,6 +96,7 @@ ALLOWLIST = {
     ("store/hbm_store.py", "private-access", "._lock"),
     ("store/hbm_store.py", "private-access", "._rollover"),  # also ._rollover_device
     ("store/hbm_store.py", "private-access", "._charge_tenant"),
+    ("store/hbm_store.py", "private-access", "._stage_device"),
     ("store/hbm_store.py", "private-access", "._staging"),
     ("store/hbm_store.py", "private-access", "._write_stats"),
     ("service/tenants.py", "private-access", "._gate"),
@@ -114,12 +118,15 @@ REQUIRED_SURFACE = {
             "seal", "map_writer", "read_block", "block_staging_view",
             "region_bytes", "num_rounds", "host_staging_allocated",
         ],
-        "MapWriter": ["write_partition", "write_partition_device", "commit"],
+        "MapWriter": [
+            "write_partition", "write_partition_device", "write_partitions_device", "commit",
+        ],
     },
     "shuffle/writer.py": {
-        "DeviceMapWriter": ["write_partition", "commit"],
+        "DeviceMapWriter": ["write_partition", "write_partitions", "commit"],
         "TpuShuffleMapOutputWriter": [
-            "get_partition_writer", "write_partition_device", "commit_all_partitions",
+            "get_partition_writer", "write_partition_device", "write_partitions_device",
+            "commit_all_partitions",
         ],
     },
 }
@@ -149,7 +156,7 @@ DONATING_BUILDERS = {
 #: Builders returning ``(fn, ...)`` tuples where element 0 is the donating
 #: callable (same positions convention).
 TUPLE_DONATING_BUILDERS = {
-    "_scatter_fn": (4,),  # HbmBlockStore cache front-end for build_block_scatter
+    "_scatter_fn": (2,),  # HbmBlockStore cache front-end for build_block_scatter: fn(plan, src, dst)
 }
 
 # ----------------------------------------------------------------------

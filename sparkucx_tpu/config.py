@@ -430,12 +430,13 @@ class TpuShuffleConf:
 
     #: Device-resident map-output staging (store/hbm_store.py device rounds +
     #: ops/pallas_kernels.build_block_scatter): device-born map output is
-    #: written as ``(rows, lane)`` int32 device arrays and placed into the
-    #: HBM staging array by the block-scatter kernel, so seal returns the
-    #: exchange payload with zero D2H -> host memcpy -> H2D round trip.
-    #: Gates ``write_partition_device`` / ``DeviceMapWriter``
-    #: (shuffle/writer.py).  Default off: the host byte path stays the
-    #: reference-faithful default.
+    #: written as ``(rows, lane)`` int32 device arrays — a map task's packed
+    #: output in one call, or a block a call — and placed into the shuffle's
+    #: HBM staging array by the block-scatter kernel as it is written, so
+    #: seal hands over the exchange payload with zero D2H -> host memcpy ->
+    #: H2D round trip.  Gates ``write_partitions_device`` /
+    #: ``write_partition_device`` / ``DeviceMapWriter`` (shuffle/writer.py).
+    #: Default off: the host byte path stays the reference-faithful default.
     device_staging: bool = False
 
     #: Superstep pipelining across spill rounds: how many rounds may be in

@@ -14,7 +14,7 @@ partition-lengths array Spark's scheduler expects (``MapOutputCommitMessage``).
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, Optional, Sequence
 
 import numpy as np
 
@@ -74,12 +74,14 @@ class TpuShufflePartitionWriter:
 
 
 class DeviceMapWriter:
-    """Device-resident per-map writer (conf.device_staging): partitions arrive
-    as ``(rows, lane)`` int32 device arrays and never visit host memory — the
-    block-scatter kernel places the whole round into HBM staging at seal
-    (store/hbm_store.py ``MapWriter.write_partition_device``).  Same sequential
-    protocol and first-commit-wins retry semantics as the host ``MapWriter``;
-    this wrapper is the writer-layer surface that enforces the conf gate."""
+    """Device-resident per-map writer (conf.device_staging): a map task's
+    output arrives as ``(rows, lane)`` int32 device arrays — a block a call,
+    or the task's whole packed output in one — and never visits host memory:
+    the block-scatter kernel places it into the shuffle's HBM staging as it is
+    written (store/hbm_store.py ``MapWriter.write_partitions_device``).  Same
+    sequential protocol and first-commit-wins retry semantics as the host
+    ``MapWriter``; this wrapper is the writer-layer surface that enforces the
+    conf gate."""
 
     def __init__(self, store: HbmBlockStore, shuffle_id: int, map_id: int) -> None:
         if not store.conf.device_staging:
@@ -92,6 +94,10 @@ class DeviceMapWriter:
 
     def write_partition(self, reduce_id: int, rows, length: Optional[int] = None) -> None:
         self.map_writer.write_partition_device(reduce_id, rows, length=length)
+
+    def write_partitions(self, packed, reduce_ids: Sequence[int], lengths: Sequence[int]) -> None:
+        """The task's packed output in one call: one scatter dispatch."""
+        self.map_writer.write_partitions_device(packed, reduce_ids, lengths)
 
     def commit(self):
         return self.map_writer.commit()
@@ -139,28 +145,51 @@ class TpuShuffleMapOutputWriter:
 
     def write_partition_device(self, reduce_id: int, rows, length: Optional[int] = None) -> None:
         """Device-path partition write: ``rows`` is a ``(r, lane)`` int32
-        device array staged without a host round trip (requires
-        spark.shuffle.tpu.deviceStaging=true).  Follows the same increasing
-        reduce-order protocol as ``get_partition_writer`` and records the true
-        byte length for the commit message."""
+        device array staged without a host round trip — the one-block case of
+        ``write_partitions_device``."""
+        if length is None:
+            length = int(rows.shape[0]) * (rows.shape[1] * 4)
+        self._check_device_write([reduce_id])
+        self.map_writer.write_partition_device(reduce_id, rows, length=length)
+        self._record_device_write([reduce_id], [length])
+
+    def write_partitions_device(self, packed, reduce_ids: Sequence[int], lengths: Sequence[int]) -> None:
+        """A map task's whole device output in one call (requires
+        spark.shuffle.tpu.deviceStaging=true): ``packed`` is a ``(rows,
+        lane)`` int32 array on the executor's device holding the task's
+        non-empty blocks back to back in increasing reducer order, each from a
+        fresh row; ``reduce_ids`` and ``lengths`` name them and give their
+        true byte counts.  One block-scatter dispatch places them into the
+        shuffle's device staging; ``packed`` may be deleted as soon as this
+        returns.  Follows the same increasing reduce-order protocol as
+        ``get_partition_writer`` and records the lengths for the commit
+        message."""
+        self._check_device_write(reduce_ids)
+        self.map_writer.write_partitions_device(packed, reduce_ids, lengths)
+        self._record_device_write(reduce_ids, lengths)
+
+    def _check_device_write(self, reduce_ids: Sequence[int]) -> None:
         if not self._conf.device_staging:
             raise TransportError(
                 "device staging disabled — set spark.shuffle.tpu.deviceStaging=true"
             )
         if self._committed:
             raise TransportError("writer already committed")
-        if reduce_id <= self._last_partition:
-            raise TransportError(
-                f"partitions must be requested in increasing order "
-                f"(got {reduce_id} after {self._last_partition})"
-            )
-        if not (0 <= reduce_id < self.num_partitions):
-            raise ValueError(f"reduce_id {reduce_id} out of range")
-        self.map_writer.write_partition_device(reduce_id, rows, length=length)
-        self._last_partition = reduce_id
-        self._partition_lengths[reduce_id] = (
-            length if length is not None else int(rows.shape[0]) * (rows.shape[1] * 4)
-        )
+        last = self._last_partition
+        for reduce_id in reduce_ids:
+            if reduce_id <= last:
+                raise TransportError(
+                    f"partitions must be requested in increasing order "
+                    f"(got {reduce_id} after {last})"
+                )
+            if not (0 <= reduce_id < self.num_partitions):
+                raise ValueError(f"reduce_id {reduce_id} out of range")
+            last = reduce_id
+
+    def _record_device_write(self, reduce_ids: Sequence[int], lengths: Sequence[int]) -> None:
+        if len(reduce_ids):
+            self._last_partition = int(reduce_ids[-1])
+            self._partition_lengths[np.asarray(reduce_ids, dtype=np.int64)] = lengths
 
     def record_partition_length(self, reduce_id: int, count: int) -> None:
         """Called by PartitionWriterStream.close() with the partition's byte

@@ -144,6 +144,21 @@ def _device_nbytes(array) -> int:
     return 0
 
 
+def _device_block_bytes(array, offset: int, length: int, alignment: int) -> np.ndarray:
+    """``length`` bytes at byte ``offset`` (a row boundary) of a device round,
+    as uint8 on the host: the block's rows are sliced ON the device, in a
+    power-of-two bucket of rows so that blocks of nearby sizes share one
+    executable, and only they cross to the host — never the whole round."""
+    import jax
+
+    total = int(array.shape[0])
+    rows = min(1 << max(-(-length // alignment) - 1, 0).bit_length(), total)
+    at = min(offset // alignment, total - rows)  # XLA would clamp the start: do it here
+    window = jax.lax.dynamic_slice_in_dim(array, np.int32(at), rows, axis=0)
+    skip = offset - at * alignment
+    return np.asarray(window).reshape(-1).view(np.uint8)[skip : skip + length]
+
+
 @dataclass
 class _BlockEntry:
     offset: int  # absolute offset in the staging buffer (of its round)
@@ -192,11 +207,12 @@ class _ShuffleState:
         #: False (host MapWriter.write) or True (write_partition_device) — a
         #: shuffle is host- or device-staged, never both.
         self.device_mode: Optional[bool] = None
-        #: Current device round's blocks awaiting scatter materialization:
-        #: (dst_row, rows, payload) triples in append order, plus a per-block
-        #: map for serving reads of the not-yet-sealed round.
-        self.device_pending: List[Tuple[int, int, object]] = []
-        self.device_blocks: Dict[Tuple[int, int], object] = {}
+        #: The live device round: ONE ``(total_rows, lane)`` int32 staging
+        #: array on the owning store's device, zeros but for the blocks the
+        #: scatter has placed.  Made at the first device write of a round
+        #: (``HbmBlockStore._stage_device``), handed to ``seal`` as it is, None
+        #: in between.  Nothing of a producer's array is kept beside it.
+        self.device_staging: Optional[object] = None  # jax.Array
         #: Multi-round spill state: when a region fills, the whole staging epoch
         #: is snapshotted and writing continues in a fresh round — the exchange
         #: then runs one collective per round.  This is the data-volume scaling
@@ -381,72 +397,113 @@ class MapWriter:
         self.close_partition()
 
     def write_partition_device(self, reduce_id: int, rows, length: Optional[int] = None) -> None:
-        """Device-path partition write (conf.device_staging): ``rows`` is a
-        ``(r, lane)`` int32 device array — one row per ``alignment`` bytes,
-        already the exchange's wire unit.  The payload never visits host
-        memory: it stays device-resident until the block-scatter kernel places
-        the whole round into HBM staging at seal (or at D2H rollover, the one
-        point where a host copy is unavoidable).  Same protocol and offset
-        table as the host path: increasing reduce order, one write per
-        partition, first commit wins.  ``length`` is the true payload byte
-        count when the last row is padding-tailed (defaults to the full
-        ``rows`` extent)."""
-        if self._open_reduce is not None:
-            raise TransportError("previous partition still open")
-        if reduce_id <= self._last_reduce:
-            raise TransportError(
-                f"partitions must be opened in increasing reduce order "
-                f"(got {reduce_id} after {self._last_reduce})"
-            )
-        st = self._state
-        peer = st.owner_of(reduce_id)
-        lane = st.alignment // 4
-        if getattr(rows, "ndim", 0) != 2 or rows.shape[1] != lane:
-            raise TransportError(
-                f"device partition must be (rows, {lane}) int32, got shape "
-                f"{getattr(rows, 'shape', None)}"
-            )
-        nrows = int(rows.shape[0])
-        padded = nrows * st.alignment
+        """One device block: the one-block case of ``write_partitions_device``
+        (``rows`` is the block's ``(r, lane)`` int32 device array, ``length``
+        its true byte count when the last row is padding-tailed; defaults to
+        the full ``rows`` extent)."""
+        nrows = int(rows.shape[0]) if getattr(rows, "ndim", 0) == 2 else 0
+        padded = nrows * self._state.alignment
         if length is None:
             length = padded
-        min_len = (nrows - 1) * st.alignment + 1 if nrows else 0
-        if not (min_len <= length <= padded):
+        if not (max(padded - self._state.alignment + 1, 0) <= length <= padded):
             raise TransportError(
                 f"length {length} inconsistent with {nrows} staged rows of "
-                f"{st.alignment} B each"
+                f"{self._state.alignment} B each"
             )
-        if not self._discard:
-            if padded > st.region_size:
+        self.write_partitions_device(rows, [reduce_id], [length])
+
+    def write_partitions_device(self, packed, reduce_ids: Sequence[int], lengths: Sequence[int]) -> None:
+        """Device-path write of a map task's output (conf.device_staging):
+        ``packed`` is a ``(rows, lane)`` int32 array on the store's device —
+        one row per ``alignment`` bytes, already the exchange's wire unit —
+        holding the blocks of ``reduce_ids`` back to back in that order, each
+        from a fresh row (the bytes of a last row past a block's length should
+        be zeros: they travel as padding); ``lengths`` are the blocks' true
+        byte counts.  The blocks are placed into the shuffle's device staging
+        AT ONCE, by one block-scatter dispatch straight out of ``packed`` (one
+        more for each staging round the task crosses): the payload never
+        visits host memory, nothing of ``packed`` is kept, and the caller may
+        delete it as soon as this returns.  Same protocol and offset table as
+        the host path: increasing reduce order across the writer's calls, one
+        write per partition, first commit wins (a discarded retry dispatches
+        nothing)."""
+        if self._open_reduce is not None:
+            raise TransportError("previous partition still open")
+        st = self._state
+        align = st.alignment
+        lane = align // 4
+        if getattr(packed, "ndim", 0) != 2 or packed.shape[1] != lane:
+            raise TransportError(
+                f"device partition must be (rows, {lane}) int32, got shape "
+                f"{getattr(packed, 'shape', None)}"
+            )
+        reduce_ids = [int(r) for r in reduce_ids]
+        lengths = [int(n) for n in lengths]
+        if len(reduce_ids) != len(lengths):
+            raise TransportError(f"{len(reduce_ids)} reduce ids for {len(lengths)} lengths")
+        last = self._last_reduce
+        for reduce_id in reduce_ids:
+            if reduce_id <= last:
                 raise TransportError(
-                    f"single partition ({self.map_id},{reduce_id}) exceeds a "
+                    f"partitions must be opened in increasing reduce order "
+                    f"(got {reduce_id} after {last})"
+                )
+            last = reduce_id
+        peers = [st.owner_of(r) for r in reduce_ids]  # validates the range
+        if any(n < 0 for n in lengths):
+            raise TransportError("negative block length")
+        nrows = [-(-n // align) for n in lengths]
+        total = sum(nrows) * align
+        if sum(nrows) > int(packed.shape[0]):
+            raise TransportError(
+                f"blocks of {sum(nrows)} rows in a packed array of {int(packed.shape[0])}"
+            )
+        if not reduce_ids:
+            return
+        if not self._discard:
+            if max(nrows) * align > st.region_size:
+                raise TransportError(
+                    f"single partition of map {self.map_id} exceeds a "
                     f"whole region ({st.region_size} B) — raise stagingCapacity"
                 )
-            self._store.check_memory_pressure("write_partition_device", padded)
+            self._store.check_memory_pressure("write_partition_device", total)
             with self._store._lock:
+                if st.sealed:
+                    raise TransportError(f"shuffle {st.shuffle_id} already sealed")
                 if st.device_mode is False:
                     raise TransportError(
                         f"shuffle {st.shuffle_id} already has host-staged blocks — "
                         "host and device writes cannot mix"
                     )
                 st.device_mode = True
-                self._store._charge_tenant(st, padded)  #: balanced by _release_tenant
-                if int(st.region_used[peer]) + padded > st.region_size:
-                    if st.staging_closer is not None:
-                        raise TransportError(
-                            "region overflow with shm staging — multi-round spill "
-                            "requires private staging; raise stagingCapacity"
-                        )
-                    self._store._rollover_device(st)
-                start = peer * st.region_size + int(st.region_used[peer])
-                if nrows:
-                    st.device_pending.append((start // st.alignment, nrows, rows))
-                    st.device_blocks[(self.map_id, reduce_id)] = rows
-                st.blocks[(self.map_id, reduce_id)] = _BlockEntry(
-                    offset=start, length=length, padded=padded, round=st.round
-                )
-                st.region_used[peer] += padded
-        self._last_reduce = reduce_id
+                self._store._charge_tenant(st, total)  #: balanced by _release_tenant
+                # (staging row, rows, source row, bytes) of the blocks bound
+                # for the live round; dispatched when it rolls and at the end
+                run: List[Tuple[int, int, int, int]] = []
+                src_row = 0
+                for reduce_id, peer, length, rows in zip(reduce_ids, peers, lengths, nrows):
+                    padded = rows * align
+                    if int(st.region_used[peer]) + padded > st.region_size:
+                        # what is recorded so far is placed before anything
+                        # can raise: the table never names an unplaced block
+                        self._store._stage_device(st, packed, run)
+                        run = []
+                        if st.staging_closer is not None:
+                            raise TransportError(
+                                "region overflow with shm staging — multi-round spill "
+                                "requires private staging; raise stagingCapacity"
+                            )
+                        self._store._rollover_device(st)
+                    start = peer * st.region_size + int(st.region_used[peer])
+                    if rows:
+                        run.append((start // align, rows, src_row, length))
+                    st.blocks[(self.map_id, reduce_id)] = _BlockEntry(
+                        offset=start, length=length, padded=padded, round=st.round
+                    )
+                    st.region_used[peer] += padded
+                    src_row += rows
+                self._store._stage_device(st, packed, run)
+        self._last_reduce = last
 
     def commit(self) -> MapperInfo:
         """Commit this map task's outputs — the ``commitAllPartitions`` packing
@@ -666,12 +723,19 @@ class HbmBlockStore:
         #: (buffers of a removed or demoted round not taken back because
         #: something still referred to them) and the gauge ``pool_held_bytes``
         #: (what the free list holds now).
+        #: The device write path (``_stage_device``), once a dispatch:
+        #: ``scatter_dispatches``, the blocks and true bytes they placed
+        #: (``device_staged_blocks`` / ``device_staged_bytes``; ``staged_*``
+        #: count both paths at commit) and ``device_stage_ns``, the time the
+        #: dispatches held the writer's thread — not the DMA.
         #: guarded by self._lock
         self._write_stats: Dict[str, int] = dict.fromkeys(
             ("staged_blocks", "staged_bytes", "rollovers", "spilled_bytes",
              "rollover_ns", "spill_ns", "copy_ns", "released_device_bytes",
              "recycled_rounds", "zeroed_bytes", "ram_rounds", "pool_hits",
-             "pool_misses", "pool_dropped_busy", "pool_held_bytes"), 0
+             "pool_misses", "pool_dropped_busy", "pool_held_bytes",
+             "device_staged_blocks", "device_staged_bytes", "scatter_dispatches",
+             "device_stage_ns"), 0
         )
         #: RAM tier of completed rounds (``_rollover``): capacity bytes of the
         #: RAM rounds live shuffles hold plus the free list never exceed
@@ -802,9 +866,10 @@ class HbmBlockStore:
                 if st.staging_closer is not None:
                     st.staging_closer()
                 self._write_stats["released_device_bytes"] += sum(
-                    _device_nbytes(payload) for payload in st.sealed_payload or ()
+                    _device_nbytes(payload)
+                    for payload in (st.device_staging, *(st.sealed_payload or ()))
                 )
-                st.sealed_payload = None
+                st.sealed_payload = st.device_staging = None
                 self._recycle_rounds(rounds)
                 self._release_spill(st)
                 self._release_tenant(st, st.tenant_charged)
@@ -1152,19 +1217,20 @@ class HbmBlockStore:
         self._write_stats["rollover_ns"] += perf_counter_ns() - t0
 
     def _rollover_device(self, st: _ShuffleState) -> None:
-        """Device-round analogue of ``_rollover``: materialize the full round
-        in HBM via the scatter kernel, pull it D2H ONCE as the round snapshot
-        (the spill boundary is where a host copy is unavoidable — HBM cannot
-        hold every round), and continue in a fresh device round (caller holds
-        self._lock).  The lazy host staging buffer stays unallocated.  The
-        snapshot stays in RAM or goes to the disk tier by ``_rollover``'s own
-        decision (``_admit_ram_round``); it is the runtime's copy, so nothing
-        of it is taken from or given to the free list.  Same
-        ``store.rollover`` span and counters as ``_rollover``; its self time
-        here is the scatter kernel and the D2H."""
+        """Device-round analogue of ``_rollover``: pull the full device
+        staging array D2H ONCE as the round snapshot (the spill boundary is
+        where a host copy is unavoidable — HBM cannot hold every round), let
+        it go, and continue in a fresh device round, whose array the next
+        device write makes (caller holds self._lock).  The lazy host staging
+        buffer stays unallocated.  The snapshot stays in RAM or goes to the
+        disk tier by ``_rollover``'s own decision (``_admit_ram_round``); it
+        is the runtime's copy, so nothing of it is taken from or given to the
+        free list.  Same ``store.rollover`` span and counters as
+        ``_rollover``; its self time here is the wait for the scatters and
+        the D2H."""
         with self._rollover_span(st):
-            payload = self._materialize_device_round(st)
-            snap = np.asarray(payload).reshape(-1).view(np.uint8)
+            snap = np.asarray(self._device_round(st)).reshape(-1).view(np.uint8)
+            st.device_staging = None
             if self._admit_ram_round(snap.nbytes, reuse=False):
                 self._ram_round_bytes += snap.nbytes
                 self._write_stats["ram_rounds"] += 1
@@ -1172,8 +1238,6 @@ class HbmBlockStore:
                 snap = self._spill_round(st, snap)
             st.prev_rounds.append((snap, st.region_used))
             st.region_used = np.zeros_like(st.region_used)
-            st.device_pending = []
-            st.device_blocks = {}
             st.round += 1
 
     def _spill_round(
@@ -1279,58 +1343,91 @@ class HbmBlockStore:
     # -- device staging rounds (conf.device_staging) -----------------------
 
     def _scatter_fn(self, num_blocks: int, max_rows: int, out_rows: int):
-        """Compiled block scatter for the staging geometry, pow2-bucketed on
-        batch size and largest-block window so varying device rounds reuse a
-        handful of compiles (the exchange's ``_gather_fn`` discipline).
-        Returns ``(fn, bucketed_num_blocks)``; callers pad the plan arrays to
-        the bucket with zero-count entries.  Caller holds ``self._lock``
-        (its one call site is ``_materialize_device_round``)."""
+        """Compiled block scatter ``fn(plan, src, dst) -> dst'`` for the
+        staging geometry, pow2-bucketed on batch size and largest-block window
+        so map tasks of nearby sizes reuse a handful of compiles (the
+        exchange's ``_gather_fn`` discipline; the executable still specializes
+        on ``src``'s row count, so a producer that wants ONE executable hands
+        over buffers of one size).  ``plan`` is the ``(3, B)`` int32 host
+        array of ``(staging row, rows, source row)`` a block, an argument of
+        the one dispatch; ``dst`` is donated on the chip.  Returns ``(fn,
+        bucketed_num_blocks)``; callers pad the plan to the bucket with
+        zero-count entries.  Caller holds ``self._lock`` (its one call site is
+        ``_stage_device``)."""
         b = max(1 << max(num_blocks - 1, 0).bit_length(), 1)
         w = max(1 << max(max_rows - 1, 0).bit_length(), 1)
         key = (b, w, out_rows)
         fn = self._scatter_cache.get(key)
         if fn is None:
+            import jax
+
             from sparkucx_tpu.ops.pallas_kernels import build_block_scatter
 
-            fn = build_block_scatter(b, out_rows, max_block_rows=w)
+            scatter = build_block_scatter(b, out_rows, max_block_rows=w)
+
+            def block_scatter(plan, src, dst):
+                return scatter(plan[0], plan[1], plan[2], src, dst)
+
+            # the executable stays jit_block_scatter; dst is donated where
+            # build_block_scatter donates it (the in-place append)
+            donate = (2,) if jax.devices()[0].platform == "tpu" else ()
+            fn = jax.jit(block_scatter, donate_argnums=donate)
+            fn.impl = scatter.impl
             self._scatter_cache[key] = fn
         return fn, b
 
-    def _materialize_device_round(self, st: _ShuffleState):
-        """Place the current device round's pending blocks into one
-        HBM-resident slot-layout array via the block-scatter kernel (caller
-        holds self._lock).  This is the zero-round-trip write path: the result
-        is exactly the ``(total_rows, lane)`` payload ``seal`` would otherwise
-        build on the host and ``device_put`` — but no host byte ever moves."""
-        import jax
-        import jax.numpy as jnp
+    def scatter_lowerings(self) -> List[str]:
+        """``fn.impl`` of every block scatter this store has compiled."""
+        with self._lock:
+            return [fn.impl for fn in self._scatter_cache.values()]
 
-        lane = st.alignment // 4
-        total_rows = len(st.peer_ranges) * (st.region_size // st.alignment)
-        # allocated on this executor's device, never staged through device 0
-        dst = jnp.zeros((total_rows, lane), dtype=jnp.int32, device=self.device)
-        pending = st.device_pending
-        if not pending:
-            return dst
-        starts = np.asarray([p[0] for p in pending], dtype=np.int32)
-        counts = np.asarray([p[1] for p in pending], dtype=np.int32)
-        outs = np.concatenate([[0], np.cumsum(counts)[:-1]]).astype(np.int32)
-        total = int(counts.sum())
-        blocks = [p[2] for p in pending]
-        packed = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=0)
-        fn, b = self._scatter_fn(len(pending), int(counts.max()), total_rows)
-        if b > len(pending):
-            pad = b - len(pending)
-            starts = np.pad(starts, (0, pad))
-            counts = np.pad(counts, (0, pad))
-            outs = np.pad(outs, (0, pad), constant_values=total)
-        # One (3, B) upload instead of three tiny H2D transfers (same trick as
-        # the fetch path's plan upload, transport/tpu.py).
-        plan = np.stack([starts, counts, outs])
-        if self.device is not None:
-            plan = jax.device_put(plan, self.device)
-            packed = jax.device_put(packed, self.device)
-        return fn(plan[0], plan[1], plan[2], packed, dst)
+    def _device_round(self, st: _ShuffleState):
+        """The live device round's staging array, made as zeros on this
+        executor's device (never staged through device 0) where no device
+        write has made it yet (caller holds self._lock)."""
+        if st.device_staging is None:
+            import jax.numpy as jnp
+
+            total_rows = len(st.peer_ranges) * (st.region_size // st.alignment)
+            st.device_staging = jnp.zeros(
+                (total_rows, st.alignment // 4), dtype=jnp.int32, device=self.device
+            )
+        return st.device_staging
+
+    def _stage_device(self, st: _ShuffleState, src, run: List[Tuple[int, int, int, int]]) -> None:
+        """Place ``run`` — ``(staging row, rows, source row, bytes)`` a block
+        — out of the producer's packed array ``src`` into the live device
+        round by ONE block-scatter dispatch (caller holds self._lock).  No
+        host byte moves and no copy of ``src`` is made: it is the kernel's
+        source as it stands, the staging array its donated destination.
+
+        Span ``store.device_stage`` and ``device_stage_ns``, once a dispatch:
+        **the time the call holds the writer's thread** (the plan, the
+        dispatch; the first of a round also the zero fill's), NOT the DMA,
+        which is asynchronous."""
+        if not run:
+            return
+        import jax
+
+        t0 = perf_counter_ns()
+        nbytes = sum(block[3] for block in run)
+        with span(
+            "store.device_stage", shuffle_id=st.shuffle_id,
+            executor=self.executor_id, blocks=len(run), bytes=nbytes,
+        ):
+            dst = self._device_round(st)
+            fn, b = self._scatter_fn(len(run), max(block[1] for block in run), int(dst.shape[0]))
+            # zero-count entries pad the plan to its bucket
+            plan = np.zeros((3, b), dtype=np.int32)
+            plan[:, : len(run)] = np.asarray(run, dtype=np.int64)[:, :3].T
+            if self.device is not None:
+                src = jax.device_put(src, self.device)  # where it already is: as it is
+            st.device_staging = fn(plan, src, dst)
+        stats = self._write_stats
+        stats["scatter_dispatches"] += 1
+        stats["device_staged_blocks"] += len(run)
+        stats["device_staged_bytes"] += nbytes
+        stats["device_stage_ns"] += perf_counter_ns() - t0
 
     # -- write path --------------------------------------------------------
 
@@ -1402,9 +1499,9 @@ class HbmBlockStore:
             final_sizes = (st.region_used // st.alignment).astype(np.int32)
             if st.device_mode:
                 # Device write path: the final round seals as the HBM-resident
-                # scatter output — zero device_put, zero host staging; the
-                # per-block device arrays in device_blocks back read_block.
-                payload = self._materialize_device_round(st)
+                # staging array the scatters filled — zero device_put, zero
+                # host staging, nothing left to do here but hand it over.
+                payload, st.device_staging = self._device_round(st), None
             else:
                 payload = st.staging.view(np.int32).reshape(-1, lane)
                 if device_put_here:
@@ -1690,6 +1787,25 @@ class HbmBlockStore:
 
     # -- read path (serve staged blocks) ----------------------------------
 
+    def _live_device_block(self, st: _ShuffleState, e: _BlockEntry) -> np.ndarray:
+        """A block of a device shuffle's LAST round on the host (caller holds
+        self._lock): out of the staging array while the round is being
+        written, out of the sealed payload after.  A payload the exchange
+        consumed (it donates its send buffer where the receive buffer can
+        alias it) or that was removed is a clean refusal: there is no second
+        copy, on the device or the host."""
+        array = st.device_staging
+        if array is None and st.sealed_payload is not None:
+            array = st.sealed_payload[-1]
+        if isinstance(array, np.ndarray):  # demoted to the host or the disk tier
+            flat = array.reshape(-1).view(np.uint8)
+            return flat[e.offset : e.offset + e.length]
+        if array is None or array.is_deleted():
+            raise TransportError(
+                f"device round {e.round} of shuffle {st.shuffle_id} is no longer resident"
+            )
+        return _device_block_bytes(array, e.offset, e.length, st.alignment)
+
     def read_block(self, shuffle_id: int, map_id: int, reduce_id: int) -> bytes:
         """Direct block read — HBM after seal, host staging before
         (the two arms of UcxShuffleBlockResolver.getBlockData,
@@ -1726,9 +1842,11 @@ class HbmBlockStore:
         sealed = st.sealed_payload  # one read: remove_shuffle may clear it
         if sealed is not None:
             payload = sealed[e.round]
-            if not (hasattr(payload, "is_deleted") and payload.is_deleted()):
+            if not hasattr(payload, "is_deleted"):
                 flat = np.asarray(payload).reshape(-1).view(np.uint8)
                 return flat[e.offset : e.offset + e.length].tobytes()
+            if not payload.is_deleted():
+                return _device_block_bytes(payload, e.offset, e.length, st.alignment).tobytes()
         # Lock: (prev_rounds, staging) must be read atomically vs _rollover,
         # and the bytes copy must complete before a concurrent remove_shuffle
         # can munmap shm staging (the closer also runs under this lock).
@@ -1736,15 +1854,9 @@ class HbmBlockStore:
             if e.round < len(st.prev_rounds):
                 staging = st.prev_rounds[e.round][0]
             elif st.device_mode:
-                # Current device round: serve straight from the per-block
-                # device array (one tiny D2H) — there is no host staging.
-                rows = st.device_blocks.get((map_id, reduce_id))
-                if rows is None:
-                    raise TransportError(
-                        f"device block ({shuffle_id},{map_id},{reduce_id}) no longer resident"
-                    )
-                flat = np.asarray(rows).reshape(-1).view(np.uint8)
-                return flat[: e.length].tobytes()
+                # Live device round: the block's rows of the device staging
+                # array (one small D2H) — there is no host staging.
+                return self._live_device_block(st, e).tobytes()
             else:
                 staging = st.staging
             if staging is None:
@@ -1780,13 +1892,13 @@ class HbmBlockStore:
         with self._lock:
             live = e.round >= len(st.prev_rounds)
             if live and st.device_mode:
-                rows = st.device_blocks.get((map_id, reduce_id))
-                if rows is None:
+                # Live device round: a private host copy of the block (the
+                # device array is donated on by the next write and let go by
+                # a rollover); None once the exchange took the sealed array.
+                try:
+                    return np.array(self._live_device_block(st, e)), 0, e.length
+                except TransportError:
                     return None
-                # Current device round: hand out a private host copy of the
-                # block (the device array can be superseded by a rollover).
-                flat = np.array(np.asarray(rows).reshape(-1).view(np.uint8)[: e.length])
-                return flat, 0, e.length
             staging = st.staging if live else st.prev_rounds[e.round][0]
             if staging is None:
                 return None
@@ -1886,14 +1998,7 @@ class HbmBlockStore:
                             )
                         body += staging[e.offset : e.offset + e.length].tobytes()
                     elif st.device_mode:
-                        rows = st.device_blocks.get((m, r))
-                        if rows is None:
-                            raise TransportError(
-                                f"device block ({shuffle_id},{m},{r}) no longer "
-                                "resident — cannot replicate"
-                            )
-                        flat = np.asarray(rows).reshape(-1).view(np.uint8)
-                        body += flat[: e.length].tobytes()
+                        body += self._live_device_block(st, e).tobytes()
                     else:
                         staging = st.staging
                         if staging is None:

@@ -267,15 +267,18 @@ class TpuShuffleCluster:
     def executed_lowerings(self) -> Dict[str, List[str]]:
         """The lowering of every executable this cluster has compiled, by
         kind — what ran (``fn.spec.impl`` for exchanges, ``fn.impl`` for block
-        gathers), not what the conf asked for.  ``benchmark/`` holds a run's
-        ``correct`` to it."""
-        out: Dict[str, List[str]] = {"exchange": [], "gather": []}
+        gathers and for the block scatters of the stores' device write path),
+        not what the conf asked for.  ``benchmark/`` holds a run's ``correct``
+        to it."""
+        out: Dict[str, List[str]] = {"exchange": [], "gather": [], "scatter": []}
         with self._lock:
             for key, fn in self._exchange_cache.items():
                 if key[0] == "gather":
                     out["gather"].append(fn.impl)
                 else:
                     out["exchange"].append(fn.spec.impl)
+        for t in self.transports:
+            out["scatter"].extend(t.store.scatter_lowerings())
         return out
 
     def device_read_stats(self) -> List[Dict[str, int]]:

@@ -490,6 +490,77 @@ def test_released_device_bytes_joins_the_store_family(device_manager):
         assert rows[("released_device_bytes_total", str(e))] >= nbytes > 0
 
 
+# -- the device write -------------------------------------------------------------
+
+
+def device_produced_job(manager, shuffle_id, mappers, reducers, block_bytes=900):
+    """``device_job`` with each map task's output handed over as ONE packed
+    device array; returns the bytes written."""
+    import jax
+
+    lane = 128 // 4
+    rows = -(-block_bytes // 128)
+    manager.register_shuffle(shuffle_id, mappers, reducers)
+    owners = manager.cluster.meta(shuffle_id).map_owner
+    for m in range(mappers):
+        host = np.zeros((reducers * rows, lane), dtype=np.int32)
+        flat = host.reshape(-1).view(np.uint8)
+        for r in range(reducers):
+            flat[r * rows * 128 : r * rows * 128 + block_bytes] = (m * reducers + r) % 251
+        writer = manager.get_writer(shuffle_id, m)
+        packed = jax.device_put(host, manager.cluster.transport(owners[m]).device)
+        writer.write_partitions_device(packed, list(range(reducers)), [block_bytes] * reducers)
+        writer.commit_all_partitions()
+    manager.run_exchange(shuffle_id)
+    return mappers * reducers * block_bytes
+
+
+@pytest.fixture
+def device_producer():
+    from sparkucx_tpu.shuffle.manager import TpuShuffleManager
+
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=2,
+                          keep_device_recv=True, host_recv_mode="device", device_staging=True)
+    with TpuShuffleManager(conf, num_executors=2) as manager:
+        yield manager
+
+
+@pytest.mark.parametrize("full", [True, False], ids=["enabled", "recording-alone"])
+def test_device_stage_span_once_a_dispatch_and_no_seal_put(device_producer, tracer, full):
+    """One ``store.device_stage`` a map task (a dispatch), with the dispatch's
+    blocks and true bytes, whatever the number of blocks; the seal has nothing
+    to put.  The same under the flight recorder alone."""
+    if full:
+        tracer.enable()
+    assert tracer.recording
+    tracer.clear()
+    mappers, reducers = 6, 5
+    sid = 7300 + full
+    written = device_produced_job(device_producer, sid, mappers, reducers)
+    stages = [e for e in spans(tracer, "store.device_stage") if e["args"]["shuffle_id"] == sid]
+    assert len(stages) == mappers
+    assert all(e["args"]["blocks"] == reducers and e["args"]["bytes"] == reducers * 900 for e in stages)
+    assert collections.Counter(e["args"]["executor"] for e in stages) == {0: 3, 1: 3}
+    assert not [e for e in spans(tracer, "store.seal_put", "store.rollover") if e["args"]["shuffle_id"] == sid]
+    assert [e for e in spans(tracer, "exchange.seal") if e["args"]["shuffle_id"] == sid]
+    rows = family(device_producer.cluster.metrics_text(), "store")
+    for e in ("0", "1"):
+        assert rows[("scatter_dispatches_total", e)] == mappers // 2
+        assert rows[("device_staged_blocks_total", e)] == reducers * mappers // 2
+        assert rows[("device_staged_bytes_total", e)] == rows[("staged_bytes_total", e)] == written // 2
+        assert rows[("device_stage_ns_total", e)] > 0 and rows[("copy_ns_total", e)] == 0
+
+
+def test_a_host_staged_job_counts_no_device_stage(device_manager, tracer):
+    tracer.enable()
+    device_job(device_manager, 0, mappers=4, reducers=4)
+    assert not spans(tracer, "store.device_stage")
+    rows = family(device_manager.cluster.metrics_text(), "store")
+    assert all(rows[(name, e)] == 0 for e in ("0", "1") for name in (
+        "scatter_dispatches_total", "device_staged_blocks_total", "device_staged_bytes_total",
+        "device_stage_ns_total"))
+
+
 # -- names on the device ----------------------------------------------------
 
 
@@ -560,3 +631,42 @@ def test_compiled_for_the_chip_the_exchange_keeps_its_names(v5e, chips, impl, mo
     assert f"exchange_{impl}/" in re.search(r'op_name="([^"]*)"', line).group(1)
     if impl == "local":
         assert 'custom_call_target="tpu_custom_call"' in line and "block_gather/block_gather_dma" in line
+
+
+def test_compiled_for_the_chip_the_block_scatter_appends_in_place(v5e):
+    """The device write's one dispatch at the HBM-held configuration's size —
+    a (3, 256) plan, a 245,760-row packed task, the 4 GiB staging array — for
+    the v5e: the module name a device trace shows it under, the kernel's
+    name on its custom call, the scope, and the staging array aliased to the
+    result with nothing allocated beside it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from sparkucx_tpu.ops.pallas_kernels import build_block_scatter
+
+    rows = (4 << 30) // 512
+    scatter = build_block_scatter(256, rows, impl="dma", max_block_rows=2048)
+
+    def block_scatter(plan, src, dst):  # HbmBlockStore._scatter_fn's wrapper
+        return scatter(plan[0], plan[1], plan[2], src, dst)
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        compiled = jax.jit(block_scatter, donate_argnums=(2,)).lower(
+            jax.ShapeDtypeStruct((3, 256), jnp.int32, sharding=one),
+            jax.ShapeDtypeStruct((245760, 128), jnp.int32, sharding=one),
+            jax.ShapeDtypeStruct((rows, 128), jnp.int32, sharding=one),
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_block_scatter,")
+    [line] = [l for l in text.splitlines() if l.lstrip().startswith(("%block_scatter_dma.", "ROOT %block_scatter_dma."))]
+    assert 'custom_call_target="tpu_custom_call"' in line
+    assert "block_scatter/block_scatter_dma" in re.search(r'op_name="([^"]*)"', line).group(1)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == memory.output_size_in_bytes == 4 << 30
+    assert memory.temp_size_in_bytes == 0
