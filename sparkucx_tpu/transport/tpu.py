@@ -161,6 +161,8 @@ class TpuShuffleCluster:
         self.planner = make_planner(self.conf)
         self._meta: Dict[int, _ShuffleMeta] = {}  #: guarded by self._lock
         self._exchange_cache: Dict[Tuple[int, int, str], Callable] = {}  #: guarded by self._lock
+        #: jitted received-prefix slices by bucket rows (_prefix_fn)
+        self._prefix_cache: Dict[int, Callable] = {}  #: guarded by self._lock
         self._lock = threading.RLock()
         #: aggregate per-stage pipeline/exchange timings (occupancy view)
         self.stats = StatsAggregator()
@@ -411,6 +413,39 @@ class TpuShuffleCluster:
                 self._exchange_cache[key] = fn
         return fn
 
+    def _received_prefix(self, shard, used_rows: int):
+        """What of one received shard crosses back to the host: ``None`` when
+        nothing was received (no D2H at all), the shard itself when the
+        bucket of its used rows reaches the shard (no extra device op), else
+        the device-side slice ``shard[:bucket]``.  The exchange leaves a
+        tight sender-major prefix of ``used_rows`` in the shard and no reader
+        addresses a row past it; ``used_rows`` is the size matrix's column
+        sum, host metadata known at submit.  The bucket is the next power of
+        two of rows and at least a sixteenth of the shard: below that a
+        transfer is already short, and a shorter one would only buy another
+        executable — so a shard shape has four slices at most, and shuffles
+        of wandering size share them."""
+        if used_rows <= 0:
+            return None
+        shard_rows = int(shard.shape[0])
+        bucket = max(1 << (used_rows - 1).bit_length(), shard_rows >> 4)
+        if bucket >= shard_rows:
+            return shard
+        return self._prefix_fn(bucket)(shard)
+
+    def _prefix_fn(self, bucket: int):
+        """The jitted slice ``shard[:bucket]`` (``jit_recv_prefix``), one a
+        bucket; its executables are cached by shape and device inside it."""
+        with self._lock:
+            fn = self._prefix_cache.get(bucket)
+            if fn is None:
+
+                def recv_prefix(shard):
+                    return jax.lax.slice_in_dim(shard, 0, bucket)
+
+                fn = self._prefix_cache[bucket] = jax.jit(recv_prefix)
+        return fn
+
     def run_exchange(self, shuffle_id: int) -> None:
         """Seal every executor's staging for this shuffle and run ONE collective
         superstep.  After this, every block is resident on its consuming
@@ -627,21 +662,49 @@ class TpuShuffleCluster:
             # fresh wrappers per call — reusing these keeps the async-copy
             # cache) and start their D2H now, while later sub-rounds keep the
             # device busy; the drain's np.asarray then observes completion
-            # instead of initiating the copy.
+            # instead of initiating the copy.  What crosses is each shard's
+            # received prefix (_received_prefix), and nothing for a consumer
+            # that received nothing: the column sums of the size matrix say
+            # how long it is, with no wait for recv_sizes.  Counters
+            # ``exchange.d2h``: ``shard_bytes`` (the whole shards),
+            # ``moved_bytes`` (what was pinned for the host),
+            # ``skipped_shards`` / ``sliced_shards``.
             shard_by_device = {s.device: s.data for s in recv.addressable_shards}
+            host_src = None
             if mode != "device":
-                for a in shard_by_device.values():
-                    a.copy_to_host_async()
+                shards = [shard_by_device[d] for d in devices]
+                used = sub_sizes.sum(axis=0)
+                host_src = [self._received_prefix(a, int(u)) for a, u in zip(shards, used)]
+                for a in host_src:
+                    if a is not None:
+                        a.copy_to_host_async()
+                self.stats.record_counters(
+                    "exchange.d2h",
+                    shard_bytes=sum(a.nbytes for a in shards),
+                    moved_bytes=sum(a.nbytes for a in host_src if a is not None),
+                    skipped_shards=sum(a is None for a in host_src),
+                    sliced_shards=sum(
+                        a is not None and a is not whole for a, whole in zip(host_src, shards)
+                    ),
+                )
             recv_sizes.copy_to_host_async()
-            return recv, recv_sizes, shard_by_device
+            return recv, recv_sizes, shard_by_device, host_src
 
         def _drain_chunk(rnd, chunk, nchunks, ticket):
             """Complete one sub-round host-side (drain-worker thread at
             depth > 1).  Single-shot rounds materialize their whole receive
             state here — including the streamed memmap spill — so host RSS
             keeps the historical one-in-flight-window bound."""
-            recv, recv_sizes, shard_by_device = ticket
+            recv, recv_sizes, shard_by_device, host_src = ticket
             sizes_host = np.asarray(recv_sizes)
+
+            def host_part(j):
+                # the bytes _submit pinned: a received prefix, or nothing
+                a = host_src[j]
+                if a is None:
+                    return np.empty(0, dtype=np.uint8)
+                return np.asarray(a).reshape(-1).view(np.uint8)
+
             if mode == "device":
                 # No host copy at all: fetches slice the retained HBM shard
                 # and D2H only the requested block (locate_received_block).
@@ -654,21 +717,17 @@ class TpuShuffleCluster:
                 # rounds the shuffle spills.
                 with span("exchange.d2h_memmap", shuffle_id=shuffle_id, round=rnd):
                     host_parts = self._memmap_round(
-                        meta,
-                        rnd,
-                        (
-                            np.asarray(shard_by_device[devices[j]]).reshape(-1).view(np.uint8)
-                            for j in range(n)
-                        ),
+                        meta, rnd, (host_part(j) for j in range(n))
                     )
             else:
-                # One D2H per executor shard; fetches (or the round splice)
-                # then slice host memory.
-                with span("exchange.d2h", shuffle_id=shuffle_id, round=rnd, chunk=chunk):
-                    host_parts = [
-                        np.asarray(shard_by_device[devices[j]]).reshape(-1).view(np.uint8)
-                        for j in range(n)
-                    ]
+                # One D2H per executor shard that received rows; fetches (or
+                # the round splice) then slice host memory.
+                with span(
+                    "exchange.d2h",
+                    shuffle_id=shuffle_id, round=rnd, chunk=chunk,
+                    bytes=sum(a.nbytes for a in host_src if a is not None),
+                ):
+                    host_parts = [host_part(j) for j in range(n)]
             dev_parts = (
                 [shard_by_device[devices[j]] for j in range(n)] if keep_device else None
             )
@@ -676,10 +735,11 @@ class TpuShuffleCluster:
 
         def _finish_round(rnd, nchunks, parts):
             """Emit one staging round's receive state: a single-shot round
-            passes its only chunk through (whole padded shards, the
-            historical layout); a chunked round splices its sub-round shards
-            back into the exact single-shot layout (bit-equality pinned in
-            tests/test_skew.py and tests/test_planner.py)."""
+            passes its only chunk through (each shard's received prefix,
+            bucketed: the historical layout up to where a reader looks); a
+            chunked round splices its sub-round shards back into the exact
+            single-shot layout (bit-equality pinned in tests/test_skew.py and
+            tests/test_planner.py)."""
             if plan.single_shot:
                 sizes_host, shards, dev_shards = parts[0]
                 used = int(sizes_host.sum())
@@ -947,10 +1007,11 @@ class TpuShuffleCluster:
                             continue
                         used = int(sizes_host[q].sum())
                         if used:
+                            prefix = self._received_prefix(
+                                shard_by_device[sub_devices[q]], used
+                            )
                             consumer_parts[c].append(
-                                np.asarray(shard_by_device[sub_devices[q]])[:used]
-                                .reshape(-1)
-                                .view(np.uint8)
+                                np.asarray(prefix)[:used].reshape(-1).view(np.uint8)
                             )
             assembled = [
                 np.concatenate(parts) if parts else np.empty(0, dtype=np.uint8)
@@ -1120,6 +1181,14 @@ class TpuShuffleCluster:
             return block_rows.reshape(-1).view(np.uint8)[:length], length
         shard = meta.recv_shards[rnd][consumer]
         start = src_row * self.row_bytes
+        if start + length > shard.size:
+            # the host part is the shard's received prefix: a block past its
+            # end was never received, and a short slice would pass for it
+            raise TransportError(
+                f"block ({shuffle_id},{map_id},{reduce_id}) at bytes "
+                f"[{start}, {start + length}) lies past the {shard.size} bytes "
+                f"executor {consumer} received in round {rnd}"
+            )
         return shard[start : start + length], length
 
     def _locate_rows(

@@ -670,3 +670,31 @@ def test_compiled_for_the_chip_the_block_scatter_appends_in_place(v5e):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == memory.output_size_in_bytes == 4 << 30
     assert memory.temp_size_in_bytes == 0
+
+
+def test_compiled_for_the_chip_the_received_prefix_is_one_named_slice(v5e):
+    """The D2H's device-side slice at the small job's size — a 16 MiB prefix
+    of a 64 MiB received shard — for the v5e: the module name a device trace
+    shows it under, one slice and nothing allocated beside its result."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from sparkucx_tpu.config import TpuShuffleConf
+    from sparkucx_tpu.transport.tpu import TpuShuffleCluster
+
+    cluster = TpuShuffleCluster(TpuShuffleConf(num_executors=1), num_executors=1)
+    one = SingleDeviceSharding(v5e.devices[0])
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        compiled = cluster._prefix_fn((16 << 20) // 512).lower(
+            jax.ShapeDtypeStruct(((64 << 20) // 512, 128), jnp.int32, sharding=one)
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_recv_prefix,")
+    assert len([l for l in text.splitlines() if " slice(" in l]) == 1
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == 16 << 20 and memory.temp_size_in_bytes == 0
