@@ -166,26 +166,26 @@ class Traffic(shipped.Traffic):
 '''
 
 
-def test_a_new_cell_needs_only_new_files_and_entries(tmp_path):
-    """A throw-away configuration, traffic mix with a driver and an entry kind
-    of its own, per-layer metric and cell, added beside a copy of the benchmark
-    without editing a file of it."""
-    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = {
-        p: p.read_bytes() for p in (tmp_path / "benchmark").rglob("*") if p.is_file()
-    }
-    (tmp_path / "benchmark" / "configs" / "groupbytest-4k.json").write_text(json.dumps({
+def a_copy_with_a_throwaway_cell(root, also_copy=()):
+    """A copy of the benchmark under ``root`` with a throw-away configuration,
+    traffic mix with a driver and an entry kind of its own, per-layer metric
+    and cell added beside it, each **appended** to its list of a
+    ``BENCHMARK.json`` there, as a later PR adds them.  Returns that
+    ``BENCHMARK.json`` and the bytes of every copied file as they were."""
+    for path in ("benchmark", *also_copy):
+        shutil.copytree(os.path.join(ROOT, path), root / path, ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    (root / "benchmark" / "configs" / "groupbytest-4k.json").write_text(json.dumps({
         "source": "a throw-away example", "reference": "groupby", "mappers": 3,
         "pairs_per_mapper": 30, "value_bytes": 4000, "reducers": 5, "keys": "uniform-int31",
         "conf": {"staging_capacity_per_executor": 1 << 20},  # conf overrides are data of the file
         "reduced": {}, "assumed": [], "guarantees": "as the others", "rehearse": {},
     }))
-    (tmp_path / "benchmark" / "traffic" / "manager-pieces.json").write_text(json.dumps({
+    (root / "benchmark" / "traffic" / "manager-pieces.json").write_text(json.dumps({
         "driver": "manager-pieces", "pieces": 3,
     }))
-    (tmp_path / "benchmark" / "traffic" / "manager-pieces.py").write_text(THROWAWAY_DRIVER)
-    (tmp_path / "benchmark" / "layer_metrics" / "jobs_in_window.py").write_text(
+    (root / "benchmark" / "traffic" / "manager-pieces.py").write_text(THROWAWAY_DRIVER)
+    (root / "benchmark" / "layer_metrics" / "jobs_in_window.py").write_text(
         '"""A throw-away reader."""\n\n\ndef read(run):\n    return len(run.jobs)\n'
     )
     bench = json.loads(json.dumps(BENCH))
@@ -196,7 +196,15 @@ def test_a_new_cell_needs_only_new_files_and_entries(tmp_path):
     bench["per_layer"].append({"name": "jobs_in_window", "unit": "jobs", "better": "higher",
                                "source": "program_counter", "layer": "entry points",
                                "moves": "shuffle_throughput", "workloads": ["gbt4k-pieces-1chip"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return bench, before
+
+
+def test_a_new_cell_needs_only_new_files_and_entries(tmp_path):
+    """A throw-away configuration, traffic mix with a driver and an entry kind
+    of its own, per-layer metric and cell, added beside a copy of the benchmark
+    without editing a file of it."""
+    _, before = a_copy_with_a_throwaway_cell(tmp_path)
     env = dict(os.environ, PYTHONPATH=ROOT, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"))
     out = subprocess.run(
         [sys.executable, str(tmp_path / "benchmark" / "run.py"), "--workload", "gbt4k-pieces-1chip",
@@ -217,3 +225,50 @@ def test_a_new_cell_needs_only_new_files_and_entries(tmp_path):
     assert set(rounds["rounds_per_job"]) == {1}
     after = {p: p.read_bytes() for p in before}
     assert after == before
+
+
+#: the tests of this directory that run a job (a process of ``run.py``): the
+#: guard below leaves them out, they hold no declaration to a place.  A new
+#: test that runs a job is named here, or it runs inside the guard as well.
+RUN_A_JOB = ["test_rehearsal", "test_without_a_chip_there_is_no_result", "test_an_unknown_cell_is_refused",
+             "test_a_new_cell_needs_only_new_files_and_entries",
+             "test_the_rehearsal_prints_the_devproduce_line_and_the_write_metrics",
+             "test_a_lost_block_comes_out_as_not_correct"]
+GUARD = "test_appended_entries_fail_no_test_that_reads_the_declarations"
+#: what the guard is there to catch, planted beside the copied tests: it must
+#: be the one test that fails there
+PINNED = '''from benchmark.cells import load_benchmark
+
+
+def test_that_pins_the_last_cell():
+    assert load_benchmark()["workloads"][-1]["name"] == {last!r}
+'''
+
+
+def test_appended_entries_fail_no_test_that_reads_the_declarations(tmp_path):
+    """The guard: what ``test_a_new_cell_needs_only_new_files_and_entries``
+    builds, and a second throw-away metric that lists every cell, appended in
+    a copy that holds these tests too; every test there that runs no job
+    passes.  A test that holds ``BENCHMARK.json`` to a place (the last cell,
+    the tail of ``per_layer``, a ``workloads`` list equal to a fixed one)
+    closes the benchmark to every PR that may add and not edit, and fails
+    here in the PR that writes it."""
+    bench, _ = a_copy_with_a_throwaway_cell(tmp_path, also_copy=["tests/benchmark"])
+    shutil.copy(tmp_path / "benchmark" / "layer_metrics" / "jobs_in_window.py",
+                tmp_path / "benchmark" / "layer_metrics" / "jobs_in_any_window.py")
+    bench["per_layer"].append({"name": "jobs_in_any_window", "unit": "jobs", "better": "higher",
+                               "source": "program_counter", "layer": "entry points", "moves": "shuffle_throughput",
+                               "workloads": [w["name"] for w in bench["workloads"]]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    (tmp_path / "tests" / "benchmark" / "test_planted_pin.py").write_text(PINNED.format(last=CELLS[-1]))
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join([str(tmp_path), ROOT]), JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"))
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", "tests/benchmark", "-q", "-p", "no:cacheprovider", "-p", "no:xdist",
+         "-p", "no:randomly", "-k", "not (" + " or ".join(RUN_A_JOB + [GUARD]) + ")"],
+        capture_output=True, text=True, env=env, cwd=tmp_path, timeout=600,
+    )
+    summary = out.stdout.strip().splitlines()[-1]
+    failed = [line for line in out.stdout.splitlines() if line.startswith("FAILED ")]
+    assert len(failed) == 1 and "test_that_pins_the_last_cell" in failed[0], out.stdout[-4000:] + out.stderr[-2000:]
+    assert out.returncode == 1 and "1 failed" in summary and " passed" in summary and "error" not in summary
