@@ -19,7 +19,7 @@ CreateShuffle       16  header: json {shuffle_id, num_mappers, num_reducers}
 OpenMapWriter       17  header: json {shuffle_id, map_id} -> writer handle
 WritePartition      18  header: json {writer, reduce_id}; body: bytes (repeat ok)
 CommitMap           19  header: json {writer} -> partition lengths
-RunExchange         20  header: json {shuffle_id}
+RunExchange         20  header: json {shuffle_id} (optional: see the stage boundary)
 FetchBlock           3  AM FetchBlockReq (batched form, peer.py framing)
 RemoveShuffle       21  header: json {shuffle_id}
 Stats               22  header: json {shuffle_id}
@@ -28,12 +28,32 @@ Shutdown            23  —
 
 Every control op gets an ``Ack`` (id 24) with ``{ok, error?, ...result}``.
 
+The stage boundary: a reduce task speaks ``FetchBlock`` and nothing else (the
+JVM shim's ``TpuShuffleReader`` calls ``fetchBlocks`` only; of the classes
+under ``jvm/src`` only ``InteropCheck`` ever sends ``RunExchange``), and the
+barrier between the map and the reduce stage is Spark's scheduler's, which the
+wire never sees.  So the daemon runs the exchange itself: the first
+``FetchBlock`` of a shuffle that is registered, not yet exchanged and whose
+maps have **all** committed runs it, once a shuffle; fetches of that shuffle
+that arrive meanwhile wait for it and are then served.  With a map still
+uncommitted a fetch answers as ever (size -1, nothing started).  An exchange
+that fails fails every fetch that waited on it and every later fetch of that
+shuffle (their blocks come back ``None``); the connections and the daemon
+live on.  ``RunExchange`` is still served as it always was — an engine that
+knows its own barrier may send it — and shares the guard: racing a first
+fetch, there is one exchange.
+
 Telemetry: every served frame is counted per op (``frames``, ``body_bytes``,
 ``serve_ns`` from the frame header's arrival to the reply sent, ``ack_ns`` the
 reply's send, from its start to the frame's end; always on) — the ``daemon``
 family of the cluster's metrics registry.  A span ``daemon.<op>`` over the
 ``serve_ns`` interval is recorded only while full tracing is on
-(``TRACER.enabled``), not under the flight recorder's ``recording``.
+(``TRACER.enabled``), not under the flight recorder's ``recording``.  The
+stage boundary has plain rows of the same family (``stage_stats()``:
+``stage_exchanges``, ``stage_waiters``, ``stage_wait_ns``, the gauge
+``connections`` and ``connections_peak``) and two spans recorded like
+``exchange.superstep``: ``daemon.stage_exchange`` (once a shuffle) and
+``daemon.stage_wait`` (once a fetch that waited).
 """
 
 from __future__ import annotations
@@ -41,6 +61,7 @@ from __future__ import annotations
 import json
 import socket
 import threading
+from contextlib import nullcontext
 from time import perf_counter_ns
 from typing import Dict, List, Optional, Tuple
 
@@ -54,7 +75,8 @@ from sparkucx_tpu.core.definitions import (
     pack_frame,
     pack_frame_prefix,
 )
-from sparkucx_tpu.obs.metrics import labelled_counter_provider
+from sparkucx_tpu.core.operation import TransportError
+from sparkucx_tpu.obs.metrics import MetricSample, labelled_counter_provider, sample
 from sparkucx_tpu.service.reactor import Reactor
 from sparkucx_tpu.shuffle.manager import TpuShuffleManager
 from sparkucx_tpu.transport.peer import (
@@ -65,9 +87,15 @@ from sparkucx_tpu.transport.peer import (
     recv_frame,
     unpack_batch_fetch_req,
 )
-from sparkucx_tpu.utils.trace import TRACER
+from sparkucx_tpu.utils.logging import get_logger
+from sparkucx_tpu.utils.trace import TRACER, span
 import struct
 
+logger = get_logger("shuffle.daemon")
+#: the longest a frame waits for another thread's exchange of its shuffle: a
+#: serving thread (one of a bounded pool under the reactor) is never parked
+#: for good behind an exchange that hangs; its fetch then answers size -1
+_STAGE_WAIT_S = 600.0
 _TAG = struct.Struct("<Q")
 _COUNT = struct.Struct("<I")
 _SIZE = struct.Struct("<q")
@@ -129,6 +157,17 @@ def _read_frame_rest(sock, op: int, hlen: int, blen: int) -> Optional[Tuple[int,
     return op, meta, body
 
 
+class _StageExchange:
+    """One shuffle's exchange at the stage boundary: ``done`` is set when the
+    thread that claimed it has finished, ``error`` is what it raised."""
+
+    __slots__ = ("done", "error")
+
+    def __init__(self) -> None:
+        self.done = threading.Event()
+        self.error: Optional[BaseException] = None
+
+
 class ShuffleDaemon:
     """Hosts a TpuShuffleManager behind the wire protocol."""
 
@@ -156,12 +195,22 @@ class ShuffleDaemon:
         #: per-op frame counters, always on: op id -> [frames, body_bytes,
         #: serve_ns, ack_ns]; the ``daemon`` family of the cluster's registry
         self._op_stats: Dict[int, List[int]] = {}  #: guarded by self._lock
+        #: shuffle id -> its exchange at the stage boundary, from the frame
+        #: that claims it to ``RemoveShuffle``: the per-shuffle guard
+        self._stages: Dict[int, _StageExchange] = {}  #: guarded by self._lock
+        #: always on: exchanges a fetch started, fetches that waited for
+        #: another thread's exchange and for how long, connections open now
+        #: and the most that ever were
+        self._stage_stats: Dict[str, int] = dict.fromkeys(
+            ("stage_exchanges", "stage_waiters", "stage_wait_ns", "connections", "connections_peak"), 0
+        )  #: guarded by self._lock
         self._lock = threading.Lock()
         #: ``t_ack``: when the calling thread last began to send a reply
         self._tls = threading.local()
         self.manager.cluster.metrics.register(
             "daemon", labelled_counter_provider("daemon", "op", self.op_stats)
         )
+        self.manager.cluster.metrics.register("daemon.stage", self._stage_samples)
         # Serving plane: thread-per-connection by default; with
         # server.workers set (or tenants.enabled) the shared reactor holds
         # every idle client in one selector and serves frames from a bounded
@@ -189,6 +238,7 @@ class ShuffleDaemon:
                 apply_wire_sockopts(conn, self.conf)
             except OSError:
                 return
+            self._connection_opened()
             threading.Thread(target=self._serve, args=(conn,), daemon=True).start()
 
     def _on_accept(self, conn: socket.socket) -> None:
@@ -196,7 +246,18 @@ class ShuffleDaemon:
         non-blocking under the selector), then park the connection."""
         apply_wire_sockopts(conn, self.conf)
         conn.setblocking(True)
-        self._reactor.add_connection(conn, self._serve_step)
+        self._connection_opened()
+        self._reactor.add_connection(conn, self._serve_step, self._connection_closed)
+
+    def _connection_opened(self) -> None:
+        with self._lock:
+            stats = self._stage_stats
+            stats["connections"] += 1
+            stats["connections_peak"] = max(stats["connections_peak"], stats["connections"])
+
+    def _connection_closed(self, _conn=None) -> None:
+        with self._lock:
+            self._stage_stats["connections"] -= 1
 
     def op_stats(self) -> List[Dict[str, object]]:
         """One row of counters per op served so far (the ``daemon`` family)."""
@@ -209,6 +270,19 @@ class ShuffleDaemon:
         return [
             {"op": name, "frames": f, "body_bytes": b, "serve_ns": s, "ack_ns": a}
             for name, (f, b, s, a) in sorted(rows.items())
+        ]
+
+    def stage_stats(self) -> Dict[str, int]:
+        """The stage boundary's counters and the connection gauge."""
+        with self._lock:
+            return dict(self._stage_stats)
+
+    def _stage_samples(self) -> List[MetricSample]:
+        stats = self.stage_stats()
+        return [
+            sample("daemon", "connections", stats.pop("connections")),
+            sample("daemon", "connections_peak", stats.pop("connections_peak"), kind="counter"),
+            *(sample("daemon", name + "_total", value, kind="counter") for name, value in stats.items()),
         ]
 
     def _ack(self, conn, ok: bool, body: bytes = b"", **extra) -> None:
@@ -278,7 +352,69 @@ class ShuffleDaemon:
             while self._serve_step(conn):
                 pass
         finally:
+            self._connection_closed()
             conn.close()
+
+    def _exchange_once(self, shuffle_id: int, explicit: bool) -> None:
+        """The per-shuffle guard round the exchange.  The first frame to ask
+        claims it and runs it; a frame that finds it running waits and shares
+        its outcome.  ``explicit`` is a ``RunExchange`` frame, which behaves
+        as before there was a guard: it always tries (a second one is told
+        "already exchanged"; one sent too early can be sent again), and a
+        failure of its own leaves no trace.  A failure of the exchange a
+        fetch started stays, and fails the later fetches of the shuffle too:
+        two hundred reduce tasks do not each seal and fail again."""
+        with self._lock:
+            stage = self._stages.get(shuffle_id)
+            mine = stage is None or (explicit and stage.done.is_set())
+            if mine:
+                stage = self._stages[shuffle_id] = _StageExchange()
+        if mine:
+            try:
+                with nullcontext() if explicit else span("daemon.stage_exchange", shuffle_id=shuffle_id):
+                    self.manager.run_exchange(shuffle_id)
+            except BaseException as e:
+                stage.error = e
+                raise
+            finally:
+                with self._lock:
+                    if explicit:
+                        if stage.error is not None and self._stages.get(shuffle_id) is stage:
+                            del self._stages[shuffle_id]
+                    elif stage.error is None:
+                        self._stage_stats["stage_exchanges"] += 1
+                stage.done.set()
+            return
+        if not stage.done.is_set():
+            t0 = perf_counter_ns()
+            with span("daemon.stage_wait", shuffle_id=shuffle_id):
+                finished = stage.done.wait(_STAGE_WAIT_S)
+            with self._lock:
+                self._stage_stats["stage_waiters"] += 1
+                self._stage_stats["stage_wait_ns"] += perf_counter_ns() - t0
+            if not finished:
+                raise TransportError(
+                    f"the exchange of shuffle {shuffle_id} is still running after {_STAGE_WAIT_S:.0f} s"
+                )
+        if stage.error is not None:
+            raise TransportError(f"the exchange of shuffle {shuffle_id} failed: {stage.error}")
+
+    def _exchange_at_first_fetch(self, shuffle_id: int) -> None:
+        """Before a fetch locates its blocks: run or await the shuffle's
+        exchange if this is the stage boundary.  Raises nothing: a shuffle
+        that is unknown, has a map uncommitted or whose exchange failed is
+        answered block by block, as a fetch always was (size -1)."""
+        mgr = self.manager
+        try:
+            boundary = not mgr.cluster.meta(shuffle_id).exchanged and mgr.exchange_ready(shuffle_id)
+        except (KeyError, TransportError):  # no such shuffle
+            return
+        if not boundary:
+            return
+        try:
+            self._exchange_once(shuffle_id, explicit=False)
+        except Exception as e:  # the frame's boundary: its blocks answer -1
+            logger.warning("fetch of shuffle %d finds no exchange: %s: %s", shuffle_id, type(e).__name__, e)
 
     def _dispatch(self, conn, op: int, meta: dict, body: bytes) -> None:
         mgr = self.manager
@@ -325,10 +461,13 @@ class ShuffleDaemon:
             lengths = writer.commit_all_partitions()
             self._ack(conn, True, body=np.asarray(lengths, dtype="<i8").tobytes())
         elif op == DaemonOp.RUN_EXCHANGE:
-            mgr.run_exchange(int(meta["shuffle_id"]))
+            self._exchange_once(int(meta["shuffle_id"]), explicit=True)
             self._ack(conn, True)
         elif op == DaemonOp.REMOVE_SHUFFLE:
-            mgr.unregister_shuffle(int(meta["shuffle_id"]))
+            sid = int(meta["shuffle_id"])
+            mgr.unregister_shuffle(sid)
+            with self._lock:
+                self._stages.pop(sid, None)
             self._ack(conn, True)
         elif op == DaemonOp.STATS:
             sid = int(meta["shuffle_id"])
@@ -364,6 +503,8 @@ class ShuffleDaemon:
         # vectored sendmsg over the views — the wire bytes are identical to
         # the historical [sizes | data...] frame, but no monolithic reply
         # body is ever assembled (and no per-block bytes() copies are paid).
+        for shuffle_id in {bid.shuffle_id for bid in bids}:
+            self._exchange_at_first_fetch(shuffle_id)
         parts, sizes = [], []
         for bid in bids:
             try:

@@ -284,7 +284,9 @@ class MapWriter:
     reference's 8 KB pinned write buffer, NvkvHandler.scala:26,213-242) and the
     region allocate + copy + table record happen atomically at close — so any
     number of map tasks can write concurrently, and a staging-round rollover can
-    never interleave with a half-written partition.
+    never interleave with a half-written partition.  Concurrent writers take
+    turns at the store's one lock for the copy and the rollover; what each
+    waited there is counted (``lock_wait_ns``).
     """
 
     def __init__(
@@ -301,6 +303,10 @@ class MapWriter:
         #: the staging copy in ``close_partition``); joins the store's
         #: ``copy_ns`` counter at ``commit``
         self._copy_ns = 0
+        #: ns this writer's ``close_partition`` calls waited for the store's
+        #: lock (other writers' copies and rollovers); joins ``lock_wait_ns``
+        #: at ``commit``
+        self._lock_wait_ns = 0
         self._counted = False  # this writer's blocks are in the store's counters
         #: First-commit-wins task-retry semantics: when a successful commit for
         #: this map already exists, the retry attempt's writes are swallowed and
@@ -350,7 +356,9 @@ class MapWriter:
             # watermark gate before taking the lock: a shed write fails typed
             # (retryable ResourceExhaustedError) with nothing allocated
             self._store.check_memory_pressure("close_partition", padded)
+            t_lock = perf_counter_ns()
             with self._store._lock:
+                self._lock_wait_ns += perf_counter_ns() - t_lock
                 if st.sealed:
                     # a writer opened before the seal: the sealed rounds are
                     # immutable (zero-copy views, the runtime's H2D source),
@@ -529,7 +537,8 @@ class MapWriter:
                 counters["staged_blocks"] += blocks
                 counters["staged_bytes"] += sum(length for _, length in parts)
                 counters["copy_ns"] += self._copy_ns
-        self._copy_ns = 0
+                counters["lock_wait_ns"] += self._lock_wait_ns
+        self._copy_ns = self._lock_wait_ns = 0
         return MapperInfo(
             st.shuffle_id, self.map_id, tuple(parts),
             tuple(rounds) if any(rounds) else None,
@@ -708,7 +717,9 @@ class HbmBlockStore:
         self._spill_bytes = 0  #: guarded by self._lock
         #: Map-side write counters (the ``store`` metrics family): plain ints,
         #: always on, bumped once a map task at its commit (``staged_*`` from
-        #: its block table, ``copy_ns`` from the clock round each block's copy)
+        #: its block table, ``copy_ns`` from the clock round each block's copy,
+        #: ``lock_wait_ns`` from the clock round each block's wait for this
+        #: store's lock: what other writers' copies and rollovers cost it)
         #: and once a staging round (``rollovers``, ``spilled_bytes``, ``*_ns``;
         #: ``ram_rounds``: rollovers whose round stayed in RAM;
         #: ``recycled_rounds``: host rollovers that spilled and kept their
@@ -735,7 +746,7 @@ class HbmBlockStore:
              "recycled_rounds", "zeroed_bytes", "ram_rounds", "pool_hits",
              "pool_misses", "pool_dropped_busy", "pool_held_bytes",
              "device_staged_blocks", "device_staged_bytes", "scatter_dispatches",
-             "device_stage_ns"), 0
+             "device_stage_ns", "lock_wait_ns"), 0
         )
         #: RAM tier of completed rounds (``_rollover``): capacity bytes of the
         #: RAM rounds live shuffles hold plus the free list never exceed
