@@ -41,6 +41,16 @@ from __future__ import annotations
 #:   map-side write counters (the ``store`` metrics family) inside the
 #:   store-lock section that records the commit.  Reviewed with the
 #:   layer-spans tracing PR.
+#: - hbm_store.py ``._release_tenant`` / ``._await_drained`` /
+#:   ``._receive_ended``: the same friend, for a partition received in place
+#:   (``MapWriter.reserve`` / ``end_receive``).  The reservation is made and
+#:   ended inside the store-lock section that allocates the region: it holds
+#:   back while a seal, a spill or a removal drains the shuffle's receives,
+#:   gives the round's in-flight count back on the store's condition, and
+#:   returns the tenant charge of an extent that is abandoned (the pair of
+#:   ``._charge_tenant`` above).  The count and the condition are the store's
+#:   own bookkeeping of who may touch a round's buffer, not writer API.
+#:   Reviewed with the receive-in-place PR.
 #: - service/tenants.py ``._gate``: ``Tenant`` is a same-file data holder of
 #:   its ``TenantRegistry`` — the registry lazily creates the per-tenant
 #:   CreditGate under its own lock; exposing the slot publicly would invite
@@ -96,6 +106,9 @@ ALLOWLIST = {
     ("store/hbm_store.py", "private-access", "._lock"),
     ("store/hbm_store.py", "private-access", "._rollover"),  # also ._rollover_device
     ("store/hbm_store.py", "private-access", "._charge_tenant"),
+    ("store/hbm_store.py", "private-access", "._release_tenant"),
+    ("store/hbm_store.py", "private-access", "._await_drained"),
+    ("store/hbm_store.py", "private-access", "._receive_ended"),
     ("store/hbm_store.py", "private-access", "._stage_device"),
     ("store/hbm_store.py", "private-access", "._staging"),
     ("store/hbm_store.py", "private-access", "._write_stats"),
@@ -574,6 +587,11 @@ TIER_DOC_KEYS = (
 #:   exercises mid-stripe states deterministically.
 #: - ``_read_frame`` (shuffle/daemon.py): the daemon protocol tests speak
 #:   raw frames on a socket; the helper IS the framing contract under test.
+#: - ``_frame`` (shuffle/daemon.py): its other half — the receive-in-place
+#:   tests send a frame's bytes in pieces (a body that stalls or ends
+#:   half-way) and compare what ``DaemonClient`` put on the wire against the
+#:   joined frame; ``scripts/gen_shim_fixtures.py`` makes the JVM fixtures
+#:   from the same helper.
 #: - ``_estimate`` (shuffle/external.py): spill-size estimator unit tests;
 #:   the public path only exposes it through end-to-end sort memory use.
 #: - ``_ici_order`` (parallel/mesh.py): ring-order derivation pinned
@@ -584,6 +602,7 @@ TESTS_ALLOWLIST = {
     ("", "private-access", "private attribute access"),
     ("", "private-access", "private import: _StripeRx"),
     ("", "private-access", "private import: _read_frame"),
+    ("", "private-access", "private import: _frame"),
     ("", "private-access", "private import: _estimate"),
     ("", "private-access", "private import: _ici_order"),
     ("", "private-access", "private import: _free_port"),

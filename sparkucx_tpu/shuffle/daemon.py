@@ -43,6 +43,25 @@ live on.  ``RunExchange`` is still served as it always was — an engine that
 knows its own barrier may send it — and shares the guard: racing a first
 fetch, there is one exchange.
 
+The body of a ``WritePartition``: a written block's bytes are touched once on
+each side of the wire.  The client sends fixed header + JSON and the body as
+two buffers of one vectored ``sendmsg`` (never joined: no copy of the body).
+The daemon reads the fixed header and the JSON as for any op, resolves the
+writer's partition stream, has the store reserve the body's extent in the
+live staging round (``MapWriter.reserve``: every admission check, the tenant
+charge and the rollover happen there, under the store's lock, before a byte is
+read) and receives the socket straight into that extent (``recv_into``), outside every
+lock — no frame buffer, no ``bytes`` of it, no copy at ``close_partition``,
+which only records the block.  One path for every body size.  The receive runs
+under ``conf.wire_timeout_ms`` (the wait for the next frame's header stays
+unbounded): a body that stalls or whose sender dies ends that connection only
+and gives the round's in-flight count back, so the spill, seal or removal that
+waits for it (``inflight_wait_ns``) goes on; its extent is a hole no block
+names, and the uncommitted map's retry writes it again.  A frame refused
+before its body is read (unknown writer, sealed shuffle, a body larger than a
+region) is acked with the error after the body is dropped unread-into-memory:
+the connection stays in step.  The bytes on the wire are what they were.
+
 Telemetry: every served frame is counted per op (``frames``, ``body_bytes``,
 ``serve_ns`` from the frame header's arrival to the reply sent, ``ack_ns`` the
 reply's send, from its start to the frame's end; always on) — the ``daemon``
@@ -138,6 +157,46 @@ def _frame(op: int, header: dict, body: bytes = b"") -> bytes:
     return struct.pack("<IQQ", op, len(payload), len(body)) + payload + body
 
 
+def _recv_body(sock: socket.socket, view: memoryview, timeout_ms: int) -> None:
+    """Fill ``view`` from the socket: a frame's body straight into the place
+    the caller chose for it.  Mid-frame, so under ``conf.wire_timeout_ms``
+    (0 = none): a sender that stalls raises ``socket.timeout``, one that
+    closes ``ConnectionError`` — both ``OSError``, the connection's end.  The
+    socket waits for its next frame without a timeout, as before."""
+    n = len(view)
+    if not n:
+        return
+    try:  # what has arrived already (all of a small body): no wait, so no timeout to set
+        got = sock.recv_into(view, n, socket.MSG_DONTWAIT)
+        if not got:
+            raise ConnectionError(f"peer closed before a body of {n} B")
+    except BlockingIOError:
+        got = 0
+    if got == n:
+        return
+    if timeout_ms:
+        sock.settimeout(timeout_ms / 1000.0)
+    try:
+        while got < n:
+            r = sock.recv_into(view[got:])
+            if not r:
+                raise ConnectionError(f"peer closed mid-body with {got}/{n} B received")
+            got += r
+    finally:
+        if timeout_ms:
+            sock.settimeout(None)
+
+
+def _drop_body(sock: socket.socket, n: int, timeout_ms: int) -> None:
+    """Read ``n`` bytes of a refused frame off the socket and keep none:
+    the connection stays in step with its peer, nothing of ``n`` is allocated."""
+    scratch = memoryview(bytearray(min(n, 1 << 16)))
+    while n:
+        part = scratch[: min(n, len(scratch))]
+        _recv_body(sock, part, timeout_ms)
+        n -= len(part)
+
+
 def _read_frame(sock) -> Optional[Tuple[int, dict, bytes]]:
     hdr = recv_exact(sock, FRAME_HEADER_SIZE)
     if hdr is None:
@@ -145,15 +204,22 @@ def _read_frame(sock) -> Optional[Tuple[int, dict, bytes]]:
     return _read_frame_rest(sock, *struct.unpack("<IQQ", hdr))
 
 
-def _read_frame_rest(sock, op: int, hlen: int, blen: int) -> Optional[Tuple[int, dict, bytes]]:
-    """The JSON header and body of the frame whose fixed header said so."""
+def _read_meta(sock, hlen: int, blen: int) -> Optional[dict]:
+    """The JSON header of the frame whose fixed header said so; None at EOF."""
     if hlen + blen > MAX_FRAME_BYTES:
         raise ValueError(f"frame too large ({hlen + blen} B)")
     header = recv_exact(sock, hlen) if hlen else b""
-    body = recv_exact(sock, blen) if blen else b""
-    if (hlen and header is None) or (blen and body is None):
+    if header is None:
         return None
-    meta = json.loads(header) if header else {}
+    return json.loads(header) if header else {}
+
+
+def _read_frame_rest(sock, op: int, hlen: int, blen: int) -> Optional[Tuple[int, dict, bytes]]:
+    """The JSON header and body of the frame whose fixed header said so."""
+    meta = _read_meta(sock, hlen, blen)
+    body = recv_exact(sock, blen) if blen and meta is not None else b""
+    if meta is None or body is None:
+        return None
     return op, meta, body
 
 
@@ -337,6 +403,8 @@ class ShuffleDaemon:
     def _serve_frame(self, conn: socket.socket, op: int, hlen: int, blen: int) -> bool:
         """The rest of the frame whose fixed header said so, dispatched and
         answered; False when the peer went away mid-frame."""
+        if op == DaemonOp.WRITE_PARTITION:
+            return self._serve_write(conn, hlen, blen)
         frame = _read_frame_rest(conn, op, hlen, blen)
         if frame is None:
             return False
@@ -346,6 +414,61 @@ class ShuffleDaemon:
         except Exception as e:
             self._ack(conn, False, error=f"{type(e).__name__}: {e}")
         return True
+
+    def _serve_write(self, conn: socket.socket, hlen: int, blen: int) -> bool:
+        """A ``WritePartition`` frame: the JSON header is read as any op's,
+        the body is not — the store reserves its extent and the socket is
+        received straight into it (``PartitionWriterStream.reserve``), outside
+        every lock.  An error of admission (unknown writer, sealed shuffle,
+        quota, a body larger than a region) is raised before a byte of the
+        body is read: the body is dropped unread-into-memory, the error
+        acked, the connection kept.  A body that stalls past
+        ``conf.wire_timeout_ms`` or whose sender closes ends this connection
+        only, as a dead socket always did; the stream gives the round's
+        in-flight count back on the way out."""
+        meta = _read_meta(conn, hlen, blen)
+        if meta is None:
+            return False
+        timeout_ms = self.conf.wire_timeout_ms
+        try:
+            stream = self._partition_stream(int(meta["writer"]), int(meta["reduce_id"]))
+            view = stream.reserve(blen)
+        except Exception as e:
+            _drop_body(conn, blen, timeout_ms)
+            self._ack(conn, False, error=f"{type(e).__name__}: {e}")
+            return True
+        try:
+            _recv_body(conn, view, timeout_ms)
+        except BaseException:  # the socket's own failure: the connection's end
+            stream.end_receive(blen, False)
+            raise
+        try:
+            stream.end_receive(blen, True)
+        except Exception as e:  # the buffered path refuses at its ``write``
+            self._ack(conn, False, error=f"{type(e).__name__}: {e}")
+            return True
+        self._ack(conn, True, written=blen)
+        return True
+
+    def _partition_stream(self, handle: int, reduce_id: int):
+        """The open stream of ``(writer, reduce_id)``; opening it closes any
+        other open stream of the writer (the sequential protocol)."""
+        key = (handle, reduce_id)
+        stale = []
+        with self._lock:
+            writer = self._writers[handle]
+            stream = self._streams.get(key)
+            if stream is None:
+                # pop under the lock, close outside it (close takes the store's lock)
+                for k in [k for k in self._streams if k[0] == handle]:
+                    stale.append(self._streams.pop(k))
+        for s in stale:
+            s.close()
+        if stream is None:
+            stream = writer.get_partition_writer(reduce_id).open_stream()
+            with self._lock:
+                self._streams[key] = stream
+        return stream
 
     def _serve(self, conn: socket.socket) -> None:
         try:
@@ -428,26 +551,6 @@ class ShuffleDaemon:
                 self._next_writer += 1
                 self._writers[handle] = writer
             self._ack(conn, True, writer=handle)
-        elif op == DaemonOp.WRITE_PARTITION:
-            handle, reduce_id = int(meta["writer"]), int(meta["reduce_id"])
-            key = (handle, reduce_id)
-            stale = []
-            with self._lock:
-                writer = self._writers[handle]
-                stream = self._streams.get(key)
-                if stream is None:
-                    # close any open stream of this writer (sequential protocol);
-                    # pop under the lock, close outside it (close flushes)
-                    for k in [k for k in self._streams if k[0] == handle]:
-                        stale.append(self._streams.pop(k))
-            for s in stale:
-                s.close()
-            if stream is None:
-                stream = writer.get_partition_writer(reduce_id).open_stream()
-                with self._lock:
-                    self._streams[key] = stream
-            stream.write(body)
-            self._ack(conn, True, written=len(body))
         elif op == DaemonOp.COMMIT_MAP:
             handle = int(meta["writer"])
             with self._lock:
@@ -550,9 +653,21 @@ class DaemonClient:
         self._lock = threading.Lock()
 
     def _call(self, op: int, header: dict, body: bytes = b"") -> Tuple[dict, bytes]:
+        # the frame's bytes in the frame's order, the body never joined to
+        # its header: one vectored send, and the rest of a short one in a loop
+        payload = json.dumps(header).encode()
+        prefix = struct.pack("<IQQ", op, len(payload), len(body)) + payload
         with self._lock:
-            self._sock.sendall(_frame(op, header, body))
-            frame = _read_frame(self._sock)
+            sock = self._sock
+            sent = sock.sendmsg((prefix, body))
+            if sent < len(prefix) + len(body):
+                rest = (
+                    [memoryview(prefix)[sent:], body]
+                    if sent < len(prefix)
+                    else [memoryview(body)[sent - len(prefix) :]]
+                )
+                BlockServer._sendmsg_all(sock, rest)
+            frame = _read_frame(sock)
         if frame is None:
             raise ConnectionError("daemon closed connection")
         _, meta, ack_body = frame

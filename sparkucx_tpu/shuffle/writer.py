@@ -32,6 +32,7 @@ class PartitionWriterStream:
         self.reduce_id = reduce_id
         self.count = 0
         self._closed = False
+        self._scratch: Optional[bytearray] = None  # a buffered-path body being received
 
     def write(self, data: bytes) -> int:
         if self._closed:
@@ -39,6 +40,34 @@ class PartitionWriterStream:
         self._owner.map_writer.write(data)
         self.count += len(data)
         return len(data)
+
+    def reserve(self, nbytes: int) -> memoryview:
+        """One frame's body of a stream fed from a socket: a writable view of
+        ``nbytes`` for the caller to fill and then report with
+        ``end_receive`` — the body's own place in staging
+        (``MapWriter.reserve``: no copy follows), or a scratch buffer that
+        goes through ``write`` when the partition is on the buffered path.
+        Errors of admission are raised here, before a byte of the body is
+        read."""
+        if self._closed:
+            raise TransportError("write to closed partition stream")
+        view = self._owner.map_writer.reserve(nbytes)
+        if view is None:
+            self._scratch = bytearray(nbytes)
+            view = memoryview(self._scratch)
+        return view
+
+    def end_receive(self, nbytes: int, filled: bool) -> None:
+        """The view ``reserve`` handed out was filled, or its body never
+        fully arrived: then the partition is lost (the map cannot commit; its
+        retry writes it again)."""
+        scratch, self._scratch = self._scratch, None
+        if scratch is None:
+            self._owner.map_writer.end_receive(nbytes, filled)
+        elif filled:
+            self._owner.map_writer.write(scratch)
+        if filled:
+            self.count += nbytes
 
     def close(self) -> None:
         if self._closed:

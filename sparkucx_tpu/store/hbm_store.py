@@ -171,6 +171,18 @@ class _BlockEntry:
     local: bool = True
 
 
+@dataclass
+class _Reservation:
+    """The extent an open partition holds in a round's staging while its
+    frames are received in place (``MapWriter.reserve``).  It belongs to the
+    round it was made in: a rollover does not move it."""
+
+    round: int  # the staging round it was made in
+    start: int  # absolute offset in the round's buffer
+    padded: int  # bytes of the region it takes (a multiple of the alignment)
+    filled: int = 0  # bytes received into it so far
+
+
 class _ShuffleState:
     def __init__(
         self,
@@ -241,6 +253,16 @@ class _ShuffleState:
         #: refusal, so the lazy ``staging`` property never re-allocates (and
         #: never serves fresh zeros as block bytes) once this is set.
         self.removed = False  #: guarded by self._lock
+        #: staging round -> socket receives in flight into its buffer
+        #: (``MapWriter.reserve`` takes one, ``end_receive`` gives it back);
+        #: whoever would read, zero or hand on a round's buffer waits for its
+        #: count to reach zero (``HbmBlockStore._await_receives``)
+        #: (read and written under the owning store's ``_lock``)
+        self.inflight: Dict[int, int] = {}
+        #: waiters in ``_await_receives``: no new reservation is admitted
+        #: while one drains, so a stream of writers cannot starve it
+        #: (under the owning store's ``_lock``)
+        self.draining = 0
 
     @property
     def staging(self) -> Optional[np.ndarray]:
@@ -287,6 +309,25 @@ class MapWriter:
     never interleave with a half-written partition.  Concurrent writers take
     turns at the store's one lock for the copy and the rollover; what each
     waited there is counted (``lock_wait_ns``).
+
+    A partition fed from a socket (``reserve`` / ``end_receive``; the daemon's
+    ``WritePartition``) splits that atom in two.  **Atomic under the store's
+    lock, before a byte is read**: the admission checks, the tenant charge,
+    the rollover when the region cannot take the block, the region allocate
+    and the round's in-flight count.  **Atomic under it at close**: the table
+    record, which names the extent and the round it was reserved in.  **In
+    between, outside the lock**: the receive into the extent, which no block
+    names yet and no other writer can be given.  A rollover MAY interleave
+    with it on the RAM arm — the completed round's buffer lives on in
+    ``prev_rounds`` and the receive ends in it — and with a partition that is
+    reserved but not closed on either arm (its bytes are in the round,
+    wherever the round went).  A rollover's disk arm, ``seal``,
+    ``remove_shuffle`` and ``close`` may NOT: whoever would read, zero or
+    hand on a round's buffer first waits, on the store's condition, until no
+    receive into it is in flight (``inflight_wait_ns``); while one waits no
+    new reservation is admitted.  A body that never fully arrives leaves its
+    extent a hole that no entry names (padding; tenant charge given back) and
+    the partition lost: the map cannot commit, so the retry writes it again.
     """
 
     def __init__(
@@ -308,6 +349,13 @@ class MapWriter:
         #: at ``commit``
         self._lock_wait_ns = 0
         self._counted = False  # this writer's blocks are in the store's counters
+        #: the open partition's extent while it is received in place
+        self._resv: Optional[_Reservation] = None
+        self._receiving = False  # between ``reserve`` and ``end_receive``
+        self._lost = False  # a body of the open partition never fully arrived
+        #: blocks and bytes recorded in place and partitions that went back
+        #: to the buffered path; join the store's counters at ``commit``
+        self._inplace_blocks = self._inplace_bytes = self._inplace_fallbacks = 0
         #: First-commit-wins task-retry semantics: when a successful commit for
         #: this map already exists, the retry attempt's writes are swallowed and
         #: commit() returns the existing table — the reference's atomic
@@ -348,6 +396,8 @@ class MapWriter:
     def close_partition(self) -> None:
         if self._open_reduce is None:
             raise TransportError("no open partition")
+        if self._resv is not None and self._close_reserved():
+            return
         st = self._state
         reduce_id = self._open_reduce
         peer = st.owner_of(reduce_id)
@@ -396,6 +446,158 @@ class MapWriter:
         self._last_reduce = reduce_id
         self._open_reduce = None
         self._chunks = []
+
+    # -- receive in place (a partition fed from a socket) -------------------
+
+    def reserve(self, nbytes: int) -> Optional[memoryview]:
+        """The next ``nbytes`` of the open partition as a writable view of
+        their place in staging, for the caller to fill from a socket outside
+        every lock and then report with ``end_receive``; None when this
+        partition is on the buffered path (a retry's discarded writes, a
+        partition already fed through ``write``, one whose extent could not
+        grow in place): the caller then feeds ``write``.
+
+        Under the store's lock, before a byte is read, this does everything
+        ``close_partition`` does before its copy: ``check_memory_pressure``,
+        the sealed / device-mode checks, ``_charge_tenant``, the rollover when
+        the region cannot take the block; the region-size check comes first,
+        so a body larger than a region fails typed with nothing allocated.
+        The first frame of a partition takes its extent at the region's tail;
+        a further frame grows it while that tail is still the extent's end
+        and the region has room, and otherwise the partition goes back to the
+        buffered path (``inplace_fallbacks``; the extent stays as padding).
+        The round's in-flight count is taken here and given back by
+        ``end_receive``."""
+        if self._open_reduce is None:
+            raise TransportError("no open partition")
+        self._refuse_unsettled()
+        if self._discard or self._chunks:
+            return None
+        st = self._state
+        store = self._store
+        total = self._written + nbytes
+        if total > st.region_size:
+            raise TransportError(
+                f"single partition ({self.map_id},{self._open_reduce}) exceeds a "
+                f"whole region ({st.region_size} B) — raise stagingCapacity"
+            )
+        peer = st.owner_of(self._open_reduce)
+        padded = -(-total // st.alignment) * st.alignment
+        resv = self._resv
+        grow = padded - (resv.padded if resv is not None else 0)
+        store.check_memory_pressure("reserve_partition", grow)
+        t_lock = perf_counter_ns()
+        with store._lock:
+            self._lock_wait_ns += perf_counter_ns() - t_lock
+            if st.draining:
+                store._await_drained(st)
+            if st.removed:
+                raise TransportError(f"unknown shuffle {st.shuffle_id}")
+            if st.sealed:
+                raise TransportError(f"shuffle {st.shuffle_id} already sealed")
+            if st.device_mode:
+                raise TransportError(
+                    f"shuffle {st.shuffle_id} already has device-staged rounds — "
+                    "host and device writes cannot mix"
+                )
+            tail = peer * st.region_size + int(st.region_used[peer])
+            if resv is not None and not (
+                resv.round == st.round
+                and resv.start + resv.padded == tail
+                and int(st.region_used[peer]) + grow <= st.region_size
+            ):
+                self._unreserve()
+                return None
+            st.device_mode = False
+            store._charge_tenant(st, grow)  #: balanced by _release_tenant
+            try:
+                while resv is None and int(st.region_used[peer]) + padded > st.region_size:
+                    if st.staging_closer is not None:
+                        raise TransportError(
+                            "region overflow with shm staging — multi-round spill "
+                            "requires private staging; raise stagingCapacity"
+                        )
+                    store._rollover(st)  # may wait, lock released, for receives
+            except BaseException:
+                store._release_tenant(st, grow)
+                raise
+            if resv is None:
+                start = peer * st.region_size + int(st.region_used[peer])
+                resv = self._resv = _Reservation(st.round, start, 0)
+            resv.padded = padded
+            st.region_used[peer] += grow
+            st.inflight[resv.round] = st.inflight.get(resv.round, 0) + 1
+            self._receiving = True
+            at = resv.start + resv.filled
+            return memoryview(st.staging)[at : at + nbytes]
+
+    def end_receive(self, nbytes: int, filled: bool) -> None:
+        """The receive ``reserve`` handed out has ended: ``filled`` says all
+        ``nbytes`` arrived.  Gives the round's in-flight count back and wakes
+        whoever waits for it.  A body that did not fully arrive loses the
+        partition: its extent stays a hole (padding that no entry names), its
+        tenant charge is given back, and the writer refuses to close or
+        commit — the map's retry writes it again."""
+        st = self._state
+        resv = self._resv
+        with self._store._lock:
+            self._receiving = False
+            if filled:
+                resv.filled += nbytes
+                self._written += nbytes
+            else:
+                self._lost = True  # ``_resv`` stays: ``close_partition`` refuses
+                self._store._release_tenant(st, resv.padded)
+            self._store._receive_ended(st, resv.round)
+
+    def _refuse_unsettled(self) -> None:
+        if self._lost or self._receiving:
+            raise TransportError(
+                f"partition ({self.map_id},{self._open_reduce}) "
+                + ("lost a body mid-receive" if self._lost else "has a receive in flight")
+            )
+
+    def _unreserve(self) -> None:
+        """Back to the buffered path (caller holds the store's lock): what
+        was received so far leaves its extent for ``_chunks``, the extent
+        stays behind as padding, its tenant charge is given back
+        (``close_partition`` charges the whole partition again)."""
+        st, resv = self._state, self._resv
+        staging = st.staging if resv.round == st.round else st.prev_rounds[resv.round][0]
+        t0 = perf_counter_ns()
+        self._chunks.insert(0, staging[resv.start : resv.start + resv.filled].tobytes())
+        self._copy_ns += perf_counter_ns() - t0
+        self._store._release_tenant(st, resv.padded)
+        self._resv = None
+        self._inplace_fallbacks += 1
+
+    def _close_reserved(self) -> bool:
+        """``close_partition`` of a partition received in place: only the
+        table record — the extent was allocated and charged at ``reserve``
+        and the bytes are there.  False when ``write`` fed the partition
+        after its reservation: it goes back to the buffered path and the
+        caller carries on with the allocate + copy."""
+        self._refuse_unsettled()
+        st, resv = self._state, self._resv
+        t_lock = perf_counter_ns()
+        with self._store._lock:
+            self._lock_wait_ns += perf_counter_ns() - t_lock
+            if st.removed:
+                raise TransportError(f"unknown shuffle {st.shuffle_id}")
+            if st.sealed:
+                raise TransportError(f"shuffle {st.shuffle_id} already sealed")
+            if self._chunks:
+                self._unreserve()
+                return False
+            st.blocks[(self.map_id, self._open_reduce)] = _BlockEntry(
+                offset=resv.start, length=self._written, padded=resv.padded, round=resv.round
+            )
+            self._inplace_blocks += 1
+            self._inplace_bytes += self._written
+        self._resv = None
+        self._last_reduce = self._open_reduce
+        self._open_reduce = None
+        return True
 
     def write_partition(self, reduce_id: int, data: bytes) -> None:
         """Convenience: open + write + close in one call."""
@@ -538,6 +740,9 @@ class MapWriter:
                 counters["staged_bytes"] += sum(length for _, length in parts)
                 counters["copy_ns"] += self._copy_ns
                 counters["lock_wait_ns"] += self._lock_wait_ns
+                counters["inplace_blocks"] += self._inplace_blocks
+                counters["inplace_bytes"] += self._inplace_bytes
+                counters["inplace_fallbacks"] += self._inplace_fallbacks
         self._copy_ns = self._lock_wait_ns = 0
         return MapperInfo(
             st.shuffle_id, self.map_id, tuple(parts),
@@ -708,6 +913,9 @@ class HbmBlockStore:
         # arrive before this process registers the shuffle); applied at creation.
         self._pending_infos: Dict[int, List[MapperInfo]] = {}  #: guarded by self._lock
         self._lock = threading.RLock()
+        #: on ``_lock``: woken when a receive in place ends and when a waiter
+        #: for such receives is done (``_await_receives``, ``_await_drained``)
+        self._cond = threading.Condition(self._lock)
         # disk round tier accounting (conf.spill_to_disk).  The tempdir path
         # lives in a plain dict holder so the weakref.finalize below can purge
         # it when the store is dropped WITHOUT close() (GC / interpreter
@@ -739,6 +947,12 @@ class HbmBlockStore:
         #: (``device_staged_blocks`` / ``device_staged_bytes``; ``staged_*``
         #: count both paths at commit) and ``device_stage_ns``, the time the
         #: dispatches held the writer's thread — not the DMA.
+        #: The receive in place (``MapWriter.reserve``; a daemon's store):
+        #: ``inplace_blocks`` / ``inplace_bytes`` (blocks recorded where a
+        #: socket put them, and their bytes) and ``inplace_fallbacks``
+        #: (partitions that went back to the buffered path), added at
+        #: ``commit`` like ``copy_ns``; ``inflight_wait_ns``: what a spill, a
+        #: seal, a removal and ``close`` waited for receives in flight.
         #: guarded by self._lock
         self._write_stats: Dict[str, int] = dict.fromkeys(
             ("staged_blocks", "staged_bytes", "rollovers", "spilled_bytes",
@@ -746,7 +960,8 @@ class HbmBlockStore:
              "recycled_rounds", "zeroed_bytes", "ram_rounds", "pool_hits",
              "pool_misses", "pool_dropped_busy", "pool_held_bytes",
              "device_staged_blocks", "device_staged_bytes", "scatter_dispatches",
-             "device_stage_ns", "lock_wait_ns"), 0
+             "device_stage_ns", "lock_wait_ns", "inplace_blocks", "inplace_bytes",
+             "inplace_fallbacks", "inflight_wait_ns"), 0
         )
         #: RAM tier of completed rounds (``_rollover``): capacity bytes of the
         #: RAM rounds live shuffles hold plus the free list never exceed
@@ -866,6 +1081,9 @@ class HbmBlockStore:
             st = self._shuffles.pop(shuffle_id, None)
             if st is not None:
                 st.removed = True
+                # nothing below may zero, unmap or hand a buffer to the free
+                # list under a receive: wait them out (no new one is admitted)
+                self._await_receives(st)
                 # The live staging round, the RAM rounds and the device-sealed
                 # payload are released HERE, not at the interpreter's next
                 # collection: a writer or reader handle may keep the state
@@ -907,6 +1125,7 @@ class HbmBlockStore:
             self._replica_bytes = 0
             for st in states:
                 st.removed = True
+                self._await_receives(st)  # the shm closer unmaps the buffer
                 if st.staging_closer is not None:
                     st.staging = None
                     st.staging_closer()
@@ -1083,6 +1302,13 @@ class HbmBlockStore:
         count and the pad bytes of a block are zeros in whatever leaves the
         host.
 
+        Receives in place (``MapWriter.reserve``): on the RAM arm a receive
+        in flight ends in the completed round's buffer, which lives on in
+        ``prev_rounds``; the disk arm first waits until none is in flight into
+        the live round (``_await_receives``: the lock is released meanwhile,
+        one integer compare where there is none) and then spills whatever is
+        the live round by then.
+
         Span ``store.rollover`` (once a staging round); its child
         ``store.spill`` fires only on the disk arm, where the rollover's self
         time is the zeroing of the used prefixes; on the RAM arm it is
@@ -1095,6 +1321,16 @@ class HbmBlockStore:
                 st.staging = self._take_round_buffer(staging.nbytes)
                 self._write_stats["ram_rounds"] += 1
             else:
+                # the spill reads the buffer and the reuse zeroes it: not under
+                # a receive.  The lock is released while this waits, so the
+                # shuffle may be gone and the round another by the end
+                if self._await_receives(st, live_only=True):
+                    if st.removed or st.sealed:
+                        raise TransportError(
+                            f"shuffle {st.shuffle_id} was "
+                            f"{'removed' if st.removed else 'sealed'} during a rollover"
+                        )
+                    staging, used = st.staging, st.region_used
                 st.prev_rounds.append((self._spill_round(st, staging), used))
                 for p in np.flatnonzero(used):
                     start = int(p) * st.region_size
@@ -1103,6 +1339,50 @@ class HbmBlockStore:
                 self._write_stats["zeroed_bytes"] += int(used.sum())
             st.region_used = np.zeros(len(used), dtype=used.dtype)
             st.round += 1
+
+    # -- receives in place -------------------------------------------------
+
+    def _await_receives(self, st: _ShuffleState, live_only: bool = False) -> bool:
+        """Return with no receive in flight into any round of ``st`` (with
+        ``live_only``: into its live round) — caller holds self._lock, which
+        is RELEASED while this waits, so the state may have changed by the
+        time it returns.  No new reservation of the shuffle is admitted
+        meanwhile (``_await_drained``), and every receive ends: it runs under
+        ``conf.wire_timeout_ms`` and gives its count back on every way out
+        (``MapWriter.end_receive``).  Nothing in flight is one compare.
+        True when it waited: the caller then looks at the state again."""
+
+        def pending():
+            return st.inflight.get(st.round) if live_only else st.inflight
+
+        if not pending():
+            return False
+        t0 = perf_counter_ns()
+        st.draining += 1
+        try:
+            while pending():
+                self._cond.wait(timeout=1.0)
+        finally:
+            st.draining -= 1
+            self._cond.notify_all()
+            self._write_stats["inflight_wait_ns"] += perf_counter_ns() - t0
+        return True
+
+    def _await_drained(self, st: _ShuffleState) -> None:
+        """Hold a new reservation back while a waiter of ``_await_receives``
+        drains the shuffle (caller holds self._lock, released meanwhile)."""
+        while st.draining:
+            self._cond.wait(timeout=1.0)
+
+    def _receive_ended(self, st: _ShuffleState, round_idx: int) -> None:
+        """Give one in-flight count of a round back (caller holds self._lock)."""
+        left = st.inflight[round_idx] - 1
+        if left:
+            st.inflight[round_idx] = left
+        else:
+            del st.inflight[round_idx]
+            if st.draining:
+                self._cond.notify_all()
 
     # -- RAM rounds and the free list of round buffers ---------------------
 
@@ -1494,6 +1774,9 @@ class HbmBlockStore:
                 raise TransportError(f"unknown shuffle {shuffle_id}")
             if st.sealed:
                 raise TransportError(f"shuffle {shuffle_id} already sealed")
+            # the rounds are handed on as they are: not under a receive
+            if self._await_receives(st) and (st.removed or st.sealed):  # the lock was released
+                raise TransportError(f"shuffle {shuffle_id} was removed or sealed during its seal")
             lane = st.alignment // 4
             out = []
             # Staging (completed rounds) stays host-resident until
