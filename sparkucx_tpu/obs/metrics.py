@@ -86,14 +86,6 @@ class MetricsRegistry:
             self._providers = [(n, p) for n, p in self._providers if n != name]
             self._providers.append((name, provider))
 
-    def unregister(self, name: str) -> None:
-        with self._lock:
-            self._providers = [(n, p) for n, p in self._providers if n != name]
-
-    def provider_names(self) -> List[str]:
-        with self._lock:
-            return [n for n, _ in self._providers]
-
     def snapshot(self) -> List[MetricSample]:
         """Walk every provider OUTSIDE the registry lock (providers take
         subsystem locks; the registry lock stays a leaf).  A provider that

@@ -28,7 +28,7 @@ from typing import Callable, List, Optional
 
 from sparkucx_tpu.core import operation as _operation
 from sparkucx_tpu.testing import faults
-from sparkucx_tpu.utils.trace import TRACER, Tracer
+from sparkucx_tpu.utils.trace import TRACER, Tracer, jsonable
 
 #: Keep bundles bounded: the recorder is always on and chaos tests trigger
 #: hundreds of captures — only the newest N stay resident.
@@ -133,7 +133,7 @@ class FlightRecorder:
                 "reason": reason,
                 "wall_time": time.time(),
                 "executor": self.executor_id,
-                "context": {k: _jsonable(v) for k, v in context.items()},
+                "context": {k: jsonable(v) for k, v in context.items()},
                 "trace_tail": self.tracer.tail(self.tail_events),
                 "trace_dropped": self.tracer.dropped,
                 "metrics": (
@@ -183,9 +183,3 @@ class FlightRecorder:
             bundle["path"] = path
         except OSError:
             pass  # postmortem capture must never become a second failure
-
-
-def _jsonable(v):
-    if isinstance(v, (str, int, float, bool)) or v is None:
-        return v
-    return str(v)

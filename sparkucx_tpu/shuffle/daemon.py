@@ -73,6 +73,18 @@ stage boundary has plain rows of the same family (``stage_stats()``:
 ``connections`` and ``connections_peak``) and two spans recorded like
 ``exchange.superstep``: ``daemon.stage_exchange`` (once a shuffle) and
 ``daemon.stage_wait`` (once a fetch that waited).
+
+A frame by phase (PR 36), under full tracing only and at the cost of plain
+clock reads while the frame runs: the site hands its marks to
+``Tracer.record_spans`` after the frame's span has closed, one call and one
+take of the ring's lock (``utils/trace.py``), so the children partition their
+parent.  ``daemon.write_partition.meta`` / ``.admit`` / ``.body`` / ``.record``
+/ ``.ack`` on one ``write_partition`` frame in ``WRITE_PHASES_EVERY`` of a
+connection; ``daemon.fetch_block.locate`` / ``.send`` on every ``fetch_block``
+frame; and the root ``daemon.client_turn.<op>`` of those same frames: the
+connection's wait for its client, from the end of the frame before on this
+connection to this frame's begin (``docs/OBSERVABILITY.md`` has the table).
+Untraced, a frame pays one dict's truth test and a ``None`` check a phase.
 """
 
 from __future__ import annotations
@@ -115,6 +127,15 @@ logger = get_logger("shuffle.daemon")
 #: serving thread (one of a bounded pool under the reactor) is never parked
 #: for good behind an exchange that hangs; its fetch then answers size -1
 _STAGE_WAIT_S = 600.0
+#: under full tracing, the ``write_partition`` frames of a connection that are
+#: recorded by phase: numbers 1, 10, 19, ... counted from 0 since tracing came
+#: on (the first sampled frame has a frame before it to end its client's
+#: turn).  A neighbour of 8 that does not divide the 200 frames of a map task
+#: at GroupByTest width, so the sampled reduce ids rotate from task to task.
+WRITE_PHASES_EVERY = 9
+_WRITE_PHASES = tuple(
+    "daemon.write_partition." + phase for phase in ("meta", "admit", "body", "record", "ack")
+)
 _TAG = struct.Struct("<Q")
 _COUNT = struct.Struct("<I")
 _SIZE = struct.Struct("<q")
@@ -223,6 +244,17 @@ def _read_frame_rest(sock, op: int, hlen: int, blen: int) -> Optional[Tuple[int,
     return op, meta, body
 
 
+class _ConnTurn:
+    """What full tracing keeps of one connection: where its last frame's span
+    ended (0: none yet) and how many ``write_partition`` frames it has sent."""
+
+    __slots__ = ("t_end", "writes")
+
+    def __init__(self) -> None:
+        self.t_end = 0
+        self.writes = 0
+
+
 class _StageExchange:
     """One shuffle's exchange at the stage boundary: ``done`` is set when the
     thread that claimed it has finished, ``error`` is what it raised."""
@@ -271,6 +303,11 @@ class ShuffleDaemon:
             ("stage_exchanges", "stage_waiters", "stage_wait_ns", "connections", "connections_peak"), 0
         )  #: guarded by self._lock
         self._lock = threading.Lock()
+        #: connection -> its ``_ConnTurn``, while full tracing is on (empty
+        #: otherwise).  No lock: a connection is served by one thread at a
+        #: time on either plane, so an entry has one writer, and each dict
+        #: operation is atomic
+        self._turns: Dict[socket.socket, _ConnTurn] = {}
         #: ``t_ack``: when the calling thread last began to send a reply
         self._tls = threading.local()
         self.manager.cluster.metrics.register(
@@ -321,7 +358,8 @@ class ShuffleDaemon:
             stats["connections"] += 1
             stats["connections_peak"] = max(stats["connections_peak"], stats["connections"])
 
-    def _connection_closed(self, _conn=None) -> None:
+    def _connection_closed(self, conn=None) -> None:
+        self._turns.pop(conn, None)
         with self._lock:
             self._stage_stats["connections"] -= 1
 
@@ -366,7 +404,8 @@ class ShuffleDaemon:
         only — one a frame would cost every untraced run and flush the
         flight recorder's tail); waiting for the next frame's header is
         outside.  This runs once a block: it reads the clock three times and
-        adds four ints, and anything more shows in the job's time."""
+        adds four ints, and anything more shows in the job's time.  What full
+        tracing adds to the span is in ``_serve_traced``."""
         if not self._running:
             return False
         try:
@@ -376,9 +415,10 @@ class ShuffleDaemon:
             t0 = perf_counter_ns()
             op, hlen, body_bytes = struct.unpack("<IQQ", hdr)
             if TRACER.enabled:
-                with TRACER.span("daemon." + OP_NAMES.get(op, "unknown")):
-                    served = self._serve_frame(conn, op, hlen, body_bytes)
+                served = self._serve_traced(conn, op, hlen, body_bytes)
             else:
+                if self._turns:  # tracing went off: the next count starts anew
+                    self._turns.clear()
                 served = self._serve_frame(conn, op, hlen, body_bytes)
             if not served:
                 return False
@@ -400,11 +440,51 @@ class ShuffleDaemon:
             # UcxWorkerWrapper.scala:248-253)
             return False
 
-    def _serve_frame(self, conn: socket.socket, op: int, hlen: int, blen: int) -> bool:
-        """The rest of the frame whose fixed header said so, dispatched and
-        answered; False when the peer went away mid-frame."""
+    def _serve_traced(self, conn: socket.socket, op: int, hlen: int, blen: int) -> bool:
+        """``_serve_frame`` under full tracing: the span ``daemon.<op>`` as
+        ever, and after it has closed — off the frame's clock — the frame by
+        phase and the connection's wait for its client, from plain clock
+        marks (module docstring).  The phases lie between the span's own
+        bounds (``ctx.t0`` / ``ctx.t1``), so they partition it; the client's
+        turn runs from the span of the connection's frame before to this
+        one's, so a connection's spans and turns tile its time."""
+        turn = self._turns.get(conn)
+        if turn is None:
+            turn = self._turns[conn] = _ConnTurn()
+        marks = None
         if op == DaemonOp.WRITE_PARTITION:
-            return self._serve_write(conn, hlen, blen)
+            if turn.writes % WRITE_PHASES_EVERY == 1:
+                marks = []
+            turn.writes += 1
+        with TRACER.span("daemon." + OP_NAMES.get(op, "unknown")) as ctx:
+            served = self._serve_frame(conn, op, hlen, blen, marks)
+        t_begin, t_end = ctx.t0, ctx.t1
+        waited_from, turn.t_end = turn.t_end, t_end
+        t_ack = getattr(self._tls, "t_ack", 0)
+        if not served or t_ack <= t_begin:
+            return served
+        if op == int(AmId.FETCH_BLOCK_REQ):
+            cuts = (t_begin, t_ack, t_end)
+            names = ("daemon.fetch_block.locate", "daemon.fetch_block.send")
+        elif marks is not None and len(marks) == 3:  # a frame refused on the way has fewer
+            cuts = (t_begin, *marks, t_ack, t_end)
+            names = _WRITE_PHASES
+        else:
+            return served
+        TRACER.record_spans(ctx, zip(names, cuts, cuts[1:]))
+        if waited_from:
+            TRACER.record_spans(None, (("daemon.client_turn." + OP_NAMES[op], waited_from, t_begin),))
+        return served
+
+    def _serve_frame(
+        self, conn: socket.socket, op: int, hlen: int, blen: int, marks: Optional[List[int]] = None
+    ) -> bool:
+        """The rest of the frame whose fixed header said so, dispatched and
+        answered; False when the peer went away mid-frame.  ``marks``, where
+        full tracing samples this ``WritePartition`` frame by phase, takes
+        the clock at its phase boundaries."""
+        if op == DaemonOp.WRITE_PARTITION:
+            return self._serve_write(conn, hlen, blen, marks)
         frame = _read_frame_rest(conn, op, hlen, blen)
         if frame is None:
             return False
@@ -415,7 +495,9 @@ class ShuffleDaemon:
             self._ack(conn, False, error=f"{type(e).__name__}: {e}")
         return True
 
-    def _serve_write(self, conn: socket.socket, hlen: int, blen: int) -> bool:
+    def _serve_write(
+        self, conn: socket.socket, hlen: int, blen: int, marks: Optional[List[int]] = None
+    ) -> bool:
         """A ``WritePartition`` frame: the JSON header is read as any op's,
         the body is not — the store reserves its extent and the socket is
         received straight into it (``PartitionWriterStream.reserve``), outside
@@ -425,10 +507,14 @@ class ShuffleDaemon:
         acked, the connection kept.  A body that stalls past
         ``conf.wire_timeout_ms`` or whose sender closes ends this connection
         only, as a dead socket always did; the stream gives the round's
-        in-flight count back on the way out."""
+        in-flight count back on the way out.  ``marks`` (a sampled frame
+        under full tracing, else None) gets the clock after the JSON header
+        is parsed, after the extent is reserved and after the body is in."""
         meta = _read_meta(conn, hlen, blen)
         if meta is None:
             return False
+        if marks is not None:
+            marks.append(perf_counter_ns())
         timeout_ms = self.conf.wire_timeout_ms
         try:
             stream = self._partition_stream(int(meta["writer"]), int(meta["reduce_id"]))
@@ -437,11 +523,15 @@ class ShuffleDaemon:
             _drop_body(conn, blen, timeout_ms)
             self._ack(conn, False, error=f"{type(e).__name__}: {e}")
             return True
+        if marks is not None:
+            marks.append(perf_counter_ns())
         try:
             _recv_body(conn, view, timeout_ms)
         except BaseException:  # the socket's own failure: the connection's end
             stream.end_receive(blen, False)
             raise
+        if marks is not None:
+            marks.append(perf_counter_ns())
         try:
             stream.end_receive(blen, True)
         except Exception as e:  # the buffered path refuses at its ``write``
@@ -475,7 +565,7 @@ class ShuffleDaemon:
             while self._serve_step(conn):
                 pass
         finally:
-            self._connection_closed()
+            self._connection_closed(conn)
             conn.close()
 
     def _exchange_once(self, shuffle_id: int, explicit: bool) -> None:

@@ -40,6 +40,30 @@ from sparkucx_tpu.core.transport import ExecutorId, ShuffleTransport
 from sparkucx_tpu.memory.pool import MemoryPool
 from sparkucx_tpu.utils.trace import TRACER, instant, span
 
+#: under full tracing, the fetch windows whose record turns are timed
+#: (``read.window.decode`` / ``read.window.consumer``): numbers 0, 5, 10, ...
+#: of the windows this process has opened since tracing came on.  A reader
+#: lives for one task; at GroupByTest width a task is one window, but the 1k
+#: job's is two (50 + 13 blocks), so an even interval would only ever time
+#: the first of a task: odd, the samples rotate
+WINDOW_TURNS_EVERY = 5
+_windows_traced = 0  # benign race between reader threads: it only picks samples
+
+
+class _WindowMarks:
+    """What a fetch window notes of itself under full tracing, for the
+    children of its ``read.window`` span: the time this thread spent fetching
+    it (issue and await: one interval, or two where windows are pipelined)
+    and, in a sampled window read through ``read()``, the summed turns of the
+    deserializer and of the consumer above it."""
+
+    __slots__ = ("fetch_ns", "fetch_turns", "sampled", "decode_ns", "consumer_ns", "turns")
+
+    def __init__(self, sampled: bool) -> None:
+        self.fetch_ns = self.fetch_turns = 0
+        self.sampled = sampled
+        self.decode_ns = self.consumer_ns = self.turns = 0
+
 #: The fail-fast arm of the failure taxonomy (docs/API.md "Failure
 #: semantics", machine-checked by analysis ERROR_TAXONOMY): faults every
 #: replica answers identically (tenant admission) or that name an executor
@@ -205,6 +229,30 @@ def pickle_serialize_records(records: Iterable[Any]) -> bytes:
     return bio.getvalue()
 
 
+def _timed_turns(records: Iterable[Any], marks: _WindowMarks) -> Iterator[Any]:
+    """``yield from records`` with the clock read at every hand-over: the
+    time inside ``next`` is the deserializer's turn, the time this generator
+    is suspended at its ``yield`` is the turn of everything above it (the
+    caller of ``read()``).  Both are summed into the window's marks."""
+    clock = time.perf_counter_ns
+    it = iter(records)
+    decode_ns = consumer_ns = turns = 0
+    try:
+        t = clock()
+        for rec in it:
+            t_out = clock()
+            decode_ns += t_out - t
+            yield rec
+            t = clock()
+            consumer_ns += t - t_out
+            turns += 1
+        decode_ns += clock() - t  # the turn that found the block exhausted
+    finally:
+        marks.decode_ns += decode_ns
+        marks.consumer_ns += consumer_ns
+        marks.turns += turns
+
+
 class TpuShuffleReader:
     """Reads the blocks of reduce partitions [start_partition, end_partition)
     for one reducer — ``ShuffleReader.read()`` (UcxShuffleReader.scala:74)."""
@@ -302,6 +350,9 @@ class TpuShuffleReader:
         #: (spread target, not necessarily the primary) — hedges must pick a
         #: different holder than this (single reader thread; no lock)
         self._window_targets: Dict[ShuffleBlockId, ExecutorId] = {}
+        #: the marks of the window whose blocks are being yielded, under full
+        #: tracing (None otherwise): where ``read()`` adds its record turns
+        self._yielding: Optional[_WindowMarks] = None
         self.metrics = ShuffleReadMetrics()
 
     # -- raw block iterator ------------------------------------------------
@@ -338,13 +389,18 @@ class TpuShuffleReader:
             # the fetch request carries (trace_id, span_id) over the wire and
             # every server's serve span — primary or replica — parents here
             wctx = self._start_window_span(len(window))
+            marks = self._window_marks(wctx)
             try:
                 with TRACER.activate(wctx):
                     requests = self._issue_window(window)
                     self._await_window(requests, len(window))
+                if marks is not None:  # issue and await: one real interval
+                    marks.fetch_ns = time.perf_counter_ns() - wctx.t0
+                    marks.fetch_turns = 1
+                    self._yielding = marks
                 yield from self._yield_window(requests, wctx)
             finally:
-                self._end_window_span(wctx)
+                self._end_window_span(wctx, marks)
         self._sweep_abandoned()
         self._flush_read_counters()
 
@@ -357,7 +413,7 @@ class TpuShuffleReader:
         costs = [
             sum(self.block_sizes(b.map_id, b.reduce_id) for b in w) for w in windows
         ]
-        issued: deque = deque()  # (window, wctx, requests, cost) awaiting completion
+        issued: deque = deque()  # (window, wctx, marks, requests, cost) awaiting completion
         nxt = 0
         while nxt < len(windows) or issued:
             while nxt < len(windows):
@@ -370,17 +426,27 @@ class TpuShuffleReader:
                 # each carries its own explicit ctx rather than the thread
                 # stack (start_span/end_span straddle the pipeline)
                 wctx = self._start_window_span(len(windows[nxt]))
+                marks = self._window_marks(wctx)
                 with TRACER.activate(wctx):
                     reqs = self._issue_window(windows[nxt])
-                issued.append((windows[nxt], wctx, reqs, cost))
+                if marks is not None:
+                    marks.fetch_ns = time.perf_counter_ns() - wctx.t0
+                issued.append((windows[nxt], wctx, marks, reqs, cost))
                 nxt += 1
-            window, wctx, requests, cost = issued.popleft()
+            window, wctx, marks, requests, cost = issued.popleft()
             try:
+                t_await = time.perf_counter_ns() if marks is not None else 0
                 with TRACER.activate(wctx):
                     self._await_window(requests, len(window))
+                if marks is not None:
+                    # issued ahead and awaited here, other windows drained
+                    # between: the two turns this thread spent on the fetch
+                    marks.fetch_ns += time.perf_counter_ns() - t_await
+                    marks.fetch_turns = 2
+                    self._yielding = marks
                 yield from self._yield_window(requests, wctx)
             finally:
-                self._end_window_span(wctx)
+                self._end_window_span(wctx, marks)
                 # credits return when the window is consumed (or the caller
                 # abandons the iterator / a fetch raises or times out) — the
                 # gate drains to zero either way, so one dead peer's windows
@@ -449,10 +515,55 @@ class TpuShuffleReader:
                 "read.window", shuffle_id=self.shuffle_id, blocks=num_blocks
             )
 
-    def _end_window_span(self, wctx) -> None:
-        if wctx is not None:
-            with TRACER.executor_scope(self.executor_id):
-                TRACER.end_span(wctx)
+    def _window_marks(self, wctx) -> Optional[_WindowMarks]:
+        """The marks of the window whose span was just opened: under full
+        tracing only (the children of ``read.window`` are ``enabled``-only;
+        the span itself is the flight recorder's too).  One window in
+        ``WINDOW_TURNS_EVERY`` of the process has its record turns timed."""
+        global _windows_traced
+        if wctx is None or not TRACER.enabled:
+            _windows_traced = 0  # tracing is off: the next count starts anew
+            return None
+        n = _windows_traced
+        _windows_traced = n + 1
+        return _WindowMarks(n % WINDOW_TURNS_EVERY == 0)
+
+    def _end_window_span(self, wctx, marks: Optional[_WindowMarks] = None) -> None:
+        """Close ``read.window`` and, from the window's marks, lay its
+        children inside it, end to end from its open: ``read.window.fetch``,
+        the time this thread spent fetching the window (issue, await, the copy
+        out of the received shards) — the real interval from the window's
+        open where issue and await are one, a summed span of two ``turns``
+        where the window was issued ahead of consumption; then — a sampled
+        window that ``read()`` drained — the summed spans
+        ``read.window.decode`` and ``read.window.consumer``.  Their turns
+        interleave record by record, so each is one event whose ``dur`` is
+        the sum of its turns (docs/OBSERVABILITY.md "summed span"); what is
+        left of the window after them is the hand-off of its blocks and, of a
+        pipelined window, the time it waited its turn."""
+        self._yielding = None
+        if wctx is None:
+            return
+        with TRACER.executor_scope(self.executor_id):
+            TRACER.end_span(wctx)
+            if marks is None or not marks.fetch_turns:
+                return
+            fetched = wctx.t0 + marks.fetch_ns
+            TRACER.record_spans(
+                wctx,
+                (("read.window.fetch", wctx.t0, fetched),),
+                args={"turns": 2} if marks.fetch_turns == 2 else None,
+            )
+            if marks.sampled and marks.turns:
+                decoded = fetched + marks.decode_ns
+                TRACER.record_spans(
+                    wctx,
+                    (
+                        ("read.window.decode", fetched, decoded),
+                        ("read.window.consumer", decoded, decoded + marks.consumer_ns),
+                    ),
+                    args={"turns": marks.turns},
+                )
 
     def _flush_read_counters(self) -> None:
         """Surface the reader's failover telemetry through the transport's
@@ -914,7 +1025,11 @@ class TpuShuffleReader:
             # and the pooled buffer recycles without the detach() copy.
             for blk in self.fetch_blocks():
                 try:
-                    yield from self.deserializer(blk.data)
+                    marks = self._yielding
+                    if marks is None or not marks.sampled:
+                        yield from self.deserializer(blk.data)
+                    else:  # a sampled window under full tracing
+                        yield from _timed_turns(self.deserializer(blk.data), marks)
                 finally:
                     blk.release()
 

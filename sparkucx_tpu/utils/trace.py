@@ -35,6 +35,24 @@ The obs plane (PR 14) grew this into a distributed tracer:
   tracing only, as the daemon's ``daemon.<op>``) or keep plain counters.
 * ``current_context()`` exposes the innermost open span for wire pickup and
   ``activate()``/``remote_context()`` re-parent server-side work under it.
+
+Marks to spans (PR 36): a site on a hot interval — a daemon frame, a reduce
+task's fetch window — does not open a child span a phase.  It reads
+``perf_counter_ns()`` at the phase boundaries while the interval runs and,
+after its own span has closed, hands the ``(name, t0_ns, t1_ns)`` triples and
+the parent's ``SpanCtx`` to ``Tracer.record_spans``: one call, one take of the
+ring's lock, one ordinary "X" event a triple (``trace_id`` the parent's,
+``parent_id`` its ``span_id``; a root each with ``parent=None``).  The events
+come from the builder every span's event comes from (``_event``), so export,
+``merge_events``, TRACE_PULL and the flight recorder cannot tell them apart.
+A **summed span** is such a triple whose turns interleave with another's
+record by record (a decoder against its consumer): ``t1 - t0`` is the SUM of
+its turns and ``t0`` lays it end to end after the parent's last real child, so
+children never overlap and time by name adds up; its ``args`` carry ``turns``.
+Cost (``PERF.md`` section 6, PR 36): the process id is read once a process (and
+again in a forked child), not three to four system calls a span; the
+thread-local stack and executor id are plain attributes; ``span()`` is a
+slotted context manager, not a generator.
 """
 
 from __future__ import annotations
@@ -48,7 +66,7 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Deque, Dict, List, Optional
+from typing import Deque, Dict, Iterable, List, Optional, Tuple
 
 #: Flight-recorder ring default: bounded so long-running tracing can't OOM an
 #: executor (conf ``obs.ringCapacity`` overrides per cluster).
@@ -57,14 +75,24 @@ DEFAULT_RING_CAPACITY = 8192
 #: Process-scoped id generator: the pid in the top bits keeps ids distinct
 #: across daemon worker processes, the counter keeps them distinct in-process
 #: (the loopback cluster shares one TRACER across every virtual executor).
-_ids = itertools.count(1)
+#: ``os.getpid()`` is a system call and a span read it three to four times;
+#: the pid changes only at fork, where ``_read_pid`` runs again in the child
+_PID = 0
 
 
-def _new_id() -> int:
-    return ((os.getpid() & 0xFFFF) << 48) | next(_ids)
+def _read_pid() -> None:
+    """Cache the pid and start the id counter under it: ``_new_id`` is the
+    counter's own ``__next__``, no Python frame an id."""
+    global _PID, _new_id
+    _PID = os.getpid()
+    _new_id = itertools.count(((_PID & 0xFFFF) << 48) | 1).__next__
 
 
-@dataclass
+_read_pid()
+os.register_at_fork(after_in_child=_read_pid)
+
+
+@dataclass(slots=True)
 class SpanCtx:
     """An open span's identity — what travels over the wire and what children
     parent under.  ``trace_id`` names the causal chain, ``span_id`` this span,
@@ -77,6 +105,9 @@ class SpanCtx:
     category: str = "shuffle"
     t0: int = 0  # perf_counter_ns at open; 0 for remote/synthetic contexts
     args: Dict[str, object] = field(default_factory=dict)
+    #: perf_counter_ns at close, 0 while open: with ``t0`` the bounds of the
+    #: recorded event, for a site that lays marks inside them (``record_spans``)
+    t1: int = 0
 
 
 class _NoopSpan:
@@ -97,6 +128,67 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
+class _LiveSpan:
+    """What ``Tracer.span`` returns while the tracer is active: the span is
+    on its thread's stack between ``__enter__`` and ``__exit__`` and recorded
+    at exit, exception or not.  Made with the tracer's own ``_stack`` and
+    ``_record_span``, bound."""
+
+    __slots__ = ("_stack_of", "_record", "_ctx", "_stack")
+
+    def __init__(self, stack_of, record, ctx: SpanCtx) -> None:
+        self._stack_of = stack_of
+        self._record = record
+        self._ctx = ctx
+
+    def __enter__(self) -> SpanCtx:
+        self._stack = self._stack_of()  # the entering thread's
+        self._stack.append(self._ctx)
+        return self._ctx
+
+    def __exit__(self, *exc) -> bool:
+        ctx = self._ctx
+        self._stack.pop()
+        ctx.t1 = time.perf_counter_ns()
+        self._record(ctx, ctx.t1 - ctx.t0)
+        return False
+
+
+class _Tls(threading.local):
+    """Per-thread span stack and executor id.  Class-level defaults: reading
+    an attribute a thread never set is a plain lookup, not a caught
+    ``AttributeError``."""
+
+    stack: Optional[List[SpanCtx]] = None
+    eid: Optional[int] = None
+
+
+def _event(
+    name: str, category: str, t0_ns: int, dur_ns: int, tid: int,
+    trace_id: int, span_id: int, parent_id: int, args, eid,
+) -> dict:
+    """The one builder of a complete ("X") event: ``_record_span`` and
+    ``record_spans`` both end here, so a bulk child has a span's shape."""
+    ev = {
+        "name": name,
+        "cat": category,
+        "ph": "X",
+        "ts": t0_ns / 1e3,  # microseconds, the chrome trace unit
+        "dur": dur_ns / 1e3,
+        "pid": _PID,
+        "tid": tid,
+        "uid": _new_id(),
+        "trace_id": trace_id,
+        "span_id": span_id,
+        "parent_id": parent_id,
+    }
+    if args:
+        ev["args"] = args
+    if eid is not None:
+        ev["eid"] = eid
+    return ev
+
+
 class Tracer:
     def __init__(
         self,
@@ -110,7 +202,7 @@ class Tracer:
         self._events: Deque[dict] = deque(maxlen=max(1, int(capacity)))  #: guarded by self._lock
         self._dropped = 0  #: guarded by self._lock
         self._lock = threading.Lock()
-        self._tls = threading.local()
+        self._tls = _Tls()
 
     # -- switches ----------------------------------------------------------
 
@@ -144,7 +236,7 @@ class Tracer:
     # -- thread-local span stack / scopes ----------------------------------
 
     def _stack(self) -> List[SpanCtx]:
-        st = getattr(self._tls, "stack", None)
+        st = self._tls.stack
         if st is None:
             st = self._tls.stack = []
         return st
@@ -152,18 +244,15 @@ class Tracer:
     def current_context(self) -> Optional[SpanCtx]:
         """The innermost open span on THIS thread — what a transport packs
         into the wire trace extension.  None when no span is open."""
-        st = getattr(self._tls, "stack", None)
+        st = self._tls.stack
         return st[-1] if st else None
-
-    def current_executor(self) -> Optional[int]:
-        return getattr(self._tls, "eid", None)
 
     @contextmanager
     def executor_scope(self, executor_id: Optional[int]):
         """Attribute events on this thread to a virtual executor — the
         loopback cluster runs every executor in one process, so pid alone
         can't tell their tracks apart; ``export_merged`` maps eid -> pid."""
-        prev = getattr(self._tls, "eid", None)
+        prev = self._tls.eid
         self._tls.eid = executor_id
         try:
             yield
@@ -199,61 +288,70 @@ class Tracer:
         explicit half of the API for spans whose open and close straddle
         threads or interleave (pipelined fetch windows).  Pair with
         ``end_span``; parent under it elsewhere via ``activate``."""
-        if not self.active:
+        if not (self.enabled or self.recording):
             return None
-        parent = self.current_context()
+        st = self._tls.stack
+        parent = st[-1] if st else None
         return SpanCtx(
-            trace_id=parent.trace_id if parent else _new_id(),
-            span_id=_new_id(),
-            parent_id=parent.span_id if parent else 0,
-            name=name,
-            category=category,
-            t0=time.perf_counter_ns(),
-            args={k: _jsonable(v) for k, v in args.items()} if args else {},
+            parent.trace_id if parent else _new_id(),
+            _new_id(),
+            parent.span_id if parent else 0,
+            name,
+            category,
+            time.perf_counter_ns(),
+            {k: jsonable(v) for k, v in args.items()} if args else {},
         )
 
     def end_span(self, ctx: Optional[SpanCtx], **extra_args) -> None:
-        if ctx is None or not self.active:
+        if ctx is None or not (self.enabled or self.recording):
             return
         if extra_args:
-            ctx.args.update({k: _jsonable(v) for k, v in extra_args.items()})
-        self._record_span(ctx, time.perf_counter_ns() - ctx.t0)
+            ctx.args.update({k: jsonable(v) for k, v in extra_args.items()})
+        ctx.t1 = time.perf_counter_ns()
+        self._record_span(ctx, ctx.t1 - ctx.t0)
 
-    @contextmanager
     def span(self, name: str, category: str = "shuffle", **args):
-        """Time a region; nested spans nest in the viewer (same tid)."""
-        if not self.active:
-            yield
-            return
-        ctx = self.start_span(name, category=category, **args)
-        st = self._stack()
-        st.append(ctx)
-        try:
-            yield ctx
-        finally:
-            st.pop()
-            self._record_span(ctx, time.perf_counter_ns() - ctx.t0)
+        """Time a region; nested spans nest in the viewer (same tid).  A
+        context manager whose ``as`` target is the open ``SpanCtx`` (None
+        while the tracer is inactive)."""
+        if not (self.enabled or self.recording):
+            return _NOOP_SPAN
+        return _LiveSpan(self._stack, self._record_span, self.start_span(name, category, **args))
 
     def _record_span(self, ctx: SpanCtx, dur_ns: int) -> None:
-        ev = {
-            "name": ctx.name,
-            "cat": ctx.category,
-            "ph": "X",
-            "ts": ctx.t0 / 1e3,  # microseconds, the chrome trace unit
-            "dur": dur_ns / 1e3,
-            "pid": os.getpid(),
-            "tid": threading.get_ident() & 0xFFFFFFFF,
-            "uid": _new_id(),
-            "trace_id": ctx.trace_id,
-            "span_id": ctx.span_id,
-            "parent_id": ctx.parent_id,
-        }
-        if ctx.args:
-            ev["args"] = ctx.args
-        eid = getattr(self._tls, "eid", None)
-        if eid is not None:
-            ev["eid"] = eid
-        self._append(ev)
+        self._append(_event(
+            ctx.name, ctx.category, ctx.t0, dur_ns, threading.get_ident() & 0xFFFFFFFF,
+            ctx.trace_id, ctx.span_id, ctx.parent_id, ctx.args, self._tls.eid,
+        ))
+
+    def record_spans(
+        self,
+        parent: Optional[SpanCtx],
+        marks: Iterable[Tuple[str, int, int]],
+        category: str = "shuffle",
+        args: Optional[Dict[str, object]] = None,
+    ) -> None:
+        """Marks to spans: one complete event a ``(name, t0_ns, t1_ns)``
+        triple of ``perf_counter_ns`` readings the caller took itself, each a
+        child of ``parent`` (a span that may have closed already; ``None``: a
+        root each, a trace of its own), all appended under ONE take of the
+        ring's lock.  For the phases of an interval too short to open a span
+        a phase (module docstring); ``args`` — e.g. a summed span's ``turns`` —
+        go on every event of the call.  The caller checks ``enabled`` or
+        ``active`` as its parent span's site does; nothing is checked here."""
+        tid = threading.get_ident() & 0xFFFFFFFF
+        eid = self._tls.eid
+        trace_id, parent_id = (parent.trace_id, parent.span_id) if parent is not None else (0, 0)
+        new = [
+            _event(name, category, t0, t1 - t0, tid, trace_id or _new_id(), _new_id(), parent_id, args, eid)
+            for name, t0, t1 in marks
+        ]
+        with self._lock:
+            events = self._events
+            over = len(events) + len(new) - events.maxlen
+            if over > 0:
+                self._dropped += over  # ring full: deque drops the oldest
+            events.extend(new)
 
     def instant(self, name: str, category: str = "shuffle", **args) -> None:
         """Zero-duration marker (commits, failures, retries)."""
@@ -266,7 +364,7 @@ class Tracer:
             "ph": "i",
             "s": "t",
             "ts": time.perf_counter_ns() / 1e3,
-            "pid": os.getpid(),
+            "pid": _PID,
             "tid": threading.get_ident() & 0xFFFFFFFF,
             "uid": _new_id(),
             "trace_id": parent.trace_id if parent else 0,
@@ -274,8 +372,8 @@ class Tracer:
             "parent_id": parent.span_id if parent else 0,
         }
         if args:
-            ev["args"] = {k: _jsonable(v) for k, v in args.items()}
-        eid = getattr(self._tls, "eid", None)
+            ev["args"] = {k: jsonable(v) for k, v in args.items()}
+        eid = self._tls.eid
         if eid is not None:
             ev["eid"] = eid
         self._append(ev)
@@ -336,7 +434,7 @@ def merge_events(buffers: List[List[dict]]) -> List[dict]:
     return merged
 
 
-def _jsonable(v):
+def jsonable(v):
     if isinstance(v, (str, int, float, bool)) or v is None:
         return v
     return str(v)

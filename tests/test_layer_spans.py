@@ -2,18 +2,23 @@
 the map-side write (``store.rollover`` / ``store.spill`` and the ``store``
 metrics family), the round's submit lane (``exchange.assemble`` /
 ``exchange.h2d`` / ``exchange.collective`` under ``exchange.pipeline.submit``)
-and the daemon's side of a frame (``daemon.<op>`` and the ``daemon`` family).
+and the daemon's side of a frame (``daemon.<op>`` and the ``daemon`` family);
+and, opened by clock marks handed to ``Tracer.record_spans`` (PR 36), a daemon
+frame by phase, the connection's wait for its client and a reduce task's
+``read.window``.
 
 Counts and nesting on the CPU mesh; no duration here is a rate."""
 
 import collections
 import re
+import time
 
 import numpy as np
 import pytest
 
 from sparkucx_tpu.config import TpuShuffleConf
-from sparkucx_tpu.shuffle.daemon import DaemonClient, ShuffleDaemon
+from sparkucx_tpu.core.block import ShuffleBlockId
+from sparkucx_tpu.shuffle.daemon import WRITE_PHASES_EVERY, DaemonClient, ShuffleDaemon
 from sparkucx_tpu.transport.tpu import TpuShuffleCluster
 from sparkucx_tpu.utils.trace import TRACER
 
@@ -261,7 +266,7 @@ def test_concurrent_writers_lose_no_count(tier):
 FRAMES = 24  # blocks a daemon job writes: 4 mappers x 6 reducers
 
 
-def daemon_job(client, shuffle_id, block_bytes=700):
+def daemon_job(client, shuffle_id, block_bytes=700, fetch=False):
     """One job over the socket; returns the bytes written."""
     mappers, reducers = 4, FRAMES // 4
     client.create_shuffle(shuffle_id, mappers, reducers)
@@ -273,7 +278,12 @@ def daemon_job(client, shuffle_id, block_bytes=700):
             client.write_partition(writer, r, data)
             written += len(data)
         client.commit_map(writer)
-    client.run_exchange(shuffle_id)
+    if fetch:  # the reduce side as the JVM shim runs it: fetches only, one a reducer
+        for r in range(reducers):
+            got = client.fetch_blocks([ShuffleBlockId(shuffle_id, m, r) for m in range(mappers)])
+            assert [bytes(b) for b in got] == [bytes([(m * reducers + r) % 251]) * block_bytes for m in range(mappers)]
+    else:
+        client.run_exchange(shuffle_id)
     client.remove_shuffle(shuffle_id)
     return written
 
@@ -384,6 +394,21 @@ def test_recording_alone_pays_nothing_per_block(tracer, blocks):
     assert not [n for n in names if n.startswith(("store.", "daemon."))]
     # the same events whatever the number of blocks
     assert sum(names.values()) == sum(1 for _ in names), names
+    # a reduce task's read: its ``read.window`` is the recorder's, once a
+    # window; the children PR 36 opened inside it are full tracing's alone
+    from sparkucx_tpu.shuffle.reader import TpuShuffleReader
+
+    tracer.clear()
+    meta = cluster.meta(sid)
+    records = 0
+    for r in range(2):
+        owner = meta.owner_of_reduce(r)
+        reader = TpuShuffleReader(cluster.transport(owner), owner, sid, r, r + 1, blocks // 2,
+                                  block_sizes=lambda m, r: 500, deserializer=lambda payload: [bytes(payload)])
+        records += sum(1 for _ in reader.read())
+    assert records == blocks
+    read_names = collections.Counter(e["name"] for e in spans(tracer) if e["name"].startswith("read."))
+    assert set(read_names) == {"read.window"} and read_names["read.window"] == -(-blocks // 2 // 50) * 2
 
 
 def test_recording_alone_pays_nothing_per_frame(daemon, tracer):
@@ -397,6 +422,297 @@ def test_recording_alone_pays_nothing_per_frame(daemon, tracer):
     second = collections.Counter(e["name"] for e in spans(tracer))
     assert first == second and max(first.values()) == 1  # one of each, none per frame
     assert first["exchange.assemble"] == first["exchange.h2d"] == 1
+    # nor does a frame's phase or a connection's turn: a job with its reduce
+    # side over the socket records no ``daemon.<op>`` and nothing under one
+    tracer.clear()
+    daemon_job(client, 2, fetch=True)
+    assert served.op_stats()[2]["op"] == "fetch_block" and served.op_stats()[2]["frames"] == FRAMES // 4
+    third = collections.Counter(e["name"] for e in spans(tracer))
+    assert not [n for n in third if n.startswith(("daemon.write_partition", "daemon.fetch_block", "daemon.client_turn", "read.window."))]
+    assert not served._turns  # the per-connection marks exist under full tracing only
+
+
+# -- a daemon frame by phase, and the connection's wait for its client ------
+
+WRITE_PHASES = tuple("daemon.write_partition." + p for p in ("meta", "admit", "body", "record", "ack"))
+FETCH_PHASES = ("daemon.fetch_block.locate", "daemon.fetch_block.send")
+
+
+def ns(event):
+    """An event's bounds back on the tracer's clock, whole nanoseconds."""
+    return round(event["ts"] * 1e3), round((event["ts"] + event["dur"]) * 1e3)
+
+
+def assert_partition(parent, children, names):
+    """The children are the parent's, carry ``names`` in order and partition
+    it: no gap, no overlap, the durations add up within 2 us."""
+    assert [c["name"] for c in children] == list(names)
+    assert all((c["trace_id"], c["parent_id"], c["tid"]) == (parent["trace_id"], parent["span_id"], parent["tid"])
+               for c in children)
+    cuts = [ns(c) for c in children]
+    assert cuts[0][0] == ns(parent)[0] and cuts[-1][1] == ns(parent)[1]
+    assert all(a[1] == b[0] for a, b in zip(cuts, cuts[1:]))
+    assert all(lo <= hi for lo, hi in cuts)
+    assert abs(sum(c["dur"] for c in children) - parent["dur"]) <= 2.0  # us
+
+
+def children_of(tracer, parent):
+    return sorted((e for e in spans(tracer) if e["parent_id"] == parent["span_id"]), key=lambda e: e["ts"])
+
+
+def frames_of(tracer):
+    """The ``daemon.<op>`` frame spans (not their phases or turns), in time."""
+    return sorted((e for e in spans(tracer) if e["name"].count(".") == 1 and e["name"].startswith("daemon.")
+                   and not e["name"].startswith("daemon.stage_")), key=lambda e: e["ts"])
+
+
+#: the daemon's two serving planes: a thread a connection, or the reactor's pool
+PLANES = {"threads": {}, "reactor": {"server_workers": 2}}
+
+
+@pytest.fixture(params=list(PLANES))
+def plane(request):
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=1,
+                          **PLANES[request.param])
+    served = ShuffleDaemon(conf, num_executors=1, port=0)
+    clients = [DaemonClient(served.address) for _ in range(2)]
+    yield served, clients
+    for c in clients:
+        c.close()
+    served.close()
+
+
+def test_write_phases_partition_a_sampled_frame_and_skip_the_rest(daemon, tracer):
+    served, client = daemon
+    tracer.enable()
+    daemon_job(client, 0)
+    tracer.disable()
+    writes = [f for f in frames_of(tracer) if f["name"] == "daemon.write_partition"]
+    assert len(writes) == FRAMES
+    sampled = [i for i in range(FRAMES) if i % WRITE_PHASES_EVERY == 1]
+    assert sampled[0] == 1 and len(sampled) >= 2  # from the second frame on: it has a frame before it
+    for i, frame in enumerate(writes):
+        children = children_of(tracer, frame)
+        if i in sampled:
+            assert_partition(frame, children, WRITE_PHASES)
+        else:
+            assert not children
+    # none but a write or a fetch is opened, and nothing is recorded twice
+    names = collections.Counter(e["name"] for e in spans(tracer))
+    assert all(names[p] == len(sampled) for p in WRITE_PHASES)
+    assert names["daemon.client_turn.write_partition"] == len(sampled)
+    assert not [n for n in names if n.startswith("daemon.") and n.count(".") > 1
+                and not n.startswith(("daemon.write_partition.", "daemon.client_turn."))]
+
+
+def test_fetch_phases_partition_every_frame(daemon, tracer):
+    served, client = daemon
+    tracer.enable()
+    daemon_job(client, 0, fetch=True)
+    tracer.disable()
+    fetches = [f for f in frames_of(tracer) if f["name"] == "daemon.fetch_block"]
+    assert len(fetches) == FRAMES // 4  # one a reducer
+    for frame in fetches:
+        assert_partition(frame, [c for c in children_of(tracer, frame) if c["name"] in FETCH_PHASES], FETCH_PHASES)
+    # the exchange the first fetch ran at the stage boundary is inside its ``locate``
+    [stage] = spans(tracer, "daemon.stage_exchange")
+    first_locate = children_of(tracer, fetches[0])[0]
+    assert first_locate["name"] == "daemon.fetch_block.locate" and inside(stage, first_locate)
+    assert len(spans(tracer, "daemon.client_turn.fetch_block")) == len(fetches)
+
+
+def test_client_turn_ends_where_its_frame_begins(daemon, tracer):
+    """One connection: a turn is a root span that runs from the end of the
+    frame before it to the begin of the frame it is named for, so the
+    connection's frames and turns tile its time."""
+    served, client = daemon
+    tracer.enable()
+    daemon_job(client, 0, fetch=True)
+    tracer.disable()
+    frames = frames_of(tracer)
+    assert len({f["tid"] for f in frames}) == 1  # one connection, one serving thread
+    begins = {ns(f)[0]: i for i, f in enumerate(frames)}
+    turns = [e for e in spans(tracer) if e["name"].startswith("daemon.client_turn.")]
+    assert turns
+    for turn in turns:
+        assert turn["parent_id"] == 0 and turn["trace_id"] not in {f["trace_id"] for f in frames}
+        i = begins[ns(turn)[1]]  # ends where a frame begins ...
+        assert turn["name"] == "daemon.client_turn." + frames[i]["name"].split(".", 1)[1]  # ... and is named for it
+        assert i > 0 and ns(turn)[0] == ns(frames[i - 1])[1]  # begins where the frame before ended
+
+
+def test_client_turns_and_write_samples_are_kept_per_connection(plane, tracer):
+    """Two connections on either serving plane: each counts its own
+    ``write_partition`` frames, and a connection that paused has a turn as
+    long as its pause whatever the other connection did meanwhile."""
+    served, (a, b) = plane
+    mappers, reducers = 2, 11
+    a.create_shuffle(0, mappers, reducers)
+    writers = [a.open_map_writer(0, 0), b.open_map_writer(0, 1)]
+    tracer.enable()
+    for r in range(reducers):  # frame about: 22 frames, 11 a connection
+        for client, writer in zip((a, b), writers):
+            client.write_partition(writer, r, bytes([r]) * 300)
+    for client, writer in zip((a, b), writers):
+        client.commit_map(writer)
+    block = [ShuffleBlockId(0, 0, 0)]
+    assert bytes(a.fetch_blocks(block)[0]) == bytes([0]) * 300
+    pause = 0.05
+    time.sleep(pause)
+    for _ in range(3):
+        b.fetch_blocks(block)
+    a.fetch_blocks(block)  # a's turn began before the pause; b's frames lie inside it
+    for client in (a, b):
+        # a frame's phases are recorded after its reply is sent, before the
+        # connection's next frame is read: one more frame each, and they are in
+        client.stats(0)
+    tracer.disable()
+    names = collections.Counter(e["name"] for e in spans(tracer))
+    per_connection = len([i for i in range(reducers) if i % WRITE_PHASES_EVERY == 1])
+    in_common = len([i for i in range(2 * reducers) if i % WRITE_PHASES_EVERY == 1])
+    assert names["daemon.write_partition.meta"] == 2 * per_connection != in_common
+    last = [f for f in frames_of(tracer) if f["name"] == "daemon.fetch_block"][-1]
+    [turn] = [e for e in spans(tracer, "daemon.client_turn.fetch_block") if ns(e)[1] == ns(last)[0]]
+    assert turn["dur"] >= pause * 1e6
+    # b's turns between its three fetches are its own: none spans the pause
+    others = [e for e in spans(tracer, "daemon.client_turn.fetch_block") if e is not turn]
+    assert len(others) == 4 and sorted(e["dur"] for e in others)[1] < pause * 1e6
+    a.remove_shuffle(0)
+
+
+def test_the_marks_of_a_connection_go_with_it_and_with_tracing(daemon, tracer):
+    served, client = daemon
+    tracer.enable()
+    second = DaemonClient(served.address)
+    second.create_shuffle(9, 1, 1)
+    client.create_shuffle(8, 1, 1)
+    assert len(served._turns) == 2
+    second.close()
+    for _ in range(200):  # until the serving thread has seen the close
+        if len(served._turns) == 1:
+            break
+        time.sleep(0.01)
+    assert len(served._turns) == 1
+    tracer.disable()
+    client.remove_shuffle(8)  # the first untraced frame drops what is left
+    assert not served._turns
+
+
+# -- a reduce task's read on the host, opened ---------------------------------
+
+WINDOW_CHILDREN = ("read.window.fetch", "read.window.decode", "read.window.consumer")
+
+
+@pytest.fixture(params=[50, 2], ids=["one-window-a-task", "pipelined-windows"])
+def host_job(request):
+    """5 mappers x 8 reducers of 1-3 records a block through a manager; with 2
+    blocks a request a task is three windows and the default credit budget
+    issues them ahead of consumption (``_fetch_windows_pipelined``)."""
+    from sparkucx_tpu.shuffle.manager import TpuShuffleManager
+    from sparkucx_tpu.shuffle.reader import serialize_records
+
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 20, block_alignment=128, num_executors=2,
+                          max_blocks_per_request=request.param)
+    mappers, reducers = 5, 8
+    written = {}
+    with TpuShuffleManager(conf, num_executors=2) as manager:
+        manager.register_shuffle(0, mappers, reducers)
+        for m in range(mappers):
+            writer = manager.get_writer(0, m)
+            for r in range(reducers):
+                records = [(m * 100 + r * 10 + i, bytes([m, r, i]) * 40) for i in range(1 + (m + r) % 3)]
+                written.setdefault(r, []).extend(records)
+                with writer.get_partition_writer(r).open_stream() as stream:
+                    stream.write(serialize_records(records))
+            writer.commit_all_partitions()
+        manager.run_exchange(0)
+        yield manager, written, -(-mappers // request.param)
+
+
+def read_all(manager, reducers, slow_ms=0.0):
+    """Every reduce task drained through ``read()``; returns the records and
+    the readers' byte and record counts."""
+    def slow(payload):
+        from sparkucx_tpu.shuffle.reader import default_deserializer
+
+        for rec in default_deserializer(payload):
+            time.sleep(slow_ms / 1e3)
+            yield rec
+
+    got, counts = {}, []
+    for r in range(reducers):
+        reader = manager.get_reader(0, r, r + 1, deserializer=slow) if slow_ms else manager.get_reader(0, r, r + 1)
+        got[r] = []
+        for rec in reader.read():
+            if slow_ms:
+                time.sleep(2 * slow_ms / 1e3)  # the consumer's turn, twice the decoder's
+            got[r].append(rec)
+        counts.append((reader.metrics.records_read, reader.metrics.remote_bytes_read, reader.metrics.remote_blocks_fetched))
+    return got, counts
+
+
+def test_read_window_children_lie_end_to_end_inside_it(host_job, tracer):
+    from sparkucx_tpu.shuffle.reader import WINDOW_TURNS_EVERY
+
+    manager, written, windows_a_task = host_job
+    assert tracer.recording and not tracer.enabled
+    untraced = read_all(manager, len(written))  # and the count of traced windows starts anew
+    assert untraced[0] == written
+    assert not [e for e in spans(tracer) if e["name"] in WINDOW_CHILDREN]  # ``enabled``-only
+    tracer.clear()
+    tracer.enable()
+    slow_ms = 1.0
+    traced = read_all(manager, len(written), slow_ms=slow_ms)
+    tracer.disable()
+    assert traced == untraced  # records, bytes and blocks read: the same with tracing on
+    windows = sorted(spans(tracer, "read.window"), key=lambda e: e["ts"])
+    assert len(windows) == len(written) * windows_a_task
+    sampled = 0
+    for i, window in enumerate(windows):
+        children = children_of(tracer, window)
+        assert all((c["trace_id"], c["tid"], c.get("eid")) == (window["trace_id"], window["tid"], window.get("eid"))
+                   for c in children)
+        fetch = children[0]
+        assert fetch["name"] == "read.window.fetch" and ns(fetch)[0] == ns(window)[0]
+        # one real interval, or the two turns (issue, await) of a window issued ahead
+        assert fetch.get("args") == (None if windows_a_task == 1 else {"turns": 2})
+        if i % WINDOW_TURNS_EVERY:  # one window in five of the process, from the first
+            assert len(children) == 1 and inside(fetch, window)
+            continue
+        sampled += 1
+        assert [c["name"] for c in children] == list(WINDOW_CHILDREN)
+        _, decode, consumer = children
+        # laid end to end after the real child, inside the window: never overlapping
+        assert ns(fetch)[1] == ns(decode)[0] and ns(decode)[1] == ns(consumer)[0]
+        assert ns(consumer)[1] <= ns(window)[1]
+        assert fetch["dur"] + decode["dur"] + consumer["dur"] <= window["dur"] + 1e-3
+        # the window's records, and whose turn each sleep was
+        turns = decode["args"]["turns"]
+        assert consumer["args"] == decode["args"] and turns >= 1
+        assert decode["dur"] >= turns * slow_ms * 1e3 and consumer["dur"] >= turns * 2 * slow_ms * 1e3
+        assert decode["dur"] < consumer["dur"]
+    assert sampled == -(-len(windows) // WINDOW_TURNS_EVERY)
+    # summed over the sampled windows of one task a window, ``turns`` are its records
+    if windows_a_task == 1:
+        for r in range(0, len(written), WINDOW_TURNS_EVERY):
+            [decode] = [c for c in children_of(tracer, windows[r]) if c["name"] == "read.window.decode"]
+            assert decode["args"]["turns"] == len(written[r])
+
+
+def test_fetch_blocks_without_read_records_the_fetch_only(host_job, tracer):
+    manager, written, windows_a_task = host_job
+    for r in range(2):
+        list(manager.get_reader(0, r, r + 1).fetch_blocks())  # untraced: the count starts anew
+    tracer.clear()
+    tracer.enable()
+    blocks = [bytes(b.data) for b in manager.get_reader(0, 0, 1).fetch_blocks()]
+    tracer.disable()
+    assert len(blocks) == 5
+    windows = spans(tracer, "read.window")
+    assert len(windows) == windows_a_task
+    for window in windows:  # the first is a sampled window: nobody took its turns
+        [fetch] = children_of(tracer, window)
+        assert fetch["name"] == "read.window.fetch" and inside(fetch, window)
 
 
 # -- the device read and the single-round seal -------------------------------
