@@ -27,6 +27,14 @@ self-delimiting):
 Anything else — unknown tags, truncated frames, nesting deeper than
 ``MAX_DEPTH`` — raises ``ValueError``.  Decoding allocates only containers
 and scalars; there is no code path to object construction or callables.
+
+``decode_records`` takes any payload with the buffer protocol — ``bytes``,
+``bytearray``, a ``memoryview`` of any format (shuffle/reader.py serves a
+read-only one of its pooled fetch buffer); one that is not C-contiguous is
+copied once — and hands out nothing that refers to it: a ``b`` value is always
+a ``bytes`` that owns its memory, never a view, because the reader gives the
+buffer back to its pool while a consumer may still hold every value.  That
+copy is the one pass a decoded byte pays.
 """
 
 from __future__ import annotations
@@ -76,8 +84,8 @@ def _encode(obj: Any, out: bytearray, depth: int = 0) -> None:
         out += raw
     elif isinstance(obj, (bytes, bytearray)):
         # zero-copy append: bytearray.__iadd__ copies straight out of the
-        # source buffer — materializing an intermediate bytes() doubled the
-        # allocation on the map-side hot path (PERF.md codec microbench)
+        # source buffer — an intermediate bytes() would double the allocation
+        # on the map-side hot path
         out += b"b"
         out += _U32.pack(len(obj))
         out += obj
@@ -127,77 +135,139 @@ def encode_records(records: Iterable[Any]) -> bytes:
     return bytes(out)
 
 
-def _need(payload: bytes, pos: int, n: int) -> None:
-    if pos + n > len(payload):
-        raise ValueError(
-            f"truncated record frame: need {n} bytes at offset {pos}, "
-            f"have {len(payload) - pos}"
-        )
+#: the tags as the ints that indexing a byte buffer yields
+_NONE, _TRUE, _FALSE, _INT, _BIGINT, _FLOAT, _STR, _BYTES, _TUPLE, _LIST, _MAP = (
+    b"NTFijfsbtlm"
+)
+
+_u32_at = _U32.unpack_from
+_i64_at = _I64.unpack_from
+_f64_at = _F64.unpack_from
 
 
-def _decode(payload: bytes, pos: int, depth: int = 0):
-    if depth > MAX_DEPTH:
-        raise ValueError(f"record nests deeper than MAX_DEPTH={MAX_DEPTH}")
-    _need(payload, pos, 1)
-    tag = payload[pos : pos + 1]
-    pos += 1
-    if tag == b"N":
-        return None, pos
-    if tag == b"T":
-        return True, pos
-    if tag == b"F":
-        return False, pos
-    if tag == b"i":
-        _need(payload, pos, 8)
-        return _I64.unpack_from(payload, pos)[0], pos + 8
-    if tag == b"f":
-        _need(payload, pos, 8)
-        return _F64.unpack_from(payload, pos)[0], pos + 8
-    if tag in (b"j", b"s", b"b"):
-        _need(payload, pos, 4)
-        (n,) = _U32.unpack_from(payload, pos)
-        pos += 4
-        _need(payload, pos, n)
-        raw = payload[pos : pos + n]
-        pos += n
-        if tag == b"j":
-            return int.from_bytes(raw, "big", signed=True), pos
-        if tag == b"s":
-            return str(raw, "utf-8"), pos
-        return bytes(raw), pos
-    if tag in (b"t", b"l", b"m"):
-        _need(payload, pos, 4)
-        (n,) = _U32.unpack_from(payload, pos)
-        pos += 4
-        if tag == b"m":
-            d = {}
-            for _ in range(n):
-                k, pos = _decode(payload, pos, depth + 1)
-                v, pos = _decode(payload, pos, depth + 1)
-                try:
-                    d[k] = v
-                except TypeError:
-                    # container-typed key in a crafted frame: keep the
-                    # documented ValueError error contract
-                    raise ValueError(
-                        f"unhashable map key of type {type(k).__name__}"
-                    ) from None
-            return d, pos
-        items = []
-        for _ in range(n):
-            item, pos = _decode(payload, pos, depth + 1)
-            items.append(item)
-        return (tuple(items) if tag == b"t" else items), pos
-    raise ValueError(f"unknown record tag {bytes(tag)!r} at offset {pos - 1}")
+def _truncated(pos: int, need: int, n: int) -> ValueError:
+    return ValueError(
+        f"truncated record frame: need {need} bytes at offset {pos}, "
+        f"have {n - pos}"
+    )
+
+
+def _byte_view(payload) -> memoryview:
+    """``payload`` as a 1-D ``B``-format view: its index is an int, its slice
+    copies nothing, and ``unpack_from`` reads it in place.  A view of another
+    format or shape is cast; only one that is not C-contiguous pays a copy
+    (``_encode`` does the same for its side)."""
+    view = memoryview(payload)
+    if not view.c_contiguous:
+        view = memoryview(view.tobytes())
+    if view.format != "B" or view.ndim != 1:
+        view = view.cast("B")
+    return view
 
 
 def decode_records(payload) -> Iterator[Any]:
     """Decode a stream of records; raises ``ValueError`` on any malformation
     (unknown tag, truncation, over-deep nesting) — never executes anything.
-    ``payload`` may be any bytes-like (``bytes`` or a read-only ``memoryview``
-    served zero-copy by the fetch iterator, shuffle/reader.py)."""
+    ``payload`` is any object with the buffer protocol: ``bytes``,
+    ``bytearray``, or a ``memoryview`` of any format (the fetch iterator of
+    shuffle/reader.py serves a read-only one of its pooled buffer).  Nothing
+    yielded refers to ``payload``: a ``b`` value is a ``bytes`` of its own.
+
+    One loop, one frame an iteration, for every record shape: a tag is an int
+    compared in the order record streams carry them, a bound is one comparison
+    with the payload's length, and an open container is an entry of ``stack``
+    (so nesting costs no call and ``MAX_DEPTH`` is the stack's height)."""
+    buf = _byte_view(payload)
+    n = len(buf)
     pos = 0
-    n = len(payload)
-    while pos < n:
-        rec, pos = _decode(payload, pos)
-        yield rec
+    # the innermost open container: its tag, the items it still lacks (a map
+    # counts keys and values) and those decoded so far; ``stack`` holds the
+    # same three of every container round it
+    kind, left, items = 0, 0, None
+    stack: list = []
+    while True:
+        if pos >= n:
+            if items is None:
+                return
+            raise _truncated(pos, 1, n)
+        tag = buf[pos]
+        pos += 1
+        if tag == _TUPLE or tag == _LIST or tag == _MAP:
+            end = pos + 4
+            if end > n:
+                raise _truncated(pos, 4, n)
+            count = _u32_at(buf, pos)[0]
+            pos = end
+            if count:
+                if len(stack) >= MAX_DEPTH:
+                    raise ValueError(
+                        f"record nests deeper than MAX_DEPTH={MAX_DEPTH}"
+                    )
+                stack.append((kind, left, items))
+                kind, left, items = tag, (2 * count if tag == _MAP else count), []
+                continue
+            val = () if tag == _TUPLE else [] if tag == _LIST else {}
+        elif tag == _INT:
+            end = pos + 8
+            if end > n:
+                raise _truncated(pos, 8, n)
+            val = _i64_at(buf, pos)[0]
+            pos = end
+        elif tag == _BYTES or tag == _STR or tag == _BIGINT:
+            end = pos + 4
+            if end > n:
+                raise _truncated(pos, 4, n)
+            size = _u32_at(buf, pos)[0]
+            pos = end
+            end += size
+            if end > n:
+                raise _truncated(pos, size, n)
+            if tag == _BYTES:
+                val = bytes(buf[pos:end])  # the one copy: the value owns its bytes
+            elif tag == _STR:
+                val = str(buf[pos:end], "utf-8")
+            else:
+                val = int.from_bytes(buf[pos:end], "big", signed=True)
+            pos = end
+        elif tag == _FLOAT:
+            end = pos + 8
+            if end > n:
+                raise _truncated(pos, 8, n)
+            val = _f64_at(buf, pos)[0]
+            pos = end
+        elif tag == _NONE:
+            val = None
+        elif tag == _TRUE:
+            val = True
+        elif tag == _FALSE:
+            val = False
+        else:
+            raise ValueError(
+                f"unknown record tag {bytes((tag,))!r} at offset {pos - 1}"
+            )
+        # hand ``val`` to the container it belongs to, closing every container
+        # it completes; at the top level it is a record
+        while items is not None:
+            if kind == _MAP and left & 1:  # a map's value: its key came before
+                try:
+                    hash(items[-1])
+                except TypeError:
+                    # container-typed key in a crafted frame: keep the
+                    # documented ValueError error contract
+                    raise ValueError(
+                        f"unhashable map key of type {type(items[-1]).__name__}"
+                    ) from None
+            items.append(val)
+            left -= 1
+            if left:
+                break
+            if kind == _TUPLE:
+                val = tuple(items)
+            elif kind == _LIST:
+                val = items
+            else:
+                pairs = iter(items)
+                val = dict(zip(pairs, pairs))
+            kind, left, items = stack.pop()
+        else:
+            yield val

@@ -543,8 +543,14 @@ def test_client_turn_ends_where_its_frame_begins(daemon, tracer):
 
 def test_client_turns_and_write_samples_are_kept_per_connection(plane, tracer):
     """Two connections on either serving plane: each counts its own
-    ``write_partition`` frames, and a connection that paused has a turn as
-    long as its pause whatever the other connection did meanwhile."""
+    ``write_partition`` frames, and a connection that paused has a turn that
+    runs from its own frame before, over the pause and whatever the other
+    connection did meanwhile, to its next frame.  Judged by order and
+    containment on the tracer's clock, never by a duration: the daemon closes
+    a frame some time after the client has its reply, so a pause that starts
+    at the reply is longer than the turn by as much as the serving thread
+    waited (7 ms under six busy workers), and only a frame's begin is ordered
+    against another connection's calls."""
     served, (a, b) = plane
     mappers, reducers = 2, 11
     a.create_shuffle(0, mappers, reducers)
@@ -557,11 +563,16 @@ def test_client_turns_and_write_samples_are_kept_per_connection(plane, tracer):
         client.commit_map(writer)
     block = [ShuffleBlockId(0, 0, 0)]
     assert bytes(a.fetch_blocks(block)[0]) == bytes([0]) * 300
-    pause = 0.05
-    time.sleep(pause)
+    for _ in range(500):  # the daemon closes a frame after its reply is sent: wait for that
+        if spans(tracer, "daemon.fetch_block"):
+            break
+        time.sleep(0.01)
+    paused_from = time.perf_counter_ns()  # the tracer's clock
+    time.sleep(0.05)
+    paused_until = time.perf_counter_ns()
     for _ in range(3):
         b.fetch_blocks(block)
-    a.fetch_blocks(block)  # a's turn began before the pause; b's frames lie inside it
+    a.fetch_blocks(block)  # a's turn began before the pause; b's frames begin inside it
     for client in (a, b):
         # a frame's phases are recorded after its reply is sent, before the
         # connection's next frame is read: one more frame each, and they are in
@@ -571,12 +582,20 @@ def test_client_turns_and_write_samples_are_kept_per_connection(plane, tracer):
     per_connection = len([i for i in range(reducers) if i % WRITE_PHASES_EVERY == 1])
     in_common = len([i for i in range(2 * reducers) if i % WRITE_PHASES_EVERY == 1])
     assert names["daemon.write_partition.meta"] == 2 * per_connection != in_common
-    last = [f for f in frames_of(tracer) if f["name"] == "daemon.fetch_block"][-1]
-    [turn] = [e for e in spans(tracer, "daemon.client_turn.fetch_block") if ns(e)[1] == ns(last)[0]]
-    assert turn["dur"] >= pause * 1e6
-    # b's turns between its three fetches are its own: none spans the pause
-    others = [e for e in spans(tracer, "daemon.client_turn.fetch_block") if e is not turn]
-    assert len(others) == 4 and sorted(e["dur"] for e in others)[1] < pause * 1e6
+    # every fetch is a round trip, so the frames' order is the calls': a, b, b, b, a
+    first, *theirs, last = [f for f in frames_of(tracer) if f["name"] == "daemon.fetch_block"]
+    assert len(theirs) == 3
+    turns = {ns(e)[1]: e for e in spans(tracer, "daemon.client_turn.fetch_block")}  # by the frame each ends at
+    assert len(turns) == 5
+    # a's turn is a's: it begins where a's own frame before ended, not b's,
+    # and so holds the pause and the begin of each of b's three frames
+    turn = turns[ns(last)[0]]
+    assert ns(turn)[0] == ns(first)[1] <= paused_from and ns(turn)[1] >= paused_until
+    assert all(ns(turn)[0] <= ns(f)[0] <= ns(turn)[1] for f in theirs)
+    # b's turns between its three fetches are its own: each begins where b's
+    # frame before ended, which is after the pause — none spans it
+    for before, frame in zip(theirs, theirs[1:]):
+        assert ns(turns[ns(frame)[0]])[0] == ns(before)[1] >= paused_until
     a.remove_shuffle(0)
 
 
@@ -713,6 +732,26 @@ def test_fetch_blocks_without_read_records_the_fetch_only(host_job, tracer):
     for window in windows:  # the first is a sampled window: nobody took its turns
         [fetch] = children_of(tracer, window)
         assert fetch["name"] == "read.window.fetch" and inside(fetch, window)
+
+
+def test_a_value_read_outlives_its_fetch_buffer(host_job):
+    """``read()`` hands a block's pooled fetch buffer back as soon as its
+    records are out, and a ``groupByKey`` consumer keeps every value: each is
+    compared only after the last block is released and the pool has handed
+    every buffer it took back out again, overwritten."""
+    manager, written, _ = host_job
+    got = {r: list(manager.get_reader(0, r, r + 1).read()) for r in written}
+    taken = [manager.pool.get(bucket) for bucket, stack in manager.pool.stats().items()
+             for _ in range(stack["free"])]
+    try:
+        assert taken  # the fetch buffers did come back
+        for block in taken:
+            block.host_view()[:] = 0xA5
+        assert got == written
+        assert all(type(value) is bytes for records in got.values() for _, value in records)
+    finally:
+        for block in taken:
+            block.close()
 
 
 # -- the device read and the single-round seal -------------------------------
