@@ -94,6 +94,9 @@ class ShuffleReadMetrics:
     hedge_wins: int = 0
     #: hedged fetches the primary beat (hedge buffer quarantined)
     hedge_losses: int = 0
+    #: bytes of the largest fetch window issued: windows are cut by count
+    #: (``max_blocks_per_request``), never by bytes
+    window_bytes_max: int = 0
 
 
 class BlockFetchResult:
@@ -392,7 +395,7 @@ class TpuShuffleReader:
             marks = self._window_marks(wctx)
             try:
                 with TRACER.activate(wctx):
-                    requests = self._issue_window(window)
+                    requests = self._issue_window(window, wctx)
                     self._await_window(requests, len(window))
                 if marks is not None:  # issue and await: one real interval
                     marks.fetch_ns = time.perf_counter_ns() - wctx.t0
@@ -428,7 +431,7 @@ class TpuShuffleReader:
                 wctx = self._start_window_span(len(windows[nxt]))
                 marks = self._window_marks(wctx)
                 with TRACER.activate(wctx):
-                    reqs = self._issue_window(windows[nxt])
+                    reqs = self._issue_window(windows[nxt], wctx)
                 if marks is not None:
                     marks.fetch_ns = time.perf_counter_ns() - wctx.t0
                 issued.append((windows[nxt], wctx, marks, reqs, cost))
@@ -480,9 +483,17 @@ class TpuShuffleReader:
         ]
 
     def _issue_window(
-        self, window: List[ShuffleBlockId]
+        self, window: List[ShuffleBlockId], wctx=None
     ) -> List[Tuple[ShuffleBlockId, MemoryBlock, Request]]:
+        """Issue one window's fetches.  Its bytes, once a window: the
+        ``bytes`` argument of its ``read.window`` span (``wctx``) and
+        ``metrics.window_bytes_max``."""
         sizes = [self.block_sizes(bid.map_id, bid.reduce_id) for bid in window]
+        nbytes = sum(sizes)
+        if nbytes > self.metrics.window_bytes_max:
+            self.metrics.window_bytes_max = nbytes
+        if wctx is not None:
+            wctx.args["bytes"] = nbytes
         if self.pool is not None:
             buffers = self.pool.get_many(sizes)
         else:

@@ -431,7 +431,7 @@ class MapWriter:
                             "region overflow with shm staging — multi-round spill "
                             "requires private staging; raise stagingCapacity"
                         )
-                    self._store._rollover(st)
+                    self._store._rollover(st, peer)
                 start = peer * st.region_size + int(st.region_used[peer])
                 pos = start
                 t0 = perf_counter_ns()
@@ -517,7 +517,7 @@ class MapWriter:
                             "region overflow with shm staging — multi-round spill "
                             "requires private staging; raise stagingCapacity"
                         )
-                    store._rollover(st)  # may wait, lock released, for receives
+                    store._rollover(st, peer)  # may wait, lock released, for receives
             except BaseException:
                 store._release_tenant(st, grow)
                 raise
@@ -703,7 +703,7 @@ class MapWriter:
                                 "region overflow with shm staging — multi-round spill "
                                 "requires private staging; raise stagingCapacity"
                             )
-                        self._store._rollover_device(st)
+                        self._store._rollover_device(st, peer)
                     start = peer * st.region_size + int(st.region_used[peer])
                     if rows:
                         run.append((start // align, rows, src_row, length))
@@ -738,6 +738,9 @@ class MapWriter:
                 counters = self._store._write_stats
                 counters["staged_blocks"] += blocks
                 counters["staged_bytes"] += sum(length for _, length in parts)
+                counters["largest_block_bytes"] = max(
+                    counters["largest_block_bytes"], max((length for _, length in parts), default=0)
+                )
                 counters["copy_ns"] += self._copy_ns
                 counters["lock_wait_ns"] += self._lock_wait_ns
                 counters["inplace_blocks"] += self._inplace_blocks
@@ -953,6 +956,10 @@ class HbmBlockStore:
         #: (partitions that went back to the buffered path), added at
         #: ``commit`` like ``copy_ns``; ``inflight_wait_ns``: what a spill, a
         #: seal, a removal and ``close`` waited for receives in flight.
+        #: What unequal blocks do to staging: ``rollover_tail_bytes`` (once a
+        #: rollover: the free bytes of the region whose overflow rolled the
+        #: round) and the gauge ``largest_block_bytes`` (once a map task at
+        #: its commit: the longest block any committed task staged here).
         #: guarded by self._lock
         self._write_stats: Dict[str, int] = dict.fromkeys(
             ("staged_blocks", "staged_bytes", "rollovers", "spilled_bytes",
@@ -961,7 +968,8 @@ class HbmBlockStore:
              "pool_misses", "pool_dropped_busy", "pool_held_bytes",
              "device_staged_blocks", "device_staged_bytes", "scatter_dispatches",
              "device_stage_ns", "lock_wait_ns", "inplace_blocks", "inplace_bytes",
-             "inplace_fallbacks", "inflight_wait_ns"), 0
+             "inplace_fallbacks", "inflight_wait_ns", "rollover_tail_bytes",
+             "largest_block_bytes"), 0
         )
         #: RAM tier of completed rounds (``_rollover``): capacity bytes of the
         #: RAM rounds live shuffles hold plus the free list never exceed
@@ -1273,9 +1281,10 @@ class HbmBlockStore:
         with self._lock:
             self._write_stats["released_device_bytes"] += nbytes
 
-    def _rollover(self, st: _ShuffleState) -> None:
+    def _rollover(self, st: _ShuffleState, peer: int) -> None:
         """Hand the completed staging epoch on and start the next round
-        (caller holds self._lock).
+        (caller holds self._lock); ``peer`` is the region that could not take
+        the caller's block.
 
         While the store's round buffers fit its RAM budget
         (``conf.max_host_pool_bytes``; ``_admit_ram_round``) the round's
@@ -1313,7 +1322,7 @@ class HbmBlockStore:
         ``store.spill`` fires only on the disk arm, where the rollover's self
         time is the zeroing of the used prefixes; on the RAM arm it is
         bookkeeping."""
-        with self._rollover_span(st):
+        with self._rollover_span(st, peer):
             staging, used = st.staging, st.region_used
             if self._admit_ram_round(staging.nbytes, reuse=True):
                 st.prev_rounds.append((staging, used))
@@ -1495,19 +1504,26 @@ class HbmBlockStore:
             stats["pool_held_bytes"] += nbytes
 
     @contextmanager
-    def _rollover_span(self, st: _ShuffleState):
-        """Span ``store.rollover`` and the ``rollovers`` / ``rollover_ns``
-        counters round one rollover's body (caller holds self._lock)."""
+    def _rollover_span(self, st: _ShuffleState, peer: int):
+        """Span ``store.rollover`` and the ``rollovers`` / ``rollover_ns`` /
+        ``rollover_tail_bytes`` counters round one rollover's body
+        (caller holds self._lock).  The tail is what region ``peer``, whose
+        overflow rolls the round, had free: a round rolls when ONE region
+        cannot take the next block, so it rolls with up to a block's bytes
+        unused there — little under level blocks, up to the largest block
+        under skewed ones."""
         t0 = perf_counter_ns()
+        tail = st.region_size - int(st.region_used[peer])
         with span(
             "store.rollover", shuffle_id=st.shuffle_id, round=st.round,
-            executor=self.executor_id, bytes=int(st.region_used.sum()),
+            executor=self.executor_id, bytes=int(st.region_used.sum()), tail_bytes=tail,
         ):
             yield
         self._write_stats["rollovers"] += 1
+        self._write_stats["rollover_tail_bytes"] += tail
         self._write_stats["rollover_ns"] += perf_counter_ns() - t0
 
-    def _rollover_device(self, st: _ShuffleState) -> None:
+    def _rollover_device(self, st: _ShuffleState, peer: int) -> None:
         """Device-round analogue of ``_rollover``: pull the full device
         staging array D2H ONCE as the round snapshot (the spill boundary is
         where a host copy is unavoidable — HBM cannot hold every round), let
@@ -1519,7 +1535,7 @@ class HbmBlockStore:
         free list.  Same ``store.rollover`` span and counters as
         ``_rollover``; its self time here is the wait for the scatters and
         the D2H."""
-        with self._rollover_span(st):
+        with self._rollover_span(st, peer):
             snap = np.asarray(self._device_round(st)).reshape(-1).view(np.uint8)
             st.device_staging = None
             if self._admit_ram_round(snap.nbytes, reuse=False):

@@ -504,7 +504,14 @@ class TpuShuffleCluster:
             )
             for rnd in range(num_rounds)
         )
-        used_total = sum(int(np.sum(sr[1])) for s in sealed for sr in s)
+        # the job's (sender, destination) lanes in rows, every round summed:
+        # what the key law did to the exchange, read off the same matrices
+        lanes = np.zeros((n, n), dtype=np.int64)
+        for sender, s in enumerate(sealed):
+            for _, size_rows in s:
+                lanes[sender] += size_rows
+        used_total, lane_max = int(lanes.sum()), int(lanes.max())
+        recv_rows = [int(rows) for rows in lanes.sum(axis=0)]
         signals = PlanSignals.from_registry(self.metrics)
         ctx = PlanContext(
             num_executors=n,
@@ -526,6 +533,19 @@ class TpuShuffleCluster:
             planner=type(self.planner).__name__,
             **plan.describe(),
             **{f"signal_{k}": v for k, v in signals.describe().items()},
+            recv_rows=recv_rows,
+            lane_rows_max=lane_max,
+            lane_rows_mean=used_total / lanes.size,
+        )
+        # Counters ``exchange.plan``, once an exchange: rows received by each
+        # executor, and the job's hottest and mean lane (sums over the
+        # ``exchanges`` counted: one shuffle's are the values themselves).
+        self.stats.record_counters(
+            "exchange.plan",
+            exchanges=1,
+            lane_rows_max=lane_max,
+            lane_rows_mean=used_total // lanes.size,
+            **{f"recv_rows_e{j}": rows for j, rows in enumerate(recv_rows)},
         )
 
         q = plan.slot_rows
@@ -667,7 +687,8 @@ class TpuShuffleCluster:
             # that received nothing: the column sums of the size matrix say
             # how long it is, with no wait for recv_sizes.  Counters
             # ``exchange.d2h``: ``shard_bytes`` (the whole shards),
-            # ``moved_bytes`` (what was pinned for the host),
+            # ``moved_bytes`` (what was pinned for the host), ``used_bytes``
+            # (the rows received: ``moved ÷ used`` is the buckets' overshoot),
             # ``skipped_shards`` / ``sliced_shards``.
             shard_by_device = {s.device: s.data for s in recv.addressable_shards}
             host_src = None
@@ -682,6 +703,7 @@ class TpuShuffleCluster:
                     "exchange.d2h",
                     shard_bytes=sum(a.nbytes for a in shards),
                     moved_bytes=sum(a.nbytes for a in host_src if a is not None),
+                    used_bytes=int(used.sum()) * self.row_bytes,
                     skipped_shards=sum(a is None for a in host_src),
                     sliced_shards=sum(
                         a is not None and a is not whole for a, whole in zip(host_src, shards)

@@ -54,13 +54,14 @@ def expected_d2h(sizes: np.ndarray, plan) -> dict:
     """The ``exchange.d2h`` counters of a one-round shuffle under ``plan``."""
     n, q = len(sizes), plan.slot_rows
     shard_rows = n * q
-    out = dict(shard_bytes=0, moved_bytes=0, skipped_shards=0, sliced_shards=0)
+    out = dict(shard_bytes=0, moved_bytes=0, used_bytes=0, skipped_shards=0, sliced_shards=0)
     for chunk in range(plan.chunks_per_round[0]):
         used = np.clip(sizes - chunk * q, 0, q).sum(axis=0)
         for u in used:
             rows = bucket_rows(int(u), shard_rows)
             out["shard_bytes"] += shard_rows * ROW
             out["moved_bytes"] += rows * ROW
+            out["used_bytes"] += int(u) * ROW
             out["skipped_shards"] += rows == 0
             out["sliced_shards"] += 0 < rows < shard_rows
     return out
@@ -170,7 +171,7 @@ def test_the_counters_are_the_bucketed_used_bytes(rng, n):
         if moved is not None:
             assert (got["moved_bytes"], got["sliced_shards"]) == (moved, sliced)
         used_bytes = int(sizes.sum()) * ROW
-        assert used_bytes <= got["moved_bytes"] < max(2 * used_bytes, 1)
+        assert got["used_bytes"] == used_bytes <= got["moved_bytes"] < max(2 * used_bytes, 1)
 
 
 def test_device_receive_moves_nothing_to_the_host(rng):
