@@ -97,6 +97,14 @@ class ShuffleReadMetrics:
     #: bytes of the largest fetch window issued: windows are cut by count
     #: (``max_blocks_per_request``), never by bytes
     window_bytes_max: int = 0
+    #: blocks (and their bytes) read where they lay: views of this
+    #: executor's received shard, no buffer and no copy
+    #: (``transport.resident_blocks``)
+    resident_blocks: int = 0
+    resident_bytes: int = 0
+    #: blocks a transport's fetch copied (or received) into a result buffer;
+    #: ``resident_blocks + copied_blocks == remote_blocks_fetched``
+    copied_blocks: int = 0
 
 
 class BlockFetchResult:
@@ -110,7 +118,19 @@ class BlockFetchResult:
     bytes out only if the buffer is pooled (about to be recycled), so the
     ``data`` *property* stays valid for collect-into-list consumers; only a
     captured memoryview object itself goes stale at that point.  Constructing
-    with a plain ``bytes`` payload keeps the old copying contract."""
+    with a plain ``bytes`` payload keeps the old copying contract.
+
+    A *borrowed* block (``buf is None``, ``pooled=False``: the block already
+    lay in this process and the transport handed out
+    ``resident_blocks``) has no fetch buffer at all: ``data`` is a read-only
+    view of the executor's received shard, which nothing writes while the
+    shuffle is registered, and ``release()`` / ``detach()`` copy nothing and
+    hand nothing back.  The view holds a reference to its shard: a consumer
+    of raw ``fetch_blocks()`` that keeps ``data`` past ``unregister_shuffle``
+    keeps that shard's memory (or mapping) alive and goes on reading the
+    bytes that were written — never freed memory; one that wants the bytes
+    without the shard takes ``bytes(data)``.  ``read()`` keeps nothing: each
+    value it yields owns its bytes."""
 
     __slots__ = ("block_id", "_data", "_buf", "_pooled", "_san", "_released")
 
@@ -484,34 +504,45 @@ class TpuShuffleReader:
 
     def _issue_window(
         self, window: List[ShuffleBlockId], wctx=None
-    ) -> List[Tuple[ShuffleBlockId, MemoryBlock, Request]]:
+    ) -> List[Tuple[ShuffleBlockId, Any, Optional[Request]]]:
         """Issue one window's fetches.  Its bytes, once a window: the
         ``bytes`` argument of its ``read.window`` span (``wctx``) and
-        ``metrics.window_bytes_max``."""
+        ``metrics.window_bytes_max``.
+
+        Blocks addressed to this reader's own executor, on a transport that
+        hands out ``resident_blocks``, are already in this process: they are
+        borrowed where they lie — ``(bid, view, None)``, no buffer and no
+        request.  Everything else, and a borrow that raises, is a fetch into
+        a result buffer — ``(bid, buf, req)`` — whose copying path names the
+        block at fault and fails it alone, into ``_retry_fetch``."""
         sizes = [self.block_sizes(bid.map_id, bid.reduce_id) for bid in window]
         nbytes = sum(sizes)
         if nbytes > self.metrics.window_bytes_max:
             self.metrics.window_bytes_max = nbytes
         if wctx is not None:
             wctx.args["bytes"] = nbytes
-        if self.pool is not None:
-            buffers = self.pool.get_many(sizes)
-        else:
-            buffers = [MemoryBlock(np.zeros(s, dtype=np.uint8), size=s) for s in sizes]
         groups: dict = {}
-        for bid, buf in zip(window, buffers):
+        for bid, size in zip(window, sizes):
             target = self._spread_target(bid)
             self._window_targets[bid] = target
-            groups.setdefault(target, []).append((bid, buf))
-        requests: List[Tuple[ShuffleBlockId, MemoryBlock, Request]] = []
+            groups.setdefault(target, []).append((bid, size))
+        resident = getattr(self.transport, "resident_blocks", None)
+        requests: List[Tuple[ShuffleBlockId, Any, Optional[Request]]] = []
         for sender, items in groups.items():
+            bids = [bid for bid, _ in items]
+            if resident is not None and sender == self.executor_id:
+                try:
+                    views = resident(bids)
+                except Exception:
+                    pass  # the fetch below fails the block at fault, alone
+                else:
+                    requests.extend((bid, view, None) for bid, view in zip(bids, views))
+                    continue
+            buffers = self._alloc_bufs([size for _, size in items])
             reqs = self.transport.fetch_blocks_by_block_ids(
-                sender,
-                [bid for bid, _ in items],
-                [buf for _, buf in items],
-                [None] * len(items),
+                sender, bids, buffers, [None] * len(items)
             )
-            requests.extend((bid, buf, req) for (bid, buf), req in zip(items, reqs))
+            requests.extend(zip(bids, buffers, reqs))
         return requests
 
     def _start_window_span(self, num_blocks: int):
@@ -577,21 +608,27 @@ class TpuShuffleReader:
                 )
 
     def _flush_read_counters(self) -> None:
-        """Surface the reader's failover telemetry through the transport's
+        """Surface the reader's telemetry through the transport's
         StatsAggregator, where the metrics registry's ``ops`` provider picks
-        it up (``sparkucx_tpu_ops_*_total{kind="read"}``)."""
+        it up (``sparkucx_tpu_ops_*_total{kind="read"}``): once a task, how
+        its blocks were read — borrowed or copied — and, if any, its
+        failover counters."""
         agg = getattr(self.transport, "stats_agg", None)
         if agg is None:
             return
         m = self.metrics
+        counters = dict(
+            resident_blocks=m.resident_blocks,
+            resident_bytes=m.resident_bytes,
+            copied_blocks=m.copied_blocks,
+        )
         if (
             m.failovers
             or m.blocks_retried
             or m.fetch_timeouts
             or m.hedges_issued
         ):
-            agg.record_counters(
-                "read",
+            counters.update(
                 failovers=m.failovers,
                 blocks_retried=m.blocks_retried,
                 fetch_timeouts=m.fetch_timeouts,
@@ -599,6 +636,7 @@ class TpuShuffleReader:
                 hedge_wins=m.hedge_wins,
                 hedge_losses=m.hedge_losses,
             )
+        agg.record_counters("read", **counters)
 
     def _hedge_delay_ns(self) -> int:
         """Hedge delay for the current window: max(observed rx stall p99 over
@@ -627,7 +665,7 @@ class TpuShuffleReader:
         hedge has completed — a stalled primary whose hedge already won must
         not keep the window spinning toward the deadline."""
         for i, (_, _, req) in enumerate(requests):
-            if req.completed():
+            if req is None or req.completed():  # borrowed: nothing in flight
                 continue
             h = hedges.get(i)
             if h is not None and h[1].completed():
@@ -655,7 +693,7 @@ class TpuShuffleReader:
             return
         allows = getattr(self.transport, "breaker_allows", None)
         for i, (bid, _, req) in enumerate(requests):
-            if req.completed() or i in hedges:
+            if req is None or req.completed() or i in hedges:
                 continue
             primary = self.sender_of(bid.map_id)
             actual = self._window_targets.get(bid, primary)
@@ -785,39 +823,51 @@ class TpuShuffleReader:
 
     def _yield_window(self, requests, wctx=None) -> Iterator[BlockFetchResult]:
         prev: Optional[BlockFetchResult] = None
+        metrics = self.metrics
         try:
             self._sweep_abandoned()
             for bid, buf, req in requests:
-                if not req.completed():
-                    # window hit its deadline with this fetch outstanding; the
-                    # recv thread may still scatter into buf, so quarantine it
-                    # (closed by a later sweep once the request settles) and
-                    # fail over with a fresh buffer
-                    self._abandoned.append((buf, req))
-                    with TRACER.activate(wctx):
-                        result, buf = self._retry_fetch(bid, None, None)
+                if req is None:
+                    # borrowed: ``buf`` is the block itself, a read-only view
+                    # of the received shard — no buffer to hand back
+                    view, buf = buf, None
+                    nbytes = view.size
+                    metrics.resident_blocks += 1
+                    metrics.resident_bytes += nbytes
                 else:
-                    result = req.wait(0)
-                    if result.status != OperationStatus.SUCCESS:
-                        # replica failover under the window span: the replica
-                        # server's serve span parents here too, so the merged
-                        # trace shows primary AND replica children
+                    if not req.completed():
+                        # window hit its deadline with this fetch outstanding; the
+                        # recv thread may still scatter into buf, so quarantine it
+                        # (closed by a later sweep once the request settles) and
+                        # fail over with a fresh buffer
+                        self._abandoned.append((buf, req))
                         with TRACER.activate(wctx):
-                            result, buf = self._retry_fetch(bid, buf, result)
-                # Zero-copy hand-off: a read-only view of the recv bytes.
-                # The old `bytes(...)` here copied every fetched block a
-                # second time; now the copy happens only in detach(), and
-                # only for pooled buffers nobody released in time.
-                view = buf.host_view()[: result.stats.recv_size]
-                view.flags.writeable = False
-                self.metrics.remote_bytes_read += int(result.stats.recv_size)
-                self.metrics.remote_blocks_fetched += 1
+                            result, buf = self._retry_fetch(bid, None, None)
+                    else:
+                        result = req.wait(0)
+                        if result.status != OperationStatus.SUCCESS:
+                            # replica failover under the window span: the replica
+                            # server's serve span parents here too, so the merged
+                            # trace shows primary AND replica children
+                            with TRACER.activate(wctx):
+                                result, buf = self._retry_fetch(bid, buf, result)
+                    # Zero-copy hand-off: a read-only view of the recv bytes.
+                    # The old `bytes(...)` here copied every fetched block a
+                    # second time; now the copy happens only in detach(), and
+                    # only for pooled buffers nobody released in time.
+                    view = buf.host_view()[: result.stats.recv_size]
+                    view.flags.writeable = False
+                    nbytes = int(result.stats.recv_size)
+                    metrics.copied_blocks += 1
+                metrics.remote_bytes_read += nbytes
+                metrics.remote_blocks_fetched += 1
+                pooled = buf is not None and self.pool is not None
                 prev = BlockFetchResult(
                     bid,
                     memoryview(view),
                     buf,
-                    pooled=self.pool is not None,
-                    sanitizer=self.pool.sanitizer if self.pool is not None else None,
+                    pooled=pooled,
+                    sanitizer=self.pool.sanitizer if pooled else None,
                 )
                 yield prev
                 prev.detach()
@@ -825,10 +875,13 @@ class TpuShuffleReader:
             if prev is not None:
                 prev.detach()
 
-    def _alloc_buf(self, size: int) -> MemoryBlock:
+    def _alloc_bufs(self, sizes: List[int]) -> List[MemoryBlock]:
         if self.pool is not None:
-            return self.pool.get_many([size])[0]
-        return MemoryBlock(np.zeros(size, dtype=np.uint8), size=size)
+            return self.pool.get_many(sizes)
+        return [MemoryBlock(np.zeros(s, dtype=np.uint8), size=s) for s in sizes]
+
+    def _alloc_buf(self, size: int) -> MemoryBlock:
+        return self._alloc_bufs([size])[0]
 
     def _sweep_abandoned(self) -> None:
         """Close quarantined buffers whose requests have since settled; a

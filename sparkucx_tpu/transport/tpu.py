@@ -1188,10 +1188,53 @@ class TpuShuffleCluster:
         chunk the block sits at its region-relative offset (MapperInfo offsets
         are absolute in the sender's staging buffer; regions are slot-aligned).
         """
+        return self._received_block(
+            self._exchanged_meta(shuffle_id), consumer, map_id, reduce_id
+        )
+
+    def resident_blocks(
+        self, consumer: ExecutorId, block_ids: Sequence[ShuffleBlockId]
+    ) -> List[np.ndarray]:
+        """``locate_received_block`` for a batch of one shuffle's blocks that
+        ``consumer`` received: one look-up of the meta, each (round, sender)
+        chunk start summed once a call, and a READ-ONLY uint8 view a block,
+        in order, straight out of the received shard (of the mapping in
+        ``host_recv_mode='memmap'``; the block's own D2H array in
+        ``'device'``).  Nothing is copied and nothing allocated: a view keeps
+        its shard alive, by reference count, for as long as it is held — past
+        ``remove_shuffle`` too, which drops references and deletes nothing.
+        Raises the typed errors of ``locate_received_block`` at the first
+        block that has none."""
+        if not block_ids:
+            return []
+        meta = self._exchanged_meta(block_ids[0].shuffle_id)
+        starts: Dict[Tuple[int, int], int] = {}
+        views = []
+        for bid in block_ids:
+            if bid.shuffle_id != meta.shuffle_id:
+                raise TransportError(f"block {bid} not from shuffle {meta.shuffle_id}")
+            view, _ = self._received_block(meta, consumer, bid.map_id, bid.reduce_id, starts)
+            view.flags.writeable = False
+            views.append(view)
+        return views
+
+    def _exchanged_meta(self, shuffle_id: int) -> _ShuffleMeta:
         meta = self.meta(shuffle_id)
         if not meta.exchanged:
             raise TransportError(f"shuffle {shuffle_id} not exchanged yet")
-        rnd, src_row, rows = self._locate_rows(meta, consumer, map_id, reduce_id)
+        return meta
+
+    def _received_block(
+        self,
+        meta: _ShuffleMeta,
+        consumer: ExecutorId,
+        map_id: int,
+        reduce_id: int,
+        starts: Optional[Dict[Tuple[int, int], int]] = None,
+    ) -> Tuple[np.ndarray, int]:
+        """(view, length) of one block of an exchanged shuffle; ``starts`` as
+        in ``_locate_rows``."""
+        rnd, src_row, rows = self._locate_rows(meta, consumer, map_id, reduce_id, starts)
         if rows == 0:
             return np.empty(0, dtype=np.uint8), 0
         length = meta.mapper_infos[map_id].partitions[reduce_id][1]
@@ -1207,18 +1250,25 @@ class TpuShuffleCluster:
             # the host part is the shard's received prefix: a block past its
             # end was never received, and a short slice would pass for it
             raise TransportError(
-                f"block ({shuffle_id},{map_id},{reduce_id}) at bytes "
+                f"block ({meta.shuffle_id},{map_id},{reduce_id}) at bytes "
                 f"[{start}, {start + length}) lies past the {shard.size} bytes "
                 f"executor {consumer} received in round {rnd}"
             )
         return shard[start : start + length], length
 
     def _locate_rows(
-        self, meta: _ShuffleMeta, consumer: ExecutorId, map_id: int, reduce_id: int
+        self,
+        meta: _ShuffleMeta,
+        consumer: ExecutorId,
+        map_id: int,
+        reduce_id: int,
+        starts: Optional[Dict[Tuple[int, int], int]] = None,
     ) -> Tuple[int, int, int]:
         """Row-granular location of a block inside ``consumer``'s received shard:
         (round, src_row, row_count).  Same offset math as
-        ``locate_received_block`` in rows of ``row_bytes``."""
+        ``locate_received_block`` in rows of ``row_bytes``.  A batch passes
+        one ``starts`` dict for all its blocks: the (round, sender) chunk
+        starts it has summed so far."""
         if meta.owner_of_reduce(reduce_id) != consumer:
             raise TransportError(
                 f"reducer {reduce_id} is owned by executor "
@@ -1240,7 +1290,11 @@ class TpuShuffleCluster:
                 f"not in consumer {consumer}'s region"
             )
         row = self.row_bytes
-        chunk_start = int(meta.recv_sizes[rnd][consumer, :sender].sum())
+        chunk_start = None if starts is None else starts.get((rnd, sender))
+        if chunk_start is None:
+            chunk_start = int(meta.recv_sizes[rnd][consumer, :sender].sum())
+            if starts is not None:
+                starts[rnd, sender] = chunk_start
         return rnd, chunk_start + region_rel // row, -(-length // row)
 
     def _gather_fn(self, impl: Optional[str], num_blocks: int, out_rows: int):
@@ -1405,6 +1459,12 @@ class TpuShuffleTransport(ShuffleTransport):
         self.store.close()
 
     @property
+    def stats_agg(self) -> StatsAggregator:
+        """The cluster's aggregator: where a reader on this facet flushes its
+        ``read`` counters, once a task (``sparkucx_tpu_ops_*{kind="read"}``)."""
+        return self.cluster.stats
+
+    @property
     def recorder(self) -> FlightRecorder:
         """The cluster's flight recorder — exposed per-facet so the chaos
         harness (testing.faults.kill_executor) finds it on any transport."""
@@ -1503,6 +1563,16 @@ class TpuShuffleTransport(ShuffleTransport):
                 cb(result)
             requests.append(req)
         return requests
+
+    def resident_blocks(self, block_ids: Sequence[ShuffleBlockId]) -> List[np.ndarray]:
+        """The blocks this executor received, where they lie: a read-only
+        uint8 view a block, in order, of its received shard
+        (``TpuShuffleCluster.resident_blocks``) — no buffer, no copy, no
+        ``Request``.  What ``TpuShuffleReader`` takes in place of
+        ``fetch_blocks_by_block_ids`` for a window addressed to its own
+        executor; raises ``TransportError`` where that fetch would have
+        completed a block with ``FAILURE``."""
+        return self.cluster.resident_blocks(self.executor_id, block_ids)
 
     def fetch_blocks_device(
         self,

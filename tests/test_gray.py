@@ -524,6 +524,8 @@ class TestHedgedFetches:
             assert m.hedges_issued >= 1
             assert m.hedge_wins >= 1
             assert m.fetch_timeouts == 0  # zero deadline expiries
+            # a hedge's bytes arrive from another executor: copied, never borrowed
+            assert (m.copied_blocks, m.resident_blocks) == (len(payloads), 0)
             # 6 windows x 0.25 s of stall would be >= 1.5 s un-hedged; hedges
             # must keep the read well under the sum of the stalls
             assert elapsed < 1.5

@@ -742,23 +742,45 @@ def test_fetch_blocks_without_read_records_the_fetch_only(host_job, tracer):
 
 
 def test_a_value_read_outlives_its_fetch_buffer(host_job):
-    """``read()`` hands a block's pooled fetch buffer back as soon as its
-    records are out, and a ``groupByKey`` consumer keeps every value: each is
-    compared only after the last block is released and the pool has handed
-    every buffer it took back out again, overwritten."""
+    """A ``groupByKey`` consumer keeps every value ``read()`` yields, and a
+    local read decodes straight out of the received shards (no pool buffer
+    is taken: there is none to come back): each value is compared only after
+    the shuffle is removed, its shards dropped and — where a shard is
+    writable host memory — overwritten.  Each value owns its bytes."""
+    import gc
+
     manager, written, _ = host_job
+    before = manager.pool.stats()
     got = {r: list(manager.get_reader(0, r, r + 1).read()) for r in written}
-    taken = [manager.pool.get(bucket) for bucket, stack in manager.pool.stats().items()
-             for _ in range(stack["free"])]
-    try:
-        assert taken  # the fetch buffers did come back
-        for block in taken:
-            block.host_view()[:] = 0xA5
-        assert got == written
-        assert all(type(value) is bytes for records in got.values() for _, value in records)
-    finally:
-        for block in taken:
-            block.close()
+    assert manager.pool.stats() == before  # a local read takes no fetch buffer
+    shards = [shard for rnd in manager.cluster.meta(0).recv_shards for shard in rnd]
+    assert sum(shard.nbytes for shard in shards) >= sum(len(v) for recs in written.values() for _, v in recs)
+    manager.unregister_shuffle(0)
+    for shard in shards:
+        if shard.flags.writeable:
+            shard[:] = 0xA5
+    del shards
+    gc.collect()
+    assert got == written
+    assert all(type(value) is bytes for records in got.values() for _, value in records)
+
+
+def test_read_family_counts_how_the_blocks_were_read(host_job):
+    """Once a task, not once a block: ``resident_blocks`` / ``resident_bytes``
+    / ``copied_blocks`` of the ``read`` kind, through ``metrics_text()``."""
+    manager, written, _ = host_job
+    _, counts = read_all(manager, len(written))
+    text = manager.cluster.metrics_text()
+
+    def read_counter(name):
+        [line] = [l for l in text.splitlines()
+                  if l.startswith(f"sparkucx_tpu_ops_{name}_total") and 'kind="read"' in l]
+        return int(float(line.rsplit(" ", 1)[1]))
+
+    assert read_counter("resident_blocks") == sum(blocks for _, _, blocks in counts) == 5 * 8
+    assert read_counter("resident_bytes") == sum(nbytes for _, nbytes, _ in counts)
+    assert read_counter("copied_blocks") == 0
+    assert "failovers_total" not in text  # the fault counters only where one is not zero
 
 
 # -- the device read and the single-round seal -------------------------------

@@ -190,6 +190,77 @@ class TestPullFallback:
         assert req.wait(1).status == OperationStatus.FAILURE
 
 
+class TestResidentBlocks:
+    """``resident_blocks``: the blocks an executor received, where they lie —
+    what a local read borrows in place of a fetch into a result buffer."""
+
+    def test_views_are_the_located_blocks_in_order_and_copy_nothing(self, cluster, rng):
+        meta, oracle = _run_shuffle(cluster, 20, 2 * N_EXEC, 2 * N_EXEC, rng)
+        for r in range(2 * N_EXEC):
+            consumer = meta.owner_of_reduce(r)
+            bids = [ShuffleBlockId(20, m, r) for m in reversed(range(2 * N_EXEC)) if oracle[(m, r)]]
+            [shard] = [rnd[consumer] for rnd in meta.recv_shards]
+            writeable = shard.flags.writeable
+            views = cluster.transport(consumer).resident_blocks(bids)
+            assert len(views) == len(bids)
+            for bid, view in zip(bids, views):
+                located, length = cluster.locate_received_block(consumer, 20, bid.map_id, r)
+                assert view.dtype == np.uint8 and view.ndim == 1 and view.size == length
+                assert view.tobytes() == located.tobytes() == oracle[(bid.map_id, r)]
+                assert not view.flags.writeable and memoryview(view).readonly
+                assert np.shares_memory(view, shard)  # no copy: the shard's own bytes
+            assert shard.flags.writeable == writeable  # the views are read-only, the shard as it was
+        assert cluster.transport(0).resident_blocks([]) == []
+
+    def test_every_sender_chunk_start_is_summed_once_a_call(self, cluster, rng, monkeypatch):
+        meta, oracle = _run_shuffle(cluster, 21, 3 * N_EXEC, N_EXEC, rng)
+        r = N_EXEC - 1
+        consumer = meta.owner_of_reduce(r)
+        bids = [ShuffleBlockId(21, m, r) for m in range(3 * N_EXEC) if oracle[(m, r)]]
+        senders = {meta.map_owner[b.map_id] for b in bids}
+        assert len(senders) < len(bids)  # several blocks a sender: the sum would repeat
+
+        class Counting:
+            def __init__(self, sizes):
+                self.sizes, self.sums = sizes, 0
+
+            def __getitem__(self, key):
+                self.sums += 1
+                return self.sizes[key]
+
+        counted = [Counting(sizes) for sizes in meta.recv_sizes]
+        monkeypatch.setattr(meta, "recv_sizes", counted)
+        views = cluster.transport(consumer).resident_blocks(bids)
+        assert [v.tobytes() for v in views] == [oracle[(b.map_id, r)] for b in bids]
+        assert sum(c.sums for c in counted) == len(senders)
+
+    @pytest.mark.parametrize("sid, case, match", [
+        (22, "wrong-owner", "owned by"),
+        (24, "not-exchanged", "not exchanged"),
+        (26, "unknown-shuffle", "unknown shuffle"),
+        (28, "another-shuffle", "not from shuffle"),
+        (30, "map-never-committed", "never committed"),
+    ], ids=lambda v: v if isinstance(v, str) and "-" in v else "")
+    def test_it_raises_the_typed_errors_of_the_single_lookup(self, cluster, rng, sid, case, match):
+        meta, oracle = _run_shuffle(cluster, sid, 2, N_EXEC, rng, max_block=100)
+        consumer = meta.owner_of_reduce(0)
+        good = ShuffleBlockId(sid, 0, 0)
+        t = cluster.transport(consumer)
+        if case == "wrong-owner":
+            t, bids = cluster.transport((consumer + 1) % N_EXEC), [good]
+        elif case == "not-exchanged":
+            cluster.create_shuffle(sid + 1, 1, 1)
+            bids = [ShuffleBlockId(sid + 1, 0, 0)]
+        elif case == "unknown-shuffle":
+            bids = [ShuffleBlockId(9999, 0, 0)]
+        elif case == "another-shuffle":
+            bids = [good, ShuffleBlockId(sid + 1, 0, 0)]
+        else:
+            bids = [good, ShuffleBlockId(sid, 7, 0)]
+        with pytest.raises(TransportError, match=match):
+            t.resident_blocks(bids)
+
+
 class TestStats:
     def test_fetch_stats_recv_size(self, cluster, rng):
         meta, oracle = _run_shuffle(cluster, 8, 2, 2, rng, max_block=500)

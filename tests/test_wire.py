@@ -550,6 +550,9 @@ class TestReaderCreditPipelining:
             assert got == payloads  # window order, every byte intact
             assert reader.metrics.remote_blocks_fetched == len(payloads)
             assert reader.metrics.remote_bytes_read == sum(sizes)
+            # bytes that arrive from elsewhere: a pooled buffer a block, none borrowed
+            assert (reader.metrics.copied_blocks, reader.metrics.resident_blocks) == (len(payloads), 0)
+            assert sum(s["requests"] for s in pool.stats().values()) == len(payloads)
         finally:
             a.close()
             b.close()
