@@ -7,6 +7,10 @@ driver handles the one data-dependent decision — splitter-skew overflow —
 by re-running with doubled receive headroom.
 
 Run: python examples/03_terasort.py               (any backend; up to 4 executors)
+
+The served-path form of this job's shuffle alone (100-byte records through
+get_writer / run_exchange / get_reader(...).read_batches(), the sort left to the
+consumer) is the benchmark cell ts10gb-batchjobs-1chip: benchmark/traffic/manager-batchjobs.py.
 """
 
 import os

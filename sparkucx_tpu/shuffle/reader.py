@@ -105,6 +105,9 @@ class ShuffleReadMetrics:
     #: blocks a transport's fetch copied (or received) into a result buffer;
     #: ``resident_blocks + copied_blocks == remote_blocks_fetched``
     copied_blocks: int = 0
+    #: ``read_batches()``: batches handed out, one a block (``records_read``
+    #: counts the records in them)
+    record_batches: int = 0
 
 
 class BlockFetchResult:
@@ -150,6 +153,12 @@ class BlockFetchResult:
         self._released = False
         if sanitizer is not None:
             sanitizer.export_view(buf)
+
+    @property
+    def pooled(self) -> bool:
+        """``data`` lies in a pooled fetch buffer that is recycled at
+        ``release()`` / ``detach()``: bytes kept longer must be copied out."""
+        return self._pooled
 
     @property
     def data(self):
@@ -250,6 +259,69 @@ def pickle_serialize_records(records: Iterable[Any]) -> bytes:
     for rec in records:
         pickle.dump(rec, bio, protocol=pickle.HIGHEST_PROTOCOL)
     return bio.getvalue()
+
+
+class RaggedBlockError(ValueError):
+    """A block handed to a fixed-width serializer whose length is no whole
+    number of records (the typed codec's "malformed frame" rule: refused by
+    name, never floored)."""
+
+    def __init__(self, nbytes: int, record_bytes: int, block_id: Optional[ShuffleBlockId] = None) -> None:
+        self.block_id = block_id
+        self.nbytes = nbytes
+        self.record_bytes = record_bytes
+        where = f"block {block_id.name}" if block_id is not None else "a block"
+        super().__init__(
+            f"{where} of {nbytes} B is not a whole number of {record_bytes} B records "
+            f"({nbytes % record_bytes} B over)"
+        )
+
+
+class FixedWidthSerializer:
+    """Records of one fixed width, back to back with no framing — what Spark
+    gives a job like TeraSort through ``ShuffleDependency.serializer``; the
+    caller names the width, nothing is detected.  A record is ``record_bytes``
+    bytes, its first ``key_bytes`` the key.
+
+    Writer side: ``serialize(rows)`` turns an ``(n, record_bytes)`` ``uint8``
+    array into a block's bytes — a view of the array where it is contiguous,
+    one copy otherwise.  Reader side: hand the serializer to ``get_reader``
+    as its ``deserializer``; ``TpuShuffleReader.read_batches()`` then gives
+    each block as ONE batch, ``batch(data)``: a read-only ``(n,
+    record_bytes)`` view, no Python a record.  Called as a plain
+    deserializer (``read()``) it yields ``(key, value)`` ``bytes`` pairs, a
+    record at a time."""
+
+    __slots__ = ("record_bytes", "key_bytes")
+
+    def __init__(self, record_bytes: int, key_bytes: int = 0) -> None:
+        if not (record_bytes > 0 and 0 <= key_bytes <= record_bytes):
+            raise ValueError(f"record_bytes {record_bytes} / key_bytes {key_bytes}: no such record")
+        self.record_bytes = int(record_bytes)
+        self.key_bytes = int(key_bytes)
+
+    def serialize(self, rows: np.ndarray) -> memoryview:
+        rows = np.asarray(rows)
+        if rows.dtype != np.uint8 or rows.ndim != 2 or rows.shape[1] != self.record_bytes:
+            raise ValueError(
+                f"records of shape {rows.shape} {rows.dtype}, not (n, {self.record_bytes}) uint8"
+            )
+        return memoryview(np.ascontiguousarray(rows).reshape(-1))
+
+    def batch(self, data, block_id: Optional[ShuffleBlockId] = None) -> np.ndarray:
+        """The block ``data`` as one batch: a read-only view, no byte moved."""
+        flat = np.frombuffer(data, dtype=np.uint8)
+        if flat.size % self.record_bytes:
+            raise RaggedBlockError(flat.size, self.record_bytes, block_id)
+        rows = flat.reshape(-1, self.record_bytes)
+        rows.flags.writeable = False
+        return rows
+
+    def __call__(self, payload) -> Iterator[Tuple[bytes, bytes]]:
+        k = self.key_bytes
+        for row in self.batch(payload):
+            raw = row.tobytes()
+            yield raw[:k], raw[k:]
 
 
 def _timed_turns(records: Iterable[Any], marks: _WindowMarks) -> Iterator[Any]:
@@ -612,7 +684,8 @@ class TpuShuffleReader:
         StatsAggregator, where the metrics registry's ``ops`` provider picks
         it up (``sparkucx_tpu_ops_*_total{kind="read"}``): once a task, how
         its blocks were read — borrowed or copied — and, if any, its
-        failover counters."""
+        failover counters and, of a batch read, ``record_batches`` /
+        ``batch_records``."""
         agg = getattr(self.transport, "stats_agg", None)
         if agg is None:
             return
@@ -636,6 +709,8 @@ class TpuShuffleReader:
                 hedge_wins=m.hedge_wins,
                 hedge_losses=m.hedge_losses,
             )
+        if m.record_batches:
+            counters.update(record_batches=m.record_batches, batch_records=m.records_read)
         agg.record_counters("read", **counters)
 
     def _hedge_delay_ns(self) -> int:
@@ -1074,6 +1149,80 @@ class TpuShuffleReader:
         self.metrics.remote_blocks_fetched += len(bids)
         self.metrics.remote_bytes_read += int(table[:, 1].sum())
         return DeviceRead(packed, table, bids)
+
+    def read_batches(self) -> Iterator[np.ndarray]:
+        """This task's records a block at a time: one read-only ``(n,
+        record_bytes)`` ``uint8`` batch a non-empty block, in (reduce, map)
+        order, records unordered as written — for a consumer that works on
+        arrays (a sort, a columnar engine), never a record at a time.  The
+        reader's ``deserializer`` must be a ``FixedWidthSerializer``.
+
+        The normal path: ``fetch_blocks()``'s windows, credit gate, retries,
+        failover and hedges.  A block borrowed where it lay
+        (``resident_blocks``) gives a batch that is a view of the received
+        shard and lives by ``BlockFetchResult``'s rule for borrowed data; a
+        block a fetch copied into a pooled buffer gives a batch that owns its
+        bytes, taken before the buffer goes back.  ``metrics.records_read``
+        counts the records (the sum of the batches' lengths).
+
+        ``aggregator`` / ``key_ordering`` over batches are not supported yet
+        (the ordered return is the next step) and raise here, rather than
+        fall into ``ExternalCombiner`` a record at a time.
+
+        Span ``read.batches``, once a task: a summed span of the reader's own
+        turns — issuing and awaiting the windows, the look-ups, the hand-out
+        of each batch — WITHOUT the caller's turns between batches
+        (``args``: ``blocks``, ``records``, ``bytes``, ``turns``)."""
+        serializer = self.deserializer
+        if not isinstance(serializer, FixedWidthSerializer):
+            raise TypeError(
+                "read_batches() needs a FixedWidthSerializer as the reader's deserializer, "
+                f"not {type(serializer).__name__}"
+            )
+        if self.aggregator is not None or self.key_ordering:
+            raise NotImplementedError(
+                "aggregator / key_ordering over record batches are not supported yet; "
+                "read() combines and sorts a record at a time"
+            )
+        return self._batches(serializer)
+
+    def _batches(self, serializer: "FixedWidthSerializer") -> Iterator[np.ndarray]:
+        metrics = self.metrics
+        clock = time.perf_counter_ns
+        timed = TRACER.active
+        t_open = t = clock() if timed else 0
+        own_ns = 0
+        try:
+            for blk in self.fetch_blocks():
+                try:
+                    batch = serializer.batch(blk.data, blk.block_id)
+                    if blk.pooled:  # the buffer is recycled at release: own the bytes
+                        batch = batch.copy()
+                        batch.flags.writeable = False
+                finally:
+                    blk.release()
+                metrics.records_read += len(batch)
+                metrics.record_batches += 1
+                if timed:
+                    own_ns += clock() - t
+                yield batch
+                if timed:
+                    t = clock()
+            if timed:
+                own_ns += clock() - t  # the turn that found the task drained
+        finally:
+            if timed and TRACER.active:
+                with TRACER.executor_scope(self.executor_id):
+                    TRACER.record_spans(
+                        None,
+                        (("read.batches", t_open, t_open + own_ns),),
+                        args={  # a reader lives for one task: its metrics are the task's
+                            "shuffle_id": self.shuffle_id, "reduce_id": self.start_partition,
+                            "blocks": metrics.record_batches, "records": metrics.records_read,
+                            "bytes": metrics.records_read * serializer.record_bytes,
+                            "turns": metrics.record_batches + 1,
+                        },
+                    )
 
     def read(self) -> Iterator[Any]:
         """deserialize -> combine -> sort (UcxShuffleReader.scala:137-199).

@@ -589,7 +589,10 @@ class TpuShuffleCluster:
             and ``exchange.collective`` (the dispatch).  Counters
             ``exchange.assemble``: ``direct_bytes`` (host bytes handed to
             ``device_put`` as views of a sealed round) and ``copied_bytes``
-            (host bytes that went through a pad / chunk-window copy first)."""
+            (host bytes that went through a pad / chunk-window copy first);
+            where a piece's source is a mapping of the store's disk tier
+            (``np.memmap``) also ``disk_rounds`` / ``disk_bytes``, and its put
+            is ``exchange.h2d``'s child ``exchange.h2d.disk``."""
             faults.check("exchange.submit", shuffle_id=shuffle_id, round=rnd)
             if self.membership.epoch != epoch0:
                 if plan.single_shot:
@@ -613,6 +616,7 @@ class TpuShuffleCluster:
                     size_rows.append(np.zeros(n, dtype=np.int32))
             sub_sizes = np.stack([chunk_size_rows(sr, chunk, q) for sr in size_rows])
             direct_bytes = copied_bytes = 0
+            on_disk = [isinstance(p, np.memmap) for p in payloads]  # the store's disk tier
             with span(
                 "exchange.assemble",
                 shuffle_id=shuffle_id, round=rnd, chunk=chunk,
@@ -642,17 +646,20 @@ class TpuShuffleCluster:
                         else:  # a pad or a strided chunk window: the one copy
                             copied_bytes += piece.nbytes
                     pieces.append(piece)
-            self.stats.record_counters(
-                "exchange.assemble", direct_bytes=direct_bytes, copied_bytes=copied_bytes
-            )
+            assembled = dict(direct_bytes=direct_bytes, copied_bytes=copied_bytes)
+            if any(on_disk):  # pieces whose source is a mapping of the store's disk tier
+                disk_bytes = sum(piece.nbytes for piece, disk in zip(pieces, on_disk) if disk)
+                assembled.update(disk_rounds=sum(on_disk), disk_bytes=disk_bytes)
+            self.stats.record_counters("exchange.assemble", **assembled)
             # What this span measures is the time the device_put calls hold
             # the submit lane (runtime staging copy + enqueue), not the DMA:
             # the transfer itself is asynchronous and is no XLA op.
+            disk_marks = []
             with span(
                 "exchange.h2d",
                 shuffle_id=shuffle_id, round=rnd, chunk=chunk,
                 bytes=direct_bytes + copied_bytes + sub_sizes.size * 4,
-            ):
+            ) as h2d:
                 for i, piece in enumerate(pieces):
                     if piece is None:
                         # Made on the device, no host bytes; and fresh every
@@ -668,11 +675,19 @@ class TpuShuffleCluster:
                         # rounds are immutable until remove_shuffle
                         # (HbmBlockStore.seal), and run_exchange returns only
                         # after every round has drained.
+                        t_put = time.perf_counter_ns() if on_disk[i] else 0
                         pieces[i] = jax.device_put(piece, devices[i])
+                        if on_disk[i]:
+                            disk_marks.append(("exchange.h2d.disk", t_put, time.perf_counter_ns()))
                 data = jax.make_array_from_single_device_arrays(
                     (n * bucketed, lane), data_sharding, pieces
                 )
                 size_mat = jax.device_put(sub_sizes.astype(np.int32), data_sharding)
+            if disk_marks and h2d is not None:
+                # ``exchange.h2d``'s child, once a piece whose source is a
+                # mapping of the disk tier: the time the put holds the lane
+                # reading the file's pages, not the DMA
+                TRACER.record_spans(h2d, disk_marks, args={"round": rnd, "bytes": assembled["disk_bytes"]})
             with span(
                 "exchange.collective",
                 shuffle_id=shuffle_id, round=rnd, chunk=chunk, rows=bucketed,
