@@ -62,6 +62,13 @@ before its body is read (unknown writer, sealed shuffle, a body larger than a
 region) is acked with the error after the body is dropped unread-into-memory:
 the connection stays in step.  The bytes on the wire are what they were.
 
+The body of a ``FetchBlock`` reply lands once too: the daemon sends views of
+the received shards with one ``sendmsg`` (``_serve_fetch``), and
+``DaemonClient.fetch_blocks`` receives the body straight into a landing
+buffer the connection keeps and hands each block out as a read-only view of
+where it landed — no frame-sized buffer a reply, no copy a block; a buffer
+a caller still holds views of is never written again.
+
 Telemetry: every served frame is counted per op (``frames``, ``body_bytes``,
 ``serve_ns`` from the frame header's arrival to the reply sent, ``ack_ns`` the
 reply's send, from its start to the frame's end; always on) — the ``daemon``
@@ -91,6 +98,7 @@ from __future__ import annotations
 
 import json
 import socket
+import sys
 import threading
 from contextlib import nullcontext
 from time import perf_counter_ns
@@ -105,9 +113,16 @@ from sparkucx_tpu.core.definitions import (
     AmId,
     pack_frame,
     pack_frame_prefix,
+    unpack_frame_header,
 )
 from sparkucx_tpu.core.operation import TransportError
-from sparkucx_tpu.obs.metrics import MetricSample, labelled_counter_provider, sample
+from sparkucx_tpu.obs.metrics import (
+    MetricSample,
+    MetricsRegistry,
+    counter_dict_provider,
+    labelled_counter_provider,
+    sample,
+)
 from sparkucx_tpu.service.reactor import Reactor
 from sparkucx_tpu.shuffle.manager import TpuShuffleManager
 from sparkucx_tpu.transport.peer import (
@@ -115,7 +130,6 @@ from sparkucx_tpu.transport.peer import (
     apply_wire_sockopts,
     pack_batch_fetch_req,
     recv_exact,
-    recv_frame,
     unpack_batch_fetch_req,
 )
 from sparkucx_tpu.utils.logging import get_logger
@@ -216,6 +230,32 @@ def _drop_body(sock: socket.socket, n: int, timeout_ms: int) -> None:
         part = scratch[: min(n, len(scratch))]
         _recv_body(sock, part, timeout_ms)
         n -= len(part)
+
+
+#: a kept landing buffer longer than this many times the reply at hand is let
+#: go (``DaemonClient.fetch_blocks``): "much larger" by what the client sees
+LANDING_SLACK = 4
+#: what ``fetch_blocks`` hands out for an empty block of a reply without a body
+_NO_BYTES = memoryview(b"")
+
+
+def _recv_landing(sock: socket.socket, view: memoryview, peer: str) -> None:
+    """Fill ``view`` from the socket: a fetch reply's body straight into the
+    client's landing buffer.  Mid-frame, under the socket's own timeout, in
+    ``recv_exact``'s words: a read that times out means the daemon hung, one
+    that returns nothing that it closed — both ``OSError``, both saying how
+    far the body got."""
+    n, got = len(view), 0
+    while got < n:
+        try:
+            r = sock.recv_into(view[got:], n - got)
+        except socket.timeout:
+            raise OSError(
+                f"peer {peer} hung mid-frame: read timed out with {got}/{n} B received"
+            ) from None
+        if r == 0:
+            raise ConnectionError(f"daemon {peer} closed the connection mid-body with {got}/{n} B received")
+        got += r
 
 
 def _read_frame(sock) -> Optional[Tuple[int, dict, bytes]]:
@@ -740,7 +780,16 @@ class DaemonClient:
     def __init__(self, address: Tuple[str, int], conf: Optional[TpuShuffleConf] = None) -> None:
         self._sock = socket.create_connection(address, timeout=30)
         apply_wire_sockopts(self._sock, conf)
+        self._peer = f"{address[0]}:{address[1]}"
         self._lock = threading.Lock()
+        #: where the body of a ``fetch_blocks`` reply lands: the one buffer
+        #: this connection keeps across calls.  Written again only while no
+        #: view of it lives; replaced by the buffer of a reply it could not take
+        self._landing: Optional[bytearray] = None  #: guarded by self._lock
+        #: always on, bumped once a reply (``fetch_stats()``)
+        self._fetch_stats: Dict[str, int] = dict.fromkeys(
+            ("fetch_replies", "landed_reused", "landed_fresh", "view_blocks", "view_bytes"), 0
+        )  #: guarded by self._lock
 
     def _call(self, op: int, header: dict, body: bytes = b"") -> Tuple[dict, bytes]:
         # the frame's bytes in the frame's order, the body never joined to
@@ -785,29 +834,88 @@ class DaemonClient:
         self._call(DaemonOp.RUN_EXCHANGE, {"shuffle_id": shuffle_id})
 
     def fetch_blocks(self, block_ids) -> list:
-        """Batched data-plane fetch (AM ids 3/4). Returns list of bytes|None."""
+        """Batched data-plane fetch (AM ids 3/4): one entry a requested block,
+        in request order — ``None`` for a block the daemon could not serve,
+        else a **read-only ``memoryview``** of the block's bytes where the
+        reply landed (zero-length for an empty block).  A view compares equal
+        to the ``bytes`` written and is valid for as long as the caller holds
+        it; a value that must outlive it is copied (``bytes(view[a:b])``, as
+        ``default_deserializer`` does).
+
+        The reply's body is received once, straight into this connection's
+        landing buffer, and nothing is copied out of it.  The buffer is kept
+        across calls and written again only when nothing else refers to it (a
+        view holds its owner, so ``sys.getrefcount`` sees every holder — the
+        rule of ``HbmBlockStore._recycle_rounds``) and it is long enough;
+        otherwise this reply gets a new one, which is the kept one from then
+        on (the old one lives as long as its views).  A kept buffer more than
+        ``LANDING_SLACK`` times the reply at hand is let go the same way: a
+        straggler's reply is not held for the life of the connection.
+
+        Counters, once a reply (``fetch_stats()``): ``fetch_replies``;
+        ``landed_reused`` / ``landed_fresh``, replies received into the kept
+        buffer / into a new one (a reply without a body lands nowhere and
+        counts in neither) — ``landed_reused / fetch_replies`` is the share of
+        replies that cost no allocation and no first touch of new pages, near
+        1 for a caller that lets a task's blocks go before its next fetch;
+        ``view_blocks`` / ``view_bytes``, the views handed out and their bytes."""
+        req = pack_frame(AmId.FETCH_BLOCK_REQ, b"", pack_batch_fetch_req(0, block_ids))
         with self._lock:
-            self._sock.sendall(
-                struct.pack("<IQQ", int(AmId.FETCH_BLOCK_REQ), 0, len(pack_batch_fetch_req(0, block_ids)))
-                + pack_batch_fetch_req(0, block_ids)
-            )
-            frame = recv_frame(self._sock)
-        if frame is None:
-            raise ConnectionError("daemon closed connection")
-        _, header, body = frame
-        (count,) = _COUNT.unpack_from(header, _TAG.size)
-        sizes = [
-            _SIZE.unpack_from(header, _TAG.size + _COUNT.size + i * _SIZE.size)[0]
-            for i in range(count)
-        ]
-        out, pos = [], 0
-        for s in sizes:
-            if s < 0:
-                out.append(None)
+            sock, peer = self._sock, self._peer
+            sock.sendall(req)
+            hdr = recv_exact(sock, FRAME_HEADER_SIZE, idle_ok=True, peer=peer)
+            if hdr is None:
+                raise ConnectionError("daemon closed connection")
+            _, hlen, blen = unpack_frame_header(hdr)
+            if hlen + blen > MAX_FRAME_BYTES:
+                raise ValueError(f"frame too large from peer {peer}")
+            header = recv_exact(sock, hlen, peer=peer)
+            if header is None:
+                raise ConnectionError("daemon closed connection")
+            (count,) = _COUNT.unpack_from(header, _TAG.size)
+            sizes = struct.unpack_from(f"<{count}q", header, _TAG.size + _COUNT.size)
+            if sum(s for s in sizes if s > 0) != blen:
+                raise ValueError(f"fetch reply from peer {peer} names other sizes than its body's {blen} B")
+            stats = self._fetch_stats
+            if blen:
+                buf = self._landing
+                # (3 = the attribute, ``buf`` and getrefcount's argument)
+                reused = (
+                    buf is not None
+                    and sys.getrefcount(buf) == 3
+                    and blen <= len(buf) <= LANDING_SLACK * blen
+                )
+                if not reused:
+                    buf = self._landing = bytearray(blen)
+                view = memoryview(buf)
+                _recv_landing(sock, view[:blen], peer)
+                view = view.toreadonly()
+                stats["landed_reused" if reused else "landed_fresh"] += 1
             else:
-                out.append(body[pos : pos + s])
-                pos += s
+                view = _NO_BYTES
+            out, pos, missing = [], 0, 0
+            for s in sizes:
+                if s < 0:
+                    out.append(None)
+                    missing += 1
+                else:
+                    out.append(view[pos : pos + s])
+                    pos += s
+            stats["fetch_replies"] += 1
+            stats["view_blocks"] += count - missing
+            stats["view_bytes"] += blen
         return out
+
+    def fetch_stats(self) -> Dict[str, int]:
+        """The receive's counters (``fetch_blocks`` names them)."""
+        with self._lock:
+            return dict(self._fetch_stats)
+
+    def register_metrics(self, registry: MetricsRegistry) -> None:
+        """The ``daemonclient`` family of ``registry``.  A client lives in the
+        engine's process, which has no registry of its own: one that has adds
+        the counters here, beside its other always-on ones."""
+        registry.register("daemonclient", counter_dict_provider("daemonclient", self.fetch_stats))
 
     def remove_shuffle(self, shuffle_id: int) -> None:
         self._call(DaemonOp.REMOVE_SHUFFLE, {"shuffle_id": shuffle_id})
