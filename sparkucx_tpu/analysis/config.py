@@ -89,6 +89,18 @@ from __future__ import annotations
 #:   robustness PR.  ``._chaos_killed`` is the harness's own idempotency tag
 #:   stamped onto the victim (second kill = no-op) — chaos bookkeeping, not
 #:   transport state, so it stays the harness's private mark.
+#: - transport/tpu.py ``._single_device_array_to_np_array_did_copy``: the one
+#:   place the JAX runtime says whether ``np.asarray`` of an array of a device
+#:   copies (a chip) or hands out a view (the CPU backend) — the observable
+#:   the received shards' landing is chosen from, asked once a cluster with a
+#:   one-word array (``_d2h_copies``); no public API says it.  Guarded: a
+#:   runtime without the method keeps the landing of before.  Reviewed PR 43.
+#: - native/__init__.py ``_multiarray_umath`` / ``._ARRAY_API``: NumPy's data
+#:   allocator hook (NEP 49, ``PyDataMem_SetHandler``) is C API only; the
+#:   table NumPy publishes for extension modules is the way in from ctypes
+#:   (``_numpy_set_handler``), its indices are the ABI.  Guarded: a NumPy
+#:   without it, or one that does not take the handler, means no
+#:   ``LandingPool`` and the allocation of before.  Reviewed PR 43.
 #:
 #: cache-hygiene:
 #: - hbm_store.py ``out_rows``: the scatter output shape IS the staging
@@ -116,6 +128,9 @@ ALLOWLIST = {
     ("core/block.py", "private-access", "._mmap"),
     ("shuffle/daemon.py", "private-access", "._sendmsg_all"),
     ("transport/peer.py", "private-access", "._sendmsg_all"),
+    ("transport/tpu.py", "private-access", "._single_device_array_to_np_array_did_copy"),
+    ("native/__init__.py", "private-access", "_multiarray_umath"),
+    ("native/__init__.py", "private-access", "._ARRAY_API"),
     ("transport/tpu.py", "host-sync", "(via '_recover_and_rerun')"),
     ("store/hbm_store.py", "cache-hygiene", "'out_rows'"),
 }

@@ -79,7 +79,11 @@ class TpuShuffleConf:
     #: (``spill_to_disk``) and buffers are released.  A store bounds it by an
     #: eighth of ``MemAvailable`` at its creation (``stats()``:
     #: ``ram_budget_bytes``).  0 = no RAM tier and no free list: every
-    #: rollover spills and every buffer is released at removal.
+    #: rollover spills and every buffer is released at removal.  The receive
+    #: side takes the same figure for itself, an executor's each: the blocks
+    #: a removed shuffle's received shards landed in stay held for the next
+    #: shuffle's D2H (transport/tpu.py ``_landing``; a chip only), and with
+    #: 0 none is kept.
     max_host_pool_bytes: int = 1 << 31
 
     # transport / workers (L3)
@@ -387,7 +391,9 @@ class TpuShuffleConf:
     #: Where the post-exchange received shards live on the HOST (SURVEY §7's
     #: "HBM budget" hard-part, host half).  ``'array'`` keeps one RAM copy per
     #: round (fastest fetches; ~1x received bytes of host RSS on top of the
-    #: store's staging).  ``'memmap'`` writes each round's shards to disk
+    #: store's staging; on a chip the copies' blocks are kept for the next
+    #: shuffle's landings once released, under ``max_host_pool_bytes`` an
+    #: executor — transport/tpu.py ``_landing``).  ``'memmap'`` writes each round's shards to disk
     #: (``spill_dir``) and serves fetches through ``np.memmap`` views — host
     #: RSS stays bounded by one round regardless of round count, the page
     #: cache does the rest.  ``'device'`` keeps NO host copy at all: fetches

@@ -12,12 +12,14 @@ that report host-side numbers print both, since which path ran decides them.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import glob
 import hashlib
 import os
 import subprocess
 import threading
+import weakref
 from typing import Optional
 
 import numpy as np
@@ -109,6 +111,10 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_void_p, ctypes.c_void_p,
             ctypes.POINTER(TsSegment), ctypes.c_uint64, ctypes.c_int,
         ]
+        lib.ts_landing_pool_new.restype = ctypes.c_void_p
+        lib.ts_landing_pool_new.argtypes = [ctypes.c_uint64, ctypes.c_uint64]
+        lib.ts_landing_pool_retire.argtypes = [ctypes.c_void_p]
+        lib.ts_landing_pool_stats.argtypes = [ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64)]
         lib.ts_version.restype = ctypes.c_uint64
         _lib = lib
         return _lib
@@ -200,6 +206,106 @@ class SharedArena:
         self.close()
         if self.created:
             self.unlink()
+
+
+#: NumPy's C API table holds ``PyDataMem_SetHandler`` at this index since
+#: NumPy 1.22 (``numpy/__multiarray_api.h``; the table is the ABI and its
+#: indices never move)
+_NPY_SET_HANDLER = 304
+_set_handler = None  # resolved once by _numpy_set_handler()
+
+
+def _numpy_set_handler():
+    """NumPy's ``PyDataMem_SetHandler`` (NEP 49) as a Python callable, or
+    None where this NumPy has none.  It is C API only; it is reached through
+    the table NumPy publishes for extension modules (``_ARRAY_API``)."""
+    global _set_handler
+    if _set_handler is None:
+        try:
+            try:
+                from numpy._core import _multiarray_umath as umath
+            except ImportError:  # NumPy 1.x
+                from numpy.core import _multiarray_umath as umath
+            get_pointer = ctypes.pythonapi.PyCapsule_GetPointer
+            get_pointer.restype = ctypes.c_void_p
+            get_pointer.argtypes = [ctypes.py_object, ctypes.c_char_p]
+            table = get_pointer(umath._ARRAY_API, None)
+            addr = (ctypes.c_void_p * (_NPY_SET_HANDLER + 1)).from_address(table)[_NPY_SET_HANDLER]
+            _set_handler = ctypes.PYFUNCTYPE(ctypes.py_object, ctypes.py_object)(addr)
+        except (ImportError, AttributeError, ValueError, TypeError):
+            _set_handler = False
+    return _set_handler or None
+
+
+def _handler_name() -> str:
+    try:
+        from numpy._core.multiarray import get_handler_name
+    except ImportError:  # NumPy 1.x
+        from numpy.core.multiarray import get_handler_name
+    return get_handler_name()
+
+
+class LandingPool:
+    """Host blocks that come back: a NumPy data allocator (NEP 49) that keeps
+    the large blocks its arrays release and hands them to the next array of
+    that size, so a buffer of 64 MiB is pages this process already holds
+    instead of a fresh mapping a time.
+
+    ``with pool.allocating():`` names the pool the allocator of every NumPy
+    array the calling thread creates inside (NumPy keeps the choice in a
+    context variable: other threads are not touched) — also of the array the
+    JAX runtime allocates for ``copy_to_host_async``.  An array remembers its
+    allocator, so wherever its last reference is dropped its block comes back
+    here: kept if it has ``min_bytes`` or more, under ``budget_bytes`` (the
+    blocks that came back longest ago make room), freed otherwise.  Nothing is ever taken from under a
+    live array.  ``None`` from ``create`` where the native library or NumPy's
+    hook is missing: the caller then allocates as it always did."""
+
+    NAME = "sparkucx_tpu_landing"
+
+    def __init__(self, lib, handle: int, set_handler) -> None:
+        self._lib = lib
+        self._handle = handle
+        self._set_handler = set_handler
+        new_capsule = ctypes.pythonapi.PyCapsule_New
+        new_capsule.restype = ctypes.py_object
+        new_capsule.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_void_p]
+        self._capsule = new_capsule(handle, b"mem_handler", None)
+        # the native pool outlives this object (arrays hold its handler); it
+        # only stops keeping blocks
+        weakref.finalize(self, lib.ts_landing_pool_retire, handle)
+
+    @classmethod
+    def create(cls, budget_bytes: int, min_bytes: int = 1 << 20) -> Optional["LandingPool"]:
+        lib, set_handler = _load(), _numpy_set_handler()
+        if lib is None or set_handler is None or budget_bytes <= 0:
+            return None
+        handle = lib.ts_landing_pool_new(int(budget_bytes), int(min_bytes))
+        if not handle:
+            return None
+        pool = cls(lib, handle, set_handler)
+        with pool.allocating():  # does NumPy take it?
+            taken = _handler_name() == cls.NAME
+        return pool if taken else None
+
+    @contextlib.contextmanager
+    def allocating(self):
+        """NumPy arrays the calling thread creates inside come from the pool."""
+        previous = self._set_handler(self._capsule)
+        try:
+            yield self
+        finally:
+            self._set_handler(previous)
+
+    def stats(self) -> dict:
+        """``hits`` / ``misses`` (large allocations served from a kept block /
+        freshly allocated), ``kept_blocks`` / ``dropped_blocks`` (released
+        blocks taken back / freed: refused, or pushed out by newer ones),
+        ``held_bytes``."""
+        out = (ctypes.c_uint64 * 6)()
+        self._lib.ts_landing_pool_stats(self._handle, out)
+        names = ("hits", "misses", "kept_blocks", "dropped_blocks", "held_bytes", "budget_bytes")
+        return dict(zip(names, map(int, out)))
 
 
 def batch_copy(

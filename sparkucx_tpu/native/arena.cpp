@@ -20,9 +20,12 @@
 #include <atomic>
 #include <cerrno>
 #include <cstdint>
+#include <cstdio>
 #include <cstdlib>
 #include <cstring>
 #include <fcntl.h>
+#include <mutex>
+#include <new>
 #include <sys/mman.h>
 #include <sys/stat.h>
 #include <thread>
@@ -141,6 +144,145 @@ void ts_batch_copy(uint8_t* dst, const uint8_t* src, const TsSegment* segs,
   for (int t = 0; t < spawn; ++t) team.emplace_back(worker);
   worker();
   for (auto& th : team) th.join();
+}
+
+// ---------------------------------------------------------------------------
+// Landing pool: a NumPy data allocator (NEP 49) that keeps its large blocks
+// ---------------------------------------------------------------------------
+//
+// The runtime lands a device array on the host in a NumPy array it allocates
+// for that one array; at 64 MiB that is a fresh mapping every time, first
+// touched by the runtime's copy.  NumPy lets a thread name the allocator of
+// the arrays it creates (PyDataMem_SetHandler) and remembers it on the array,
+// so the array's last release comes back here: a block of `min_bytes` or
+// more is then kept — its pages stay the process's — under `budget` bytes,
+// and handed to the next allocation of that size; when the budget is full the
+// blocks that came back longest ago make room.  Every block is plain
+// malloc memory, kept or not, so realloc and a pool that has been emptied
+// need nothing special.  The struct is never freed: arrays may outlive the
+// Python object that made it, and they hold the handler.
+
+struct TsDataMemAllocator {  // numpy/ndarraytypes.h PyDataMemAllocator
+  void* ctx;
+  void* (*malloc)(void* ctx, size_t size);
+  void* (*calloc)(void* ctx, size_t nelem, size_t elsize);
+  void* (*realloc)(void* ctx, void* ptr, size_t new_size);
+  void (*free)(void* ctx, void* ptr, size_t size);
+};
+
+struct TsDataMemHandler {  // PyDataMem_Handler, version 1
+  char name[127];
+  uint8_t version;
+  TsDataMemAllocator allocator;
+};
+
+struct TsLandingBlock {
+  size_t size;
+  void* ptr;
+};
+
+struct TsLandingPool {
+  TsDataMemHandler handler;  // first member: what the capsule points at
+  std::mutex mu;
+  std::vector<TsLandingBlock> kept;  // in the order they came back
+  uint64_t budget = 0, min_bytes = 0, held_bytes = 0;
+  uint64_t hits = 0, misses = 0, kept_blocks = 0, dropped_blocks = 0;
+};
+
+static void* landing_take(TsLandingPool* pool, size_t size) {
+  if (size < pool->min_bytes) return nullptr;
+  std::lock_guard<std::mutex> g(pool->mu);
+  // the block of this size that came back last: the likeliest still cached
+  for (size_t i = pool->kept.size(); i-- > 0;) {
+    if (pool->kept[i].size != size) continue;
+    void* ptr = pool->kept[i].ptr;
+    pool->kept.erase(pool->kept.begin() + i);
+    pool->held_bytes -= size;
+    ++pool->hits;
+    return ptr;
+  }
+  ++pool->misses;
+  return nullptr;
+}
+
+static void* landing_malloc(void* ctx, size_t size) {
+  void* ptr = landing_take(static_cast<TsLandingPool*>(ctx), size);
+  return ptr ? ptr : malloc(size ? size : 1);
+}
+
+static void* landing_calloc(void* ctx, size_t nelem, size_t elsize) {
+  size_t size = nelem * elsize;
+  if (elsize && size / elsize != nelem) return nullptr;
+  void* ptr = landing_take(static_cast<TsLandingPool*>(ctx), size);
+  if (ptr) return memset(ptr, 0, size);
+  return calloc(nelem ? nelem : 1, elsize ? elsize : 1);
+}
+
+static void* landing_realloc(void*, void* ptr, size_t new_size) {
+  return realloc(ptr, new_size ? new_size : 1);
+}
+
+static void landing_free(void* ctx, void* ptr, size_t size) {
+  auto* pool = static_cast<TsLandingPool*>(ctx);
+  std::vector<void*> gone;  // freed outside the lock
+  if (ptr && size >= pool->min_bytes) {
+    std::lock_guard<std::mutex> g(pool->mu);
+    if (size <= pool->budget) {
+      // the newest block stays and the oldest go: sizes nobody asks for any
+      // more must not hold the budget against the sizes in use
+      size_t n = 0;
+      while (pool->held_bytes + size > pool->budget && n < pool->kept.size()) {
+        pool->held_bytes -= pool->kept[n].size;
+        gone.push_back(pool->kept[n++].ptr);
+      }
+      pool->kept.erase(pool->kept.begin(), pool->kept.begin() + n);
+      pool->kept.push_back({size, ptr});
+      pool->held_bytes += size;
+      ++pool->kept_blocks;
+    } else {
+      gone.push_back(ptr);
+    }
+    pool->dropped_blocks += gone.size();
+  } else {
+    gone.push_back(ptr);
+  }
+  for (void* block : gone) free(block);
+}
+
+// A pool keeping blocks of `min_bytes` or more under `budget` bytes; what it
+// returns is also the address of its PyDataMem_Handler.
+void* ts_landing_pool_new(uint64_t budget, uint64_t min_bytes) {
+  auto* pool = new (std::nothrow) TsLandingPool();
+  if (!pool) return nullptr;
+  snprintf(pool->handler.name, sizeof(pool->handler.name), "sparkucx_tpu_landing");
+  pool->handler.version = 1;
+  pool->handler.allocator = {pool, landing_malloc, landing_calloc, landing_realloc, landing_free};
+  pool->budget = budget;
+  pool->min_bytes = min_bytes ? min_bytes : 1;
+  return pool;
+}
+
+// Give the kept blocks back and keep none from now on (the pool's owner is
+// gone); blocks still out are freed as they come back.
+void ts_landing_pool_retire(void* handle) {
+  auto* pool = static_cast<TsLandingPool*>(handle);
+  std::lock_guard<std::mutex> g(pool->mu);
+  pool->budget = 0;
+  for (auto& block : pool->kept) free(block.ptr);
+  pool->kept.clear();
+  pool->held_bytes = 0;
+}
+
+// hits, misses, kept_blocks, dropped_blocks, held_bytes, budget
+void ts_landing_pool_stats(void* handle, uint64_t* out) {
+  auto* pool = static_cast<TsLandingPool*>(handle);
+  std::lock_guard<std::mutex> g(pool->mu);
+  out[0] = pool->hits;
+  out[1] = pool->misses;
+  out[2] = pool->kept_blocks;
+  out[3] = pool->dropped_blocks;
+  out[4] = pool->held_bytes;
+  out[5] = pool->budget;
 }
 
 uint64_t ts_version() { return 1; }

@@ -123,7 +123,7 @@ def _mem_available_bytes() -> Optional[int]:
     return None
 
 
-def _ram_round_budget(conf: TpuShuffleConf) -> int:
+def ram_round_budget(conf: TpuShuffleConf) -> int:
     """Bytes of host RAM a store may hold as completed staging rounds and as
     recycled round buffers: ``conf.max_host_pool_bytes``, bounded by an eighth
     of the host's ``MemAvailable`` at store creation (a host has at most eight
@@ -974,7 +974,7 @@ class HbmBlockStore:
         #: RAM tier of completed rounds (``_rollover``): capacity bytes of the
         #: RAM rounds live shuffles hold plus the free list never exceed
         #: ``_ram_budget`` while the disk tier is on; 0 = every rollover spills
-        self._ram_budget = _ram_round_budget(self.conf)
+        self._ram_budget = ram_round_budget(self.conf)
         self._ram_round_bytes = 0  #: guarded by self._lock
         #: buffer size -> all-zero round buffers of removed shuffles and
         #: demoted rounds, that nothing else refers to (``_recycle_rounds``)

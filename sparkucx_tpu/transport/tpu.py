@@ -55,6 +55,7 @@ from sparkucx_tpu.core.operation import (
     TransportError,
 )
 from sparkucx_tpu.core.transport import ExecutorId, ShuffleTransport
+from sparkucx_tpu.native import LandingPool
 from sparkucx_tpu.parallel.membership import ClusterMembership
 from sparkucx_tpu.parallel.mesh import executor_mesh, surviving_submesh
 from sparkucx_tpu.ops.exchange import bucket_send_rows, rebucket_slots
@@ -67,7 +68,7 @@ from sparkucx_tpu.ops.skew import (
     slice_subround,
 )
 from sparkucx_tpu.shuffle.resolver import degraded_plan, ring_neighbors
-from sparkucx_tpu.store.hbm_store import HbmBlockStore, default_peer_ranges
+from sparkucx_tpu.store.hbm_store import HbmBlockStore, default_peer_ranges, ram_round_budget
 from sparkucx_tpu.testing import faults
 from sparkucx_tpu.transport.executor import (
     build_plan_exchange,
@@ -125,6 +126,34 @@ class _ShuffleMeta:
         raise ValueError(f"reduce_id {reduce_id} unowned")
 
 
+#: a landing shorter than this is not worth keeping a block for (the
+#: allocator's own lists serve it from pages the process holds already)
+LANDING_MIN_BYTES = 1 << 20
+#: ``TpuShuffleCluster._landing_pool`` before the first exchange decides it
+_UNDECIDED = object()
+
+
+def _d2h_copies(device) -> bool:
+    """Does a plain ``np.asarray`` of an array of ``device`` copy?  Asked of
+    the runtime itself, with a one-word array: on a chip it does — the bytes
+    cross into a NumPy array the runtime allocates for that one
+    ``jax.Array`` — on the CPU backend the array already lies in host memory
+    and the answer is a view.  A runtime that cannot be asked is one whose
+    landing stays as it was."""
+    probe = jax.device_put(np.zeros(1, dtype=np.int32), device)
+    try:
+        return bool(probe._single_device_array_to_np_array_did_copy()[1])
+    except AttributeError:
+        return False
+
+
+def _start_landing(prefix) -> None:
+    """Start one received prefix on its way to the host, asynchronously: the
+    runtime allocates the NumPy array the bytes land in HERE, from the
+    calling thread's NumPy allocator, and ``np.asarray`` later waits for it."""
+    prefix.copy_to_host_async()
+
+
 class _MeshChanged(Exception):
     """Internal abort signal: cluster membership changed under an in-flight
     exchange.  Never escapes ``run_exchange`` — it either converts into a
@@ -163,6 +192,9 @@ class TpuShuffleCluster:
         self._exchange_cache: Dict[Tuple[int, int, str], Callable] = {}  #: guarded by self._lock
         #: jitted received-prefix slices by bucket rows (_prefix_fn)
         self._prefix_cache: Dict[int, Callable] = {}  #: guarded by self._lock
+        #: the blocks received shards land in, or None (_landing): decided
+        #: at the first exchange with a host receive mode
+        self._landing_pool = _UNDECIDED  #: guarded by self._lock
         self._lock = threading.RLock()
         #: aggregate per-stage pipeline/exchange timings (occupancy view)
         self.stats = StatsAggregator()
@@ -446,6 +478,29 @@ class TpuShuffleCluster:
                 fn = self._prefix_cache[bucket] = jax.jit(recv_prefix)
         return fn
 
+    def _landing(self) -> Optional[LandingPool]:
+        """Where received shards land, decided once a cluster from what the
+        runtime says: where a plain ``np.asarray`` of a device's array copies
+        (``_d2h_copies``: a chip), every landing is a NumPy array the runtime
+        allocates anew — at 64 MiB a fresh mapping, first touched by the
+        runtime's copy and unmapped again when the shuffle goes — so the
+        submit lane allocates them from a ``LandingPool``: a block comes back
+        when the last view of its shard is dropped and the next shard of that
+        size lands in it, pages this process already holds.  Kept under the
+        store's own rule for host pages held between jobs
+        (``ram_round_budget``), an executor's figure each; past it a shard
+        lands in fresh pages as before.  ``None`` — the landing of before —
+        on the CPU backend, where an array already lies in host memory, and
+        where the native library or NumPy's allocator hook is missing."""
+        with self._lock:
+            if self._landing_pool is _UNDECIDED:
+                self._landing_pool = None
+                if all(_d2h_copies(d) for d in self.mesh.devices.reshape(-1)):
+                    self._landing_pool = LandingPool.create(
+                        ram_round_budget(self.conf) * self.num_executors, LANDING_MIN_BYTES
+                    )
+            return self._landing_pool
+
     def run_exchange(self, shuffle_id: int) -> None:
         """Seal every executor's staging for this shuffle and run ONE collective
         superstep.  After this, every block is resident on its consuming
@@ -572,6 +627,7 @@ class TpuShuffleCluster:
         data_sharding = NamedSharding(self.mesh, P(ax, None))
         devices = list(self.mesh.devices.reshape(-1))
         keep_device = self.conf.keep_device_recv
+        pool = self._landing() if mode != "device" else None
 
         def _submit(rnd, chunk, nchunks):
             """One sub-round's assemble + H2D + collective dispatch + async
@@ -700,39 +756,53 @@ class TpuShuffleCluster:
             # instead of initiating the copy.  What crosses is each shard's
             # received prefix (_received_prefix), and nothing for a consumer
             # that received nothing: the column sums of the size matrix say
-            # how long it is, with no wait for recv_sizes.  Counters
-            # ``exchange.d2h``: ``shard_bytes`` (the whole shards),
-            # ``moved_bytes`` (what was pinned for the host), ``used_bytes``
-            # (the rows received: ``moved ÷ used`` is the buckets' overshoot),
-            # ``skipped_shards`` / ``sliced_shards``.
+            # how long it is, with no wait for recv_sizes.  Where it lands is
+            # the cluster's rule (_landing).  Counters ``exchange.d2h``:
+            # ``shard_bytes`` (the whole shards), ``moved_bytes`` (what was
+            # pinned for the host), ``used_bytes`` (the rows received:
+            # ``moved ÷ used`` is the buckets' overshoot), ``skipped_shards``
+            # / ``sliced_shards``, ``kept_shards`` / ``fresh_shards`` (shards
+            # that land in a block the pool had kept / in newly allocated
+            # pages: with the skipped ones, the shards of a sub-round).
             shard_by_device = {s.device: s.data for s in recv.addressable_shards}
-            host_src = None
+            host_src, landing = None, None
             if mode != "device":
                 shards = [shard_by_device[d] for d in devices]
                 used = sub_sizes.sum(axis=0)
                 host_src = [self._received_prefix(a, int(u)) for a, u in zip(shards, used)]
-                for a in host_src:
-                    if a is not None:
-                        a.copy_to_host_async()
+                moving = [a for a in host_src if a is not None]
+                kept = 0
+                if pool is None:
+                    for a in moving:
+                        _start_landing(a)
+                else:
+                    hits = pool.stats()["hits"]
+                    with pool.allocating():
+                        for a in moving:
+                            _start_landing(a)
+                    kept = pool.stats()["hits"] - hits
+                landing = "kept" if moving and kept == len(moving) else "fresh"
                 self.stats.record_counters(
                     "exchange.d2h",
                     shard_bytes=sum(a.nbytes for a in shards),
-                    moved_bytes=sum(a.nbytes for a in host_src if a is not None),
+                    moved_bytes=sum(a.nbytes for a in moving),
                     used_bytes=int(used.sum()) * self.row_bytes,
-                    skipped_shards=sum(a is None for a in host_src),
+                    skipped_shards=len(host_src) - len(moving),
                     sliced_shards=sum(
                         a is not None and a is not whole for a, whole in zip(host_src, shards)
                     ),
+                    kept_shards=kept,
+                    fresh_shards=len(moving) - kept,
                 )
             recv_sizes.copy_to_host_async()
-            return recv, recv_sizes, shard_by_device, host_src
+            return recv, recv_sizes, shard_by_device, host_src, landing
 
         def _drain_chunk(rnd, chunk, nchunks, ticket):
             """Complete one sub-round host-side (drain-worker thread at
             depth > 1).  Single-shot rounds materialize their whole receive
             state here — including the streamed memmap spill — so host RSS
             keeps the historical one-in-flight-window bound."""
-            recv, recv_sizes, shard_by_device, host_src = ticket
+            recv, recv_sizes, shard_by_device, host_src, landing = ticket
             sizes_host = np.asarray(recv_sizes)
 
             def host_part(j):
@@ -763,6 +833,7 @@ class TpuShuffleCluster:
                     "exchange.d2h",
                     shuffle_id=shuffle_id, round=rnd, chunk=chunk,
                     bytes=sum(a.nbytes for a in host_src if a is not None),
+                    landing=landing,
                 ):
                     host_parts = [host_part(j) for j in range(n)]
             dev_parts = (

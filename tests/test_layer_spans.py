@@ -165,6 +165,30 @@ def test_submit_lane_children_carry_the_rounds_bytes(multi_round, tracer, name):
         assert e["args"]["bytes"] >= 2 * (1 << 22)
 
 
+def test_d2h_is_once_a_sub_round_under_the_drain_and_names_its_landing(tracer):
+    """``exchange.d2h`` (PR 43): still once a sub-round, child of
+    ``exchange.pipeline.drain``, with the bytes that cross and how they
+    landed — on the CPU backend no landing block is kept: ``fresh``."""
+    conf = TpuShuffleConf(staging_capacity_per_executor=1 << 22, block_alignment=128, num_executors=2)
+    cluster = TpuShuffleCluster(conf, num_executors=2)
+    tracer.enable()
+    run_shuffle(cluster, 0, mappers=8, reducers=2, block_bytes=900_000)
+    tracer.disable()
+    drains = spans(tracer, "exchange.pipeline.drain")
+    d2h = spans(tracer, "exchange.d2h")
+    assert len(d2h) == len(drains) >= 2
+    by_id = {d["span_id"]: d for d in drains}
+    counters = cluster.stats.counters("exchange.d2h")
+    for e in d2h:
+        assert set(e["args"]) == {"shuffle_id", "round", "chunk", "bytes", "landing"}
+        assert e["args"]["landing"] == "fresh"
+        assert inside(e, by_id[e["parent_id"]]) and by_id[e["parent_id"]]["args"]["round"] == e["args"]["round"]
+    assert sum(e["args"]["bytes"] for e in d2h) == counters["moved_bytes"]
+    assert counters["kept_shards"] == 0
+    assert counters["fresh_shards"] + counters["skipped_shards"] == 2 * len(d2h)
+    cluster.remove_shuffle(0)
+
+
 def test_store_family_counts_what_was_written(multi_round):
     cluster, written = multi_round
     rows = family(cluster.metrics_text(), "store")

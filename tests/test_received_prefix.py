@@ -54,7 +54,10 @@ def expected_d2h(sizes: np.ndarray, plan) -> dict:
     """The ``exchange.d2h`` counters of a one-round shuffle under ``plan``."""
     n, q = len(sizes), plan.slot_rows
     shard_rows = n * q
-    out = dict(shard_bytes=0, moved_bytes=0, used_bytes=0, skipped_shards=0, sliced_shards=0)
+    out = dict(
+        shard_bytes=0, moved_bytes=0, used_bytes=0, skipped_shards=0, sliced_shards=0,
+        kept_shards=0, fresh_shards=0,  # PR 43: on the CPU backend no landing block is kept
+    )
     for chunk in range(plan.chunks_per_round[0]):
         used = np.clip(sizes - chunk * q, 0, q).sum(axis=0)
         for u in used:
@@ -64,6 +67,7 @@ def expected_d2h(sizes: np.ndarray, plan) -> dict:
             out["used_bytes"] += int(u) * ROW
             out["skipped_shards"] += rows == 0
             out["sliced_shards"] += 0 < rows < shard_rows
+            out["fresh_shards"] += rows > 0
     return out
 
 

@@ -666,7 +666,7 @@ class TestRamRoundTier:
         small.create_shuffle(0, 1, 1)
         assert small.stats(0)["ram_budget_bytes"] == 1 << 30
         monkeypatch.setattr(hbm_store, "_mem_available_bytes", lambda: None)
-        assert hbm_store._ram_round_budget(TpuShuffleConf(max_host_pool_bytes=123)) == 123
+        assert hbm_store.ram_round_budget(TpuShuffleConf(max_host_pool_bytes=123)) == 123
         with pytest.raises(ValueError, match="max_host_pool_bytes"):
             TpuShuffleConf(max_host_pool_bytes=-1).validate()
         conf = TpuShuffleConf.from_spark_conf({"spark.shuffle.tpu.memory.maxHostPoolBytes": "512m"})
