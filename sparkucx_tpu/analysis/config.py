@@ -554,15 +554,14 @@ RETRY_PATH_FUNCS = ("_retry_fetch",)
 #: fields must be in its vocabulary — tier typos become findings instead of
 #: silently-dead dispatch arms.  ``lowering`` carries the union of the plan
 #: tier (stock|pallas|auto) and the kernel lowering it resolves to
-#: (auto|dma|xla|interpret|tiled) because both ride the same field name.
+#: (auto|dma|xla|interpret) because both ride the same field name.
 #: The bare word ``impl`` is deliberately NOT pinned: every op module uses
-#: it for its own local dispatch tiers (ragged|dense|radix|single|...), so
+#: it for its own local dispatch tiers (ragged|dense|single|local|...), so
 #: a global vocabulary for it would be fiction — the plan-level names
-#: (``lowering``, ``exchange_impl``, ``gather_impl``) are the pinned ones.
+#: (``lowering``, ``exchange_impl``) are the pinned ones.
 TIER_VOCAB = {
-    "lowering": ("stock", "pallas", "auto", "dma", "xla", "tiled", "interpret"),
+    "lowering": ("stock", "pallas", "auto", "dma", "xla", "interpret"),
     "exchange_impl": ("stock", "pallas", "auto"),
-    "gather_impl": ("auto", "dma", "tiled", "xla"),
     "combine": ("off", "auto", "dense", "sorted"),
     "codec": ("off", "dict", "rle", "delta"),
     "wire_compress_codec": ("off", "dict", "rle", "delta"),
@@ -576,7 +575,6 @@ TIER_VOCAB = {
 #: unreachable in practice and rots).
 TIER_DOC_KEYS = (
     "exchange_impl",
-    "gather_impl",
     "wire_compress_codec",
     "quantize_mode",
     "planner_mode",

@@ -403,9 +403,6 @@ class TpuShuffleConf:
     #: multi-controller executor honors 'array'/'memmap' per host ('device'
     #: raises there: it releases device shards after the collective).
     host_recv_mode: str = "array"
-    #: Ragged block-gather lowering: 'auto' (pipelined DMA kernel on TPU, XLA
-    #: gather elsewhere) | 'dma' | 'tiled' | 'xla'.
-    gather_impl: str = "auto"
     #: Inter-chip exchange implementation (ops/ici_exchange.py): 'stock'
     #: (default — the byte-for-byte ragged_all_to_all/dense collective path),
     #: 'pallas' (hand-rolled bidirectional-ring supersteps with FAST-style
@@ -602,7 +599,6 @@ class TpuShuffleConf:
             ("numSlices", "num_slices", int),
             ("meshAxisName", "mesh_axis_name", str),
             ("keepDeviceRecv", "keep_device_recv", lambda v: str(v).lower() == "true"),
-            ("gatherImpl", "gather_impl", str),
             ("exchange.impl", "exchange_impl", str),
             ("exchange.fusedCombine", "exchange_fused_combine", lambda v: str(v).lower() == "true"),
             ("partialAggregation", "partial_aggregation", lambda v: str(v).lower() == "true"),
@@ -651,8 +647,6 @@ class TpuShuffleConf:
             raise ValueError("max_blocks_per_request must be positive")
         if self.num_executors <= 0:
             raise ValueError("num_executors must be positive")
-        if self.gather_impl not in ("auto", "dma", "tiled", "xla"):
-            raise ValueError(f"unknown gather_impl {self.gather_impl!r}")
         if self.exchange_impl not in ("stock", "pallas", "auto"):
             raise ValueError(f"unknown exchange_impl {self.exchange_impl!r}")
         if self.num_slices <= 0:

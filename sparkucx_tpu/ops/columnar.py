@@ -41,7 +41,12 @@ from jax import shard_map
 from jax.lax import ragged_all_to_all
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkucx_tpu.ops.exchange import exclusive_cumsum, gather_rows, ragged_params
+from sparkucx_tpu.ops.exchange import (
+    exclusive_cumsum,
+    gather_rows,
+    ragged_params,
+    resolve_collective_impl,
+)
 
 
 @dataclass(frozen=True)
@@ -62,11 +67,7 @@ class ColumnarSpec:
     impl: str = "auto"
 
     def resolve_impl(self, platform: Optional[str] = None) -> "ColumnarSpec":
-        if self.impl != "auto":
-            return self
-        if platform is None:
-            platform = jax.devices()[0].platform
-        return replace(self, impl="ragged" if platform == "tpu" else "dense")
+        return replace(self, impl=resolve_collective_impl(self.impl, platform))
 
 
 def size_matrix_from_owners(axis_name: str, num_executors: int, owners: jnp.ndarray):

@@ -5,16 +5,14 @@ each scheduled window lands (the FAST ring's supersteps, ops/ici_exchange.py),
 it is dequantized and folded into a fixed dense per-group accumulator — the
 EQuARX in-collective-compute argument (PAPERS.md, arXiv:2506.17615) applied to
 the shuffle's reduce side.  Post-exchange memory and D2H drain bytes go from
-O(rows) to O(groups), and under the Pallas DMA lowering the whole exchange is
-ONE kernel launch instead of one dispatch per scheduled item.
+O(rows) to O(groups).
 
-This module is the single source of the combine arithmetic.  Every tier —
-the Pallas kernel epilogue (ops/pallas_kernels.ring_combine_grid), the
-scheduled-XLA walk (ops/ici_exchange.build_combine_exchange), and the
-relational fused body (ops/relational.py) — calls :func:`combine_window` on
+This module is the single source of the combine arithmetic.  Both users —
+the scheduled walk (ops/ici_exchange.build_combine_exchange) and the
+relational fused body (ops/relational.py) — call :func:`combine_window` on
 windows in the SAME canonical order (own slot first, then schedule items in
-step order), so exact dtypes are bit-identical across tiers and against the
-unfused path by construction (tests/test_fused_combine.py pins it).
+step order), so exact dtypes are bit-identical against the unfused path by
+construction (tests/test_fused_combine.py pins it).
 
 Window row layout is the partial-aggregate exchange row
 (ops/relational._aggregate_body): ``[key (uint32 bitcast) | payload | count
@@ -113,9 +111,8 @@ class CombineSpec:
 
 def acc_init(spec: CombineSpec):
     """Fresh accumulator ``(acc_vals (G, width), acc_counts (G, 1))`` — every
-    column at its fold identity, counts zero.  Traced jnp (callable inside
-    kernel bodies); counts stay 2-D so the kernel's VMEM scratch never holds
-    a rank-1 array."""
+    column at its fold identity, counts zero.  Traced jnp (callable inside a
+    shard body); counts are 2-D, one column beside the value columns."""
     import jax.numpy as jnp
 
     cols = [
@@ -131,8 +128,7 @@ def combine_window(spec: CombineSpec, window, acc_vals, acc_counts):
     ``window``: ``(rows, spec.row_width)`` in ``spec.dtype`` lanes, the
     sender-major grid region one schedule item delivered.  Pure jnp over
     static shapes (no per-row scatter): a ``(rows, num_groups)`` one-hot mask
-    turns every fold into a masked column reduction — the vector shape the
-    Pallas epilogue and the XLA walk both lower cleanly.  Invalid rows
+    turns every fold into a masked column reduction.  Invalid rows
     (count == 0: staging padding, quota-truncated tails) hit no group.
     """
     import jax

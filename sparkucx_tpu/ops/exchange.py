@@ -96,6 +96,20 @@ def gather_rows(rows: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return rows[idx]
 
 
+def resolve_collective_impl(impl: str, platform: Optional[str] = None) -> str:
+    """The one place that decides "ragged on TPU, dense elsewhere": ``'auto'``
+    is the ragged collective where the backend lowers it, the dense slot
+    all_to_all everywhere else (XLA:CPU has no ragged_all_to_all kernel); any
+    other value is the caller's own and passes through.  Every spec's
+    ``resolve_impl`` calls this and adds only its own degenerate tiers
+    (``local``, ``single``).  ``platform`` defaults to the first device's."""
+    if impl != "auto":
+        return impl
+    if platform is None:
+        platform = jax.devices()[0].platform
+    return "ragged" if platform == "tpu" else "dense"
+
+
 @dataclass(frozen=True)
 class ExchangeSpec:
     """Static description of one compiled exchange.
@@ -137,11 +151,10 @@ class ExchangeSpec:
         """
         if self.impl != "auto":
             return self
-        if platform is None:
-            platform = jax.devices()[0].platform
-        if platform != "tpu":
-            return replace(self, impl="dense")
-        return replace(self, impl="local" if self.num_executors == 1 else "ragged")
+        impl = resolve_collective_impl(self.impl, platform)
+        if impl == "ragged" and self.num_executors == 1:
+            impl = "local"
+        return replace(self, impl=impl)
 
     def validate(self) -> None:
         if self.send_rows % self.num_executors:

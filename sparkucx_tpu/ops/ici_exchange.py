@@ -17,8 +17,7 @@ transfer.  This module applies that argument to the TPU ICI torus:
   rather than back-to-back.  Offsets take the short way around the ring
   (direction +1 for d <= dim/2), antipodal offsets alternate direction by
   chunk parity so both directions carry equal load.
-* **Lowerings** (mirroring the scatter's dma/tiled/xla tiers,
-  ops/pallas_kernels.py):
+* **Lowerings** of the exchange and of the fused send side:
 
   - ``'dma'`` — Pallas kernel over ``pltpu.make_async_remote_copy``
     (pallas_kernels.ring_exchange_grid): per step, one remote DMA per ring
@@ -49,6 +48,11 @@ transfer.  This module applies that argument to the TPU ICI torus:
   scatter (the device-staging write, ops/pallas_kernels.build_block_scatter)
   with the scheduled exchange in ONE kernel/jit — staging->wire with no
   intermediate HBM round trip and no separate scatter launch.
+
+* **Fused receive side**: :func:`build_combine_exchange` folds every landed
+  window into a dense per-group accumulator (ops/combine.py) instead of
+  compacting it into a receive buffer.  One lowering on every platform: the
+  schedule's permutes with a fold per window.
 
 * **Hierarchy**: on a (dcn, ici) mesh the two phases of the hierarchical
   route (ops/hierarchy.py) each get their OWN ring schedule
@@ -701,14 +705,17 @@ def build_quantized_exchange(
 # ----------------------------------------------------------------------------
 
 
-def _combine_axis_grid_xla(ax, dim: int, slot_rows: int, sched: RingSchedule, flat, me, cspec):
-    """Scheduled-permute fold: one ppermute per item, but every landed window
-    goes straight into the dense accumulator — the sender-major grid is never
-    materialized, so even this tier's post-exchange memory is O(groups).
+def combine_axis_grid(ax, dim: int, slot_rows: int, sched: RingSchedule, flat, me, cspec):
+    """One fused-combine exchange phase: one ppermute per schedule item, and
+    every landed window goes straight into the dense accumulator — the
+    sender-major grid is never materialized, so post-exchange memory is
+    O(groups).  Returns the ``(acc_vals, acc_counts)`` pair (identity-seeded —
+    callers merge running accumulators via ``merge_accumulators``).  Also the
+    shard-body entry point for ops/relational.py's fused aggregate, which
+    runs its own shard_map.
 
-    Fold order is the canonical one every lowering shares (own slot, then
-    schedule items in step order) — bit-equality across tiers for exact
-    dtypes rests on it."""
+    Fold order is canonical (own slot, then schedule items in step order) —
+    bit-equality with the unfused path for exact dtypes rests on it."""
     from sparkucx_tpu.ops.combine import acc_init, combine_window
 
     lane = flat.shape[1]
@@ -728,41 +735,8 @@ def _combine_axis_grid_xla(ax, dim: int, slot_rows: int, sched: RingSchedule, fl
     return accv, accc
 
 
-def combine_axis_grid(
-    ax, dim, slot_rows, sched, flat, me, cspec, lowering, mesh_axes=None
-):
-    """Dispatch one fused-combine exchange phase to its lowering tier and
-    return the ``(acc_vals, acc_counts)`` accumulator pair (identity-seeded —
-    callers merge running accumulators via ``merge_accumulators``).  Also the
-    shard-body entry point for ops/relational.py's fused aggregate, which
-    runs its own shard_map."""
-    lowering = resolve_schedule_lowering(lowering, sched.kind)
-    if lowering == "xla":
-        return _combine_axis_grid_xla(ax, dim, slot_rows, sched, flat, me, cspec)
-    from sparkucx_tpu.ops.combine import acc_init, combine_window
-    from sparkucx_tpu.ops.pallas_kernels import ring_combine_grid
-
-    _grid, accv, accc = ring_combine_grid(
-        ax,
-        dim,
-        slot_rows,
-        slot_rows // sched.chunks,
-        sched.raw_steps(),
-        functools.partial(combine_window, cspec),
-        functools.partial(acc_init, cspec),
-        cspec.num_groups,
-        cspec.width,
-        flat,
-        mesh_axes=mesh_axes,
-        interpret=(lowering == "interpret"),
-    )
-    # the landed grid stays on device and unread — the accumulator IS the
-    # receive side; XLA drops the unused output buffer from the drain
-    return accv, accc
-
-
-def _combine_prep(mesh: Mesh, spec, cspec, lowering: str, chunks_per_dest, schedule):
-    """Shared validation + schedule resolution for the fused-combine builder
+def _combine_prep(mesh: Mesh, spec, cspec, chunks_per_dest, schedule):
+    """Validation + schedule resolution for the fused-combine builder
     (flat meshes only — the combinable payload rides one ring)."""
     if set(mesh.axis_names) == {"dcn", "ici"}:
         raise ValueError("combine exchange supports flat meshes only")
@@ -776,12 +750,10 @@ def _combine_prep(mesh: Mesh, spec, cspec, lowering: str, chunks_per_dest, sched
             f"spec.lane={spec.lane} != combine row width {cspec.row_width} "
             f"(key + payload + count)"
         )
-    platform = mesh.devices.reshape(-1)[0].platform
-    resolved = spec.resolve_impl(platform=platform)
+    resolved = spec.resolve_impl(platform=mesh.devices.reshape(-1)[0].platform)
     resolved.validate()
     if resolved.num_executors == 1:
         raise ValueError("combine ici exchange needs num_executors > 1")
-    low = resolve_ici_lowering(lowering, platform)
     if schedule is None:
         ids = device_slice_ids(mesh.devices.reshape(-1))
         kind = "ici" if ids is None or len(set(ids)) == 1 else "dcn"
@@ -793,8 +765,7 @@ def _combine_prep(mesh: Mesh, spec, cspec, lowering: str, chunks_per_dest, sched
         raise ValueError(
             f"chunks {schedule.chunks} must divide slot_rows {resolved.slot_rows}"
         )
-    low = resolve_schedule_lowering(low, schedule.kind)
-    return platform, resolved, low, schedule
+    return resolved, schedule
 
 
 def build_combine_exchange(
@@ -803,7 +774,6 @@ def build_combine_exchange(
     cspec,
     *,
     chunks_per_dest: int = 1,
-    lowering: str = "auto",
     schedule=None,
 ):
     """Compile the fused-combine exchange: ``fn(data, size_matrix, acc_vals,
@@ -824,23 +794,18 @@ def build_combine_exchange(
     * ``recv_sizes``: the usual ``(n, n)`` receive-size metadata — row
       accounting is unchanged, only the payload drain shrinks to O(groups).
 
-    ``lowering`` follows ``build_ici_exchange``: 'dma' is ONE fused kernel
-    launch (pallas_kernels.ring_combine_grid) on TPU, 'xla' the scheduled
-    permutes with per-window folds, 'interpret' the kernel body under the
-    Pallas interpreter (CI).  Bit-equality across tiers for exact dtypes is
-    pinned by tests/test_fused_combine.py.  Flat meshes only."""
+    One lowering on every platform: the schedule's permutes with a fold per
+    landed window (:func:`combine_axis_grid`).  Flat meshes only."""
     from sparkucx_tpu.ops.combine import merge_accumulators
 
-    platform, resolved, low, schedule = _combine_prep(
-        mesh, spec, cspec, lowering, chunks_per_dest, schedule
-    )
+    resolved, schedule = _combine_prep(mesh, spec, cspec, chunks_per_dest, schedule)
     n, slot = resolved.num_executors, resolved.slot_rows
 
     def body(data, size_row, accv, accc):
         me, sizes = gather_size_matrix(resolved, size_row)
         recv_sizes = sizes[:, me]
         av, ac = combine_axis_grid(
-            resolved.axis_name, n, slot, schedule, data, me, cspec, low
+            resolved.axis_name, n, slot, schedule, data, me, cspec
         )
         accv, accc = merge_accumulators(cspec, (accv, accc), (av, ac))
         return accv, accc, recv_sizes[None, :]
@@ -861,7 +826,6 @@ def build_combine_exchange(
     )
     fn.spec = resolved
     fn.schedule = schedule
-    fn.lowering = low
     fn.cspec = cspec
     return fn
 

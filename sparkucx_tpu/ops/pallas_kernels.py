@@ -18,20 +18,18 @@ slot-layout staging positions in an HBM-resident staging array, so map output
 produced on the chip reaches the exchange without a D2H -> host memcpy -> H2D
 round trip.
 
-Three interchangeable lowerings each (bit-identical results):
+Two lowerings each (bit-identical results), picked by platform where the
+caller names none:
 
 * ``impl='dma'`` — Pallas kernel, one *dynamic-size* HBM->HBM DMA per block,
   K-deep pipelined on a rotating semaphore ring (the DMA engine streams block
   i+1..i+K while block i completes).  This is the TPU analogue of the
   reference's ForkJoin parallel file reads (UcxWorkerWrapper.scala:416-426):
   the DMA engine plays the IO thread pool.  TPU-only (Mosaic supports
-  dynamic-size DMA slices; the interpreter does not).
-* ``impl='tiled'`` — Pallas kernel with *static-size* tile DMAs (full tiles +
-  an overlapping shifted tail, single-row DMAs for sub-tile blocks).  Portable
-  to ``interpret=True``, which is how CI tests the kernel structure on CPU.
-* ``impl='xla'`` — pure jnp fallback: searchsorted + take for the gather,
-  masked ``dynamic_update_slice`` windows for the scatter; the portable path
-  and the oracle the Pallas paths are tested against.
+  dynamic-size DMA slices; the interpreter does not), and what the chip runs.
+* ``impl='xla'`` — pure jnp: searchsorted + take for the gather, masked
+  ``dynamic_update_slice`` windows for the scatter; what every other backend
+  runs, tested against a NumPy oracle (tests/test_pallas_kernels.py).
 
 Sizes here are **rows** of ``lane`` 32-bit elements — the exchange's wire unit
 (one row = the store's block alignment; ops/exchange.py module docstring).
@@ -50,10 +48,6 @@ import numpy as np
 # Pipelining depth of the dynamic-DMA path: how many block copies may be in
 # flight at once (the numIoThreads analogue, UcxShuffleConf.scala:66-71).
 DMA_PIPELINE_DEPTH = 8
-
-# Rows per static-size DMA in the tiled path: 8 sublanes is the int32 native
-# tile height, so a (8, 128) tile is one 4 KiB descriptor.
-TILE_ROWS = 8
 
 
 def _gather_dma_kernel(starts_ref, counts_ref, outs_ref, src_ref, out_ref, sems):
@@ -102,86 +96,23 @@ def _gather_dma_kernel(starts_ref, counts_ref, outs_ref, src_ref, out_ref, sems)
     jax.lax.fori_loop(jnp.maximum(num_blocks - k, 0), num_blocks, drain, 0)
 
 
-def _gather_tiled_kernel(starts_ref, counts_ref, outs_ref, src_ref, out_ref, sem):
-    """Static-size tile DMAs: portable to the Pallas interpreter.
-
-    Per block: full TILE_ROWS tiles, then either one overlapping shifted tail
-    tile (count >= TILE_ROWS — rewrites a few already-correct rows, which is
-    safe because src and dst shift together) or single-row DMAs (count <
-    TILE_ROWS).  Serial start/wait — this lowering is for correctness testing,
-    the dynamic path is the perf path.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    num_blocks = starts_ref.shape[0]
-
-    def copy(src_row, dst_row, rows):
-        dma = pltpu.make_async_copy(
-            src_ref.at[pl.ds(src_row, rows)],
-            out_ref.at[pl.ds(dst_row, rows)],
-            sem,
-        )
-        dma.start()
-        dma.wait()
-
-    def block_body(b, _):
-        start, count, out = starts_ref[b], counts_ref[b], outs_ref[b]
-        full = count // TILE_ROWS
-
-        def tile_body(t, _):
-            copy(start + t * TILE_ROWS, out + t * TILE_ROWS, TILE_ROWS)
-            return 0
-
-        jax.lax.fori_loop(0, full, tile_body, 0)
-
-        tail = count - full * TILE_ROWS
-
-        @pl.when(jnp.logical_and(tail > 0, count >= TILE_ROWS))
-        def _shifted_tail():
-            copy(start + count - TILE_ROWS, out + count - TILE_ROWS, TILE_ROWS)
-
-        @pl.when(count < TILE_ROWS)
-        def _tiny_block():
-            def row_body(r, _):
-                copy(start + r, out + r, 1)
-                return 0
-
-            jax.lax.fori_loop(0, count, row_body, 0)
-
-        return 0
-
-    jax.lax.fori_loop(0, num_blocks, block_body, 0)
-
-
 @jax.named_scope("block_gather")
-def _pallas_gather(kernel, interpret: bool, out_rows: int, starts, counts, outs, src):
+def _pallas_gather(out_rows: int, starts, counts, outs, src):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    sem_shape = (
-        pltpu.SemaphoreType.DMA((DMA_PIPELINE_DEPTH,))
-        if kernel is _gather_dma_kernel
-        else pltpu.SemaphoreType.DMA
-    )
-    # The tiled kernel's (predicated) tail copy traces an 8-row slice even when
-    # it can never run, so the buffer must be at least one tile tall; the
-    # caller-visible shape is restored by the slice below.
-    alloc_rows = max(out_rows, TILE_ROWS)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((alloc_rows, src.shape[1]), src.dtype),
+    return pl.pallas_call(
+        _gather_dma_kernel,
+        out_shape=jax.ShapeDtypeStruct((out_rows, src.shape[1]), src.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            scratch_shapes=[sem_shape],
+            scratch_shapes=[pltpu.SemaphoreType.DMA((DMA_PIPELINE_DEPTH,))],
         ),
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
-        interpret=interpret,
-        name="block_gather_dma" if kernel is _gather_dma_kernel else "block_gather_tiled",
+        name="block_gather_dma",
     )(starts, counts, outs, src)
-    return out[:out_rows]
 
 
 @jax.named_scope("block_gather")
@@ -206,12 +137,7 @@ def _xla_gather(out_rows: int, starts, counts, outs, src):
     return jnp.where(covered[:, None], rows, jnp.zeros((), dtype=src.dtype))
 
 
-def build_block_gather(
-    num_blocks: int,
-    out_rows: int,
-    impl: Optional[str] = None,
-    interpret: bool = False,
-):
+def build_block_gather(num_blocks: int, out_rows: int, impl: Optional[str] = None):
     """Compile a ragged block gather: ``fn(starts, counts, outs, src) -> packed``.
 
     * ``starts``/``counts``/``outs``: (num_blocks,) int32 — source row offset,
@@ -224,16 +150,15 @@ def build_block_gather(
       uninitialized there; the xla path happens to zero it) — callers must
       slice ``[:total_rows]``.
 
-    ``impl``: 'dma' (TPU, pipelined dynamic-size DMAs) | 'tiled' (portable
-    static-size DMAs) | 'xla' (pure jnp).  Default: 'dma' on TPU else 'xla'.
+    ``impl``: 'dma' (TPU, pipelined dynamic-size DMAs) | 'xla' (pure jnp).
+    Default, and what every caller but a test takes: 'dma' on TPU else 'xla'.
     """
     if impl is None:
         impl = "dma" if jax.devices()[0].platform == "tpu" else "xla"
     if impl == "xla":
         f = functools.partial(_xla_gather, out_rows)
-    elif impl in ("dma", "tiled"):
-        kernel = _gather_dma_kernel if impl == "dma" else _gather_tiled_kernel
-        f = functools.partial(_pallas_gather, kernel, interpret, out_rows)
+    elif impl == "dma":
+        f = functools.partial(_pallas_gather, out_rows)
     else:
         raise ValueError(f"unknown impl {impl!r}")
     f.__name__ = "block_gather"  # the executable is jit_block_gather, not jit__unknown
@@ -287,81 +212,16 @@ def _scatter_dma_kernel(starts_ref, counts_ref, outs_ref, src_ref, dst_ref, out_
     jax.lax.fori_loop(jnp.maximum(num_blocks - k, 0), num_blocks, drain, 0)
 
 
-def _scatter_tiled_kernel(starts_ref, counts_ref, outs_ref, src_ref, dst_ref, out_ref, sem):
-    """Static-size-DMA scatter, portable to ``interpret=True`` (CI's path).
-
-    Mirrors ``_gather_tiled_kernel`` with the copy direction reversed: full
-    tiles, an overlapping shifted tail when count >= TILE_ROWS (safe — src and
-    dst shift together), single-row DMAs below one tile.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    del dst_ref  # aliased to out_ref
-    num_blocks = starts_ref.shape[0]
-
-    def copy(src_row, dst_row, rows):
-        dma = pltpu.make_async_copy(
-            src_ref.at[pl.ds(src_row, rows)],
-            out_ref.at[pl.ds(dst_row, rows)],
-            sem,
-        )
-        dma.start()
-        dma.wait()
-
-    def block_body(b, _):
-        start, count, out = starts_ref[b], counts_ref[b], outs_ref[b]
-        full = count // TILE_ROWS
-
-        def tile_body(t, _):
-            copy(out + t * TILE_ROWS, start + t * TILE_ROWS, TILE_ROWS)
-            return 0
-
-        jax.lax.fori_loop(0, full, tile_body, 0)
-
-        tail = count - full * TILE_ROWS
-
-        @pl.when(jnp.logical_and(tail > 0, count >= TILE_ROWS))
-        def _shifted_tail():
-            copy(out + count - TILE_ROWS, start + count - TILE_ROWS, TILE_ROWS)
-
-        @pl.when(count < TILE_ROWS)
-        def _tiny_block():
-            def row_body(r, _):
-                copy(out + r, start + r, 1)
-                return 0
-
-            jax.lax.fori_loop(0, count, row_body, 0)
-
-        return 0
-
-    jax.lax.fori_loop(0, num_blocks, block_body, 0)
-
-
 @jax.named_scope("block_scatter")
-def _pallas_scatter(kernel, interpret: bool, out_rows: int, starts, counts, outs, src, dst):
+def _pallas_scatter(out_rows: int, starts, counts, outs, src, dst):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    sem_shape = (
-        pltpu.SemaphoreType.DMA((DMA_PIPELINE_DEPTH,))
-        if kernel is _scatter_dma_kernel
-        else pltpu.SemaphoreType.DMA
-    )
-    alloc_rows = max(out_rows, TILE_ROWS)
-    if dst.shape[0] != alloc_rows:
-        dst = jnp.pad(dst, ((0, alloc_rows - dst.shape[0]), (0, 0)))
-    # The packed src can hold fewer than TILE_ROWS rows (tiny rounds); the
-    # tiled kernel's TILE_ROWS-sized copies need the operand itself to be at
-    # least one tile tall even though the guarded reads never leave the
-    # packed region at runtime.
-    if src.shape[0] < TILE_ROWS:
-        src = jnp.pad(src, ((0, TILE_ROWS - src.shape[0]), (0, 0)))
     # dst is operand 4 of the FULL input tuple (scalar-prefetch args included in
     # the alias numbering), aliased to output 0: untouched rows pass through.
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((alloc_rows, src.shape[1]), src.dtype),
+    return pl.pallas_call(
+        _scatter_dma_kernel,
+        out_shape=jax.ShapeDtypeStruct((out_rows, src.shape[1]), src.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             in_specs=[
@@ -369,14 +229,12 @@ def _pallas_scatter(kernel, interpret: bool, out_rows: int, starts, counts, outs
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            scratch_shapes=[sem_shape],
+            scratch_shapes=[pltpu.SemaphoreType.DMA((DMA_PIPELINE_DEPTH,))],
         ),
         input_output_aliases={4: 0},
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
-        interpret=interpret,
-        name="block_scatter_dma" if kernel is _scatter_dma_kernel else "block_scatter_tiled",
+        name="block_scatter_dma",
     )(starts, counts, outs, src, dst)
-    return out[:out_rows]
 
 
 @jax.named_scope("block_scatter")
@@ -410,7 +268,6 @@ def build_block_scatter(
     num_blocks: int,
     out_rows: int,
     impl: Optional[str] = None,
-    interpret: bool = False,
     max_block_rows: Optional[int] = None,
 ):
     """Compile a ragged block scatter: ``fn(starts, counts, outs, src, dst) -> dst'``.
@@ -438,9 +295,8 @@ def build_block_scatter(
     if impl == "xla":
         window = max(1, max_block_rows if max_block_rows is not None else out_rows)
         f = functools.partial(_xla_scatter, window, out_rows)
-    elif impl in ("dma", "tiled"):
-        kernel = _scatter_dma_kernel if impl == "dma" else _scatter_tiled_kernel
-        f = functools.partial(_pallas_scatter, kernel, interpret, out_rows)
+    elif impl == "dma":
+        f = functools.partial(_pallas_scatter, out_rows)
     else:
         raise ValueError(f"unknown impl {impl!r}")
     # Donating dst turns the aliasing into a true in-place append; on CPU
@@ -516,7 +372,7 @@ def _ring_device_id(mesh_axes, axis_name):
 
 def _ring_exchange_steps(
     num_devices, slot_rows, window_rows, steps, me, dev_id, data_ref, out_ref,
-    send_sem, recv_sem, on_step=None,
+    send_sem, recv_sem,
 ):
     """Shared schedule walk: remote-copy every (offset, chunk) window.
 
@@ -525,12 +381,7 @@ def _ring_exchange_steps(
     schedule is SPMD-symmetric, so each step's ``wait()`` pairs my outgoing
     descriptor with the incoming copy of the same (offset, chunk) from
     ``me-d`` — same window size, same semaphore index, both directions of the
-    ring in flight at once.
-
-    ``on_step(step)`` — optional superstep epilogue, called after the step's
-    waits: every window the step delivered is landed in ``out_ref`` and may
-    be consumed before the next step's copies start (the fused-combine
-    kernel's receive-side fold, ``ring_combine_grid``)."""
+    ring in flight at once."""
     import jax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -556,8 +407,6 @@ def _ring_exchange_steps(
             copies.append(copy)
         for copy in copies:
             copy.wait()
-        if on_step is not None:
-            on_step(step)
 
 
 def _ring_barrier(num_devices, offsets, me, dev_id):
@@ -663,152 +512,6 @@ def ring_exchange_grid(
         ),
         interpret=interpret,
         name="ring_exchange",
-    )(data)
-
-
-def ring_combine_grid(
-    axis_name: str,
-    num_devices: int,
-    slot_rows: int,
-    window_rows: int,
-    steps,
-    combine_fn,
-    acc_init_fn,
-    num_groups: int,
-    acc_width: int,
-    data,
-    *,
-    mesh_axes=None,
-    interpret: bool = False,
-    collective_id: int = 15,
-):
-    """Fused receive side: scheduled ring exchange + per-superstep combine
-    fold, ONE kernel — the compute-in-exchange tier (ops/combine.py).
-
-    Same wire schedule as :func:`ring_exchange_grid`; the difference is what
-    happens to a landed window.  After each superstep's waits, every window
-    the step delivered (the ``(offset, chunk)`` region from sender
-    ``me - offset``) is DMA'd into VMEM and folded into a dense per-group
-    accumulator held in VMEM for the whole schedule — landed rows are
-    consumed the moment they arrive instead of surviving as O(rows) recv
-    staging, and the post-exchange drain is the O(groups) accumulator.
-
-    * ``combine_fn(window, acc_vals, acc_counts) -> (acc_vals, acc_counts)``
-      — the fold (``ops/combine.combine_window`` closed over its spec);
-      plain traced jnp over static shapes, so this module stays free of the
-      combine dataclasses exactly as it stays free of the schedule ones.
-    * ``acc_init_fn() -> (acc_vals (G, w), acc_counts (G, 1))`` — the fold
-      identities.
-    * returns ``(grid, acc_vals, acc_counts)``: the sender-major landed grid
-      (callers keep it on device or discard it — it never drains) plus the
-      accumulator pair.
-
-    Window fold order is canonical — own slot first, then schedule items in
-    step order — and shared with the scheduled-XLA walk
-    (ops/ici_exchange.py), so exact dtypes are bit-identical across
-    lowerings.  ``interpret=True`` runs the same body under the Pallas
-    interpreter (CI's tier; the barrier is skipped as in
-    :func:`ring_exchange_grid`).
-    """
-    import jax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if mesh_axes is None:
-        mesh_axes = ((axis_name, num_devices),)
-    mesh_axes = tuple((str(n), int(s)) for n, s in mesh_axes)
-    if dict(mesh_axes)[axis_name] != num_devices:
-        raise ValueError(
-            f"ring axis {axis_name!r} has size {dict(mesh_axes)[axis_name]} in "
-            f"mesh_axes, expected num_devices={num_devices}"
-        )
-    steps = tuple(tuple(step) for step in steps)
-    offsets = sorted({offset for step in steps for offset, _, _ in step})
-    lane = int(data.shape[1])
-
-    def kernel(
-        data_ref, grid_ref, accv_ref, accc_ref,
-        send_sem, recv_sem, local_sem, accv_vmem, accc_vmem, win_vmem,
-    ):
-        me = jax.lax.axis_index(axis_name)
-        dev_id = _ring_device_id(mesh_axes, axis_name)
-        if not interpret:  # interpret discharge is synchronous; the barrier
-            _ring_barrier(num_devices, offsets, me, dev_id)  # is TPU-only
-        av0, ac0 = acc_init_fn()
-        accv_vmem[...] = av0
-        accc_vmem[...] = ac0
-
-        def fold(row0, rows):
-            # land the window in VMEM, fold it, keep the acc resident
-            cp = pltpu.make_async_copy(
-                grid_ref.at[pl.ds(row0, rows)],
-                win_vmem.at[pl.ds(0, rows)],
-                local_sem,
-            )
-            cp.start()
-            cp.wait()
-            av, ac = combine_fn(win_vmem[0:rows], accv_vmem[...], accc_vmem[...])
-            accv_vmem[...] = av
-            accc_vmem[...] = ac
-
-        # own slot never crosses a link: one local HBM->HBM DMA, folded first
-        # (the canonical order every lowering shares)
-        local = pltpu.make_async_copy(
-            data_ref.at[pl.ds(me * slot_rows, slot_rows)],
-            grid_ref.at[pl.ds(me * slot_rows, slot_rows)],
-            local_sem,
-        )
-        local.start()
-        local.wait()
-        fold(me * slot_rows, slot_rows)
-
-        def epilogue(step):
-            # every window this superstep delivered: sender me-d's chunk
-            for offset, chunk, _direction in step:
-                src = jax.lax.rem(me - offset + num_devices, num_devices)
-                fold(src * slot_rows + chunk * window_rows, window_rows)
-
-        _ring_exchange_steps(
-            num_devices, slot_rows, window_rows, steps, me, dev_id,
-            data_ref, grid_ref, send_sem, recv_sem, on_step=epilogue,
-        )
-        # drain the O(groups) accumulator to HBM — the only receive-side
-        # bytes that leave the kernel
-        for vmem, out in ((accv_vmem, accv_ref), (accc_vmem, accc_ref)):
-            flush = pltpu.make_async_copy(
-                vmem.at[pl.ds(0, num_groups)],
-                out.at[pl.ds(0, num_groups)],
-                local_sem,
-            )
-            flush.start()
-            flush.wait()
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((num_devices * slot_rows, lane), data.dtype),
-            jax.ShapeDtypeStruct((num_groups, acc_width), data.dtype),
-            jax.ShapeDtypeStruct((num_groups, 1), jnp.int32),
-        ),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=(
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ),
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-            pltpu.VMEM((num_groups, acc_width), data.dtype),
-            pltpu.VMEM((num_groups, 1), jnp.int32),
-            pltpu.VMEM((slot_rows, lane), data.dtype),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=collective_id
-        ),
-        interpret=interpret,
-        name="ring_combine",
     )(data)
 
 

@@ -42,7 +42,7 @@ from sparkucx_tpu.ops.columnar import (
     unpack_shard_prefixes,
 )
 from sparkucx_tpu.ops.compress import QuantizeSpec, dequantize_rows, quantize_rows
-from sparkucx_tpu.ops.exchange import exclusive_cumsum
+from sparkucx_tpu.ops.exchange import exclusive_cumsum, resolve_collective_impl
 
 #: Padding sort key (sorts last) — ops/sort.py's sentinel, same discipline:
 #: valid rows may legitimately carry this key; because received rows are a
@@ -194,8 +194,7 @@ class AggregateSpec:
     #: ``exchange.fusedCombine``): 'off' | 'auto' | 'dense' | 'sorted'.
     #: 'dense' folds every landed exchange window into a fixed per-group
     #: accumulator as it arrives — post-exchange memory and drain bytes drop
-    #: from O(rows) to O(groups), and the Pallas lowering runs the whole
-    #: scheduled ring as ONE kernel launch.  It requires ``partial=True``
+    #: from O(rows) to O(groups).  It requires ``partial=True``
     #: (the windows are partial-aggregate rows) and every key to lie inside
     #: ``[0, combine_groups)``.  'sorted' is the high-cardinality fallback:
     #: a bounded per-superstep sort/merge into a (recv_capacity) accumulator —
@@ -209,9 +208,6 @@ class AggregateSpec:
     combine: str = "off"
     #: dense key-domain size (pow2-bucketed — a compile-cache key dimension)
     combine_groups: int = 0
-    #: ICI lowering of the fused exchange ('auto' | 'dma' | 'xla' |
-    #: 'interpret' — ops/ici_exchange.resolve_ici_lowering vocabulary)
-    combine_lowering: str = "auto"
 
     @property
     def width(self) -> int:
@@ -299,11 +295,7 @@ class AggregateSpec:
         )
 
     def resolve_impl(self, platform: Optional[str] = None) -> "AggregateSpec":
-        if self.impl != "auto":
-            return self
-        if platform is None:
-            platform = jax.devices()[0].platform
-        return replace(self, impl="ragged" if platform == "tpu" else "dense")
+        return replace(self, impl=resolve_collective_impl(self.impl, platform))
 
     def validate(self) -> None:
         if self.impl not in ("ragged", "dense"):
@@ -579,13 +571,13 @@ def _sorted_combine_walk(spec: AggregateSpec, sched, slot_rows, flat, me):
 
 
 def _fused_aggregate_body(
-    spec: AggregateSpec, sched, lowering, keys, values, num_valid, mask=None
+    spec: AggregateSpec, sched, keys, values, num_valid, mask=None
 ):
     """The COMPUTE-IN-EXCHANGE shard body (``spec.combine != 'off'``): local
     partial reduce, place the partial rows into per-destination slots of the
     sender-major ring grid, then fold every window into the accumulator AS IT
-    LANDS (ops/ici_exchange.combine_axis_grid — one Pallas launch under the
-    DMA lowering) instead of staging O(rows) received rows.  The dense tier
+    LANDS (ops/ici_exchange.combine_axis_grid) instead of staging O(rows)
+    received rows.  The dense tier
     compacts the (combine_groups,) accumulator through the same
     :func:`_segment_reduce` the unfused final phase uses — single-element
     segments are identity folds, so the output contract (ascending keys,
@@ -632,7 +624,7 @@ def _fused_aggregate_body(
 
     if spec.combine == "dense":
         accv, accc = combine_axis_grid(
-            ax, n, cap, sched, slot, me, spec.combine_cspec, lowering
+            ax, n, cap, sched, slot, me, spec.combine_cspec
         )
         # compaction: one segment reduce over the dense domain — every group
         # is its own single-row segment (identity fold, exact for floats too)
@@ -684,8 +676,7 @@ def build_grouped_aggregate(mesh: Mesh, spec: AggregateSpec):
     """
     if spec.num_executors != mesh.devices.size:
         raise ValueError(f"spec.num_executors={spec.num_executors} != mesh size {mesh.devices.size}")
-    platform = mesh.devices.reshape(-1)[0].platform
-    spec = spec.resolve_impl(platform=platform)
+    spec = spec.resolve_impl(platform=mesh.devices.reshape(-1)[0].platform)
     if spec.combine == "auto":
         spec = spec.resolve_combine()
     spec.validate()
@@ -695,29 +686,17 @@ def build_grouped_aggregate(mesh: Mesh, spec: AggregateSpec):
         # compute-in-exchange route: the shard body IS the scheduled ring
         # (same FAST schedule the ICI exchange builds), folding windows into
         # the accumulator as they land instead of staging received rows
-        from sparkucx_tpu.ops.hierarchy import device_slice_ids
         from sparkucx_tpu.ops.ici_exchange import (
             DEFAULT_CHUNKS_PER_DEST,
-            resolve_ici_lowering,
-            resolve_schedule_lowering,
             ring_schedule,
             schedule_chunks,
         )
 
-        ids = device_slice_ids(mesh.devices.reshape(-1))
-        kind = "ici" if ids is None or len(set(ids)) == 1 else "dcn"
         sched = ring_schedule(
             spec.num_executors,
             schedule_chunks(spec.capacity, DEFAULT_CHUNKS_PER_DEST),
-            kind=kind,
         )
-        if spec.combine == "sorted":
-            low = "xla"  # the bounded merge rides scheduled permutes only
-        else:
-            low = resolve_schedule_lowering(
-                resolve_ici_lowering(spec.combine_lowering, platform), kind
-            )
-        body = functools.partial(_fused_aggregate_body, spec, sched, low)
+        body = functools.partial(_fused_aggregate_body, spec, sched)
         reuse_dq = False
     else:
         body = functools.partial(_aggregate_body, spec)
@@ -883,11 +862,7 @@ class JoinSpec:
     join_type: str = "inner"
 
     def resolve_impl(self, platform: Optional[str] = None) -> "JoinSpec":
-        if self.impl != "auto":
-            return self
-        if platform is None:
-            platform = jax.devices()[0].platform
-        return replace(self, impl="ragged" if platform == "tpu" else "dense")
+        return replace(self, impl=resolve_collective_impl(self.impl, platform))
 
     def validate(self) -> None:
         if self.impl not in ("ragged", "dense"):

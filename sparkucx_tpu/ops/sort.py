@@ -48,7 +48,7 @@ from sparkucx_tpu.ops.columnar import (
     size_matrix_from_owners,
     unpack_shard_prefixes,
 )
-from sparkucx_tpu.ops.exchange import gather_rows
+from sparkucx_tpu.ops.exchange import gather_rows, resolve_collective_impl
 
 KEY_MAX = np.uint32(0xFFFFFFFF)  # padding sentinel; sorts last
 
@@ -77,27 +77,21 @@ class SortSpec:
     def resolve_impl(self, platform: Optional[str] = None) -> "SortSpec":
         """'auto' -> 'single' when one executor (sample sort degenerates to one
         local sort — no splitters, no exchange, HALF the sort work; any
-        backend), else 'ragged' on TPU / 'dense' elsewhere.  'radix' swaps the
-        n=1 local sort for the Pallas LSD radix kernel (ops/radix.py) whose
-        scatter moves key+payload together by segment DMA — the explicit
-        opt-in meant to beat the XLA argsort+gather path (never yet run on a
-        chip — root PERF.md)."""
+        backend), else 'ragged' on TPU / 'dense' elsewhere."""
         if self.impl != "auto":
             return self
         if self.num_executors == 1 and self.recv_capacity >= self.capacity:
             return replace(self, impl="single")
-        if platform is None:
-            platform = jax.devices()[0].platform
-        return replace(self, impl="ragged" if platform == "tpu" else "dense")
+        return replace(self, impl=resolve_collective_impl(self.impl, platform))
 
     def validate(self) -> None:
-        if self.impl not in ("ragged", "dense", "single", "radix"):
+        if self.impl not in ("ragged", "dense", "single"):
             raise ValueError(f"unknown impl {self.impl!r}")
-        if self.impl in ("single", "radix") and (
+        if self.impl == "single" and (
             self.num_executors != 1 or self.recv_capacity < self.capacity
         ):
             raise ValueError(
-                f"impl={self.impl!r} needs num_executors=1 and recv_capacity >= capacity"
+                "impl='single' needs num_executors=1 and recv_capacity >= capacity"
             )
         if np.dtype(self.dtype).itemsize != 4:
             raise ValueError("payload dtype must be 32-bit (keys bitcast through it)")
@@ -206,32 +200,6 @@ def _sort_body_single(spec: SortSpec, keys: jnp.ndarray, payload: jnp.ndarray, n
     return out_keys, out_pay, nv[None].astype(jnp.int32)
 
 
-def _sort_body_radix(spec: SortSpec, keys, payload, num_valid, *, interpret: bool):
-    """n=1 path with the Pallas LSD radix sort (ops/radix.py): key and payload
-    fuse into one row tile and move TOGETHER by segment DMA each pass —
-    no XLA argsort, no permutation gather (the two halves of the 'single'
-    path's time)."""
-    from sparkucx_tpu.ops.radix import radix_sort_rows
-
-    nv = num_valid[0]
-    idx = jnp.arange(spec.capacity, dtype=jnp.int32)
-    keys = jnp.where(idx < nv, keys, KEY_MAX)
-    rows = jnp.concatenate(
-        [jax.lax.bitcast_convert_type(keys, spec.dtype)[:, None], payload], axis=1
-    )
-    rows = radix_sort_rows(rows, interpret=interpret)
-    out_keys = jax.lax.bitcast_convert_type(rows[:, 0], jnp.uint32)
-    # invalid rows (forced KEY_MAX, input tail) sort stably to the back:
-    # positions >= nv are exactly them; zero their payload like the other
-    # lowerings so caller padding cannot leak through the permutation
-    out_pay = jnp.where((idx < nv)[:, None], rows[:, 1:], 0)
-    pad = spec.recv_capacity - spec.capacity
-    if pad:
-        out_keys = jnp.concatenate([out_keys, jnp.full(pad, KEY_MAX, jnp.uint32)])
-        out_pay = jnp.concatenate([out_pay, jnp.zeros((pad, spec.width), spec.dtype)])
-    return out_keys, out_pay, nv[None].astype(jnp.int32)
-
-
 def build_distributed_sort(mesh: Mesh, spec: SortSpec):
     """Compile the full distributed sort for ``mesh``.
 
@@ -260,13 +228,7 @@ def build_distributed_sort(mesh: Mesh, spec: SortSpec):
     spec.validate()
     ax = spec.axis_name
 
-    if spec.impl == "radix":
-        # the Pallas kernel needs real Mosaic for its dynamic-size DMAs; any
-        # other backend runs the interpreter (CPU-mesh tests)
-        interpret = mesh.devices.reshape(-1)[0].platform != "tpu"
-        body = functools.partial(_sort_body_radix, interpret=interpret)
-    else:
-        body = _sort_body_single if spec.impl == "single" else _sort_body
+    body = _sort_body_single if spec.impl == "single" else _sort_body
     shard = shard_map(
         functools.partial(body, spec),
         mesh=mesh,

@@ -35,6 +35,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sparkucx_tpu.ops.columnar import ColumnarSpec
+from sparkucx_tpu.ops.exchange import resolve_collective_impl
 from sparkucx_tpu.ops.relational import exchange_keyed_rows, expand_matches, padded_keys
 from sparkucx_tpu.ops.sort import KEY_MAX
 
@@ -79,11 +80,7 @@ class TcSpec:
         return self.tc_recv_capacity or self.tc_capacity
 
     def resolve_impl(self, platform: Optional[str] = None) -> "TcSpec":
-        if self.impl != "auto":
-            return self
-        if platform is None:
-            platform = jax.devices()[0].platform
-        return replace(self, impl="ragged" if platform == "tpu" else "dense")
+        return replace(self, impl=resolve_collective_impl(self.impl, platform))
 
     def validate(self) -> None:
         if self.impl not in ("ragged", "dense"):

@@ -1389,10 +1389,6 @@ class TpuShuffleCluster:
         fetches of varying batch sizes reuse a handful of compilations."""
         from sparkucx_tpu.ops.pallas_kernels import build_block_gather
 
-        if impl is None or impl == "auto":
-            impl = self.conf.gather_impl
-        if impl == "auto":
-            impl = None  # build_block_gather picks by platform
         b = 1 << max(num_blocks - 1, 0).bit_length()
         r = 1 << max(out_rows - 1, 0).bit_length()
         key = ("gather", impl, b, r)

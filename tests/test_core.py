@@ -276,6 +276,16 @@ class TestConf:
         assert c.tenant_hbm_quota_bytes == 16 << 20
         assert c.eviction_epoch_ms == 1000
 
+    def test_from_spark_conf_passes_over_a_key_that_left(self):
+        # gatherImpl was a key until PR 44 (the gather's lowering is the
+        # platform's own, as the scatter's always was): a Spark conf that
+        # still sets it builds the default conf, as with any key not read
+        c = TpuShuffleConf.from_spark_conf({"spark.shuffle.tpu.gatherImpl": "tiled"})
+        assert c == TpuShuffleConf.from_spark_conf({})
+        assert not hasattr(c, "gather_impl")
+        with pytest.raises(TypeError, match="gather_impl"):
+            TpuShuffleConf(gather_impl="xla")
+
     def test_validation(self):
         with pytest.raises(ValueError):
             TpuShuffleConf(block_alignment=100).validate()

@@ -49,7 +49,6 @@ def _conf(device: bool, n: int, cap: int, mode: str = "array", **kw) -> TpuShuff
         block_alignment=ALIGN,
         num_executors=n,
         device_staging=device,
-        gather_impl="xla",
         host_recv_mode=mode,
         keep_device_recv=(mode == "device"),
     )

@@ -161,6 +161,57 @@ class TestRaggedLowering:
         assert fn.spec.impl == "dense"
 
 
+def _auto_spec(name):
+    from sparkucx_tpu.ops.columnar import ColumnarSpec
+    from sparkucx_tpu.ops.relational import AggregateSpec, JoinSpec
+    from sparkucx_tpu.ops.sort import SortSpec
+    from sparkucx_tpu.ops.tc import TcSpec
+
+    return {
+        "exchange": lambda: ExchangeSpec(num_executors=4, send_rows=64, recv_rows=64),
+        "columnar": lambda: ColumnarSpec(num_executors=4, capacity=8, recv_capacity=8, width=1),
+        "sort": lambda: SortSpec(num_executors=4, capacity=8, recv_capacity=16),
+        "tc": lambda: TcSpec(num_executors=4, edge_capacity=8, tc_capacity=8, join_capacity=8),
+        "aggregate": lambda: AggregateSpec(
+            num_executors=4, capacity=8, recv_capacity=8, aggs=("sum",)
+        ),
+        "join": lambda: JoinSpec(
+            num_executors=4, build_capacity=8, build_recv_capacity=8, build_width=1,
+            probe_capacity=8, probe_recv_capacity=8, probe_width=1, out_capacity=8,
+        ),
+    }[name]()
+
+
+class TestCollectiveImplRule:
+    """"Ragged on TPU, dense elsewhere" is ``resolve_collective_impl``'s to
+    decide; the six specs' ``resolve_impl`` ask it and test no platform."""
+
+    @pytest.mark.parametrize("platform,want", [("tpu", "ragged"), ("cpu", "dense")])
+    @pytest.mark.parametrize(
+        "name", ["exchange", "columnar", "sort", "tc", "aggregate", "join"]
+    )
+    def test_every_spec_resolves_auto_by_it(self, name, platform, want):
+        import inspect
+        from dataclasses import replace
+
+        from sparkucx_tpu.ops.exchange import resolve_collective_impl
+
+        spec = _auto_spec(name)
+        assert spec.impl == "auto"
+        assert resolve_collective_impl("auto", platform) == want
+        assert spec.resolve_impl(platform).impl == want
+        named = replace(spec, impl="dense")  # a caller's own choice passes through
+        assert resolve_collective_impl("dense", platform) == "dense"
+        assert named.resolve_impl(platform) == named
+        assert "tpu" not in inspect.getsource(type(spec).resolve_impl).split('"""')[-1]
+
+    def test_platform_defaults_to_the_first_device(self):
+        from sparkucx_tpu.ops.exchange import resolve_collective_impl
+
+        assert jax.devices()[0].platform == "cpu"
+        assert resolve_collective_impl("auto") == "dense"
+
+
 class TestLocalLowering:
     """The n=1 degenerate exchange lowers to the Pallas DMA prefix copy on
     TPU ('local'); its resolve/validate logic is platform-independent and the

@@ -1,12 +1,12 @@
 """Compute-in-exchange fused combine (ROADMAP 2): the receive side of the
 scheduled ring folds each landed window into a dense per-group accumulator
-instead of staging O(rows) — ops/combine.py, ops/pallas_kernels.ring_combine_grid,
+instead of staging O(rows) — ops/combine.py,
 ops/ici_exchange.build_combine_exchange, and the relational fused bodies.
 
 The load-bearing contracts pinned here:
 
-* every lowering tier (scheduled-XLA walk, interpreted Pallas kernel) matches
-  a numpy oracle exactly and is BIT-IDENTICAL to the other tiers;
+* the scheduled walk matches a numpy oracle exactly at every chunking, and
+  is what a TPU mesh gets too (AOT-lowered for the chip's platform here);
 * the fused grouped aggregate is bit-identical to the unfused path for exact
   dtypes (int32 everywhere; float32 over integral values, where sums are
   order-independent), for both the dense tier and the sorted fallback;
@@ -86,13 +86,14 @@ def _grid_case(rng, cspec, slot=SLOT):
     return data, sizes, exp_v, exp_c
 
 
-def _run_exchange(mesh, cspec, data, sizes, lowering, chunks=2):
+def _run_exchange(mesh, cspec, data, sizes, chunks=2):
     lane = cspec.row_width
     spec = ExchangeSpec(
         num_executors=N, send_rows=N * SLOT, recv_rows=N * SLOT, lane=lane,
         axis_name="ex", impl="dense",
     )
-    fn = build_combine_exchange(mesh, spec, cspec, chunks_per_dest=chunks, lowering=lowering)
+    fn = build_combine_exchange(mesh, spec, cspec, chunks_per_dest=chunks)
+    assert fn.schedule.chunks == chunks
     av0 = np.zeros((N, cspec.num_groups, cspec.width), np.int32)
     for c, a in enumerate(cspec.aggs):
         av0[:, :, c] = agg_identity(a, np.int32)
@@ -107,15 +108,17 @@ def _run_exchange(mesh, cspec, data, sizes, lowering, chunks=2):
 
 
 # ----------------------------------------------------------------------------
-# kernel / lowering tiers
+# the exchange itself
 # ----------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("lowering", ["xla", "interpret"])
-def test_combine_exchange_matches_oracle(mesh, rng, lowering):
+@pytest.mark.parametrize("chunks", [1, 2, 4])
+def test_combine_exchange_matches_oracle(mesh, rng, chunks):
+    """Whole slots, halves, quarters: the windows a schedule cuts must fold to
+    the same accumulator."""
     cspec = CombineSpec(num_groups=GROUPS, aggs=AGGS, dtype=np.int32)
     data, sizes, exp_v, exp_c = _grid_case(rng, cspec)
-    accv, accc, recv = _run_exchange(mesh, cspec, data, sizes, lowering)
+    accv, accc, recv = _run_exchange(mesh, cspec, data, sizes, chunks)
     accv = np.asarray(accv).reshape(N, GROUPS, len(AGGS))
     accc = np.asarray(accc).reshape(N, GROUPS)
     # recv_sizes is the receive-side view: row r = rows each sender sent to r
@@ -124,15 +127,24 @@ def test_combine_exchange_matches_oracle(mesh, rng, lowering):
     assert np.array_equal(accv.astype(np.int64), exp_v)
 
 
-def test_combine_exchange_tiers_bit_identical(mesh, rng):
-    """interpret (the Pallas kernel body, CPU-interpreted) vs the scheduled
-    XLA walk: same canonical fold order, so bytes must match exactly."""
-    cspec = CombineSpec(num_groups=GROUPS, aggs=AGGS, dtype=np.int32)
-    data, sizes, _, _ = _grid_case(rng, cspec)
-    rx = _run_exchange(mesh, cspec, data, sizes, "xla")
-    ri = _run_exchange(mesh, cspec, data, sizes, "interpret")
-    for a, b in zip(rx, ri):
-        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+def test_combine_exchange_lowers_for_tpu_as_the_chip_picks_it():
+    """The builder at its defaults (``impl='auto'``, no lowering to name),
+    exported for the tpu platform from here: permutes and folds, no kernel —
+    the one lowering there is lowers where the chip would run it."""
+    from jax import export as jax_export
+
+    cspec = CombineSpec(num_groups=1024, aggs=("sum", "min", "max", "avg"), dtype=np.int32)
+    slot, lane = 4096, cspec.row_width
+    spec = ExchangeSpec(num_executors=N, send_rows=N * slot, recv_rows=N * slot, lane=lane)
+    assert spec.impl == "auto"
+    fn = build_combine_exchange(make_mesh(N), spec, cspec, chunks_per_dest=2)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)
+    exported = jax_export.export(fn, platforms=["tpu"])(
+        i32(N * N * slot, lane), i32(N, N),
+        i32(N * cspec.num_groups, cspec.width), i32(N * cspec.num_groups, 1),
+    )
+    text = exported.mlir_module()
+    assert "collective_permute" in text and "tpu_custom_call" not in text
 
 
 def test_combine_window_and_merge_unit(rng):
@@ -210,19 +222,6 @@ def test_fused_bit_identical_to_unfused(mesh, rng, tier, dtype):
     ok, _, oc = oracle_aggregate(keys, vals, spec.aggs)
     assert np.array_equal(got[0], ok)
     assert np.array_equal(got[2], oc)
-
-
-def test_fused_interpret_lowering_bit_identical(mesh, rng):
-    """The Pallas kernel tier through the RELATIONAL body (not just the raw
-    exchange): combine_lowering='interpret' runs ring_combine_grid."""
-    keys, vals = _dense_case(rng)
-    spec = _agg_spec(combine="dense", combine_groups=64)
-    r_x = run_grouped_aggregate(mesh, spec, keys, vals)
-    r_i = run_grouped_aggregate(
-        mesh, replace(spec, combine_lowering="interpret"), keys, vals
-    )
-    for a, b in zip(r_x, r_i):
-        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
 def test_fused_with_filter(mesh, rng):

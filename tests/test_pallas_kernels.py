@@ -1,10 +1,10 @@
 """Ragged block-gather kernels (ops/pallas_kernels.py) and the device-resident
 batch fetch built on them (TpuShuffleCluster.fetch_blocks_to_device).
 
-On the CPU test mesh the 'xla' lowering runs compiled and the 'tiled' Pallas
-lowering runs in interpret mode; the 'dma' lowering needs real Mosaic
-dynamic-size DMA and is covered by the TPU-gated test at the bottom (run on
-hardware; skipped here)."""
+On the CPU test mesh the 'xla' lowering runs compiled; the 'dma' lowering
+needs real Mosaic dynamic-size DMA: it is AOT-lowered for the tpu platform
+here, and run by the TPU-gated tests at the bottom (on hardware; skipped
+here) and by every check of the benchmark's two HBM cells."""
 
 import jax
 import numpy as np
@@ -45,6 +45,9 @@ PLANS = [
     [(100 * ROW, 30 * ROW), (5 * ROW, 100), (200 * ROW, ROW * 8)],
     [(0, 13)],
     [],
+    # every row count from 1 to 12 a block: each residue of the 8-row tile,
+    # blocks under one tile among them
+    [(i * 16 * ROW, (i + 1) * ROW) for i in range(12)],
 ]
 
 
@@ -55,21 +58,6 @@ class TestGatherLowering:
         fn = build_block_gather(len(plan), max(total, 1), impl="xla")
         if not len(plan):
             return  # nothing to run; pack_plan handled the degenerate shape
-        out = np.asarray(fn(starts, counts, outs, src))
-        assert np.array_equal(out[:total], _oracle(src, starts, counts))
-
-    @pytest.mark.parametrize("plan", PLANS[:3])
-    def test_tiled_interpret_matches_oracle(self, src, plan):
-        starts, counts, outs, total = pack_plan(plan, ROW)
-        fn = build_block_gather(len(plan), max(total, 1), impl="tiled", interpret=True)
-        out = np.asarray(fn(starts, counts, outs, src))
-        assert np.array_equal(out[:total], _oracle(src, starts, counts))
-
-    def test_tiled_covers_all_tail_shapes(self, src):
-        # every residue mod TILE_ROWS, including count < TILE_ROWS
-        plan = [(i * 16 * ROW, (i + 1) * ROW) for i in range(12)]
-        starts, counts, outs, total = pack_plan(plan, ROW)
-        fn = build_block_gather(len(plan), total, impl="tiled", interpret=True)
         out = np.asarray(fn(starts, counts, outs, src))
         assert np.array_equal(out[:total], _oracle(src, starts, counts))
 
@@ -101,9 +89,26 @@ class TestGatherLowering:
         assert outs.tolist() == [0, 1]
         assert total == 3
 
-    def test_unknown_impl(self):
+    @pytest.mark.parametrize("impl", ["bogus", "tiled"])
+    def test_unknown_impl(self, impl):
         with pytest.raises(ValueError, match="unknown impl"):
-            build_block_gather(1, 1, impl="bogus")
+            build_block_gather(1, 1, impl=impl)
+
+    @pytest.mark.parametrize("out_rows", [512, 1])
+    def test_dma_lowers_aot_for_tpu(self, out_rows):
+        # the gather's twin of TestScatterLowering.test_dma_lowers_aot_for_tpu:
+        # the kernel the chip picks, exported for the tpu platform from here,
+        # down to the one-row bucket a tiny fetch makes
+        from jax import export as jax_export
+
+        import jax.numpy as jnp
+
+        fn = build_block_gather(8, out_rows, impl="dma")
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        exported = jax_export.export(jax.jit(fn), platforms=["tpu"])(
+            i32(8), i32(8), i32(8), i32(512, LANE)
+        )
+        assert "block_gather_dma" in exported.mlir_module()
 
 
 OUT_ROWS = 256
@@ -114,6 +119,7 @@ SCATTER_PLANS = [
     [(0, 8), (16, 16), (250, 1)],
     [(95, 5)],
     [(0, 0)],
+    [(i * 20, i + 1) for i in range(12)],  # every residue of the 8-row tile
 ]
 
 
@@ -154,23 +160,6 @@ class TestScatterLowering:
         out = np.asarray(fn(starts, counts, outs, src[: max(total, 1)], dst))
         assert np.array_equal(out, _scatter_oracle(dst, src, starts, counts, outs))
 
-    @pytest.mark.parametrize("plan", SCATTER_PLANS[:3])
-    def test_tiled_interpret_matches_oracle(self, src, plan):
-        starts, counts, outs, total = _scatter_args(plan)
-        dst = self._dst()
-        fn = build_block_scatter(len(plan), OUT_ROWS, impl="tiled", interpret=True)
-        out = np.asarray(fn(starts, counts, outs, src[: max(total, 1)], dst))
-        assert np.array_equal(out, _scatter_oracle(dst, src, starts, counts, outs))
-
-    def test_tiled_covers_all_tail_shapes(self, src):
-        # every residue mod TILE_ROWS, including counts < TILE_ROWS
-        plan = [(i * 20, i + 1) for i in range(12)]
-        starts, counts, outs, total = _scatter_args(plan)
-        dst = self._dst()
-        fn = build_block_scatter(len(plan), OUT_ROWS, impl="tiled", interpret=True)
-        out = np.asarray(fn(starts, counts, outs, src[:total], dst))
-        assert np.array_equal(out, _scatter_oracle(dst, src, starts, counts, outs))
-
     def test_xla_window_clamp_at_buffer_edge(self, src):
         # regression: a block ending exactly at the last dst row must not have
         # its dynamic_slice window clamped backwards (would shift src rows)
@@ -188,31 +177,30 @@ class TestScatterLowering:
         counts = np.asarray([4, 0, 0], dtype=np.int32)
         outs = np.asarray([0, 4, 4], dtype=np.int32)
         dst = self._dst()
-        for impl, interp in (("xla", False), ("tiled", True)):
-            fn = build_block_scatter(3, OUT_ROWS, impl=impl, interpret=interp)
-            out = np.asarray(fn(starts, counts, outs, src[:4], dst))
-            assert np.array_equal(
-                out, _scatter_oracle(dst, src, starts, counts, outs)
-            ), impl
+        fn = build_block_scatter(3, OUT_ROWS, impl="xla")
+        out = np.asarray(fn(starts, counts, outs, src[:4], dst))
+        assert np.array_equal(out, _scatter_oracle(dst, src, starts, counts, outs))
 
-    def test_unknown_impl(self):
+    @pytest.mark.parametrize("impl", ["bogus", "tiled"])
+    def test_unknown_impl(self, impl):
         with pytest.raises(ValueError, match="unknown impl"):
-            build_block_scatter(1, 1, impl="bogus")
+            build_block_scatter(1, 1, impl=impl)
 
-    def test_dma_lowers_aot_for_tpu(self):
+    @pytest.mark.parametrize("out_rows", [OUT_ROWS, 1])
+    def test_dma_lowers_aot_for_tpu(self, out_rows):
         # AOT Mosaic lowering: the dma kernel must export for the tpu platform
         # even from the CPU test mesh (catches pallas lowering regressions
-        # without hardware; same pattern as the radix-sort AOT test)
+        # without hardware), down to a staging array of one row
         from jax import export as jax_export
 
         import jax.numpy as jnp
 
-        fn = build_block_scatter(8, OUT_ROWS, impl="dma")
+        fn = build_block_scatter(8, out_rows, impl="dma")
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
         exported = jax_export.export(jax.jit(fn), platforms=["tpu"])(
-            i32(8), i32(8), i32(8), i32(64, LANE), i32(OUT_ROWS, LANE)
+            i32(8), i32(8), i32(8), i32(64, LANE), i32(out_rows, LANE)
         )
-        assert len(exported.mlir_module_serialized) > 0
+        assert "block_scatter_dma" in exported.mlir_module()
 
 
 N_EXEC = 4
@@ -224,7 +212,6 @@ def exchanged_cluster():
         staging_capacity_per_executor=1 << 20,
         block_alignment=128,
         num_executors=N_EXEC,
-        gather_impl="xla",  # CPU mesh: the portable lowering
         keep_device_recv=True,  # device-side fetch is the subject under test
     )
     cluster = TpuShuffleCluster(conf, num_executors=N_EXEC)
@@ -324,7 +311,6 @@ class TestDeviceFetch:
             staging_capacity_per_executor=4096,
             block_alignment=128,
             num_executors=2,
-            gather_impl="xla",
             keep_device_recv=True,
         )
         cluster = TpuShuffleCluster(conf, num_executors=2)

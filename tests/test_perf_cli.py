@@ -104,10 +104,10 @@ def test_unknown_mode_refused(capsys):
     assert "invalid choice: 'superstep'" in capsys.readouterr().err
 
 
-def test_tpu_smoke_script(tmp_path):
-    """The hardware acceptance smoke must pass on the CI mesh (dense/xla
-    lowerings) — the same script gates real-chip deployments.  The drives of
-    the TPU-only kernels must skip by name here, not vanish."""
+def test_tpu_smoke_script():
+    """The scheduled-ring plane's chip check on the CI mesh: its two kernels
+    are TPU-only, so both drives must skip by name here, not vanish, and the
+    script must exit 0 (as it does on the chip, where they run)."""
     import os
     import subprocess
     import sys
@@ -117,14 +117,11 @@ def test_tpu_smoke_script(tmp_path):
         [sys.executable, os.path.join(root, "scripts", "tpu_smoke.py")],
         capture_output=True, text=True, timeout=300, cwd=root,
         env={**os.environ, "JAX_PLATFORMS": "cpu",
-             "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-             "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache")},
+             "XLA_FLAGS": "--xla_force_host_platform_device_count=8"},
     )
     assert r.returncode == 0, r.stdout + r.stderr
-    assert "all 13 drives passed" in r.stdout
-    for kernel in ("ring_exchange_grid", "fused_scatter_ring_grid", "ring_combine_grid",
-                   "build_block_scatter impl='dma'", "ops/radix.py"):
-        line = next(ln for ln in r.stdout.splitlines() if kernel in ln)
-        assert "[impl=skipped (" in line, line
-    # the script placed its compile cache where it was told, not in the checkout
-    assert os.listdir(tmp_path / "jax_cache")
+    assert "all 2 drives passed" in r.stdout
+    drives = [ln for ln in r.stdout.splitlines() if ln.startswith("ok: ")]
+    assert len(drives) == 2, r.stdout
+    for kernel, line in zip(("ring_exchange_grid", "fused_scatter_ring_grid"), drives):
+        assert kernel in line and "[impl=skipped (TPU-only" in line, line

@@ -377,7 +377,7 @@ class TestClusterBitEquality:
     def test_device_staging_rounds(self):
         """Device-sealed rounds take the on-device chunk-slicing arm of the
         quota submit (slice_subround with xp=jnp)."""
-        conf = _conf(8, device_staging=True, gather_impl="xla")
+        conf = _conf(8, device_staging=True)
         cluster, meta, oracle = _exchange(conf)
         _fetch_all(cluster, meta, 0, 3 * N_EXEC, 8, oracle)
 

@@ -235,6 +235,15 @@ class TestDistributedSort:
         np.testing.assert_array_equal(ko[NV:], np.full(RECV - NV, KEY_MAX, np.uint32))
         np.testing.assert_array_equal(po[NV:], np.zeros((RECV - NV, 2), np.int32))
 
+    def test_unknown_impl_refused(self):
+        # 'radix' was an impl until PR 44: a conf written for it gets the
+        # check every unknown impl gets, not a silent fall to another sort
+        spec = SortSpec(num_executors=1, capacity=8, recv_capacity=8, impl="radix")
+        with pytest.raises(ValueError, match="unknown impl 'radix'"):
+            spec.validate()
+        with pytest.raises(ValueError, match="unknown impl 'radix'"):
+            build_distributed_sort(make_mesh(1), spec)
+
     def test_spec_validation(self, mesh):
         with pytest.raises(ValueError, match="mesh size"):
             build_distributed_sort(mesh, SortSpec(num_executors=4, capacity=8, recv_capacity=8))
@@ -248,6 +257,85 @@ class TestDistributedSort:
                 num_executors=N, capacity=8, recv_capacity=8,
                 samples_per_shard=2, impl="dense",
             ).validate()
+
+
+def _single_sort_case(name):
+    """(keys, payload, capacity) of one shape the n=1 sort must get right:
+    the correctness shapes of the kernel sort that left in PR 44, kept on the
+    sort that stays."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+
+    def ids(n, width=1):  # payload = row id: equality proves the stable order
+        return np.arange(n, dtype=np.int32)[:, None] * np.ones(width, np.int32)
+
+    def u32(hi, n):
+        return rng.integers(0, hi, size=n, dtype=np.uint64).astype(np.uint32)
+
+    if name.startswith("fuzz"):
+        hi = {"fuzz-tiny-keyspace": 4, "fuzz-16bit": 1 << 16, "fuzz-full-range": 1 << 32}[name]
+        n, width = int(rng.integers(10, 2000)), int(rng.integers(1, 6))
+        return u32(hi, n), rng.integers(-1000, 1000, size=(n, width)).astype(np.int32), 2048
+    if name == "heavy-duplicates":
+        return u32(3, 777), ids(777), 1024
+    if name in ("all-equal", "all-zero", "all-keymax"):
+        k = {"all-equal": 7, "all-zero": 0, "all-keymax": 0xFFFFFFFF}[name]
+        return np.full(300, k, np.uint32), ids(300), 512
+    if name == "sign-bit-keys":  # above 2^31 they bitcast to negative int32 lanes
+        return np.array([0, 2**31, 2**31 - 1, 0xFFFFFFFF, 5], np.uint32), ids(5), 8
+    if name == "non-multiple-padding":
+        return u32(1 << 32, 1000), ids(1000, 2), 1056
+    if name == "one-row":
+        return np.array([42], np.uint32), ids(1), 8
+    if name == "two-rows":
+        return np.array([3, 1], np.uint32), ids(2), 8
+    if name == "odd-row-count":
+        return u32(1 << 32, 1001), ids(1001), 1008
+    if name == "float32-payload":
+        keys = np.array(
+            [0xD0327A78, 0xE9AA5979, 0xF0000000, 0xBF800001, 0, 1, 2, 3, 4, 5, 6, 7],
+            np.uint32,
+        )
+        return keys, rng.normal(size=(12, 2)).astype(np.float32), 16
+    if name == "valid-keymax-before-padding":
+        return (
+            np.array([5, KEY_MAX, 1, KEY_MAX], np.uint32),
+            np.array([[50], [91], [10], [92]], np.int32),
+            8,
+        )
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("case", [
+    "fuzz-tiny-keyspace", "fuzz-16bit", "fuzz-full-range", "heavy-duplicates",
+    "all-equal", "all-zero", "all-keymax", "sign-bit-keys", "non-multiple-padding",
+    "one-row", "two-rows", "odd-row-count", "float32-payload",
+    "valid-keymax-before-padding",
+])
+def test_single_sort_shapes_vs_oracle(case):
+    """The n=1 sort ('single': what 'auto' runs on one chip) against
+    ``oracle_sort``, row for row: unsigned key order, stability under
+    duplication, valid KEY_MAX rows ahead of the zeroed padding, a float
+    payload carried bit for bit."""
+    keys, payload, cap = _single_sort_case(case)
+    n, width = payload.shape
+    spec = SortSpec(
+        num_executors=1, capacity=cap, recv_capacity=cap, width=width,
+        dtype=payload.dtype,
+    )
+    mesh1 = make_mesh(1)
+    f = build_distributed_sort(mesh1, spec)
+    assert f.spec.impl == "single"
+    pk = np.full(cap, 12345, np.uint32)  # padding deliberately NOT KEY_MAX
+    pk[:n] = keys
+    pp = np.full((cap, width), -7, payload.dtype)
+    pp[:n] = payload
+    ko, po, cnt = (np.asarray(x) for x in f(*_place(mesh1, pk, pp, np.array([n], np.int32))))
+    assert cnt.tolist() == [n]
+    want_k, want_p = oracle_sort(keys, payload)
+    np.testing.assert_array_equal(ko[:n], want_k)
+    np.testing.assert_array_equal(po[:n].view(np.uint32), want_p.view(np.uint32))
+    np.testing.assert_array_equal(ko[n:], np.full(cap - n, KEY_MAX, np.uint32))
+    np.testing.assert_array_equal(po[n:], np.zeros((cap - n, width), payload.dtype))
 
 
 class TestRunDistributedSort:
