@@ -52,7 +52,7 @@ class TpuShuffleManager:
         self.pool = MemoryPool(self.conf)
         self.pool.preallocate_from_conf()
         self.resolvers: List[TpuShuffleBlockResolver] = [
-            TpuShuffleBlockResolver(self.conf, t, t.store) for t in self.cluster.transports
+            TpuShuffleBlockResolver(self.conf, t) for t in self.cluster.transports
         ]
         self._shuffle_dims: Dict[int, tuple] = {}
         self._lock = threading.Lock()

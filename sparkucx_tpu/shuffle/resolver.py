@@ -114,13 +114,17 @@ class TpuShuffleBlockResolver:
         self,
         conf: TpuShuffleConf,
         transport: ShuffleTransport,
-        store: HbmBlockStore,
     ) -> None:
         self.conf = conf
         self.transport = transport
-        self.store = store
         self._shuffles: Set[int] = set()  #: guarded by self._lock
         self._lock = threading.Lock()
+
+    @property
+    def store(self) -> HbmBlockStore:
+        """The executor's store: the transport's own as it is NOW — an
+        executor that rejoined has a new one (``TpuShuffleTransport.restart``)."""
+        return self.transport.store
 
     def on_map_committed(self, shuffle_id: int, map_id: int, num_reducers: int) -> None:
         """Register each non-empty partition with the transport for peer serving

@@ -283,6 +283,8 @@ def kill_executor(transport) -> None:
     Idempotent: a second kill of the same transport is a no-op — real
     processes only die once, and chaos tests that tear down in both the test
     body and a finally block must not trip over the first kill's cleanup.
+    An executor that came back (``TpuShuffleCluster.rejoin_executor``: the
+    transport's ``restart`` clears the latch) is a new process and dies again.
     """
     if getattr(transport, "_chaos_killed", False):
         return

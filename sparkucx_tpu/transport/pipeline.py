@@ -253,5 +253,14 @@ class RoundPipeline:
                 if exc is None:
                     exc = e  # earliest round's failure wins, like the serial loop
         if exc is not None:
-            raise exc
+            try:
+                raise exc
+            finally:
+                # The raised exception's traceback holds this frame, whose
+                # locals would hold the exception: a cycle that keeps every
+                # frame under the failed submit — its views of the sealed
+                # rounds — from being freed until the interpreter's next full
+                # collection (an aborted exchange's round buffers then read
+                # busy at remove_shuffle and never reach the free list).
+                del exc, submit_exc, futures
         return results

@@ -32,6 +32,7 @@ over ``jax.distributed`` + the control plane in parallel/bootstrap.py.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -219,12 +220,26 @@ class TpuShuffleCluster:
             range(self.num_executors), self.conf.membership_suspect_after_ms
         )
         #: degraded-mode recovery telemetry (the chaos tests and the metrics
-        #: registry read this)
+        #: registry read this; its numbers are the ``elastic`` family of
+        #: ``metrics_text()``).  Once an exchange with replication on:
+        #: ``replicated_rounds`` / ``replicated_bytes`` (sealed rounds copied
+        #: to a ring successor, and their unpadded block bytes) and
+        #: ``replicate_ns``.  Once a recovery: ``recoveries``, ``recover_ns``,
+        #: ``restaged_blocks`` / ``restaged_bytes`` (blocks of dead executors
+        #: rebuilt from replicas) and ``degraded_subexchanges`` (collectives
+        #: dispatched on the shrunk mesh).
         self.elastic_stats = {
             "recoveries": 0,
             "last_recovery_ms": 0.0,
             "last_epoch": 0,
             "degraded_mesh": None,
+            "restaged_blocks": 0,
+            "restaged_bytes": 0,
+            "degraded_subexchanges": 0,
+            "replicated_rounds": 0,
+            "replicated_bytes": 0,
+            "replicate_ns": 0,
+            "recover_ns": 0,
         }  #: guarded by self._lock
         #: Obs plane (PR 14): cluster-level registry + flight recorder.  The
         #: registry absorbs the collective plane's surfaces (exchange timings,
@@ -905,7 +920,13 @@ class TpuShuffleCluster:
             # An executor died under this exchange: abort the stale full-mesh
             # plan and re-run degraded on the surviving pow2 bucket (or raise
             # a typed ExecutorLostError when recovery is impossible).
-            with span("exchange.recover", shuffle_id=shuffle_id):
+            # The recovery's large host arrays — the restaged rounds, each
+            # sub-exchange's send array, the landings of its received
+            # prefixes, the recovered shards — come from the pool received
+            # shards land in, where there is one (_landing): the blocks the
+            # last job's recovery gave back, not fresh mappings a time.
+            allocating = pool.allocating() if pool is not None else contextlib.nullcontext()
+            with span("exchange.recover", shuffle_id=shuffle_id), allocating:
                 self._recover_and_rerun(meta, sealed, mode)
             return
 
@@ -934,6 +955,8 @@ class TpuShuffleCluster:
         same placement either way."""
         n = self.num_executors
         factor = self.conf.replication_factor
+        t0 = time.perf_counter_ns()
+        copied_rounds = copied_bytes = 0
         for t in self.transports:
             if not self.membership.is_alive(t.executor_id):
                 continue
@@ -945,6 +968,12 @@ class TpuShuffleCluster:
                     self.transports[succ].store.put_replica(
                         shuffle_id, t.executor_id, rnd, entries, body
                     )
+                    copied_rounds += 1
+                    copied_bytes += len(body)
+        with self._lock:
+            self.elastic_stats["replicated_rounds"] += copied_rounds
+            self.elastic_stats["replicated_bytes"] += copied_bytes
+            self.elastic_stats["replicate_ns"] += time.perf_counter_ns() - t0
 
     def _recover_and_rerun(self, meta, sealed, mode: str) -> None:
         """Degraded-mode recovery: quarantine the aborted exchange's partial
@@ -960,7 +989,7 @@ class TpuShuffleCluster:
         """
         shuffle_id = meta.shuffle_id
         op = OperationStats()
-        t0 = time.monotonic()
+        t0 = time.monotonic_ns()
         snap = self.membership.snapshot()
         dead, alive, epoch = snap["dead"], snap["alive"], snap["epoch"]
         first_dead = sorted(dead)[0] if dead else -1
@@ -1010,16 +1039,152 @@ class TpuShuffleCluster:
         m, phys, waves = degraded_plan(n, alive)
         alive_set = set(alive)
         slot_rows = meta.region_bytes // self.row_bytes
-        send_rows = n * slot_rows
         lane = self.row_bytes // 4
 
-        # Restage each dead executor's rounds bit-identically from replicas:
-        # zeros staging (padding rows are zero by construction), replica block
-        # bodies at their MapperInfo absolute offsets, per-region used-row
-        # counts rebuilt from the padded lengths (allocation was contiguous,
-        # so the padded sum IS the region's used prefix).
+        # Span ``exchange.recover.restage``, once a recovery: every dead
+        # executor's rounds rebuilt from its ring successors' replicas.
+        with span("exchange.recover.restage", shuffle_id=shuffle_id) as restage:
+            restaged, restaged_blocks, restaged_bytes = self._restage_dead(
+                meta, sealed, sorted(dead), alive_set
+            )
+            if restage is not None:
+                restage.args.update(blocks=restaged_blocks, bytes=restaged_bytes)
+
+        def round_payload(l, rnd):
+            src = sealed[l] if sealed[l] is not None else restaged.get(l, [])
+            if rnd < len(src):
+                return src[rnd]
+            return None, np.zeros(n, dtype=np.int32)
+
+        fn, submesh = self._degraded_exchange_fn(m, phys, m * slot_rows)
+        bucketed = bucket_send_rows(m * slot_rows, m)
+        ax = self.conf.mesh_axis_name
+        sub_sharding = NamedSharding(submesh, P(ax, None))
+        sub_devices = list(submesh.devices.reshape(-1))
+
+        meta.recv_shards, meta.recv_sizes = [], []
+        subexchanges = 0
+        for rnd in range(num_rounds):
+            # Span ``exchange.recover.round``, once a re-run staging round;
+            # ``subexchanges``: the collectives it dispatched on the shrunk
+            # mesh (a wave pair that carries no row dispatches none)
+            dispatched = 0
+            with span("exchange.recover.round", shuffle_id=shuffle_id, round=rnd) as round_span:
+                payloads, size_rows = [], []
+                for l in range(n):
+                    p, s = round_payload(l, rnd)
+                    payloads.append(p)
+                    size_rows.append(s)
+                full_sizes = np.stack(size_rows).astype(np.int64)  # [sender, dest]
+                consumer_parts: List[List[np.ndarray]] = [[] for _ in range(n)]
+                for i in range(waves):
+                    for j in range(waves):
+                        host = np.zeros((m * bucketed, lane), dtype=np.int32)
+                        sub_sizes = np.zeros((m, m), dtype=np.int32)
+                        lo = j * m * slot_rows
+                        hi = min((j + 1) * m, n) * slot_rows
+                        for p in range(m):
+                            l = i * m + p
+                            if l >= n:
+                                continue
+                            for q in range(m):
+                                c = j * m + q
+                                if c < n:
+                                    sub_sizes[p, q] = full_sizes[l, c]
+                            if payloads[l] is None:
+                                continue
+                            src = np.asarray(payloads[l])
+                            block = np.zeros((m * slot_rows, lane), dtype=np.int32)
+                            block[: hi - lo] = src[lo:hi]
+                            host[p * bucketed : (p + 1) * bucketed] = rebucket_slots(
+                                block, m, bucketed
+                            )
+                        if not int(sub_sizes.sum()):
+                            continue  # empty sub-exchange: contributes zero rows
+                        dispatched += 1
+                        data = jax.device_put(host, sub_sharding)
+                        size_mat = jax.device_put(sub_sizes, sub_sharding)
+                        with span(
+                            "exchange.collective.degraded",
+                            shuffle_id=shuffle_id, round=rnd, wave=(i, j), rows=bucketed,
+                        ):
+                            recv, recv_sizes = fn(data, size_mat)
+                        shard_by_device = {s.device: s.data for s in recv.addressable_shards}
+                        sizes_host = np.asarray(recv_sizes)  # [consumer, sender]
+                        for q in range(m):
+                            c = j * m + q
+                            if c >= n:
+                                continue
+                            used = int(sizes_host[q].sum())
+                            if used:
+                                prefix = self._received_prefix(
+                                    shard_by_device[sub_devices[q]], used
+                                )
+                                consumer_parts[c].append(
+                                    np.asarray(prefix)[:used].reshape(-1).view(np.uint8)
+                                )
+                assembled = [
+                    np.concatenate(parts) if parts else np.empty(0, dtype=np.uint8)
+                    for parts in consumer_parts
+                ]
+                if mode == "memmap":
+                    with span("exchange.d2h_memmap", shuffle_id=shuffle_id, round=rnd):
+                        shards = self._memmap_round(meta, rnd, iter(assembled))
+                else:
+                    shards = assembled
+                recv_mat = full_sizes.T.astype(np.int32).copy()
+                meta.recv_shards.append(shards)
+                meta.recv_sizes.append(recv_mat)
+                active = int(np.count_nonzero(recv_mat))
+                self.stats.record_rows("exchange.lanes", active, recv_mat.size - active)
+                subexchanges += dispatched
+                if round_span is not None:
+                    round_span.args["subexchanges"] = dispatched
+        meta.exchanged = True
+        recover_ns = time.monotonic_ns() - t0
+        recovery_ms = recover_ns / 1e6
+        with self._lock:
+            self.elastic_stats["recoveries"] += 1
+            self.elastic_stats["last_recovery_ms"] = recovery_ms
+            self.elastic_stats["last_epoch"] = epoch
+            self.elastic_stats["degraded_mesh"] = (m, tuple(phys))
+            self.elastic_stats["restaged_blocks"] += restaged_blocks
+            self.elastic_stats["restaged_bytes"] += restaged_bytes
+            self.elastic_stats["degraded_subexchanges"] += subexchanges
+            self.elastic_stats["recover_ns"] += recover_ns
+        op.mark_done()
+        self.stats.record("exchange.recovery", op)
+        instant(
+            "exchange.recovered",
+            shuffle_id=shuffle_id, epoch=epoch, mesh=m, waves=waves,
+            recovery_ms=round(recovery_ms, 3),
+        )
+        # full postmortem bundle (metrics + membership): safe here — the
+        # recovery is done and no subsystem lock is held on this thread
+        self.recorder.capture(
+            "elastic_recovery",
+            shuffle_id=shuffle_id,
+            epoch=epoch,
+            mesh=m,
+            recovery_ms=round(recovery_ms, 3),
+        )
+
+    def _restage_dead(self, meta, sealed, dead, alive_set):
+        """Rebuild each dead executor's sealed rounds bit-identically from
+        replicas: zeros staging (padding rows are zero by construction),
+        replica block bodies at their MapperInfo absolute offsets, per-region
+        used-row counts rebuilt from the padded lengths (allocation was
+        contiguous, so the padded sum IS the region's used prefix).  The dead
+        executors' entries of ``sealed`` are dropped: their memory died with
+        them.  Returns ({executor: [(payload, size_rows) a round]}, blocks
+        restaged, their bytes)."""
+        shuffle_id = meta.shuffle_id
+        n = self.num_executors
+        send_rows = n * (meta.region_bytes // self.row_bytes)
+        lane = self.row_bytes // 4
         restaged: Dict[int, List[Tuple[np.ndarray, np.ndarray]]] = {}
-        for d in sorted(dead):
+        blocks = nbytes = 0
+        for d in dead:
             dead_rounds = len(sealed[d])
             sealed[d] = None  # its memory died with it — recover honestly
             cands = ring_neighbors(d, range(n), self.conf.replication_factor)
@@ -1052,118 +1217,19 @@ class TpuShuffleCluster:
                             )
                         flat[off : off + ln] = np.frombuffer(bytes(body), dtype=np.uint8)
                         sizes[off // meta.region_bytes] += -(-ln // self.row_bytes)
+                        blocks += 1
+                        nbytes += ln
                 rounds_out.append((payload, sizes.astype(np.int32)))
             restaged[d] = rounds_out
+        return restaged, blocks, nbytes
 
-        def round_payload(l, rnd):
-            src = sealed[l] if sealed[l] is not None else restaged.get(l, [])
-            if rnd < len(src):
-                return src[rnd]
-            return None, np.zeros(n, dtype=np.int32)
-
-        fn, submesh = self._degraded_exchange_fn(m, phys, m * slot_rows, epoch)
-        bucketed = bucket_send_rows(m * slot_rows, m)
-        ax = self.conf.mesh_axis_name
-        sub_sharding = NamedSharding(submesh, P(ax, None))
-        sub_devices = list(submesh.devices.reshape(-1))
-
-        meta.recv_shards, meta.recv_sizes = [], []
-        for rnd in range(num_rounds):
-            payloads, size_rows = [], []
-            for l in range(n):
-                p, s = round_payload(l, rnd)
-                payloads.append(p)
-                size_rows.append(s)
-            full_sizes = np.stack(size_rows).astype(np.int64)  # [sender, dest]
-            consumer_parts: List[List[np.ndarray]] = [[] for _ in range(n)]
-            for i in range(waves):
-                for j in range(waves):
-                    host = np.zeros((m * bucketed, lane), dtype=np.int32)
-                    sub_sizes = np.zeros((m, m), dtype=np.int32)
-                    lo = j * m * slot_rows
-                    hi = min((j + 1) * m, n) * slot_rows
-                    for p in range(m):
-                        l = i * m + p
-                        if l >= n:
-                            continue
-                        for q in range(m):
-                            c = j * m + q
-                            if c < n:
-                                sub_sizes[p, q] = full_sizes[l, c]
-                        if payloads[l] is None:
-                            continue
-                        src = np.asarray(payloads[l])
-                        block = np.zeros((m * slot_rows, lane), dtype=np.int32)
-                        block[: hi - lo] = src[lo:hi]
-                        host[p * bucketed : (p + 1) * bucketed] = rebucket_slots(
-                            block, m, bucketed
-                        )
-                    if not int(sub_sizes.sum()):
-                        continue  # empty sub-exchange: contributes zero rows
-                    data = jax.device_put(host, sub_sharding)
-                    size_mat = jax.device_put(sub_sizes, sub_sharding)
-                    with span(
-                        "exchange.collective.degraded",
-                        shuffle_id=shuffle_id, round=rnd, wave=(i, j), rows=bucketed,
-                    ):
-                        recv, recv_sizes = fn(data, size_mat)
-                    shard_by_device = {s.device: s.data for s in recv.addressable_shards}
-                    sizes_host = np.asarray(recv_sizes)  # [consumer, sender]
-                    for q in range(m):
-                        c = j * m + q
-                        if c >= n:
-                            continue
-                        used = int(sizes_host[q].sum())
-                        if used:
-                            prefix = self._received_prefix(
-                                shard_by_device[sub_devices[q]], used
-                            )
-                            consumer_parts[c].append(
-                                np.asarray(prefix)[:used].reshape(-1).view(np.uint8)
-                            )
-            assembled = [
-                np.concatenate(parts) if parts else np.empty(0, dtype=np.uint8)
-                for parts in consumer_parts
-            ]
-            if mode == "memmap":
-                with span("exchange.d2h_memmap", shuffle_id=shuffle_id, round=rnd):
-                    shards = self._memmap_round(meta, rnd, iter(assembled))
-            else:
-                shards = assembled
-            recv_mat = full_sizes.T.astype(np.int32).copy()
-            meta.recv_shards.append(shards)
-            meta.recv_sizes.append(recv_mat)
-            active = int(np.count_nonzero(recv_mat))
-            self.stats.record_rows("exchange.lanes", active, recv_mat.size - active)
-        meta.exchanged = True
-        recovery_ms = (time.monotonic() - t0) * 1e3
-        with self._lock:
-            self.elastic_stats["recoveries"] += 1
-            self.elastic_stats["last_recovery_ms"] = recovery_ms
-            self.elastic_stats["last_epoch"] = epoch
-            self.elastic_stats["degraded_mesh"] = (m, tuple(phys))
-        op.mark_done()
-        self.stats.record("exchange.recovery", op)
-        instant(
-            "exchange.recovered",
-            shuffle_id=shuffle_id, epoch=epoch, mesh=m, waves=waves,
-            recovery_ms=round(recovery_ms, 3),
-        )
-        # full postmortem bundle (metrics + membership): safe here — the
-        # recovery is done and no subsystem lock is held on this thread
-        self.recorder.capture(
-            "elastic_recovery",
-            shuffle_id=shuffle_id,
-            epoch=epoch,
-            mesh=m,
-            recovery_ms=round(recovery_ms, 3),
-        )
-
-    def _degraded_exchange_fn(self, m: int, phys, sub_rows: int, epoch: int):
-        """Compile (or reuse) the shrunk-mesh exchange for a degraded epoch.
-        The cache key carries the membership epoch and surviving device set on
-        top of the usual pow2 bucket, so a later failure pattern with the same
-        geometry still recompiles against its own mesh."""
+    def _degraded_exchange_fn(self, m: int, phys, sub_rows: int):
+        """Compile (or reuse) the shrunk-mesh exchange for a degraded mesh.
+        The cache key carries the surviving device set on top of the usual
+        pow2 bucket: those identify the executable.  The membership epoch
+        does not — an executor that is lost, rejoins and is lost again
+        leaves the same survivors two epochs later, and finds the executable
+        of the first loss again."""
         send_rows = bucket_send_rows(sub_rows, m)
         from sparkucx_tpu.ops.ici_exchange import resolve_exchange_impl
 
@@ -1171,7 +1237,7 @@ class TpuShuffleCluster:
         impl = resolve_exchange_impl(
             self.conf.exchange_impl, submesh.devices.reshape(-1)[0].platform, m
         )
-        key = ("degraded", epoch, m, tuple(phys), send_rows, self.row_bytes, impl)
+        key = ("degraded", m, tuple(phys), send_rows, self.row_bytes, impl)
         with self._lock:
             fn = self._exchange_cache.get(key)
             if fn is None:
@@ -1193,10 +1259,16 @@ class TpuShuffleCluster:
         return self.membership.mark_dead(executor_id, reason)
 
     def rejoin_executor(self, executor_id: ExecutorId) -> bool:
-        """Regrow: mark a previously-dead executor alive again.  The full mesh
-        is restored for the NEXT shuffle epoch — in-flight degraded state is
-        untouched, and because full-mesh compile-cache keys carry no epoch,
-        regrowing recompiles nothing."""
+        """Regrow: a previously-dead executor comes back, as a restarted
+        process does — with an empty store of its own and nothing of the one
+        that died (``TpuShuffleTransport.restart``), and able to die again —
+        and is marked alive.  The full mesh is restored for the NEXT shuffle
+        epoch — in-flight degraded state is untouched, and because no
+        compile-cache key carries an epoch, regrowing recompiles nothing.
+        False, and nothing done, for an executor that is alive or unknown."""
+        if executor_id not in range(self.num_executors) or self.membership.is_alive(executor_id):
+            return False
+        self.transports[executor_id].restart()
         return self.membership.mark_alive(executor_id)
 
     def _memmap_round(self, meta, rnd: int, host_views):
@@ -1559,6 +1631,25 @@ class TpuShuffleTransport(ShuffleTransport):
         collective-plane analogue of a peer observing ECONNRESET."""
         self.store.close()
         self.cluster.membership.mark_dead(self.executor_id, "chaos kill_executor")
+
+    def restart(self) -> None:
+        """Come back as a restarted executor process does
+        (``TpuShuffleCluster.rejoin_executor``): outstanding requests
+        cancelled, no block registered, and a NEW store — no shuffle, no
+        replica, no free list, no spill directory, counters from zero; what
+        the old one held went with the process.  What ``kill_executor``
+        latched on this transport is cleared: the executor can be lost again.
+        Holders of the executor's store look it up here (the manager's
+        resolver does), never keep the old one."""
+        self.close()
+        with self._registry_lock:
+            blocks, self._registry = list(self._registry.values()), {}
+        for block in blocks:
+            block.close()
+        self.store = HbmBlockStore(
+            self.cluster.conf, device=self.device, executor_id=self.executor_id
+        )
+        self._chaos_killed = False
 
     def add_executor(self, executor_id: ExecutorId, address: bytes) -> None:
         # Single-controller mode: membership is the cluster's mesh; nothing to do.

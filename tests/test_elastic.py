@@ -40,6 +40,19 @@ def _clean_faults():
     faults.reset()
 
 
+@pytest.fixture
+def tracer():
+    """The process-wide tracer, enabled and cleared; back to what it was afterwards."""
+    from sparkucx_tpu.utils.trace import TRACER
+
+    enabled, recording = TRACER.enabled, TRACER.recording
+    TRACER.enable()
+    TRACER.clear()
+    yield TRACER
+    TRACER.enabled, TRACER.recording = enabled, recording
+    TRACER.clear()
+
+
 # ---------------------------------------------------------------------------
 # membership units
 # ---------------------------------------------------------------------------
@@ -311,6 +324,74 @@ class TestElasticRecovery:
         assert cluster.elastic_stats["recoveries"] == 1
         assert cluster.membership.epoch == epoch_after_rejoin
 
+        # the rejoined executor is a new process: it can be lost AGAIN (a
+        # latch of the first kill made the second a silent no-op), the
+        # recovery runs again, and finds the shrunk mesh's executable of the
+        # first loss although the membership is two epochs on
+        executables = len(cluster._exchange_cache)
+        meta3 = cluster.create_shuffle(2, M, R)
+        again = _run_shuffle(cluster, meta3, 2, M, R, seed=13, kill=2)
+        assert len(again) == M * R
+        assert cluster.elastic_stats["recoveries"] == 2
+        assert cluster.elastic_stats["last_epoch"] == epoch_after_rejoin + 1
+        assert cluster.membership.alive() == [0, 1, 3]
+        assert len(cluster._exchange_cache) == executables
+
+    def test_rejoin_is_a_restarted_executor(self):
+        """What comes back under a lost executor's id is what a restarted
+        process has: a store of its own with nothing in it — no shuffle of
+        before the loss, no replica it held for its ring predecessor, an empty
+        free list, counters from zero — and no block registered.  An alive or
+        unknown id is left as it is."""
+        cluster = _mk_cluster(4)
+        meta = cluster.create_shuffle(0, 12, 8)
+        assert not cluster.rejoin_executor(2) and not cluster.rejoin_executor(9)
+        before = cluster.transport(2).store
+        _run_shuffle(cluster, meta, 0, 12, 8, kill=2)
+        assert cluster.transport(3).store.replica_stats()["replica_sources"] == 1
+        assert cluster.rejoin_executor(2)
+        store = cluster.transport(2).store
+        assert store is not before and store.executor_id == 2
+        assert store.replica_stats() == {"replica_bytes": 0, "replica_rounds": 0, "replica_sources": 0}
+        assert not any(v for k, v in store.write_stats().items() if k != "executor")
+        with pytest.raises(Exception, match="unknown shuffle"):
+            store.num_rounds(0)
+        assert not cluster.rejoin_executor(2)  # alive again: nothing to do
+        assert cluster.transport(2).store is store
+        # the recovered shuffle is the cluster's and outlives the rejoin
+        view, length = cluster.locate_received_block(meta.owner_of_reduce(5), 0, 2, 5)
+        assert length == 2000 and len(view) == 2000
+        cluster.remove_shuffle(0)
+        assert all(t.store.replica_stats()["replica_bytes"] == 0 for t in cluster.transports)
+
+    def test_the_elastic_counters_count_what_a_recovery_did(self):
+        """The ``elastic`` family of ``metrics_text()``: one replica of every
+        sealed round before the first submit, and a recovery's restaged
+        blocks, sub-exchanges and nanoseconds; all zero on a conf with
+        replication off, which copies nothing."""
+        n, M, R = 4, 12, 8
+        cluster = _mk_cluster(n)
+        meta = cluster.create_shuffle(0, M, R)
+        _run_shuffle(cluster, meta, 0, M, R, kill=2)
+        stats = cluster.elastic_stats
+        lost_maps = [m for m in range(M) if meta.map_owner[m] == 2]
+        assert stats["restaged_blocks"] == len(lost_maps) * R
+        assert stats["restaged_bytes"] == len(lost_maps) * R * 2000
+        assert stats["replicated_bytes"] == M * R * 2000
+        assert stats["replicated_rounds"] >= n  # a round or more an executor, one copy each
+        assert 1 <= stats["degraded_subexchanges"] <= 4 * len(meta.recv_sizes)
+        assert stats["replicate_ns"] > 0 and stats["recover_ns"] > 0
+        text = cluster.metrics_text()
+        for name in ("recoveries", "restaged_blocks", "restaged_bytes", "degraded_subexchanges",
+                     "replicated_rounds", "replicated_bytes", "replicate_ns", "recover_ns"):
+            assert f"sparkucx_tpu_elastic_{name} " in text, name
+        assert "sparkucx_tpu_elastic_recoveries 1" in text
+
+        plain = _mk_cluster(n, replication_factor=0, elastic=False)
+        _run_shuffle(plain, plain.create_shuffle(0, M, R), 0, M, R)
+        assert not any(v for k, v in plain.elastic_stats.items() if k != "degraded_mesh")
+        assert all(t.store.replica_stats()["replica_bytes"] == 0 for t in plain.transports)
+
     def test_quota_engine_fails_fast_on_loss(self):
         """The quota-capped engine has no degraded path: losing an executor
         mid-run must raise the typed error, not hang in a stale plan."""
@@ -418,3 +499,199 @@ class TestSpmdDegradedGuard:
             assert ei.value.executor_id == 0
         finally:
             ex.close()
+
+
+# ---------------------------------------------------------------------------
+# the loss on the served path: TpuShuffleManager -> writer -> store ->
+# run_exchange -> reader
+# ---------------------------------------------------------------------------
+
+
+#: the upstream gate job at test size on four executors: 8 map tasks of 60
+#: records of 25,000 bytes over 200 reducers, 12 MB a job, through 1 MiB of
+#: staging an executor — a dozen staging rounds, so that an executor can be
+#: lost after some of them have gone through
+LOST, LOST_AT_ROUND, MAPPERS = 2, 4, 8
+
+
+def _manager(**conf_kw):
+    from sparkucx_tpu.shuffle.manager import TpuShuffleManager
+
+    conf_kw.setdefault("staging_capacity_per_executor", 1 << 20)
+    conf_kw.setdefault("elastic", True)
+    conf_kw.setdefault("replication_factor", 1)
+    return TpuShuffleManager(TpuShuffleConf(**conf_kw), num_executors=4)
+
+
+def _run_job(mgr, groupbytest, records, shuffle_id, lose=(LOST,)):
+    """One whole job through the manager: every map task written and
+    committed, the exchange (during which ``lose`` die at the submit of
+    staging round ``LOST_AT_ROUND``), every reduce task read in full.
+    Returns ({reducer: [(key, value)]}, the readers' metrics)."""
+
+    def die(**_ctx):
+        for executor in lose:
+            faults.kill_executor(mgr.cluster.transport(executor))
+
+    if lose:
+        faults.arm("exchange.submit", die, times=1,
+                   match={"shuffle_id": shuffle_id, "round": LOST_AT_ROUND})
+    try:
+        groupbytest.write_and_exchange(mgr, shuffle_id, records)
+    finally:
+        faults.reset()
+    read, metrics = {}, []
+    for r in range(records.reducers):
+        reader = mgr.get_reader(shuffle_id, r, r + 1)
+        read[r] = [(key, bytes(value)) for key, value in reader.read()]
+        metrics.append(reader.metrics)
+    return read, metrics
+
+
+def _equals_the_plain_groupby(records, read) -> bool:
+    """Group count, crc32 of every value under its key, key in its partition."""
+    checks = []
+    for r, pairs in read.items():
+        check = records.check(r, full=True)
+        for key, value in pairs:
+            check.add(key, value)
+        checks.append(check)
+    return all(c.ok() for c in checks) and records.complete(checks)
+
+
+class TestLossThroughTheManager:
+    def test_a_job_that_loses_an_executor_equals_the_plain_groupby_and_the_undisturbed_job(self, groupbytest):
+        records = groupbytest.records(MAPPERS, seed=41)
+        with _manager() as undisturbed:
+            whole, _ = _run_job(undisturbed, groupbytest, records, 0, lose=())
+            assert undisturbed.cluster.elastic_stats["recoveries"] == 0
+        with _manager() as mgr:
+            read, metrics = _run_job(mgr, groupbytest, records, 0)
+            cluster = mgr.cluster
+            assert cluster.elastic_stats["recoveries"] == 1
+            assert cluster.membership.alive() == [0, 1, 3]
+            assert len(cluster.meta(0).recv_sizes) > LOST_AT_ROUND  # rounds had gone through
+        assert _equals_the_plain_groupby(records, read)
+        assert read == whole  # byte for byte, record order too
+        assert sum(len(pairs) for pairs in read.values()) == MAPPERS * 60
+        assert not any(m.blocks_retried or m.failovers or m.fetch_timeouts for m in metrics)
+
+    def test_the_dead_executors_partitions_are_read_through_the_managers_reader(self, groupbytest):
+        """A quarter of the reduce partitions are the dead executor's; their
+        reader resolves on its transport, borrows every block where the
+        recovery left it and never sees the loss."""
+        records = groupbytest.records(MAPPERS, seed=42)
+        with _manager() as mgr:
+            _run_job(mgr, groupbytest, records, 0)
+            meta = mgr.cluster.meta(0)
+            mine = [r for r in range(records.reducers) if meta.owner_of_reduce(r) == LOST]
+            assert len(mine) == records.reducers // 4
+            for r in mine:
+                reader = mgr.get_reader(0, r, r + 1)
+                assert reader.executor_id == LOST and reader.replica_of is not None
+                check = records.check(r, full=True)
+                for key, value in reader.read():
+                    check.add(key, value)
+                assert check.ok(), r
+                m = reader.metrics
+                assert m.resident_blocks == len(records.mappers_of(r)) and m.copied_blocks == 0
+                assert (m.blocks_retried, m.failovers, m.fetch_timeouts) == (0, 0, 0)
+
+    def test_three_jobs_in_a_row_each_lose_and_regain_executor_2(self, groupbytest):
+        """One manager, the benchmark's loop: lose executor 2 mid-exchange,
+        read, unregister, rejoin.  The third job compiles nothing (the shrunk
+        mesh's executable is found again whatever the epoch, a rejoin
+        recompiles nothing on the full mesh), and nothing of a job is left
+        after its removal: no replica body, no received shard, and the
+        survivors' round buffers back on their free lists, level from job to
+        job."""
+        from benchmark.counters import CompileCounter
+
+        records = groupbytest.records(MAPPERS, seed=43)
+        compiles = CompileCounter()
+        held = []
+        with _manager() as mgr:
+            cluster = mgr.cluster
+            for sid in range(3):
+                mark = compiles.snapshot()
+                read, metrics = _run_job(mgr, groupbytest, records, sid)
+                assert _equals_the_plain_groupby(records, read), sid
+                assert not any(m.blocks_retried or m.failovers or m.fetch_timeouts for m in metrics)
+                assert cluster.elastic_stats["recoveries"] == sid + 1
+                assert cluster.elastic_stats["last_epoch"] == 2 * sid + 1
+                assert sum(t.store.replica_stats()["replica_bytes"] for t in cluster.transports) > 0
+                mgr.unregister_shuffle(sid)
+                stores = [t.store for t in cluster.transports]
+                assert all(s.replica_stats()["replica_bytes"] == 0 for s in stores)
+                with pytest.raises(Exception, match="unknown shuffle"):
+                    cluster.meta(sid)
+                assert cluster.rejoin_executor(LOST)
+                assert cluster.membership.alive() == [0, 1, 2, 3]
+                survivors = [t.store.write_stats() for t in cluster.transports if t.executor_id != LOST]
+                assert all(s["pool_dropped_busy"] == 0 for s in survivors), survivors
+                held.append([s["pool_held_bytes"] for s in survivors])
+                assert cluster.transport(LOST).store.write_stats()["pool_held_bytes"] == 0
+                if sid == 2:
+                    assert compiles.since(mark)["compiles"] == 0
+            assert held[0] == held[1] == held[2] and all(h > 0 for h in held[0])
+            assert set(cluster.executed_lowerings()["exchange"]) == {"dense"}
+            assert cluster.elastic_stats["restaged_blocks"] == 3 * sum(
+                len(records.blocks[m]) for m in range(MAPPERS) if m % 4 == LOST)
+
+    def test_the_loss_of_an_executor_and_its_successor_is_refused_typed(self, groupbytest):
+        records = groupbytest.records(MAPPERS, seed=44)
+        with _manager() as mgr:
+            with pytest.raises(BlockNotFoundError, match="unrecoverable"):
+                _run_job(mgr, groupbytest, records, 0, lose=(2, 3))
+            mgr.unregister_shuffle(0)
+            # both come back, and the next job runs whole
+            assert mgr.cluster.rejoin_executor(2) and mgr.cluster.rejoin_executor(3)
+            read, _ = _run_job(mgr, groupbytest, records, 1, lose=())
+            assert _equals_the_plain_groupby(records, read)
+
+    def test_with_replication_off_nothing_of_it_runs(self, groupbytest, tracer):
+        """The default conf: no copy, no replica, no span of the elastic
+        path, the counters at zero — and a loss is the typed error."""
+        records = groupbytest.records(MAPPERS, seed=45)
+        with _manager(replication_factor=0, elastic=False) as mgr:
+            read, _ = _run_job(mgr, groupbytest, records, 0, lose=())
+            names = {ev["name"] for ev in tracer.events}
+            stats = dict(mgr.cluster.elastic_stats)
+            replica_bytes = [t.store.replica_stats()["replica_bytes"] for t in mgr.cluster.transports]
+            mgr.unregister_shuffle(0)
+            with pytest.raises(ExecutorLostError):
+                _run_job(mgr, groupbytest, records, 1)
+        assert _equals_the_plain_groupby(records, read)
+        assert "exchange.collective" in names
+        assert not {n for n in names if n.startswith(("exchange.replicate", "exchange.recover"))}
+        assert replica_bytes == [0, 0, 0, 0]
+        assert not any(v for k, v in stats.items() if k != "degraded_mesh")
+
+    def test_a_traced_recovery_records_its_phases(self, groupbytest, tracer):
+        """``exchange.recover`` and its children: one ``restage`` with the
+        blocks and bytes that came back from replicas, one ``round`` a re-run
+        staging round with the sub-exchanges it dispatched, which are the
+        ``exchange.collective.degraded`` spans inside it."""
+        records = groupbytest.records(MAPPERS, seed=46)
+        with _manager() as mgr:
+            _run_job(mgr, groupbytest, records, 0)
+            events = [ev for ev in tracer.events if ev.get("ph") == "X"]
+            stats = dict(mgr.cluster.elastic_stats)
+            rounds = len(mgr.cluster.meta(0).recv_sizes)
+        named = lambda name: [ev for ev in events if ev["name"] == name]
+        [replicate], [recover], [restage] = (
+            named("exchange.replicate"), named("exchange.recover"), named("exchange.recover.restage"))
+        assert restage["args"]["blocks"] == stats["restaged_blocks"] > 0
+        assert restage["args"]["bytes"] == stats["restaged_bytes"] == sum(
+            len(p) for m in range(MAPPERS) if m % 4 == LOST for _, p in records.blocks[m])
+        reruns = named("exchange.recover.round")
+        assert [ev["args"]["round"] for ev in reruns] == list(range(rounds))
+        degraded = named("exchange.collective.degraded")
+        assert sum(ev["args"]["subexchanges"] for ev in reruns) == len(degraded) == stats["degraded_subexchanges"]
+        inside = lambda ev, outer: outer["ts"] <= ev["ts"] and ev["ts"] + ev["dur"] <= outer["ts"] + outer["dur"] + 1
+        assert inside(restage, recover) and all(inside(ev, recover) for ev in reruns)
+        assert all(any(inside(ev, rerun) for rerun in reruns) for ev in degraded)
+        assert replicate["ts"] + replicate["dur"] <= recover["ts"] + 1
+        # the replicas are whole before the first round is submitted
+        first_submit = min(ev["ts"] for ev in named("exchange.pipeline.submit"))
+        assert replicate["ts"] + replicate["dur"] <= first_submit + 1

@@ -367,3 +367,40 @@ def test_past_the_budget_a_shard_lands_in_fresh_pages(monkeypatch):
     assert counters["kept_shards"] + counters["fresh_shards"] == 3 * rounds
     assert 0 < counters["kept_shards"] < 2 * rounds
     assert stats["dropped_blocks"] > 0 and stats["held_bytes"] <= STAGING
+
+
+# -- a recovery under the pool (PR 45) ------------------------------------------------
+
+
+@needs_pool
+@pytest.mark.parametrize("landing", LANDINGS)
+def test_a_recovery_allocates_from_the_pool_and_recovers_the_same_bytes(monkeypatch, landing):
+    """An executor lost mid-exchange, twice on one cluster: the recovery's
+    host arrays (restaged rounds, each sub-exchange's send array, the
+    landings, the recovered shards) come from the landing pool where there is
+    one — the second recovery lands in the blocks the first gave back — and
+    the recovered bytes are the staged bytes under either landing."""
+    from sparkucx_tpu.testing import faults
+
+    cluster = make_cluster(monkeypatch, landing, n=4, elastic=True, replication_factor=1)
+    pool = cluster._landing()
+    assert (pool is not None) == (landing == "pooled")
+    hits = []
+    try:
+        for sid in range(2):
+            faults.arm("exchange.submit", lambda **_: faults.kill_executor(cluster.transport(2)),
+                       times=1, match={"shuffle_id": sid, "round": 1})
+            meta, oracle = run_job(cluster, sid, seed=7 + sid)
+            assert cluster.elastic_stats["recoveries"] == sid + 1
+            read_back(cluster, meta, oracle, sid)
+            cluster.remove_shuffle(sid)
+            del meta
+            gc.collect()
+            assert cluster.rejoin_executor(2)
+            if pool is not None:
+                hits.append(pool.stats()["hits"])
+    finally:
+        faults.reset()
+    if pool is not None:
+        first, second = hits[0], hits[1] - hits[0]
+        assert second > first and pool.stats()["held_bytes"] > 0
