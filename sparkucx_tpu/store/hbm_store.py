@@ -144,6 +144,25 @@ def _device_nbytes(array) -> int:
     return 0
 
 
+def _owns_flat_bytes(arr: np.ndarray) -> bool:
+    """A flat contiguous ``uint8`` array that owns its memory: nobody else's
+    buffer shows through it."""
+    return (
+        arr.dtype == np.uint8 and arr.ndim == 1 and arr.flags.c_contiguous and arr.flags.owndata
+    )
+
+
+def _gather_blocks(dst: np.ndarray, src: np.ndarray, segments) -> None:
+    """The replica tier's one copy: ``segments`` of ``(dst offset, src offset,
+    length)`` bytes out of a staging round (a RAM round, the disk tier's
+    mapping) into ``dst``.  Slice assignment, one ``memcpy`` a block with the
+    interpreter given up: on the chip's host it moves a job's 1.0 GB in
+    0.09 s where ``native.batch_copy``'s thread team (a team a call, 36
+    calls a job) takes 0.33 (PERF.md section 6, PR 46's probe)."""
+    for at, off, ln in segments:
+        dst[at : at + ln] = src[off : off + ln]
+
+
 def _device_block_bytes(array, offset: int, length: int, alignment: int) -> np.ndarray:
     """``length`` bytes at byte ``offset`` (a row boundary) of a device round,
     as uint8 on the host: the block's rows are sliced ON the device, in a
@@ -2280,45 +2299,73 @@ class HbmBlockStore:
 
     # -- neighbor-replication tier (REPLICA_PUT/failover serving) ----------
 
-    def replica_source(self, shuffle_id: int) -> List[Tuple[int, List[Tuple[int, int, int]], bytes]]:
+    def replica_source(
+        self, shuffle_id: int, alloc: Optional[Callable[[int], np.ndarray]] = None
+    ) -> List[Tuple[int, List[Tuple[int, int, int]], object]]:
         """Snapshot this executor's sealed rounds for replication: one
-        ``(round, [(map, reduce, length)...], body bytes)`` per staging round,
+        ``(round, [(map, reduce, length)...], body)`` per staging round,
         body = the unpadded block payloads concatenated in table order.  Only
         locally staged entries are included — entries installed from peers'
-        MapperInfo carry sender-relative offsets and no local bytes."""
+        MapperInfo carry sender-relative offsets and no local bytes.
+
+        A replicated byte is copied ONCE, outside the store's lock.  Under the
+        lock only what is small is taken, a round: the sorted table, each
+        block's offset, and a reference to the round's array — a sealed
+        round is never written again before ``remove_shuffle``, and the
+        reference keeps its buffer off the free list by the rule
+        ``_recycle_rounds`` applies to every holder.  Then, with the lock
+        released, each round's blocks are gathered into one destination:
+        ``alloc(nbytes)``'s ``uint8`` array where the caller names where a
+        body lies (the cluster's landing pool, ``_replicate_sealed``), else a
+        ``bytearray`` (the wire's frame wants a bytes-like).  What can still
+        change or go under a reader — the live round of a shuffle not yet
+        sealed, shm staging (``remove_shuffle`` unmaps it), a device round's
+        per-block D2H — is gathered with the lock held, as ever."""
         st = self._state(shuffle_id)
-        out: List[Tuple[int, List[Tuple[int, int, int]], bytes]] = []
+
+        def gather(nbytes, segments, source, staged):
+            body = bytearray(nbytes) if alloc is None else alloc(nbytes)
+            dst = np.frombuffer(body, dtype=np.uint8) if alloc is None else body
+            if source is None:  # a device round's last: one small D2H a block
+                for (at, _off, ln), e in zip(segments, staged):
+                    dst[at : at + ln] = self._live_device_block(st, e)
+            else:
+                _gather_blocks(dst, source, segments)
+            return body
+
+        plans = []  # (round, entries, body gathered under the lock or None, gather's arguments)
         with self._lock:
-            for rnd in range(st.round + 1):
-                keys = sorted(
-                    k for k, e in st.blocks.items() if e.round == rnd and e.local
-                )
+            by_round: Dict[int, List[Tuple[int, int]]] = {}
+            for key, e in st.blocks.items():
+                if e.local:
+                    by_round.setdefault(e.round, []).append(key)
+            for rnd in sorted(by_round):
                 entries: List[Tuple[int, int, int]] = []
-                body = bytearray()
-                for m, r in keys:
-                    e = st.blocks[(m, r)]
-                    entries.append((m, r, e.length))
-                    if not e.length:
-                        continue
-                    if rnd < len(st.prev_rounds):
-                        staging = st.prev_rounds[rnd][0]
-                        if staging is None:
-                            raise TransportError(
-                                f"shuffle {shuffle_id} staging already released"
-                            )
-                        body += staging[e.offset : e.offset + e.length].tobytes()
-                    elif st.device_mode:
-                        body += self._live_device_block(st, e).tobytes()
-                    else:
-                        staging = st.staging
-                        if staging is None:
-                            raise TransportError(
-                                f"shuffle {shuffle_id} staging already released"
-                            )
-                        body += staging[e.offset : e.offset + e.length].tobytes()
-                if entries:
-                    out.append((rnd, entries, bytes(body)))
-        return out
+                segments: List[Tuple[int, int, int]] = []  # (body offset, round offset, length)
+                staged: List[_BlockEntry] = []  # the blocks of ``segments``
+                pos = 0
+                for key in sorted(by_round[rnd]):
+                    e = st.blocks[key]
+                    entries.append((key[0], key[1], e.length))
+                    if e.length:
+                        segments.append((pos, e.offset, e.length))
+                        staged.append(e)
+                        pos += e.length
+                live = rnd >= len(st.prev_rounds)
+                source = None
+                if not (live and st.device_mode):
+                    source = st.staging if live else st.prev_rounds[rnd][0]
+                    if source is None:
+                        raise TransportError(f"shuffle {shuffle_id} staging already released")
+                args = (pos, segments, source, staged)
+                under_lock = live and (
+                    source is None or not st.sealed or st.staging_closer is not None
+                )
+                plans.append((rnd, entries, gather(*args) if under_lock else None, args))
+        return [
+            (rnd, entries, gather(*args) if body is None else body)
+            for rnd, entries, body, args in plans
+        ]
 
     def put_replica(
         self,
@@ -2345,13 +2392,19 @@ class HbmBlockStore:
                 f"replica round (shuffle={shuffle_id}, src={src_executor}, "
                 f"round={round_idx}) table claims {pos} B but body is {len(body)} B"
             )
-        # bytes bodies wrap zero-copy (np.frombuffer over bytes never copies);
-        # a decoded bytearray from the compressed replica path (transport/
-        # peer.py) also wraps directly — the receiver hands ownership over, so
-        # the historical defensive bytes() copy only remains for exotic
-        # bytes-likes (non-contiguous memoryviews)
+        # The sender hands ownership over, so what owns its bytes is installed
+        # as it is: a flat ``uint8`` array that owns its memory (the cluster's
+        # one gather, ``replica_source(alloc=...)``) becomes the tier's array,
+        # read-only from here on; bytes bodies wrap zero-copy (np.frombuffer
+        # over bytes never copies), as does a decoded bytearray from the
+        # compressed replica path (transport/peer.py).  Anything else — a view
+        # of somebody's buffer, a non-contiguous memoryview — is copied: a
+        # replica is its own bytes, never a window into its source.
         if not len(body):
             arr = np.empty(0, dtype=np.uint8)
+        elif isinstance(body, np.ndarray) and _owns_flat_bytes(body):
+            arr = body
+            arr.flags.writeable = False
         elif isinstance(body, (bytes, bytearray)):
             arr = np.frombuffer(body, dtype=np.uint8)
         else:
@@ -2382,12 +2435,15 @@ class HbmBlockStore:
 
     def replica_block(
         self, shuffle_id: int, src_executor: int, map_id: int, reduce_id: int
-    ) -> Optional[bytes]:
+    ) -> Optional[np.ndarray]:
         """The replicated bytes of one block FROM A NAMED SOURCE executor —
         the restage path's accessor (elastic recovery rebuilds a dead
         executor's staging from its ring-successor's replica tier, and must
         not accidentally serve a same-keyed block replicated from a different
-        source).  None when no replica of (src, block) landed here."""
+        source): a read-only ``uint8`` view of the replica round where it
+        lies — a replica is never written again, and the view keeps its array
+        alive past ``remove_shuffle``.  None when no replica of (src, block)
+        landed here."""
         with self._lock:
             rounds = self._replicas.get((shuffle_id, src_executor))
             if not rounds:
@@ -2395,7 +2451,9 @@ class HbmBlockStore:
             for index, arr in rounds.values():
                 hit = index.get((map_id, reduce_id))
                 if hit is not None:
-                    return arr[hit[0] : hit[0] + hit[1]].tobytes()
+                    view = arr[hit[0] : hit[0] + hit[1]]
+                    view.flags.writeable = False
+                    return view
         return None
 
     def replica_stats(self) -> Dict[str, int]:

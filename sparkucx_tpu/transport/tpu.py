@@ -223,11 +223,15 @@ class TpuShuffleCluster:
         #: registry read this; its numbers are the ``elastic`` family of
         #: ``metrics_text()``).  Once an exchange with replication on:
         #: ``replicated_rounds`` / ``replicated_bytes`` (sealed rounds copied
-        #: to a ring successor, and their unpadded block bytes) and
-        #: ``replicate_ns``.  Once a recovery: ``recoveries``, ``recover_ns``,
-        #: ``restaged_blocks`` / ``restaged_bytes`` (blocks of dead executors
-        #: rebuilt from replicas) and ``degraded_subexchanges`` (collectives
-        #: dispatched on the shrunk mesh).
+        #: to a ring successor, and their unpadded block bytes),
+        #: ``replicate_ns``, ``replica_copied_bytes`` (bytes copied while
+        #: replicating: ``replicated_bytes`` where a byte is written once) and
+        #: ``replica_landing_hits`` / ``replica_landing_misses`` (replica
+        #: arrays that came from a block the landing pool kept / did not;
+        #: 0 / 0 where there is no pool).  Once a recovery: ``recoveries``,
+        #: ``recover_ns``, ``restaged_blocks`` / ``restaged_bytes`` (blocks of
+        #: dead executors rebuilt from replicas) and ``degraded_subexchanges``
+        #: (collectives dispatched on the shrunk mesh).
         self.elastic_stats = {
             "recoveries": 0,
             "last_recovery_ms": 0.0,
@@ -239,6 +243,9 @@ class TpuShuffleCluster:
             "replicated_rounds": 0,
             "replicated_bytes": 0,
             "replicate_ns": 0,
+            "replica_copied_bytes": 0,
+            "replica_landing_hits": 0,
+            "replica_landing_misses": 0,
             "recover_ns": 0,
         }  #: guarded by self._lock
         #: Obs plane (PR 14): cluster-level registry + flight recorder.  The
@@ -629,9 +636,12 @@ class TpuShuffleCluster:
         # historical quota-off engine); chunked plans fail fast with a typed
         # error, exactly like the retired quota engine.
         epoch0 = self.membership.epoch
+        pool = self._landing() if mode != "device" else None
         if plan.single_shot and self.conf.elastic and self.conf.replication_factor >= 1:
-            with span("exchange.replicate", shuffle_id=shuffle_id):
-                self._replicate_sealed(shuffle_id)
+            with span("exchange.replicate", shuffle_id=shuffle_id) as replicate:
+                copied = self._replicate_sealed(shuffle_id, pool)
+                if replicate is not None:
+                    replicate.args.update(copied)
 
         def _mesh_changed() -> Optional[_MeshChanged]:
             if self.membership.epoch != epoch0:
@@ -642,7 +652,6 @@ class TpuShuffleCluster:
         data_sharding = NamedSharding(self.mesh, P(ax, None))
         devices = list(self.mesh.devices.reshape(-1))
         keep_device = self.conf.keep_device_recv
-        pool = self._landing() if mode != "device" else None
 
         def _submit(rnd, chunk, nchunks):
             """One sub-round's assemble + H2D + collective dispatch + async
@@ -947,33 +956,62 @@ class TpuShuffleCluster:
 
     # -- elastic membership / degraded-mode recovery -----------------------
 
-    def _replicate_sealed(self, shuffle_id: int) -> None:
+    def _replicate_sealed(self, shuffle_id: int, pool: Optional[LandingPool]) -> Dict[str, int]:
         """Copy every executor's sealed rounds to its ring successors
         (single-controller twin of PeerTransport._replicate_push): a direct
         store-to-store ``put_replica`` with the same entry table and landing
         zone as the wire path, so ``_recover_and_rerun`` restages from the
-        same placement either way."""
+        same placement either way.
+
+        A replicated byte is written once: for each round and successor the
+        source store gathers the round's blocks, outside its lock, into ONE
+        array (``replica_source``), which the successor's store installs as
+        it is — the replica's own bytes, never a view of the source's
+        staging.  That array is allocated from ``pool``, the blocks received
+        shards land in (``_landing``), where there is one: the body lengths
+        of a job's rounds come again with the next job, so from the second
+        job on a body is a block the one before gave back at
+        ``remove_shuffle``, pages this process already holds.  All of it is
+        done when this returns: nothing is still copying at the first
+        submit.  Returns the ``exchange.replicate`` span's arguments, which
+        are also what the ``elastic`` counters rise by."""
         n = self.num_executors
         factor = self.conf.replication_factor
         t0 = time.perf_counter_ns()
         copied_rounds = copied_bytes = 0
+        before = pool.stats() if pool is not None else None
+
+        def alloc(nbytes: int) -> np.ndarray:
+            if pool is None:
+                return np.empty(nbytes, dtype=np.uint8)
+            with pool.allocating():
+                return np.empty(nbytes, dtype=np.uint8)
+
         for t in self.transports:
             if not self.membership.is_alive(t.executor_id):
                 continue
-            rounds = t.store.replica_source(shuffle_id)
             for succ in ring_neighbors(t.executor_id, range(n), factor):
                 if not self.membership.is_alive(succ):
                     continue
-                for rnd, entries, body in rounds:
+                # a gather a successor: each holds bytes of its own
+                for rnd, entries, body in t.store.replica_source(shuffle_id, alloc):
                     self.transports[succ].store.put_replica(
                         shuffle_id, t.executor_id, rnd, entries, body
                     )
                     copied_rounds += 1
                     copied_bytes += len(body)
+        hits = misses = 0
+        if before is not None:
+            after = pool.stats()
+            hits, misses = after["hits"] - before["hits"], after["misses"] - before["misses"]
         with self._lock:
             self.elastic_stats["replicated_rounds"] += copied_rounds
             self.elastic_stats["replicated_bytes"] += copied_bytes
+            self.elastic_stats["replica_copied_bytes"] += copied_bytes
+            self.elastic_stats["replica_landing_hits"] += hits
+            self.elastic_stats["replica_landing_misses"] += misses
             self.elastic_stats["replicate_ns"] += time.perf_counter_ns() - t0
+        return {"copied_bytes": copied_bytes, "landing_hits": hits, "landing_misses": misses}
 
     def _recover_and_rerun(self, meta, sealed, mode: str) -> None:
         """Degraded-mode recovery: quarantine the aborted exchange's partial
@@ -1215,7 +1253,7 @@ class TpuShuffleCluster:
                                 f"{live_cands}) — shuffle {shuffle_id} is "
                                 "unrecoverable",
                             )
-                        flat[off : off + ln] = np.frombuffer(bytes(body), dtype=np.uint8)
+                        flat[off : off + ln] = body  # a view of the replica: one copy
                         sizes[off // meta.region_bytes] += -(-ln // self.row_bytes)
                         blocks += 1
                         nbytes += ln
