@@ -76,9 +76,13 @@ class TpuShuffleConf:
     #: shuffle's round buffers, zeroed, to a store-level free list that the
     #: next shuffle's rounds are taken from, so up to this much stays held
     #: after removal until ``close()``.  Past it rounds go to the disk tier
-    #: (``spill_to_disk``) and buffers are released.  A store bounds it by an
-    #: eighth of ``MemAvailable`` at its creation (``stats()``:
-    #: ``ram_budget_bytes``).  0 = no RAM tier and no free list: every
+    #: (``spill_to_disk``) and buffers are released — but for the free
+    #: list's floor: an idle store keeps ONE buffer of its own
+    #: ``staging_capacity_per_executor`` although that alone is over this
+    #: figure (a one-round 4 GiB staging), up to a quarter of ``MemAvailable``.
+    #: A store bounds the figure by an eighth of ``MemAvailable`` at its
+    #: creation (``stats()``: ``ram_budget_bytes``).  0 = no RAM tier, no
+    #: free list and no floor: every
     #: rollover spills and every buffer is released at removal.  The receive
     #: side takes the same figure for itself, an executor's each: the blocks
     #: a removed shuffle's received shards landed in stay held for the next

@@ -134,6 +134,23 @@ def ram_round_budget(conf: TpuShuffleConf) -> int:
     return budget if available is None else min(budget, available // 8)
 
 
+def staging_floor_limit() -> int:
+    """The largest staging round an idle store keeps although it alone is over
+    ``ram_round_budget`` (the floor of its free list, ``_recycle_rounds``): a
+    quarter of the host's ``MemAvailable`` at store creation.
+
+    A quarter and not the budget's eighth: the floor is ONE buffer, it takes
+    the place of the budget's buffers and never stacks on them, and it is
+    memory the operator granted to every live shuffle of this executor
+    (``staging_capacity_per_executor``) and the executor held a moment ago —
+    the peak does not move, only what an idle store gives back.  An eighth
+    needs 34.4 GB available for a 4 GiB round, which a one-chip v5e host
+    (39.7 GB at store creation) clears by a seventh; a quarter needs 17.2 GB
+    there, and on a four-chip host four idle stores hold 17 of 125 GB."""
+    available = _mem_available_bytes()
+    return sys.maxsize if available is None else available // 4
+
+
 def _device_nbytes(array) -> int:
     """Bytes of HBM behind ``array``: 0 for a host array, and for a device
     array already donated to an exchange."""
@@ -962,8 +979,10 @@ class HbmBlockStore:
         #: The free list of round buffers: ``pool_hits`` / ``pool_misses``
         #: (round buffers taken from it / allocated), ``pool_dropped_busy``
         #: (buffers of a removed or demoted round not taken back because
-        #: something still referred to them) and the gauge ``pool_held_bytes``
-        #: (what the free list holds now).
+        #: something still referred to them), ``pool_kept_over_budget``
+        #: (buffers the free list's floor — not the budget — kept: a removed
+        #: shuffle's one staging round larger than the budget) and the gauge
+        #: ``pool_held_bytes`` (what the free list holds now).
         #: The device write path (``_stage_device``), once a dispatch:
         #: ``scatter_dispatches``, the blocks and true bytes they placed
         #: (``device_staged_blocks`` / ``device_staged_bytes``; ``staged_*``
@@ -984,7 +1003,7 @@ class HbmBlockStore:
             ("staged_blocks", "staged_bytes", "rollovers", "spilled_bytes",
              "rollover_ns", "spill_ns", "copy_ns", "released_device_bytes",
              "recycled_rounds", "zeroed_bytes", "ram_rounds", "pool_hits",
-             "pool_misses", "pool_dropped_busy", "pool_held_bytes",
+             "pool_misses", "pool_dropped_busy", "pool_kept_over_budget", "pool_held_bytes",
              "device_staged_blocks", "device_staged_bytes", "scatter_dispatches",
              "device_stage_ns", "lock_wait_ns", "inplace_blocks", "inplace_bytes",
              "inplace_fallbacks", "inflight_wait_ns", "rollover_tail_bytes",
@@ -994,6 +1013,9 @@ class HbmBlockStore:
         #: RAM rounds live shuffles hold plus the free list never exceed
         #: ``_ram_budget`` while the disk tier is on; 0 = every rollover spills
         self._ram_budget = ram_round_budget(self.conf)
+        #: what one staging round kept over the budget may be (``_floor_keeps``);
+        #: no RAM tier, no floor
+        self._floor_limit = staging_floor_limit() if self._ram_budget else 0
         self._ram_round_bytes = 0  #: guarded by self._lock
         #: buffer size -> all-zero round buffers of removed shuffles and
         #: demoted rounds, that nothing else refers to (``_recycle_rounds``)
@@ -1126,7 +1148,7 @@ class HbmBlockStore:
                     for payload in (st.device_staging, *(st.sealed_payload or ()))
                 )
                 st.sealed_payload = st.device_staging = None
-                self._recycle_rounds(rounds)
+                self._recycle_rounds(rounds, floor=True)
                 self._release_spill(st)
                 self._release_tenant(st, st.tenant_charged)
             for key in [k for k in self._replicas if k[0] == shuffle_id]:
@@ -1474,7 +1496,25 @@ class HbmBlockStore:
             rounds.append((live, st.region_used))
         return rounds
 
-    def _recycle_rounds(self, rounds: List[Tuple[np.ndarray, np.ndarray]]) -> None:
+    def _floor_keeps(self, nbytes: int, regions: int) -> bool:
+        """Whether the free list's floor keeps a removed shuffle's buffer that
+        the budget has no room for (caller holds self._lock): it is exactly
+        one staging round of this store's conf over ``regions`` peers — memory
+        the operator granted to every live shuffle of the executor — no larger
+        than ``_floor_limit``, and it would be the only thing the RAM tier
+        holds: no other free buffer, no RAM round of a live shuffle."""
+        align = self.conf.block_alignment
+        own = (self.conf.staging_capacity_per_executor // regions) // align * align * regions
+        return (
+            nbytes == own
+            and nbytes <= self._floor_limit
+            and not self._ram_round_bytes
+            and not self._write_stats["pool_held_bytes"]
+        )
+
+    def _recycle_rounds(
+        self, rounds: List[Tuple[np.ndarray, np.ndarray]], floor: bool = False
+    ) -> None:
         """Give round buffers that no state holds any more to the free list
         (caller holds self._lock, and NO other name for any of the buffers;
         ``rounds`` is emptied).  Decided from what the store observes of each:
@@ -1482,9 +1522,12 @@ class HbmBlockStore:
         * only a buffer that owns its memory is kept (a D2H snapshot of a
           device round is a view of the runtime's copy; shm staging never
           gets here);
-        * only while the free list and the RAM rounds fit ``_ram_budget`` — a
-          buffer larger than the budget (a one-round 4 GiB staging) is
-          released as ever;
+        * only while the free list and the RAM rounds fit ``_ram_budget`` —
+          but with ``floor`` (a removal) an empty free list keeps ONE buffer
+          of the store's own staging size although it is over the budget
+          (``_floor_keeps``, ``pool_kept_over_budget``): a one-round 4 GiB
+          job then writes into pages the process holds, job after job; any
+          other buffer over the budget is released as ever;
         * a buffer something else still refers to — a ``block_staging_view``,
           a sealed round in the exchange's hands, a ``jax.device_put`` that
           aliases the host array on the CPU backend — is NEVER taken: it is
@@ -1501,7 +1544,8 @@ class HbmBlockStore:
             nbytes = buf.nbytes
             if not buf.flags.owndata:
                 continue
-            if self._ram_round_bytes + stats["pool_held_bytes"] + nbytes > self._ram_budget:
+            over = self._ram_round_bytes + stats["pool_held_bytes"] + nbytes > self._ram_budget
+            if over and not (floor and self._floor_keeps(nbytes, len(used))):
                 continue
             # (2 = only ``buf`` + getrefcount's argument, as in core/block.py)
             if sys.getrefcount(buf) > 2 and not collected:
@@ -1521,6 +1565,8 @@ class HbmBlockStore:
                 buf[start : start + int(used[p])] = 0
             self._free_rounds.setdefault(nbytes, []).append(buf)
             stats["pool_held_bytes"] += nbytes
+            if over:
+                stats["pool_kept_over_budget"] += 1
 
     @contextmanager
     def _rollover_span(self, st: _ShuffleState, peer: int):

@@ -241,23 +241,29 @@ def test_device_read_family_counts_once_a_task():
 
 
 @pytest.mark.parametrize("executors", [1, 4])
-@pytest.mark.parametrize("budget", [(1 << 20) - 1, 1 << 31], ids=["buffer-over-the-budget", "default-budget"])
+@pytest.mark.parametrize("budget", [0, (1 << 20) - 1, 1 << 31],
+                         ids=["buffer-over-the-budget", "the-floors-buffer", "default-budget"])
 def test_a_removed_shuffle_holds_no_device_array_and_no_staging_without_a_collection(executors, budget):
     """What the deployment holds a shuffle — the sealed round and the received
     shards in HBM, the host staging buffer — is released at
-    ``unregister_shuffle``, with the collector off.  A staging buffer larger
-    than the store's RAM budget (the HBM-held job's 4 GiB round) is freed
-    there and then; one that fits is the store's alone from then on, all
-    zeros, on its free list."""
+    ``unregister_shuffle``, with the collector off.  A staging buffer over the
+    store's RAM budget is freed there and then where the store keeps nothing
+    (``max_host_pool_bytes=0``); one that fits — or is the store's own staging
+    size, over a budget it alone exceeds (the HBM-held job's 4 GiB round: the
+    free list's floor) — is the store's alone from then on, all zeros, on its
+    free list, and the next shuffle's staging."""
     gc.collect()
     gc.disable()
     try:
         conf = device_conf(1 << 20, executors, max_host_pool_bytes=budget)
         with TpuShuffleManager(conf, num_executors=executors) as mgr:
             before = {id(a) for a in jax.live_arrays()}
+            kept = []
             for sid in (0, 1):
-                write_job(mgr, sid, 5, 8, seed=sid)
+                want = write_job(mgr, sid, 5, 8, seed=sid)
                 staging = [weakref.ref(t.store._state(sid)._staging) for t in mgr.cluster.transports]
+                if sid and budget:
+                    assert [id(ref()) for ref in staging] == kept  # the job before's buffer, job after job
                 states = [weakref.ref(t.store._state(sid)) for t in mgr.cluster.transports]
                 held = [a for a in jax.live_arrays() if id(a) not in before]
                 assert held and all(ref() is not None for ref in staging)
@@ -265,20 +271,31 @@ def test_a_removed_shuffle_holds_no_device_array_and_no_staging_without_a_collec
                 released = sum(t.store.write_stats()["released_device_bytes"] for t in mgr.cluster.transports)
                 del held
                 for r in range(8):
-                    mgr.get_reader(sid, r, r + 1).read_device()  # readers, and their results, come and go
+                    got = mgr.get_reader(sid, r, r + 1).read_device()  # readers, and their results, come and go
+                    if sid:  # byte-exact out of a buffer the job before filled with other bytes
+                        assert blocks_of(got) == {k: v for k, v in want.items() if k[1] == r}
+                del got
                 mgr.unregister_shuffle(sid)
                 assert [a.shape for a in jax.live_arrays() if id(a) not in before] == []
                 for ref, t in zip(staging, mgr.cluster.transports):
                     free = t.store._free_rounds.get(1 << 20, [])
-                    if budget < 1 << 20:
+                    if not budget:
                         assert ref() is None and not free
                     else:
                         assert [id(buf) for buf in free] == [id(ref())] and not ref().any()
+                kept = [id(ref()) for ref in staging]
                 assert [ref() for ref in states] == [None] * executors
                 now = sum(t.store.write_stats()["released_device_bytes"] for t in mgr.cluster.transports)
                 assert now - released >= held_bytes > 0
             rows = family(mgr.cluster.metrics_text(), "store")
             assert sum(v for (metric, _), v in rows.items() if metric == "released_device_bytes_total") == now
+            for e, t in enumerate(mgr.cluster.transports):
+                stats = t.store.write_stats()
+                over = 2 if budget == (1 << 20) - 1 else 0  # one a removal, kept by the floor and not the budget
+                assert rows[("pool_kept_over_budget_total", str(e))] == stats["pool_kept_over_budget"] == over
+                assert rows[("pool_held_bytes_total", str(e))] == stats["pool_held_bytes"] == (1 << 20 if budget else 0)
+                assert (stats["pool_hits"], stats["pool_misses"], stats["pool_dropped_busy"]) == (
+                    (1, 1, 0) if budget else (0, 2, 0))
     finally:
         gc.enable()
 

@@ -102,6 +102,26 @@ class TestDeviceWriteBitIdentity:
                 assert d_len == h_len == len(oracle[(m, r)])
                 assert bytes(d_view) == bytes(h_view) == oracle[(m, r)]
 
+    @pytest.mark.parametrize("n", [1, 4])
+    def test_a_device_staged_job_allocates_and_keeps_no_host_buffer(self, n):
+        """Under a budget its staging size alone exceeds — where a host-staged
+        job's buffer is the free list's floor — a device-staged shuffle has no
+        host buffer to keep: nothing allocated, nothing on the free list."""
+        cap = 1 << 20
+        for device in (True, False):
+            cluster, *_ = _exchange(device, n, 8, 8, cap, "device", max_host_pool_bytes=cap - 1)
+            cluster.remove_shuffle(0)
+            for t in cluster.transports:
+                stats = t.store.write_stats()
+                host = {k: stats[k] for k in ("pool_hits", "pool_misses", "pool_dropped_busy",
+                                              "pool_kept_over_budget", "pool_held_bytes")}
+                if device:
+                    assert not any(host.values()) and t.store._free_rounds == {}
+                    assert stats["device_staged_bytes"] == stats["staged_bytes"] > 0
+                else:  # the same job from the host: its one buffer is kept
+                    assert host == {"pool_hits": 0, "pool_misses": 1, "pool_dropped_busy": 0,
+                                    "pool_kept_over_budget": 1, "pool_held_bytes": cap}
+
     @pytest.mark.parametrize("n", [1, 8])
     def test_host_staging_never_allocated(self, n):
         dev_c, dev_meta, *_ = _exchange(True, n, 8, 8, 1 << 20)

@@ -160,7 +160,10 @@ class TestDiskSpillTier:
         assert os.listdir(str(tmp_path)) == []  # files AND subdir reclaimed
         stats = s.write_stats()
         assert stats["ram_rounds"] == 0 and stats["rollovers"] == stats["recycled_rounds"] == 7
-        assert stats["pool_held_bytes"] == 0 and stats["pool_hits"] == 0  # nothing kept after removal
+        # no RAM tier: nothing kept after removal; a RAM tier under one round:
+        # the live round's buffer, the store's own staging size, by the floor
+        assert stats["pool_held_bytes"] == (4096 if budget else 0) and stats["pool_hits"] == 0
+        assert stats["pool_kept_over_budget"] == (1 if budget else 0)
         s.close()
 
     @NO_RAM_ROUNDS
@@ -552,6 +555,7 @@ class TestRamRoundTier:
         # the three RAM rounds fit the budget again as free buffers; the
         # fourth buffer (the live round's) would pass it
         assert s.write_stats()["pool_held_bytes"] == 3 * self.ROUND and s._ram_round_bytes == 0
+        assert s.write_stats()["pool_kept_over_budget"] == 0  # the floor never stacks on a list that holds a buffer
         s.close()
 
     def test_held_bytes_never_exceed_the_budget(self, tmp_path):
@@ -589,17 +593,38 @@ class TestRamRoundTier:
         assert stats["pool_dropped_busy"] == 0
         s.close()
 
-    def test_a_buffer_larger_than_the_budget_is_never_pooled(self, tmp_path):
-        s = self._store(tmp_path, max_host_pool_bytes=self.ROUND)
-        for sid in range(2):
-            s.create_shuffle(sid, 1, 1, capacity=2 * self.ROUND)  # one round, as the HBM-held job's 4 GiB
+    @pytest.mark.parametrize("case", ["not-the-staging-size", "a-second-one", "no-ram-tier"])
+    def test_a_buffer_larger_than_the_budget_is_released_unless_the_floor_keeps_it(self, tmp_path, case):
+        """Over the budget the free list keeps ONE buffer of the store's own
+        staging size (``TestStagingFloor``); every other buffer over the budget
+        — of another size, a second one, any with the RAM tier off — is
+        released there and then, the collector off."""
+        import gc
+        import weakref
+
+        budget = {"not-the-staging-size": self.ROUND, "a-second-one": self.ROUND - 1, "no-ram-tier": 0}[case]
+        capacity = 2 * self.ROUND if case == "not-the-staging-size" else None  # None: the store's own
+        s = self._store(tmp_path, max_host_pool_bytes=budget)
+        for sid in range(2):  # two one-round shuffles side by side, as the HBM-held job's 4 GiB
+            s.create_shuffle(sid, 1, 1, capacity=capacity)
             w = s.map_writer(sid, 0)
-            w.write_partition(0, b"k" * 2 * self.ROUND)
+            w.write_partition(0, b"k" * s._state(sid).region_size)
             w.commit()
-            s.remove_shuffle(sid)
-            stats = s.write_stats()
-            assert stats["pool_held_bytes"] == 0 and s._free_rounds == {}
-            assert (stats["pool_hits"], stats["pool_misses"], stats["pool_dropped_busy"]) == (0, sid + 1, 0)
+        gc.collect()
+        gc.disable()
+        try:
+            released = []
+            for sid in range(2):
+                ref = weakref.ref(s._state(sid).staging)
+                s.remove_shuffle(sid)
+                released.append(ref() is None)
+        finally:
+            gc.enable()
+        second = case == "a-second-one"  # the first of the store's own size is the floor's, the second is not
+        assert released == [not second, True]
+        stats = s.write_stats()
+        assert stats["pool_kept_over_budget"] == int(second) and stats["pool_held_bytes"] == second * self.ROUND
+        assert (stats["pool_hits"], stats["pool_misses"], stats["pool_dropped_busy"]) == (0, 2, 0)
         s.close()
 
     def test_close_empties_the_free_list(self, tmp_path):
@@ -721,6 +746,178 @@ class TestRamRoundTier:
                 s.seal(0)
         finally:
             del s._shuffles[0]
+        s.close()
+
+
+class TestStagingFloor:
+    """The free list's floor: an idle store keeps ONE buffer of its own staging
+    size although the buffer alone is over ``max_host_pool_bytes`` — the
+    one-round job of a 4 GiB ``staging_capacity_per_executor`` under the 2 GiB
+    default writes, job after job, into pages the process already holds."""
+
+    ROUND = 1 << 16
+    BUDGET = 1 << 15  # the RAM tier is on and a staging round alone is over it
+
+    def _store(self, tmp_path, regions=1, **conf):
+        conf.setdefault("max_host_pool_bytes", self.BUDGET)
+        return HbmBlockStore(
+            TpuShuffleConf(
+                staging_capacity_per_executor=regions * self.ROUND, block_alignment=ALIGN,
+                spill_dir=str(tmp_path), **conf,
+            )
+        )
+
+    @staticmethod
+    def _job(s, sid, blocks, regions=1):
+        """One map task of ``blocks`` = {reduce: payload}, a region a reducer."""
+        s.create_shuffle(sid, 1, regions, peer_ranges=default_peer_ranges(regions, regions))
+        w = s.map_writer(sid, 0)
+        for r in sorted(blocks):
+            w.write_partition(r, blocks[r])
+        w.commit()
+
+    def test_the_kept_buffer_is_the_next_shuffles_staging(self, tmp_path):
+        s = self._store(tmp_path)
+        assert s._ram_budget == self.BUDGET < self.ROUND
+        held = []
+        for sid in range(4):
+            self._job(s, sid, {0: bytes([sid + 1]) * 1000})
+            held.append(id(s._state(sid).staging))
+            assert s.read_block(sid, 0, 0) == bytes([sid + 1]) * 1000
+            s.remove_shuffle(sid)
+            stats = s.write_stats()
+            assert stats["pool_held_bytes"] == self.ROUND > self.BUDGET
+            assert [id(b) for b in s._free_rounds[self.ROUND]] == held[:1] and not s._free_rounds[self.ROUND][0].any()
+            assert (stats["pool_hits"], stats["pool_misses"], stats["pool_dropped_busy"]) == (sid, 1, 0)
+            assert stats["pool_kept_over_budget"] == sid + 1
+        assert len(set(held)) == 1  # one buffer, job after job
+        s.close()
+        assert s.write_stats()["pool_held_bytes"] == 0 and s._free_rounds == {}
+
+    @pytest.mark.parametrize("regions", [1, 4])
+    def test_a_job_written_into_a_buffer_another_job_filled_is_exact_and_zero_padded(self, tmp_path, regions):
+        """The padding the exchange sends — rows past a region's used count,
+        the tail of a block's last row — is zeros in the second job's round
+        although the first job left 0xFF in every byte of the buffer."""
+        s = self._store(tmp_path, regions=regions)
+        self._job(s, 0, {r: b"\xff" * self.ROUND for r in range(regions)}, regions)
+        first = s._state(0).staging
+        assert first.all()
+        del first
+        s.remove_shuffle(0)
+        rng = np.random.default_rng(47)
+        blocks = {r: rng.integers(1, 256, size=int(rng.integers(1, self.ROUND // 3)), dtype=np.uint8).tobytes()
+                  for r in range(regions) if r != 2}  # region 2 of 4 stays empty
+        self._job(s, 1, blocks, regions)
+        assert s.write_stats()["pool_hits"] == 1 and s.write_stats()["pool_kept_over_budget"] == 1
+        expected = np.zeros(regions * self.ROUND, dtype=np.uint8)
+        for r, payload in blocks.items():
+            assert s.read_block(1, 0, r) == payload
+            expected[r * self.ROUND : r * self.ROUND + len(payload)] = np.frombuffer(payload, dtype=np.uint8)
+        (payload, sizes), = s.seal(1)
+        assert np.array_equal(np.asarray(payload).reshape(-1).view(np.uint8), expected)
+        assert [int(n) for n in sizes] == [-(-len(blocks.get(r, b"")) // ALIGN) for r in range(regions)]
+        del payload
+        s.close()
+
+    def test_a_busy_buffer_is_dropped_and_counted_never_reused(self, tmp_path):
+        s = self._store(tmp_path)
+        self._job(s, 0, {0: b"a" * 3000})
+        (view, _sizes), = s.seal(0)  # the sealed round, somebody's across the removal (an exchange's)
+        assert np.shares_memory(view, s._state(0).staging)
+        s.remove_shuffle(0)
+        stats = s.write_stats()
+        assert (stats["pool_dropped_busy"], stats["pool_kept_over_budget"], stats["pool_held_bytes"]) == (1, 0, 0)
+        self._job(s, 1, {0: b"b" * 3000})
+        assert not np.shares_memory(view, s._state(1).staging)
+        assert view.reshape(-1).view(np.uint8)[:3000].tobytes() == b"a" * 3000 and s.read_block(1, 0, 0) == b"b" * 3000
+        assert (s.write_stats()["pool_hits"], s.write_stats()["pool_misses"]) == (0, 2)
+        del view
+        s.remove_shuffle(1)  # nobody's: the floor's
+        assert s.write_stats()["pool_kept_over_budget"] == 1 and s.write_stats()["pool_held_bytes"] == self.ROUND
+        s.close()
+
+    @pytest.mark.parametrize("available, kept", [
+        (4 * (1 << 16), True), (4 * (1 << 16) - 1, False), (None, True),
+    ], ids=["a-quarter", "over-a-quarter", "no-meminfo"])
+    def test_the_floor_is_bounded_by_a_share_of_the_hosts_memory(self, tmp_path, monkeypatch, available, kept):
+        from sparkucx_tpu.store import hbm_store
+
+        monkeypatch.setattr(hbm_store, "_mem_available_bytes", lambda: available)
+        s = self._store(tmp_path, max_host_pool_bytes=1 << 13)  # under an eighth of either reading
+        assert s._ram_budget == 1 << 13
+        self._job(s, 0, {0: b"x" * 100})
+        s.remove_shuffle(0)
+        stats = s.write_stats()
+        assert stats["pool_kept_over_budget"] == int(kept) and stats["pool_held_bytes"] == (self.ROUND if kept else 0)
+        s.close()
+        assert self._store(tmp_path, max_host_pool_bytes=0)._floor_limit == 0  # no RAM tier, no floor
+
+    def test_a_round_that_rolls_still_spills_and_its_live_buffer_is_the_floors(self, tmp_path):
+        """``_admit_ram_round``'s verdict does not move: a round of the
+        staging size is over the budget and goes to the disk tier, its buffer
+        reused from round to round; the removal keeps that one buffer."""
+        s = self._store(tmp_path)
+        s.create_shuffle(0, 3, 1)
+        for m in range(3):
+            w = s.map_writer(0, m)
+            w.write_partition(0, bytes([m + 1]) * self.ROUND)
+            w.commit()
+        assert [s.round_tier(0, k) for k in range(3)] == ["disk", "disk", "host"]
+        assert all(s.read_block(0, m, 0) == bytes([m + 1]) * self.ROUND for m in range(3))
+        s.remove_shuffle(0)
+        stats = s.write_stats()
+        assert (stats["ram_rounds"], stats["recycled_rounds"], stats["pool_kept_over_budget"]) == (0, 2, 1)
+        s.create_shuffle(1, 3, 1)  # ... and with the kept buffer as its staging it spills the same
+        for m in range(3):
+            w = s.map_writer(1, m)
+            w.write_partition(0, bytes([m + 9]) * self.ROUND)
+            w.commit()
+        assert [s.round_tier(1, k) for k in range(3)] == ["disk", "disk", "host"]
+        assert all(s.read_block(1, m, 0) == bytes([m + 9]) * self.ROUND for m in range(3))
+        assert s.write_stats()["pool_hits"] == 1 and s.write_stats()["ram_rounds"] == 0
+        s.close()
+
+    def test_the_kept_buffer_goes_before_a_live_round_goes_to_disk(self, tmp_path):
+        """Where the free list is let go today the kept buffer goes too: a
+        smaller shuffle's round that fits the budget stays in RAM, and the
+        free list — the floor's buffer — is cleared to make its room."""
+        small = self.BUDGET // 2
+        s = self._store(tmp_path)
+        self._job(s, 0, {0: b"x" * 100})
+        s.remove_shuffle(0)
+        assert s.write_stats()["pool_held_bytes"] == self.ROUND
+        s.create_shuffle(1, 2, 1, capacity=small)
+        for m in range(2):
+            w = s.map_writer(1, m)
+            w.write_partition(0, bytes([m + 1]) * small)
+            w.commit()
+        assert [s.round_tier(1, k) for k in range(2)] == ["host", "host"]
+        assert s.write_stats()["pool_held_bytes"] == 0 and s._free_rounds == {}
+        assert s._ram_round_bytes + s.write_stats()["pool_held_bytes"] <= self.BUDGET
+        s.close()
+
+    def test_a_demoted_round_is_not_the_floors(self, tmp_path):
+        """A demotion sheds memory: the buffer of a sealed one-round shuffle
+        sent to the disk tier is over the budget and is released."""
+        import gc
+        import weakref
+
+        s = self._store(tmp_path)
+        self._job(s, 0, {0: b"d" * 5000})
+        ref = weakref.ref(s._state(0).staging)
+        s.seal(0)
+        gc.collect()
+        gc.disable()
+        try:
+            assert s.demote_round(0, 0) == "host->disk"
+            assert s.read_block(0, 0, 0) == b"d" * 5000
+            stats = s.write_stats()
+            assert (stats["pool_held_bytes"], stats["pool_kept_over_budget"]) == (0, 0)
+            if not stats["pool_dropped_busy"]:  # (the CPU backend's put may alias the host array)
+                assert ref() is None
+        finally:
+            gc.enable()
         s.close()
 
 
