@@ -1,0 +1,13 @@
+"""Reduce-side read, ordered: device time of the executables
+``jit_ordered_records`` in the traced job, ms (mean over the cell's chips) —
+what the chip spends ordering a job's reduce tasks.  Left out where the trace
+has no such executable (a program that orders nothing on the device)."""
+
+MODULE = "jit_ordered_records("
+
+
+def read(run):
+    if run.reduction is None:
+        return None
+    device_s = sum(s for name, s in run.reduction.module_s.items() if name.startswith(MODULE))
+    return device_s * 1e3 if device_s > 0 else None

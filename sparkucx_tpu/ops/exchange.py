@@ -66,33 +66,16 @@ def exclusive_cumsum(x, axis: int = -1, xp=jnp):
     return xp.cumsum(x, axis=axis) - x
 
 
-#: Lane-width band where XLA:TPU lowers a row gather markedly slower than
-#: adjacent widths (8/16/24 lanes and >=100 take the fast path, 25..32 do
-#: not).  Gathers whose width lands in the band are chunked into <=24-lane
-#: column slices, each of which lowers on the fast path; chunking a fast
-#: width makes it worse, hence the band guard rather than chunking
-#: everything.  (An observation from before the current measurement record;
-#: to be re-checked when the sort cell exists — ROADMAP queue 1 item 6.)
-SLOW_GATHER_LANES = (25, 32)
-_GATHER_CHUNK = 24
-
-
 def gather_rows(rows: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """``rows[idx]`` (1-D row index) with the TPU slow-band lane chunking.
-
-    The cliff is an XLA:TPU artifact, so non-TPU backends always take the
-    plain gather.  ``jax.default_backend()`` is a trace-time proxy for the
-    mesh platform — exact for every in-tree caller (meshes are built over the
-    default backend's devices)."""
-    w = rows.shape[1]
-    if (
-        SLOW_GATHER_LANES[0] <= w <= SLOW_GATHER_LANES[1]
-        and jax.default_backend() == "tpu"
-    ):
-        return jnp.concatenate(
-            [rows[:, i : i + _GATHER_CHUNK][idx] for i in range(0, w, _GATHER_CHUNK)],
-            axis=1,
-        )
+    """``rows[idx]`` (1-D row index): the row gather of every sort and
+    partition here.  It is the plain gather at every width: chunking widths
+    of 25..32 lanes into <= 24-lane column slices, as this function did from
+    an observation that predates the measurement record, is slower or level
+    wherever it was re-measured on a v5e (342,784 rows: 25 lanes 4.69 ms
+    plain against 5.94 chunked, 28 lanes 4.63 / 5.10, 32 lanes 4.67 / 4.74;
+    PERF.md section 6, PR 48) — XLA's TPU gather costs ~10 ns an index
+    whatever the row's width, so a second gather over the same indices never
+    pays."""
     return rows[idx]
 
 

@@ -16,10 +16,20 @@ concatenation of shards in mesh order is the fully sorted dataset.  This is the
 TPU-native answer to the job the reference's GroupByTest/TeraSort harness runs
 over Spark + UCX (buildlib/test.sh:163-179, BASELINE.json configs[1]).
 
-Rows are (key, payload-lane...) with 32-bit lanes; a 100-byte TeraSort row is
-one uint32 key lane + 24 payload lanes.  Keys travel with their payload through
-one exchange (bitcast into the payload dtype) so the permutation is applied
-exactly once.
+Rows are 32-bit lanes.  Every sort here is ``sort_rows``: a row's first
+``key_lanes`` lanes are its key, compared lexicographically, most significant
+lane first — either as the ``uint32`` values they hold (the distributed sort:
+one ``uint32`` key lane in front of ``width`` payload lanes) or, with
+``key_bytes``, as the row's first ``key_bytes`` BYTES in memory order,
+unsigned, most significant first (a TeraSort record: 100 bytes are 25 lanes,
+its 10-byte key 2.5 of them; the reduce side's ordered return,
+``transport/tpu.py`` ``ordered_records``).  Keys travel with their payload
+through one exchange (bitcast into the payload dtype) so the permutation is
+applied exactly once.
+
+The distributed sort's *splitters* stay one-lane ``uint32`` scalars: no cell
+range-partitions on the chip (TeraSort's map side does, with
+``TeraSortPartitioner``), so a lexicographic splitter has no caller yet.
 
 Skew: splitters come from `samples_per_shard` evenly spaced local samples, so a
 range can exceed `recv_capacity` only under adversarial key skew; the returned
@@ -62,7 +72,9 @@ class SortSpec:
     ``recv_capacity``: per-executor output rows — headroom over the balanced
     ``total/n`` guards against sampling error (1.5-2x is ample for uniform
     keys, e.g. TeraSort's).
-    ``width``: payload lanes of ``dtype`` per row (>= 0); keys are uint32.
+    ``width``: payload lanes of ``dtype`` per row (>= 0).  The key is ONE
+    ``uint32`` lane compared by value (the splitters are scalars of it);
+    ``sort_rows`` itself orders any number of key lanes.
     """
 
     num_executors: int
@@ -99,6 +111,81 @@ class SortSpec:
             raise ValueError("samples_per_shard must be >= num_executors")
 
 
+def _byteswap32(lanes: jnp.ndarray) -> jnp.ndarray:
+    """``uint32`` lanes with their four bytes reversed: a row's bytes lie
+    little-endian in a lane, a byte-string key compares its first byte first."""
+    return (
+        (lanes << 24)
+        | ((lanes & jnp.uint32(0xFF00)) << 8)
+        | ((lanes >> 8) & jnp.uint32(0xFF00))
+        | (lanes >> 24)
+    )
+
+
+def key_lanes_of(key_bytes: int) -> int:
+    """32-bit lanes a key of ``key_bytes`` bytes reaches into."""
+    return -(-int(key_bytes) // 4)
+
+
+def sort_rows(
+    rows: jnp.ndarray,
+    key_lanes: int,
+    valid: jnp.ndarray,
+    key_bytes: Optional[int] = None,
+) -> jnp.ndarray:
+    """The one local sort: ``rows`` ``(N, W)`` of a 32-bit dtype, ordered by
+    their first ``key_lanes`` lanes compared lexicographically (lane 0 most
+    significant), **stable**, rows that are not valid last and zeroed.
+
+    ``valid`` is a count (a scalar: the first ``valid`` rows are the data) or
+    an ``(N,)`` bool mask.  ``key_bytes`` ``None``: a lane compares as the
+    ``uint32`` it holds.  ``key_bytes = k``: the key is the row's first ``k``
+    bytes in memory order compared as unsigned bytes, most significant first
+    — each lane byte-swapped, the last one masked to the key's bytes in it;
+    ``key_lanes`` must be ``key_lanes_of(k)``.
+
+    How: ONE ``lax.sort`` over (the key lanes..., the row index) with every
+    operand a key — the index last, so the order is total: stable by
+    construction and the same on every run without ``is_stable`` — then one
+    row gather by the sorted index, so the payload lanes move once, whatever
+    the key's width.  Rows that are not valid sort last by the all-ones key
+    where no row can have it (a byte-string key whose last lane is masked,
+    ``key_bytes`` no multiple of 4), else by a leading flag lane.  Measured on
+    the chip at TeraSort's shape (342,784 rows of 25 lanes; PERF.md section 6,
+    PR 48): the sort 1.7 ms, the row gather 4.7 ms.  An index costs XLA's TPU
+    gather ~10 ns whatever it fetches (a 1-D gather of a key lane 3.6 ms), so
+    sorting a lane at a time through the order so far is six times slower
+    (9.8 ms); and the sort's compile time grows with its operands and doubles
+    with ``is_stable`` (161 s for five stable operands, 54 s for these four),
+    so nothing rides along that need not.  Chosen by the code; there is no
+    host fallback."""
+    n, width = rows.shape
+    if not 1 <= key_lanes <= width:
+        raise ValueError(f"key_lanes {key_lanes} of rows {width} lanes wide")
+    if key_bytes is not None and key_lanes != key_lanes_of(key_bytes):
+        raise ValueError(f"a {key_bytes}-byte key is {key_lanes_of(key_bytes)} lanes, not {key_lanes}")
+    idx = jnp.arange(n, dtype=jnp.int32)
+    valid = jnp.asarray(valid)
+    if valid.ndim == 0:
+        valid = idx < valid
+    lanes = [jax.lax.bitcast_convert_type(rows[:, i], jnp.uint32) for i in range(key_lanes)]
+    tail = 4
+    if key_bytes is not None:
+        lanes = [_byteswap32(lane) for lane in lanes]
+        tail = key_bytes - 4 * (key_lanes - 1)  # key bytes in the last lane: 1..4
+        if tail < 4:
+            lanes[-1] = lanes[-1] & jnp.uint32((0xFFFFFFFF << (8 * (4 - tail))) & 0xFFFFFFFF)
+    if tail < 4:  # the all-ones key is no row's: padding rows take it
+        lanes = [jnp.where(valid, lane, KEY_MAX) for lane in lanes]
+    else:
+        lanes.insert(0, jnp.logical_not(valid).astype(jnp.uint32))
+    order = jax.lax.sort((*lanes, idx), num_keys=len(lanes) + 1, is_stable=False)[-1]
+    # valid rows sort to the front, so the first ``count`` of the output are
+    # the data; a padding row's lanes must not leak through the permutation
+    count = valid.sum(dtype=jnp.int32)
+    return jnp.where((idx < count)[:, None], gather_rows(rows, order), jnp.zeros((), rows.dtype))
+
+
 def _global_splitters(spec: SortSpec, sorted_keys: jnp.ndarray, num_valid: jnp.ndarray):
     """Sample each shard's sorted prefix, gather, and pick n-1 range boundaries.
 
@@ -132,25 +219,32 @@ def _global_splitters(spec: SortSpec, sorted_keys: jnp.ndarray, num_valid: jnp.n
     return allsamp[jnp.clip(cut, 0, n * s - 1)]  # (n-1,) splitters
 
 
+def _keyed_rows(spec: SortSpec, keys: jnp.ndarray, payload: jnp.ndarray) -> jnp.ndarray:
+    """(key | payload) rows: the key lane bitcast into the payload's dtype."""
+    return jnp.concatenate([jax.lax.bitcast_convert_type(keys, spec.dtype)[:, None], payload], axis=1)
+
+
+def _sorted_keys(rows: jnp.ndarray, count: jnp.ndarray) -> jnp.ndarray:
+    """The key lane of sorted rows, ``KEY_MAX`` on the padding rows."""
+    keys = jax.lax.bitcast_convert_type(rows[:, 0], jnp.uint32)
+    return jnp.where(jnp.arange(rows.shape[0], dtype=jnp.int32) < count, keys, KEY_MAX)
+
+
 def _sort_body(spec: SortSpec, keys: jnp.ndarray, payload: jnp.ndarray, num_valid: jnp.ndarray):
     n = spec.num_executors
-    nv = num_valid[0]
+    nv = num_valid[0].astype(jnp.int32)
 
-    # 1. Local sort (padding KEY_MAX rows sort last; re-force in case the
-    #    caller's padding was not sentinel-keyed).
+    # 1. Local sort, key and payload as one row (padding rows last).
     idx = jnp.arange(spec.capacity, dtype=jnp.int32)
-    keys = jnp.where(idx < nv, keys, KEY_MAX)
-    order = jnp.argsort(keys, stable=True)  # stability is the documented contract
-    skeys = keys[order]
-    spay = gather_rows(payload, order)
+    rows = sort_rows(_keyed_rows(spec, keys, payload), 1, nv)
+    skeys = _sorted_keys(rows, nv)
 
     # 2. Splitters -> per-row destination executor (padding rows -> n, never sent).
     splitters = _global_splitters(spec, skeys, nv)
     owners = jnp.searchsorted(splitters, skeys, side="right").astype(jnp.int32)
     owners = jnp.where(idx < nv, owners, n)
 
-    # 3. One exchange moves key+payload together: key lane bitcast to dtype.
-    rows = jnp.concatenate([jax.lax.bitcast_convert_type(skeys, spec.dtype)[:, None], spay], axis=1)
+    # 3. One exchange moves key+payload together.
     # keys already sorted => owners are non-decreasing: rows are dest-contiguous.
     sizes, send_sizes, recv_sizes, output_offsets = size_matrix_from_owners(
         spec.axis_name, n, owners
@@ -169,13 +263,8 @@ def _sort_body(spec: SortSpec, keys: jnp.ndarray, payload: jnp.ndarray, num_vali
 
     # 4. Final local sort of the received range.
     total = recv_sizes.sum().astype(jnp.int32)
-    rkeys = jax.lax.bitcast_convert_type(recv[:, 0], jnp.uint32)
-    ridx = jnp.arange(spec.recv_capacity, dtype=jnp.int32)
-    rkeys = jnp.where(ridx < total, rkeys, KEY_MAX)
-    rorder = jnp.argsort(rkeys, stable=True)
-    out_keys = rkeys[rorder]
-    out_pay = gather_rows(recv[:, 1:], rorder)
-    return out_keys, out_pay, total[None]
+    out = sort_rows(recv, 1, total)
+    return _sorted_keys(out, total), out[:, 1:], total[None]
 
 
 def _sort_body_single(spec: SortSpec, keys: jnp.ndarray, payload: jnp.ndarray, num_valid: jnp.ndarray):
@@ -184,20 +273,16 @@ def _sort_body_single(spec: SortSpec, keys: jnp.ndarray, payload: jnp.ndarray, n
     The distributed body would sort locally, self-exchange ~100 B/row, and
     sort the (recv_capacity-padded) receive buffer again — twice the sort and
     a pointless copy."""
-    nv = num_valid[0]
-    idx = jnp.arange(spec.capacity, dtype=jnp.int32)
-    keys = jnp.where(idx < nv, keys, KEY_MAX)
-    order = jnp.argsort(keys, stable=True)
-    out_keys = keys[order]
-    # valid rows sort to the front (stable argsort, padding keys KEY_MAX), so
-    # zeroing the tail matches the collective lowerings' output contract —
-    # the caller's padding payload must not leak through the permutation
-    out_pay = jnp.where((idx < nv)[:, None], gather_rows(payload, order), 0)
+    nv = num_valid[0].astype(jnp.int32)
+    # padding rows come out last and zeroed, the collective lowerings' output
+    # contract: the caller's padding payload does not leak through
+    out = sort_rows(_keyed_rows(spec, keys, payload), 1, nv)
+    out_keys, out_pay = _sorted_keys(out, nv), out[:, 1:]
     pad = spec.recv_capacity - spec.capacity
     if pad:
         out_keys = jnp.concatenate([out_keys, jnp.full(pad, KEY_MAX, jnp.uint32)])
         out_pay = jnp.concatenate([out_pay, jnp.zeros((pad, spec.width), spec.dtype)])
-    return out_keys, out_pay, nv[None].astype(jnp.int32)
+    return out_keys, out_pay, nv[None]
 
 
 def build_distributed_sort(mesh: Mesh, spec: SortSpec):

@@ -211,6 +211,23 @@ class DeviceRead(NamedTuple):
     block_ids: List[ShuffleBlockId]
 
 
+class OrderedDeviceRead(NamedTuple):
+    """What ``TpuShuffleReader.read_device`` returns under ``key_ordering``:
+    one reduce task's fixed-width records on its executor's device, sorted
+    there."""
+
+    #: (capacity, record_bytes / 4) int32 ``jax.Array`` on the owning
+    #: executor's device: its first ``num_records`` rows are the task's
+    #: records in non-decreasing order of their first ``key_bytes`` bytes
+    #: (unsigned, most significant first; equal keys in any order), the rows
+    #: after them zero.  ``capacity`` is one figure a shuffle, not the task's.
+    records: Any
+    #: the task's record count, from the block table
+    num_records: int
+    #: the blocks the records came from, in (reduce, map) order
+    block_ids: List[ShuffleBlockId]
+
+
 def default_deserializer(payload: bytes) -> Iterable[Any]:
     """Record stream per block (the Spark serializer-stream analogue).
 
@@ -391,6 +408,14 @@ class TpuShuffleReader:
         self.deserializer = deserializer
         self.aggregator = aggregator
         self.key_ordering = key_ordering
+        if key_ordering and isinstance(deserializer, FixedWidthSerializer):
+            # the ordered return over fixed-width records is the device's
+            # (read_device / read_batches): records are rows of 32-bit lanes
+            if deserializer.record_bytes % 4 or not deserializer.key_bytes:
+                raise ValueError(
+                    f"key_ordering over fixed-width records needs record_bytes a multiple of 4 and a "
+                    f"key, not {deserializer.record_bytes} B records with {deserializer.key_bytes} B keys"
+                )
         if sender_of is None:
             # binds the id, not the reader: a lambda over ``self`` would put
             # every reader in a reference cycle, and with it the shuffle
@@ -1121,7 +1146,7 @@ class TpuShuffleReader:
 
     # -- record pipeline ---------------------------------------------------
 
-    def read_device(self) -> DeviceRead:
+    def read_device(self):
         """This task's blocks read ON THE DEVICE: every non-empty block of its
         partition range, in (reduce, map) order, gathered into one packed
         ``jax.Array`` on the owning executor's device — the bytes never visit
@@ -1131,7 +1156,14 @@ class TpuShuffleReader:
         owns: the transport raises its typed ``TransportError`` otherwise.
         No retry, failover or hedge applies — the blocks are local after the
         exchange — so the fault counters of ``metrics`` stay 0; blocks and
-        bytes are counted.  Span ``read.device``, once a task."""
+        bytes are counted.  Span ``read.device``, once a task.
+
+        Returns a ``DeviceRead``.  With ``key_ordering`` the reader's
+        ``deserializer`` must be a ``FixedWidthSerializer`` and the return is
+        an ``OrderedDeviceRead``: the task's records sorted on the device by
+        their key (``_read_ordered``; span ``read.ordered`` instead)."""
+        if self.key_ordering:
+            return self._read_ordered(to_host=False)
         fetch = getattr(self.transport, "fetch_blocks_device", None)
         if fetch is None:
             raise TransportError(
@@ -1150,6 +1182,44 @@ class TpuShuffleReader:
         self.metrics.remote_bytes_read += int(table[:, 1].sum())
         return DeviceRead(packed, table, bids)
 
+    def _read_ordered(self, to_host: bool):
+        """The ordered return over fixed-width records: the task's blocks
+        located and gathered on its executor's device as ``read_device``
+        does, and sorted THERE by the serializer's key — the one ordered path
+        for such records; nothing is sorted on the host.  An
+        ``OrderedDeviceRead``, or with ``to_host`` the read-only ``(n,
+        record_bytes)`` batch of its first ``n`` rows after one D2H.
+
+        Span ``read.ordered``, once a task (``args``: ``blocks``, ``records``,
+        ``bytes``, ``capacity``), over ``read.device.locate``,
+        ``fetch.device_gather``, ``read.ordered.sort`` and — ``to_host`` —
+        ``read.ordered.d2h``."""
+        serializer = self.deserializer
+        if not isinstance(serializer, FixedWidthSerializer):
+            raise TypeError(
+                "key_ordering on the device needs a FixedWidthSerializer as the reader's "
+                f"deserializer, not {type(serializer).__name__}; read() orders any records on the host"
+            )
+        fetch = getattr(self.transport, "fetch_blocks_ordered", None)
+        if fetch is None:
+            raise TransportError(f"{type(self.transport).__name__} has no device-resident fetch")
+        bids, width = self._block_ids(), serializer.record_bytes
+        with TRACER.executor_scope(self.executor_id), span(
+            "read.ordered", shuffle_id=self.shuffle_id,
+            reduce_id=self.start_partition, blocks=len(bids),
+        ) as ctx:
+            records, n = fetch(bids, self.shuffle_id, width, serializer.key_bytes, flat=to_host)
+            if ctx is not None:
+                ctx.args.update(records=n, bytes=n * width, capacity=int(records.size) * 4 // width)
+            batch = self.transport.ordered_to_host(records, n, width) if to_host else None
+        self.metrics.remote_blocks_fetched += len(bids)
+        self.metrics.remote_bytes_read += n * width
+        self.metrics.records_read += n
+        if not to_host:
+            return OrderedDeviceRead(records, n, bids)
+        self.metrics.record_batches += 1
+        return batch
+
     def read_batches(self) -> Iterator[np.ndarray]:
         """This task's records a block at a time: one read-only ``(n,
         record_bytes)`` ``uint8`` batch a non-empty block, in (reduce, map)
@@ -1165,25 +1235,36 @@ class TpuShuffleReader:
         bytes, taken before the buffer goes back.  ``metrics.records_read``
         counts the records (the sum of the batches' lengths).
 
-        ``aggregator`` / ``key_ordering`` over batches are not supported yet
-        (the ordered return is the next step) and raise here, rather than
-        fall into ``ExternalCombiner`` a record at a time.
+        With ``key_ordering``: ONE batch a task — all its records in
+        non-decreasing order of their first ``key_bytes`` bytes (unsigned,
+        most significant first; equal keys in any order), sorted on the
+        executor's device over the shards kept in HBM and brought to the host
+        in one D2H (``_read_ordered``; ``conf.keep_device_recv``, else the
+        transport's typed "device shards not retained").  The batch owns its
+        bytes and outlives the shuffle.
+
+        ``aggregator`` over batches is not supported and raises here, rather
+        than fall into ``ExternalCombiner`` a record at a time.
 
         Span ``read.batches``, once a task: a summed span of the reader's own
         turns — issuing and awaiting the windows, the look-ups, the hand-out
         of each batch — WITHOUT the caller's turns between batches
-        (``args``: ``blocks``, ``records``, ``bytes``, ``turns``)."""
+        (``args``: ``blocks``, ``records``, ``bytes``, ``turns``); under
+        ``key_ordering`` ``read.ordered`` instead."""
         serializer = self.deserializer
         if not isinstance(serializer, FixedWidthSerializer):
             raise TypeError(
                 "read_batches() needs a FixedWidthSerializer as the reader's deserializer, "
                 f"not {type(serializer).__name__}"
             )
-        if self.aggregator is not None or self.key_ordering:
+        if self.aggregator is not None:
             raise NotImplementedError(
-                "aggregator / key_ordering over record batches are not supported yet; "
-                "read() combines and sorts a record at a time"
+                "an aggregator over record batches is not supported; "
+                "read() combines a record at a time"
             )
+        if self.key_ordering:
+            batch = self._read_ordered(to_host=True)
+            return iter((batch,) if len(batch) else ())
         return self._batches(serializer)
 
     def _batches(self, serializer: "FixedWidthSerializer") -> Iterator[np.ndarray]:

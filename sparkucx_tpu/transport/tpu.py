@@ -33,12 +33,14 @@ over ``jax.distributed`` + the control plane in parallel/bootstrap.py.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -61,6 +63,7 @@ from sparkucx_tpu.parallel.membership import ClusterMembership
 from sparkucx_tpu.parallel.mesh import executor_mesh, surviving_submesh
 from sparkucx_tpu.ops.exchange import bucket_send_rows, rebucket_slots
 from sparkucx_tpu.ops.planner import PlanContext, PlanSignals, make_planner
+from sparkucx_tpu.ops.sort import key_lanes_of, sort_rows
 from sparkucx_tpu.ops.skew import (
     chunk_size_rows,
     pad_rows_pow2,
@@ -119,6 +122,10 @@ class _ShuffleMeta:
     # the source the device-side block gather serves from:
     recv_device: Optional[List[List[object]]] = None      # [round][executor] jax.Array
     exchanged: bool = False
+    #: record_bytes -> records an ordered read of ONE reduce partition is
+    #: sorted at (``TpuShuffleCluster._ordered_geometry``), worked out at the
+    #: shuffle's first ordered read from the sealed size matrix
+    ordered_capacity: Dict[int, int] = field(default_factory=dict)  #: guarded by self._lock
 
     def owner_of_reduce(self, reduce_id: int) -> ExecutorId:
         for p, (s, e) in enumerate(self.peer_ranges):
@@ -153,6 +160,33 @@ def _start_landing(prefix) -> None:
     runtime allocates the NumPy array the bytes land in HERE, from the
     calling thread's NumPy allocator, and ``np.asarray`` later waits for it."""
     prefix.copy_to_host_async()
+
+
+@functools.partial(jax.jit, static_argnames=("record_lanes", "key_bytes", "flat"))
+def ordered_records(table, *segments, record_lanes: int, key_bytes: int, flat: bool):
+    """Slot-aligned gathered segments -> a reduce task's records in key order
+    (the executable ``jit_ordered_records`` of a device trace; one a shuffle
+    geometry, cached by shape inside ``jax.jit``).
+
+    ``segments``: ``(rows, lane)`` int32 buffers in which every block starts
+    on a slot boundary (``TpuShuffleCluster._ordered_geometry``), so each IS a
+    ``(record places, record_lanes)`` array by reshape; ``table``: (2, B)
+    int32, the first record place and the record count of every block (places
+    no block covers are padding).  Returns the first segment's count of
+    places — every record of the task fits them — sorted by the records'
+    first ``key_bytes`` bytes (``ops.sort.sort_rows``), padding last and
+    zero.  ``flat``: as ONE row-major 1-D array, the form that crosses to the
+    host — XLA lays a 25-lane 2-D array out column-major on a TPU, and
+    ``np.asarray`` of it is a strided host array whose turning round costs
+    more than the sort; the reshape does it on the chip."""
+    places = [seg.reshape(-1, record_lanes) for seg in segments]
+    capacity = places[0].shape[0]
+    records = places[0] if len(places) == 1 else jnp.concatenate(places)
+    place = jnp.arange(records.shape[0], dtype=jnp.int32)[:, None]
+    first, count = table[0][None, :], table[1][None, :]
+    valid = ((place >= first) & (place < first + count)).any(axis=1)
+    ordered = sort_rows(records, key_lanes_of(key_bytes), valid, key_bytes)[:capacity]
+    return ordered.reshape(-1) if flat else ordered
 
 
 class _MeshChanged(Exception):
@@ -210,6 +244,19 @@ class TpuShuffleCluster:
         #: round the task's blocks lie in), ``rows`` payload rows gathered.
         self._device_read_stats: List[Dict[str, int]] = [
             dict.fromkeys(("tasks", "blocks", "rows", "bytes", "gathers", "locate_ns"), 0)
+            for _ in range(self.num_executors)
+        ]  #: guarded by self._lock
+        #: Ordered-read counters (the ``orderedread`` metrics family), one row
+        #: an executor, bumped once a ``fetch_blocks_ordered`` /
+        #: ``ordered_to_host`` call (a reduce task), never a record:
+        #: ``records`` / ``bytes`` the task's own, ``capacity_records`` what
+        #: they were sorted at, ``sort_dispatches`` calls of the ordering
+        #: executable (one a non-empty task), ``d2h_bytes`` / ``d2h_ns`` the
+        #: ordered array's one transfer to the host (``read_batches()`` only).
+        self._ordered_read_stats: List[Dict[str, int]] = [
+            dict.fromkeys(
+                ("tasks", "records", "bytes", "capacity_records", "sort_dispatches", "d2h_bytes", "d2h_ns"), 0
+            )
             for _ in range(self.num_executors)
         ]  #: guarded by self._lock
         #: Liveness/epoch layer.  Always constructed (it is just bookkeeping);
@@ -271,6 +318,10 @@ class TpuShuffleCluster:
         self.metrics.register(
             "deviceread",
             labelled_counter_provider("deviceread", "executor", self.device_read_stats),
+        )
+        self.metrics.register(
+            "orderedread",
+            labelled_counter_provider("orderedread", "executor", self.ordered_read_stats),
         )
         self.recorder = FlightRecorder(
             TRACER,
@@ -342,6 +393,12 @@ class TpuShuffleCluster:
         in the row) — the ``deviceread`` metrics family."""
         with self._lock:
             return [{"executor": e, **row} for e, row in enumerate(self._device_read_stats)]
+
+    def ordered_read_stats(self) -> List[Dict[str, int]]:
+        """The ordered-read counters, one row an executor — the
+        ``orderedread`` metrics family."""
+        with self._lock:
+            return [{"executor": e, **row} for e, row in enumerate(self._ordered_read_stats)]
 
     def metrics_text(self) -> str:
         """The cluster registry's Prometheus exposition (collective-plane
@@ -567,8 +624,6 @@ class TpuShuffleCluster:
                         f"expected {(send_rows, lane)} — mismatched staging "
                         "geometry (stagingCapacity/blockAlignment) across executors"
                     )
-        import jax.numpy as jnp
-
         n = self.num_executors
         staging_slot = send_rows // n
         # Plan context from the sealed size matrices (metadata-before-data:
@@ -1493,14 +1548,16 @@ class TpuShuffleCluster:
                 starts[rnd, sender] = chunk_start
         return rnd, chunk_start + region_rel // row, -(-length // row)
 
-    def _gather_fn(self, impl: Optional[str], num_blocks: int, out_rows: int):
+    def _gather_fn(self, impl: Optional[str], num_blocks: int, out_rows: int, exact: bool = False):
         """Cache compiled gathers; shapes are bucketed to powers of two (blocks
         padded with zero-count entries, which the kernels skip) so repeated
-        fetches of varying batch sizes reuse a handful of compilations."""
+        fetches of varying batch sizes reuse a handful of compilations.
+        ``exact``: ``out_rows`` is already one static figure a shuffle (the
+        ordered read's) and is taken as it is."""
         from sparkucx_tpu.ops.pallas_kernels import build_block_gather
 
         b = 1 << max(num_blocks - 1, 0).bit_length()
-        r = 1 << max(out_rows - 1, 0).bit_length()
+        r = out_rows if exact else 1 << max(out_rows - 1, 0).bit_length()
         key = ("gather", impl, b, r)
         with self._lock:
             fn = self._exchange_cache.get(key)
@@ -1547,12 +1604,7 @@ class TpuShuffleCluster:
         ``fetch.device_gather`` (one dispatch a round, the plan its argument;
         the gather itself is asynchronous).  Counter family ``deviceread{executor}``, once a call.
         """
-        meta = self.meta(shuffle_id)
-        if not meta.exchanged:
-            raise TransportError(f"shuffle {shuffle_id} not exchanged yet")
-        if meta.recv_device is None:
-            raise TransportError("device shards not retained (conf.keep_device_recv=false)")
-
+        meta = self._retained_meta(shuffle_id)
         t0 = time.perf_counter_ns()
         with span("read.device.locate", shuffle_id=shuffle_id, blocks=len(block_ids)):
             entries, plans, rows = self._plan_device_fetch(meta, consumer, shuffle_id, block_ids, impl)
@@ -1569,10 +1621,20 @@ class TpuShuffleCluster:
             counters["locate_ns"] += locate_ns
         return packed, entries
 
-    def _plan_device_fetch(self, meta, consumer, shuffle_id, block_ids, impl):
+    def _retained_meta(self, shuffle_id: int) -> _ShuffleMeta:
+        """The meta of an exchanged shuffle whose received shards are in HBM."""
+        meta = self._exchanged_meta(shuffle_id)
+        if meta.recv_device is None:
+            raise TransportError("device shards not retained (conf.keep_device_recv=false)")
+        return meta
+
+    def _plan_device_fetch(self, meta, consumer, shuffle_id, block_ids, impl, slot_rows=1, out_rows=None):
         """Host half of a device fetch: every block located in ``consumer``'s
         received shards, and one gather plan a staging round — ``(round, fn,
-        (3, B) starts/counts/outs)``.  Returns (entries, plans, payload rows)."""
+        (3, B) starts/counts/outs)``.  Returns (entries, plans, payload rows).
+        The ordered read's form: every block starts on a multiple of
+        ``slot_rows`` rows and every round's segment is ``out_rows`` rows,
+        exactly (``_ordered_geometry``)."""
         located = []  # (round, src_row, rows) per request
         for bid in block_ids:
             if bid.shuffle_id != shuffle_id:
@@ -1586,20 +1648,23 @@ class TpuShuffleCluster:
             idxs = [i for i, (r, _, c) in enumerate(located) if r == rnd and c]
             starts = np.asarray([located[i][1] for i in idxs], dtype=np.int32)
             counts = np.asarray([located[i][2] for i in idxs], dtype=np.int32)
-            outs = np.concatenate([[0], np.cumsum(counts)[:-1]]).astype(np.int32)
+            slots = -(-counts // slot_rows) * slot_rows
+            outs = (np.cumsum(slots) - slots).astype(np.int32)
             total = int(counts.sum())
             for i, o in zip(idxs, outs):
                 bid = block_ids[i]
                 entries[i] = (base + int(o), meta.mapper_infos[bid.map_id].partitions[bid.reduce_id][1])
-            fn, b_pad, r_pad = self._gather_fn(impl, len(idxs), total)
+            fn, b_pad, r_pad = self._gather_fn(
+                impl, len(idxs), total if out_rows is None else out_rows, exact=out_rows is not None
+            )
             pad = b_pad - len(idxs)
             if pad:
                 starts = np.pad(starts, (0, pad))
                 counts = np.pad(counts, (0, pad))
-                # Padding entries land at the packed end (outs=total, count=0):
-                # the xla lowering's searchsorted needs outs+counts non-
-                # decreasing; the Pallas lowerings skip zero-count blocks.
-                outs = np.pad(outs, (0, pad), constant_values=total)
+                # Padding entries land at the packed end (count=0): the xla
+                # lowering's searchsorted needs outs+counts non-decreasing;
+                # the Pallas lowerings skip zero-count blocks.
+                outs = np.pad(outs, (0, pad), constant_values=int(slots.sum()))
             plans.append((rnd, fn, np.stack([starts, counts, outs])))
             # the next round's blocks start where this round's bucket ends:
             # the segments are concatenated whole, never sliced to ``total``
@@ -1611,8 +1676,6 @@ class TpuShuffleCluster:
         """Device half: one gather dispatch a round, its (3, B) plan an
         argument of the call.  Every shape here is a power-of-two bucket, so
         tasks of different totals share their executables."""
-        import jax.numpy as jnp
-
         segments = []
         for rnd, fn, plan in plans:
             segments.append(fn(plan, meta.recv_device[rnd][consumer]))
@@ -1622,6 +1685,136 @@ class TpuShuffleCluster:
                 device=self.transports[consumer].device,
             )
         return segments[0] if len(segments) == 1 else jnp.concatenate(segments, axis=0)
+
+    # -- the ordered read: a task's records sorted on the device -----------
+
+    def _ordered_geometry(self, meta: _ShuffleMeta, record_bytes: int) -> Tuple[int, int, int]:
+        """(slot rows, slot records, capacity records) of an ordered read of
+        this shuffle's ``record_bytes``-byte records.
+
+        A block starts on a row and its records lie back to back from there,
+        so rows and records meet again every ``lcm(record_bytes, row_bytes)``
+        bytes: a *slot* (25 rows = 128 records of 100 B at 512 B rows).  A
+        gather that starts every block on a slot boundary therefore leaves a
+        buffer that IS a ``(records, lanes)`` array by reshape — no record
+        straddles anything — with fewer than a slot of unused record places
+        after each block, which the sort takes for padding.  ``capacity`` is
+        the most record places any ONE reduce partition of the shuffle needs
+        that way (its records, each block rounded up to a slot): one static
+        figure a shuffle from the sealed size matrix, the same on every
+        executor, so every task shares one executable."""
+        slot_bytes = int(np.lcm(record_bytes, self.row_bytes))
+        slot_rows, slot_records = slot_bytes // self.row_bytes, slot_bytes // record_bytes
+        with self._lock:
+            capacity = meta.ordered_capacity.get(record_bytes)
+        if capacity is None:
+            lengths = np.asarray(
+                [[ln for _, ln in info.partitions] for info in meta.mapper_infos.values()],
+                dtype=np.int64,
+            ).reshape(-1, meta.num_reducers)
+            slots = -(-lengths // slot_bytes)  # a ragged block is refused by name later
+            capacity = int(slots.sum(axis=0).max(initial=0)) * slot_records
+            with self._lock:
+                meta.ordered_capacity[record_bytes] = capacity
+        return slot_rows, slot_records, capacity
+
+    def fetch_blocks_ordered(
+        self,
+        consumer: ExecutorId,
+        shuffle_id: int,
+        block_ids: Sequence[ShuffleBlockId],
+        record_bytes: int,
+        key_bytes: int,
+        flat: bool = False,
+    ) -> Tuple[object, int]:
+        """The ordered form of ``fetch_blocks_to_device``: the blocks' records
+        — ``record_bytes`` wide, back to back in every block — gathered and
+        **sorted on ``consumer``'s device** by their first ``key_bytes`` bytes
+        compared as unsigned bytes, most significant first (stable).
+
+        Returns ``(records, n)``: ``records`` a ``(capacity, record_bytes / 4)``
+        int32 ``jax.Array`` on the device whose first ``n`` rows are the
+        records in key order and whose other rows are zero; ``n`` comes from
+        the block table, no sync.  ``capacity`` is one figure a shuffle
+        (``_ordered_geometry``) times the reduce partitions the blocks span,
+        so a shuffle's tasks share the gather's and the sort's executables.
+        ``flat``: the same rows as one ``(capacity * record_bytes / 4,)``
+        array, row-major — what ``ordered_to_host`` takes.
+        Blocks of several staging rounds are gathered a round a segment and
+        sorted together.  A block that is no whole number of records raises
+        ``RaggedBlockError``; shards not retained, the same typed error as
+        the unordered fetch.
+
+        Spans, once a call: ``read.device.locate``, ``fetch.device_gather``
+        (as in the unordered fetch) and ``read.ordered.sort`` — the dispatch
+        of ``ordered_records``: the time the call holds the thread, not the
+        sort, which is asynchronous.  Counter family ``orderedread{executor}``."""
+        from sparkucx_tpu.shuffle.reader import RaggedBlockError
+
+        if record_bytes <= 0 or record_bytes % 4 or not 0 < key_bytes <= record_bytes:
+            raise ValueError(
+                f"an ordered read needs records of whole 32-bit lanes and a key inside them, "
+                f"not {record_bytes} B records with {key_bytes} B keys"
+            )
+        meta = self._retained_meta(shuffle_id)
+        slot_rows, slot_records, capacity = self._ordered_geometry(meta, record_bytes)
+        capacity *= max(1, len({bid.reduce_id for bid in block_ids}))
+        with span("read.device.locate", shuffle_id=shuffle_id, blocks=len(block_ids)):
+            entries, plans, _ = self._plan_device_fetch(
+                meta, consumer, shuffle_id, block_ids, None,
+                slot_rows=slot_rows, out_rows=capacity // slot_records * slot_rows,
+            )
+            for i in np.flatnonzero(entries[:, 1] % record_bytes)[:1]:
+                raise RaggedBlockError(int(entries[i, 1]), record_bytes, block_ids[i])
+            n = int(entries[:, 1].sum()) // record_bytes
+            if plans:
+                # a power-of-two bucket of blocks, as the gather's plan: tasks
+                # of nearby block counts share the sort's executable
+                table = np.zeros((2, 1 << (len(block_ids) - 1).bit_length()), dtype=np.int32)
+                table[0, : len(block_ids)] = entries[:, 0] // slot_rows * slot_records
+                table[1, : len(block_ids)] = entries[:, 1] // record_bytes
+        lanes = record_bytes // 4
+        if not plans:
+            shape = (capacity * lanes,) if flat else (capacity, lanes)
+            records = jnp.zeros(shape, dtype=jnp.int32, device=self.transports[consumer].device)
+        else:
+            with span("fetch.device_gather", shuffle_id=shuffle_id, blocks=len(block_ids)):
+                segments = [fn(plan, meta.recv_device[rnd][consumer]) for rnd, fn, plan in plans]
+            with span("read.ordered.sort", shuffle_id=shuffle_id, records=n, capacity=capacity):
+                records = ordered_records(
+                    table, *segments, record_lanes=lanes, key_bytes=key_bytes, flat=flat
+                )
+        with self._lock:
+            counters = self._ordered_read_stats[consumer]
+            counters["tasks"] += 1
+            counters["records"] += n
+            counters["bytes"] += n * record_bytes
+            counters["capacity_records"] += capacity
+            counters["sort_dispatches"] += bool(plans)
+        return records, n
+
+    def ordered_to_host(self, consumer: ExecutorId, records, n: int, record_bytes: int) -> np.ndarray:
+        """The first ``n`` records of a ``flat`` ordered read on the host: ONE
+        D2H of the whole array (its shape is the shuffle's, so nothing is
+        sliced on the device and nothing compiles), landed where received
+        shards land (``_landing``), handed out as a read-only ``(n,
+        record_bytes)`` ``uint8`` view of it.  Span ``read.ordered.d2h``: the
+        wait until the bytes are host-readable — the sort and the transfer."""
+        capacity = records.size * 4 // record_bytes
+        pool = self._landing()
+        t0 = time.perf_counter_ns()
+        with span("read.ordered.d2h", records=n, bytes=n * record_bytes, capacity=capacity):
+            with pool.allocating() if pool is not None else contextlib.nullcontext():
+                _start_landing(records)
+            host = np.asarray(records)
+        d2h_ns = time.perf_counter_ns() - t0
+        with self._lock:
+            counters = self._ordered_read_stats[consumer]
+            counters["d2h_bytes"] += host.nbytes
+            counters["d2h_ns"] += d2h_ns
+        batch = host.view(np.uint8).reshape(capacity, record_bytes)[:n]
+        batch.flags.writeable = False
+        return batch
 
 
 class TpuShuffleTransport(ShuffleTransport):
@@ -1801,6 +1994,21 @@ class TpuShuffleTransport(ShuffleTransport):
                 raise ValueError("no block ids")
             shuffle_id = block_ids[0].shuffle_id
         return self.cluster.fetch_blocks_to_device(self.executor_id, shuffle_id, block_ids, impl=impl)
+
+    def fetch_blocks_ordered(
+        self, block_ids: Sequence[ShuffleBlockId], shuffle_id: int, record_bytes: int, key_bytes: int,
+        flat: bool = False,
+    ) -> Tuple[object, int]:
+        """These blocks' fixed-width records gathered and sorted by key on
+        this executor's device (``TpuShuffleCluster.fetch_blocks_ordered``)."""
+        return self.cluster.fetch_blocks_ordered(
+            self.executor_id, shuffle_id, block_ids, record_bytes, key_bytes, flat=flat
+        )
+
+    def ordered_to_host(self, records, n: int, record_bytes: int) -> np.ndarray:
+        """A ``flat`` ordered read's first ``n`` records on the host, in one
+        D2H (``TpuShuffleCluster.ordered_to_host``)."""
+        return self.cluster.ordered_to_host(self.executor_id, records, n, record_bytes)
 
     def progress(self) -> None:
         """Poll outstanding async work (non-blocking).  Post-exchange fetches

@@ -13,6 +13,7 @@ import pytest
 
 from sparkucx_tpu.config import TpuShuffleConf
 from sparkucx_tpu.core.block import ShuffleBlockId
+from sparkucx_tpu.core.operation import TransportError
 from sparkucx_tpu.shuffle.manager import TpuShuffleManager
 from sparkucx_tpu.shuffle.reader import (
     FixedWidthSerializer,
@@ -196,11 +197,15 @@ def test_what_is_not_a_batch_read_raises(rng, tmp_path):
         _write_records(mgr, 0, rng)
         with pytest.raises(TypeError, match="FixedWidthSerializer"):
             mgr.get_reader(0, 0, 1).read_batches()
-        for unsupported in ({"aggregator": lambda a, b: a}, {"key_ordering": True}):
-            reader = mgr.get_reader(0, 0, 1, deserializer=SERIALIZER, **unsupported)
-            with pytest.raises(NotImplementedError, match="not supported yet"):
-                reader.read_batches()
-            assert reader.metrics.remote_blocks_fetched == 0  # refused before a block is touched
+        reader = mgr.get_reader(0, 0, 1, deserializer=SERIALIZER, aggregator=lambda a, b: a)
+        with pytest.raises(NotImplementedError, match="not supported"):
+            reader.read_batches()
+        assert reader.metrics.remote_blocks_fetched == 0  # refused before a block is touched
+        # the ordered return is the device's: host-received shards have none
+        reader = mgr.get_reader(0, 0, 1, deserializer=SERIALIZER, key_ordering=True)
+        with pytest.raises(TransportError, match="device shards not retained"):
+            reader.read_batches()
+        assert reader.metrics.remote_blocks_fetched == 0
 
 
 @pytest.mark.parametrize("mode", MODES)

@@ -175,13 +175,12 @@ class TestRunColumnarShuffle:
         assert int(np.asarray(counts).sum()) == n * cap
 
 
-class TestGatherRowsBandChunking:
-    """gather_rows chunks lane widths in the empirically slow XLA band
-    (25..32 on v5e) into <=24-lane gathers; results must be bit-identical to
-    the plain gather at every width."""
+class TestGatherRows:
+    """gather_rows is the plain row gather at every width (the 25..32-lane
+    chunking it once did measured slower on the chip, PR 48)."""
 
     def test_equivalence_across_widths(self):
-        from sparkucx_tpu.ops.exchange import SLOW_GATHER_LANES, gather_rows
+        from sparkucx_tpu.ops.exchange import gather_rows
 
         rng = np.random.default_rng(0)
         idx = rng.permutation(257).astype(np.int32)
@@ -189,5 +188,3 @@ class TestGatherRowsBandChunking:
             rows = rng.normal(size=(257, w)).astype(np.float32)
             got = np.asarray(jax.jit(gather_rows)(rows, idx))
             np.testing.assert_array_equal(got, rows[idx], err_msg=f"width {w}")
-        lo, hi = SLOW_GATHER_LANES
-        assert lo <= 32 <= hi  # the measured-slow width stays covered

@@ -464,3 +464,71 @@ class TestExternalSort:
         assert mp[:, 0].tolist() == [0, 20, 1, 21, 22, 2]
         with pytest.raises(ValueError):
             merge_sorted_runs([], [])
+
+
+# -- the one local sort (``sort_rows``): several key lanes, byte-string keys ----
+
+from sparkucx_tpu.ops.sort import key_lanes_of, sort_rows  # noqa: E402
+
+
+def _lexsort_rows(rows, lanes, valid):
+    """NumPy's ``lexsort`` over the first ``lanes`` lanes as ``uint32``
+    (stable; lane 0 most significant), valid rows first, padding zeroed."""
+    keys = rows[:, :lanes].view(np.uint32)
+    order = np.lexsort([keys[:, i] for i in reversed(range(lanes))] + [~valid])
+    out = rows[order]
+    out[int(valid.sum()):] = 0
+    return out
+
+
+@pytest.mark.parametrize("lanes", [1, 2, 3])
+@pytest.mark.parametrize("width", [3, 7, 25])
+def test_sort_rows_is_numpys_lexsort(rng, lanes, width):
+    """Lanes compared as the ``uint32`` they hold, lane 0 first; few distinct
+    values a lane, so later lanes decide and ties test stability; values of
+    both signs as ``int32`` (an unsigned compare)."""
+    n = 777
+    rows = rng.integers(-3, 3, size=(n, width)).astype(np.int32)
+    rows[:, 0] *= 0x40000000  # 0x80000000 and 0xC0000000 must sort after 0x40000000
+    rows[:, -1] = np.arange(n)  # tells equal keys apart: stability is visible
+    valid = np.arange(n) < 700
+    want = _lexsort_rows(rows, lanes, valid)
+    got = np.asarray(jax.jit(sort_rows, static_argnums=(1,))(jnp.asarray(rows), lanes, 700))
+    assert np.array_equal(got, want)
+    mask = rng.random(n) < 0.8  # validity as a mask: padding anywhere, not only behind
+    got = np.asarray(jax.jit(sort_rows, static_argnums=(1,))(jnp.asarray(rows), lanes, jnp.asarray(mask)))
+    assert np.array_equal(got, _lexsort_rows(rows, lanes, mask))
+
+
+@pytest.mark.parametrize("key_bytes", [1, 3, 4, 5, 8, 10, 12])
+def test_sort_rows_orders_byte_string_keys(rng, key_bytes):
+    """``key_bytes``: the row's first bytes in memory order, unsigned, most
+    significant first — against Python's own order of ``bytes``; the bytes
+    after the key in the last lane do not take part; bytes >= 0x80 in every
+    position."""
+    n, width = 600, 5
+    raw = rng.integers(0, 256, size=(n, width * 4), dtype=np.uint8)
+    raw[:, :key_bytes] = rng.choice(np.array([0x00, 0x7F, 0x80, 0xFF], np.uint8), size=(n, key_bytes))
+    raw[: n // 2, : max(key_bytes - 1, 0)] = raw[0, : max(key_bytes - 1, 0)]  # the last key byte decides
+    rows = raw.view(np.int32).reshape(n, width)
+    lanes = key_lanes_of(key_bytes)
+    fn = jax.jit(lambda r, valid: sort_rows(r, lanes, valid, key_bytes=key_bytes))
+    got = np.asarray(fn(jnp.asarray(rows), n)).view(np.uint8).reshape(n, width * 4)
+    order = sorted(range(n), key=lambda i: (bytes(raw[i, :key_bytes]), i))  # stable
+    assert np.array_equal(got, raw[order])
+    # padding anywhere among the rows, whatever its bytes: last, zeroed — also
+    # behind a valid key of all 0xFF
+    mask = rng.random(n) < 0.7
+    got = np.asarray(fn(jnp.asarray(rows), jnp.asarray(mask))).view(np.uint8).reshape(n, width * 4)
+    kept = [i for i in order if mask[i]]
+    assert np.array_equal(got[: len(kept)], raw[kept]) and not got[len(kept):].any()
+
+
+def test_sort_rows_refuses_what_is_no_key():
+    rows = jnp.zeros((4, 3), jnp.int32)
+    with pytest.raises(ValueError, match="key_lanes"):
+        sort_rows(rows, 4, 4)
+    with pytest.raises(ValueError, match="key_lanes"):
+        sort_rows(rows, 0, 4)
+    with pytest.raises(ValueError, match="10-byte key is 3 lanes"):
+        sort_rows(rows, 2, 4, key_bytes=10)
