@@ -75,11 +75,11 @@ from __future__ import annotations
 #:   entries were pruned with PR 13 — the unified plan executor replaced
 #:   them.)
 #:
-#: - tpu.py ``_recover_and_rerun``: the degraded-mode recovery path (elastic
-#:   mesh, reached from ``_run_exchange`` only after an executor died).  It
-#:   deliberately materializes restaged replica rounds and degraded-wave
-#:   results host-side: recovery is an abort-and-rerun cold path measured in
-#:   hundreds of ms, not a pipeline lane — blocking there is the design.
+#:   (tpu.py ``_recover_and_rerun``'s entry was pruned with PR 49: the
+#:   degraded re-run is a plan ``execute_plan`` interprets, its submit closure
+#:   blocks on nothing and its waits — ``np.asarray`` of the size matrix and
+#:   of the received prefixes — sit in its drain closure, the lane the
+#:   "drain stage" note above blesses.  Nothing is left to excuse.)
 #:
 #: - testing/faults.py ``kill_executor``: the chaos harness's whole job is to
 #:   kill an executor the way SIGKILL would — yanking the live connection
@@ -131,7 +131,6 @@ ALLOWLIST = {
     ("transport/tpu.py", "private-access", "._single_device_array_to_np_array_did_copy"),
     ("native/__init__.py", "private-access", "_multiarray_umath"),
     ("native/__init__.py", "private-access", "._ARRAY_API"),
-    ("transport/tpu.py", "host-sync", "(via '_recover_and_rerun')"),
     ("store/hbm_store.py", "cache-hygiene", "'out_rows'"),
 }
 

@@ -13,31 +13,34 @@ resets on exit even when the test body raises).
 
 Named points currently instrumented (transport/peer.py):
 
-====================  ==========================================================
-peer.client.recv      top of a client lane's recv loop, before each frame
-                      (ctx: ``peer``, ``lane``)
-peer.client.frame     transform hook over each received client frame header
-                      (ctx: ``peer``, ``lane``) — garbling it kills the lane
-peer.server.frame     server dispatch, after each decoded frame
-                      (ctx: ``peer``, ``am_id``)
-peer.server.chunk     transform hook over each striped chunk's payload, after
-                      its crc trailer is computed (ctx: ``tag``, ``block``) —
-                      garbling it models in-flight corruption the client-side
-                      ``wire.checksum`` verify must catch
+========================  ==========================================================
+peer.client.recv          top of a client lane's recv loop, before each frame
+                          (ctx: ``peer``, ``lane``)
+peer.client.frame         transform hook over each received client frame header
+                          (ctx: ``peer``, ``lane``) — garbling it kills the lane
+peer.server.frame         server dispatch, after each decoded frame
+                          (ctx: ``peer``, ``am_id``)
+peer.server.chunk         transform hook over each striped chunk's payload, after
+                          its crc trailer is computed (ctx: ``tag``, ``block``) —
+                          garbling it models in-flight corruption the client-side
+                          ``wire.checksum`` verify must catch
 
-replica.push          replicator thread, before pushing a sealed shuffle
-                      (ctx: ``shuffle_id``, ``executor``)
-replica.apply         server side, before installing a received replica round
-                      (ctx: ``shuffle_id``, ``src_executor``, ``round_idx``)
-exchange.submit       collective plane (transport/tpu.py), before each round's
-                      submit (ctx: ``shuffle_id``, ``round``) — the hook that
-                      lets chaos tests kill an executor mid-superstep
-store.mem_pressure    store/hbm_store.py + memory/pool.py, before each
-                      allocation-bearing mutation (close_partition, device
-                      write, replica install, restage, pool growth) — arming
-                      ``fail(ResourceExhaustedError(...))`` models a host
-                      under memory pressure (ctx: ``site``, ``nbytes``)
-====================  ==========================================================
+replica.push              replicator thread, before pushing a sealed shuffle
+                          (ctx: ``shuffle_id``, ``executor``)
+replica.apply             server side, before installing a received replica round
+                          (ctx: ``shuffle_id``, ``src_executor``, ``round_idx``)
+exchange.submit           collective plane (transport/tpu.py), before each round's
+                          submit (ctx: ``shuffle_id``, ``round``) — the hook that
+                          lets chaos tests kill an executor mid-superstep
+exchange.recover.submit   collective plane, before each sub-exchange of a degraded
+                          re-run is submitted (ctx: ``shuffle_id``, ``round``,
+                          ``chunk``) — a second loss inside the recovery
+store.mem_pressure        store/hbm_store.py + memory/pool.py, before each
+                          allocation-bearing mutation (close_partition, device
+                          write, replica install, restage, pool growth) — arming
+                          ``fail(ResourceExhaustedError(...))`` models a host
+                          under memory pressure (ctx: ``site``, ``nbytes``)
+========================  ==========================================================
 
 :func:`kill_executor` force-kills a loopback-cluster executor: its server
 socket, accepted connections, and outbound client connections all die

@@ -65,6 +65,7 @@ from sparkucx_tpu.ops.exchange import bucket_send_rows, rebucket_slots
 from sparkucx_tpu.ops.planner import PlanContext, PlanSignals, make_planner
 from sparkucx_tpu.ops.sort import key_lanes_of, sort_rows
 from sparkucx_tpu.ops.skew import (
+    ExchangePlan,
     chunk_size_rows,
     pad_rows_pow2,
     piece_slices,
@@ -160,6 +161,22 @@ def _start_landing(prefix) -> None:
     runtime allocates the NumPy array the bytes land in HERE, from the
     calling thread's NumPy allocator, and ``np.asarray`` later waits for it."""
     prefix.copy_to_host_async()
+
+
+def _wave_piece(src: np.ndarray, lo: int, hi: int, m: int, slot_rows: int, bucketed: int) -> np.ndarray:
+    """One sender's piece of a sub-exchange of the degraded re-run: rows
+    ``[lo, hi)`` of its sealed round — the regions of one wave of consumers —
+    in the shrunk mesh's send layout, ``m`` slots of ``bucketed // m`` rows.
+    Where the slot is the region and the wave is whole those rows ARE the
+    piece, and it is a view of ``src``; else it is a copy: zero regions
+    appended for the consumers a short last wave lacks (``m`` does not
+    divide the executors), each region moved to its slot's start
+    (``rebucket_slots``: a staging size whose region is no power of two)."""
+    rows = src[lo:hi]
+    short = m * slot_rows - (hi - lo)
+    if short:
+        rows = np.pad(rows, ((0, short), (0, 0)))
+    return rebucket_slots(rows, m, bucketed)
 
 
 @functools.partial(jax.jit, static_argnames=("record_lanes", "key_bytes", "flat"))
@@ -277,8 +294,12 @@ class TpuShuffleCluster:
         #: arrays that came from a block the landing pool kept / did not;
         #: 0 / 0 where there is no pool).  Once a recovery: ``recoveries``,
         #: ``recover_ns``, ``restaged_blocks`` / ``restaged_bytes`` (blocks of
-        #: dead executors rebuilt from replicas) and ``degraded_subexchanges``
-        #: (collectives dispatched on the shrunk mesh).
+        #: dead executors rebuilt from replicas), ``degraded_subexchanges``
+        #: (collectives dispatched on the shrunk mesh) and what their submits
+        #: handed the devices: ``recover_direct_bytes`` (host bytes put as
+        #: views of a sealed or restaged round), ``recover_copied_bytes``
+        #: (bytes through the one copy into the shrunk mesh's layout) and
+        #: ``recover_zero_pieces`` (pieces made on the device).
         self.elastic_stats = {
             "recoveries": 0,
             "last_recovery_ms": 0.0,
@@ -294,6 +315,9 @@ class TpuShuffleCluster:
             "replica_landing_hits": 0,
             "replica_landing_misses": 0,
             "recover_ns": 0,
+            "recover_direct_bytes": 0,
+            "recover_copied_bytes": 0,
+            "recover_zero_pieces": 0,
         }  #: guarded by self._lock
         #: Obs plane (PR 14): cluster-level registry + flight recorder.  The
         #: registry absorbs the collective plane's surfaces (exchange timings,
@@ -984,14 +1008,15 @@ class TpuShuffleCluster:
             # An executor died under this exchange: abort the stale full-mesh
             # plan and re-run degraded on the surviving pow2 bucket (or raise
             # a typed ExecutorLostError when recovery is impossible).
-            # The recovery's large host arrays — the restaged rounds, each
-            # sub-exchange's send array, the landings of its received
-            # prefixes, the recovered shards — come from the pool received
-            # shards land in, where there is one (_landing): the blocks the
-            # last job's recovery gave back, not fresh mappings a time.
+            # The recovery's large host arrays — the restaged rounds, the
+            # landings of each sub-exchange's received prefixes, the
+            # recovered shards — come from the pool received shards land in,
+            # where there is one (_landing): the blocks the last job's
+            # recovery gave back, not fresh mappings a time.  (The allocator
+            # is this thread's: the re-run's drain worker takes it itself.)
             allocating = pool.allocating() if pool is not None else contextlib.nullcontext()
             with span("exchange.recover", shuffle_id=shuffle_id), allocating:
-                self._recover_and_rerun(meta, sealed, mode)
+                self._recover_and_rerun(meta, sealed, mode, pool, plan.pipeline_depth)
             return
 
         meta.recv_shards, meta.recv_sizes = [], []
@@ -1068,11 +1093,18 @@ class TpuShuffleCluster:
             self.elastic_stats["replicate_ns"] += time.perf_counter_ns() - t0
         return {"copied_bytes": copied_bytes, "landing_hits": hits, "landing_misses": misses}
 
-    def _recover_and_rerun(self, meta, sealed, mode: str) -> None:
+    def _recover_and_rerun(self, meta, sealed, mode: str, pool: Optional[LandingPool], depth: int) -> None:
         """Degraded-mode recovery: quarantine the aborted exchange's partial
         state, restage every dead executor's rounds from ring-successor
         replicas, shrink to the surviving pow2 bucket, and re-run the whole
         shuffle as ``waves x waves`` sub-exchanges on the shrunk mesh.
+
+        The re-run is a plan the plan executor interprets (``execute_plan``
+        under the name ``exchange.recover.pipeline``, ``depth`` deep as the
+        aborted plan was): a staging round is a round, a sub-exchange that
+        carries a row a chunk of it, and the three closures below are the
+        recovery's own — so sub-exchange k + 1 is put while k's received rows
+        cross back.  ``pool``: the blocks received shards land in, or None.
 
         Determinism: each sub-exchange (i, j) moves wave i's senders' regions
         for wave j's consumers, and a consumer's final shard concatenates its
@@ -1143,96 +1175,210 @@ class TpuShuffleCluster:
             if restage is not None:
                 restage.args.update(blocks=restaged_blocks, bytes=restaged_bytes)
 
-        def round_payload(l, rnd):
-            src = sealed[l] if sealed[l] is not None else restaged.get(l, [])
-            if rnd < len(src):
-                return src[rnd]
-            return None, np.zeros(n, dtype=np.int32)
-
         fn, submesh = self._degraded_exchange_fn(m, phys, m * slot_rows)
         bucketed = bucket_send_rows(m * slot_rows, m)
         ax = self.conf.mesh_axis_name
         sub_sharding = NamedSharding(submesh, P(ax, None))
         sub_devices = list(submesh.devices.reshape(-1))
 
-        meta.recv_shards, meta.recv_sizes = [], []
-        subexchanges = 0
+        # The re-run as a plan (transport/executor.py): a round of the plan
+        # is a staging round, a chunk of it one (senders' wave i, consumers'
+        # wave j) sub-exchange — and only a pair that carries a row is a
+        # chunk, read off the sealed and restaged size matrices before a byte
+        # moves: a pair with no row dispatches nothing.
+        payloads: List[List[Optional[np.ndarray]]] = []
+        #: [round][sender, destination] rows, zero-padded to whole waves
+        sizes = np.zeros((num_rounds, waves * m, waves * m), dtype=np.int64)
         for rnd in range(num_rounds):
-            # Span ``exchange.recover.round``, once a re-run staging round;
-            # ``subexchanges``: the collectives it dispatched on the shrunk
-            # mesh (a wave pair that carries no row dispatches none)
-            dispatched = 0
-            with span("exchange.recover.round", shuffle_id=shuffle_id, round=rnd) as round_span:
-                payloads, size_rows = [], []
-                for l in range(n):
-                    p, s = round_payload(l, rnd)
-                    payloads.append(p)
-                    size_rows.append(s)
-                full_sizes = np.stack(size_rows).astype(np.int64)  # [sender, dest]
-                consumer_parts: List[List[np.ndarray]] = [[] for _ in range(n)]
-                for i in range(waves):
-                    for j in range(waves):
-                        host = np.zeros((m * bucketed, lane), dtype=np.int32)
-                        sub_sizes = np.zeros((m, m), dtype=np.int32)
-                        lo = j * m * slot_rows
-                        hi = min((j + 1) * m, n) * slot_rows
-                        for p in range(m):
-                            l = i * m + p
-                            if l >= n:
-                                continue
-                            for q in range(m):
-                                c = j * m + q
-                                if c < n:
-                                    sub_sizes[p, q] = full_sizes[l, c]
-                            if payloads[l] is None:
-                                continue
-                            src = np.asarray(payloads[l])
-                            block = np.zeros((m * slot_rows, lane), dtype=np.int32)
-                            block[: hi - lo] = src[lo:hi]
-                            host[p * bucketed : (p + 1) * bucketed] = rebucket_slots(
-                                block, m, bucketed
-                            )
-                        if not int(sub_sizes.sum()):
-                            continue  # empty sub-exchange: contributes zero rows
-                        dispatched += 1
-                        data = jax.device_put(host, sub_sharding)
-                        size_mat = jax.device_put(sub_sizes, sub_sharding)
-                        with span(
-                            "exchange.collective.degraded",
-                            shuffle_id=shuffle_id, round=rnd, wave=(i, j), rows=bucketed,
-                        ):
-                            recv, recv_sizes = fn(data, size_mat)
-                        shard_by_device = {s.device: s.data for s in recv.addressable_shards}
-                        sizes_host = np.asarray(recv_sizes)  # [consumer, sender]
-                        for q in range(m):
-                            c = j * m + q
-                            if c >= n:
-                                continue
-                            used = int(sizes_host[q].sum())
-                            if used:
-                                prefix = self._received_prefix(
-                                    shard_by_device[sub_devices[q]], used
-                                )
-                                consumer_parts[c].append(
-                                    np.asarray(prefix)[:used].reshape(-1).view(np.uint8)
-                                )
-                assembled = [
-                    np.concatenate(parts) if parts else np.empty(0, dtype=np.uint8)
-                    for parts in consumer_parts
+            payloads.append([])
+            for l in range(n):
+                src = sealed[l] if sealed[l] is not None else restaged.get(l, [])
+                payload, size_rows = src[rnd] if rnd < len(src) else (None, 0)
+                payloads[rnd].append(payload)
+                sizes[rnd, l, :n] = size_rows
+
+        def pair_sizes(rnd, i, j):
+            return sizes[rnd, i * m : (i + 1) * m, j * m : (j + 1) * m]
+
+        pairs = [
+            [(i, j) for i in range(waves) for j in range(waves) if pair_sizes(rnd, i, j).any()]
+            for rnd in range(num_rounds)
+        ]
+        plan = ExchangePlan(
+            slot_rows=bucketed // m,
+            chunks_per_round=tuple(len(p) for p in pairs),
+            pipeline_depth=depth,
+        )
+        allocating = pool.allocating if pool is not None else contextlib.nullcontext
+        #: what each round's submits handed the devices (the ``elastic``
+        #: counters ``recover_*`` and the round span's arguments) and the
+        #: clock marks its ``exchange.recover.round`` span is made from: the
+        #: submit lane writes ``began``, the drain worker ``ended``, both are
+        #: read when the pipeline has shut down
+        tallies = [dict(direct_bytes=0, copied_bytes=0, zero_pieces=0) for _ in range(num_rounds)]
+        began, ended = [0] * num_rounds, [0] * num_rounds
+
+        def submit(rnd, chunk, nchunks):
+            """One sub-exchange's pieces, their puts, the dispatch and the
+            start of its landings — all asynchronous: it is in flight when
+            the next is assembled.  A sender's piece is a view of its sealed
+            or restaged round wherever the shrunk mesh's slot is the region
+            (``_wave_piece``), put on its own device; a sender with no row
+            for this wave's consumers, or no round at all, contributes a zero
+            piece made on its device, fresh every submit (the exchange
+            donates its first argument).  Child spans of
+            ``exchange.recover.pipeline.submit``: ``exchange.recover.h2d``
+            (the time the puts hold this lane, not the DMA) and
+            ``exchange.collective.degraded`` (the dispatch)."""
+            if chunk == 0:
+                began[rnd] = time.perf_counter_ns()
+            faults.check("exchange.recover.submit", shuffle_id=shuffle_id, round=rnd, chunk=chunk)
+            if self.membership.epoch != epoch:
+                # a second loss (or a rejoin) under the re-run: its plan is
+                # stale too, and there is no recovery of a recovery
+                now = self.membership.snapshot()
+                newly = sorted(set(now["dead"]) - set(dead))
+                raise ExecutorLostError(
+                    newly[0] if newly else first_dead,
+                    now["epoch"],
+                    "membership changed again under the degraded re-run "
+                    f"(epoch {epoch} -> {now['epoch']}); dead executors: {dict(now['dead'])}",
+                )
+            i, j = pairs[rnd][chunk]
+            sub_sizes = pair_sizes(rnd, i, j).astype(np.int32)
+            lo, hi = j * m * slot_rows, min((j + 1) * m, n) * slot_rows
+            tally = tallies[rnd]
+            pieces = []
+            for p in range(m):
+                l = i * m + p
+                payload = payloads[rnd][l] if l < n else None
+                if payload is None or not sub_sizes[p].any():
+                    pieces.append(None)
+                    tally["zero_pieces"] += 1
+                    continue
+                host = np.asarray(payload)
+                piece = _wave_piece(host, lo, hi, m, slot_rows, bucketed)
+                is_view = np.may_share_memory(piece, host)
+                tally["direct_bytes" if is_view else "copied_bytes"] += piece.nbytes
+                pieces.append(piece)
+            with span(
+                "exchange.recover.h2d",
+                shuffle_id=shuffle_id, round=rnd, wave=(i, j),
+                bytes=sum(piece.nbytes for piece in pieces if piece is not None),
+            ):
+                for p, piece in enumerate(pieces):
+                    if piece is None:
+                        pieces[p] = jnp.zeros((bucketed, lane), dtype=jnp.int32, device=sub_devices[p])
+                    else:
+                        # a view is read after device_put returns: sealed and
+                        # restaged rounds are not written until this returns
+                        pieces[p] = jax.device_put(piece, sub_devices[p])
+                data = jax.make_array_from_single_device_arrays(
+                    (m * bucketed, lane), sub_sharding, pieces
+                )
+                size_mat = jax.device_put(sub_sizes, sub_sharding)
+            with span(
+                "exchange.collective.degraded",
+                shuffle_id=shuffle_id, round=rnd, wave=(i, j), rows=bucketed,
+            ):
+                recv, recv_sizes = fn(data, size_mat)
+            # Each consumer's received prefix starts for the host now, into a
+            # block of the landing pool where there is one; how long it is
+            # the size matrix's column sum says, with no wait for recv_sizes.
+            shard_by_device = {s.device: s.data for s in recv.addressable_shards}
+            landings = []
+            with allocating():
+                for q in range(m):
+                    used = int(sub_sizes[:, q].sum())
+                    if used:
+                        prefix = self._received_prefix(shard_by_device[sub_devices[q]], used)
+                        _start_landing(prefix)
+                        landings.append((j * m + q, q, prefix))
+            recv_sizes.copy_to_host_async()
+            return landings, recv_sizes
+
+        def drain_chunk(rnd, chunk, nchunks, ticket):
+            """A sub-exchange's received rows as host views, one a consumer
+            that received any (the drain worker at depth > 1).  Span
+            ``exchange.recover.d2h``: the wait until they are host-readable."""
+            landings, recv_sizes = ticket
+            with span(
+                "exchange.recover.d2h",
+                shuffle_id=shuffle_id, round=rnd, wave=pairs[rnd][chunk],
+                bytes=sum(prefix.nbytes for _, _, prefix in landings),
+            ):
+                sizes_host = np.asarray(recv_sizes)  # [consumer, sender]
+                return [
+                    (c, np.asarray(prefix)[: int(sizes_host[q].sum())].reshape(-1).view(np.uint8))
+                    for c, q, prefix in landings
                 ]
-                if mode == "memmap":
-                    with span("exchange.d2h_memmap", shuffle_id=shuffle_id, round=rnd):
-                        shards = self._memmap_round(meta, rnd, iter(assembled))
-                else:
-                    shards = assembled
-                recv_mat = full_sizes.T.astype(np.int32).copy()
-                meta.recv_shards.append(shards)
-                meta.recv_sizes.append(recv_mat)
-                active = int(np.count_nonzero(recv_mat))
-                self.stats.record_rows("exchange.lanes", active, recv_mat.size - active)
-                subexchanges += dispatched
-                if round_span is not None:
-                    round_span.args["subexchanges"] = dispatched
+
+        def finish_round(rnd, nchunks, parts):
+            """A re-run round's recovered shards: each consumer's parts in
+            chunk order — ascending senders' wave, the sender-major layout of
+            the full mesh.  The landing pool's allocator is the calling
+            thread's, and this is the drain worker: it is taken here, or
+            every recovered shard is a fresh mapping."""
+            consumer_parts: List[List[np.ndarray]] = [[] for _ in range(n)]
+            for chunk_parts in parts:
+                for c, part in chunk_parts:
+                    consumer_parts[c].append(part)
+            with allocating():
+                shards = [
+                    np.concatenate(ps) if ps else np.empty(0, dtype=np.uint8)
+                    for ps in consumer_parts
+                ]
+            if mode == "memmap":
+                with span("exchange.d2h_memmap", shuffle_id=shuffle_id, round=rnd):
+                    shards = self._memmap_round(meta, rnd, iter(shards))
+            ended[rnd] = time.perf_counter_ns()
+            return rnd, shards
+
+        def used_rows(result):
+            return int(sizes[result[0]].sum())
+
+        planned = time.perf_counter_ns()
+        results = dict(
+            execute_plan(
+                plan,
+                submit=submit,
+                drain_chunk=drain_chunk,
+                finish_round=finish_round,
+                result_bytes=lambda r: used_rows(r) * self.row_bytes,
+                occupancy=lambda r: (
+                    used_rows(r), len(pairs[r[0]]) * m * bucketed - used_rows(r)
+                ),
+                stats=self.stats,
+                # its own aggregator and spans: ``exchange.pipeline`` is the
+                # full mesh's rounds, and its readers see only those
+                name="exchange.recover.pipeline",
+            )
+        )
+        meta.recv_shards, meta.recv_sizes = [], []
+        for rnd in range(num_rounds):
+            # a round in which no executor had a row dispatched nothing
+            empty = [np.empty(0, dtype=np.uint8) for _ in range(n)]
+            meta.recv_shards.append(results.get(rnd, empty))
+            recv_mat = np.ascontiguousarray(sizes[rnd, :n, :n].T, dtype=np.int32)
+            meta.recv_sizes.append(recv_mat)
+            active = int(np.count_nonzero(recv_mat))
+            self.stats.record_rows("exchange.lanes", active, recv_mat.size - active)
+        recovered = {f"recover_{k}": sum(t[k] for t in tallies) for k in tallies[0]}
+        if TRACER.active:
+            # Span ``exchange.recover.round``, once a re-run staging round,
+            # from its clock marks — rounds overlap in the pipeline: the
+            # first submit of the round to the end of its ``finish_round``.
+            # ``subexchanges``: the collectives it dispatched on the shrunk
+            # mesh (a wave pair that carries no row dispatches none).
+            recover_span = TRACER.current_context()
+            for rnd in range(num_rounds):
+                t_began = began[rnd] or planned
+                TRACER.record_spans(
+                    recover_span,
+                    [("exchange.recover.round", t_began, ended[rnd] or t_began)],
+                    args={"shuffle_id": shuffle_id, "round": rnd,
+                          "subexchanges": len(pairs[rnd]), **tallies[rnd]},
+                )
         meta.exchanged = True
         recover_ns = time.monotonic_ns() - t0
         recovery_ms = recover_ns / 1e6
@@ -1243,8 +1389,10 @@ class TpuShuffleCluster:
             self.elastic_stats["degraded_mesh"] = (m, tuple(phys))
             self.elastic_stats["restaged_blocks"] += restaged_blocks
             self.elastic_stats["restaged_bytes"] += restaged_bytes
-            self.elastic_stats["degraded_subexchanges"] += subexchanges
+            self.elastic_stats["degraded_subexchanges"] += plan.num_subrounds
             self.elastic_stats["recover_ns"] += recover_ns
+            for key, value in recovered.items():
+                self.elastic_stats[key] += value
         op.mark_done()
         self.stats.record("exchange.recovery", op)
         instant(

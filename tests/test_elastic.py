@@ -403,6 +403,186 @@ class TestElasticRecovery:
 
 
 # ---------------------------------------------------------------------------
+# the re-run through the plan executor: views, the one copy, zero pieces
+# ---------------------------------------------------------------------------
+
+#: (executors, the one lost, staging bytes an executor, pipeline depth): a
+#: region of 32 rows is the shrunk mesh's slot and every wave is whole — a
+#: sender's piece is a view; a region of 24 rows is not the slot (32); three
+#: executors on two survivors leave a last wave of one — both take the one copy
+GEOMETRIES = {
+    "pow2-slot": (4, 2, 4 * 4096, 2),
+    "pow2-slot-serial": (4, 2, 4 * 4096, 1),
+    "odd-slot": (4, 2, 4 * 3072, 2),
+    "short-last-wave": (3, 1, 3 * 4096, 2),
+}
+
+
+def _shards_and_sizes(cluster, shuffle_id=0):
+    """Every round's received shards cut to the rows received (the full mesh
+    keeps a shard's bucketed prefix, the recovery its exact rows: a reader
+    looks no further), and the size matrices."""
+    meta = cluster.meta(shuffle_id)
+    row = cluster.row_bytes
+    shards = [
+        [bytes(np.asarray(shard)[: int(sizes[c].sum()) * row]) for c, shard in enumerate(rnd_shards)]
+        for rnd_shards, sizes in zip(meta.recv_shards, meta.recv_sizes)
+    ]
+    return shards, [sizes.tolist() for sizes in meta.recv_sizes]
+
+
+def _pieces_by_geometry(cluster, shuffle_id=0):
+    """(sub-exchanges, pieces that carry a row) of a recovered shuffle, from
+    its size matrices and the shrunk mesh alone."""
+    n = cluster.num_executors
+    m, _phys = cluster.elastic_stats["degraded_mesh"]
+    waves = -(-n // m)
+    subexchanges = carrying = 0
+    for recv_mat in cluster.meta(shuffle_id).recv_sizes:  # [consumer, sender]
+        for i in range(waves):
+            for j in range(waves):
+                rows = recv_mat[j * m : (j + 1) * m, i * m : (i + 1) * m].sum(axis=0)
+                subexchanges += bool(rows.sum())
+                carrying += int(np.count_nonzero(rows))
+    return subexchanges, carrying
+
+
+class TestTheRerunThroughThePlanExecutor:
+    @pytest.mark.parametrize("mode", ["array", "memmap"])
+    @pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+    def test_the_recovered_shards_are_the_undisturbed_runs_byte_for_byte(self, geometry, mode):
+        n, lost, staging, depth = GEOMETRIES[geometry]
+        M, R = 3 * n, 2 * n
+        conf = dict(staging_capacity_per_executor=staging, host_recv_mode=mode, pipeline_depth=depth)
+        whole = _mk_cluster(n, **conf)
+        _run_shuffle(whole, whole.create_shuffle(0, M, R), 0, M, R)
+        cluster = _mk_cluster(n, **conf)
+        _run_shuffle(cluster, cluster.create_shuffle(0, M, R), 0, M, R, kill=lost)
+        assert _shards_and_sizes(cluster) == _shards_and_sizes(whole)
+        stats = cluster.elastic_stats
+        assert stats["recoveries"] == 1 and stats["degraded_mesh"][0] == 2
+        subexchanges, carrying = _pieces_by_geometry(cluster)
+        assert stats["degraded_subexchanges"] == subexchanges > len(cluster.meta(0).recv_sizes)
+        assert stats["recover_zero_pieces"] == 2 * subexchanges - carrying
+        piece_bytes = 2 * 32 * cluster.row_bytes  # two slots of the 32-row bucket
+        if geometry.startswith("pow2-slot"):
+            assert stats["recover_copied_bytes"] == 0
+            assert stats["recover_direct_bytes"] == carrying * piece_bytes
+        else:
+            assert stats["recover_direct_bytes"] < carrying * piece_bytes
+            assert stats["recover_direct_bytes"] + stats["recover_copied_bytes"] == carrying * piece_bytes
+            assert stats["recover_copied_bytes"] > 0
+        text = cluster.metrics_text()
+        for name in ("recover_direct_bytes", "recover_copied_bytes", "recover_zero_pieces"):
+            assert f"sparkucx_tpu_elastic_{name} {stats[name]}" in text, name
+
+    def test_the_rerun_is_pipelined_under_its_own_name(self, groupbytest, tracer):
+        """Sub-exchange k + 1 is submitted before k has drained; the re-run's
+        spans and aggregator are ``exchange.recover.pipeline`` and its
+        children, and nothing is recorded under the full mesh's names
+        (``exchange.pipeline.*``, ``exchange.h2d``, ``exchange.d2h``) once the
+        exchange has been aborted."""
+        records = groupbytest.records(MAPPERS, seed=47)
+        with _manager() as mgr:
+            _run_job(mgr, groupbytest, records, 0)
+            events = [ev for ev in tracer.events if ev.get("ph") == "X"]
+            stats = dict(mgr.cluster.elastic_stats)
+            ops = mgr.cluster.stats
+            full = ops.summary("exchange.pipeline.submit"), ops.summary("exchange.pipeline.drain")
+            rerun = ops.summary("exchange.recover.pipeline.submit"), ops.summary("exchange.recover.pipeline.drain")
+        named = lambda name: sorted((ev for ev in events if ev["name"] == name), key=lambda ev: ev["ts"])
+        end = lambda ev: ev["ts"] + ev["dur"]
+        inside = lambda ev, outer: outer["ts"] <= ev["ts"] and end(ev) <= end(outer) + 1
+        [recover] = named("exchange.recover")
+        submits, drains = named("exchange.recover.pipeline.submit"), named("exchange.recover.pipeline.drain")
+        k = stats["degraded_subexchanges"]
+        assert len(submits) == len(drains) == k == rerun[0].ops == rerun[1].ops > 2
+        assert all(submits[i + 1]["ts"] < end(drains[i]) for i in range(k - 1))
+        assert all(inside(ev, recover) for ev in submits)
+        for child in ("exchange.recover.h2d", "exchange.collective.degraded"):
+            assert len(named(child)) == k
+            assert all(any(inside(ev, submit) for submit in submits) for ev in named(child)), child
+        assert len(named("exchange.recover.d2h")) == k
+        assert all(any(inside(ev, drain) for drain in drains) for ev in named("exchange.recover.d2h"))
+        # the full mesh's names saw the rounds submitted before the loss, and nothing since
+        assert full[0].ops == LOST_AT_ROUND and full[1].ops <= LOST_AT_ROUND
+        for name in ("exchange.pipeline.submit", "exchange.pipeline.drain", "exchange.h2d",
+                     "exchange.d2h", "exchange.assemble", "exchange.collective"):
+            assert all(end(ev) <= recover["ts"] + 1 for ev in named(name)), name
+        # the rounds' spans carry what their submits handed the devices
+        reruns = named("exchange.recover.round")
+        for key in ("direct_bytes", "copied_bytes", "zero_pieces"):
+            assert sum(ev["args"][key] for ev in reruns) == stats[f"recover_{key}"], key
+        assert stats["recover_copied_bytes"] == 0 < stats["recover_direct_bytes"]
+
+    @pytest.mark.parametrize("at", [{"round": 0, "chunk": 0}, {"round": 2, "chunk": 1}])
+    def test_a_second_loss_inside_the_rerun_is_refused_typed_and_leaves_no_thread(self, at):
+        import threading
+
+        n, M, R = 4, 12, 8
+        cluster = _mk_cluster(n)
+        meta = cluster.create_shuffle(0, M, R)
+        fired = []
+
+        def second(**ctx):
+            fired.append(ctx)
+            faults.kill_executor(cluster.transport(0))
+
+        def first(**ctx):
+            faults.kill_executor(cluster.transport(2))
+            faults.arm("exchange.recover.submit", second, times=1, match=at)
+
+        faults.arm("exchange.submit", first, times=1, match={"round": 1})
+        rng = np.random.default_rng(3)
+        for m in range(M):
+            t = cluster.transport(meta.map_owner[m])
+            w = t.store.map_writer(0, m)
+            for r in range(R):
+                w.write_partition(r, rng.integers(0, 256, size=2000, dtype=np.uint8).tobytes())
+            t.commit_block(w.commit().pack())
+        with pytest.raises(ExecutorLostError, match="under the degraded re-run") as ei:
+            cluster.run_exchange(0)
+        assert len(fired) == 1 and ei.value.executor_id == 0
+        assert not cluster.meta(0).exchanged and cluster.elastic_stats["recoveries"] == 0
+        assert not [t.name for t in threading.enumerate() if "pipeline" in t.name]
+
+    def test_the_drain_workers_arrays_come_from_the_landing_pool(self, monkeypatch):
+        """With a runtime whose D2H copies (a chip's, patched in as
+        tests/test_recv_landing.py does) the recovered shards — concatenated
+        on the pipeline's drain worker — are the landing pool's, and a second
+        job of the same sizes finds every large array of its recovery among
+        the blocks the first gave back."""
+        import gc
+
+        import sparkucx_tpu.transport.tpu as tpu_mod
+        from sparkucx_tpu.native import LandingPool
+        from test_recv_landing import copying_runtime, get_handler_name  # a chip's copy_to_host_async
+
+        if LandingPool.create(1 << 20) is None:
+            pytest.skip("no landing pool here (no compiler, or no NumPy allocator hook)")
+        monkeypatch.setattr(tpu_mod, "_d2h_copies", lambda device: True)
+        monkeypatch.setattr(tpu_mod, "_start_landing", copying_runtime)
+        monkeypatch.setattr(tpu_mod, "LANDING_MIN_BYTES", 1024)
+        n, M, R = 4, 12, 8
+        cluster = _mk_cluster(n, staging_capacity_per_executor=4 * 8192)
+        pool = cluster._landing()
+        assert pool is not None
+        for sid in range(2):
+            before = pool.stats()
+            meta = cluster.create_shuffle(sid, M, R)
+            _run_shuffle(cluster, meta, sid, M, R, kill=2)
+            assert cluster.elastic_stats["recoveries"] == sid + 1
+            shards = [s for rnd in meta.recv_shards for s in rnd if s.nbytes]
+            assert shards and all(get_handler_name(s) == LandingPool.NAME for s in shards)
+            after = pool.stats()
+            del shards, meta
+            cluster.remove_shuffle(sid)
+            gc.collect()
+            assert cluster.rejoin_executor(2)
+        assert after["hits"] - before["hits"] > 0 and after["misses"] == before["misses"]
+
+
+# ---------------------------------------------------------------------------
 # membership gossip over the peer wire
 # ---------------------------------------------------------------------------
 
