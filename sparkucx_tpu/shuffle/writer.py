@@ -230,11 +230,12 @@ class TpuShuffleMapOutputWriter:
         and return per-partition lengths (Spark's MapOutputCommitMessage)."""
         if self._committed:
             raise TransportError("writer already committed")
-        info = self.map_writer.commit()
+        info = self.map_writer.commit(ends_task=False)
         self._transport.commit_block(info.pack())
         self._committed = True
         if self._on_commit is not None:
             self._on_commit()
+        self.map_writer.end_task()  # span ``write.task``: the commit has shipped
         return self._partition_lengths.copy()
 
     def abort(self, error: Optional[BaseException] = None) -> None:

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import functools
 import gc
+import resource
 import shutil
 import sys
 import threading
@@ -62,7 +63,7 @@ from sparkucx_tpu.core.operation import (
 )
 from sparkucx_tpu.service.eviction import ServeCache
 from sparkucx_tpu.testing import faults
-from sparkucx_tpu.utils.trace import span
+from sparkucx_tpu.utils.trace import TRACER, span
 
 
 #: the largest host buffer ``seal`` hands to one ``device_put``: the default
@@ -72,6 +73,38 @@ SEAL_PUT_PIECE_BYTES = 64 << 20
 #: pieces whose transfer may be outstanding before the next is put: what HBM
 #: holds beside the round itself while it is being put
 SEAL_PUT_PIECES_IN_FLIGHT = 2
+#: under full tracing, the buffered-path blocks of the process recorded by
+#: phase (``write.block`` and its three children): numbers 0, 199, 398, ...
+#: counted over every writer of the process since tracing came on.  A prime
+#: that divides none of the benchmark's blocks a map task (200, 100, 75, 63),
+#: so the sampled reduce ids rotate from task to task; 32 of the 1k job's
+#: 6,350 blocks a job.  At one in 37 (172 a job) the traced write of that job
+#: was 6 ms, 7%, longer on the chip's host (``PERF.md`` section 6, PR 50).
+WRITE_BLOCK_EVERY = 199
+_blocks_traced = 0  # benign race between writer threads: a sampling count
+_WRITE_BLOCK_PHASES = ("write.block.admit", "write.block.copy", "write.block.record")
+#: the calling thread's resource usage, where the platform has it (Linux)
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_counts_faults() -> bool:
+    """Whether a thread's ``ru_minflt`` can be read and the kernel keeps the
+    count at all, asked once a process: a process that has imported NumPy has
+    faulted thousands of pages in, so a count of 0 for the whole process is a
+    kernel that keeps none (a sandboxed one: the chip's host, where the read
+    is a 9 us system call that answers 0 through any first touch; ``PERF.md``
+    section 6, PR 50).  There a task's rise says nothing and is left out."""
+    return _RUSAGE_THREAD is not None and resource.getrusage(resource.RUSAGE_SELF).ru_minflt > 0
+
+
+def _thread_minor_faults() -> Optional[Tuple[int, int]]:
+    """``(thread ident, ru_minflt)`` of the calling thread: page faults that
+    needed no I/O — a first touch of a fresh page is one.  None where the
+    kernel keeps no such count."""
+    if not _kernel_counts_faults():
+        return None
+    return threading.get_ident(), resource.getrusage(_RUSAGE_THREAD).ru_minflt
 
 
 @functools.lru_cache(maxsize=None)
@@ -364,11 +397,24 @@ class MapWriter:
     new reservation is admitted.  A body that never fully arrives leaves its
     extent a hole that no entry names (padding; tenant charge given back) and
     the partition lost: the map cannot commit, so the retry writes it again.
+
+    Tracing (``docs/OBSERVABILITY.md``; PR 50): a committed writer is one span
+    ``write.task``, from its creation to the end of its commit (``end_task``),
+    recorded from clock marks at the commit — nothing is open meanwhile.
+    Under full tracing it has the children ``write.task.copy`` and
+    ``write.task.lock_wait`` (summed spans of ``_copy_ns`` / ``_lock_wait_ns``:
+    no clock is read for them that was not read before), ``write.task.commit``
+    and, one buffered-path block in ``WRITE_BLOCK_EVERY`` of the process,
+    ``write.block`` with the three phases of its ``close_partition``; and the
+    argument ``minor_faults``.  A discarded retry and an aborted writer record
+    nothing.  Untraced, a block pays one ``None`` check at its open and two
+    at its close.
     """
 
     def __init__(
         self, store: "HbmBlockStore", state: _ShuffleState, map_id: int, discard: bool = False
     ) -> None:
+        global _blocks_traced
         self._store = store
         self._state = state
         self.map_id = map_id
@@ -384,6 +430,10 @@ class MapWriter:
         #: lock (other writers' copies and rollovers); joins ``lock_wait_ns``
         #: at ``commit``
         self._lock_wait_ns = 0
+        #: timed copies and takes of the store's lock beside the one of each
+        #: that a buffered block's ``close_partition`` makes (those are counted
+        #: at ``commit``, from the table): the summed spans' ``turns``
+        self._extra_copies = self._extra_lock_takes = 0
         self._counted = False  # this writer's blocks are in the store's counters
         #: the open partition's extent while it is received in place
         self._resv: Optional[_Reservation] = None
@@ -398,8 +448,24 @@ class MapWriter:
         #: check-or-replace protocol (IndexShuffleBlockResolver.scala:161-217:
         #: "if an existing index is valid, keep it and discard this attempt").
         self._discard = discard
+        #: ``write.task``: the clock at this writer's creation, 0 where nothing
+        #: records (both switches off, a discarded retry) and once recorded
+        self._t_open = perf_counter_ns() if (TRACER.recording or TRACER.enabled) and not discard else 0
+        #: under full tracing: the sampled blocks' marks, waiting for the
+        #: commit; the open block's marks where it is sampled; the thread's
+        #: minor faults at creation.  ``None`` untraced: no mark is taken
+        self._blocks: Optional[List[Tuple[int, int, List[int]]]] = None
+        self._block: Optional[List[int]] = None
+        self._faults: Optional[Tuple[int, int]] = None
+        self._task: Optional[tuple] = None  # ``commit``'s marks, for ``end_task``
+        if not TRACER.enabled:
+            _blocks_traced = 0  # tracing is off: the next count starts anew
+        elif self._t_open:
+            self._blocks = []
+            self._faults = _thread_minor_faults()
 
     def open_partition(self, reduce_id: int) -> None:
+        global _blocks_traced
         if self._open_reduce is not None:
             raise TransportError("previous partition still open")
         if reduce_id <= self._last_reduce:
@@ -411,6 +477,10 @@ class MapWriter:
         self._open_reduce = reduce_id
         self._chunks = []
         self._written = 0
+        if self._blocks is not None:
+            n = _blocks_traced
+            _blocks_traced = n + 1
+            self._block = [perf_counter_ns()] if n % WRITE_BLOCK_EVERY == 0 else None
 
     def write(self, data: bytes) -> None:
         if self._open_reduce is None:
@@ -427,11 +497,15 @@ class MapWriter:
                 t0 = perf_counter_ns()
                 self._chunks.append(bytes(data))
                 self._copy_ns += perf_counter_ns() - t0
+                self._extra_copies += 1
         self._written += len(data)
 
     def close_partition(self) -> None:
         if self._open_reduce is None:
             raise TransportError("no open partition")
+        marks = self._block  # a sampled block's clock marks (full tracing)
+        if marks is not None:
+            marks.append(perf_counter_ns())
         if self._resv is not None and self._close_reserved():
             return
         st = self._state
@@ -474,7 +548,8 @@ class MapWriter:
                 for chunk in self._chunks:
                     st.staging[pos : pos + len(chunk)] = np.frombuffer(chunk, dtype=np.uint8)
                     pos += len(chunk)
-                self._copy_ns += perf_counter_ns() - t0
+                t1 = perf_counter_ns()
+                self._copy_ns += t1 - t0
                 st.blocks[(self.map_id, reduce_id)] = _BlockEntry(
                     offset=start, length=self._written, padded=padded, round=st.round
                 )
@@ -482,6 +557,10 @@ class MapWriter:
         self._last_reduce = reduce_id
         self._open_reduce = None
         self._chunks = []
+        if marks is not None:  # sampled only where ``_blocks`` is: never a discard
+            marks += (t0, t1, perf_counter_ns())
+            self._blocks.append((reduce_id, self._written, marks))
+            self._block = None
 
     # -- receive in place (a partition fed from a socket) -------------------
 
@@ -525,6 +604,7 @@ class MapWriter:
         t_lock = perf_counter_ns()
         with store._lock:
             self._lock_wait_ns += perf_counter_ns() - t_lock
+            self._extra_lock_takes += 1
             if st.draining:
                 store._await_drained(st)
             if st.removed:
@@ -603,6 +683,7 @@ class MapWriter:
         t0 = perf_counter_ns()
         self._chunks.insert(0, staging[resv.start : resv.start + resv.filled].tobytes())
         self._copy_ns += perf_counter_ns() - t0
+        self._extra_copies += 1
         self._store._release_tenant(st, resv.padded)
         self._resv = None
         self._inplace_fallbacks += 1
@@ -618,6 +699,7 @@ class MapWriter:
         t_lock = perf_counter_ns()
         with self._store._lock:
             self._lock_wait_ns += perf_counter_ns() - t_lock
+            self._extra_lock_takes += 1
             if st.removed:
                 raise TransportError(f"unknown shuffle {st.shuffle_id}")
             if st.sealed:
@@ -633,6 +715,7 @@ class MapWriter:
         self._resv = None
         self._last_reduce = self._open_reduce
         self._open_reduce = None
+        self._block = None  # received in place: the daemon's phases, no ``write.block``
         return True
 
     def write_partition(self, reduce_id: int, data: bytes) -> None:
@@ -751,21 +834,31 @@ class MapWriter:
                 self._store._stage_device(st, packed, run)
         self._last_reduce = last
 
-    def commit(self) -> MapperInfo:
+    def commit(self, ends_task: bool = True) -> MapperInfo:
         """Commit this map task's outputs — the ``commitAllPartitions`` packing
         (NvkvShuffleMapOutputWriter.scala:116-148).  Returns the MapperInfo blob
         object the transport ships as AM id 2.  For a retry attempt (discard
-        mode) this returns the FIRST successful attempt's table."""
+        mode) this returns the FIRST successful attempt's table.
+
+        ``ends_task``: the span ``write.task`` ends with this call; a caller
+        that ships the commit passes False and calls ``end_task`` once it has
+        shipped (``TpuShuffleMapOutputWriter.commit_all_partitions``)."""
         if self._open_reduce is not None:
             raise TransportError("commit with open partition")
+        t_commit = perf_counter_ns() if self._t_open else 0
         st = self._state
         parts, rounds = [], []
-        blocks = 0
+        blocks = nbytes = 0
         for r in range(st.num_reducers):
             e = st.blocks.get((self.map_id, r))
-            parts.append((e.offset, e.length) if e is not None else (0, 0))
-            rounds.append(e.round if e is not None else 0)
-            blocks += e is not None
+            if e is None:
+                parts.append((0, 0))
+                rounds.append(0)
+            else:
+                parts.append((e.offset, e.length))
+                rounds.append(e.round)
+                blocks += 1
+                nbytes += e.length
         with self._store._lock:
             st.committed_maps.add(self.map_id)
             # once a writer; a retry's table is the first attempt's, counted then
@@ -773,7 +866,7 @@ class MapWriter:
                 self._counted = True
                 counters = self._store._write_stats
                 counters["staged_blocks"] += blocks
-                counters["staged_bytes"] += sum(length for _, length in parts)
+                counters["staged_bytes"] += nbytes
                 counters["largest_block_bytes"] = max(
                     counters["largest_block_bytes"], max((length for _, length in parts), default=0)
                 )
@@ -782,11 +875,62 @@ class MapWriter:
                 counters["inplace_blocks"] += self._inplace_blocks
                 counters["inplace_bytes"] += self._inplace_bytes
                 counters["inplace_fallbacks"] += self._inplace_fallbacks
+        if t_commit:
+            # a buffered block is one copy and one take; a shuffle is staged
+            # on the host or on the device, never both
+            buffered = 0 if st.device_mode else blocks - self._inplace_blocks
+            self._task = (
+                t_commit, blocks, nbytes,
+                self._copy_ns, buffered + self._extra_copies,
+                self._lock_wait_ns, buffered + self._extra_lock_takes,
+            )
+            if ends_task:
+                self.end_task()
         self._copy_ns = self._lock_wait_ns = 0
         return MapperInfo(
             st.shuffle_id, self.map_id, tuple(parts),
             tuple(rounds) if any(rounds) else None,
         )
+
+    def end_task(self) -> None:
+        """The committed task's interval ends here: ``write.task`` and, under
+        full tracing, its children go to the tracer in one call, from the
+        marks this writer took (class docstring).  The summed spans are laid
+        end to end from the task's open, as ``read.window.decode`` is; one
+        without a turn is left out.  Nothing to do for a writer that recorded
+        no marks, or has handed them over."""
+        task, self._task = self._task, None
+        t_open, self._t_open = self._t_open, 0
+        if task is None or not TRACER.active:
+            return
+        t_commit, blocks, nbytes, copy_ns, copies, lock_ns, lock_takes = task
+        t_end = perf_counter_ns()
+        args = {
+            "shuffle_id": self._state.shuffle_id, "map_id": self.map_id,
+            "executor": self._store.executor_id, "blocks": blocks, "bytes": nbytes,
+        }
+        children: List[tuple] = []
+        if self._blocks is not None and TRACER.enabled:
+            before, now = self._faults, _thread_minor_faults()
+            if before is not None and before[0] == now[0]:  # one thread's count
+                args["minor_faults"] = now[1] - before[1]
+            t = t_open
+            for name, ns, turns in (
+                ("write.task.copy", copy_ns, copies),
+                ("write.task.lock_wait", lock_ns, lock_takes),
+            ):
+                if turns:
+                    children.append((name, t, t + ns, {"turns": turns}))
+                    t += ns
+            for reduce_id, length, (t_in, t_close, t_copy, t_copied, t_out) in self._blocks:
+                cuts = (t_close, t_copy, t_copied, t_out)
+                children.append((
+                    "write.block", t_in, t_out, {"reduce_id": reduce_id, "bytes": length},
+                    list(zip(_WRITE_BLOCK_PHASES, cuts, cuts[1:])),
+                ))
+            children.append(("write.task.commit", t_commit, t_end))
+        self._blocks = None
+        TRACER.record_spans(None, (("write.task", t_open, t_end, args, children),))
 
     @property
     def is_retry_discard(self) -> bool:
@@ -1476,7 +1620,10 @@ class HbmBlockStore:
             self._write_stats["pool_held_bytes"] -= nbytes
             return free.pop()
         self._write_stats["pool_misses"] += 1
-        return np.zeros(nbytes, dtype=np.uint8)
+        # fresh pages: the copies that touch them first pay the faults
+        # (``write.task``'s ``minor_faults``), not this call
+        with span("store.round_buffer.fresh", executor=self.executor_id, bytes=nbytes):
+            return np.zeros(nbytes, dtype=np.uint8)
 
     def _detach_host_rounds(self, st: _ShuffleState) -> List[Tuple[np.ndarray, np.ndarray]]:
         """Take a removed shuffle's private host round buffers out of its

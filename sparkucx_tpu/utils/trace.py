@@ -45,6 +45,9 @@ ring's lock, one ordinary "X" event a triple (``trace_id`` the parent's,
 ``parent_id`` its ``span_id``; a root each with ``parent=None``).  The events
 come from the builder every span's event comes from (``_event``), so export,
 ``merge_events``, TRACE_PULL and the flight recorder cannot tell them apart.
+A mark may carry its own ``args`` and marks of its own (PR 50): an interval
+that was never a span — a map task, ``write.task`` — goes to the ring with its
+phases under it in the same call.
 A **summed span** is such a triple whose turns interleave with another's
 record by record (a decoder against its consumer): ``t1 - t0`` is the SUM of
 its turns and ``t0`` lays it end to end after the parent's last real child, so
@@ -58,6 +61,7 @@ slotted context manager, not a generator.
 from __future__ import annotations
 
 import atexit
+import ctypes
 import itertools
 import json
 import os
@@ -90,6 +94,21 @@ def _read_pid() -> None:
 
 _read_pid()
 os.register_at_fork(after_in_child=_read_pid)
+
+#: ``PyObject_GC_UnTrack`` of the C API (CPython; None elsewhere): takes an
+#: object out of the cycle collector's lists.  An event that holds an ``args``
+#: dict is a dict holding a dict, which the collector tracks for good and walks
+#: at every full collection — 250 ns an event, and a traced window's ring holds
+#: some 300,000: a traced 1k job's write grew by a quarter over its window
+#: while the ring filled (``PERF.md`` section 6, PR 50).  An event cannot be
+#: part of a cycle (nothing it holds refers back to it) and is freed by its
+#: reference count when the ring drops it, so ``_event`` untracks it; one
+#: without ``args`` holds atoms only and was never tracked.
+try:
+    _gc_untrack = ctypes.pythonapi.PyObject_GC_UnTrack
+    _gc_untrack.argtypes, _gc_untrack.restype = [ctypes.py_object], None
+except AttributeError:  # pragma: no cover - not CPython: events stay tracked
+    _gc_untrack = None
 
 
 @dataclass(slots=True)
@@ -184,9 +203,26 @@ def _event(
     }
     if args:
         ev["args"] = args
+        if _gc_untrack is not None:
+            _gc_untrack(ev)  # what is stored after this is an atom
     if eid is not None:
         ev["eid"] = eid
     return ev
+
+
+def _lay(out: List[dict], marks, category, args, tid, eid, trace_id: int, parent_id: int) -> None:
+    """``record_spans``: one event a mark onto ``out``, a mark's own marks
+    under it.  A function of the module, not a closure of the call: one that
+    calls itself would be a cycle holding the call's events until the
+    collector ran."""
+    for mark in marks:
+        trace, span_id = trace_id or _new_id(), _new_id()
+        out.append(_event(
+            mark[0], category, mark[1], mark[2] - mark[1], tid, trace, span_id, parent_id,
+            mark[3] if len(mark) > 3 else args, eid,
+        ))
+        if len(mark) > 4:
+            _lay(out, mark[4], category, args, tid, eid, trace, span_id)
 
 
 class Tracer:
@@ -327,7 +363,7 @@ class Tracer:
     def record_spans(
         self,
         parent: Optional[SpanCtx],
-        marks: Iterable[Tuple[str, int, int]],
+        marks: Iterable[tuple],
         category: str = "shuffle",
         args: Optional[Dict[str, object]] = None,
     ) -> None:
@@ -337,15 +373,16 @@ class Tracer:
         root each, a trace of its own), all appended under ONE take of the
         ring's lock.  For the phases of an interval too short to open a span
         a phase (module docstring); ``args`` — e.g. a summed span's ``turns`` —
-        go on every event of the call.  The caller checks ``enabled`` or
-        ``active`` as its parent span's site does; nothing is checked here."""
-        tid = threading.get_ident() & 0xFFFFFFFF
-        eid = self._tls.eid
+        go on every event of the call.  A mark may be longer than a triple:
+        ``(name, t0_ns, t1_ns, own_args)`` carries its own ``args`` in place
+        of the call's, and ``(name, t0_ns, t1_ns, own_args, children)`` is an
+        interval that is itself made from marks, the parent of the marks in
+        ``children`` (a map task with its phases: still one call, one take of
+        the lock).  The caller checks ``enabled`` or ``active`` as its parent
+        span's site does; nothing is checked here."""
+        new: List[dict] = []
         trace_id, parent_id = (parent.trace_id, parent.span_id) if parent is not None else (0, 0)
-        new = [
-            _event(name, category, t0, t1 - t0, tid, trace_id or _new_id(), _new_id(), parent_id, args, eid)
-            for name, t0, t1 in marks
-        ]
+        _lay(new, marks, category, args, threading.get_ident() & 0xFFFFFFFF, self._tls.eid, trace_id, parent_id)
         with self._lock:
             events = self._events
             over = len(events) + len(new) - events.maxlen

@@ -147,6 +147,31 @@ class TestMarksToSpans:
         t.record_spans(ctx, [("window.decode", 10, 20), ("window.consumer", 20, 50)], args={"turns": 7})
         assert [e.get("args") for e in t.events] == [None, {"turns": 7}, {"turns": 7}]
 
+    def test_a_mark_may_carry_its_own_args_and_marks_of_its_own(self):
+        """PR 50: an interval that was never a span goes to the ring with its
+        phases under it, in one call — ids and the trace run down the tree, a
+        mark's own args take the place of the call's."""
+        t = Tracer(enabled=True)
+        phases = [("block.admit", 12, 13), ("block.copy", 13, 19)]
+        task = ("task", 0, 100, {"map_id": 4}, [
+            ("task.copy", 0, 30, {"turns": 9}), ("block", 10, 20, None, phases), ("task.commit", 90, 100)])
+        t.record_spans(None, [task, ("turn", 200, 300)], args={"of": "the call"})
+        by_name = {e["name"]: e for e in t.events}
+        assert [e["name"] for e in t.events] == [
+            "task", "task.copy", "block", "block.admit", "block.copy", "task.commit", "turn"]
+        root = by_name["task"]
+        assert root["parent_id"] == 0 and root["args"] == {"map_id": 4}
+        for child in ("task.copy", "block", "task.commit"):
+            assert by_name[child]["parent_id"] == root["span_id"]
+        for phase in ("block.admit", "block.copy"):
+            assert by_name[phase]["parent_id"] == by_name["block"]["span_id"]
+        assert {e["trace_id"] for e in t.events if e["name"] != "turn"} == {root["trace_id"]}
+        assert by_name["turn"]["trace_id"] != root["trace_id"]  # a root each, as ever
+        # own args where the mark has them (None too), the call's where it is a triple
+        assert by_name["task.copy"]["args"] == {"turns": 9} and "args" not in by_name["block"]
+        assert by_name["task.commit"]["args"] == by_name["turn"]["args"] == {"of": "the call"}
+        assert len({e["span_id"] for e in t.events}) == 7
+
     def test_a_full_ring_counts_what_a_bulk_call_pushes_out(self):
         t = Tracer(enabled=True, capacity=4)
         ctx = t.start_span("frame")
@@ -179,6 +204,45 @@ class TestSpanCost:
         t.end_span(ctx)
         t.record_spans(ctx, [("c", 1, 2)])
         assert not calls and len(t.events) == 4
+
+    def test_the_ring_is_not_the_collectors_to_walk(self):
+        """PR 50: an event with ``args`` is a dict holding a dict, which the
+        cycle collector would track for good and walk at every full
+        collection — a traced window's ring holds hundreds of thousands.
+        ``_event`` takes it out of the collector's lists (it can be part of
+        no cycle); one without ``args`` holds atoms and was never in them.
+        Both are freed by their reference counts when the ring lets go."""
+        import gc
+        import weakref
+
+        t = Tracer(enabled=True, capacity=4)
+        with t.executor_scope(3):
+            with t.span("a", shuffle_id=1):
+                t.instant("i", x=1)
+            ctx = t.start_span("b")
+            t.end_span(ctx, blocks=2)
+            t.record_spans(ctx, [("c", 1, 2, {"turns": 5}), ("d", 2, 3)])
+        spans = [e for e in t.events if e["ph"] == "X"]
+        assert [e["name"] for e in spans] == ["a", "b", "c", "d"]
+        assert [e.get("args") for e in spans] == [{"shuffle_id": 1}, {"blocks": 2}, {"turns": 5}, None]
+        assert all(e["eid"] == 3 for e in spans)
+        assert not any(gc.is_tracked(e) for e in spans)
+
+        class Held:
+            pass
+
+        # what an event holds goes when the ring drops the event, no collection needed
+        held = Held()
+        gone = weakref.ref(held)
+        t.record_spans(None, [("e", 1, 2, {"held": held})])
+        del held
+        assert gone() is not None
+        gc.disable()
+        try:
+            t.record_spans(None, [("f%d" % i, i, i + 1) for i in range(4)])  # the ring is 4 long
+            assert gone() is None
+        finally:
+            gc.enable()
 
     def test_a_forked_child_reads_its_pid_again(self, monkeypatch):
         # this process's pid and id counter come back when the test ends
