@@ -51,6 +51,13 @@ from __future__ import annotations
 #:   ``._charge_tenant`` above).  The count and the condition are the store's
 #:   own bookkeeping of who may touch a round's buffer, not writer API.
 #:   Reviewed with the receive-in-place PR.
+#: - hbm_store.py ``._put_behind``: the same friend, after a block is recorded
+#:   (``close_partition``) or a receive in place has ended (``end_receive``)
+#:   — the writer whose block took a region past the end of a piece puts that
+#:   piece of the single round on the store's device, on its own thread and
+#:   OUTSIDE the store's lock.  Who may run the donated update chain, and
+#:   when a piece's bytes are final, is the store's bookkeeping, not writer
+#:   API.  Reviewed with the put-behind-the-writer PR (PR 51).
 #: - service/tenants.py ``._gate``: ``Tenant`` is a same-file data holder of
 #:   its ``TenantRegistry`` — the registry lazily creates the per-tenant
 #:   CreditGate under its own lock; exposing the slot publicly would invite
@@ -121,6 +128,7 @@ ALLOWLIST = {
     ("store/hbm_store.py", "private-access", "._release_tenant"),
     ("store/hbm_store.py", "private-access", "._await_drained"),
     ("store/hbm_store.py", "private-access", "._receive_ended"),
+    ("store/hbm_store.py", "private-access", "._put_behind"),
     ("store/hbm_store.py", "private-access", "._stage_device"),
     ("store/hbm_store.py", "private-access", "._staging"),
     ("store/hbm_store.py", "private-access", "._write_stats"),

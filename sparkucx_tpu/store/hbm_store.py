@@ -63,12 +63,16 @@ from sparkucx_tpu.core.operation import (
 )
 from sparkucx_tpu.service.eviction import ServeCache
 from sparkucx_tpu.testing import faults
+from sparkucx_tpu.utils.logging import get_logger
 from sparkucx_tpu.utils.trace import TRACER, span
+
+logger = get_logger("store.hbm_store")
 
 
 #: the largest host buffer ``seal`` hands to one ``device_put``: the default
 #: staging capacity, so a default-conf round is one put as ever; a larger
-#: single round is put in pieces of this size (``HbmBlockStore._put_round``)
+#: single round is put in pieces of this size (``HbmBlockStore._put_round``),
+#: each as soon as the writers have passed it (``_PutBehind``)
 SEAL_PUT_PIECE_BYTES = 64 << 20
 #: pieces whose transfer may be outstanding before the next is put: what HBM
 #: holds beside the round itself while it is being put
@@ -240,16 +244,117 @@ class _BlockEntry:
     local: bool = True
 
 
-@dataclass
+@dataclass(eq=False)
 class _Reservation:
     """The extent an open partition holds in a round's staging while its
     frames are received in place (``MapWriter.reserve``).  It belongs to the
-    round it was made in: a rollover does not move it."""
+    round it was made in: a rollover does not move it.  One extent is one
+    object (``_PutBehind.open`` keeps the open ones by identity)."""
 
     round: int  # the staging round it was made in
     start: int  # absolute offset in the round's buffer
     padded: int  # bytes of the region it takes (a multiple of the alignment)
     filled: int = 0  # bytes received into it so far
+
+
+class _PutBehind:
+    """A single round on its way to the store's device while it is still
+    being written: the state of ``_put_round``'s update chain, kept from the
+    first piece whose bytes are final to the seal (PR 51).
+
+    A store that will seal a shuffle's one round straight onto its device —
+    it has a device, the staging round is larger than one
+    ``SEAL_PUT_PIECE_BYTES`` piece and is the store's own buffer, one its
+    free list handed the shuffle (pages the process holds), and as long as
+    nothing rolled over and the writes are host writes — does not wait for
+    the seal: a piece is put as soon as it lies wholly below its region's
+    ``region_used``, and below the rows that every partition still open for
+    a receive in place has filled (``open``), while no receive in place is in
+    flight into the round.
+    Such a piece holds its final bytes: ``region_used`` only grows within a
+    round, a block once copied is never rewritten, and a retried map's or an
+    abandoned reservation's extent stays behind as padding.  ``reserve``
+    moves ``region_used`` by the PADDED total before a byte is there, and the
+    partition's next frame is received from its UNPADDED end — into the last
+    row of the frame before it, below ``region_used`` — so an extent counts
+    only up to its last whole row received until the partition is recorded,
+    lost or sent back to the buffered path.  The pieces are
+    ``_put_round``'s own (whole pieces at the same offsets, at most
+    ``SEAL_PUT_PIECES_IN_FLIGHT`` awaiting their transfer), so the seal puts
+    what is left — the piece each writer stands in, a piece that straddles
+    two regions — and hands ``buf`` over: the same bytes cross once, earlier.
+
+    ONE owner at a time runs the donated chain ``buf = update(buf, piece,
+    at)``: the thread whose ``close_partition`` (or ``end_receive``) passed a
+    piece's end claims the pieces that are final under the store's lock
+    (``owner``), puts them OUTSIDE it, and looks again before it lets go, so
+    a writer that passes a piece meanwhile leaves it to the owner and goes
+    on copying.  ``seal``, ``remove_shuffle`` and ``close`` wait for the
+    owner on the store's condition.  A rollover, a removal and a put that
+    raised take the state off the shuffle (``_drop_put_behind``): the device
+    buffer is let go and the shuffle goes on as if nothing had been put.
+
+    ``cursor[p]`` is the next piece (its index in the round) of region
+    ``p``'s queue — the pieces that START in region ``p``, in order — and
+    ``next_end[p]`` the absolute staging offset the region's used prefix
+    must reach for that piece to be final: ``sys.maxsize`` once the queue is
+    through or its next piece reaches into the next region.  A writer
+    compares one integer a block.  All fields but ``buf`` and ``in_flight``
+    are read and written under the owning store's lock; those two belong to
+    the owner (to ``seal`` once it has waited the owner out)."""
+
+    __slots__ = (
+        "rows", "piece_rows", "region_rows", "alignment", "cursor", "next_end",
+        "open", "owner", "buf", "in_flight",
+    )
+
+    def __init__(self, regions: int, region_rows: int, piece_rows: int, alignment: int) -> None:
+        self.rows, self.piece_rows = regions * region_rows, piece_rows
+        self.region_rows, self.alignment = region_rows, alignment
+        self.cursor = [-(-p * region_rows // piece_rows) for p in range(regions)]
+        self.next_end = [0] * regions
+        for p in range(regions):
+            self._aim(p)
+        #: the extents of the partitions being received in place into the
+        #: round: from a partition's first ``reserve`` until it is recorded,
+        #: lost or back on the buffered path
+        self.open: set = set()
+        self.owner = False  # a thread is putting claimed pieces outside the lock
+        self.buf = None  # the round on the device: zeros but for the pieces put
+        self.in_flight: deque = deque()  # pieces whose transfer may be outstanding
+
+    def _aim(self, p: int) -> None:
+        at = self.cursor[p] * self.piece_rows
+        end = min(at + self.piece_rows, self.rows)
+        inside = at < end <= (p + 1) * self.region_rows
+        self.next_end[p] = end * self.alignment if inside else sys.maxsize
+
+    def claim(self, p: int) -> int:
+        """Region ``p``'s next piece, taken: the cursor moves past it."""
+        k = self.cursor[p]
+        self.cursor[p] = k + 1
+        self._aim(p)
+        return k
+
+    def final_marks(self, region_used) -> List[int]:
+        """Per region, the absolute staging offset below which every byte is
+        final: its used prefix, held below the first row that a partition
+        still open for a receive in place has not wholly received."""
+        region_bytes = self.region_rows * self.alignment
+        marks = [p * region_bytes + int(used) for p, used in enumerate(region_used)]
+        for resv in self.open:
+            p = resv.start // region_bytes
+            marks[p] = min(marks[p], resv.start + resv.filled // self.alignment * self.alignment)
+        return marks
+
+    def is_put(self, at: int) -> bool:
+        """Whether the piece that starts at row ``at`` was claimed."""
+        return at // self.piece_rows < self.cursor[at // self.region_rows]
+
+    def release(self) -> None:
+        """Let the device buffer and the pieces go (no owner is active)."""
+        self.buf = None
+        self.in_flight.clear()
 
 
 class _ShuffleState:
@@ -332,6 +437,29 @@ class _ShuffleState:
         #: while one drains, so a stream of writers cannot starve it
         #: (under the owning store's ``_lock``)
         self.draining = 0
+        #: the round's pieces put behind the writers, where the store will
+        #: seal this shuffle's one round onto its device (``_PutBehind``):
+        #: set at the staging's first touch (``HbmBlockStore._take_staging``);
+        #: None before and otherwise, after a rollover, and once sealed or removed
+        #: (under the owning store's ``_lock``)
+        self.put_behind: Optional[_PutBehind] = None
+
+    def pieces(self) -> Optional[_PutBehind]:
+        """The staging round as ``SEAL_PUT_PIECE_BYTES`` pieces, nothing put
+        yet; None for a round of one piece."""
+        region_rows = self.region_size // self.alignment
+        piece_rows = SEAL_PUT_PIECE_BYTES // self.alignment
+        if len(self.peer_ranges) * region_rows <= piece_rows:
+            return None
+        return _PutBehind(len(self.peer_ranges), region_rows, piece_rows, self.alignment)
+
+    def settled(self, resv: _Reservation) -> None:
+        """``resv``'s partition was recorded, lost or sent back to the
+        buffered path (caller holds the owning store's ``_lock``): nothing
+        more is received into its extent, which no longer holds a put
+        cursor."""
+        if self.put_behind is not None:
+            self.put_behind.open.discard(resv)
 
     @property
     def staging(self) -> Optional[np.ndarray]:
@@ -409,6 +537,13 @@ class MapWriter:
     argument ``minor_faults``.  A discarded retry and an aborted writer record
     nothing.  Untraced, a block pays one ``None`` check at its open and two
     at its close.
+
+    Behind the writer (``_PutBehind``; PR 51): where the store will seal the
+    shuffle's single round onto its device in pieces, the ``close_partition``
+    (or ``end_receive``) that takes a region's used prefix past the end of a
+    piece puts that piece from this thread, after the block is recorded and
+    outside the store's lock (``HbmBlockStore._put_behind``).  Anywhere else
+    a block pays one more ``None`` check under the lock.
     """
 
     def __init__(
@@ -511,6 +646,7 @@ class MapWriter:
         st = self._state
         reduce_id = self._open_reduce
         peer = st.owner_of(reduce_id)
+        passed = False  # this block took its region past the end of a piece to put
         if not self._discard:
             padded = -(-self._written // st.alignment) * st.alignment
             # watermark gate before taking the lock: a shed write fails typed
@@ -554,6 +690,8 @@ class MapWriter:
                     offset=start, length=self._written, padded=padded, round=st.round
                 )
                 st.region_used[peer] += padded
+                behind = st.put_behind
+                passed = behind is not None and start + padded >= behind.next_end[peer]
         self._last_reduce = reduce_id
         self._open_reduce = None
         self._chunks = []
@@ -561,6 +699,8 @@ class MapWriter:
             marks += (t0, t1, perf_counter_ns())
             self._blocks.append((reduce_id, self._written, marks))
             self._block = None
+        if passed:
+            self._store._put_behind(st)
 
     # -- receive in place (a partition fed from a socket) -------------------
 
@@ -637,15 +777,18 @@ class MapWriter:
             except BaseException:
                 store._release_tenant(st, grow)
                 raise
+            staging = st.staging  # its first touch says whether the round is put behind its writers
             if resv is None:
                 start = peer * st.region_size + int(st.region_used[peer])
                 resv = self._resv = _Reservation(st.round, start, 0)
+                if st.put_behind is not None:
+                    st.put_behind.open.add(resv)  # until ``_ShuffleState.settled``
             resv.padded = padded
             st.region_used[peer] += grow
             st.inflight[resv.round] = st.inflight.get(resv.round, 0) + 1
             self._receiving = True
             at = resv.start + resv.filled
-            return memoryview(st.staging)[at : at + nbytes]
+            return memoryview(staging)[at : at + nbytes]
 
     def end_receive(self, nbytes: int, filled: bool) -> None:
         """The receive ``reserve`` handed out has ended: ``filled`` says all
@@ -664,7 +807,11 @@ class MapWriter:
             else:
                 self._lost = True  # ``_resv`` stays: ``close_partition`` refuses
                 self._store._release_tenant(st, resv.padded)
+                st.settled(resv)  # a hole: what is there stays there
             self._store._receive_ended(st, resv.round)
+            engaged = st.put_behind is not None
+        if engaged:  # the whole rows received are final now: the put cursor may pass them
+            self._store._put_behind(st)
 
     def _refuse_unsettled(self) -> None:
         if self._lost or self._receiving:
@@ -685,6 +832,7 @@ class MapWriter:
         self._copy_ns += perf_counter_ns() - t0
         self._extra_copies += 1
         self._store._release_tenant(st, resv.padded)
+        st.settled(resv)
         self._resv = None
         self._inplace_fallbacks += 1
 
@@ -712,10 +860,18 @@ class MapWriter:
             )
             self._inplace_blocks += 1
             self._inplace_bytes += self._written
+            st.settled(resv)
+            behind = st.put_behind
+            # the extent's last row is final now: it may end a piece to put
+            passed = behind is not None and (
+                resv.start + resv.padded >= behind.next_end[resv.start // st.region_size]
+            )
         self._resv = None
         self._last_reduce = self._open_reduce
         self._open_reduce = None
         self._block = None  # received in place: the daemon's phases, no ``write.block``
+        if passed:
+            self._store._put_behind(st)
         return True
 
     def write_partition(self, reduce_id: int, data: bytes) -> None:
@@ -1142,6 +1298,13 @@ class HbmBlockStore:
         #: rollover: the free bytes of the region whose overflow rolled the
         #: round) and the gauge ``largest_block_bytes`` (once a map task at
         #: its commit: the longest block any committed task staged here).
+        #: The single round put behind the writers (``_PutBehind``):
+        #: ``early_put_pieces`` / ``early_put_bytes`` (pieces put on the
+        #: device before their shuffle's seal, and their bytes),
+        #: ``seal_put_pieces`` (pieces of a round larger than one piece that
+        #: ``seal`` itself still put) and ``early_put_dropped`` (device
+        #: buffers with pieces in them that a rollover, a removal or a failed
+        #: put discarded).
         #: guarded by self._lock
         self._write_stats: Dict[str, int] = dict.fromkeys(
             ("staged_blocks", "staged_bytes", "rollovers", "spilled_bytes",
@@ -1151,7 +1314,8 @@ class HbmBlockStore:
              "device_staged_blocks", "device_staged_bytes", "scatter_dispatches",
              "device_stage_ns", "lock_wait_ns", "inplace_blocks", "inplace_bytes",
              "inplace_fallbacks", "inflight_wait_ns", "rollover_tail_bytes",
-             "largest_block_bytes"), 0
+             "largest_block_bytes", "early_put_pieces", "early_put_bytes",
+             "seal_put_pieces", "early_put_dropped"), 0
         )
         #: RAM tier of completed rounds (``_rollover``): capacity bytes of the
         #: RAM rounds live shuffles hold plus the free list never exceed
@@ -1259,7 +1423,7 @@ class HbmBlockStore:
                 self.conf.block_alignment,
                 staging=staging,
                 staging_closer=closer,
-                alloc=self._take_round_buffer,
+                alloc=functools.partial(self._take_staging, shuffle_id),
             )
             self._shuffles[shuffle_id].app_id = app_id
             pending = self._pending_infos.pop(shuffle_id, [])
@@ -1275,8 +1439,10 @@ class HbmBlockStore:
             if st is not None:
                 st.removed = True
                 # nothing below may zero, unmap or hand a buffer to the free
-                # list under a receive: wait them out (no new one is admitted)
-                self._await_receives(st)
+                # list under a receive or a piece's put: wait them out (no
+                # new one is admitted)
+                self._await_quiet(st)
+                early = self._drop_put_behind(st)
                 # The live staging round, the RAM rounds and the device-sealed
                 # payload are released HERE, not at the interpreter's next
                 # collection: a writer or reader handle may keep the state
@@ -1289,9 +1455,9 @@ class HbmBlockStore:
                     st.staging_closer()
                 self._write_stats["released_device_bytes"] += sum(
                     _device_nbytes(payload)
-                    for payload in (st.device_staging, *(st.sealed_payload or ()))
+                    for payload in (st.device_staging, early, *(st.sealed_payload or ()))
                 )
-                st.sealed_payload = st.device_staging = None
+                st.sealed_payload = st.device_staging = early = None
                 self._recycle_rounds(rounds, floor=True)
                 self._release_spill(st)
                 self._release_tenant(st, st.tenant_charged)
@@ -1318,7 +1484,8 @@ class HbmBlockStore:
             self._replica_bytes = 0
             for st in states:
                 st.removed = True
-                self._await_receives(st)  # the shm closer unmaps the buffer
+                self._await_quiet(st)  # the shm closer unmaps the buffer
+                self._drop_put_behind(st)
                 if st.staging_closer is not None:
                     st.staging = None
                     st.staging_closer()
@@ -1507,6 +1674,9 @@ class HbmBlockStore:
         ``store.spill`` fires only on the disk arm, where the rollover's self
         time is the zeroing of the used prefixes; on the RAM arm it is
         bookkeeping."""
+        # the job has turned multi-round: the exchange uploads its rounds, and
+        # what was put of this one on the device is let go
+        self._drop_put_behind(st)
         with self._rollover_span(st, peer):
             staging, used = st.staging, st.region_used
             if self._admit_ram_round(staging.nbytes, reuse=True):
@@ -1578,6 +1748,151 @@ class HbmBlockStore:
             if st.draining:
                 self._cond.notify_all()
 
+    # -- the single round put behind the writers (``_PutBehind``) -----------
+
+    def _await_quiet(self, st: _ShuffleState) -> bool:
+        """Return with no receive in flight into any round of ``st`` and no
+        owner putting pieces of its live round — caller holds self._lock,
+        RELEASED while this waits.  True when it waited: the caller then
+        looks at the state again."""
+        waited = False
+        while self._await_receives(st) or self._await_put_owner(st):
+            waited = True  # either wait may have let the other begin
+        return waited
+
+    def _await_put_owner(self, st: _ShuffleState) -> bool:
+        """Wait the owner of ``st``'s update chain out (caller holds
+        self._lock, released meanwhile): it is inside a ``device_put``, an
+        update or the wait for an older piece's transfer, and takes this lock
+        to let go.  No new reservation is admitted meanwhile."""
+        behind = st.put_behind
+        if behind is None or not behind.owner:
+            return False
+        st.draining += 1
+        try:
+            while behind.owner:
+                self._cond.wait(timeout=1.0)
+        finally:
+            st.draining -= 1
+            self._cond.notify_all()
+        return True
+
+    def _drop_put_behind(self, st: _ShuffleState):
+        """Take the pieces put behind the writers off ``st`` (caller holds
+        self._lock): the device buffer is let go here — and returned, for a
+        removal to count — or by the owner when it comes back for the lock,
+        and whatever seals the round now puts all of it."""
+        behind, st.put_behind = st.put_behind, None
+        if behind is None:
+            return None
+        if behind.buf is not None or behind.owner:
+            self._write_stats["early_put_dropped"] += 1
+        if behind.owner:
+            return None
+        buf = behind.buf
+        behind.release()
+        return buf
+
+    def _claim_pieces(self, st: _ShuffleState, behind: _PutBehind):
+        """The pieces of ``st``'s live round that hold their final bytes and
+        nobody has put, claimed for the caller (caller holds self._lock):
+        ``(piece indices, the round as rows)``, and ``behind.owner`` set;
+        ``((), None)`` where another thread owns the chain, the state was
+        taken off the shuffle, a receive in place is in flight into the
+        round (``reserve`` moves ``region_used`` before the bytes are there),
+        no piece is final (``_PutBehind.final_marks``: a partition open
+        between two frames holds its region's pieces from its last whole row
+        on), or the watermark gate refuses — no early put then, not a failed
+        write: the seal puts the piece."""
+        if st.put_behind is not behind or behind.owner or st.inflight.get(st.round):
+            return (), None
+        marks = behind.final_marks(st.region_used)
+        final = [p for p, mark in enumerate(marks) if behind.next_end[p] <= mark]
+        if not final:
+            return (), None
+        try:
+            self._check_pressure_locked("piece_put", SEAL_PUT_PIECE_BYTES)
+        except ResourceExhaustedError:
+            return (), None
+        pieces = []
+        for p in final:
+            while behind.next_end[p] <= marks[p]:
+                pieces.append(behind.claim(p))
+        behind.owner = True
+        return pieces, st.staging.view(np.int32).reshape(-1, st.alignment // 4)
+
+    def _put_behind(self, st: _ShuffleState) -> None:
+        """Put the pieces of ``st``'s live round that are final now, on the
+        calling thread — a writer whose block passed a piece's end — as the
+        one owner of the update chain: claimed under self._lock, put OUTSIDE
+        it, and claimed again until none is left, so a writer that passes a
+        piece meanwhile goes on copying.  A put the runtime refuses (the
+        device is out of memory, or lost) costs the shuffle its early pieces,
+        never the write: the seal puts the round whole and raises what is
+        still wrong.  Any other error is this code's and goes up through the
+        write; the chain is let go either way.
+
+        Span ``store.piece_put``, once a piece: **the time the calls hold
+        this thread** (the runtime's staging of the source, the update's
+        dispatch, the wait for an older piece where two are in flight), NOT
+        the DMA."""
+        import jax
+
+        behind = st.put_behind
+        if behind is None:
+            return
+        with self._lock:
+            pieces, payload = self._claim_pieces(st, behind)
+        row_bytes = behind.alignment
+        while pieces:
+            put = nbytes = 0
+            try:
+                for k in pieces:
+                    at = k * behind.piece_rows
+                    piece_bytes = (min(at + behind.piece_rows, behind.rows) - at) * row_bytes
+                    with span(
+                        "store.piece_put", shuffle_id=st.shuffle_id, executor=self.executor_id,
+                        at=at * row_bytes, bytes=piece_bytes,
+                    ):
+                        self._put_piece(behind, payload, at)
+                    put += 1
+                    nbytes += piece_bytes
+            except jax.errors.JaxRuntimeError:
+                logger.warning(
+                    "shuffle %d: a piece put behind the writer failed; the seal puts the round",
+                    st.shuffle_id, exc_info=True,
+                )
+            finally:
+                with self._lock:
+                    behind.owner = False
+                    self._write_stats["early_put_pieces"] += put
+                    self._write_stats["early_put_bytes"] += nbytes
+                    if st.put_behind is not behind:  # dropped meanwhile: this owner lets go
+                        behind.release()
+                    elif put < len(pieces):  # the cursor is past a piece that was not put
+                        self._drop_put_behind(st)
+                    self._cond.notify_all()
+            with self._lock:
+                pieces, payload = self._claim_pieces(st, behind)
+
+    def _put_piece(self, behind: _PutBehind, payload: np.ndarray, at: int) -> None:
+        """One link of the update chain (its owner's call): the piece of
+        ``payload`` that starts at row ``at`` onto ``self.device`` and into
+        ``behind.buf``, which each update donates back (in place: HBM holds
+        the round once and the pieces in flight)."""
+        import jax
+        import jax.numpy as jnp
+
+        if behind.buf is None:
+            behind.buf = jnp.zeros(payload.shape, dtype=jnp.int32, device=self.device)
+        if len(behind.in_flight) == SEAL_PUT_PIECES_IN_FLIGHT:
+            # the host runs ahead of the transfers: unchecked, every piece
+            # would sit in HBM beside the round (3 GiB more at 4 GiB)
+            behind.in_flight.popleft().block_until_ready()
+        piece = jax.device_put(payload[at : at + behind.piece_rows], self.device)
+        behind.buf = _update_rows_fn()(behind.buf, piece, np.int32(at))
+        behind.in_flight.append(piece)
+
     # -- RAM rounds and the free list of round buffers ---------------------
 
     def _admit_ram_round(self, nbytes: int, reuse: bool) -> bool:
@@ -1608,6 +1923,25 @@ class HbmBlockStore:
             self._free_rounds.clear()
             self._write_stats["pool_held_bytes"] = 0
         return True
+
+    def _take_staging(self, shuffle_id: int, nbytes: int) -> np.ndarray:
+        """A shuffle's staging round at its first touch (``_ShuffleState
+        .staging``; caller holds self._lock; never shm staging, which is
+        handed to the state whole).  Here the store knows what the round will
+        be written into, and a store with a device puts it behind its writers
+        (``_PutBehind``) only where that is a buffer of the free list — pages
+        the process holds: every job of a long-lived executor but its first.
+        A fresh buffer's write is the first touch of its pages, which a put
+        gains nothing on — on the chip's host it cost that job 1.2 s (PERF.md
+        section 6, PR 51).  The rest of ``seal``'s ``device_put_here`` — host
+        writes, one round — is known only later: a rollover drops the state,
+        a device-staged shuffle never comes here."""
+        held = bool(self._free_rounds.get(nbytes))  # what the take below hands out
+        buf = self._take_round_buffer(nbytes)
+        st = self._shuffles.get(shuffle_id)
+        if held and st is not None and self.device is not None:
+            st.put_behind = st.pieces()
+        return buf
 
     def _take_round_buffer(self, nbytes: int) -> np.ndarray:
         """An all-zero uint8 buffer for a staging round (caller holds
@@ -2002,8 +2336,9 @@ class HbmBlockStore:
                 raise TransportError(f"unknown shuffle {shuffle_id}")
             if st.sealed:
                 raise TransportError(f"shuffle {shuffle_id} already sealed")
-            # the rounds are handed on as they are: not under a receive
-            if self._await_receives(st) and (st.removed or st.sealed):  # the lock was released
+            # the rounds are handed on as they are: not under a receive, and
+            # the update chain of a round put behind its writers has one owner
+            if self._await_quiet(st) and (st.removed or st.sealed):  # the lock was released
                 raise TransportError(f"shuffle {shuffle_id} was removed or sealed during its seal")
             lane = st.alignment // 4
             out = []
@@ -2035,6 +2370,7 @@ class HbmBlockStore:
                         executor=self.executor_id, bytes=int(payload.nbytes),
                     ):
                         payload = self._put_round(payload, st)
+            st.put_behind = None  # ``_put_round`` has handed its buffer over, or it never began
             out.append((payload, final_sizes))
             st.sealed_payload = [p for p, _ in out]
         # Replication hook, outside the lock: the sealed rounds are now
@@ -2045,43 +2381,46 @@ class HbmBlockStore:
         return out
 
     def _put_round(self, payload: np.ndarray, st: _ShuffleState):
-        """The single sealed round onto ``self.device``.  A round of up to
-        ``SEAL_PUT_PIECE_BYTES`` is one ``device_put``, as ever.  A larger
-        one goes in pieces of that size, ``SEAL_PUT_PIECES_IN_FLIGHT`` at a
-        time, into a zeroed device buffer that each update donates back (in
-        place: HBM holds the round once and a few pieces): ONE
-        ``device_put`` of 4 GiB ran at 0.30 GiB/s on a v5e host where 1 GiB
-        and less run at 4.9 (PERF.md section 6, PR 27).  A piece no region's used
-        prefix reaches is not put at all: it is zeros on the host and stays
-        zeros on the device."""
+        """The single sealed round onto ``self.device`` (caller holds
+        self._lock and has waited the owner of ``st.put_behind`` out).  A
+        round of up to ``SEAL_PUT_PIECE_BYTES`` is one ``device_put``, as
+        ever.  A larger one goes in pieces of that size,
+        ``SEAL_PUT_PIECES_IN_FLIGHT`` at a time, into a zeroed device buffer
+        that each update donates back (in place: HBM holds the round once and
+        a few pieces): ONE ``device_put`` of 4 GiB ran at 0.30 GiB/s on a v5e
+        host where 1 GiB and less run at 4.9 (PERF.md section 6, PR 27).  A
+        piece no region's used prefix reaches is not put at all: it is zeros
+        on the host and stays zeros on the device.  Nor is a piece that was
+        put behind the writers (``_PutBehind``): this call carries that chain
+        on from where its owner left it — the piece each region's writer
+        stood in, a piece across two regions, everything where nothing was
+        put early — and hands its buffer over (``seal_put_pieces``)."""
         import jax
         import jax.numpy as jnp
 
         rows, lane = payload.shape
-        piece_rows = SEAL_PUT_PIECE_BYTES // (lane * 4)
-        if rows <= piece_rows:
+        behind = st.put_behind or st.pieces()
+        if behind is None:
             return jax.device_put(payload, self.device)
-        region_rows = st.region_size // st.alignment
+        region_rows = behind.region_rows
         used_rows = -(-st.region_used // st.alignment)
-        update = _update_rows_fn()
-        buf = jnp.zeros((rows, lane), dtype=jnp.int32, device=self.device)
-        in_flight: deque = deque()
-        for at in range(0, rows, piece_rows):
-            end = min(at + piece_rows, rows)
+        pieces = 0
+        for at in range(0, rows, behind.piece_rows):
+            if behind.is_put(at):
+                continue
+            end = min(at + behind.piece_rows, rows)
             first, last = at // region_rows, (end - 1) // region_rows
             # used rows of region p lie in [p * region_rows, p * region_rows + used_rows[p])
             if not any(
                 p * region_rows + int(used_rows[p]) > at for p in range(first, last + 1)
             ):
                 continue
-            if len(in_flight) == SEAL_PUT_PIECES_IN_FLIGHT:
-                # the host runs ahead of the transfers: unchecked, every piece
-                # would sit in HBM beside the round (3 GiB more at 4 GiB)
-                in_flight.popleft().block_until_ready()
-            piece = jax.device_put(payload[at:end], self.device)
-            buf = update(buf, piece, np.int32(at))
-            in_flight.append(piece)
-        return buf
+            self._put_piece(behind, payload, at)
+            pieces += 1
+        self._write_stats["seal_put_pieces"] += pieces
+        if behind.buf is None:  # not a used row in the round
+            behind.buf = jnp.zeros((rows, lane), dtype=jnp.int32, device=self.device)
+        return behind.buf
 
     def num_rounds(self, shuffle_id: int) -> int:
         st = self._state(shuffle_id)

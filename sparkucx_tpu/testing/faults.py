@@ -37,7 +37,9 @@ exchange.recover.submit   collective plane, before each sub-exchange of a degrad
                           ``chunk``) — a second loss inside the recovery
 store.mem_pressure        store/hbm_store.py + memory/pool.py, before each
                           allocation-bearing mutation (close_partition, device
-                          write, replica install, restage, pool growth) — arming
+                          write, replica install, restage, pool growth, a piece
+                          put behind the writer: site ``piece_put``, where a
+                          refusal means no early put, not a failed write) — arming
                           ``fail(ResourceExhaustedError(...))`` models a host
                           under memory pressure (ctx: ``site``, ``nbytes``)
 ========================  ==========================================================
