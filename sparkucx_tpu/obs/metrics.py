@@ -233,19 +233,26 @@ def wire_lane_provider(fn: Callable[[], Iterable[Mapping]]) -> Provider:
 
 
 def labelled_counter_provider(
-    family: str, label: str, fn: Callable[[], Iterable[Mapping[str, object]]]
+    family: str, label: str, fn: Callable[[], Iterable[Mapping[str, object]]],
+    gauges: Iterable[str] = (),
 ) -> Provider:
     """Adapt an accessor that returns one flat counter dict per value of a
     dimension (the store's per-executor write counters, the daemon's per-op
     frame counters): the ``label`` key of each dict becomes that label, every
-    other numeric key a ``<name>_total`` counter row."""
+    other numeric key a ``<name>_total`` counter row — but the keys named in
+    ``gauges``, which go up and down and keep their name."""
+    gauges = frozenset(gauges)
 
     def provide() -> List[MetricSample]:
         out: List[MetricSample] = []
         for row in fn():
             lab = {label: row[label]}
             for name, value in row.items():
-                if name != label and isinstance(value, (int, float)):
+                if name == label or not isinstance(value, (int, float)):
+                    continue
+                if name in gauges:
+                    out.append(sample(family, name, value, lab, kind="gauge"))
+                else:
                     out.append(sample(family, f"{name}_total", value, lab, kind="counter"))
         return out
 

@@ -39,7 +39,18 @@ from sparkucx_tpu.transport.tpu import TpuShuffleCluster
 
 
 class TpuShuffleManager:
-    """Single-controller manager: owns the cluster and per-executor components."""
+    """Single-controller manager: owns the cluster and per-executor components.
+
+    **Task threads.**  ``get_writer`` and ``get_reader`` of one manager may be
+    called from as many threads as the executor has task slots, and the
+    writers and readers they return run side by side: each is its task's own
+    object (one thread a writer, one a reader), and what they share — the
+    store's regions and tables, the cluster's block tables and counters — is
+    taken under its owner's lock.  ``register_shuffle``, ``run_exchange`` and
+    ``unregister_shuffle`` are the stage boundaries and stay the caller's to
+    order: every map task committed before the exchange, every reader done
+    before the removal.  Nothing here bounds the number of tasks in flight
+    (``docs/DEPLOYMENT.md``, "Task slots")."""
 
     def __init__(
         self,

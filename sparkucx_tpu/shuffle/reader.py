@@ -1191,9 +1191,15 @@ class TpuShuffleReader:
         record_bytes)`` batch of its first ``n`` rows after one D2H.
 
         Span ``read.ordered``, once a task (``args``: ``blocks``, ``records``,
-        ``bytes``, ``capacity``), over ``read.device.locate``,
-        ``fetch.device_gather``, ``read.ordered.sort`` and — ``to_host`` —
-        ``read.ordered.d2h``."""
+        ``bytes``, ``capacity`` and ``in_flight``: the ordered reads in flight
+        on the executor when this one opened — 0 from one task thread), over
+        ``read.device.locate``, ``fetch.device_gather``, ``read.ordered.sort``
+        and — ``to_host`` — ``read.ordered.d2h``.
+
+        As many task threads as the executor has slots may each run one: a
+        reader is its task's own, the transport's tables are read under its
+        lock, and each read holds its own gathered buffer, sorted array and
+        landing block (``orderedread`` gauge ``in_flight``)."""
         serializer = self.deserializer
         if not isinstance(serializer, FixedWidthSerializer):
             raise TypeError(
@@ -1208,10 +1214,15 @@ class TpuShuffleReader:
             "read.ordered", shuffle_id=self.shuffle_id,
             reduce_id=self.start_partition, blocks=len(bids),
         ) as ctx:
+            if ctx is not None:
+                ctx.args["in_flight"] = self.transport.ordered_in_flight()
             records, n = fetch(bids, self.shuffle_id, width, serializer.key_bytes, flat=to_host)
             if ctx is not None:
                 ctx.args.update(records=n, bytes=n * width, capacity=int(records.size) * 4 // width)
-            batch = self.transport.ordered_to_host(records, n, width) if to_host else None
+            if to_host:
+                batch = self.transport.ordered_to_host(records, n, width)
+            else:
+                self.transport.ordered_handed_out(records)
         self.metrics.remote_blocks_fetched += len(bids)
         self.metrics.remote_bytes_read += n * width
         self.metrics.records_read += n

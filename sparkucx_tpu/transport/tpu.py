@@ -270,12 +270,24 @@ class TpuShuffleCluster:
         #: they were sorted at, ``sort_dispatches`` calls of the ordering
         #: executable (one a non-empty task), ``d2h_bytes`` / ``d2h_ns`` the
         #: ordered array's one transfer to the host (``read_batches()`` only).
+        #: Tasks side by side (an executor's task slots): the gauges
+        #: ``in_flight`` / ``in_flight_device_bytes`` — ordered reads between
+        #: their gather's dispatch and the end of their D2H (``read_batches()``)
+        #: or their hand-out (``read_device()``), and what they hold on the
+        #: device meanwhile: each one's gathered segments and its sorted array,
+        #: by shape — and the high-water marks ``in_flight_peak`` /
+        #: ``in_flight_device_bytes_peak``.  Nothing bounds them but the caller.
         self._ordered_read_stats: List[Dict[str, int]] = [
             dict.fromkeys(
-                ("tasks", "records", "bytes", "capacity_records", "sort_dispatches", "d2h_bytes", "d2h_ns"), 0
+                ("tasks", "records", "bytes", "capacity_records", "sort_dispatches", "d2h_bytes", "d2h_ns",
+                 "in_flight", "in_flight_device_bytes", "in_flight_peak", "in_flight_device_bytes_peak"), 0
             )
             for _ in range(self.num_executors)
         ]  #: guarded by self._lock
+        #: ``id`` of an ordered read's device array -> (executor, device bytes
+        #: it holds in flight), from ``fetch_blocks_ordered`` until
+        #: ``ordered_to_host`` / ``ordered_handed_out`` takes it out
+        self._ordered_held: Dict[int, Tuple[int, int]] = {}  #: guarded by self._lock
         #: Liveness/epoch layer.  Always constructed (it is just bookkeeping);
         #: with elastic.enabled=false nothing ever reports a death through it,
         #: the epoch stays 0, and every code path below is byte-identical to
@@ -345,7 +357,10 @@ class TpuShuffleCluster:
         )
         self.metrics.register(
             "orderedread",
-            labelled_counter_provider("orderedread", "executor", self.ordered_read_stats),
+            labelled_counter_provider(
+                "orderedread", "executor", self.ordered_read_stats,
+                gauges=("in_flight", "in_flight_device_bytes"),
+            ),
         )
         self.recorder = FlightRecorder(
             TRACER,
@@ -1896,7 +1911,13 @@ class TpuShuffleCluster:
         Spans, once a call: ``read.device.locate``, ``fetch.device_gather``
         (as in the unordered fetch) and ``read.ordered.sort`` — the dispatch
         of ``ordered_records``: the time the call holds the thread, not the
-        sort, which is asynchronous.  Counter family ``orderedread{executor}``."""
+        sort, which is asynchronous.  Counter family ``orderedread{executor}``.
+
+        Safe from as many task threads as the caller runs: the read is in
+        the family's ``in_flight`` gauges from its gather's dispatch until
+        ``ordered_to_host`` has brought the array across or
+        ``ordered_handed_out`` has given it away — one of the two ends every
+        call, as ``TpuShuffleReader._read_ordered`` does."""
         from sparkucx_tpu.shuffle.reader import RaggedBlockError
 
         if record_bytes <= 0 or record_bytes % 4 or not 0 < key_bytes <= record_bytes:
@@ -1922,24 +1943,63 @@ class TpuShuffleCluster:
                 table[0, : len(block_ids)] = entries[:, 0] // slot_rows * slot_records
                 table[1, : len(block_ids)] = entries[:, 1] // record_bytes
         lanes = record_bytes // 4
-        if not plans:
-            shape = (capacity * lanes,) if flat else (capacity, lanes)
-            records = jnp.zeros(shape, dtype=jnp.int32, device=self.transports[consumer].device)
-        else:
-            with span("fetch.device_gather", shuffle_id=shuffle_id, blocks=len(block_ids)):
-                segments = [fn(plan, meta.recv_device[rnd][consumer]) for rnd, fn, plan in plans]
-            with span("read.ordered.sort", shuffle_id=shuffle_id, records=n, capacity=capacity):
-                records = ordered_records(
-                    table, *segments, record_lanes=lanes, key_bytes=key_bytes, flat=flat
-                )
+        # what the task holds on the device from here on: a gathered segment
+        # a round (each of the sort's capacity) and the sorted array
+        held = (len(plans) + 1) * capacity * record_bytes
         with self._lock:
             counters = self._ordered_read_stats[consumer]
+            counters["in_flight"] += 1
+            counters["in_flight_device_bytes"] += held
+            counters["in_flight_peak"] = max(counters["in_flight_peak"], counters["in_flight"])
+            counters["in_flight_device_bytes_peak"] = max(
+                counters["in_flight_device_bytes_peak"], counters["in_flight_device_bytes"]
+            )
+        try:
+            if not plans:
+                shape = (capacity * lanes,) if flat else (capacity, lanes)
+                records = jnp.zeros(shape, dtype=jnp.int32, device=self.transports[consumer].device)
+            else:
+                with span("fetch.device_gather", shuffle_id=shuffle_id, blocks=len(block_ids)):
+                    segments = [fn(plan, meta.recv_device[rnd][consumer]) for rnd, fn, plan in plans]
+                with span("read.ordered.sort", shuffle_id=shuffle_id, records=n, capacity=capacity):
+                    records = ordered_records(
+                        table, *segments, record_lanes=lanes, key_bytes=key_bytes, flat=flat
+                    )
+        except BaseException:
+            with self._lock:
+                counters["in_flight"] -= 1
+                counters["in_flight_device_bytes"] -= held
+            raise
+        with self._lock:
             counters["tasks"] += 1
             counters["records"] += n
             counters["bytes"] += n * record_bytes
             counters["capacity_records"] += capacity
             counters["sort_dispatches"] += bool(plans)
+            self._ordered_held[id(records)] = (consumer, held)
         return records, n
+
+    def _ordered_left(self, records) -> None:
+        """The ordered read whose device array ``records`` is leaves the
+        ``in_flight`` gauges; one that has left already is left alone.
+        Caller holds the cluster lock."""
+        consumer, held = self._ordered_held.pop(id(records), (None, 0))
+        if consumer is not None:
+            counters = self._ordered_read_stats[consumer]
+            counters["in_flight"] -= 1
+            counters["in_flight_device_bytes"] -= held
+
+    def ordered_in_flight(self, consumer: ExecutorId) -> int:
+        """Ordered reads in flight on ``consumer``'s device now: the gauge as
+        one unlocked read of an int, for a span's argument."""
+        return self._ordered_read_stats[consumer]["in_flight"]
+
+    def ordered_handed_out(self, records) -> None:
+        """An ordered read's device array has left the reader (``read_device()``
+        hands it to its consumer; ``ordered_to_host`` has brought it across):
+        the read is out of the ``in_flight`` gauges."""
+        with self._lock:
+            self._ordered_left(records)
 
     def ordered_to_host(self, consumer: ExecutorId, records, n: int, record_bytes: int) -> np.ndarray:
         """The first ``n`` records of a ``flat`` ordered read on the host: ONE
@@ -1951,15 +2011,20 @@ class TpuShuffleCluster:
         capacity = records.size * 4 // record_bytes
         pool = self._landing()
         t0 = time.perf_counter_ns()
-        with span("read.ordered.d2h", records=n, bytes=n * record_bytes, capacity=capacity):
-            with pool.allocating() if pool is not None else contextlib.nullcontext():
-                _start_landing(records)
-            host = np.asarray(records)
+        try:
+            with span("read.ordered.d2h", records=n, bytes=n * record_bytes, capacity=capacity):
+                with pool.allocating() if pool is not None else contextlib.nullcontext():
+                    _start_landing(records)
+                host = np.asarray(records)
+        except BaseException:
+            self.ordered_handed_out(records)
+            raise
         d2h_ns = time.perf_counter_ns() - t0
         with self._lock:
             counters = self._ordered_read_stats[consumer]
             counters["d2h_bytes"] += host.nbytes
             counters["d2h_ns"] += d2h_ns
+            self._ordered_left(records)
         batch = host.view(np.uint8).reshape(capacity, record_bytes)[:n]
         batch.flags.writeable = False
         return batch
@@ -2157,6 +2222,16 @@ class TpuShuffleTransport(ShuffleTransport):
         """A ``flat`` ordered read's first ``n`` records on the host, in one
         D2H (``TpuShuffleCluster.ordered_to_host``)."""
         return self.cluster.ordered_to_host(self.executor_id, records, n, record_bytes)
+
+    def ordered_handed_out(self, records) -> None:
+        """An ordered read's device array goes to its consumer as it is
+        (``TpuShuffleCluster.ordered_handed_out``)."""
+        self.cluster.ordered_handed_out(records)
+
+    def ordered_in_flight(self) -> int:
+        """Ordered reads in flight on this executor's device now (the
+        ``orderedread`` gauge ``in_flight``)."""
+        return self.cluster.ordered_in_flight(self.executor_id)
 
     def progress(self) -> None:
         """Poll outstanding async work (non-blocking).  Post-exchange fetches
