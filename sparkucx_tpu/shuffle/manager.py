@@ -46,7 +46,14 @@ class TpuShuffleManager:
     writers and readers they return run side by side: each is its task's own
     object (one thread a writer, one a reader), and what they share — the
     store's regions and tables, the cluster's block tables and counters — is
-    taken under its owner's lock.  ``register_shuffle``, ``run_exchange`` and
+    taken under its owner's lock.  On the write side the slots' map tasks
+    copy into one store AT ONCE: a block takes its extent of staging under
+    the store's lock and is copied into it outside the lock
+    (``MapWriter.close_partition``; while the shuffle has one writer open
+    the block keeps the lock through its copy: nobody can wait for it),
+    and ``close_partition`` returns after the block's table record, so a
+    task's commit follows the last byte of its last block.
+    ``register_shuffle``, ``run_exchange`` and
     ``unregister_shuffle`` are the stage boundaries and stay the caller's to
     order: every map task committed before the exchange, every reader done
     before the removal.  Nothing here bounds the number of tasks in flight

@@ -232,6 +232,16 @@ def _device_block_bytes(array, offset: int, length: int, alignment: int) -> np.n
     return np.asarray(window).reshape(-1).view(np.uint8)[skip : skip + length]
 
 
+def _copy_chunks(staging: np.ndarray, start: int, chunks: Sequence[bytes]) -> None:
+    """A buffered block's copy: the writer's ``chunks`` back to back into
+    ``staging`` from byte ``start`` — slice assignment, one ``memcpy`` a chunk
+    with the interpreter given up."""
+    for chunk in chunks:
+        n = len(chunk)
+        staging[start : start + n] = np.frombuffer(chunk, dtype=np.uint8)
+        start += n
+
+
 @dataclass
 class _BlockEntry:
     offset: int  # absolute offset in the staging buffer (of its round)
@@ -244,17 +254,19 @@ class _BlockEntry:
     local: bool = True
 
 
-@dataclass(eq=False)
+@dataclass(eq=False, slots=True)
 class _Reservation:
     """The extent an open partition holds in a round's staging while its
-    frames are received in place (``MapWriter.reserve``).  It belongs to the
-    round it was made in: a rollover does not move it.  One extent is one
-    object (``_PutBehind.open`` keeps the open ones by identity)."""
+    bytes reach it outside the store's lock: the frames of a receive in place
+    (``MapWriter.reserve``), the copy of a buffered block
+    (``MapWriter.close_partition``).  It belongs to the round it was made in:
+    a rollover does not move it.  One extent is one object
+    (``_PutBehind.open`` keeps the open ones by identity)."""
 
     round: int  # the staging round it was made in
     start: int  # absolute offset in the round's buffer
     padded: int  # bytes of the region it takes (a multiple of the alignment)
-    filled: int = 0  # bytes received into it so far
+    filled: int = 0  # bytes received into it so far (a buffered copy: 0 while it runs)
 
 
 class _PutBehind:
@@ -268,25 +280,28 @@ class _PutBehind:
     free list handed the shuffle (pages the process holds), and as long as
     nothing rolled over and the writes are host writes — does not wait for
     the seal: a piece is put as soon as it lies wholly below its region's
-    ``region_used``, and below the rows that every partition still open for
-    a receive in place has filled (``open``), while no receive in place is in
-    flight into the round.
+    ``region_used``, and below the rows that every extent still open — a
+    partition received in place, a buffered block being copied — has filled
+    (``open``; ``final_marks``).
     Such a piece holds its final bytes: ``region_used`` only grows within a
     round, a block once copied is never rewritten, and a retried map's or an
-    abandoned reservation's extent stays behind as padding.  ``reserve``
-    moves ``region_used`` by the PADDED total before a byte is there, and the
-    partition's next frame is received from its UNPADDED end — into the last
-    row of the frame before it, below ``region_used`` — so an extent counts
-    only up to its last whole row received until the partition is recorded,
-    lost or sent back to the buffered path.  The pieces are
+    abandoned reservation's extent stays behind as padding.  An extent is
+    taken — ``region_used`` moves by its PADDED total — before a byte is
+    there, several writers' copies end in any order, and a partition's next
+    frame is received from its UNPADDED end — into the last row of the frame
+    before it, below ``region_used`` — so an extent counts only up to its
+    last whole row received (a buffered copy: not at all) until the partition
+    is recorded, lost or sent back to the buffered path: with four writers a
+    copy is in flight nearly always, and the record that completes a piece
+    may be an older extent's, finishing last (``MapWriter._record``).  The pieces are
     ``_put_round``'s own (whole pieces at the same offsets, at most
     ``SEAL_PUT_PIECES_IN_FLIGHT`` awaiting their transfer), so the seal puts
     what is left — the piece each writer stands in, a piece that straddles
     two regions — and hands ``buf`` over: the same bytes cross once, earlier.
 
     ONE owner at a time runs the donated chain ``buf = update(buf, piece,
-    at)``: the thread whose ``close_partition`` (or ``end_receive``) passed a
-    piece's end claims the pieces that are final under the store's lock
+    at)``: the thread whose ``close_partition`` (or ``end_receive``) took its
+    region's final mark past a piece's end claims the pieces that are final under the store's lock
     (``owner``), puts them OUTSIDE it, and looks again before it lets go, so
     a writer that passes a piece meanwhile leaves it to the owner and goes
     on copying.  ``seal``, ``remove_shuffle`` and ``close`` wait for the
@@ -298,10 +313,11 @@ class _PutBehind:
     ``p``'s queue — the pieces that START in region ``p``, in order — and
     ``next_end[p]`` the absolute staging offset the region's used prefix
     must reach for that piece to be final: ``sys.maxsize`` once the queue is
-    through or its next piece reaches into the next region.  A writer
-    compares one integer a block.  All fields but ``buf`` and ``in_flight``
-    are read and written under the owning store's lock; those two belong to
-    the owner (to ``seal`` once it has waited the owner out)."""
+    through or its next piece reaches into the next region.  A record
+    compares its region's final mark with it (``final_marks``: an integer a
+    region and one an extent still open).  All fields but ``buf`` and
+    ``in_flight`` are read and written under the owning store's lock; those
+    two belong to the owner (to ``seal`` once it has waited the owner out)."""
 
     __slots__ = (
         "rows", "piece_rows", "region_rows", "alignment", "cursor", "next_end",
@@ -315,9 +331,10 @@ class _PutBehind:
         self.next_end = [0] * regions
         for p in range(regions):
             self._aim(p)
-        #: the extents of the partitions being received in place into the
-        #: round: from a partition's first ``reserve`` until it is recorded,
-        #: lost or back on the buffered path
+        #: the extents whose bytes are on their way into the round outside
+        #: the store's lock: from ``_take_extent`` with ``hold`` (a
+        #: partition's first ``reserve``, a buffered close) until the
+        #: partition is recorded, lost or back on the buffered path
         self.open: set = set()
         self.owner = False  # a thread is putting claimed pieces outside the lock
         self.buf = None  # the round on the device: zeros but for the pieces put
@@ -338,8 +355,9 @@ class _PutBehind:
 
     def final_marks(self, region_used) -> List[int]:
         """Per region, the absolute staging offset below which every byte is
-        final: its used prefix, held below the first row that a partition
-        still open for a receive in place has not wholly received."""
+        final: its used prefix, held below the first row that an extent
+        still open has not wholly received (a buffered copy in flight: its
+        first row)."""
         region_bytes = self.region_rows * self.alignment
         marks = [p * region_bytes + int(used) for p, used in enumerate(region_used)]
         for resv in self.open:
@@ -427,12 +445,20 @@ class _ShuffleState:
         #: refusal, so the lazy ``staging`` property never re-allocates (and
         #: never serves fresh zeros as block bytes) once this is set.
         self.removed = False  #: guarded by self._lock
-        #: staging round -> socket receives in flight into its buffer
-        #: (``MapWriter.reserve`` takes one, ``end_receive`` gives it back);
+        #: staging round -> extents being filled outside the store's lock
+        #: (``MapWriter._take_extent`` with ``hold`` takes one; a socket's
+        #: ``end_receive``, a buffered ``close_partition`` gives it back);
         #: whoever would read, zero or hand on a round's buffer waits for its
         #: count to reach zero (``HbmBlockStore._await_receives``)
         #: (read and written under the owning store's ``_lock``)
         self.inflight: Dict[int, int] = {}
+        #: map writers of the shuffle created and not yet committed (a
+        #: retry that discards is not counted; an abandoned one stays).  With
+        #: one open nobody can wait at the lock for its copy, and a buffered
+        #: block keeps the lock through allocate + copy + record; with more,
+        #: the copy leaves the lock (``MapWriter.close_partition``)
+        #: (under the owning store's ``_lock``)
+        self.open_writers = 0
         #: waiters in ``_await_receives``: no new reservation is admitted
         #: while one drains, so a stream of writers cannot starve it
         #: (under the owning store's ``_lock``)
@@ -500,31 +526,39 @@ class MapWriter:
     records (offset, length) (:236-246).
 
     Concurrency: streamed bytes buffer writer-locally (the role of the
-    reference's 8 KB pinned write buffer, NvkvHandler.scala:26,213-242) and the
-    region allocate + copy + table record happen atomically at close — so any
-    number of map tasks can write concurrently, and a staging-round rollover can
-    never interleave with a half-written partition.  Concurrent writers take
-    turns at the store's one lock for the copy and the rollover; what each
-    waited there is counted (``lock_wait_ns``).
-
-    A partition fed from a socket (``reserve`` / ``end_receive``; the daemon's
-    ``WritePartition``) splits that atom in two.  **Atomic under the store's
-    lock, before a byte is read**: the admission checks, the tenant charge,
+    reference's 8 KB pinned write buffer, NvkvHandler.scala:26,213-242) and
+    reach staging at close, in three steps that the buffered close and a
+    partition fed from a socket (``reserve`` / ``end_receive``; the daemon's
+    ``WritePartition``) share.  **Atomic under the store's lock, before a
+    byte moves** (``_take_extent``): the admission checks, the tenant charge,
     the rollover when the region cannot take the block, the region allocate
-    and the round's in-flight count.  **Atomic under it at close**: the table
-    record, which names the extent and the round it was reserved in.  **In
-    between, outside the lock**: the receive into the extent, which no block
-    names yet and no other writer can be given.  A rollover MAY interleave
-    with it on the RAM arm — the completed round's buffer lives on in
-    ``prev_rounds`` and the receive ends in it — and with a partition that is
-    reserved but not closed on either arm (its bytes are in the round,
-    wherever the round went).  A rollover's disk arm, ``seal``,
-    ``remove_shuffle`` and ``close`` may NOT: whoever would read, zero or
-    hand on a round's buffer first waits, on the store's condition, until no
-    receive into it is in flight (``inflight_wait_ns``); while one waits no
-    new reservation is admitted.  A body that never fully arrives leaves its
-    extent a hole that no entry names (padding; tenant charge given back) and
-    the partition lost: the map cannot commit, so the retry writes it again.
+    and the round's in-flight count.  **Outside the lock**: the bytes reach
+    the extent — the copy of the writer's chunks, the socket's ``recv_into``
+    — which no block names yet and no other writer can be given; so the map
+    tasks of an executor's slots copy into one store AT ONCE, and what a
+    task waits at the lock is other tasks' allocates and records, not their
+    copies (``lock_wait_ns``).  **Atomic under it again** (``_record``): the
+    table entry, which names the extent and the round it was taken in.
+    ``close_partition`` returns after the record, so a task's ``commit``
+    follows the last byte of its last block.  While the shuffle has ONE
+    writer open (``_ShuffleState.open_writers``: every cell but the executor
+    with task slots) nobody can be kept waiting by a copy, and a buffered
+    block keeps the lock through all three steps — the same sequence with
+    the release and the second take left out, a microsecond or two a block
+    (``unlocked_copy_blocks`` / ``unlocked_copy_bytes`` count the others).
+
+    A rollover MAY interleave with bytes on their way on the RAM arm — the
+    completed round's buffer lives on in ``prev_rounds`` and the copy or the
+    receive ends in it — and with a partition that is reserved but not closed
+    on either arm (its bytes are in the round, wherever the round went).  A
+    rollover's disk arm, ``seal``, ``remove_shuffle`` and ``close`` may NOT:
+    whoever would read, zero or hand on a round's buffer first waits, on the
+    store's condition, until nothing is in flight into it
+    (``inflight_wait_ns``); while one waits no new extent is held.  Bytes
+    that never fully arrive — a body cut short, a copy that raised — leave
+    their extent a hole that no entry names (padding; tenant charge given
+    back) and the partition lost: the map cannot commit, so the retry writes
+    it again.
 
     Tracing (``docs/OBSERVABILITY.md``; PR 50): a committed writer is one span
     ``write.task``, from its creation to the end of its commit (``end_task``),
@@ -577,6 +611,9 @@ class MapWriter:
         #: blocks and bytes recorded in place and partitions that went back
         #: to the buffered path; join the store's counters at ``commit``
         self._inplace_blocks = self._inplace_bytes = self._inplace_fallbacks = 0
+        #: buffered blocks and bytes copied into their extent outside the
+        #: store's lock; join the store's counters at ``commit``
+        self._unlocked_blocks = self._unlocked_bytes = 0
         #: First-commit-wins task-retry semantics: when a successful commit for
         #: this map already exists, the retry attempt's writes are swallowed and
         #: commit() returns the existing table — the reference's atomic
@@ -641,57 +678,60 @@ class MapWriter:
         marks = self._block  # a sampled block's clock marks (full tracing)
         if marks is not None:
             marks.append(perf_counter_ns())
+        if self._lost:
+            self._refuse_unsettled()
         if self._resv is not None and self._close_reserved():
             return
-        st = self._state
+        st, store = self._state, self._store
         reduce_id = self._open_reduce
-        peer = st.owner_of(reduce_id)
-        passed = False  # this block took its region past the end of a piece to put
+        passed = False  # this block took its region's final mark past the end of a piece to put
         if not self._discard:
             padded = -(-self._written // st.alignment) * st.alignment
             # watermark gate before taking the lock: a shed write fails typed
             # (retryable ResourceExhaustedError) with nothing allocated
-            self._store.check_memory_pressure("close_partition", padded)
+            store.check_memory_pressure("close_partition", padded)
+            lock = store._lock
             t_lock = perf_counter_ns()
-            with self._store._lock:
+            with lock:
                 self._lock_wait_ns += perf_counter_ns() - t_lock
-                if st.sealed:
-                    # a writer opened before the seal: the sealed rounds are
-                    # immutable (zero-copy views, the runtime's H2D source),
-                    # and a rollover here would zero the buffer they alias
-                    raise TransportError(f"shuffle {st.shuffle_id} already sealed")
-                if st.device_mode:
+                # the only writer open keeps nobody waiting: it keeps the lock
+                # through all three steps (a writer opened meanwhile waits
+                # for this one copy)
+                unlocked = st.open_writers > 1
+                staging, start = self._take_extent(padded, unlocked)
+                try:
+                    round_idx = st.round  # the extent's own: a rollover may interleave with the copy
+                    try:
+                        if unlocked:
+                            # the extent is this writer's alone: no block names
+                            # it yet and no other writer can be given it; whoever
+                            # would read, zero or hand on its round waits for the
+                            # in-flight count
+                            lock.release()
+                        t0 = perf_counter_ns()
+                        _copy_chunks(staging, start, self._chunks)
+                        t1 = perf_counter_ns()
+                    finally:
+                        if unlocked:
+                            t_lock = perf_counter_ns()
+                            lock.acquire()
+                            self._lock_wait_ns += perf_counter_ns() - t_lock
+                            self._extra_lock_takes += 1
+                            store._receive_ended(st, round_idx)
+                    if st.removed:  # a removal latches ``removed``, then waits for this copy
+                        raise TransportError(f"unknown shuffle {st.shuffle_id}")
+                except BaseException as e:
+                    self._lose(padded)
+                    if isinstance(e, TransportError) or not isinstance(e, Exception):
+                        raise  # an interrupt stays an interrupt
                     raise TransportError(
-                        f"shuffle {st.shuffle_id} already has device-staged rounds — "
-                        "host and device writes cannot mix"
-                    )
-                st.device_mode = False
-                # Admission check first: an over-quota tenant write must fail
-                # typed with nothing allocated, rolled over, or copied.
-                self._store._charge_tenant(st, padded)  #: balanced by _release_tenant
-                # Allocate in the current round; roll the staging epoch when the
-                # region can't take this partition (multi-round spill).
-                if int(st.region_used[peer]) + padded > st.region_size:
-                    if st.staging_closer is not None:
-                        raise TransportError(
-                            "region overflow with shm staging — multi-round spill "
-                            "requires private staging; raise stagingCapacity"
-                        )
-                    self._store._rollover(st, peer)
-                start = peer * st.region_size + int(st.region_used[peer])
-                pos = start
-                t0 = perf_counter_ns()
-                for chunk in self._chunks:
-                    st.staging[pos : pos + len(chunk)] = np.frombuffer(chunk, dtype=np.uint8)
-                    pos += len(chunk)
-                t1 = perf_counter_ns()
-                self._copy_ns += t1 - t0
-                st.blocks[(self.map_id, reduce_id)] = _BlockEntry(
-                    offset=start, length=self._written, padded=padded, round=st.round
-                )
-                st.region_used[peer] += padded
-                behind = st.put_behind
-                passed = behind is not None and start + padded >= behind.next_end[peer]
+                        f"partition ({self.map_id},{reduce_id}) lost its copy into staging: {e!r}"
+                    ) from e
+                passed = self._record(start, padded, round_idx)
+            self._copy_ns += t1 - t0
+            if unlocked:
+                self._unlocked_blocks += 1
+                self._unlocked_bytes += self._written
         self._last_reduce = reduce_id
         self._open_reduce = None
         self._chunks = []
@@ -700,7 +740,122 @@ class MapWriter:
             self._blocks.append((reduce_id, self._written, marks))
             self._block = None
         if passed:
-            self._store._put_behind(st)
+            store._put_behind(st)
+
+    # -- the three steps of a block, shared by the buffered close and the ---
+    # -- receive in place (callers hold the store's lock) --------------------
+
+    def _take_extent(self, padded: int, hold: bool) -> Optional[Tuple[np.ndarray, int]]:
+        """Everything a block needs under the store's lock before a byte of
+        it moves (caller holds the lock): no waiter is draining the shuffle,
+        the removed / sealed / device-mode checks, the tenant charge (an
+        over-quota write fails typed with nothing allocated, rolled over or
+        copied), the rollover when the region cannot take the block, the first
+        touch of the staging round and the region allocate — ``region_used``
+        moves by ``padded`` bytes, or by what ``padded`` adds to the extent
+        this writer's open partition already holds (a further frame of a
+        receive in place).  ``(staging, start)``: the live round's buffer and
+        the extent's absolute offset in it.
+
+        With ``hold`` the caller fills the extent OUTSIDE the lock: it
+        becomes this writer's ``_Reservation`` of the round it was made in
+        (joining ``put_behind.open`` where the round is put behind its
+        writers) and the round's in-flight count is taken, to be given back
+        by ``end_receive`` / ``close_partition``.  None when the open partition's
+        extent cannot grow in place: it went back to the buffered path
+        (``_unreserve``)."""
+        st, store = self._state, self._store
+        if st.draining:
+            store._await_drained(st)
+        if st.removed:
+            raise TransportError(f"unknown shuffle {st.shuffle_id}")
+        if st.sealed:
+            # a writer opened before the seal: the sealed rounds are immutable
+            # (zero-copy views, the runtime's H2D source), and a rollover here
+            # would zero the buffer they alias
+            raise TransportError(f"shuffle {st.shuffle_id} already sealed")
+        if st.device_mode:
+            raise TransportError(
+                f"shuffle {st.shuffle_id} already has device-staged rounds — "
+                "host and device writes cannot mix"
+            )
+        peer = st.owner_of(self._open_reduce)
+        base = peer * st.region_size
+        used = int(st.region_used[peer])
+        resv = self._resv
+        grow = padded
+        if resv is not None:
+            grow -= resv.padded
+            if not (
+                resv.round == st.round
+                and resv.start + resv.padded == base + used
+                and used + grow <= st.region_size
+            ):
+                self._unreserve()
+                return None
+        st.device_mode = False
+        store._charge_tenant(st, grow)  #: balanced by _release_tenant
+        try:
+            # a rollover may wait, lock released, for copies in flight: look again
+            while resv is None and used + padded > st.region_size:
+                if st.staging_closer is not None:
+                    raise TransportError(
+                        "region overflow with shm staging — multi-round spill "
+                        "requires private staging; raise stagingCapacity"
+                    )
+                store._rollover(st, peer)
+                used = int(st.region_used[peer])
+        except BaseException:
+            store._release_tenant(st, grow)
+            raise
+        staging = st.staging  # its first touch says whether the round is put behind its writers
+        if resv is not None:
+            start = resv.start
+            resv.padded = padded
+        else:
+            start = base + used
+            if hold:
+                resv = self._resv = _Reservation(st.round, start, padded)
+                if st.put_behind is not None:
+                    st.put_behind.open.add(resv)  # until ``_ShuffleState.settled``
+        st.region_used[peer] = used + grow
+        if hold:
+            st.inflight[resv.round] = st.inflight.get(resv.round, 0) + 1
+        return staging, start
+
+    def _record(self, start: int, padded: int, round_idx: int) -> bool:
+        """The table record of the open partition, whose last byte is in its
+        extent (caller holds the store's lock): the entry names the extent
+        and the round it was taken in, and the extent no longer holds a put
+        cursor.  True when the record took its region's final mark past the
+        end of a piece to put (``_PutBehind``): the caller then calls
+        ``_put_behind`` outside the lock."""
+        st = self._state
+        st.blocks[(self.map_id, self._open_reduce)] = _BlockEntry(
+            offset=start, length=self._written, padded=padded, round=round_idx
+        )
+        resv = self._resv
+        if resv is not None:
+            st.settled(resv)
+            self._resv = None
+        behind = st.put_behind
+        if behind is None:
+            return False
+        p = start // st.region_size
+        end = behind.next_end[p]
+        # the used prefix has to be past the piece's end before an extent still open can matter
+        return p * st.region_size + int(st.region_used[p]) >= end and behind.final_marks(st.region_used)[p] >= end
+
+    def _lose(self, padded: int) -> None:
+        """The open partition's bytes never fully reached their extent of
+        ``padded`` bytes (caller holds the store's lock): it stays a hole
+        that no entry names, its tenant charge is given back, and the writer
+        refuses to close or commit (``_resv`` stays) — the map's retry writes
+        it again."""
+        self._lost = True
+        self._store._release_tenant(self._state, padded)
+        if self._resv is not None:
+            self._state.settled(self._resv)  # a hole: what is there stays there
 
     # -- receive in place (a partition fed from a socket) -------------------
 
@@ -712,11 +867,12 @@ class MapWriter:
         partition already fed through ``write``, one whose extent could not
         grow in place): the caller then feeds ``write``.
 
-        Under the store's lock, before a byte is read, this does everything
-        ``close_partition`` does before its copy: ``check_memory_pressure``,
-        the sealed / device-mode checks, ``_charge_tenant``, the rollover when
-        the region cannot take the block; the region-size check comes first,
-        so a body larger than a region fails typed with nothing allocated.
+        Under the store's lock, before a byte is read, this is the buffered
+        close's first step (``_take_extent``: ``check_memory_pressure``
+        before the lock; the sealed / device-mode checks, ``_charge_tenant``,
+        the rollover when the region cannot take the block); the region-size
+        check comes first, so a body larger than a region fails typed with
+        nothing allocated.
         The first frame of a partition takes its extent at the region's tail;
         a further frame grows it while that tail is still the extent's end
         and the region has room, and otherwise the partition goes back to the
@@ -728,66 +884,26 @@ class MapWriter:
         self._refuse_unsettled()
         if self._discard or self._chunks:
             return None
-        st = self._state
-        store = self._store
+        st, store = self._state, self._store
         total = self._written + nbytes
         if total > st.region_size:
             raise TransportError(
                 f"single partition ({self.map_id},{self._open_reduce}) exceeds a "
                 f"whole region ({st.region_size} B) — raise stagingCapacity"
             )
-        peer = st.owner_of(self._open_reduce)
         padded = -(-total // st.alignment) * st.alignment
-        resv = self._resv
-        grow = padded - (resv.padded if resv is not None else 0)
-        store.check_memory_pressure("reserve_partition", grow)
+        held = self._resv.padded if self._resv is not None else 0
+        store.check_memory_pressure("reserve_partition", padded - held)
         t_lock = perf_counter_ns()
         with store._lock:
             self._lock_wait_ns += perf_counter_ns() - t_lock
             self._extra_lock_takes += 1
-            if st.draining:
-                store._await_drained(st)
-            if st.removed:
-                raise TransportError(f"unknown shuffle {st.shuffle_id}")
-            if st.sealed:
-                raise TransportError(f"shuffle {st.shuffle_id} already sealed")
-            if st.device_mode:
-                raise TransportError(
-                    f"shuffle {st.shuffle_id} already has device-staged rounds — "
-                    "host and device writes cannot mix"
-                )
-            tail = peer * st.region_size + int(st.region_used[peer])
-            if resv is not None and not (
-                resv.round == st.round
-                and resv.start + resv.padded == tail
-                and int(st.region_used[peer]) + grow <= st.region_size
-            ):
-                self._unreserve()
+            extent = self._take_extent(padded, True)
+            if extent is None:
                 return None
-            st.device_mode = False
-            store._charge_tenant(st, grow)  #: balanced by _release_tenant
-            try:
-                while resv is None and int(st.region_used[peer]) + padded > st.region_size:
-                    if st.staging_closer is not None:
-                        raise TransportError(
-                            "region overflow with shm staging — multi-round spill "
-                            "requires private staging; raise stagingCapacity"
-                        )
-                    store._rollover(st, peer)  # may wait, lock released, for receives
-            except BaseException:
-                store._release_tenant(st, grow)
-                raise
-            staging = st.staging  # its first touch says whether the round is put behind its writers
-            if resv is None:
-                start = peer * st.region_size + int(st.region_used[peer])
-                resv = self._resv = _Reservation(st.round, start, 0)
-                if st.put_behind is not None:
-                    st.put_behind.open.add(resv)  # until ``_ShuffleState.settled``
-            resv.padded = padded
-            st.region_used[peer] += grow
-            st.inflight[resv.round] = st.inflight.get(resv.round, 0) + 1
             self._receiving = True
-            at = resv.start + resv.filled
+            staging, start = extent
+            at = start + self._resv.filled
             return memoryview(staging)[at : at + nbytes]
 
     def end_receive(self, nbytes: int, filled: bool) -> None:
@@ -805,9 +921,7 @@ class MapWriter:
                 resv.filled += nbytes
                 self._written += nbytes
             else:
-                self._lost = True  # ``_resv`` stays: ``close_partition`` refuses
-                self._store._release_tenant(st, resv.padded)
-                st.settled(resv)  # a hole: what is there stays there
+                self._lose(resv.padded)
             self._store._receive_ended(st, resv.round)
             engaged = st.put_behind is not None
         if engaged:  # the whole rows received are final now: the put cursor may pass them
@@ -855,18 +969,10 @@ class MapWriter:
             if self._chunks:
                 self._unreserve()
                 return False
-            st.blocks[(self.map_id, self._open_reduce)] = _BlockEntry(
-                offset=resv.start, length=self._written, padded=resv.padded, round=resv.round
-            )
             self._inplace_blocks += 1
             self._inplace_bytes += self._written
-            st.settled(resv)
-            behind = st.put_behind
             # the extent's last row is final now: it may end a piece to put
-            passed = behind is not None and (
-                resv.start + resv.padded >= behind.next_end[resv.start // st.region_size]
-            )
-        self._resv = None
+            passed = self._record(resv.start, resv.padded, resv.round)
         self._last_reduce = self._open_reduce
         self._open_reduce = None
         self._block = None  # received in place: the daemon's phases, no ``write.block``
@@ -1020,6 +1126,7 @@ class MapWriter:
             # once a writer; a retry's table is the first attempt's, counted then
             if not (self._discard or self._counted):
                 self._counted = True
+                st.open_writers -= 1
                 counters = self._store._write_stats
                 counters["staged_blocks"] += blocks
                 counters["staged_bytes"] += nbytes
@@ -1031,6 +1138,8 @@ class MapWriter:
                 counters["inplace_blocks"] += self._inplace_blocks
                 counters["inplace_bytes"] += self._inplace_bytes
                 counters["inplace_fallbacks"] += self._inplace_fallbacks
+                counters["unlocked_copy_blocks"] += self._unlocked_blocks
+                counters["unlocked_copy_bytes"] += self._unlocked_bytes
         if t_commit:
             # a buffered block is one copy and one take; a shuffle is staged
             # on the host or on the device, never both
@@ -1293,7 +1402,13 @@ class HbmBlockStore:
         #: socket put them, and their bytes) and ``inplace_fallbacks``
         #: (partitions that went back to the buffered path), added at
         #: ``commit`` like ``copy_ns``; ``inflight_wait_ns``: what a spill, a
-        #: seal, a removal and ``close`` waited for receives in flight.
+        #: seal, a removal and ``close`` waited for receives and copies in
+        #: flight.  The buffered close: ``unlocked_copy_blocks`` /
+        #: ``unlocked_copy_bytes`` (blocks closed while their shuffle had
+        #: more than one writer open, whose copy into staging ran outside
+        #: this store's lock, and their bytes; added at ``commit``;
+        #: ``copy_ns`` counts every copy, ``lock_wait_ns`` both takes of
+        #: such a block).
         #: What unequal blocks do to staging: ``rollover_tail_bytes`` (once a
         #: rollover: the free bytes of the region whose overflow rolled the
         #: round) and the gauge ``largest_block_bytes`` (once a map task at
@@ -1315,7 +1430,8 @@ class HbmBlockStore:
              "device_stage_ns", "lock_wait_ns", "inplace_blocks", "inplace_bytes",
              "inplace_fallbacks", "inflight_wait_ns", "rollover_tail_bytes",
              "largest_block_bytes", "early_put_pieces", "early_put_bytes",
-             "seal_put_pieces", "early_put_dropped"), 0
+             "seal_put_pieces", "early_put_dropped", "unlocked_copy_blocks",
+             "unlocked_copy_bytes"), 0
         )
         #: RAM tier of completed rounds (``_rollover``): capacity bytes of the
         #: RAM rounds live shuffles hold plus the free list never exceed
@@ -1704,16 +1820,18 @@ class HbmBlockStore:
             st.region_used = np.zeros(len(used), dtype=used.dtype)
             st.round += 1
 
-    # -- receives in place -------------------------------------------------
+    # -- bytes on their way into a round outside the lock --------------------
 
     def _await_receives(self, st: _ShuffleState, live_only: bool = False) -> bool:
-        """Return with no receive in flight into any round of ``st`` (with
-        ``live_only``: into its live round) — caller holds self._lock, which
-        is RELEASED while this waits, so the state may have changed by the
-        time it returns.  No new reservation of the shuffle is admitted
-        meanwhile (``_await_drained``), and every receive ends: it runs under
-        ``conf.wire_timeout_ms`` and gives its count back on every way out
-        (``MapWriter.end_receive``).  Nothing in flight is one compare.
+        """Return with no receive and no buffered copy in flight into any
+        round of ``st`` (with ``live_only``: into its live round) —
+        caller holds self._lock, which is RELEASED while this waits, so the
+        state may have changed by the time it returns.  No new extent of the
+        shuffle is taken meanwhile (``_await_drained``), and every one in
+        flight ends: a receive runs under ``conf.wire_timeout_ms``, a copy is
+        a ``memcpy``, and each gives its count back on every way out
+        (``MapWriter.end_receive`` / ``close_partition``).  Nothing in flight is
+        one compare.
         True when it waited: the caller then looks at the state again."""
 
         def pending():
@@ -1733,7 +1851,7 @@ class HbmBlockStore:
         return True
 
     def _await_drained(self, st: _ShuffleState) -> None:
-        """Hold a new reservation back while a waiter of ``_await_receives``
+        """Hold a new extent back while a waiter of ``_await_receives``
         drains the shuffle (caller holds self._lock, released meanwhile)."""
         while st.draining:
             self._cond.wait(timeout=1.0)
@@ -1798,13 +1916,13 @@ class HbmBlockStore:
         nobody has put, claimed for the caller (caller holds self._lock):
         ``(piece indices, the round as rows)``, and ``behind.owner`` set;
         ``((), None)`` where another thread owns the chain, the state was
-        taken off the shuffle, a receive in place is in flight into the
-        round (``reserve`` moves ``region_used`` before the bytes are there),
-        no piece is final (``_PutBehind.final_marks``: a partition open
-        between two frames holds its region's pieces from its last whole row
-        on), or the watermark gate refuses — no early put then, not a failed
+        taken off the shuffle, no piece is final (``_PutBehind.final_marks``:
+        an extent whose bytes are still on their way — a copy in flight, a
+        partition open between two frames — holds its region's pieces from
+        its last whole row on; never the in-flight count, which with four
+        writers is hardly ever zero), or the watermark gate refuses — no early put then, not a failed
         write: the seal puts the piece."""
-        if st.put_behind is not behind or behind.owner or st.inflight.get(st.round):
+        if st.put_behind is not behind or behind.owner:
             return (), None
         marks = behind.final_marks(st.region_used)
         final = [p for p, mark in enumerate(marks) if behind.next_end[p] <= mark]
@@ -2292,6 +2410,7 @@ class HbmBlockStore:
             raise ValueError(f"map_id {map_id} out of range [0, {st.num_mappers})")
         with self._lock:
             discard = map_id in st.committed_maps  # first commit wins (task retry)
+            st.open_writers += not discard  # until its ``commit``
         return MapWriter(self, st, map_id, discard=discard)
 
     def apply_mapper_info(self, info: MapperInfo) -> None:

@@ -3,8 +3,9 @@ ordered reads in flight on one device (``read_batches()`` and
 ``read_device()`` under ``key_ordering``) against the plain TeraSort of
 ``benchmark/references/terasort-ordered.py`` on seeded records, the
 ``orderedread`` statement of tasks in flight, four map tasks writing one
-region with the round put behind them (PR 51's ``_PutBehind``), and the
-tracer's spans of sibling task threads.
+region with the round put behind them (PR 51's ``_PutBehind``), the same
+four copying into the store AT ONCE (PR 53: the extent under the store's
+lock, the copy outside it), and the tracer's spans of sibling task threads.
 
 The CPU mesh: bytes, orders and counts, no rate."""
 
@@ -298,6 +299,104 @@ def test_four_map_tasks_of_one_region_with_the_round_put_behind_them(monkeypatch
         received = np.asarray(sealed.recv_device[0][0]).reshape(-1).view(np.uint8)
         used = int(state.region_used[0])
         assert np.array_equal(received[:used], host[:used])
+        batches = in_threads(SLOTS, lambda r: list(mgr.get_reader(
+            0, r, r + 1, deserializer=TERASORT, key_ordering=True).read_batches())[0], range(records.reducers))
+        for r, batch in enumerate(batches):
+            assert np.array_equal(batch, records.sorted_partition(r))
+
+
+def copies_that_meet(monkeypatch):
+    """The first four copies into staging wait for each other inside
+    ``hbm_store._copy_chunks``: four map tasks copy at once, whatever the
+    host — under a lock held round the copy they could never meet."""
+    meet = threading.Barrier(SLOTS)
+    real = hbm_store._copy_chunks
+    calls = []
+
+    def meeting(staging, start, chunks):
+        calls.append(threading.get_ident())
+        if len(calls) <= SLOTS:
+            meet.wait(timeout=60)
+        return real(staging, start, chunks)
+
+    monkeypatch.setattr(hbm_store, "_copy_chunks", meeting)
+    return calls
+
+
+def test_four_map_tasks_copy_into_one_store_at_once(monkeypatch):
+    """PR 53: the slots' map tasks take their extents under the store's lock
+    and copy outside it — four copies in flight into one region, every block
+    written with the four slots busy counted as copied outside the lock, the
+    round still put behind them piece by piece, sealed as the staging byte
+    for byte and read back as the plain sort."""
+    monkeypatch.setattr(hbm_store, "SEAL_PUT_PIECE_BYTES", PIECE)
+    records = ordered.make_records({**CONFIG, "mappers": 8}, 53)
+    with hbm_manager(staging=1 << 21) as mgr:
+        store = mgr.cluster.transports[0].store
+        write_job(mgr, 7, ordered.make_records(CONFIG, 1))  # the job before: the free list holds its buffer
+        mgr.unregister_shuffle(7)
+        before = store.write_stats()
+        calls = copies_that_meet(monkeypatch)
+        mgr.register_shuffle(0, records.num_mappers, records.reducers)
+        busy = threading.Barrier(SLOTS)  # two waves of four tasks: each writes with four writers open
+
+        def map_task(m):
+            writer = mgr.get_writer(0, m)
+            busy.wait(timeout=60)
+            for r, payload in records.blocks[m]:
+                with writer.get_partition_writer(r).open_stream() as stream:
+                    stream.write(payload)
+            busy.wait(timeout=60)
+            writer.commit_all_partitions()
+
+        in_threads(SLOTS, map_task, range(records.num_mappers))
+        assert len(set(calls[:SLOTS])) == SLOTS  # four threads met inside their copies
+        state = store._state(0)
+        behind = state.put_behind
+        assert behind is not None and not behind.owner and not behind.open and state.inflight == {}
+        after = store.write_stats()
+        blocks = sum(len(b) for b in records.blocks)
+        nbytes = sum(len(payload) for b in records.blocks for _, payload in b)
+        assert after["unlocked_copy_blocks"] - before["unlocked_copy_blocks"] == blocks == len(calls)
+        assert after["unlocked_copy_bytes"] - before["unlocked_copy_bytes"] == nbytes
+        assert after["staged_blocks"] - before["staged_blocks"] == blocks
+        assert after["inflight_wait_ns"] == before["inflight_wait_ns"]  # nobody had to wait for a copy
+        host = state.staging.copy()
+        used = int(state.region_used[0])
+        # every piece wholly below the used prefix went before the seal, whoever's copy ended last
+        assert after["early_put_pieces"] - before["early_put_pieces"] == used // PIECE > 4
+        mgr.run_exchange(0)
+        sealed = store.write_stats()
+        assert sealed["early_put_dropped"] == before["early_put_dropped"]
+        assert sealed["seal_put_pieces"] - before["seal_put_pieces"] == -(-used // PIECE) - used // PIECE
+        received = np.asarray(mgr.cluster.meta(0).recv_device[0][0]).reshape(-1).view(np.uint8)
+        assert np.array_equal(received[:used], host[:used])
+        batches = in_threads(SLOTS, lambda r: list(mgr.get_reader(
+            0, r, r + 1, deserializer=TERASORT, key_ordering=True).read_batches())[0], range(records.reducers))
+        for r, batch in enumerate(batches):
+            assert np.array_equal(batch, records.sorted_partition(r))
+
+
+@pytest.mark.parametrize("threads", [1, SLOTS])
+def test_the_writers_open_decide_where_a_task_copies(threads):
+    """What the store observes, not a size: a job written one map task at a
+    time keeps the one-take atom (``unlocked_copy_blocks`` 0: nobody can
+    wait for its copies), the same job from four slots copies outside the
+    lock — through staging rounds that roll over with copies in flight —
+    and both read back as the plain sort."""
+    records = ordered.make_records({**CONFIG, "mappers": 8, "records_per_mapper": 16000}, 59)
+    with hbm_manager(staging=1 << 21) as mgr:
+        store = mgr.cluster.transports[0].store
+        before = store.write_stats()
+        write_job(mgr, 0, records, threads=threads)
+        after = store.write_stats()
+        blocks = sum(len(b) for b in records.blocks)
+        assert after["staged_blocks"] - before["staged_blocks"] == blocks
+        unlocked = after["unlocked_copy_blocks"] - before["unlocked_copy_blocks"]
+        assert unlocked == 0 if threads == 1 else blocks // 2 < unlocked <= blocks
+        assert after["rollovers"] - before["rollovers"] > 0
+        assert store._state(0).open_writers == 0
+        assert store._state(0).inflight == {}
         batches = in_threads(SLOTS, lambda r: list(mgr.get_reader(
             0, r, r + 1, deserializer=TERASORT, key_ordering=True).read_batches())[0], range(records.reducers))
         for r, batch in enumerate(batches):

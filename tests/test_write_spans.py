@@ -229,7 +229,10 @@ def test_fresh_pages_show_as_minor_faults(tracer):
     free list's buffer, touched by the job before) a small fraction of that."""
     if not KERNEL_COUNTS_FAULTS():
         pytest.skip("this kernel keeps no count of minor faults")
-    staging = 1 << 24
+    # over glibc's 32 MiB cap on its mmap threshold: ``np.zeros`` then maps fresh
+    # pages whatever this process freed before (a 16 MiB round came out of the
+    # heap, resident, once a test before it in the worker had freed as much)
+    staging = 1 << 26
     mgr = manager(tracer, staging=staging)
     tracer.enable()
     page = 4096
