@@ -30,6 +30,7 @@ import threading
 from typing import Callable, Dict, List, Optional
 
 from sparkucx_tpu.config import TpuShuffleConf
+from sparkucx_tpu.core.operation import ExecutorLostError
 from sparkucx_tpu.core.transport import ExecutorId
 from sparkucx_tpu.memory.pool import MemoryPool
 from sparkucx_tpu.shuffle.reader import TpuShuffleReader, default_deserializer
@@ -125,11 +126,33 @@ class TpuShuffleManager:
         merge_combiners=None,
     ) -> TpuShuffleReader:
         """getReader (compat/spark_3_0/UcxShuffleManager.scala:55-60).  The reduce
-        range must be owned by one executor (contiguous ownership); defaults to
-        the owner of ``start_partition``."""
+        range must be owned by one executor (contiguous ownership).
+
+        ``executor_id`` is where the engine's scheduler placed the task.  Left
+        out, the task runs where the exchange delivered its partitions — the
+        owner of ``start_partition`` — and borrows its blocks from that
+        executor's received shards.  Any other LIVE executor may be named: a
+        task re-placed there, as an engine does with the tasks of an executor
+        it lost after the exchange, never received its blocks and pulls each
+        from the executor that staged it (the shuffle's map owners) or, that
+        one being dead, from its ring successors' replicas
+        (``replication_factor``) — the same records in the same order, at a
+        copy a block.  Naming a dead executor is refused with
+        ``ExecutorLostError``: nothing runs there.  (Left out, the owner is
+        taken even while dead: the shards a recovery produced in its name lie
+        with the survivors and are read as ever; the shards it received before
+        it died went with it, and their reader raises ``ExecutorLostError`` at
+        its first window — re-place the task.)"""
         num_mappers, _, meta = self._dims(shuffle_id)
+        owner = meta.owner_of_reduce(start_partition)
         if executor_id is None:
-            executor_id = meta.owner_of_reduce(start_partition)
+            executor_id = owner
+        elif not self.cluster.membership.is_alive(executor_id):
+            raise ExecutorLostError(
+                executor_id, self.cluster.membership.epoch,
+                f"no reader of shuffle {shuffle_id} can be placed on it; alive: "
+                f"{self.cluster.membership.alive()}",
+            )
         transport = self.cluster.transport(executor_id)
 
         def block_sizes(m: int, r: int) -> int:
@@ -176,6 +199,10 @@ class TpuShuffleManager:
             memory_budget=self.conf.reduce_memory_budget,
             spill_dir=self.conf.spill_dir,
             merge_combiners=merge_combiners,
+            # the pull path's primary for block (m, r) is the executor that
+            # staged it, its replicas that executor's ring successors
+            sender_of=meta.map_owner.__getitem__,
+            received_by=owner,
         )
 
     def add_unregister_hook(self, fn: Callable[[int], None]) -> None:

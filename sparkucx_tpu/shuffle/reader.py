@@ -29,6 +29,7 @@ import numpy as np
 
 from sparkucx_tpu.core.block import MemoryBlock, ShuffleBlockId
 from sparkucx_tpu.core.operation import (
+    BlockNotFoundError,
     ExecutorLostError,
     OperationStatus,
     Request,
@@ -108,6 +109,16 @@ class ShuffleReadMetrics:
     #: ``read_batches()``: batches handed out, one a block (``records_read``
     #: counts the records in them)
     record_batches: int = 0
+    #: blocks (and their bytes) of windows this executor never received — a
+    #: task re-placed after its partition's owner was lost — pulled from the
+    #: executors that staged them or from their replicas (``_refetch_window``);
+    #: counted among ``copied_blocks`` too
+    refetched_blocks: int = 0
+    refetched_bytes: int = 0
+    #: refetched blocks (and their bytes) a replica holder served, the
+    #: executor that staged them being dead (each is a ``failovers`` too)
+    replica_blocks: int = 0
+    replica_bytes: int = 0
 
 
 class BlockFetchResult:
@@ -395,9 +406,18 @@ class TpuShuffleReader:
         fetch_hedge_ms: int = 0,
         fetch_hedge_max_ms: int = 0,
         holders_of: Optional[Callable[[ExecutorId, int], Sequence[ExecutorId]]] = None,
+        received_by: Optional[ExecutorId] = None,
     ) -> None:
         self.transport = transport
         self.executor_id = executor_id
+        #: the executor an exchange delivered this reader's partitions to (a
+        #: collective transport: after ``run_exchange`` block (m, r) lies in
+        #: the received shards of r's owner, whoever staged it); None where
+        #: blocks lie with their senders until they are fetched (the wire
+        #: transport).  A reader placed on ANOTHER executor — a task the
+        #: engine re-placed because that one was lost — never received its
+        #: windows and pulls every block (``_refetch_window``).
+        self.received_by = received_by
         self.shuffle_id = shuffle_id
         self.start_partition = start_partition
         self.end_partition = end_partition
@@ -473,6 +493,8 @@ class TpuShuffleReader:
         #: the marks of the window whose blocks are being yielded, under full
         #: tracing (None otherwise): where ``read()`` adds its record turns
         self._yielding: Optional[_WindowMarks] = None
+        #: the executor that served the last block ``_retry_fetch`` returned
+        self._pulled_from: Optional[ExecutorId] = None
         self.metrics = ShuffleReadMetrics()
 
     # -- raw block iterator ------------------------------------------------
@@ -581,7 +603,11 @@ class TpuShuffleReader:
         a deterministic-per-reader rotation over the sorted holders, so N
         concurrent reducers spread a fan-in across every holder instead of
         piling onto one server, while any single reader stays deterministic
-        (retries and the bit-equality contract rely on that)."""
+        (retries and the bit-equality contract rely on that).  A block an
+        exchange delivered (``received_by``) is fetched where it was
+        received, whoever staged it."""
+        if self.received_by is not None:
+            return self.received_by
         primary = self.sender_of(bid.map_id)
         if self.holders_of is None:
             return primary
@@ -611,13 +637,21 @@ class TpuShuffleReader:
         borrowed where they lie — ``(bid, view, None)``, no buffer and no
         request.  Everything else, and a borrow that raises, is a fetch into
         a result buffer — ``(bid, buf, req)`` — whose copying path names the
-        block at fault and fails it alone, into ``_retry_fetch``."""
+        block at fault and fails it alone, into ``_retry_fetch``.  A borrow
+        that raises ``ExecutorLostError`` — the shards died with the executor
+        that received them — ends the task there: no fetch can find them.
+
+        A window this reader's executor never received (``received_by`` is
+        another executor: a re-placed task) is pulled, a block at a time,
+        from where it was staged or replicated (``_refetch_window``)."""
         sizes = [self.block_sizes(bid.map_id, bid.reduce_id) for bid in window]
         nbytes = sum(sizes)
         if nbytes > self.metrics.window_bytes_max:
             self.metrics.window_bytes_max = nbytes
         if wctx is not None:
             wctx.args["bytes"] = nbytes
+        if self.received_by is not None and self.received_by != self.executor_id:
+            return self._refetch_window(window, sizes, wctx)
         groups: dict = {}
         for bid, size in zip(window, sizes):
             target = self._spread_target(bid)
@@ -630,6 +664,8 @@ class TpuShuffleReader:
             if resident is not None and sender == self.executor_id:
                 try:
                     views = resident(bids)
+                except ExecutorLostError:
+                    raise  # what received them is dead: typed, at once, no byte
                 except Exception:
                     pass  # the fetch below fails the block at fault, alone
                 else:
@@ -640,6 +676,58 @@ class TpuShuffleReader:
                 sender, bids, buffers, [None] * len(items)
             )
             requests.extend(zip(bids, buffers, reqs))
+        return requests
+
+    def _refetch_window(
+        self, window: List[ShuffleBlockId], sizes: List[int], wctx=None
+    ) -> List[Tuple[ShuffleBlockId, Any, Optional[Request]]]:
+        """Pull one window of a partition this executor never received: the
+        task was re-placed here after the executor the exchange delivered its
+        blocks to was lost, and their received copy went with it.  Each block
+        comes through the pull path (``_retry_fetch``) from the executor that
+        staged it (``sender_of``) or, that one being dead, from a replica
+        holder (``replica_of``); no batch fetch is tried first: this executor
+        has no shard to slice.  Returns completed requests, a block each.
+
+        Span ``read.refetch``, once a window (``args``: ``blocks``, ``bytes``,
+        ``from_replica``), made from clock marks as a child of the window's
+        ``read.window``; under full tracing its children ``read.refetch.block``
+        a pulled block (``executor``, ``replica``, ``bytes``).  Counters
+        ``refetched_blocks`` / ``refetched_bytes`` / ``replica_blocks`` /
+        ``replica_bytes``."""
+        clock = time.perf_counter_ns
+        marks = [] if wctx is not None and TRACER.enabled else None
+        metrics = self.metrics
+        replicas0 = metrics.replica_blocks
+        requests: List[Tuple[ShuffleBlockId, Any, Optional[Request]]] = []
+        t0 = t = clock()
+        try:
+            for bid, size in zip(window, sizes):
+                result, buf = self._retry_fetch(bid, None, None, refetch=True)
+                metrics.refetched_blocks += 1
+                metrics.refetched_bytes += size
+                req = Request(result.stats)
+                req.complete(result)
+                requests.append((bid, buf, req))
+                if marks is not None:
+                    served_by, t_prev, t = self._pulled_from, t, clock()
+                    marks.append(("read.refetch.block", t_prev, t, {
+                        "executor": served_by, "bytes": size,
+                        "replica": served_by != self.sender_of(bid.map_id),
+                    }))
+        except BaseException:
+            for _, buf, _ in requests:
+                buf.close()
+            raise
+        finally:
+            if wctx is not None:
+                with TRACER.executor_scope(self.executor_id):
+                    TRACER.record_spans(wctx, ((
+                        "read.refetch", t0, clock(),
+                        {"blocks": len(requests), "bytes": sum(sizes[: len(requests)]),
+                         "from_replica": metrics.replica_blocks - replicas0},
+                        marks or (),
+                    ),))
         return requests
 
     def _start_window_span(self, num_blocks: int):
@@ -709,8 +797,9 @@ class TpuShuffleReader:
         StatsAggregator, where the metrics registry's ``ops`` provider picks
         it up (``sparkucx_tpu_ops_*_total{kind="read"}``): once a task, how
         its blocks were read — borrowed or copied — and, if any, its
-        failover counters and, of a batch read, ``record_batches`` /
-        ``batch_records``."""
+        failover counters, what a re-placed task pulled (``refetched_blocks``
+        / ``refetched_bytes`` / ``replica_blocks`` / ``replica_bytes``) and, of
+        a batch read, ``record_batches`` / ``batch_records``."""
         agg = getattr(self.transport, "stats_agg", None)
         if agg is None:
             return
@@ -733,6 +822,17 @@ class TpuShuffleReader:
                 hedges_issued=m.hedges_issued,
                 hedge_wins=m.hedge_wins,
                 hedge_losses=m.hedge_losses,
+            )
+        if m.refetched_blocks:
+            counters.update(
+                refetched_blocks=m.refetched_blocks,
+                refetched_bytes=m.refetched_bytes,
+                replica_blocks=m.replica_blocks,
+                replica_bytes=m.replica_bytes,
+                # beside them whether or not any rose
+                failovers=m.failovers,
+                blocks_retried=m.blocks_retried,
+                fetch_timeouts=m.fetch_timeouts,
             )
         if m.record_batches:
             counters.update(record_batches=m.record_batches, batch_records=m.records_read)
@@ -995,7 +1095,7 @@ class TpuShuffleReader:
                 still.append((buf, req))
         self._abandoned = still
 
-    def _retry_fetch(self, bid: ShuffleBlockId, buf: Optional[MemoryBlock], failed):
+    def _retry_fetch(self, bid: ShuffleBlockId, buf: Optional[MemoryBlock], failed, refetch: bool = False):
         """Per-block pull-path retry + replica failover — the straggler/failure
         escape hatch next to the batch path.  The reference logs failed sends
         and gives up (SURVEY.md section 5.3: "No retry, no re-fetch fallback");
@@ -1012,12 +1112,25 @@ class TpuShuffleReader:
         timed-out attempt quarantines its buffer too.  Returns
         ``(result, buffer_holding_the_bytes)``.
 
+        ``refetch``: the block is one of a window this executor never
+        received (``_refetch_window``: a re-placed task) and nothing has
+        failed yet — the first attempt is the block's first fetch, so a block
+        it serves is no ``blocks_retried``; one a later attempt serves is.
+
         Fail-fast faults (``_FAIL_FAST_ERRORS``) are NOT retried: tenant
         admission rejections (UnknownTenantError / TenantQuotaExceededError)
-        hit the same registry budgets on every replica, and
-        ``ExecutorLostError`` means the membership plane already declared
-        the peer dead — failing over would just re-pay the backoff to hit
-        the same wall.  They propagate immediately.
+        hit the same registry budgets on every replica, and an
+        ``ExecutorLostError`` a fetch came back with says that what was asked
+        for died with an executor (received shards, an exchange that depended
+        on it): no candidate holds another copy of THAT.  They propagate
+        immediately.  A candidate the membership has already declared dead
+        (the transport's ``peer_alive``) is another matter: it is neither
+        asked nor slept on — no attempt, no backoff, the next candidate at
+        once — so a block whose stager died is served by its replica holder
+        at the cost of a live fetch.  The backoff is for a peer that lives
+        and fails.  Where every candidate is dead nothing is asked at all:
+        ``ExecutorLostError`` of the stager where the block had no replica
+        holder, ``BlockNotFoundError`` where those are lost too.
         ``ResourceExhaustedError`` (memory-pressure shed, the third arm of
         the failure taxonomy) IS retried: it inherits the jittered doubling
         backoff, which is exactly the back-off-and-retry contract the typed
@@ -1033,7 +1146,10 @@ class TpuShuffleReader:
             if buf is not None:
                 buf.close()
             raise failed.error
-        last_error = failed.error if failed is not None else "fetch deadline exceeded"
+        if failed is not None:
+            last_error = failed.error
+        else:
+            last_error = "not fetched yet" if refetch else "fetch deadline exceeded"
         size = self.block_sizes(bid.map_id, bid.reduce_id)
         primary = self.sender_of(bid.map_id)
         candidates: List[ExecutorId] = [primary]
@@ -1053,6 +1169,23 @@ class TpuShuffleReader:
                 e for e in self.replica_of(primary)
                 if e != primary and e not in candidates
             ]
+        alive = getattr(self.transport, "peer_alive", None)
+        if alive is not None:
+            living = [e for e in candidates if alive(e)]
+            if not living:
+                if buf is not None:
+                    buf.close()
+                if len(candidates) == 1:
+                    raise ExecutorLostError(
+                        primary, getattr(self.transport, "membership_epoch", 0),
+                        f"it staged {bid} and the block has no replica holder",
+                    )
+                raise BlockNotFoundError(
+                    bid.shuffle_id, bid.map_id, bid.reduce_id,
+                    f"executor {primary} that staged it is lost, and so are its "
+                    f"other holders {candidates[1:]}",
+                )
+            candidates = living
         allows = getattr(self.transport, "breaker_allows", None)
         if allows is not None and len(candidates) > 1:
             admitted = [e for e in candidates if allows(e)]
@@ -1125,6 +1258,12 @@ class TpuShuffleReader:
                                 f"expected {size} B — replica diverges from primary"
                             )
                         self.metrics.failovers += 1
+                        if refetch:
+                            self.metrics.replica_blocks += 1
+                            self.metrics.replica_bytes += size
+                    self._pulled_from = executor
+                    if refetch and attempt == 1:
+                        return result, buf  # its first fetch: nothing was retried
                     self.metrics.blocks_retried += 1
                     instant(
                         "fetch.retry",

@@ -281,9 +281,17 @@ def kill_executor(transport) -> None:
     left unusable (fetches through it fail), matching a dead process.
 
     Transports that model in-process executors (``TpuShuffleTransport``)
-    expose a ``chaos_kill`` hook instead of sockets: it closes the executor's
-    store and reports the death to cluster membership, so the collective
-    plane observes the loss the same way the wire plane observes a RST.
+    expose a ``chaos_kill`` hook instead of sockets.  What dies is what the
+    executor's process held: its store — staging, spills, the replicas it
+    kept for its ring predecessors — and the shards it had received of every
+    exchanged shuffle (host arrays, ``memmap`` files, HBM copies), which the
+    cluster lets go of; a read addressed to any of it is typed
+    (``ExecutorLostError`` for the shards) and serves no byte.  What others
+    hold of its output lives on: the replicas of its sealed rounds on its
+    ring successors, the shards other executors received from it, the shards
+    a recovery produced in its name after its death.  The death is reported
+    to cluster membership, so the collective plane observes the loss the same
+    way the wire plane observes a RST.
 
     Idempotent: a second kill of the same transport is a no-op — real
     processes only die once, and chaos tests that tear down in both the test

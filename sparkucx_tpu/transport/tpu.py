@@ -127,6 +127,15 @@ class _ShuffleMeta:
     #: sorted at (``TpuShuffleCluster._ordered_geometry``), worked out at the
     #: shuffle's first ordered read from the sealed size matrix
     ordered_capacity: Dict[int, int] = field(default_factory=dict)  #: guarded by self._lock
+    #: executors that died holding their received shards of this exchanged
+    #: shuffle (``TpuShuffleCluster.drop_received_of``): the entries of
+    #: ``recv_shards`` / ``recv_device`` are gone and a read addressed to
+    #: them is ``ExecutorLostError``
+    recv_lost: set = field(default_factory=set)
+    #: executors that were dead when a recovery produced this shuffle's
+    #: received shards: the shards addressed to them lie with the survivors
+    #: and outlive whatever happens to that id afterwards
+    recv_adopted: frozenset = frozenset()
 
     def owner_of_reduce(self, reduce_id: int) -> ExecutorId:
         for p, (s, e) in enumerate(self.peer_ranges):
@@ -311,7 +320,10 @@ class TpuShuffleCluster:
         #: handed the devices: ``recover_direct_bytes`` (host bytes put as
         #: views of a sealed or restaged round), ``recover_copied_bytes``
         #: (bytes through the one copy into the shrunk mesh's layout) and
-        #: ``recover_zero_pieces`` (pieces made on the device).
+        #: ``recover_zero_pieces`` (pieces made on the device).  Once a kill:
+        #: ``lost_recv_shards`` / ``lost_recv_bytes``, the received shards of
+        #: exchanged shuffles (host, mapped and device bytes) that died with
+        #: their executor (``drop_received_of``).
         self.elastic_stats = {
             "recoveries": 0,
             "last_recovery_ms": 0.0,
@@ -330,6 +342,8 @@ class TpuShuffleCluster:
             "recover_direct_bytes": 0,
             "recover_copied_bytes": 0,
             "recover_zero_pieces": 0,
+            "lost_recv_shards": 0,
+            "lost_recv_bytes": 0,
         }  #: guarded by self._lock
         #: Obs plane (PR 14): cluster-level registry + flight recorder.  The
         #: registry absorbs the collective plane's surfaces (exchange timings,
@@ -496,8 +510,6 @@ class TpuShuffleCluster:
             meta = self._meta.pop(shuffle_id, None)
         if meta is None:
             return
-        import os
-
         recv_device, meta.recv_device = meta.recv_device, None
         for rnd in recv_device or ():
             for t, shard in zip(self.transports, rnd):
@@ -505,16 +517,22 @@ class TpuShuffleCluster:
         del recv_device
         meta.recv_shards = None  # drop memmap views before unlinking
         for path, size in meta.recv_spill_paths:
-            try:
-                os.unlink(path)
-                freed = True
-            except FileNotFoundError:
-                freed = True  # already gone: the bytes are not on disk
-            except OSError:
-                freed = False  # still on disk: keep it charged
-            if freed:
-                with self._lock:
-                    self._recv_spill_bytes -= size
+            self._unlink_recv_spill(path, size)
+
+    def _unlink_recv_spill(self, path: str, size: int) -> bool:
+        """Remove one received-shard spill file and refund its disk budget;
+        False — still on disk, still charged — where it could not be removed."""
+        import os
+
+        try:
+            os.unlink(path)
+        except FileNotFoundError:
+            pass  # already gone: the bytes are not on disk
+        except OSError:
+            return False
+        with self._lock:
+            self._recv_spill_bytes -= size
+        return True
 
     def commit_mapper(self, info: MapperInfo) -> None:
         """AM id 2 sink — the cluster is the 'daemon' holding the commit table."""
@@ -1394,6 +1412,7 @@ class TpuShuffleCluster:
                     args={"shuffle_id": shuffle_id, "round": rnd,
                           "subexchanges": len(pairs[rnd]), **tallies[rnd]},
                 )
+        meta.recv_adopted = frozenset(dead)
         meta.exchanged = True
         recover_ns = time.monotonic_ns() - t0
         recovery_ms = recover_ns / 1e6
@@ -1513,6 +1532,49 @@ class TpuShuffleCluster:
         timeouts); returns True when this observation newly killed the
         executor (epoch bumped)."""
         return self.membership.mark_dead(executor_id, reason)
+
+    def drop_received_of(self, executor_id: ExecutorId) -> int:
+        """What dies with an executor's process besides its store: the
+        shards it received of every exchanged shuffle — host arrays, the
+        ``memmap`` mode's files (unlinked, their disk budget refunded) and
+        the HBM copies.  The cluster lets go of them here, so nothing the
+        dead executor held is ever served: a read addressed to them is
+        ``ExecutorLostError`` from now on (``_locate_rows``).  Shards a
+        recovery produced AFTER the executor's death are the survivors'
+        (``recv_adopted``) and stay.  Counted in the ``elastic`` family as
+        ``lost_recv_shards`` / ``lost_recv_bytes``; returns the bytes."""
+        with self._lock:
+            metas = list(self._meta.values())
+        shards = nbytes = 0
+        for meta in metas:
+            if not meta.exchanged or executor_id in meta.recv_adopted or executor_id in meta.recv_lost:
+                continue
+            meta.recv_lost.add(executor_id)  # before the entries go: a reader sees the typed error
+            files = set()
+            for rounds in (meta.recv_shards, meta.recv_device):
+                for rnd in rounds or ():
+                    shard, rnd[executor_id] = rnd[executor_id], None
+                    if shard is None:
+                        continue
+                    shards += 1
+                    nbytes += int(shard.nbytes)
+                    if isinstance(shard, np.memmap):
+                        files.add(shard.filename)
+                    del shard  # the mapping goes before its file
+            if files:
+                with self._lock:
+                    mine = [entry for entry in meta.recv_spill_paths if entry[0] in files]
+                    meta.recv_spill_paths = [entry for entry in meta.recv_spill_paths if entry[0] not in files]
+                still = [entry for entry in mine if not self._unlink_recv_spill(*entry)]
+                if still:  # on disk and charged: listed for the shuffle's removal
+                    with self._lock:
+                        meta.recv_spill_paths.extend(still)
+        with self._lock:
+            self.elastic_stats["lost_recv_shards"] += shards
+            self.elastic_stats["lost_recv_bytes"] += nbytes
+        if shards:
+            instant("exchange.recv_lost", executor=executor_id, shards=shards, bytes=nbytes)
+        return nbytes
 
     def rejoin_executor(self, executor_id: ExecutorId) -> bool:
         """Regrow: a previously-dead executor comes back, as a restarted
@@ -1656,9 +1718,13 @@ class TpuShuffleCluster:
             # host_recv_mode='device': no host copy exists — slice the block's
             # rows out of the HBM-resident shard and D2H just those bytes.
             shard = meta.recv_device[rnd][consumer]
+            if shard is None:  # went with its executor since the look-up above
+                raise self._received_shards_lost(meta, consumer, reduce_id)
             block_rows = np.asarray(shard[src_row : src_row + rows])
             return block_rows.reshape(-1).view(np.uint8)[:length], length
         shard = meta.recv_shards[rnd][consumer]
+        if shard is None:
+            raise self._received_shards_lost(meta, consumer, reduce_id)
         start = src_row * self.row_bytes
         if start + length > shard.size:
             # the host part is the shard's received prefix: a block past its
@@ -1669,6 +1735,13 @@ class TpuShuffleCluster:
                 f"executor {consumer} received in round {rnd}"
             )
         return shard[start : start + length], length
+
+    def _received_shards_lost(self, meta: _ShuffleMeta, consumer: ExecutorId, reduce_id: int) -> ExecutorLostError:
+        return ExecutorLostError(
+            consumer, self.membership.epoch,
+            f"the shards it received of shuffle {meta.shuffle_id} died with it; reducer "
+            f"{reduce_id}'s blocks are with the executors that staged them and their replicas",
+        )
 
     def _locate_rows(
         self,
@@ -1688,6 +1761,8 @@ class TpuShuffleCluster:
                 f"reducer {reduce_id} is owned by executor "
                 f"{meta.owner_of_reduce(reduce_id)}, not {consumer}"
             )
+        if consumer in meta.recv_lost:
+            raise self._received_shards_lost(meta, consumer, reduce_id)
         info = meta.mapper_infos.get(map_id)
         if info is None:
             raise TransportError(f"map {map_id} never committed")
@@ -2069,12 +2144,30 @@ class TpuShuffleTransport(ShuffleTransport):
         return self.cluster.recorder
 
     def chaos_kill(self) -> None:
-        """Chaos-harness death hook (testing.faults.kill_executor): close the
-        store — its staging, spills, and replicas become unreachable, like a
-        dead process's memory — and report the loss to cluster membership, the
-        collective-plane analogue of a peer observing ECONNRESET."""
+        """Chaos-harness death hook (testing.faults.kill_executor): what the
+        executor's process held at its death goes with it, like a dead
+        process's memory.  The store is closed — its staging, spills and the
+        replicas it held for others become unreachable — and the cluster lets
+        go of the shards this executor received of every exchanged shuffle
+        (``drop_received_of``: a read addressed to them is
+        ``ExecutorLostError``).  What others hold of ITS output stays: the
+        replicas of its sealed rounds on its ring successors, the shards
+        other executors received from it.  The loss is reported to cluster
+        membership, the collective-plane analogue of a peer observing
+        ECONNRESET."""
         self.store.close()
         self.cluster.membership.mark_dead(self.executor_id, "chaos kill_executor")
+        self.cluster.drop_received_of(self.executor_id)
+
+    def peer_alive(self, executor_id: ExecutorId) -> bool:
+        """Whether the cluster's membership holds ``executor_id`` alive: what
+        a reader's pull path asks before it tries — or sleeps on — a
+        candidate (``TpuShuffleReader._retry_fetch``)."""
+        return self.cluster.membership.is_alive(executor_id)
+
+    @property
+    def membership_epoch(self) -> int:
+        return self.cluster.membership.epoch
 
     def restart(self) -> None:
         """Come back as a restarted executor process does

@@ -164,6 +164,7 @@ def test_a_copied_blocks_batch_owns_its_bytes(rng, tmp_path, mode):
         # a mixed task: some blocks borrowed, some copied
         mixed = mgr.get_reader(0, 2, 3, deserializer=SERIALIZER)
         consumer = mgr.cluster.meta(0).owner_of_reduce(2)
+        mixed.received_by = None  # the fetch's target is sender_of's again, not the exchange's
         mixed.sender_of = lambda m: consumer if m % 2 else 1 - consumer
         got = sorted(b.tobytes() for b in mixed.read_batches())  # by sender, then by mapper
         assert got == sorted(rows[(m, 2)].tobytes() for m in range(MAPPERS))

@@ -381,6 +381,7 @@ def test_a_window_addressed_to_another_executor_is_still_copied(rng, tmp_path, m
         r = 0
         consumer = meta.owner_of_reduce(r)
         reader = mgr.get_reader(0, r, r + 1)
+        reader.received_by = None  # the fetch's target is sender_of's again, not the exchange's
         reader.sender_of = lambda m: 1 - consumer
         got = _fetched(reader)
         assert [data for _, data, _ in got] == [oracle[(m, r)] for m in range(MAPPERS)]
@@ -388,6 +389,7 @@ def test_a_window_addressed_to_another_executor_is_still_copied(rng, tmp_path, m
         requests, free = _pool_counts(mgr.pool)
         assert requests == MAPPERS and free >= 1  # taken from the pool, and handed back
         mixed = mgr.get_reader(0, r, r + 1)
+        mixed.received_by = None
         mixed.sender_of = lambda m: consumer if m % 2 else 1 - consumer
         assert sorted(_fetched(mixed)) == sorted(got)
         assert (mixed.metrics.resident_blocks, mixed.metrics.copied_blocks) == (MAPPERS // 2, MAPPERS - MAPPERS // 2)
