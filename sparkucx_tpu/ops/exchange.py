@@ -70,12 +70,15 @@ def gather_rows(rows: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """``rows[idx]`` (1-D row index): the row gather of every sort and
     partition here.  It is the plain gather at every width: chunking widths
     of 25..32 lanes into <= 24-lane column slices, as this function did from
-    an observation that predates the measurement record, is slower or level
-    wherever it was re-measured on a v5e (342,784 rows: 25 lanes 4.69 ms
-    plain against 5.94 chunked, 28 lanes 4.63 / 5.10, 32 lanes 4.67 / 4.74;
-    PERF.md section 6, PR 48) — XLA's TPU gather costs ~10 ns an index
-    whatever the row's width, so a second gather over the same indices never
-    pays."""
+    an observation that predates the measurement record, was slower or level
+    wherever it was re-measured on a v5e (PERF.md section 6, PR 48: a
+    dispatch and a ``block_until_ready`` a piece, ~1 ms of host time in each
+    figure, the order of the two forms not in doubt).  Inside an executable's
+    own device trace the plain gather of 342,784 rows of 25 lanes is 1.25 ms
+    (ledger, PR 54; ``scripts/probe_ordered_passes.py``, PR 55): 3.6 ns an
+    index whatever the row's width — a TPU holds such rows padded to 128
+    lanes, so each is one 512-byte row to fetch — and a second gather over
+    the same indices never pays."""
     return rows[idx]
 
 

@@ -127,6 +127,47 @@ def key_lanes_of(key_bytes: int) -> int:
     return -(-int(key_bytes) // 4)
 
 
+def key_order(keys, valid: jnp.ndarray, key_bytes: Optional[int] = None):
+    """The permutation that sorts rows by their key lanes, and how many of
+    them are valid: ``(order, count)`` — ``rows[order]`` has the valid rows
+    first, in key order, **stable**, and ``count`` of them.
+
+    ``keys``: the rows' key lanes, a ``(k, N)`` array of a 32-bit dtype or
+    ``k`` 1-D lanes, most significant first; ``valid`` and ``key_bytes`` as
+    ``sort_rows`` takes them.  The first end of ``sort_rows``, shared with a
+    caller that applies the permutation itself (``transport/tpu.py``
+    ``ordered_records``: a slice of it, the padding zeroed in the host's form).
+
+    ONE ``lax.sort`` over (the key lanes..., the row index) with every
+    operand a key — the index last, so the order is total: stable by
+    construction and the same on every run without ``is_stable``.  Rows that
+    are not valid sort last by the all-ones key where no row can have it (a
+    byte-string key whose last lane is masked, ``key_bytes`` no multiple of
+    4), else by a leading flag lane.  The sort's compile time grows with its
+    operands and doubles with ``is_stable`` (161 s for five stable operands,
+    54 s for these four; PERF.md section 6, PR 48), so nothing rides along
+    that need not."""
+    lanes = [jax.lax.bitcast_convert_type(lane, jnp.uint32) for lane in keys]
+    if key_bytes is not None and len(lanes) != key_lanes_of(key_bytes):
+        raise ValueError(f"a {key_bytes}-byte key is {key_lanes_of(key_bytes)} lanes, not {len(lanes)}")
+    idx = jnp.arange(lanes[0].shape[0], dtype=jnp.int32)
+    valid = jnp.asarray(valid)
+    if valid.ndim == 0:
+        valid = idx < valid
+    tail = 4
+    if key_bytes is not None:
+        lanes = [_byteswap32(lane) for lane in lanes]
+        tail = key_bytes - 4 * (len(lanes) - 1)  # key bytes in the last lane: 1..4
+        if tail < 4:
+            lanes[-1] = lanes[-1] & jnp.uint32((0xFFFFFFFF << (8 * (4 - tail))) & 0xFFFFFFFF)
+    if tail < 4:  # the all-ones key is no row's: padding rows take it
+        lanes = [jnp.where(valid, lane, KEY_MAX) for lane in lanes]
+    else:
+        lanes.insert(0, jnp.logical_not(valid).astype(jnp.uint32))
+    order = jax.lax.sort((*lanes, idx), num_keys=len(lanes) + 1, is_stable=False)[-1]
+    return order, valid.sum(dtype=jnp.int32)
+
+
 def sort_rows(
     rows: jnp.ndarray,
     key_lanes: int,
@@ -144,45 +185,24 @@ def sort_rows(
     — each lane byte-swapped, the last one masked to the key's bytes in it;
     ``key_lanes`` must be ``key_lanes_of(k)``.
 
-    How: ONE ``lax.sort`` over (the key lanes..., the row index) with every
-    operand a key — the index last, so the order is total: stable by
-    construction and the same on every run without ``is_stable`` — then one
-    row gather by the sorted index, so the payload lanes move once, whatever
-    the key's width.  Rows that are not valid sort last by the all-ones key
-    where no row can have it (a byte-string key whose last lane is masked,
-    ``key_bytes`` no multiple of 4), else by a leading flag lane.  Measured on
-    the chip at TeraSort's shape (342,784 rows of 25 lanes; PERF.md section 6,
-    PR 48): the sort 1.7 ms, the row gather 4.7 ms.  An index costs XLA's TPU
-    gather ~10 ns whatever it fetches (a 1-D gather of a key lane 3.6 ms), so
-    sorting a lane at a time through the order so far is six times slower
-    (9.8 ms); and the sort's compile time grows with its operands and doubles
-    with ``is_stable`` (161 s for five stable operands, 54 s for these four),
-    so nothing rides along that need not.  Chosen by the code; there is no
-    host fallback."""
-    n, width = rows.shape
-    if not 1 <= key_lanes <= width:
-        raise ValueError(f"key_lanes {key_lanes} of rows {width} lanes wide")
-    if key_bytes is not None and key_lanes != key_lanes_of(key_bytes):
-        raise ValueError(f"a {key_bytes}-byte key is {key_lanes_of(key_bytes)} lanes, not {key_lanes}")
-    idx = jnp.arange(n, dtype=jnp.int32)
-    valid = jnp.asarray(valid)
-    if valid.ndim == 0:
-        valid = idx < valid
-    lanes = [jax.lax.bitcast_convert_type(rows[:, i], jnp.uint32) for i in range(key_lanes)]
-    tail = 4
-    if key_bytes is not None:
-        lanes = [_byteswap32(lane) for lane in lanes]
-        tail = key_bytes - 4 * (key_lanes - 1)  # key bytes in the last lane: 1..4
-        if tail < 4:
-            lanes[-1] = lanes[-1] & jnp.uint32((0xFFFFFFFF << (8 * (4 - tail))) & 0xFFFFFFFF)
-    if tail < 4:  # the all-ones key is no row's: padding rows take it
-        lanes = [jnp.where(valid, lane, KEY_MAX) for lane in lanes]
-    else:
-        lanes.insert(0, jnp.logical_not(valid).astype(jnp.uint32))
-    order = jax.lax.sort((*lanes, idx), num_keys=len(lanes) + 1, is_stable=False)[-1]
+    How: the key lanes taken from ``rows`` ONCE, as the rows of one ``(N, k)
+    -> (k, N)`` transposition (a TPU holds an ``(N, 25)`` array of 32-bit
+    lanes in rows of 128, five times its bytes, and ``rows[:, i]`` a lane was
+    a pass that wrote a padded ``(N, 1)`` column and another that read it
+    back: 2.3 ms for three lanes at TeraSort's shape, 0.23 taken once);
+    ``key_order`` over them (one ``lax.sort``); then one row gather by the
+    sorted index, so the payload lanes move once, whatever the key's width.
+    Inside the executable's own device trace at TeraSort's shape (342,784
+    rows of 25 lanes; ``scripts/probe_ordered_passes.py``, PR 55): the sort
+    0.80 ms, the row gather 1.25 ms — 3.6 ns an index whatever it fetches, so
+    sorting a lane at a time through the order so far would be slower.
+    Chosen by the code; there is no host fallback."""
+    if not 1 <= key_lanes <= rows.shape[1]:
+        raise ValueError(f"key_lanes {key_lanes} of rows {rows.shape[1]} lanes wide")
+    order, count = key_order(rows[:, :key_lanes].T, valid, key_bytes)
     # valid rows sort to the front, so the first ``count`` of the output are
     # the data; a padding row's lanes must not leak through the permutation
-    count = valid.sum(dtype=jnp.int32)
+    idx = jnp.arange(rows.shape[0], dtype=jnp.int32)
     return jnp.where((idx < count)[:, None], gather_rows(rows, order), jnp.zeros((), rows.dtype))
 
 

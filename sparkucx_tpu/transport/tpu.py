@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 import threading
 import time
 from dataclasses import dataclass, field
@@ -61,9 +62,9 @@ from sparkucx_tpu.core.transport import ExecutorId, ShuffleTransport
 from sparkucx_tpu.native import LandingPool
 from sparkucx_tpu.parallel.membership import ClusterMembership
 from sparkucx_tpu.parallel.mesh import executor_mesh, surviving_submesh
-from sparkucx_tpu.ops.exchange import bucket_send_rows, rebucket_slots
+from sparkucx_tpu.ops.exchange import bucket_send_rows, gather_rows, rebucket_slots
 from sparkucx_tpu.ops.planner import PlanContext, PlanSignals, make_planner
-from sparkucx_tpu.ops.sort import key_lanes_of, sort_rows
+from sparkucx_tpu.ops.sort import key_lanes_of, key_order
 from sparkucx_tpu.ops.skew import (
     ExchangePlan,
     chunk_size_rows,
@@ -197,22 +198,50 @@ def ordered_records(table, *segments, record_lanes: int, key_bytes: int, flat: b
     ``segments``: ``(rows, lane)`` int32 buffers in which every block starts
     on a slot boundary (``TpuShuffleCluster._ordered_geometry``), so each IS a
     ``(record places, record_lanes)`` array by reshape; ``table``: (2, B)
-    int32, the first record place and the record count of every block (places
-    no block covers are padding).  Returns the first segment's count of
-    places — every record of the task fits them — sorted by the records'
-    first ``key_bytes`` bytes (``ops.sort.sort_rows``), padding last and
-    zero.  ``flat``: as ONE row-major 1-D array, the form that crosses to the
-    host — XLA lays a 25-lane 2-D array out column-major on a TPU, and
-    ``np.asarray`` of it is a strided host array whose turning round costs
-    more than the sort; the reshape does it on the chip."""
-    places = [seg.reshape(-1, record_lanes) for seg in segments]
-    capacity = places[0].shape[0]
-    records = places[0] if len(places) == 1 else jnp.concatenate(places)
-    place = jnp.arange(records.shape[0], dtype=jnp.int32)[:, None]
+    int32, the first record place and the record count of every block — no
+    two share a slot; an entry of no records is all zeros (places no block
+    covers are padding, and hold whatever the block gather left there).
+    Returns the first segment's count of places — every record of the task
+    fits them — sorted by the records' first ``key_bytes`` bytes, equal keys
+    in the order of their places (``ops.sort.key_order``), padding last and
+    zero.  ``flat``: as ONE
+    row-major 1-D array, the form that crosses to the host; else
+    ``(capacity, record_lanes)``, which a TPU hands out column-major (a
+    strided host array if it crosses).
+
+    What it costs, by the executable's own device trace at TeraSort's shape
+    (342,784 places of 25 lanes; ``scripts/probe_ordered_passes.py``, PR 55):
+    2.93 ms, of which the row gather 1.25 and the sort 0.80.  Inside the
+    executable a 25-lane array lies in rows of 128 lanes, 175 MB for 34 MB of
+    records, and every pass over that form costs 0.23–0.53 ms, so there are
+    three and no more: the reshape that makes it (0.33, what the row gather
+    reads), the key lanes taken from it once (0.23) and the reshape of the
+    gathered rows to the host's form (0.23).  The padding is zeroed in that
+    1-D form (0.05)."""
+    lane = segments[0].shape[1]
+    slot_records = lane // math.gcd(record_lanes, lane)  # record places a slot
+    # segments are joined as the 128-lane rows they are: joined as records
+    # they would be copied at five times their bytes (a TPU pads 25 lanes to 128)
+    rows = segments[0] if len(segments) == 1 else jnp.concatenate(segments)
+    records = rows.reshape(-1, record_lanes)
+    capacity = segments[0].size // record_lanes
+    # a block starts on a slot boundary and no two share a slot, so a place is
+    # covered iff its offset in its slot is under the slot's count of records:
+    # (slots, B) compares and ONE compare a place, not (places, B)
     first, count = table[0][None, :], table[1][None, :]
-    valid = ((place >= first) & (place < first + count)).any(axis=1)
-    ordered = sort_rows(records, key_lanes_of(key_bytes), valid, key_bytes)[:capacity]
-    return ordered.reshape(-1) if flat else ordered
+    base = (jnp.arange(records.shape[0] // slot_records, dtype=jnp.int32) * slot_records)[:, None]
+    covered = jnp.where(first <= base, jnp.clip(first + count - base, 0, slot_records), 0).max(axis=1)
+    valid = (jnp.arange(slot_records, dtype=jnp.int32)[None, :] < covered[:, None]).reshape(-1)
+    order, n = key_order(records[:, : key_lanes_of(key_bytes)].T, valid, key_bytes)
+    # every record of the task fits the first segment's places: the gather
+    # fetches those and no more.  What an uncovered place holds is the block
+    # gather's to leave unspecified, and the padding comes out zero whatever
+    # it is: only output places [n, capacity) can hold it
+    ordered = gather_rows(records, order[:capacity])
+    if flat:  # the form the host takes, zeroed at its own 34 MB and not at the rows' 175
+        ordered, n = ordered.reshape(-1), n * record_lanes
+    live = jnp.arange(ordered.shape[0], dtype=jnp.int32) < n
+    return jnp.where(live if flat else live[:, None], ordered, 0)
 
 
 class _MeshChanged(Exception):

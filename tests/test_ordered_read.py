@@ -324,3 +324,107 @@ def test_an_ordered_batch_outlives_its_shuffle():
         want = records.sorted_partition(6)
         mgr.unregister_shuffle(0)
         assert np.array_equal(kept, want)
+
+
+# -- ``ordered_records`` fed directly: what no gather's accidental zeros hide --
+
+def placed(blocks, width, lane=128, capacity_slots=None, poison=True):
+    """``blocks`` = ``[(segment, (n, width) uint8 rows or None)]`` laid out as
+    the slot-aligned gather leaves them — each from a slot boundary of its
+    segment, in order — as ``(table, segments, rows in table order)``.  Every
+    place no block covers is **poisoned**: all ones, or zeros (a key before
+    every real key).  ``None`` is an entry the locate leaves at (0, 0)."""
+    lanes = width // 4
+    slot_records = lane // np.gcd(lanes, lane)
+    slot_rows = slot_records * lanes // lane
+    count = 1 + max(seg for seg, _ in blocks)
+    used = [0] * count
+    for seg, rows in blocks:
+        used[seg] += -(-len(rows) // slot_records) if rows is not None else 0
+    slots = capacity_slots or max(used)
+    places = np.zeros((count, slots * slot_records, width), dtype=np.uint8)
+    if poison:
+        places[:, 0::2] = 0xFF
+    table = np.zeros((2, 1 << (len(blocks) - 1).bit_length()), dtype=np.int32)
+    at = [0] * count
+    for i, (seg, rows) in enumerate(blocks):
+        if rows is None:
+            continue
+        places[seg, at[seg]: at[seg] + len(rows)] = rows
+        table[:, i] = (seg * slots * slot_records + at[seg], len(rows))
+        at[seg] += -(-len(rows) // slot_records) * slot_records
+    segments = [places[s].reshape(-1).view(np.int32).reshape(slots * slot_rows, lane) for s in range(count)]
+    rows = [r for _, r in blocks if r is not None and len(r)]
+    return table, segments, np.concatenate(rows) if rows else np.zeros((0, width), np.uint8)
+
+
+def _real_keys(rng, n, width):
+    rows = rng.integers(0, 256, size=(n, width), dtype=np.uint8)
+    rows[:, 0] |= 1  # no real key of zeros: the poison's would come first
+    return rows
+
+
+def _cases():
+    def poisoned(rng):
+        return [(0, _real_keys(rng, n, 100)) for n in (300, 1, 129)], {}
+
+    def exactly_full(rng):  # every place a record: no padding row, nothing to zero
+        return [(0, _real_keys(rng, n, 100)) for n in (256, 128, 384)], {}
+
+    def one_record(rng):
+        return [(0, _real_keys(rng, 1, 100))], {"capacity_slots": 3}
+
+    def empty_entries(rng):  # a bucket of 8 with 5 used, a block of 0 records between two full ones
+        rows = [_real_keys(rng, 128, 100), None, _real_keys(rng, 128, 100), _real_keys(rng, 0, 100),
+                _real_keys(rng, 77, 100)]
+        return [(0, r) for r in rows], {}
+
+    def two_segments(rng):  # blocks of two staging rounds: the second segment's places follow the first's
+        return [(0, _real_keys(rng, 200, 100)), (0, _real_keys(rng, 50, 100)),
+                (1, _real_keys(rng, 130, 100)), (1, _real_keys(rng, 3, 100))], {"capacity_slots": 4}
+
+    def equal_keys(rng):  # one key on all ten bytes, every value kept, in the order of their places
+        rows = [_real_keys(rng, n, 100) for n in (150, 90)]
+        for r in rows:
+            r[:, :10] = rows[0][0, :10]
+        return [(0, r) for r in rows], {}
+
+    def key_all_ones(rng):  # a real key of 0xFF.. still comes before the padding
+        rows = _real_keys(rng, 140, 100)
+        rows[::3, :10] = 0xFF
+        return [(0, rows)], {}
+
+    return [poisoned, exactly_full, one_record, empty_entries, two_segments, equal_keys, key_all_ones]
+
+
+@pytest.mark.parametrize("flat", [True, False], ids=["flat", "rows"])
+@pytest.mark.parametrize("case", _cases(), ids=lambda f: f.__name__)
+def test_ordered_records_against_numpy(rng, case, flat):
+    """The executable alone, its segments as the ``dma`` gather may leave
+    them: total order by (key, place), every record once, padding last and
+    zero whatever the uncovered places held."""
+    blocks, kw = case(rng)
+    table, segments, rows = placed(blocks, 100, **kw)
+    capacity = segments[0].size // 25
+    got = np.asarray(ordered_records(table, *segments, record_lanes=25, key_bytes=10, flat=flat))
+    assert got.dtype == np.int32 and got.shape == ((capacity * 25,) if flat else (capacity, 25))
+    got = got.reshape(-1).view(np.uint8).reshape(capacity, 100)
+    assert np.array_equal(got[: len(rows)], by_key_bytes(rows, 10))
+    assert not got[len(rows):].any()
+
+
+@pytest.mark.parametrize("width, key_bytes, lane", [(8, 4, 128), (20, 8, 128), (100, 10, 128), (12, 12, 128),
+                                                    (36, 10, 32), (100, 10, 32)])
+def test_ordered_records_key_widths_masked_and_unmasked(rng, width, key_bytes, lane):
+    """A last key lane that is masked (10 of 12 bytes) and one that is not
+    (4, 8, 12: the padding sorts by a flag lane), over poisoned places, at
+    128- and 32-lane rows."""
+    blocks = [rng.integers(0, 256, size=(n, width), dtype=np.uint8) for n in (150, 1, 77)]
+    for block in blocks:
+        block[:, : key_bytes - 1] &= 0x81  # few distinct leading bytes: the last key byte decides often
+        block[:, key_bytes - 1] |= 1       # and no real key of zeros
+    table, segments, rows = placed([(0, b) for b in blocks], width, lane=lane)
+    got = np.asarray(ordered_records(table, *segments, record_lanes=width // 4, key_bytes=key_bytes, flat=True))
+    got = got.view(np.uint8).reshape(-1, width)
+    assert np.array_equal(got[: len(rows)], by_key_bytes(rows, key_bytes))
+    assert not got[len(rows):].any()

@@ -532,3 +532,39 @@ def test_sort_rows_refuses_what_is_no_key():
         sort_rows(rows, 0, 4)
     with pytest.raises(ValueError, match="10-byte key is 3 lanes"):
         sort_rows(rows, 2, 4, key_bytes=10)
+
+
+@pytest.mark.parametrize("key_bytes", [4, 8, 10, 12])
+@pytest.mark.parametrize("case", ["poisoned", "all_valid", "one_valid", "none_valid"])
+def test_sort_rows_padding_is_last_and_zero_whatever_it_held(rng, case, key_bytes):
+    """Rows that are not valid hold what a gather left there — all ones, or
+    a key of zeros before every real key — and come out last and zero; with
+    every row valid there is nothing to zero, with one row that row leads."""
+    n, width = 300, 25
+    raw = rng.integers(0, 256, size=(n, width * 4), dtype=np.uint8)
+    raw[:, 0] |= 1
+    raw[: n // 3, :key_bytes] = raw[0, :key_bytes]  # one key, a hundred values: place order decides
+    mask = {"poisoned": rng.random(n) < 0.6, "all_valid": np.ones(n, bool),
+            "one_valid": np.arange(n) == 171, "none_valid": np.zeros(n, bool)}[case]
+    poison = np.flatnonzero(~mask)
+    raw[poison[0::2]] = 0xFF
+    raw[poison[1::2]] = 0
+    lanes = key_lanes_of(key_bytes)
+    fn = jax.jit(lambda r, valid: sort_rows(r, lanes, valid, key_bytes=key_bytes))
+    got = np.asarray(fn(jnp.asarray(raw.view(np.int32).reshape(n, width)), jnp.asarray(mask)))
+    got = got.view(np.uint8).reshape(n, width * 4)
+    kept = sorted(np.flatnonzero(mask), key=lambda i: (bytes(raw[i, :key_bytes]), i))
+    assert np.array_equal(got[: len(kept)], raw[kept]) and not got[len(kept):].any()
+
+
+def test_key_order_is_sort_rows_permutation(rng):
+    """``key_order``: the permutation and the count ``sort_rows`` applies."""
+    from sparkucx_tpu.ops.sort import key_order
+
+    rows = rng.integers(-5, 5, size=(200, 6)).astype(np.int32)
+    mask = rng.random(200) < 0.7
+    order, count = jax.jit(key_order)(jnp.asarray(rows[:, :2].T), jnp.asarray(mask))
+    order, count = np.asarray(order), int(count)
+    assert count == mask.sum() and sorted(order.tolist()) == list(range(200))
+    want = _lexsort_rows(rows, 2, mask)
+    assert np.array_equal(rows[order][:count], want[:count]) and not mask[order[count:]].any()
