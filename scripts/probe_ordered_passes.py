@@ -1,7 +1,9 @@
 #!/usr/bin/env python
 """What does each pass of the ordering executable cost, and what do four
-D2Hs in flight sustain?  The probe of PR 55 (ISSUE "Price it first").  Needs
-the chip for its times.
+D2Hs in flight sustain?  The probe of PR 55 (ISSUE "Price it first"); kept
+because ``--d2h`` / ``--pieces`` price ROADMAP queue 1 item 2(a), which is
+open: the latency of one D2H stream under a reduce task's serial chain.
+Needs the chip for its times.
 
 ``ts10gb-sortedjobs-4tasks-1chip`` runs ``jit_ordered_records`` once a reduce
 task, 75 times a job, at one shape: one gathered segment ``int32[66950, 128]``

@@ -1,6 +1,9 @@
 #!/usr/bin/env python
 """Can a one-round job's staging go to the chip while it is still being
-written?  The probe of PR 51 (ISSUE "Probe first").  Needs a device.
+written?  The probe of PR 51 (ISSUE "Probe first"); kept because its
+``store`` order is the chip's only whole-round check of the put behind the
+writer — the benchmark's full comparison is of a store's first job, which
+puts nothing early (ROADMAP queue 1 item 15, open).  Needs a device.
 
 ``gbt25k-devfetch-1chip`` copies a job's 5,000 blocks of about 625 KB into ONE
 held 4 GiB round buffer (0.36 s) and only then, at ``seal``, puts the 3.13 GB

@@ -1,7 +1,10 @@
 #!/usr/bin/env python
 """What do four map tasks that write ONE store at once cost, through the
-program's own path?  The probe of PR 53 (ISSUE "The probe first").  Needs a
-device for the orders that put.
+program's own path?  The probe of PR 53 (ISSUE "The probe first"); kept
+because it prices the four-writer path — ``MapWriter.close_partition`` over
+the store's ``take_extent`` / ``record_extent`` (``store/writer.py``, PR 56)
+and the puts behind it, ROADMAP queue 1 item 5(c), open — from either side
+of a change to it.  Needs a device for the orders that put.
 
 ``ts10gb-sortedjobs-4tasks-1chip`` has four slot threads write one store:
 19 map tasks of 75 blocks of 1.79 MB, 2.55 GB a job into one held 4 GiB

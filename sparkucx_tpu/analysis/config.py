@@ -12,10 +12,6 @@ from __future__ import annotations
 #: Reviewed exceptions, grouped by pass.
 #:
 #: private-access (migrated verbatim from scripts/lint_private_access.py):
-#: - hbm_store.py: MapWriter is a friend class defined in the SAME file as
-#:   HbmBlockStore — allocation and epoch rollover must happen under the
-#:   store's one lock, and exposing that lock publicly would invite misuse
-#:   from outside the file.  Reviewed round 3; keep to same-file friends only.
 #: - core/block.py: ``np.memmap`` exposes no public way to close its mapping —
 #:   ``mm._mmap.close()`` is the canonical numpy idiom for releasing the fd
 #:   eagerly (numpy/numpy#13510); guarded by try/except for numpy internals
@@ -28,36 +24,6 @@ from __future__ import annotations
 #:   function of (socket, parts) — no BlockServer state — kept underscored
 #:   because the iovec windowing is an implementation detail of the wire,
 #:   not transport API.  Reviewed with the striped-wire PR.
-#: - hbm_store.py ``._charge_tenant`` / ``._staging``: same-file friends
-#:   again — MapWriter/DeviceMapWriter must run the tenant admission check
-#:   inside the store-lock critical section that allocates the region (an
-#:   over-quota write must fail typed with nothing allocated), and the tier
-#:   probe ``_tier_of`` classifies a round by its ``_ShuffleState._staging``
-#:   backing (memmap vs RAM).  Both stay underscored: admission and tier
-#:   state are store internals, not writer/eviction API.  Reviewed with the
-#:   multi-tenant service PR.
-#: - hbm_store.py ``._write_stats``: same-file friend once more — at its
-#:   commit MapWriter adds its blocks, bytes and copy time to the store's
-#:   map-side write counters (the ``store`` metrics family) inside the
-#:   store-lock section that records the commit.  Reviewed with the
-#:   layer-spans tracing PR.
-#: - hbm_store.py ``._release_tenant`` / ``._await_drained`` /
-#:   ``._receive_ended``: the same friend, for a partition received in place
-#:   (``MapWriter.reserve`` / ``end_receive``).  The reservation is made and
-#:   ended inside the store-lock section that allocates the region: it holds
-#:   back while a seal, a spill or a removal drains the shuffle's receives,
-#:   gives the round's in-flight count back on the store's condition, and
-#:   returns the tenant charge of an extent that is abandoned (the pair of
-#:   ``._charge_tenant`` above).  The count and the condition are the store's
-#:   own bookkeeping of who may touch a round's buffer, not writer API.
-#:   Reviewed with the receive-in-place PR.
-#: - hbm_store.py ``._put_behind``: the same friend, after a block is recorded
-#:   (``close_partition``) or a receive in place has ended (``end_receive``)
-#:   — the writer whose block took a region past the end of a piece puts that
-#:   piece of the single round on the store's device, on its own thread and
-#:   OUTSIDE the store's lock.  Who may run the donated update chain, and
-#:   when a piece's bytes are final, is the store's bookkeeping, not writer
-#:   API.  Reviewed with the put-behind-the-writer PR (PR 51).
 #: - service/tenants.py ``._gate``: ``Tenant`` is a same-file data holder of
 #:   its ``TenantRegistry`` — the registry lazily creates the per-tenant
 #:   CreditGate under its own lock; exposing the slot publicly would invite
@@ -115,23 +81,10 @@ from __future__ import annotations
 #:   (fixed per store), not from data, so distinct values are bounded by
 #:   distinct configs.  Bucketing it would over-allocate the HBM staging
 #:   array itself rather than a transient pad.
-#: - hbm_store.py ``._stage_device``: the same friend — MapWriter's packed
-#:   device write dispatches its scatter inside the critical section that
-#:   allocates the rows (and before the rollover that an overflow forces).
 ALLOWLIST = {
     ("testing/faults.py", "private-access", "._conns"),
     ("testing/faults.py", "private-access", "._zombies"),
     ("testing/faults.py", "private-access", "._chaos_killed"),
-    ("store/hbm_store.py", "private-access", "._lock"),
-    ("store/hbm_store.py", "private-access", "._rollover"),  # also ._rollover_device
-    ("store/hbm_store.py", "private-access", "._charge_tenant"),
-    ("store/hbm_store.py", "private-access", "._release_tenant"),
-    ("store/hbm_store.py", "private-access", "._await_drained"),
-    ("store/hbm_store.py", "private-access", "._receive_ended"),
-    ("store/hbm_store.py", "private-access", "._put_behind"),
-    ("store/hbm_store.py", "private-access", "._stage_device"),
-    ("store/hbm_store.py", "private-access", "._staging"),
-    ("store/hbm_store.py", "private-access", "._write_stats"),
     ("service/tenants.py", "private-access", "._gate"),
     ("core/block.py", "private-access", "._mmap"),
     ("shuffle/daemon.py", "private-access", "._sendmsg_all"),
@@ -153,6 +106,8 @@ REQUIRED_SURFACE = {
             "seal", "map_writer", "read_block", "block_staging_view",
             "region_bytes", "num_rounds", "host_staging_allocated",
         ],
+    },
+    "store/writer.py": {
         "MapWriter": [
             "write_partition", "write_partition_device", "write_partitions_device", "commit",
         ],
@@ -361,8 +316,6 @@ OFF_PATH_DEFAULTS = {
     "membership_suspect_after_ms": 0,
     "replication_max_backlog_bytes": 0,
     "tenants_enabled": False,
-    "tenant_hbm_quota_bytes": 0,
-    "eviction_epoch_ms": 0,
     "server_workers": 0,
     "exchange_impl": "stock",
     "device_staging": False,

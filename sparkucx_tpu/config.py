@@ -60,8 +60,6 @@ class TpuShuffleConf:
     staging_capacity_per_executor    NVKV device-space carve-up / 30 MB read buf
                                      (NvkvHandler.scala:26-29,
                                      NvkvShuffleMapOutputWriter.scala:94-103)
-    store_port                       DPU daemon port 1338
-                                     (CommonUcxShuffleManager.scala:84-89)
     ===============================  ==============================================
     """
 
@@ -293,7 +291,6 @@ class TpuShuffleConf:
     # (NvkvHandler.scala:244-256).
     block_alignment: int = 512
     staging_capacity_per_executor: int = 64 << 20
-    store_port: int = 1338
     serve_from_store: bool = True  # spark.dpuTest.enabled analogue
     # (compat/spark_3_0/UcxShuffleBlockResolver.scala:86-90, default true)
     #: Stage shuffle output in named shared memory so co-located executor
@@ -347,18 +344,6 @@ class TpuShuffleConf:
     #: store behavior stay byte-identical to the single-tenant build (the
     #: golden captures the CI wire gate pins).
     tenants_enabled: bool = False
-    #: Default per-tenant HBM staging quota in bytes, charged at region
-    #: allocation time against the tenant's registered budget; an over-quota
-    #: write raises a typed TenantQuotaExceededError instead of eating a
-    #: neighbor tenant's HBM.  0 = unlimited (admission checks disabled for
-    #: tenants registered without an explicit quota).
-    tenant_hbm_quota_bytes: int = 0
-    #: Tiered-eviction epoch (ms): every epoch the EvictionManager
-    #: (service/eviction.py) demotes the least-recently-fetched sealed rounds
-    #: one tier down (HBM-resident jax.Array -> host snapshot -> np.memmap
-    #: spill), and fetches restage demoted rounds transparently.  0 = no
-    #: background demotion (manual ``run_epoch()`` only).
-    eviction_epoch_ms: int = 0
     #: Serving-plane worker pool size for the shared selectors-based reactor
     #: (service/reactor.py) that replaces thread-per-connection accept loops
     #: in shuffle/daemon.py and the transport/peer.py block server.  0 keeps
@@ -531,7 +516,7 @@ class TpuShuffleConf:
         ``...listener.sockaddr``, ``...useWakeup``, ``...numIoThreads``,
         ``...numListenerThreads``, ``...numClientWorkers``,
         ``...maxBlocksPerRequest``, ``...blockAlignment``, ``...stagingCapacity``,
-        ``...storePort``, ``...serveFromStore``, ``...numExecutors``.
+        ``...serveFromStore``, ``...numExecutors``.
         """
         p = CONF_PREFIX
 
@@ -595,7 +580,6 @@ class TpuShuffleConf:
             ("membership.suspectAfterMs", "membership_suspect_after_ms", int),
             ("blockAlignment", "block_alignment", parse_size),
             ("stagingCapacity", "staging_capacity_per_executor", parse_size),
-            ("storePort", "store_port", int),
             ("serveFromStore", "serve_from_store", lambda v: str(v).lower() == "true"),
             ("useShmStaging", "use_shm_staging", lambda v: str(v).lower() == "true"),
             ("shmNamespace", "shm_namespace", str),
@@ -613,8 +597,6 @@ class TpuShuffleConf:
             ("spillDiskCap", "spill_disk_cap_bytes", parse_size),
             ("reduceMemoryBudget", "reduce_memory_budget", parse_size),
             ("tenants.enabled", "tenants_enabled", lambda v: str(v).lower() == "true"),
-            ("tenants.hbmQuotaBytes", "tenant_hbm_quota_bytes", parse_size),
-            ("eviction.epochMs", "eviction_epoch_ms", int),
             ("server.workers", "server_workers", int),
             ("pipelineDepth", "pipeline_depth", int),
             ("slotQuotaRows", "slot_quota_rows", int),
@@ -695,10 +677,6 @@ class TpuShuffleConf:
             raise ValueError(f"unknown quantize_mode {self.quantize_mode!r}")
         if self.quantize_block_size <= 0 or self.quantize_block_size % 4:
             raise ValueError("quantize_block_size must be a positive multiple of 4")
-        if self.tenant_hbm_quota_bytes < 0:
-            raise ValueError("tenant_hbm_quota_bytes must be >= 0 (0 = unlimited)")
-        if self.eviction_epoch_ms < 0:
-            raise ValueError("eviction_epoch_ms must be >= 0 (0 = manual epochs)")
         if self.server_workers < 0:
             raise ValueError("server_workers must be >= 0 (0 = thread-per-connection)")
         if self.fetch_hedge_ms < 0:

@@ -6,8 +6,9 @@ spill (``HbmBlockStore._spill_round``) — but until now a round only ever
 moved DOWN at rollover time and never back.  The EvictionManager turns those
 tiers into a managed cache:
 
-* **Demotion**: every epoch (``spark.shuffle.tpu.eviction.epochMs``, or a
-  manual :meth:`run_epoch`), the least-recently-fetched sealed rounds are
+* **Demotion**: every epoch (the constructor's ``epoch_ms`` once
+  :meth:`start` was called, or a manual :meth:`run_epoch`), the
+  least-recently-fetched sealed rounds are
   demoted one tier (``hbm`` -> ``host`` -> ``disk``) through
   ``HbmBlockStore.demote_round``.  Cold shuffles drain out of HBM and RAM;
   fetches keep working at every tier (``read_block`` serves memmaps too).

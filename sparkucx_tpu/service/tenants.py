@@ -70,8 +70,7 @@ class TenantRegistry:
         default_quota_bytes: int = 0,
         default_credit_bytes: int = 0,
     ) -> None:
-        #: Quota applied when ``register`` is called without one
-        #: (``spark.shuffle.tpu.tenants.hbmQuotaBytes``); 0 = unlimited.
+        #: Quota applied when ``register`` is called without one; 0 = unlimited.
         self.default_quota_bytes = int(default_quota_bytes)
         #: Serving-plane CreditGate budget per tenant; 0 disables the gates.
         self.default_credit_bytes = int(default_credit_bytes)

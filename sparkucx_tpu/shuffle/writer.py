@@ -20,7 +20,8 @@ import numpy as np
 
 from sparkucx_tpu.core.operation import TransportError
 from sparkucx_tpu.core.transport import ShuffleTransport
-from sparkucx_tpu.store.hbm_store import HbmBlockStore, MapWriter
+from sparkucx_tpu.store.hbm_store import HbmBlockStore
+from sparkucx_tpu.store.writer import MapWriter
 
 
 class PartitionWriterStream:

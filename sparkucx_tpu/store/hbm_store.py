@@ -38,11 +38,9 @@ from __future__ import annotations
 
 import functools
 import gc
-import resource
 import shutil
 import sys
 import threading
-import time
 from time import perf_counter_ns
 import weakref
 from bisect import bisect_right
@@ -62,9 +60,10 @@ from sparkucx_tpu.core.operation import (
     TransportError,
 )
 from sparkucx_tpu.service.eviction import ServeCache
+from sparkucx_tpu.store.writer import MapWriter
 from sparkucx_tpu.testing import faults
 from sparkucx_tpu.utils.logging import get_logger
-from sparkucx_tpu.utils.trace import TRACER, span
+from sparkucx_tpu.utils.trace import span
 
 logger = get_logger("store.hbm_store")
 
@@ -77,38 +76,6 @@ SEAL_PUT_PIECE_BYTES = 64 << 20
 #: pieces whose transfer may be outstanding before the next is put: what HBM
 #: holds beside the round itself while it is being put
 SEAL_PUT_PIECES_IN_FLIGHT = 2
-#: under full tracing, the buffered-path blocks of the process recorded by
-#: phase (``write.block`` and its three children): numbers 0, 199, 398, ...
-#: counted over every writer of the process since tracing came on.  A prime
-#: that divides none of the benchmark's blocks a map task (200, 100, 75, 63),
-#: so the sampled reduce ids rotate from task to task; 32 of the 1k job's
-#: 6,350 blocks a job.  At one in 37 (172 a job) the traced write of that job
-#: was 6 ms, 7%, longer on the chip's host (``PERF.md`` section 6, PR 50).
-WRITE_BLOCK_EVERY = 199
-_blocks_traced = 0  # benign race between writer threads: a sampling count
-_WRITE_BLOCK_PHASES = ("write.block.admit", "write.block.copy", "write.block.record")
-#: the calling thread's resource usage, where the platform has it (Linux)
-_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
-
-
-@functools.lru_cache(maxsize=None)
-def _kernel_counts_faults() -> bool:
-    """Whether a thread's ``ru_minflt`` can be read and the kernel keeps the
-    count at all, asked once a process: a process that has imported NumPy has
-    faulted thousands of pages in, so a count of 0 for the whole process is a
-    kernel that keeps none (a sandboxed one: the chip's host, where the read
-    is a 9 us system call that answers 0 through any first touch; ``PERF.md``
-    section 6, PR 50).  There a task's rise says nothing and is left out."""
-    return _RUSAGE_THREAD is not None and resource.getrusage(resource.RUSAGE_SELF).ru_minflt > 0
-
-
-def _thread_minor_faults() -> Optional[Tuple[int, int]]:
-    """``(thread ident, ru_minflt)`` of the calling thread: page faults that
-    needed no I/O — a first touch of a fresh page is one.  None where the
-    kernel keeps no such count."""
-    if not _kernel_counts_faults():
-        return None
-    return threading.get_ident(), resource.getrusage(_RUSAGE_THREAD).ru_minflt
 
 
 @functools.lru_cache(maxsize=None)
@@ -232,16 +199,6 @@ def _device_block_bytes(array, offset: int, length: int, alignment: int) -> np.n
     return np.asarray(window).reshape(-1).view(np.uint8)[skip : skip + length]
 
 
-def _copy_chunks(staging: np.ndarray, start: int, chunks: Sequence[bytes]) -> None:
-    """A buffered block's copy: the writer's ``chunks`` back to back into
-    ``staging`` from byte ``start`` — slice assignment, one ``memcpy`` a chunk
-    with the interpreter given up."""
-    for chunk in chunks:
-        n = len(chunk)
-        staging[start : start + n] = np.frombuffer(chunk, dtype=np.uint8)
-        start += n
-
-
 @dataclass
 class _BlockEntry:
     offset: int  # absolute offset in the staging buffer (of its round)
@@ -293,7 +250,7 @@ class _PutBehind:
     last whole row received (a buffered copy: not at all) until the partition
     is recorded, lost or sent back to the buffered path: with four writers a
     copy is in flight nearly always, and the record that completes a piece
-    may be an older extent's, finishing last (``MapWriter._record``).  The pieces are
+    may be an older extent's, finishing last (``HbmBlockStore.record_extent``).  The pieces are
     ``_put_round``'s own (whole pieces at the same offsets, at most
     ``SEAL_PUT_PIECES_IN_FLIGHT`` awaiting their transfer), so the seal puts
     what is left — the piece each writer stands in, a piece that straddles
@@ -332,7 +289,7 @@ class _PutBehind:
         for p in range(regions):
             self._aim(p)
         #: the extents whose bytes are on their way into the round outside
-        #: the store's lock: from ``_take_extent`` with ``hold`` (a
+        #: the store's lock: from ``take_extent`` with ``hold`` (a
         #: partition's first ``reserve``, a buffered close) until the
         #: partition is recorded, lost or back on the buffered path
         self.open: set = set()
@@ -446,7 +403,7 @@ class _ShuffleState:
         #: never serves fresh zeros as block bytes) once this is set.
         self.removed = False  #: guarded by self._lock
         #: staging round -> extents being filled outside the store's lock
-        #: (``MapWriter._take_extent`` with ``hold`` takes one; a socket's
+        #: (``HbmBlockStore.take_extent`` with ``hold`` takes one; a socket's
         #: ``end_receive``, a buffered ``close_partition`` gives it back);
         #: whoever would read, zero or hand on a round's buffer waits for its
         #: count to reach zero (``HbmBlockStore._await_receives``)
@@ -516,837 +473,6 @@ class _ShuffleState:
         return self.sealed_payload is not None
 
 
-class MapWriter:
-    """Sequential per-map partition writer handle.
-
-    Mirrors the ``NvkvShufflePartitionWriter``/``PartitionWriterStream`` protocol:
-    partitions must be opened in increasing reduce order
-    (NvkvShuffleMapOutputWriter.scala:108), a partition's bytes stream in via any
-    number of ``write`` calls, and ``close_partition`` pads to alignment and
-    records (offset, length) (:236-246).
-
-    Concurrency: streamed bytes buffer writer-locally (the role of the
-    reference's 8 KB pinned write buffer, NvkvHandler.scala:26,213-242) and
-    reach staging at close, in three steps that the buffered close and a
-    partition fed from a socket (``reserve`` / ``end_receive``; the daemon's
-    ``WritePartition``) share.  **Atomic under the store's lock, before a
-    byte moves** (``_take_extent``): the admission checks, the tenant charge,
-    the rollover when the region cannot take the block, the region allocate
-    and the round's in-flight count.  **Outside the lock**: the bytes reach
-    the extent — the copy of the writer's chunks, the socket's ``recv_into``
-    — which no block names yet and no other writer can be given; so the map
-    tasks of an executor's slots copy into one store AT ONCE, and what a
-    task waits at the lock is other tasks' allocates and records, not their
-    copies (``lock_wait_ns``).  **Atomic under it again** (``_record``): the
-    table entry, which names the extent and the round it was taken in.
-    ``close_partition`` returns after the record, so a task's ``commit``
-    follows the last byte of its last block.  While the shuffle has ONE
-    writer open (``_ShuffleState.open_writers``: every cell but the executor
-    with task slots) nobody can be kept waiting by a copy, and a buffered
-    block keeps the lock through all three steps — the same sequence with
-    the release and the second take left out, a microsecond or two a block
-    (``unlocked_copy_blocks`` / ``unlocked_copy_bytes`` count the others).
-
-    A rollover MAY interleave with bytes on their way on the RAM arm — the
-    completed round's buffer lives on in ``prev_rounds`` and the copy or the
-    receive ends in it — and with a partition that is reserved but not closed
-    on either arm (its bytes are in the round, wherever the round went).  A
-    rollover's disk arm, ``seal``, ``remove_shuffle`` and ``close`` may NOT:
-    whoever would read, zero or hand on a round's buffer first waits, on the
-    store's condition, until nothing is in flight into it
-    (``inflight_wait_ns``); while one waits no new extent is held.  Bytes
-    that never fully arrive — a body cut short, a copy that raised — leave
-    their extent a hole that no entry names (padding; tenant charge given
-    back) and the partition lost: the map cannot commit, so the retry writes
-    it again.
-
-    Tracing (``docs/OBSERVABILITY.md``; PR 50): a committed writer is one span
-    ``write.task``, from its creation to the end of its commit (``end_task``),
-    recorded from clock marks at the commit — nothing is open meanwhile.
-    Under full tracing it has the children ``write.task.copy`` and
-    ``write.task.lock_wait`` (summed spans of ``_copy_ns`` / ``_lock_wait_ns``:
-    no clock is read for them that was not read before), ``write.task.commit``
-    and, one buffered-path block in ``WRITE_BLOCK_EVERY`` of the process,
-    ``write.block`` with the three phases of its ``close_partition``; and the
-    argument ``minor_faults``.  A discarded retry and an aborted writer record
-    nothing.  Untraced, a block pays one ``None`` check at its open and two
-    at its close.
-
-    Behind the writer (``_PutBehind``; PR 51): where the store will seal the
-    shuffle's single round onto its device in pieces, the ``close_partition``
-    (or ``end_receive``) that takes a region's used prefix past the end of a
-    piece puts that piece from this thread, after the block is recorded and
-    outside the store's lock (``HbmBlockStore._put_behind``).  Anywhere else
-    a block pays one more ``None`` check under the lock.
-    """
-
-    def __init__(
-        self, store: "HbmBlockStore", state: _ShuffleState, map_id: int, discard: bool = False
-    ) -> None:
-        global _blocks_traced
-        self._store = store
-        self._state = state
-        self.map_id = map_id
-        self._last_reduce = -1
-        self._open_reduce: Optional[int] = None
-        self._chunks: List[bytes] = []
-        self._written = 0
-        #: ns this writer spent copying payload (``bytes(data)`` in ``write``,
-        #: the staging copy in ``close_partition``); joins the store's
-        #: ``copy_ns`` counter at ``commit``
-        self._copy_ns = 0
-        #: ns this writer's ``close_partition`` calls waited for the store's
-        #: lock (other writers' copies and rollovers); joins ``lock_wait_ns``
-        #: at ``commit``
-        self._lock_wait_ns = 0
-        #: timed copies and takes of the store's lock beside the one of each
-        #: that a buffered block's ``close_partition`` makes (those are counted
-        #: at ``commit``, from the table): the summed spans' ``turns``
-        self._extra_copies = self._extra_lock_takes = 0
-        self._counted = False  # this writer's blocks are in the store's counters
-        #: the open partition's extent while it is received in place
-        self._resv: Optional[_Reservation] = None
-        self._receiving = False  # between ``reserve`` and ``end_receive``
-        self._lost = False  # a body of the open partition never fully arrived
-        #: blocks and bytes recorded in place and partitions that went back
-        #: to the buffered path; join the store's counters at ``commit``
-        self._inplace_blocks = self._inplace_bytes = self._inplace_fallbacks = 0
-        #: buffered blocks and bytes copied into their extent outside the
-        #: store's lock; join the store's counters at ``commit``
-        self._unlocked_blocks = self._unlocked_bytes = 0
-        #: First-commit-wins task-retry semantics: when a successful commit for
-        #: this map already exists, the retry attempt's writes are swallowed and
-        #: commit() returns the existing table — the reference's atomic
-        #: check-or-replace protocol (IndexShuffleBlockResolver.scala:161-217:
-        #: "if an existing index is valid, keep it and discard this attempt").
-        self._discard = discard
-        #: ``write.task``: the clock at this writer's creation, 0 where nothing
-        #: records (both switches off, a discarded retry) and once recorded
-        self._t_open = perf_counter_ns() if (TRACER.recording or TRACER.enabled) and not discard else 0
-        #: under full tracing: the sampled blocks' marks, waiting for the
-        #: commit; the open block's marks where it is sampled; the thread's
-        #: minor faults at creation.  ``None`` untraced: no mark is taken
-        self._blocks: Optional[List[Tuple[int, int, List[int]]]] = None
-        self._block: Optional[List[int]] = None
-        self._faults: Optional[Tuple[int, int]] = None
-        self._task: Optional[tuple] = None  # ``commit``'s marks, for ``end_task``
-        if not TRACER.enabled:
-            _blocks_traced = 0  # tracing is off: the next count starts anew
-        elif self._t_open:
-            self._blocks = []
-            self._faults = _thread_minor_faults()
-
-    def open_partition(self, reduce_id: int) -> None:
-        global _blocks_traced
-        if self._open_reduce is not None:
-            raise TransportError("previous partition still open")
-        if reduce_id <= self._last_reduce:
-            raise TransportError(
-                f"partitions must be opened in increasing reduce order "
-                f"(got {reduce_id} after {self._last_reduce})"
-            )
-        self._state.owner_of(reduce_id)  # validate range
-        self._open_reduce = reduce_id
-        self._chunks = []
-        self._written = 0
-        if self._blocks is not None:
-            n = _blocks_traced
-            _blocks_traced = n + 1
-            self._block = [perf_counter_ns()] if n % WRITE_BLOCK_EVERY == 0 else None
-
-    def write(self, data: bytes) -> None:
-        if self._open_reduce is None:
-            raise TransportError("no open partition")
-        if self._written + len(data) > self._state.region_size and not self._discard:
-            raise TransportError(
-                f"single partition ({self.map_id},{self._open_reduce}) exceeds a "
-                f"whole region ({self._state.region_size} B) — raise stagingCapacity"
-            )
-        if not self._discard:
-            if type(data) is bytes:  # bytes(data) would hand it back: no copy to time
-                self._chunks.append(data)
-            else:
-                t0 = perf_counter_ns()
-                self._chunks.append(bytes(data))
-                self._copy_ns += perf_counter_ns() - t0
-                self._extra_copies += 1
-        self._written += len(data)
-
-    def close_partition(self) -> None:
-        if self._open_reduce is None:
-            raise TransportError("no open partition")
-        marks = self._block  # a sampled block's clock marks (full tracing)
-        if marks is not None:
-            marks.append(perf_counter_ns())
-        if self._lost:
-            self._refuse_unsettled()
-        if self._resv is not None and self._close_reserved():
-            return
-        st, store = self._state, self._store
-        reduce_id = self._open_reduce
-        passed = False  # this block took its region's final mark past the end of a piece to put
-        if not self._discard:
-            padded = -(-self._written // st.alignment) * st.alignment
-            # watermark gate before taking the lock: a shed write fails typed
-            # (retryable ResourceExhaustedError) with nothing allocated
-            store.check_memory_pressure("close_partition", padded)
-            lock = store._lock
-            t_lock = perf_counter_ns()
-            with lock:
-                self._lock_wait_ns += perf_counter_ns() - t_lock
-                # the only writer open keeps nobody waiting: it keeps the lock
-                # through all three steps (a writer opened meanwhile waits
-                # for this one copy)
-                unlocked = st.open_writers > 1
-                staging, start = self._take_extent(padded, unlocked)
-                try:
-                    round_idx = st.round  # the extent's own: a rollover may interleave with the copy
-                    try:
-                        if unlocked:
-                            # the extent is this writer's alone: no block names
-                            # it yet and no other writer can be given it; whoever
-                            # would read, zero or hand on its round waits for the
-                            # in-flight count
-                            lock.release()
-                        t0 = perf_counter_ns()
-                        _copy_chunks(staging, start, self._chunks)
-                        t1 = perf_counter_ns()
-                    finally:
-                        if unlocked:
-                            t_lock = perf_counter_ns()
-                            lock.acquire()
-                            self._lock_wait_ns += perf_counter_ns() - t_lock
-                            self._extra_lock_takes += 1
-                            store._receive_ended(st, round_idx)
-                    if st.removed:  # a removal latches ``removed``, then waits for this copy
-                        raise TransportError(f"unknown shuffle {st.shuffle_id}")
-                except BaseException as e:
-                    self._lose(padded)
-                    if isinstance(e, TransportError) or not isinstance(e, Exception):
-                        raise  # an interrupt stays an interrupt
-                    raise TransportError(
-                        f"partition ({self.map_id},{reduce_id}) lost its copy into staging: {e!r}"
-                    ) from e
-                passed = self._record(start, padded, round_idx)
-            self._copy_ns += t1 - t0
-            if unlocked:
-                self._unlocked_blocks += 1
-                self._unlocked_bytes += self._written
-        self._last_reduce = reduce_id
-        self._open_reduce = None
-        self._chunks = []
-        if marks is not None:  # sampled only where ``_blocks`` is: never a discard
-            marks += (t0, t1, perf_counter_ns())
-            self._blocks.append((reduce_id, self._written, marks))
-            self._block = None
-        if passed:
-            store._put_behind(st)
-
-    # -- the three steps of a block, shared by the buffered close and the ---
-    # -- receive in place (callers hold the store's lock) --------------------
-
-    def _take_extent(self, padded: int, hold: bool) -> Optional[Tuple[np.ndarray, int]]:
-        """Everything a block needs under the store's lock before a byte of
-        it moves (caller holds the lock): no waiter is draining the shuffle,
-        the removed / sealed / device-mode checks, the tenant charge (an
-        over-quota write fails typed with nothing allocated, rolled over or
-        copied), the rollover when the region cannot take the block, the first
-        touch of the staging round and the region allocate — ``region_used``
-        moves by ``padded`` bytes, or by what ``padded`` adds to the extent
-        this writer's open partition already holds (a further frame of a
-        receive in place).  ``(staging, start)``: the live round's buffer and
-        the extent's absolute offset in it.
-
-        With ``hold`` the caller fills the extent OUTSIDE the lock: it
-        becomes this writer's ``_Reservation`` of the round it was made in
-        (joining ``put_behind.open`` where the round is put behind its
-        writers) and the round's in-flight count is taken, to be given back
-        by ``end_receive`` / ``close_partition``.  None when the open partition's
-        extent cannot grow in place: it went back to the buffered path
-        (``_unreserve``)."""
-        st, store = self._state, self._store
-        if st.draining:
-            store._await_drained(st)
-        if st.removed:
-            raise TransportError(f"unknown shuffle {st.shuffle_id}")
-        if st.sealed:
-            # a writer opened before the seal: the sealed rounds are immutable
-            # (zero-copy views, the runtime's H2D source), and a rollover here
-            # would zero the buffer they alias
-            raise TransportError(f"shuffle {st.shuffle_id} already sealed")
-        if st.device_mode:
-            raise TransportError(
-                f"shuffle {st.shuffle_id} already has device-staged rounds — "
-                "host and device writes cannot mix"
-            )
-        peer = st.owner_of(self._open_reduce)
-        base = peer * st.region_size
-        used = int(st.region_used[peer])
-        resv = self._resv
-        grow = padded
-        if resv is not None:
-            grow -= resv.padded
-            if not (
-                resv.round == st.round
-                and resv.start + resv.padded == base + used
-                and used + grow <= st.region_size
-            ):
-                self._unreserve()
-                return None
-        st.device_mode = False
-        store._charge_tenant(st, grow)  #: balanced by _release_tenant
-        try:
-            # a rollover may wait, lock released, for copies in flight: look again
-            while resv is None and used + padded > st.region_size:
-                if st.staging_closer is not None:
-                    raise TransportError(
-                        "region overflow with shm staging — multi-round spill "
-                        "requires private staging; raise stagingCapacity"
-                    )
-                store._rollover(st, peer)
-                used = int(st.region_used[peer])
-        except BaseException:
-            store._release_tenant(st, grow)
-            raise
-        staging = st.staging  # its first touch says whether the round is put behind its writers
-        if resv is not None:
-            start = resv.start
-            resv.padded = padded
-        else:
-            start = base + used
-            if hold:
-                resv = self._resv = _Reservation(st.round, start, padded)
-                if st.put_behind is not None:
-                    st.put_behind.open.add(resv)  # until ``_ShuffleState.settled``
-        st.region_used[peer] = used + grow
-        if hold:
-            st.inflight[resv.round] = st.inflight.get(resv.round, 0) + 1
-        return staging, start
-
-    def _record(self, start: int, padded: int, round_idx: int) -> bool:
-        """The table record of the open partition, whose last byte is in its
-        extent (caller holds the store's lock): the entry names the extent
-        and the round it was taken in, and the extent no longer holds a put
-        cursor.  True when the record took its region's final mark past the
-        end of a piece to put (``_PutBehind``): the caller then calls
-        ``_put_behind`` outside the lock."""
-        st = self._state
-        st.blocks[(self.map_id, self._open_reduce)] = _BlockEntry(
-            offset=start, length=self._written, padded=padded, round=round_idx
-        )
-        resv = self._resv
-        if resv is not None:
-            st.settled(resv)
-            self._resv = None
-        behind = st.put_behind
-        if behind is None:
-            return False
-        p = start // st.region_size
-        end = behind.next_end[p]
-        # the used prefix has to be past the piece's end before an extent still open can matter
-        return p * st.region_size + int(st.region_used[p]) >= end and behind.final_marks(st.region_used)[p] >= end
-
-    def _lose(self, padded: int) -> None:
-        """The open partition's bytes never fully reached their extent of
-        ``padded`` bytes (caller holds the store's lock): it stays a hole
-        that no entry names, its tenant charge is given back, and the writer
-        refuses to close or commit (``_resv`` stays) — the map's retry writes
-        it again."""
-        self._lost = True
-        self._store._release_tenant(self._state, padded)
-        if self._resv is not None:
-            self._state.settled(self._resv)  # a hole: what is there stays there
-
-    # -- receive in place (a partition fed from a socket) -------------------
-
-    def reserve(self, nbytes: int) -> Optional[memoryview]:
-        """The next ``nbytes`` of the open partition as a writable view of
-        their place in staging, for the caller to fill from a socket outside
-        every lock and then report with ``end_receive``; None when this
-        partition is on the buffered path (a retry's discarded writes, a
-        partition already fed through ``write``, one whose extent could not
-        grow in place): the caller then feeds ``write``.
-
-        Under the store's lock, before a byte is read, this is the buffered
-        close's first step (``_take_extent``: ``check_memory_pressure``
-        before the lock; the sealed / device-mode checks, ``_charge_tenant``,
-        the rollover when the region cannot take the block); the region-size
-        check comes first, so a body larger than a region fails typed with
-        nothing allocated.
-        The first frame of a partition takes its extent at the region's tail;
-        a further frame grows it while that tail is still the extent's end
-        and the region has room, and otherwise the partition goes back to the
-        buffered path (``inplace_fallbacks``; the extent stays as padding).
-        The round's in-flight count is taken here and given back by
-        ``end_receive``."""
-        if self._open_reduce is None:
-            raise TransportError("no open partition")
-        self._refuse_unsettled()
-        if self._discard or self._chunks:
-            return None
-        st, store = self._state, self._store
-        total = self._written + nbytes
-        if total > st.region_size:
-            raise TransportError(
-                f"single partition ({self.map_id},{self._open_reduce}) exceeds a "
-                f"whole region ({st.region_size} B) — raise stagingCapacity"
-            )
-        padded = -(-total // st.alignment) * st.alignment
-        held = self._resv.padded if self._resv is not None else 0
-        store.check_memory_pressure("reserve_partition", padded - held)
-        t_lock = perf_counter_ns()
-        with store._lock:
-            self._lock_wait_ns += perf_counter_ns() - t_lock
-            self._extra_lock_takes += 1
-            extent = self._take_extent(padded, True)
-            if extent is None:
-                return None
-            self._receiving = True
-            staging, start = extent
-            at = start + self._resv.filled
-            return memoryview(staging)[at : at + nbytes]
-
-    def end_receive(self, nbytes: int, filled: bool) -> None:
-        """The receive ``reserve`` handed out has ended: ``filled`` says all
-        ``nbytes`` arrived.  Gives the round's in-flight count back and wakes
-        whoever waits for it.  A body that did not fully arrive loses the
-        partition: its extent stays a hole (padding that no entry names), its
-        tenant charge is given back, and the writer refuses to close or
-        commit — the map's retry writes it again."""
-        st = self._state
-        resv = self._resv
-        with self._store._lock:
-            self._receiving = False
-            if filled:
-                resv.filled += nbytes
-                self._written += nbytes
-            else:
-                self._lose(resv.padded)
-            self._store._receive_ended(st, resv.round)
-            engaged = st.put_behind is not None
-        if engaged:  # the whole rows received are final now: the put cursor may pass them
-            self._store._put_behind(st)
-
-    def _refuse_unsettled(self) -> None:
-        if self._lost or self._receiving:
-            raise TransportError(
-                f"partition ({self.map_id},{self._open_reduce}) "
-                + ("lost a body mid-receive" if self._lost else "has a receive in flight")
-            )
-
-    def _unreserve(self) -> None:
-        """Back to the buffered path (caller holds the store's lock): what
-        was received so far leaves its extent for ``_chunks``, the extent
-        stays behind as padding, its tenant charge is given back
-        (``close_partition`` charges the whole partition again)."""
-        st, resv = self._state, self._resv
-        staging = st.staging if resv.round == st.round else st.prev_rounds[resv.round][0]
-        t0 = perf_counter_ns()
-        self._chunks.insert(0, staging[resv.start : resv.start + resv.filled].tobytes())
-        self._copy_ns += perf_counter_ns() - t0
-        self._extra_copies += 1
-        self._store._release_tenant(st, resv.padded)
-        st.settled(resv)
-        self._resv = None
-        self._inplace_fallbacks += 1
-
-    def _close_reserved(self) -> bool:
-        """``close_partition`` of a partition received in place: only the
-        table record — the extent was allocated and charged at ``reserve``
-        and the bytes are there.  False when ``write`` fed the partition
-        after its reservation: it goes back to the buffered path and the
-        caller carries on with the allocate + copy."""
-        self._refuse_unsettled()
-        st, resv = self._state, self._resv
-        t_lock = perf_counter_ns()
-        with self._store._lock:
-            self._lock_wait_ns += perf_counter_ns() - t_lock
-            self._extra_lock_takes += 1
-            if st.removed:
-                raise TransportError(f"unknown shuffle {st.shuffle_id}")
-            if st.sealed:
-                raise TransportError(f"shuffle {st.shuffle_id} already sealed")
-            if self._chunks:
-                self._unreserve()
-                return False
-            self._inplace_blocks += 1
-            self._inplace_bytes += self._written
-            # the extent's last row is final now: it may end a piece to put
-            passed = self._record(resv.start, resv.padded, resv.round)
-        self._last_reduce = self._open_reduce
-        self._open_reduce = None
-        self._block = None  # received in place: the daemon's phases, no ``write.block``
-        if passed:
-            self._store._put_behind(st)
-        return True
-
-    def write_partition(self, reduce_id: int, data: bytes) -> None:
-        """Convenience: open + write + close in one call."""
-        self.open_partition(reduce_id)
-        if data:
-            self.write(data)
-        self.close_partition()
-
-    def write_partition_device(self, reduce_id: int, rows, length: Optional[int] = None) -> None:
-        """One device block: the one-block case of ``write_partitions_device``
-        (``rows`` is the block's ``(r, lane)`` int32 device array, ``length``
-        its true byte count when the last row is padding-tailed; defaults to
-        the full ``rows`` extent)."""
-        nrows = int(rows.shape[0]) if getattr(rows, "ndim", 0) == 2 else 0
-        padded = nrows * self._state.alignment
-        if length is None:
-            length = padded
-        if not (max(padded - self._state.alignment + 1, 0) <= length <= padded):
-            raise TransportError(
-                f"length {length} inconsistent with {nrows} staged rows of "
-                f"{self._state.alignment} B each"
-            )
-        self.write_partitions_device(rows, [reduce_id], [length])
-
-    def write_partitions_device(self, packed, reduce_ids: Sequence[int], lengths: Sequence[int]) -> None:
-        """Device-path write of a map task's output (conf.device_staging):
-        ``packed`` is a ``(rows, lane)`` int32 array on the store's device —
-        one row per ``alignment`` bytes, already the exchange's wire unit —
-        holding the blocks of ``reduce_ids`` back to back in that order, each
-        from a fresh row (the bytes of a last row past a block's length should
-        be zeros: they travel as padding); ``lengths`` are the blocks' true
-        byte counts.  The blocks are placed into the shuffle's device staging
-        AT ONCE, by one block-scatter dispatch straight out of ``packed`` (one
-        more for each staging round the task crosses): the payload never
-        visits host memory, nothing of ``packed`` is kept, and the caller may
-        delete it as soon as this returns.  Same protocol and offset table as
-        the host path: increasing reduce order across the writer's calls, one
-        write per partition, first commit wins (a discarded retry dispatches
-        nothing)."""
-        if self._open_reduce is not None:
-            raise TransportError("previous partition still open")
-        st = self._state
-        align = st.alignment
-        lane = align // 4
-        if getattr(packed, "ndim", 0) != 2 or packed.shape[1] != lane:
-            raise TransportError(
-                f"device partition must be (rows, {lane}) int32, got shape "
-                f"{getattr(packed, 'shape', None)}"
-            )
-        reduce_ids = [int(r) for r in reduce_ids]
-        lengths = [int(n) for n in lengths]
-        if len(reduce_ids) != len(lengths):
-            raise TransportError(f"{len(reduce_ids)} reduce ids for {len(lengths)} lengths")
-        last = self._last_reduce
-        for reduce_id in reduce_ids:
-            if reduce_id <= last:
-                raise TransportError(
-                    f"partitions must be opened in increasing reduce order "
-                    f"(got {reduce_id} after {last})"
-                )
-            last = reduce_id
-        peers = [st.owner_of(r) for r in reduce_ids]  # validates the range
-        if any(n < 0 for n in lengths):
-            raise TransportError("negative block length")
-        nrows = [-(-n // align) for n in lengths]
-        total = sum(nrows) * align
-        if sum(nrows) > int(packed.shape[0]):
-            raise TransportError(
-                f"blocks of {sum(nrows)} rows in a packed array of {int(packed.shape[0])}"
-            )
-        if not reduce_ids:
-            return
-        if not self._discard:
-            if max(nrows) * align > st.region_size:
-                raise TransportError(
-                    f"single partition of map {self.map_id} exceeds a "
-                    f"whole region ({st.region_size} B) — raise stagingCapacity"
-                )
-            self._store.check_memory_pressure("write_partition_device", total)
-            with self._store._lock:
-                if st.sealed:
-                    raise TransportError(f"shuffle {st.shuffle_id} already sealed")
-                if st.device_mode is False:
-                    raise TransportError(
-                        f"shuffle {st.shuffle_id} already has host-staged blocks — "
-                        "host and device writes cannot mix"
-                    )
-                st.device_mode = True
-                self._store._charge_tenant(st, total)  #: balanced by _release_tenant
-                # (staging row, rows, source row, bytes) of the blocks bound
-                # for the live round; dispatched when it rolls and at the end
-                run: List[Tuple[int, int, int, int]] = []
-                src_row = 0
-                for reduce_id, peer, length, rows in zip(reduce_ids, peers, lengths, nrows):
-                    padded = rows * align
-                    if int(st.region_used[peer]) + padded > st.region_size:
-                        # what is recorded so far is placed before anything
-                        # can raise: the table never names an unplaced block
-                        self._store._stage_device(st, packed, run)
-                        run = []
-                        if st.staging_closer is not None:
-                            raise TransportError(
-                                "region overflow with shm staging — multi-round spill "
-                                "requires private staging; raise stagingCapacity"
-                            )
-                        self._store._rollover_device(st, peer)
-                    start = peer * st.region_size + int(st.region_used[peer])
-                    if rows:
-                        run.append((start // align, rows, src_row, length))
-                    st.blocks[(self.map_id, reduce_id)] = _BlockEntry(
-                        offset=start, length=length, padded=padded, round=st.round
-                    )
-                    st.region_used[peer] += padded
-                    src_row += rows
-                self._store._stage_device(st, packed, run)
-        self._last_reduce = last
-
-    def commit(self, ends_task: bool = True) -> MapperInfo:
-        """Commit this map task's outputs — the ``commitAllPartitions`` packing
-        (NvkvShuffleMapOutputWriter.scala:116-148).  Returns the MapperInfo blob
-        object the transport ships as AM id 2.  For a retry attempt (discard
-        mode) this returns the FIRST successful attempt's table.
-
-        ``ends_task``: the span ``write.task`` ends with this call; a caller
-        that ships the commit passes False and calls ``end_task`` once it has
-        shipped (``TpuShuffleMapOutputWriter.commit_all_partitions``)."""
-        if self._open_reduce is not None:
-            raise TransportError("commit with open partition")
-        t_commit = perf_counter_ns() if self._t_open else 0
-        st = self._state
-        parts, rounds = [], []
-        blocks = nbytes = 0
-        for r in range(st.num_reducers):
-            e = st.blocks.get((self.map_id, r))
-            if e is None:
-                parts.append((0, 0))
-                rounds.append(0)
-            else:
-                parts.append((e.offset, e.length))
-                rounds.append(e.round)
-                blocks += 1
-                nbytes += e.length
-        with self._store._lock:
-            st.committed_maps.add(self.map_id)
-            # once a writer; a retry's table is the first attempt's, counted then
-            if not (self._discard or self._counted):
-                self._counted = True
-                st.open_writers -= 1
-                counters = self._store._write_stats
-                counters["staged_blocks"] += blocks
-                counters["staged_bytes"] += nbytes
-                counters["largest_block_bytes"] = max(
-                    counters["largest_block_bytes"], max((length for _, length in parts), default=0)
-                )
-                counters["copy_ns"] += self._copy_ns
-                counters["lock_wait_ns"] += self._lock_wait_ns
-                counters["inplace_blocks"] += self._inplace_blocks
-                counters["inplace_bytes"] += self._inplace_bytes
-                counters["inplace_fallbacks"] += self._inplace_fallbacks
-                counters["unlocked_copy_blocks"] += self._unlocked_blocks
-                counters["unlocked_copy_bytes"] += self._unlocked_bytes
-        if t_commit:
-            # a buffered block is one copy and one take; a shuffle is staged
-            # on the host or on the device, never both
-            buffered = 0 if st.device_mode else blocks - self._inplace_blocks
-            self._task = (
-                t_commit, blocks, nbytes,
-                self._copy_ns, buffered + self._extra_copies,
-                self._lock_wait_ns, buffered + self._extra_lock_takes,
-            )
-            if ends_task:
-                self.end_task()
-        self._copy_ns = self._lock_wait_ns = 0
-        return MapperInfo(
-            st.shuffle_id, self.map_id, tuple(parts),
-            tuple(rounds) if any(rounds) else None,
-        )
-
-    def end_task(self) -> None:
-        """The committed task's interval ends here: ``write.task`` and, under
-        full tracing, its children go to the tracer in one call, from the
-        marks this writer took (class docstring).  The summed spans are laid
-        end to end from the task's open, as ``read.window.decode`` is; one
-        without a turn is left out.  Nothing to do for a writer that recorded
-        no marks, or has handed them over."""
-        task, self._task = self._task, None
-        t_open, self._t_open = self._t_open, 0
-        if task is None or not TRACER.active:
-            return
-        t_commit, blocks, nbytes, copy_ns, copies, lock_ns, lock_takes = task
-        t_end = perf_counter_ns()
-        args = {
-            "shuffle_id": self._state.shuffle_id, "map_id": self.map_id,
-            "executor": self._store.executor_id, "blocks": blocks, "bytes": nbytes,
-        }
-        children: List[tuple] = []
-        if self._blocks is not None and TRACER.enabled:
-            before, now = self._faults, _thread_minor_faults()
-            if before is not None and before[0] == now[0]:  # one thread's count
-                args["minor_faults"] = now[1] - before[1]
-            t = t_open
-            for name, ns, turns in (
-                ("write.task.copy", copy_ns, copies),
-                ("write.task.lock_wait", lock_ns, lock_takes),
-            ):
-                if turns:
-                    children.append((name, t, t + ns, {"turns": turns}))
-                    t += ns
-            for reduce_id, length, (t_in, t_close, t_copy, t_copied, t_out) in self._blocks:
-                cuts = (t_close, t_copy, t_copied, t_out)
-                children.append((
-                    "write.block", t_in, t_out, {"reduce_id": reduce_id, "bytes": length},
-                    list(zip(_WRITE_BLOCK_PHASES, cuts, cuts[1:])),
-                ))
-            children.append(("write.task.commit", t_commit, t_end))
-        self._blocks = None
-        TRACER.record_spans(None, (("write.task", t_open, t_end, args, children),))
-
-    @property
-    def is_retry_discard(self) -> bool:
-        return self._discard
-
-
-class _BlockRate:
-    """One block's fetch-rate state (all fields guarded by the owning
-    tracker's ``_lock``)."""
-
-    __slots__ = ("ewma", "last_ns", "hot")
-
-    def __init__(self, now_ns: int) -> None:
-        self.ewma = 0.0  # fetches/sec EWMA of instantaneous 1/dt rates
-        self.last_ns = now_ns
-        self.hot = False
-
-
-class BlockPopularity:
-    """Per-block fetch-rate EWMAs driving the popularity-aware serving tier.
-
-    The same EWMA shape as the transport's ``_PeerHealth`` latency tracker,
-    pointed at demand instead of health: every served fetch folds its
-    instantaneous rate (``1e9 / dt_ns`` since the block's previous fetch)
-    into a per-block EWMA.  A block whose rate crosses
-    ``serve.hotThresholdFetchesPerSec`` is *hot*; the serving plane reacts at
-    shuffle granularity (replication pushes whole sealed rounds), so
-    :meth:`observe` reports shuffle-level transitions — the first block of a
-    shuffle to heat up promotes the shuffle, and the shuffle demotes only
-    when :meth:`sweep` finds every one of its blocks cooled below HALF the
-    threshold (hysteresis: the promote and demote edges never chatter on a
-    rate hovering at the threshold).  Cooling is rate-decay aware: a block
-    that simply stops being fetched demotes once ``1e9 / elapsed_ns`` falls
-    under the demote edge, even though no new sample ever arrives.
-
-    ``now_ns`` is injectable for deterministic tests.  ``_lock`` is a LEAF:
-    no calls out while held (the lock-order pass pins this via
-    LOCK_ATTR_CLASSES).
-    """
-
-    #: demote edge = threshold * _COOL_FRACTION (hysteresis band)
-    _COOL_FRACTION = 0.5
-    #: cold entries idle this long are forgotten (memory bound)
-    _IDLE_GC_NS = 60 * 1_000_000_000
-
-    def __init__(
-        self,
-        hot_threshold_per_sec: float,
-        alpha: float = 0.25,
-        now_ns: Optional[Callable[[], int]] = None,
-    ) -> None:
-        self.hot_threshold = float(hot_threshold_per_sec)
-        self.alpha = float(alpha)
-        self._now_ns = now_ns if now_ns is not None else time.monotonic_ns
-        self._rates: Dict[Tuple[int, int, int], _BlockRate] = {}  #: guarded by self._lock
-        self._hot_counts: Dict[int, int] = {}  #: shuffle -> hot-block count; guarded by self._lock
-        self.stats: Dict[str, int] = {"promotions": 0, "demotions": 0}  #: guarded by self._lock
-        self._last_sweep_ns = 0  #: guarded by self._lock
-        self._lock = threading.Lock()  # LEAF: no calls out while held
-
-    def observe(
-        self, shuffle_id: int, map_id: int, reduce_id: int
-    ) -> Tuple[bool, List[Tuple[int, bool]]]:
-        """Fold one served fetch into the block's EWMA.  Returns
-        ``(block_is_hot, [(shuffle_id, True)] when this fetch promoted the
-        shuffle)`` — the serving plane widens the shuffle's replica set on
-        that transition and admits the block to the serve cache while hot."""
-        if self.hot_threshold <= 0:
-            return False, []
-        now = self._now_ns()
-        key = (shuffle_id, map_id, reduce_id)
-        with self._lock:
-            r = self._rates.get(key)
-            if r is None:
-                self._rates[key] = _BlockRate(now)
-                return False, []
-            dt = max(now - r.last_ns, 1)
-            r.last_ns = now
-            r.ewma = self.alpha * (1e9 / dt) + (1.0 - self.alpha) * r.ewma
-            transitions: List[Tuple[int, bool]] = []
-            if not r.hot and r.ewma >= self.hot_threshold:
-                r.hot = True
-                self.stats["promotions"] += 1
-                n = self._hot_counts.get(shuffle_id, 0)
-                self._hot_counts[shuffle_id] = n + 1
-                if n == 0:
-                    transitions.append((shuffle_id, True))
-            return r.hot, transitions
-
-    def sweep(self, now_ns: Optional[int] = None) -> List[Tuple[int, bool]]:
-        """Cool-down pass: demote hot blocks whose effective rate —
-        ``min(ewma, 1e9 / elapsed_ns)``, so silence decays the rate — fell
-        below the demote edge, and forget long-idle cold blocks.  Returns
-        ``[(shuffle_id, False)]`` for every shuffle whose LAST hot block
-        cooled (the serving plane drops the widened advertisement then)."""
-        now = self._now_ns() if now_ns is None else now_ns
-        cool_edge = self.hot_threshold * self._COOL_FRACTION
-        transitions: List[Tuple[int, bool]] = []
-        with self._lock:
-            for key, r in list(self._rates.items()):
-                elapsed = max(now - r.last_ns, 1)
-                effective = min(r.ewma, 1e9 / elapsed)
-                if r.hot:
-                    if effective < cool_edge:
-                        r.hot = False
-                        r.ewma = effective
-                        self.stats["demotions"] += 1
-                        n = self._hot_counts.get(key[0], 1) - 1
-                        if n <= 0:
-                            self._hot_counts.pop(key[0], None)
-                            transitions.append((key[0], False))
-                        else:
-                            self._hot_counts[key[0]] = n
-                elif elapsed > self._IDLE_GC_NS:
-                    del self._rates[key]
-        return transitions
-
-    def maybe_sweep(
-        self, min_interval_ns: int = 1_000_000_000
-    ) -> List[Tuple[int, bool]]:
-        """Rate-limited :meth:`sweep`, safe to call on every served batch:
-        at most one cool-down pass per ``min_interval_ns`` actually scans."""
-        if self.hot_threshold <= 0:
-            return []
-        now = self._now_ns()
-        with self._lock:
-            if now - self._last_sweep_ns < min_interval_ns:
-                return []
-            self._last_sweep_ns = now
-        return self.sweep(now)
-
-    def is_hot(self, shuffle_id: int) -> bool:
-        with self._lock:
-            return self._hot_counts.get(shuffle_id, 0) > 0
-
-    def hot_shuffles(self) -> List[int]:
-        with self._lock:
-            return sorted(self._hot_counts)
-
-    def snapshot(self) -> Dict[str, int]:
-        """Counter snapshot for MetricsRegistry export (``serve`` family)."""
-        with self._lock:
-            return {
-                "promotions": self.stats["promotions"],
-                "demotions": self.stats["demotions"],
-                "tracked_blocks": len(self._rates),
-                "hot_blocks": sum(self._hot_counts.values()),
-                "hot_shuffles": len(self._hot_counts),
-            }
-
-
 class HbmBlockStore:
     """Per-executor staged shuffle store.  See module docstring."""
 
@@ -1361,8 +487,11 @@ class HbmBlockStore:
         # arrive before this process registers the shuffle); applied at creation.
         self._pending_infos: Dict[int, List[MapperInfo]] = {}  #: guarded by self._lock
         self._lock = threading.RLock()
+        #: the same lock under the name a map task's writer takes it by: the
+        #: only writer open holds it across ``take_extent``, its copy and ``record_extent``
+        self.lock = self._lock
         #: on ``_lock``: woken when a receive in place ends and when a waiter
-        #: for such receives is done (``_await_receives``, ``_await_drained``)
+        #: for such receives is done (``_await_receives``, ``take_extent``)
         self._cond = threading.Condition(self._lock)
         # disk round tier accounting (conf.spill_to_disk).  The tempdir path
         # lives in a plain dict holder so the weakref.finalize below can purge
@@ -1827,7 +956,7 @@ class HbmBlockStore:
         round of ``st`` (with ``live_only``: into its live round) —
         caller holds self._lock, which is RELEASED while this waits, so the
         state may have changed by the time it returns.  No new extent of the
-        shuffle is taken meanwhile (``_await_drained``), and every one in
+        shuffle is taken meanwhile (``take_extent`` waits), and every one in
         flight ends: a receive runs under ``conf.wire_timeout_ms``, a copy is
         a ``memcpy``, and each gives its count back on every way out
         (``MapWriter.end_receive`` / ``close_partition``).  Nothing in flight is
@@ -1849,22 +978,6 @@ class HbmBlockStore:
             self._cond.notify_all()
             self._write_stats["inflight_wait_ns"] += perf_counter_ns() - t0
         return True
-
-    def _await_drained(self, st: _ShuffleState) -> None:
-        """Hold a new extent back while a waiter of ``_await_receives``
-        drains the shuffle (caller holds self._lock, released meanwhile)."""
-        while st.draining:
-            self._cond.wait(timeout=1.0)
-
-    def _receive_ended(self, st: _ShuffleState, round_idx: int) -> None:
-        """Give one in-flight count of a round back (caller holds self._lock)."""
-        left = st.inflight[round_idx] - 1
-        if left:
-            st.inflight[round_idx] = left
-        else:
-            del st.inflight[round_idx]
-            if st.draining:
-                self._cond.notify_all()
 
     # -- the single round put behind the writers (``_PutBehind``) -----------
 
@@ -1939,7 +1052,7 @@ class HbmBlockStore:
         behind.owner = True
         return pieces, st.staging.view(np.int32).reshape(-1, st.alignment // 4)
 
-    def _put_behind(self, st: _ShuffleState) -> None:
+    def put_behind(self, st: _ShuffleState) -> None:
         """Put the pieces of ``st``'s live round that are final now, on the
         calling thread — a writer whose block passed a piece's end — as the
         one owner of the update chain: claimed under self._lock, put OUTSIDE
@@ -2090,7 +1203,7 @@ class HbmBlockStore:
                 self._ram_round_bytes -= snap.nbytes
                 st.prev_rounds[i] = (None, used)
                 rounds.append((snap, used))
-        live, st.staging = st._staging, None
+        live, st.staging = st.staging, None  # ``removed`` is latched: the property allocates nothing
         if live is not None and st.staging_closer is None and not isinstance(live, np.memmap):
             rounds.append((live, st.region_used))
         return rounds
@@ -2413,6 +1526,198 @@ class HbmBlockStore:
             st.open_writers += not discard  # until its ``commit``
         return MapWriter(self, st, map_id, discard=discard)
 
+    # -- a block's three steps: the buffered close and the receive in place --
+    # -- (``store/writer.py``) make them; callers hold ``lock`` -------------
+
+    def take_extent(
+        self, st: _ShuffleState, reduce_id: int, padded: int,
+        resv: Optional[_Reservation], hold: bool,
+    ) -> Optional[Tuple[np.ndarray, int, Optional[_Reservation]]]:
+        """Everything a block of partition ``reduce_id`` needs under the
+        store's lock before a byte of it moves (caller holds the lock): no
+        waiter is draining the shuffle, the removed / sealed / device-mode
+        checks, the tenant charge (an over-quota write fails typed with
+        nothing allocated, rolled over or copied), the rollover when the
+        region cannot take the block, the first touch of the staging round
+        and the region allocate — ``region_used`` moves by ``padded`` bytes,
+        or by what ``padded`` adds to ``resv``, the extent the writer's open
+        partition already holds (a further frame of a receive in place).
+        ``(staging, start, resv)``: the live round's buffer, the extent's
+        absolute offset in it and its reservation.
+
+        With ``hold`` the caller fills the extent OUTSIDE the lock: it
+        becomes a ``_Reservation`` of the round it was made in (joining
+        ``put_behind.open`` where the round is put behind its writers) and
+        the round's in-flight count is taken, to be given back by
+        ``receive_ended``; without, ``resv`` comes back None.  None when
+        ``resv`` cannot grow in place, and nothing was changed: the partition
+        goes back to the buffered path (``MapWriter._unreserve``)."""
+        while st.draining:  # a waiter of ``_await_receives`` drains the shuffle: the lock is released meanwhile
+            self._cond.wait(timeout=1.0)
+        if st.removed:
+            raise TransportError(f"unknown shuffle {st.shuffle_id}")
+        if st.sealed:
+            # a writer opened before the seal: the sealed rounds are immutable
+            # (zero-copy views, the runtime's H2D source), and a rollover here
+            # would zero the buffer they alias
+            raise TransportError(f"shuffle {st.shuffle_id} already sealed")
+        if st.device_mode:
+            raise TransportError(
+                f"shuffle {st.shuffle_id} already has device-staged rounds — "
+                "host and device writes cannot mix"
+            )
+        peer = st.owner_of(reduce_id)
+        base = peer * st.region_size
+        used = int(st.region_used[peer])
+        grow = padded
+        if resv is not None:
+            grow -= resv.padded
+            if not (
+                resv.round == st.round
+                and resv.start + resv.padded == base + used
+                and used + grow <= st.region_size
+            ):
+                return None
+        st.device_mode = False
+        self._charge_tenant(st, grow)  #: balanced by _release_tenant
+        try:
+            # a rollover may wait, lock released, for copies in flight: look again
+            while resv is None and used + padded > st.region_size:
+                self._refuse_shm_overflow(st)
+                self._rollover(st, peer)
+                used = int(st.region_used[peer])
+        except BaseException:
+            self._release_tenant(st, grow)
+            raise
+        staging = st.staging  # its first touch says whether the round is put behind its writers
+        if resv is not None:
+            start = resv.start
+            resv.padded = padded
+        else:
+            start = base + used
+            if hold:
+                resv = _Reservation(st.round, start, padded)
+                if st.put_behind is not None:
+                    st.put_behind.open.add(resv)  # until ``_ShuffleState.settled``
+        st.region_used[peer] = used + grow
+        if hold:
+            st.inflight[resv.round] = st.inflight.get(resv.round, 0) + 1
+        return staging, start, resv
+
+    def record_extent(
+        self, st: _ShuffleState, key: Tuple[int, int], length: int,
+        start: int, padded: int, round_idx: int, resv: Optional[_Reservation],
+    ) -> bool:
+        """The table record of block ``key`` = (map, reduce), whose last byte
+        is in its extent (caller holds the store's lock): the entry names the
+        extent and the round it was taken in, and the extent no longer holds
+        a put cursor.  True when the record took its region's final mark past
+        the end of a piece to put (``_PutBehind``): the caller then calls
+        ``put_behind`` outside the lock."""
+        st.blocks[key] = _BlockEntry(offset=start, length=length, padded=padded, round=round_idx)
+        if resv is not None:
+            st.settled(resv)
+        behind = st.put_behind
+        if behind is None:
+            return False
+        p = start // st.region_size
+        end = behind.next_end[p]
+        # the used prefix has to be past the piece's end before an extent still open can matter
+        return p * st.region_size + int(st.region_used[p]) >= end and behind.final_marks(st.region_used)[p] >= end
+
+    def lose_extent(self, st: _ShuffleState, padded: int, resv: Optional[_Reservation]) -> None:
+        """A partition's extent of ``padded`` bytes will never be recorded: its
+        bytes did not fully arrive, or it went back to the buffered path
+        (caller holds the store's lock).  It stays a hole that no entry names,
+        its tenant charge is given back and it holds no put cursor."""
+        self._release_tenant(st, padded)
+        if resv is not None:
+            st.settled(resv)
+
+    def extent_received(self, st: _ShuffleState, resv: _Reservation) -> np.ndarray:
+        """What was received into ``resv`` so far, where it lies in the round
+        the extent was made in (caller holds the store's lock)."""
+        staging = st.staging if resv.round == st.round else st.prev_rounds[resv.round][0]
+        return staging[resv.start : resv.start + resv.filled]
+
+    def receive_ended(self, st: _ShuffleState, resv: _Reservation, received: int) -> None:
+        """The bytes on their way into ``resv`` outside the lock have ended,
+        ``received`` more of them there (a buffered copy, a body cut short:
+        0): its round's in-flight count is given back (caller holds the lock)."""
+        resv.filled += received
+        left = st.inflight[resv.round] - 1
+        if left:
+            st.inflight[resv.round] = left
+        else:
+            del st.inflight[resv.round]
+            if st.draining:
+                self._cond.notify_all()
+
+    def _refuse_shm_overflow(self, st: _ShuffleState) -> None:
+        """A region of shm staging is full: there is no next round."""
+        if st.staging_closer is not None:
+            raise TransportError(
+                "region overflow with shm staging — multi-round spill "
+                "requires private staging; raise stagingCapacity"
+            )
+
+    def place_device_blocks(self, st: _ShuffleState, map_id: int, packed, blocks, total: int) -> None:
+        """A map task's device-path write, validated by its caller
+        (``MapWriter.write_partitions_device``): ``blocks`` of ``(reduce_id,
+        peer, length, rows)`` lie back to back in ``packed``, ``total`` padded
+        bytes.  Under the lock: the checks, the tenant charge, a table entry
+        a block, one scatter dispatch a staging round touched."""
+        self.check_memory_pressure("write_partition_device", total)
+        align = st.alignment
+        with self._lock:
+            if st.sealed:
+                raise TransportError(f"shuffle {st.shuffle_id} already sealed")
+            if st.device_mode is False:
+                raise TransportError(
+                    f"shuffle {st.shuffle_id} already has host-staged blocks — "
+                    "host and device writes cannot mix"
+                )
+            st.device_mode = True
+            self._charge_tenant(st, total)  #: balanced by _release_tenant
+            # (staging row, rows, source row, bytes) of the blocks bound
+            # for the live round; dispatched when it rolls and at the end
+            run: List[Tuple[int, int, int, int]] = []
+            src_row = 0
+            for reduce_id, peer, length, rows in blocks:
+                padded = rows * align
+                if int(st.region_used[peer]) + padded > st.region_size:
+                    # what is recorded so far is placed before anything
+                    # can raise: the table never names an unplaced block
+                    self._stage_device(st, packed, run)
+                    run = []
+                    self._refuse_shm_overflow(st)
+                    self._rollover_device(st, peer)
+                start = peer * st.region_size + int(st.region_used[peer])
+                if rows:
+                    run.append((start // align, rows, src_row, length))
+                st.blocks[(map_id, reduce_id)] = _BlockEntry(
+                    offset=start, length=length, padded=padded, round=st.round
+                )
+                st.region_used[peer] += padded
+                src_row += rows
+            self._stage_device(st, packed, run)
+
+    def commit_map(
+        self, st: _ShuffleState, map_id: int, adds: Optional[Dict[str, int]], largest: int
+    ) -> None:
+        """Map ``map_id`` is committed.  ``adds``, once a writer (None for a
+        retry that discards and a second ``commit``): the writer is no
+        longer open, and what it counted joins the write counters by name,
+        ``largest`` (its longest block) the gauge ``largest_block_bytes``."""
+        with self._lock:
+            st.committed_maps.add(map_id)
+            if adds is not None:
+                st.open_writers -= 1
+                counters = self._write_stats
+                for name, n in adds.items():
+                    counters[name] += n
+                counters["largest_block_bytes"] = max(counters["largest_block_bytes"], largest)
+
     def apply_mapper_info(self, info: MapperInfo) -> None:
         """Install commit metadata received from a peer process (AM id 2 inbound —
         what the DPU daemon does with MapperInfo).  Commits for a shuffle this
@@ -2545,10 +1850,6 @@ class HbmBlockStore:
         st = self._state(shuffle_id)
         return st.round + 1
 
-    def region_slot_rows(self, shuffle_id: int) -> int:
-        st = self._state(shuffle_id)
-        return st.region_size // st.alignment
-
     def region_bytes(self, shuffle_id: int) -> int:
         """Per-peer region size in bytes — public form of the staging geometry
         the transports need for offset math (was reached via ``_state``)."""
@@ -2619,11 +1920,11 @@ class HbmBlockStore:
             if hasattr(payload, "is_deleted"):
                 if not payload.is_deleted():
                     return "hbm"
-            elif st._staging is None:
+            elif not st.host_staging_allocated:
                 # demoted device round: the snapshot in sealed_payload is the
                 # only backing (device shuffles never allocate host staging)
                 return "disk" if isinstance(payload, np.memmap) else "host"
-        return "disk" if isinstance(st._staging, np.memmap) else "host"
+        return "disk" if st.host_staging_allocated and isinstance(st.staging, np.memmap) else "host"
 
     def round_tier(self, shuffle_id: int, round_idx: int) -> Optional[str]:
         """Public tier probe; None for unknown shuffles/rounds."""

@@ -87,7 +87,8 @@ from sparkucx_tpu.core.transport import ExecutorId, ShuffleTransport
 # tier-(a) wire compression policy + page formats; ops.compress keeps its jax
 # imports function-local, so this pulls no accelerator stack into the transport
 from sparkucx_tpu.ops.compress import CompressSpec, encode_chunk
-from sparkucx_tpu.store.hbm_store import BlockPopularity, HbmBlockStore
+from sparkucx_tpu.service.popularity import BlockPopularity
+from sparkucx_tpu.store.hbm_store import HbmBlockStore
 from sparkucx_tpu.testing import faults
 from sparkucx_tpu.obs.metrics import (
     MetricsRegistry,

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 import sparkucx_tpu.store.hbm_store as hbm_store
+import sparkucx_tpu.store.writer as store_writer
 from sparkucx_tpu.config import TpuShuffleConf
 from sparkucx_tpu.core.operation import TransportError
 from sparkucx_tpu.service.tenants import TenantRegistry
@@ -39,16 +40,16 @@ def small_sizes(monkeypatch):
 
 
 class HeldCopies:
-    """``hbm_store._copy_chunks`` with a gate a thread: a copy on a gated
+    """``store_writer._copy_chunks`` with a gate a thread: a copy on a gated
     thread says where its extent starts, waits to be let through, then
     copies (or raises what it was told to).  Other threads copy as ever."""
 
     def __init__(self, monkeypatch):
-        self.real = hbm_store._copy_chunks
+        self.real = store_writer._copy_chunks
         self.arrived = queue.Queue()
         self.gates = {}
         self.raises = {}
-        monkeypatch.setattr(hbm_store, "_copy_chunks", self)
+        monkeypatch.setattr(store_writer, "_copy_chunks", self)
 
     def __call__(self, staging, start, chunks):
         name = threading.current_thread().name
@@ -428,13 +429,13 @@ def test_the_only_writer_open_takes_the_lock_once(others, takes, nbytes):
         writer = store.map_writer(0, 0)
         writer.write_partition(0, b"w" * 10)  # the staging's first touch is behind us
         beside = [store.map_writer(0, 1 + k) for k in range(others)]
-        counting = store._lock = CountingLock(store._lock)
+        counting = store.lock = store._lock = CountingLock(store._lock)
         writer.open_partition(1)
         writer.write(b"x" * nbytes)
         assert counting.takes == 0
         writer.close_partition()
         assert counting.takes == takes
-        store._lock = counting.lock
+        store.lock = store._lock = counting.lock
         writer.commit()
         unlocked = takes - 1
         assert stats(store, "unlocked_copy_blocks", "unlocked_copy_bytes") == (unlocked, unlocked * nbytes)
@@ -477,7 +478,7 @@ def test_a_writer_is_open_from_its_creation_to_its_commit():
 def tracer():
     enabled, recording = TRACER.enabled, TRACER.recording
     TRACER.clear()
-    hbm_store._blocks_traced = 0
+    store_writer._blocks_traced = 0
     yield TRACER
     TRACER.enabled, TRACER.recording = enabled, recording
     TRACER.clear()
@@ -489,7 +490,7 @@ def test_the_block_phases_still_partition_the_close_and_the_task_counts_both_tak
     overlap, under the lock and outside it; ``write.task.copy`` has a turn a
     block and ``write.task.lock_wait`` one more for a block copied outside
     the lock; ``copy_ns`` and ``lock_wait_ns`` are the summed spans."""
-    monkeypatch.setattr(hbm_store, "WRITE_BLOCK_EVERY", 1)
+    monkeypatch.setattr(store_writer, "WRITE_BLOCK_EVERY", 1)
     store = store_of(REGION, device=False)
     try:
         tracer.enable()
@@ -516,7 +517,7 @@ def test_the_block_phases_still_partition_the_close_and_the_task_counts_both_tak
         assert len(blocks) == 8
         for b in blocks:
             phases = sorted((e for e in events if e["parent_id"] == b["span_id"]), key=lambda e: e["ts"])
-            assert [p["name"] for p in phases] == list(hbm_store._WRITE_BLOCK_PHASES)
+            assert [p["name"] for p in phases] == list(store_writer._WRITE_BLOCK_PHASES)
             for a, c in zip(phases, phases[1:]):
                 assert abs(a["ts"] + a["dur"] - c["ts"]) < 0.002
             assert abs(phases[-1]["ts"] + phases[-1]["dur"] - (b["ts"] + b["dur"])) < 0.002

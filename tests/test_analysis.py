@@ -473,6 +473,15 @@ class TestPrivateAndSurface:
         assert not is_allowlisted(f, {("thing.py", "lock-discipline", "._guts")})
         assert not is_allowlisted(f, {("thing.py", "private-access", "._other")})
 
+    def test_no_file_of_the_store_is_excused_a_private_access(self):
+        # PR 56: the map task's writer reaches the store through its methods;
+        # ten rows for store/hbm_store.py went and none came for store/writer.py
+        from sparkucx_tpu.analysis.config import ALLOWLIST, REQUIRED_SURFACE
+
+        assert [row for row in ALLOWLIST if row[0].startswith("store/") and row[1] == "private-access"] == []
+        assert "MapWriter" in REQUIRED_SURFACE["store/writer.py"]
+        assert "MapWriter" not in REQUIRED_SURFACE["store/hbm_store.py"]
+
 
 # ----------------------------------------------------------------------
 # lock-order (whole-program pass)

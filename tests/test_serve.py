@@ -36,7 +36,8 @@ from sparkucx_tpu.service.eviction import ServeCache
 from sparkucx_tpu.service.tenants import TenantRegistry
 from sparkucx_tpu.shuffle.reader import TpuShuffleReader
 from sparkucx_tpu.shuffle.resolver import ring_neighbors, widened_ring_neighbors
-from sparkucx_tpu.store.hbm_store import BlockPopularity, HbmBlockStore
+from sparkucx_tpu.service.popularity import BlockPopularity
+from sparkucx_tpu.store.hbm_store import HbmBlockStore
 from sparkucx_tpu.testing import faults
 from sparkucx_tpu.transport.peer import PeerTransport
 

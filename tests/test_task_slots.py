@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 import sparkucx_tpu.store.hbm_store as hbm_store
+import sparkucx_tpu.store.writer as store_writer
 import sparkucx_tpu.transport.tpu as tpu
 from benchmark.cells import load_module
 from sparkucx_tpu.config import TpuShuffleConf
@@ -307,10 +308,10 @@ def test_four_map_tasks_of_one_region_with_the_round_put_behind_them(monkeypatch
 
 def copies_that_meet(monkeypatch):
     """The first four copies into staging wait for each other inside
-    ``hbm_store._copy_chunks``: four map tasks copy at once, whatever the
+    ``store_writer._copy_chunks``: four map tasks copy at once, whatever the
     host — under a lock held round the copy they could never meet."""
     meet = threading.Barrier(SLOTS)
-    real = hbm_store._copy_chunks
+    real = store_writer._copy_chunks
     calls = []
 
     def meeting(staging, start, chunks):
@@ -319,7 +320,7 @@ def copies_that_meet(monkeypatch):
             meet.wait(timeout=60)
         return real(staging, start, chunks)
 
-    monkeypatch.setattr(hbm_store, "_copy_chunks", meeting)
+    monkeypatch.setattr(store_writer, "_copy_chunks", meeting)
     return calls
 
 

@@ -18,8 +18,8 @@ import pytest
 from sparkucx_tpu.config import TpuShuffleConf
 from sparkucx_tpu.shuffle.daemon import DaemonClient, ShuffleDaemon
 from sparkucx_tpu.shuffle.manager import TpuShuffleManager
-from sparkucx_tpu.store import hbm_store
-from sparkucx_tpu.store.hbm_store import WRITE_BLOCK_EVERY
+from sparkucx_tpu.store import writer as store_writer
+from sparkucx_tpu.store.writer import WRITE_BLOCK_EVERY
 from sparkucx_tpu.transport.tpu import TpuShuffleCluster
 from sparkucx_tpu.utils.trace import TRACER
 
@@ -28,7 +28,7 @@ TASK_CHILDREN = ("write.task.copy", "write.task.lock_wait", "write.task.commit")
 BLOCK_PHASES = ("write.block.admit", "write.block.copy", "write.block.record")
 SWITCHES = {"recording": (False, True), "enabled": (True, True)}
 #: the store's own answer for this host's kernel (the fixture below overrides it)
-KERNEL_COUNTS_FAULTS = hbm_store._kernel_counts_faults
+KERNEL_COUNTS_FAULTS = store_writer._kernel_counts_faults
 
 
 @pytest.fixture
@@ -36,12 +36,12 @@ def tracer():
     """The process-wide tracer, cleared; back to what it was afterwards."""
     enabled, recording = TRACER.enabled, TRACER.recording
     TRACER.clear()
-    hbm_store._blocks_traced = 0  # the sampling count is the process's: a test's own
+    store_writer._blocks_traced = 0  # the sampling count is the process's: a test's own
     # whether the argument is there must not hang on this host's kernel
     # (``test_fresh_pages_show_as_minor_faults`` is the one that reads values)
-    hbm_store._kernel_counts_faults = lambda: True
+    store_writer._kernel_counts_faults = lambda: True
     yield TRACER
-    hbm_store._kernel_counts_faults = KERNEL_COUNTS_FAULTS
+    store_writer._kernel_counts_faults = KERNEL_COUNTS_FAULTS
     TRACER.enabled, TRACER.recording = enabled, recording
     TRACER.clear()
 
@@ -200,8 +200,8 @@ def test_minor_faults_left_out_where_the_kernel_keeps_no_count(tracer, monkeypat
     mgr.register_shuffle(0, 1, 4)
     calls = []
     real = resource.getrusage
-    monkeypatch.setattr(hbm_store.resource, "getrusage", lambda who: calls.append(who) or real(who))
-    monkeypatch.setattr(hbm_store, "_kernel_counts_faults", lambda: False)
+    monkeypatch.setattr(store_writer.resource, "getrusage", lambda who: calls.append(who) or real(who))
+    monkeypatch.setattr(store_writer, "_kernel_counts_faults", lambda: False)
     writer = write_task(mgr, 0, 0, blocks=4, size=500, commit=False)
     assert writer.map_writer._faults is None and writer.map_writer._blocks is not None
     writer.commit_all_partitions()
@@ -215,7 +215,7 @@ def test_the_kernels_count_is_asked_once_a_process(monkeypatch):
 
     asked = []
     real = resource.getrusage
-    monkeypatch.setattr(hbm_store.resource, "getrusage", lambda who: asked.append(who) or real(who))
+    monkeypatch.setattr(store_writer.resource, "getrusage", lambda who: asked.append(who) or real(who))
     fresh = KERNEL_COUNTS_FAULTS.__wrapped__  # the function under the cache
     assert fresh() == (real(resource.RUSAGE_SELF).ru_minflt > 0)
     assert asked == [resource.RUSAGE_SELF]
