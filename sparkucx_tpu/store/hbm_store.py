@@ -332,6 +332,47 @@ class _PutBehind:
         self.in_flight.clear()
 
 
+class _EarlyRounds:
+    """A multi-round shuffle's completed RAM rounds on the store's device
+    before the exchange asks for them (PR 57): what ``_PutBehind`` is to a
+    job's one round, for the rounds a job rolls.
+
+    A round that rolled on ``_rollover``'s RAM arm is never written again
+    once no extent is open in it — a receive in place or a buffered copy
+    taken before the rollover may still be landing (``_ShuffleState
+    .inflight``) — and then lies in ``prev_rounds`` until the exchange puts
+    it, the link idle meanwhile.  A store with a device puts such a round
+    when it becomes final, where its buffer is one the free list handed out
+    (pages the process holds: ``_take_staging``'s law, a fact of the buffer):
+    ONE ``device_put`` of the round's ``(rows, lane)`` view (a round larger
+    than ``SEAL_PUT_PIECE_BYTES`` in pieces, as ``_put_round`` puts it),
+    outside the store's lock, on the thread of the writer whose record found
+    it ready (``HbmBlockStore.record_extent`` → ``put_behind``).  The host
+    round stays the shuffle's backing store and ``seal`` hands it on as
+    ever; the copy rides beside it until ``HbmBlockStore.take_early_round``
+    hands it to the exchange, which then puts nothing for that round.  What
+    the exchange did not take is let go — and counted, ``early_rounds_dropped``
+    and ``released_device_bytes`` — by ``release_early_rounds`` (a plan whose
+    window is not the staging slot, an aborted exchange), ``remove_shuffle``
+    and ``close``; a put that raised costs the shuffle its early copies and
+    never the write.
+
+    ONE owner at a time puts (``owner``; ``_PutBehind``'s rule): it claims
+    the rounds that are ready under the store's lock, puts them outside it
+    and looks again before it lets go; ``seal``, ``remove_shuffle`` and
+    ``close`` wait for it on the store's condition.  Every field is read and
+    written under the owning store's lock."""
+
+    __slots__ = ("open", "ready", "copies", "owner", "closed")
+
+    def __init__(self) -> None:
+        self.open: set = set()  # rounds rolled with an extent still open in them
+        self.ready: deque = deque()  # rounds that are final and not yet claimed
+        self.copies: Dict[int, object] = {}  # round -> its jax.Array on the store's device
+        self.owner = False  # a thread is putting claimed rounds outside the lock
+        self.closed = False  # no round is queued or put any more
+
+
 class _ShuffleState:
     def __init__(
         self,
@@ -426,6 +467,15 @@ class _ShuffleState:
         #: None before and otherwise, after a rollover, and once sealed or removed
         #: (under the owning store's ``_lock``)
         self.put_behind: Optional[_PutBehind] = None
+        #: whether the live staging round's buffer is one the store's free
+        #: list handed out — pages the process holds — and not a fresh one
+        #: (``HbmBlockStore._take_staging``, ``_rollover``)
+        #: (under the owning store's ``_lock``)
+        self.live_held = False
+        #: the completed RAM rounds on their way to the store's device before
+        #: the exchange (``_EarlyRounds``); None until a round qualifies
+        #: (under the owning store's ``_lock``)
+        self.early_rounds: Optional[_EarlyRounds] = None
 
     def pieces(self) -> Optional[_PutBehind]:
         """The staging round as ``SEAL_PUT_PIECE_BYTES`` pieces, nothing put
@@ -549,6 +599,13 @@ class HbmBlockStore:
         #: ``seal`` itself still put) and ``early_put_dropped`` (device
         #: buffers with pieces in them that a rollover, a removal or a failed
         #: put discarded).
+        #: The completed rounds of a multi-round job put before the exchange
+        #: (``_EarlyRounds``): ``early_round_puts`` / ``early_round_bytes``
+        #: (rounds put on the device when they became final, and their
+        #: bytes) and ``early_rounds_dropped`` (such copies the exchange did
+        #: not take: let go by a removal, ``close``, an aborted exchange, a
+        #: plan whose window is not the staging slot, a put that raised;
+        #: their bytes join ``released_device_bytes``).
         #: guarded by self._lock
         self._write_stats: Dict[str, int] = dict.fromkeys(
             ("staged_blocks", "staged_bytes", "rollovers", "spilled_bytes",
@@ -560,7 +617,8 @@ class HbmBlockStore:
              "inplace_fallbacks", "inflight_wait_ns", "rollover_tail_bytes",
              "largest_block_bytes", "early_put_pieces", "early_put_bytes",
              "seal_put_pieces", "early_put_dropped", "unlocked_copy_blocks",
-             "unlocked_copy_bytes"), 0
+             "unlocked_copy_bytes", "early_round_puts", "early_round_bytes",
+             "early_rounds_dropped"), 0
         )
         #: RAM tier of completed rounds (``_rollover``): capacity bytes of the
         #: RAM rounds live shuffles hold plus the free list never exceed
@@ -570,6 +628,9 @@ class HbmBlockStore:
         #: no RAM tier, no floor
         self._floor_limit = staging_floor_limit() if self._ram_budget else 0
         self._ram_round_bytes = 0  #: guarded by self._lock
+        #: HBM the early copies of completed rounds hold and the exchange
+        #: has not taken (``_EarlyRounds``): at most ``_ram_budget``
+        self._early_round_bytes = 0  #: guarded by self._lock
         #: buffer size -> all-zero round buffers of removed shuffles and
         #: demoted rounds, that nothing else refers to (``_recycle_rounds``)
         self._free_rounds: Dict[int, List[np.ndarray]] = {}  #: guarded by self._lock
@@ -688,6 +749,7 @@ class HbmBlockStore:
                 # new one is admitted)
                 self._await_quiet(st)
                 early = self._drop_put_behind(st)
+                self._release_early_rounds(st)  # before the round buffers are offered to the free list
                 # The live staging round, the RAM rounds and the device-sealed
                 # payload are released HERE, not at the interpreter's next
                 # collection: a writer or reader handle may keep the state
@@ -731,6 +793,7 @@ class HbmBlockStore:
                 st.removed = True
                 self._await_quiet(st)  # the shm closer unmaps the buffer
                 self._drop_put_behind(st)
+                self._release_early_rounds(st)
                 if st.staging_closer is not None:
                     st.staging = None
                     st.staging_closer()
@@ -919,14 +982,16 @@ class HbmBlockStore:
         ``store.spill`` fires only on the disk arm, where the rollover's self
         time is the zeroing of the used prefixes; on the RAM arm it is
         bookkeeping."""
-        # the job has turned multi-round: the exchange uploads its rounds, and
-        # what was put of this one on the device is let go
+        # the job has turned multi-round: what was put of this round piece
+        # by piece is let go, and a round is put whole once it is final
         self._drop_put_behind(st)
         with self._rollover_span(st, peer):
             staging, used = st.staging, st.region_used
             if self._admit_ram_round(staging.nbytes, reuse=True):
                 st.prev_rounds.append((staging, used))
                 self._ram_round_bytes += staging.nbytes
+                self._queue_early_round(st)
+                st.live_held = bool(self._free_rounds.get(staging.nbytes))  # what the take below hands out
                 st.staging = self._take_round_buffer(staging.nbytes)
                 self._write_stats["ram_rounds"] += 1
             else:
@@ -983,7 +1048,7 @@ class HbmBlockStore:
 
     def _await_quiet(self, st: _ShuffleState) -> bool:
         """Return with no receive in flight into any round of ``st`` and no
-        owner putting pieces of its live round — caller holds self._lock,
+        owner putting pieces of its live round or its completed rounds — caller holds self._lock,
         RELEASED while this waits.  True when it waited: the caller then
         looks at the state again."""
         waited = False
@@ -992,16 +1057,17 @@ class HbmBlockStore:
         return waited
 
     def _await_put_owner(self, st: _ShuffleState) -> bool:
-        """Wait the owner of ``st``'s update chain out (caller holds
-        self._lock, released meanwhile): it is inside a ``device_put``, an
-        update or the wait for an older piece's transfer, and takes this lock
-        to let go.  No new reservation is admitted meanwhile."""
-        behind = st.put_behind
-        if behind is None or not behind.owner:
+        """Wait the owner of ``st``'s update chain, and the owner putting its
+        completed rounds (``_EarlyRounds``), out (caller holds self._lock,
+        released meanwhile): it is inside a ``device_put``, an update or the
+        wait for an older piece's transfer, and takes this lock to let go.
+        No new reservation is admitted meanwhile."""
+        owned = [o for o in (st.put_behind, st.early_rounds) if o is not None and o.owner]
+        if not owned:
             return False
         st.draining += 1
         try:
-            while behind.owner:
+            while any(o.owner for o in owned):
                 self._cond.wait(timeout=1.0)
         finally:
             st.draining -= 1
@@ -1071,7 +1137,7 @@ class HbmBlockStore:
 
         behind = st.put_behind
         if behind is None:
-            return
+            return self._put_early_rounds(st)
         with self._lock:
             pieces, payload = self._claim_pieces(st, behind)
         row_bytes = behind.alignment
@@ -1124,6 +1190,163 @@ class HbmBlockStore:
         behind.buf = _update_rows_fn()(behind.buf, piece, np.int32(at))
         behind.in_flight.append(piece)
 
+    # -- completed rounds put before the exchange (``_EarlyRounds``) ---------
+
+    def _queue_early_round(self, st: _ShuffleState) -> None:
+        """``st``'s live round has just rolled into ``prev_rounds`` on the RAM
+        arm (caller holds self._lock, ``st.round`` is still its index): where
+        this store has a device and the round's buffer came from the free
+        list, the round waits for its put — ready at once, or when the last
+        extent still open in it is settled (``receive_ended``)."""
+        early = st.early_rounds
+        if self.device is None or not st.live_held or (early is not None and early.closed):
+            return
+        if early is None:
+            early = st.early_rounds = _EarlyRounds()
+        if st.inflight.get(st.round):
+            early.open.add(st.round)
+        else:
+            early.ready.append(st.round)
+
+    def _claim_early_rounds(self, st: _ShuffleState, early: _EarlyRounds):
+        """The rounds of ``st`` that are final and nobody has put, claimed
+        for the caller (caller holds self._lock): ``[(round, the round as
+        rows, its used bytes a region)]`` and ``early.owner`` set; ``[]``
+        where another thread owns the puts, the shuffle is sealed or removed,
+        nothing is ready, the copies the exchange has not taken would pass
+        the RAM rounds' budget (with the disk tier off a shuffle's RAM rounds
+        have no bound of their own: no round is put early from there on), or
+        the watermark gate refuses — the rounds then stay queued, a later
+        record asks again, and the exchange puts whatever was not put."""
+        if early.owner or early.closed or not early.ready or st.sealed or st.removed:
+            return []
+        lane = st.alignment // 4
+        claimed, nbytes, over_budget = [], 0, False
+        for rnd in early.ready:
+            staging, used = st.prev_rounds[rnd]
+            if self._early_round_bytes + nbytes + staging.nbytes > self._ram_budget:
+                over_budget = True
+                break
+            claimed.append((rnd, staging.view(np.int32).reshape(-1, lane), used))
+            nbytes += staging.nbytes
+        if claimed:
+            try:
+                self._check_pressure_locked("round_put", nbytes)
+            except ResourceExhaustedError:
+                return []
+        if over_budget:
+            # the budget comes back only when the exchange takes its copies:
+            # what is queued now, and rolls later, is the exchange's to put
+            early.closed = True
+            early.ready.clear()
+            early.open.clear()
+        else:
+            for _ in claimed:
+                early.ready.popleft()
+        if claimed:
+            self._early_round_bytes += nbytes  # given back for a round that was not put
+            early.owner = True
+        return claimed
+
+    def _put_early_rounds(self, st: _ShuffleState) -> None:
+        """Put the completed rounds of ``st`` that are final now, on the
+        calling thread — a writer whose record found one ready — as the one
+        owner: claimed under self._lock, put OUTSIDE it, and claimed again
+        until none is left.  A round the device has no room for beside what
+        it holds (``memory_stats``, where the runtime gives one) is left to
+        the exchange's put.  A put the runtime refuses is logged and costs
+        the shuffle its early copies, never the write; any other error is
+        this code's and goes up through the write, the copies let go either
+        way.
+
+        Span ``store.round_put``, once a round: **the time the call holds
+        this thread** (the runtime's staging of the source), NOT the DMA."""
+        import jax
+
+        early = st.early_rounds
+        if early is None:
+            return
+        with self._lock:
+            claimed = self._claim_early_rounds(st, early)
+        while claimed:
+            put: Dict[int, object] = {}
+            failed = True
+            try:
+                for rnd, payload, used in claimed:
+                    if not self._device_has_room(int(payload.nbytes)):
+                        continue
+                    faults.check("store.round_put", shuffle_id=st.shuffle_id, round=rnd)
+                    with span(
+                        "store.round_put", shuffle_id=st.shuffle_id, executor=self.executor_id,
+                        round=rnd, bytes=int(payload.nbytes),
+                    ):
+                        put[rnd], _ = self._put_round(payload, st.pieces(), used, st.alignment)
+                failed = False
+            except jax.errors.JaxRuntimeError:
+                logger.warning(
+                    "shuffle %d: a completed round's early put failed; the exchange puts its rounds",
+                    st.shuffle_id, exc_info=True,
+                )
+            finally:
+                with self._lock:
+                    early.owner = False
+                    nbytes = sum(int(a.nbytes) for a in put.values())
+                    self._early_round_bytes -= sum(int(p.nbytes) for _, p, _ in claimed) - nbytes
+                    self._write_stats["early_round_puts"] += len(put)
+                    self._write_stats["early_round_bytes"] += nbytes
+                    early.copies.update(put)
+                    if failed:
+                        self._release_early_rounds(st)
+                    self._cond.notify_all()
+            with self._lock:
+                claimed = self._claim_early_rounds(st, early)
+
+    def _device_has_room(self, nbytes: int) -> bool:
+        """Whether ``nbytes`` more fit this store's device beside what is in
+        use there, by the runtime's own count; True where it gives none (the
+        CPU backend)."""
+        stats = self.device.memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        return not limit or stats.get("bytes_in_use", 0) + nbytes <= limit
+
+    def take_early_round(self, shuffle_id: int, round_idx: int):
+        """The copy of sealed round ``round_idx`` that was put on this
+        store's device before the seal, handed over — the exchange donates
+        it and puts nothing for the round — or None: the round was not put
+        early, or its copy was let go."""
+        with self._lock:
+            st = self._shuffles.get(shuffle_id)
+            early = st.early_rounds if st is not None else None
+            array = early.copies.pop(round_idx, None) if early is not None else None
+            if array is not None:
+                self._early_round_bytes -= int(array.nbytes)
+            return array
+
+    def release_early_rounds(self, shuffle_id: int) -> None:
+        """Let go of every early copy of the shuffle's rounds that the
+        exchange has not taken (a plan whose window is not the staging slot,
+        an aborted exchange): counted in ``early_rounds_dropped`` and
+        ``released_device_bytes``; the host rounds are what they were."""
+        with self._lock:
+            st = self._shuffles.get(shuffle_id)
+            if st is not None:
+                self._release_early_rounds(st)
+
+    def _release_early_rounds(self, st: _ShuffleState) -> None:
+        """Close ``st``'s early rounds and let their copies go (caller holds
+        self._lock; no owner is putting but the caller)."""
+        early = st.early_rounds
+        if early is None:
+            return
+        early.closed = True
+        early.open.clear()
+        early.ready.clear()
+        nbytes = sum(int(a.nbytes) for a in early.copies.values())
+        self._early_round_bytes -= nbytes
+        self._write_stats["early_rounds_dropped"] += len(early.copies)
+        self._write_stats["released_device_bytes"] += nbytes
+        early.copies.clear()
+
     # -- RAM rounds and the free list of round buffers ---------------------
 
     def _admit_ram_round(self, nbytes: int, reuse: bool) -> bool:
@@ -1170,8 +1393,10 @@ class HbmBlockStore:
         held = bool(self._free_rounds.get(nbytes))  # what the take below hands out
         buf = self._take_round_buffer(nbytes)
         st = self._shuffles.get(shuffle_id)
-        if held and st is not None and self.device is not None:
-            st.put_behind = st.pieces()
+        if held and st is not None:
+            st.live_held = True
+            if self.device is not None:
+                st.put_behind = st.pieces()
         return buf
 
     def _take_round_buffer(self, nbytes: int) -> np.ndarray:
@@ -1612,14 +1837,16 @@ class HbmBlockStore:
         is in its extent (caller holds the store's lock): the entry names the
         extent and the round it was taken in, and the extent no longer holds
         a put cursor.  True when the record took its region's final mark past
-        the end of a piece to put (``_PutBehind``): the caller then calls
-        ``put_behind`` outside the lock."""
+        the end of a piece to put (``_PutBehind``), or finds a completed round
+        final and waiting for its put (``_EarlyRounds``): the caller then
+        calls ``put_behind`` outside the lock."""
         st.blocks[key] = _BlockEntry(offset=start, length=length, padded=padded, round=round_idx)
         if resv is not None:
             st.settled(resv)
         behind = st.put_behind
         if behind is None:
-            return False
+            early = st.early_rounds
+            return early is not None and bool(early.ready) and not early.owner
         p = start // st.region_size
         end = behind.next_end[p]
         # the used prefix has to be past the piece's end before an extent still open can matter
@@ -1650,6 +1877,11 @@ class HbmBlockStore:
             st.inflight[resv.round] = left
         else:
             del st.inflight[resv.round]
+            early = st.early_rounds
+            if early is not None and resv.round in early.open:
+                # the last extent open in a completed round: it is final now
+                early.open.discard(resv.round)
+                early.ready.append(resv.round)
             if st.draining:
                 self._cond.notify_all()
 
@@ -1768,11 +2000,13 @@ class HbmBlockStore:
             out = []
             # Staging (completed rounds) stays host-resident until
             # remove_shuffle — it is the shuffle's backing store, the same
-            # retention contract as Spark's map-output files on disk.  HBM is
-            # only committed one round at a time: the single-round common case
-            # seals straight to device; multi-round payloads are uploaded
-            # per-round by the exchange so device memory stays bounded by one
-            # round.
+            # retention contract as Spark's map-output files on disk.  The
+            # single-round common case seals straight to device; a
+            # multi-round shuffle's payloads are handed on as host rounds,
+            # and the exchange takes each one's copy from the device where
+            # the store put it there when it became final (``_EarlyRounds``:
+            # at most ``_ram_budget`` of HBM a store) and puts it itself
+            # where not.
             device_put_here = self.device is not None and not st.prev_rounds
             for staging, used in st.prev_rounds:
                 payload = staging.view(np.int32).reshape(-1, lane)
@@ -1793,7 +2027,10 @@ class HbmBlockStore:
                         "store.seal_put", shuffle_id=shuffle_id,
                         executor=self.executor_id, bytes=int(payload.nbytes),
                     ):
-                        payload = self._put_round(payload, st)
+                        payload, pieces = self._put_round(
+                            payload, st.put_behind or st.pieces(), st.region_used, st.alignment
+                        )
+                        self._write_stats["seal_put_pieces"] += pieces
             st.put_behind = None  # ``_put_round`` has handed its buffer over, or it never began
             out.append((payload, final_sizes))
             st.sealed_payload = [p for p, _ in out]
@@ -1804,11 +2041,16 @@ class HbmBlockStore:
             cb(shuffle_id)
         return out
 
-    def _put_round(self, payload: np.ndarray, st: _ShuffleState):
-        """The single sealed round onto ``self.device`` (caller holds
-        self._lock and has waited the owner of ``st.put_behind`` out).  A
-        round of up to ``SEAL_PUT_PIECE_BYTES`` is one ``device_put``, as
-        ever.  A larger one goes in pieces of that size,
+    def _put_round(
+        self, payload: np.ndarray, behind: Optional[_PutBehind], region_used: np.ndarray, alignment: int
+    ):
+        """A whole round onto ``self.device``: the single round at its seal
+        (caller holds self._lock and has waited the owner of ``behind``, the
+        shuffle's ``put_behind``, out) or a completed round put before the
+        exchange (``_put_early_rounds``: ``behind`` fresh, its owner's call
+        outside the lock).  ``(the round on the device, pieces put)``.  A
+        round of up to ``SEAL_PUT_PIECE_BYTES`` — ``behind`` None — is one
+        ``device_put``, as ever.  A larger one goes in pieces of that size,
         ``SEAL_PUT_PIECES_IN_FLIGHT`` at a time, into a zeroed device buffer
         that each update donates back (in place: HBM holds the round once and
         a few pieces): ONE ``device_put`` of 4 GiB ran at 0.30 GiB/s on a v5e
@@ -1818,16 +2060,15 @@ class HbmBlockStore:
         put behind the writers (``_PutBehind``): this call carries that chain
         on from where its owner left it — the piece each region's writer
         stood in, a piece across two regions, everything where nothing was
-        put early — and hands its buffer over (``seal_put_pieces``)."""
+        put early — and hands its buffer over."""
         import jax
         import jax.numpy as jnp
 
-        rows, lane = payload.shape
-        behind = st.put_behind or st.pieces()
         if behind is None:
-            return jax.device_put(payload, self.device)
+            return jax.device_put(payload, self.device), 0
+        rows, lane = payload.shape
         region_rows = behind.region_rows
-        used_rows = -(-st.region_used // st.alignment)
+        used_rows = -(-region_used // alignment)
         pieces = 0
         for at in range(0, rows, behind.piece_rows):
             if behind.is_put(at):
@@ -1841,10 +2082,9 @@ class HbmBlockStore:
                 continue
             self._put_piece(behind, payload, at)
             pieces += 1
-        self._write_stats["seal_put_pieces"] += pieces
         if behind.buf is None:  # not a used row in the round
             behind.buf = jnp.zeros((rows, lane), dtype=jnp.int32, device=self.device)
-        return behind.buf
+        return behind.buf, pieces
 
     def num_rounds(self, shuffle_id: int) -> int:
         st = self._state(shuffle_id)

@@ -126,7 +126,9 @@ class MapWriter:
     (or ``end_receive``) that takes a region's used prefix past the end of a
     piece puts that piece from this thread, after the block is recorded and
     outside the store's lock (``HbmBlockStore.put_behind``).  Anywhere else
-    a block pays one more ``None`` check under the lock.
+    a block pays one more ``None`` check under the lock.  The same call, by
+    the writer whose record finds one ready, puts a multi-round job's
+    completed rounds once they are final (``_EarlyRounds``; PR 57).
     """
 
     def __init__(self, store: HbmBlockStore, state, map_id: int, discard: bool = False) -> None:

@@ -38,10 +38,16 @@ exchange.recover.submit   collective plane, before each sub-exchange of a degrad
 store.mem_pressure        store/hbm_store.py + memory/pool.py, before each
                           allocation-bearing mutation (close_partition, device
                           write, replica install, restage, pool growth, a piece
-                          put behind the writer: site ``piece_put``, where a
-                          refusal means no early put, not a failed write) — arming
+                          put behind the writer and a completed round put before
+                          the exchange: sites ``piece_put`` / ``round_put``, where
+                          a refusal means no early put, not a failed write) — arming
                           ``fail(ResourceExhaustedError(...))`` models a host
                           under memory pressure (ctx: ``site``, ``nbytes``)
+store.round_put           store/hbm_store.py, outside the store's lock, before a
+                          completed round of a multi-round shuffle is put on the
+                          device ahead of the exchange (ctx: ``shuffle_id``,
+                          ``round``) — a runtime error here costs the shuffle its
+                          early copies, never the write
 ========================  ==========================================================
 
 :func:`kill_executor` force-kills a loopback-cluster executor: its server

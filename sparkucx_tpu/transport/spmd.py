@@ -306,6 +306,14 @@ class SpmdShuffleExecutor:
         # slot bucket; payloads relocate into the bucketed slot layout below.
         bucketed = q * n
         fn = self._exchange_fn_for(bucketed, lane, plan.lowering)
+        # a sealed round already on the device is donated as it is where the
+        # plan's slot is the staging slot: so is the copy of a completed round
+        # the store put there before the seal (HbmBlockStore.take_early_round);
+        # a window of one would have to be cut on the device, so such a plan
+        # lets the copies go and puts the host rounds as ever
+        donates = plan.single_shot and q == staging_slot
+        if not donates:
+            self.store.release_early_rounds(shuffle_id)
 
         def _submit(rnd, chunk, nchunks):
             """One sub-round's assemble + H2D + collective dispatch (all JAX
@@ -320,6 +328,10 @@ class SpmdShuffleExecutor:
                 if rnd < len(rounds):
                     payload, sizes = rounds[rnd]
                     sub_sizes = chunk_size_rows(sizes, chunk, q)
+                    if donates and not isinstance(payload, jax.Array):
+                        early = self.store.take_early_round(shuffle_id, rnd)
+                        if early is not None:
+                            payload = early
                     if isinstance(payload, jax.Array):
                         # Sealed straight onto the device (device staging or
                         # the single-round host seal): relocate/slice
@@ -328,9 +340,7 @@ class SpmdShuffleExecutor:
                         # the staging slot donates the sealed payload as-is
                         # (historical fast path).
                         piece = (
-                            payload
-                            if plan.single_shot and q == staging_slot
-                            else slice_subround(payload, n, chunk, q, xp=jnp)
+                            payload if donates else slice_subround(payload, n, chunk, q, xp=jnp)
                         )
                     else:
                         piece = slice_subround(np.asarray(payload), n, chunk, q)
@@ -396,17 +406,21 @@ class SpmdShuffleExecutor:
             used = int(logical.sum())
             return shard, logical, (used, nchunks * bucketed - used)
 
-        results = execute_plan(
-            plan,
-            submit=_submit,
-            drain_chunk=_drain_chunk,
-            finish_round=_finish_round,
-            result_bytes=lambda r: int(r[1].sum()) * self.conf.block_alignment,
-            # per-round staging occupancy of this process's shard (the slot
-            # padding the planner's quota/chunking exists to shrink)
-            occupancy=lambda r: r[2],
-            stats=self.stats,
-        )
+        try:
+            results = execute_plan(
+                plan,
+                submit=_submit,
+                drain_chunk=_drain_chunk,
+                finish_round=_finish_round,
+                result_bytes=lambda r: int(r[1].sum()) * self.conf.block_alignment,
+                # per-round staging occupancy of this process's shard (the slot
+                # padding the planner's quota/chunking exists to shrink)
+                occupancy=lambda r: r[2],
+                stats=self.stats,
+            )
+        except BaseException:
+            self.store.release_early_rounds(shuffle_id)  # no HBM for rounds never sent
+            raise
         recv_shards = [shard for shard, _, _ in results]
         recv_sizes_rows = [sizes for _, sizes, _ in results]
         for sizes in recv_sizes_rows:

@@ -769,6 +769,22 @@ class TpuShuffleCluster:
         q = plan.slot_rows
         bucketed = q * n  # staged rows per executor (n slots x the plan slot)
         fn = self._exchange_fn(bucketed, plan.lowering)
+        # a sealed round already on its executor's device is donated as it is
+        # where the plan's slot is the staging slot
+        donates = plan.single_shot and q == staging_slot
+
+        def release_early_rounds():
+            """The copies of completed rounds the stores put on their devices
+            before the seal (``HbmBlockStore.take_early_round``) that no
+            submit took are let go: the host rounds are what is read from
+            here on."""
+            for t in self.transports:
+                t.store.release_early_rounds(shuffle_id)
+
+        if not donates:
+            # a window of such a copy would have to be cut on the device, an
+            # executable a shape: the host rounds are put as ever
+            release_early_rounds()
 
         # Elastic prep: snapshot the membership epoch the plan was built
         # against, and (when replication is on) copy each executor's sealed
@@ -802,15 +818,20 @@ class TpuShuffleCluster:
             device-resident piece per executor, never from a global host
             buffer.  Three child spans of ``exchange.pipeline.submit``, once
             a round and chunk: ``exchange.assemble`` (choosing and slicing
-            each executor's piece: a view of its sealed round wherever the
-            plan's slot is the staging slot, else the one copy
-            ``slice_subround`` makes), ``exchange.h2d`` (the time the
-            per-executor ``device_put`` calls and the global array's
-            construction hold this lane — NOT the DMA, which is asynchronous)
-            and ``exchange.collective`` (the dispatch).  Counters
+            each executor's piece: the round's copy on its device where the
+            store put it there before the seal (``take_early_round``), else a
+            view of its sealed round wherever the plan's slot is the staging
+            slot, else the one copy ``slice_subround`` makes),
+            ``exchange.h2d`` (the time the per-executor ``device_put`` calls
+            — of the rounds that are still on the host: none where every one
+            was put early — and the global array's construction hold this
+            lane — NOT the DMA, which is asynchronous) and
+            ``exchange.collective`` (the dispatch).  Counters
             ``exchange.assemble``: ``direct_bytes`` (host bytes handed to
-            ``device_put`` as views of a sealed round) and ``copied_bytes``
-            (host bytes that went through a pad / chunk-window copy first);
+            ``device_put`` as views of a sealed round), ``copied_bytes``
+            (host bytes that went through a pad / chunk-window copy first)
+            and, on a submit that found one, ``early_bytes`` (bytes of rounds
+            found on their device: no host byte of them moves in the exchange);
             where a piece's source is a mapping of the store's disk tier
             (``np.memmap``) also ``disk_rounds`` / ``disk_bytes``, and its put
             is ``exchange.h2d``'s child ``exchange.h2d.disk``."""
@@ -828,15 +849,23 @@ class TpuShuffleCluster:
                     f"dead: {dead}",
                 )
             payloads, size_rows = [], []
-            for s in sealed:
+            direct_bytes = copied_bytes = early_bytes = 0
+            for t, s in zip(self.transports, sealed):
                 if rnd < len(s):
-                    payloads.append(s[rnd][0])
+                    payload = s[rnd][0]
+                    if donates and not isinstance(payload, jax.Array):
+                        # the store's own copy of the round on its device,
+                        # put when the round became final: nothing to put here
+                        early = t.store.take_early_round(shuffle_id, rnd)
+                        if early is not None:
+                            payload = early
+                            early_bytes += int(early.nbytes)
+                    payloads.append(payload)
                     size_rows.append(s[rnd][1])
                 else:  # executor had fewer spill rounds: empty contribution
                     payloads.append(None)
                     size_rows.append(np.zeros(n, dtype=np.int32))
             sub_sizes = np.stack([chunk_size_rows(sr, chunk, q) for sr in size_rows])
-            direct_bytes = copied_bytes = 0
             on_disk = [isinstance(p, np.memmap) for p in payloads]  # the store's disk tier
             with span(
                 "exchange.assemble",
@@ -852,7 +881,7 @@ class TpuShuffleCluster:
                         # as-is when the bucket is the staging slot (the
                         # historical single-shot no-copy fast path), else
                         # relocate / slice the chunk window on that device.
-                        if plan.single_shot and q == staging_slot:
+                        if donates:
                             piece = p
                         else:
                             piece = slice_subround(p, n, chunk, q, xp=jnp)
@@ -868,6 +897,8 @@ class TpuShuffleCluster:
                             copied_bytes += piece.nbytes
                     pieces.append(piece)
             assembled = dict(direct_bytes=direct_bytes, copied_bytes=copied_bytes)
+            if early_bytes:  # rounds found on their devices: no host byte of them moves here
+                assembled.update(early_bytes=early_bytes)
             if any(on_disk):  # pieces whose source is a mapping of the store's disk tier
                 disk_bytes = sum(piece.nbytes for piece, disk in zip(pieces, on_disk) if disk)
                 assembled.update(disk_rounds=sum(on_disk), disk_bytes=disk_bytes)
@@ -1069,7 +1100,9 @@ class TpuShuffleCluster:
         except _MeshChanged:
             # An executor died under this exchange: abort the stale full-mesh
             # plan and re-run degraded on the surviving pow2 bucket (or raise
-            # a typed ExecutorLostError when recovery is impossible).
+            # a typed ExecutorLostError when recovery is impossible).  The
+            # re-run reads host rounds only.
+            release_early_rounds()
             # The recovery's large host arrays — the restaged rounds, the
             # landings of each sub-exchange's received prefixes, the
             # recovered shards — come from the pool received shards land in,
@@ -1080,6 +1113,9 @@ class TpuShuffleCluster:
             with span("exchange.recover", shuffle_id=shuffle_id), allocating:
                 self._recover_and_rerun(meta, sealed, mode, pool, plan.pipeline_depth)
             return
+        except BaseException:
+            release_early_rounds()  # an aborted exchange holds no HBM of rounds it never sent
+            raise
 
         meta.recv_shards, meta.recv_sizes = [], []
         for shards, sizes_host, dev_shards, _occ in results:

@@ -180,6 +180,33 @@ def test_four_connections_write_across_ram_rollovers(make_daemon, plane, switchy
 
 
 @PLANES
+def test_a_later_job_over_the_daemon_puts_its_rolled_rounds_before_the_exchange(make_daemon, plane, switchy):
+    """(a') The same job again on the same daemon: its rounds are written into
+    buffers the first gave back, so every round that rolls is put on the
+    device by the connection thread whose record found it final (PR 57,
+    ``_EarlyRounds``) — a receive still landing in a rolled round holds its
+    put — the exchange takes every copy, and all read back exact."""
+    daemon = make_daemon(plane, staging_capacity_per_executor=4 << 20)
+    records = gate_records()
+    with closing(DaemonClient(daemon.address)) as driver:
+        for sid in (0, 1):
+            driver.create_shuffle(sid, records.num_mappers, records.reducers)
+            before = store_of(daemon).write_stats()
+            write_maps(daemon, sid, records, connections=4)
+            stats = store_of(daemon).write_stats()
+            rolled = stats["rollovers"] - before["rollovers"]
+            puts = stats["early_round_puts"] - before["early_round_puts"]
+            # the last rollover's round may have found no record after it became final
+            assert rolled >= 2 and (puts == 0 if sid == 0 else rolled - 1 <= puts <= rolled)
+            in_time(driver.run_exchange, sid)
+            assert fetch_all(daemon, sid, records) == written(records)
+            after = store_of(daemon).write_stats()
+            assert after["early_rounds_dropped"] == 0 and after["inplace_fallbacks"] == 0
+            assert store_of(daemon)._early_round_bytes == 0
+            in_time(driver.remove_shuffle, sid)
+
+
+@PLANES
 def test_a_spill_waits_for_a_slow_senders_body(make_daemon, plane, switchy):
     """(b) No RAM tier, so every rollover spills and reuses the buffer: while
     one sender sits mid-body, the writers that fill the round wait for it at
