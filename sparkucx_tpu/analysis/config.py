@@ -497,6 +497,7 @@ ERROR_TAXONOMY = {
     "UnknownTenantError": "fail-fast",
     "TenantQuotaExceededError": "fail-fast",
     "ExecutorLostError": "fail-fast",
+    "SplitBlockError": "fail-fast",
 }
 
 #: Reader retry/failover functions (matched by name): statically barred from

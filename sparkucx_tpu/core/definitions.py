@@ -58,6 +58,8 @@ import struct
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+from sparkucx_tpu.core.operation import TransportError
+
 
 class AmId(enum.IntEnum):
     """Definitions.scala:22-29."""
@@ -342,16 +344,32 @@ class MapperInfo:
     instead of relying on device-space carve-up by shuffleId, and an optional
     per-partition staging-round index (multi-round spill) carried as a
     backward-compatible tail: blobs without the tail decode with all rounds 0.
+
+    A block longer than a peer region is staged as consecutive *pieces* in
+    successive staging rounds (``store/writer.py`` ``MapWriter._close_split``):
+    ``partitions[r]`` keeps (its FIRST piece's offset, its whole length) and
+    ``rounds[r]`` its first piece's round, and ``splits[r]`` names every piece
+    in order, ``(round, offset, length)`` — a second tail, written only by a
+    map task that has such a block, so a task without one packs what it always
+    packed.  A tail this decoder does not know, and bytes no tail accounts
+    for, fail typed: a block must never come back as its first piece alone.
     """
 
     shuffle_id: int
     map_id: int
     partitions: Tuple[Tuple[int, int], ...]  # (offset, length) per reduce partition
     rounds: Optional[Tuple[int, ...]] = None  # staging round per partition
+    #: reduce partition -> its block's pieces ``((round, offset, length), ...)``
+    #: in order; only blocks staged in more than one piece have an entry
+    splits: Optional[Dict[int, Tuple[Tuple[int, int, int], ...]]] = None
 
     _HDR = struct.Struct("<iii")  # shuffle_id, map_id, num_partitions
     _ENT = struct.Struct("<qq")  # offset, length
     _RND = struct.Struct("<i")  # round index
+    _SPLITS = struct.Struct("<i")  # the second tail: how many split blocks
+    _SPLIT = struct.Struct("<ii")  # reduce partition, its pieces
+    _PIECE = struct.Struct("<iqq")  # round, offset, length
+    _TAIL_ROUNDS, _TAIL_SPLITS = 1, 2
 
     def round_of(self, reduce_id: int) -> int:
         return self.rounds[reduce_id] if self.rounds is not None else 0
@@ -364,6 +382,13 @@ class MapperInfo:
             out += b"\x01"
             for r in self.rounds:
                 out += self._RND.pack(r)
+        if self.splits:
+            out += b"\x02" + self._SPLITS.pack(len(self.splits))
+            for reduce_id in sorted(self.splits):
+                pieces = self.splits[reduce_id]
+                out += self._SPLIT.pack(reduce_id, len(pieces))
+                for piece in pieces:
+                    out += self._PIECE.pack(*piece)
         return bytes(out)
 
     @classmethod
@@ -376,7 +401,30 @@ class MapperInfo:
             offs.append((off, ln))
             pos += cls._ENT.size
         rounds: Optional[Tuple[int, ...]] = None
-        if pos < len(data) and data[pos] == 1:
-            pos += 1
-            rounds = tuple(cls._RND.unpack_from(data, pos + i * cls._RND.size)[0] for i in range(n))
-        return cls(sid, mid, tuple(offs), rounds)
+        splits: Optional[Dict[int, Tuple[Tuple[int, int, int], ...]]] = None
+        try:
+            while pos < len(data):
+                tail = data[pos]
+                pos += 1
+                if tail == cls._TAIL_ROUNDS and rounds is None and splits is None:
+                    rounds = tuple(cls._RND.unpack_from(data, pos + i * cls._RND.size)[0] for i in range(n))
+                    pos += n * cls._RND.size
+                elif tail == cls._TAIL_SPLITS and splits is None:
+                    (count,) = cls._SPLITS.unpack_from(data, pos)
+                    pos += cls._SPLITS.size
+                    splits = {}
+                    for _ in range(count):
+                        reduce_id, pieces = cls._SPLIT.unpack_from(data, pos)
+                        pos += cls._SPLIT.size
+                        splits[reduce_id] = tuple(
+                            cls._PIECE.unpack_from(data, pos + i * cls._PIECE.size) for i in range(pieces)
+                        )
+                        pos += pieces * cls._PIECE.size
+                else:
+                    raise TransportError(
+                        f"commit record of map {mid} of shuffle {sid} has a tail this decoder "
+                        f"does not know (byte {tail:#04x} at {pos - 1} of {len(data)})"
+                    )
+        except struct.error as e:
+            raise TransportError(f"commit record of map {mid} of shuffle {sid} is cut short: {e}") from e
+        return cls(sid, mid, tuple(offs), rounds, splits or None)

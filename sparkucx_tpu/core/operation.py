@@ -80,6 +80,26 @@ class BlockNotFoundError(TransportError):
         super().__init__(msg)
 
 
+class SplitBlockError(TransportError):
+    """A reader that takes a block from ONE staging round was asked for a
+    block staged in pieces over several (longer than a peer region;
+    ``MapperInfo.splits``).  Typed + addressed, raised before a byte moves:
+    such a reader never hands the block out short.  The host read
+    (``TpuShuffleReader.read`` / ``read_batches``), the pull path and the
+    daemon's fetch put the pieces together; ``docs/DEPLOYMENT.md`` lists the
+    paths that refuse."""
+
+    def __init__(self, shuffle_id: int, map_id: int, reduce_id: int, pieces: int, detail: str) -> None:
+        self.shuffle_id = shuffle_id
+        self.map_id = map_id
+        self.reduce_id = reduce_id
+        self.pieces = pieces
+        super().__init__(
+            f"block (shuffle={shuffle_id}, map={map_id}, reduce={reduce_id}) is staged in "
+            f"{pieces} pieces over as many staging rounds (it is longer than a peer region): {detail}"
+        )
+
+
 class BlockCorruptError(TransportError):
     """A block's wire payload failed its integrity check (wire.checksum).
 

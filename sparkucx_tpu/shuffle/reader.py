@@ -33,6 +33,7 @@ from sparkucx_tpu.core.operation import (
     ExecutorLostError,
     OperationStatus,
     Request,
+    SplitBlockError,
     TenantQuotaExceededError,
     TransportError,
     UnknownTenantError,
@@ -70,7 +71,7 @@ class _WindowMarks:
 #: replica answers identically (tenant admission) or that name an executor
 #: the membership plane already declared dead.  Retrying burns the failover
 #: budget to hit the same wall — the retry path re-raises these immediately.
-_FAIL_FAST_ERRORS = (TenantQuotaExceededError, UnknownTenantError, ExecutorLostError)
+_FAIL_FAST_ERRORS = (TenantQuotaExceededError, UnknownTenantError, ExecutorLostError, SplitBlockError)
 
 
 @dataclass
@@ -103,6 +104,12 @@ class ShuffleReadMetrics:
     #: (``transport.resident_blocks``)
     resident_blocks: int = 0
     resident_bytes: int = 0
+    #: of ``resident_blocks``, the blocks (and their bytes) staged in pieces
+    #: — longer than a peer region — that were put together from their
+    #: pieces' views into one array: the one copy a borrowed read makes
+    #: (span ``read.block_assemble``)
+    assembled_blocks: int = 0
+    assembled_bytes: int = 0
     #: blocks a transport's fetch copied (or received) into a result buffer;
     #: ``resident_blocks + copied_blocks == remote_blocks_fetched``
     copied_blocks: int = 0
@@ -662,14 +669,18 @@ class TpuShuffleReader:
         for sender, items in groups.items():
             bids = [bid for bid, _ in items]
             if resident is not None and sender == self.executor_id:
+                assembled: List[int] = []
                 try:
-                    views = resident(bids)
+                    views = resident(bids, assembled)
                 except ExecutorLostError:
                     raise  # what received them is dead: typed, at once, no byte
                 except Exception:
                     pass  # the fetch below fails the block at fault, alone
                 else:
                     requests.extend((bid, view, None) for bid, view in zip(bids, views))
+                    if assembled:  # blocks staged in pieces: one copy each
+                        self.metrics.assembled_blocks += len(assembled)
+                        self.metrics.assembled_bytes += sum(assembled)
                     continue
             buffers = self._alloc_bufs([size for _, size in items])
             reqs = self.transport.fetch_blocks_by_block_ids(
@@ -799,7 +810,8 @@ class TpuShuffleReader:
         its blocks were read — borrowed or copied — and, if any, its
         failover counters, what a re-placed task pulled (``refetched_blocks``
         / ``refetched_bytes`` / ``replica_blocks`` / ``replica_bytes``) and, of
-        a batch read, ``record_batches`` / ``batch_records``."""
+        a batch read, ``record_batches`` / ``batch_records``; of a task that
+        read a block staged in pieces, ``assembled_blocks`` / ``assembled_bytes``."""
         agg = getattr(self.transport, "stats_agg", None)
         if agg is None:
             return
@@ -836,6 +848,8 @@ class TpuShuffleReader:
             )
         if m.record_batches:
             counters.update(record_batches=m.record_batches, batch_records=m.records_read)
+        if m.assembled_blocks:
+            counters.update(assembled_blocks=m.assembled_blocks, assembled_bytes=m.assembled_bytes)
         agg.record_counters("read", **counters)
 
     def _hedge_delay_ns(self) -> int:

@@ -209,6 +209,11 @@ class _BlockEntry:
     #: sender-relative, so the bytes live on the SENDER, not in local staging.
     #: The replicator only pushes local entries.
     local: bool = True
+    #: a block longer than a peer region: its pieces ``(round, offset,
+    #: length)`` in order, each an extent of its round's staging, the first
+    #: at ``(round, offset)`` above (``HbmBlockStore.take_piece``).  None for
+    #: the block of one extent that every other block is
+    pieces: Optional[Tuple[Tuple[int, int, int], ...]] = None
 
 
 @dataclass(eq=False, slots=True)
@@ -618,7 +623,8 @@ class HbmBlockStore:
              "largest_block_bytes", "early_put_pieces", "early_put_bytes",
              "seal_put_pieces", "early_put_dropped", "unlocked_copy_blocks",
              "unlocked_copy_bytes", "early_round_puts", "early_round_bytes",
-             "early_rounds_dropped"), 0
+             "early_rounds_dropped", "split_blocks", "split_pieces", "split_bytes",
+             "split_rollovers"), 0
         )
         #: RAM tier of completed rounds (``_rollover``): capacity bytes of the
         #: RAM rounds live shuffles hold plus the free list never exceed
@@ -1852,6 +1858,86 @@ class HbmBlockStore:
         # the used prefix has to be past the piece's end before an extent still open can matter
         return p * st.region_size + int(st.region_used[p]) >= end and behind.final_marks(st.region_used)[p] >= end
 
+    def take_piece(
+        self, st: _ShuffleState, reduce_id: int, left: int, first: bool, hold: bool,
+    ) -> Tuple[np.ndarray, int, int, int, int, Optional[_Reservation]]:
+        """The next piece of a block of partition ``reduce_id`` that is longer
+        than a peer region, ``left`` padded bytes of it still without a place
+        (caller holds the lock): ``take_extent``'s checks, then the room the
+        block's region has in the live round — a full region first rolls the
+        round — up to ``left``.  ``(staging, start, taken bytes, the piece's
+        round, rollovers this piece forced, resv)``.  Every piece but a
+        block's last ends its region, so only the last is padded.
+
+        ``first``: the block's whole padded length (``left``) is charged to
+        its tenant here, before any piece has a place — an over-quota block
+        fails typed with nothing allocated, rolled over or copied; the charge
+        of a block that fails later is given back by ``lose_extent``, and the
+        pieces it had placed stay holes that no entry names.  ``hold`` as in
+        ``take_extent``: the piece is filled outside the lock, its round's
+        in-flight count taken until ``receive_ended``."""
+        # ``take_extent``'s checks, which it keeps inline (a call a block)
+        while st.draining:
+            self._cond.wait(timeout=1.0)
+        if st.removed:
+            raise TransportError(f"unknown shuffle {st.shuffle_id}")
+        if st.sealed:
+            raise TransportError(f"shuffle {st.shuffle_id} already sealed")
+        if st.device_mode:
+            raise TransportError(
+                f"shuffle {st.shuffle_id} already has device-staged rounds — "
+                "host and device writes cannot mix"
+            )
+        peer = st.owner_of(reduce_id)
+        st.device_mode = False
+        if first:
+            self._charge_tenant(st, left)  #: balanced by _release_tenant
+        rolled = 0
+        try:
+            # a rollover may wait, lock released, for copies in flight: look again
+            while int(st.region_used[peer]) >= st.region_size:
+                self._refuse_shm_overflow(st)
+                self._rollover(st, peer)
+                rolled += 1
+        except BaseException:
+            if first:
+                self._release_tenant(st, left)
+            raise
+        staging = st.staging
+        used = int(st.region_used[peer])
+        taken = min(left, st.region_size - used)
+        start = peer * st.region_size + used
+        resv = None
+        if hold:
+            resv = _Reservation(st.round, start, taken)
+            if st.put_behind is not None:
+                st.put_behind.open.add(resv)  # until ``_ShuffleState.settled``
+            st.inflight[resv.round] = st.inflight.get(resv.round, 0) + 1
+        st.region_used[peer] = used + taken
+        return staging, start, taken, st.round, rolled, resv
+
+    def record_pieces(
+        self, st: _ShuffleState, key: Tuple[int, int], length: int, padded: int,
+        pieces: Sequence[Tuple[int, int, int]], rollovers: int,
+    ) -> bool:
+        """The table record of block ``key`` = (map, reduce), staged as
+        ``pieces`` of ``(round, offset, length)`` whose last byte is in place
+        (caller holds the lock): ONE entry that names them in order.  True
+        where a completed round is final and waits for its put, as
+        ``record_extent`` answers (a block's pieces rolled every round but
+        its last: none is put behind its writers)."""
+        rnd, offset, _ = pieces[0]
+        st.blocks[key] = _BlockEntry(
+            offset=offset, length=length, padded=padded, round=rnd, pieces=tuple(pieces)
+        )
+        counters = self._write_stats
+        counters["split_blocks"] += 1
+        counters["split_pieces"] += len(pieces)
+        counters["split_bytes"] += length
+        counters["split_rollovers"] += rollovers
+        early = st.early_rounds
+        return early is not None and bool(early.ready) and not early.owner
+
     def lose_extent(self, st: _ShuffleState, padded: int, resv: Optional[_Reservation]) -> None:
         """A partition's extent of ``padded`` bytes will never be recorded: its
         bytes did not fully arrive, or it went back to the buffered path
@@ -1960,11 +2046,12 @@ class HbmBlockStore:
                 return
         st = self._state(info.shuffle_id)
         with self._lock:
+            splits = info.splits or {}
             for r, (off, ln) in enumerate(info.partitions):
                 if ln:
                     padded = -(-ln // st.alignment) * st.alignment
                     st.blocks[(info.map_id, r)] = _BlockEntry(
-                        off, ln, padded, info.round_of(r), local=False
+                        off, ln, padded, info.round_of(r), local=False, pieces=splits.get(r)
                     )
             st.committed_maps.add(info.map_id)
 
@@ -2128,13 +2215,15 @@ class HbmBlockStore:
         with self._lock:
             if map_id not in st.committed_maps:
                 raise TransportError(f"map {map_id} not committed in shuffle {shuffle_id}")
-            parts, rounds = [], []
+            parts, rounds, splits = [], [], {}
             for r in range(st.num_reducers):
                 e = st.blocks.get((map_id, r))
                 parts.append((e.offset, e.length) if e is not None else (0, 0))
                 rounds.append(e.round if e is not None else 0)
+                if e is not None and e.pieces is not None:
+                    splits[r] = e.pieces
         return MapperInfo(
-            shuffle_id, map_id, tuple(parts), tuple(rounds) if any(rounds) else None
+            shuffle_id, map_id, tuple(parts), tuple(rounds) if any(rounds) else None, splits or None
         )
 
     # -- tiered eviction (service/eviction.py drives these) ----------------
@@ -2308,7 +2397,7 @@ class HbmBlockStore:
 
     # -- read path (serve staged blocks) ----------------------------------
 
-    def _live_device_block(self, st: _ShuffleState, e: _BlockEntry) -> np.ndarray:
+    def _live_device_block(self, st: _ShuffleState, rnd: int, offset: int, length: int) -> np.ndarray:
         """A block of a device shuffle's LAST round on the host (caller holds
         self._lock): out of the staging array while the round is being
         written, out of the sealed payload after.  A payload the exchange
@@ -2320,12 +2409,12 @@ class HbmBlockStore:
             array = st.sealed_payload[-1]
         if isinstance(array, np.ndarray):  # demoted to the host or the disk tier
             flat = array.reshape(-1).view(np.uint8)
-            return flat[e.offset : e.offset + e.length]
+            return flat[offset : offset + length]
         if array is None or array.is_deleted():
             raise TransportError(
-                f"device round {e.round} of shuffle {st.shuffle_id} is no longer resident"
+                f"device round {rnd} of shuffle {st.shuffle_id} is no longer resident"
             )
-        return _device_block_bytes(array, e.offset, e.length, st.alignment)
+        return _device_block_bytes(array, offset, length, st.alignment)
 
     def read_block(self, shuffle_id: int, map_id: int, reduce_id: int) -> bytes:
         """Direct block read — HBM after seal, host staging before
@@ -2355,34 +2444,47 @@ class HbmBlockStore:
             raise BlockNotFoundError(shuffle_id, map_id, reduce_id, "not staged")
         if e.length == 0:
             return b""
+        if e.pieces is None:
+            return self._extent_bytes(st, e.round, e.offset, e.length)
+        # a block longer than a region: its pieces, each where its round is now
+        with span(
+            "read.block_assemble", shuffle_id=shuffle_id, map_id=map_id, reduce_id=reduce_id,
+            executor=self.executor_id, pieces=len(e.pieces), bytes=e.length,
+        ):
+            return b"".join(self._extent_bytes(st, *piece) for piece in e.pieces)
+
+    def _extent_bytes(self, st: _ShuffleState, rnd: int, offset: int, length: int) -> bytes:
+        """``length`` bytes at ``offset`` of staging round ``rnd``, from the
+        tier that holds the round now (no lock held on entry)."""
+        shuffle_id = st.shuffle_id
         # Eviction hook (no lock held): bumps the round's LRU clock and
         # transparently restages a disk-tier round to RAM before we serve.
         ev = self.eviction
         if ev is not None:
-            ev.on_access(shuffle_id, e.round)
+            ev.on_access(shuffle_id, rnd)
         sealed = st.sealed_payload  # one read: remove_shuffle may clear it
         if sealed is not None:
-            payload = sealed[e.round]
+            payload = sealed[rnd]
             if not hasattr(payload, "is_deleted"):
                 flat = np.asarray(payload).reshape(-1).view(np.uint8)
-                return flat[e.offset : e.offset + e.length].tobytes()
+                return flat[offset : offset + length].tobytes()
             if not payload.is_deleted():
-                return _device_block_bytes(payload, e.offset, e.length, st.alignment).tobytes()
+                return _device_block_bytes(payload, offset, length, st.alignment).tobytes()
         # Lock: (prev_rounds, staging) must be read atomically vs _rollover,
         # and the bytes copy must complete before a concurrent remove_shuffle
         # can munmap shm staging (the closer also runs under this lock).
         with self._lock:
-            if e.round < len(st.prev_rounds):
-                staging = st.prev_rounds[e.round][0]
+            if rnd < len(st.prev_rounds):
+                staging = st.prev_rounds[rnd][0]
             elif st.device_mode:
                 # Live device round: the block's rows of the device staging
                 # array (one small D2H) — there is no host staging.
-                return self._live_device_block(st, e).tobytes()
+                return self._live_device_block(st, rnd, offset, length).tobytes()
             else:
                 staging = st.staging
             if staging is None:
                 raise TransportError(f"shuffle {shuffle_id} staging already released")
-            return staging[e.offset : e.offset + e.length].tobytes()
+            return staging[offset : offset + length].tobytes()
 
     def block_staging_view(
         self, shuffle_id: int, map_id: int, reduce_id: int
@@ -2402,11 +2504,17 @@ class HbmBlockStore:
         rollover keeps the buffer and zeroes it for the next round
         (``_rollover``), so a view into it would read the next round's bytes.
         shm-backed staging is always a private copy (``remove_shuffle`` may
-        munmap it once the lock is released)."""
+        munmap it once the lock is released), and so is a block staged in
+        pieces (``_BlockEntry.pieces``), whose bytes lie in several rounds."""
         st = self._state(shuffle_id)
         e = st.blocks.get((map_id, reduce_id))
         if e is None:
             return None
+        if e.pieces is not None:
+            # no one buffer holds a block longer than a region: a private
+            # copy, put together from its pieces (``read_block``)
+            data = np.frombuffer(self.read_block(shuffle_id, map_id, reduce_id), dtype=np.uint8)
+            return data, 0, e.length
         ev = self.eviction
         if ev is not None:
             ev.on_access(shuffle_id, e.round)
@@ -2417,7 +2525,7 @@ class HbmBlockStore:
                 # device array is donated on by the next write and let go by
                 # a rollover); None once the exchange took the sealed array.
                 try:
-                    return np.array(self._live_device_block(st, e)), 0, e.length
+                    return np.array(self._live_device_block(st, e.round, e.offset, e.length)), 0, e.length
                 except TransportError:
                     return None
             staging = st.staging if live else st.prev_rounds[e.round][0]
@@ -2483,7 +2591,8 @@ class HbmBlockStore:
         return e.length if e is not None else 0
 
     def block_offset(self, shuffle_id: int, map_id: int, reduce_id: int) -> int:
-        """getPartitonOffset analogue."""
+        """getPartitonOffset analogue; of a block staged in pieces, its first
+        piece's (``MapperInfo.partitions`` says the same)."""
         e = self._state(shuffle_id).blocks.get((map_id, reduce_id))
         if e is None:
             raise TransportError(f"no block ({shuffle_id},{map_id},{reduce_id}) staged")
@@ -2496,7 +2605,8 @@ class HbmBlockStore:
     ) -> List[Tuple[int, List[Tuple[int, int, int]], object]]:
         """Snapshot this executor's sealed rounds for replication: one
         ``(round, [(map, reduce, length)...], body)`` per staging round,
-        body = the unpadded block payloads concatenated in table order.  Only
+        body = the unpadded block payloads concatenated in table order (a
+        block staged in pieces whole, in its first piece's round).  Only
         locally staged entries are included — entries installed from peers'
         MapperInfo carry sender-relative offsets and no local bytes.
 
@@ -2515,15 +2625,23 @@ class HbmBlockStore:
         per-block D2H — is gathered with the lock held, as ever."""
         st = self._state(shuffle_id)
 
-        def gather(nbytes, segments, source, staged):
+        def gather(nbytes, segments, source, staged, further):
             body = bytearray(nbytes) if alloc is None else alloc(nbytes)
             dst = np.frombuffer(body, dtype=np.uint8) if alloc is None else body
             if source is None:  # a device round's last: one small D2H a block
                 for (at, _off, ln), e in zip(segments, staged):
-                    dst[at : at + ln] = self._live_device_block(st, e)
+                    dst[at : at + ln] = self._live_device_block(st, e.round, e.offset, e.length)
             else:
                 _gather_blocks(dst, source, segments)
+            for later, pieces in further:  # a split block's pieces past its first
+                _gather_blocks(dst, later, pieces)
             return body
+
+        def host_round(rnd):
+            source = st.prev_rounds[rnd][0] if rnd < len(st.prev_rounds) else st.staging
+            if source is None:
+                raise TransportError(f"shuffle {shuffle_id} staging already released")
+            return source
 
         plans = []  # (round, entries, body gathered under the lock or None, gather's arguments)
         with self._lock:
@@ -2535,21 +2653,30 @@ class HbmBlockStore:
                 entries: List[Tuple[int, int, int]] = []
                 segments: List[Tuple[int, int, int]] = []  # (body offset, round offset, length)
                 staged: List[_BlockEntry] = []  # the blocks of ``segments``
+                #: a block staged in pieces is replicated WHOLE, in the body of
+                #: its first piece's round: round -> the segments of its later pieces
+                later: Dict[int, List[Tuple[int, int, int]]] = {}
                 pos = 0
                 for key in sorted(by_round[rnd]):
                     e = st.blocks[key]
                     entries.append((key[0], key[1], e.length))
-                    if e.length:
+                    if e.pieces is not None:
+                        segments.append((pos, e.offset, e.pieces[0][2]))
+                        staged.append(e)
+                        at = pos + e.pieces[0][2]
+                        for piece_round, off, ln in e.pieces[1:]:
+                            later.setdefault(piece_round, []).append((at, off, ln))
+                            at += ln
+                        pos += e.length
+                    elif e.length:
                         segments.append((pos, e.offset, e.length))
                         staged.append(e)
                         pos += e.length
-                live = rnd >= len(st.prev_rounds)
+                live = rnd >= len(st.prev_rounds) or any(k >= len(st.prev_rounds) for k in later)
                 source = None
                 if not (live and st.device_mode):
-                    source = st.staging if live else st.prev_rounds[rnd][0]
-                    if source is None:
-                        raise TransportError(f"shuffle {shuffle_id} staging already released")
-                args = (pos, segments, source, staged)
+                    source = host_round(rnd)
+                args = (pos, segments, source, staged, [(host_round(k), later[k]) for k in sorted(later)])
                 under_lock = live and (
                     source is None or not st.sealed or st.staging_closer is not None
                 )

@@ -65,6 +65,29 @@ def _copy_chunks(staging: np.ndarray, start: int, chunks: Sequence[bytes]) -> No
         start += n
 
 
+class _ChunkCursor:
+    """A writer's buffered chunks read in order, a run of bytes at a time:
+    what a block staged in pieces copies into each piece.  The runs are views
+    of the chunks (``_copy_chunks`` takes them as it takes chunks)."""
+
+    __slots__ = ("_chunks", "_index", "_at")
+
+    def __init__(self, chunks: Sequence[bytes]) -> None:
+        self._chunks, self._index, self._at = chunks, 0, 0
+
+    def take(self, nbytes: int) -> List[memoryview]:
+        run: List[memoryview] = []
+        while nbytes:
+            chunk = self._chunks[self._index]
+            n = min(nbytes, len(chunk) - self._at)
+            run.append(memoryview(chunk)[self._at : self._at + n])
+            nbytes -= n
+            self._at += n
+            if self._at == len(chunk):
+                self._index, self._at = self._index + 1, 0
+        return run
+
+
 class MapWriter:
     """Sequential per-map partition writer handle.
 
@@ -120,6 +143,12 @@ class MapWriter:
     argument ``minor_faults``.  A discarded retry and an aborted writer record
     nothing.  Untraced, a block pays one ``None`` check at its open and two
     at its close.
+
+    A block longer than a peer region (PR 58) is these three steps a
+    PIECE: it takes the room its region has, the round rolls, it takes the
+    next, and ONE entry names the pieces in order (``_close_split``;
+    ``MapperInfo.splits``).  The write has no block-size limit; the device
+    write (one scatter a task) keeps its typed refusal.
 
     Behind the writer (``_PutBehind``; PR 51): where the store will seal the
     shuffle's single round onto its device in pieces, the ``close_partition``
@@ -207,11 +236,6 @@ class MapWriter:
     def write(self, data: bytes) -> None:
         if self._open_reduce is None:
             raise TransportError("no open partition")
-        if self._written + len(data) > self._state.region_size and not self._discard:
-            raise TransportError(
-                f"single partition ({self.map_id},{self._open_reduce}) exceeds a "
-                f"whole region ({self._state.region_size} B) — raise stagingCapacity"
-            )
         if not self._discard:
             if type(data) is bytes:  # bytes(data) would hand it back: no copy to time
                 self._chunks.append(data)
@@ -240,51 +264,54 @@ class MapWriter:
             # watermark gate before taking the lock: a shed write fails typed
             # (retryable ResourceExhaustedError) with nothing allocated
             store.check_memory_pressure("close_partition", padded)
-            lock = store.lock
-            t_lock = perf_counter_ns()
-            with lock:
-                self._lock_wait_ns += perf_counter_ns() - t_lock
-                # the only writer open keeps nobody waiting: it keeps the lock
-                # through all three steps (a writer opened meanwhile waits
-                # for this one copy)
-                unlocked = st.open_writers > 1
-                staging, start, resv = store.take_extent(st, reduce_id, padded, None, unlocked)
-                try:
-                    round_idx = st.round  # the extent's own: a rollover may interleave with the copy
+            if padded > st.region_size:  # longer than a region: staged in pieces
+                t0, t1, passed = self._close_split(reduce_id, padded)
+            else:
+                lock = store.lock
+                t_lock = perf_counter_ns()
+                with lock:
+                    self._lock_wait_ns += perf_counter_ns() - t_lock
+                    # the only writer open keeps nobody waiting: it keeps the lock
+                    # through all three steps (a writer opened meanwhile waits
+                    # for this one copy)
+                    unlocked = st.open_writers > 1
+                    staging, start, resv = store.take_extent(st, reduce_id, padded, None, unlocked)
                     try:
-                        if unlocked:
-                            # the extent is this writer's alone: no block names
-                            # it yet and no other writer can be given it; whoever
-                            # would read, zero or hand on its round waits for the
-                            # in-flight count
-                            lock.release()
-                        t0 = perf_counter_ns()
-                        _copy_chunks(staging, start, self._chunks)
-                        t1 = perf_counter_ns()
-                    finally:
-                        if unlocked:
-                            t_lock = perf_counter_ns()
-                            lock.acquire()
-                            self._lock_wait_ns += perf_counter_ns() - t_lock
-                            self._extra_lock_takes += 1
-                            store.receive_ended(st, resv, 0)
-                    if st.removed:  # a removal latches ``removed``, then waits for this copy
-                        raise TransportError(f"unknown shuffle {st.shuffle_id}")
-                except BaseException as e:
-                    self._lost = True  # the map's retry writes the partition again
-                    store.lose_extent(st, padded, resv)
-                    if isinstance(e, TransportError) or not isinstance(e, Exception):
-                        raise  # an interrupt stays an interrupt
-                    raise TransportError(
-                        f"partition ({self.map_id},{reduce_id}) lost its copy into staging: {e!r}"
-                    ) from e
-                passed = store.record_extent(
-                    st, (self.map_id, reduce_id), self._written, start, padded, round_idx, resv
-                )
-            self._copy_ns += t1 - t0
-            if unlocked:
-                self._unlocked_blocks += 1
-                self._unlocked_bytes += self._written
+                        round_idx = st.round  # the extent's own: a rollover may interleave with the copy
+                        try:
+                            if unlocked:
+                                # the extent is this writer's alone: no block names
+                                # it yet and no other writer can be given it; whoever
+                                # would read, zero or hand on its round waits for the
+                                # in-flight count
+                                lock.release()
+                            t0 = perf_counter_ns()
+                            _copy_chunks(staging, start, self._chunks)
+                            t1 = perf_counter_ns()
+                        finally:
+                            if unlocked:
+                                t_lock = perf_counter_ns()
+                                lock.acquire()
+                                self._lock_wait_ns += perf_counter_ns() - t_lock
+                                self._extra_lock_takes += 1
+                                store.receive_ended(st, resv, 0)
+                        if st.removed:  # a removal latches ``removed``, then waits for this copy
+                            raise TransportError(f"unknown shuffle {st.shuffle_id}")
+                    except BaseException as e:
+                        self._lost = True  # the map's retry writes the partition again
+                        store.lose_extent(st, padded, resv)
+                        if isinstance(e, TransportError) or not isinstance(e, Exception):
+                            raise  # an interrupt stays an interrupt
+                        raise TransportError(
+                            f"partition ({self.map_id},{reduce_id}) lost its copy into staging: {e!r}"
+                        ) from e
+                    passed = store.record_extent(
+                        st, (self.map_id, reduce_id), self._written, start, padded, round_idx, resv
+                    )
+                self._copy_ns += t1 - t0
+                if unlocked:
+                    self._unlocked_blocks += 1
+                    self._unlocked_bytes += self._written
         self._last_reduce = reduce_id
         self._open_reduce = None
         self._chunks = []
@@ -294,6 +321,91 @@ class MapWriter:
             self._block = None
         if passed:
             store.put_behind(st)
+
+    def _close_split(self, reduce_id: int, padded: int) -> Tuple[int, int, bool]:
+        """``close_partition`` of a block longer than a peer region: it is
+        staged as consecutive pieces, each the room its region has in the
+        staging round of the moment, the round rolled between two of them
+        (``HbmBlockStore.take_piece``), and recorded as ONE entry that names
+        the pieces in order (``record_pieces``; ``MapperInfo.splits``).
+
+        What holds for a block holds for this one as a whole: the watermark
+        gate (the caller's) and the tenant charge come before any piece, so a
+        shed or over-quota block fails typed with nothing allocated, rolled
+        or copied; a block that fails later — the shuffle sealed or removed
+        under it, a copy that raised — leaves the pieces it had placed as
+        holes that no entry names, gives its charge back and loses the
+        partition.  Piece by piece it is the three steps of every buffered
+        close: the only writer open keeps the lock through all of them; with
+        more open each piece is copied outside the lock under its round's
+        in-flight count — so a piece's bytes are in its round before that
+        round is spilled, put early or handed on — and other writers'
+        blocks may land between two pieces (the entry names each piece's
+        round and offset; nothing assumes they are neighbours).
+
+        Span ``store.block_split``, once a block recorded, from its first
+        extent taken to its record (``docs/OBSERVABILITY.md``); the store's
+        counters ``split_blocks`` / ``split_pieces`` / ``split_bytes`` /
+        ``split_rollovers``."""
+        st, store = self._state, self._store
+        lock = store.lock
+        pieces: List[Tuple[int, int, int]] = []
+        rollovers = copy_ns = 0
+        left, cursor = padded, _ChunkCursor(self._chunks)
+        t_lock = perf_counter_ns()
+        with lock:
+            t_first = perf_counter_ns()
+            self._lock_wait_ns += t_first - t_lock
+            unlocked = st.open_writers > 1
+            try:
+                while left:
+                    staging, start, taken, round_idx, rolled, resv = store.take_piece(
+                        st, reduce_id, left, not pieces, unlocked
+                    )
+                    left -= taken
+                    rollovers += rolled
+                    nbytes = taken if left else self._written - sum(n for _, _, n in pieces)
+                    pieces.append((round_idx, start, nbytes))
+                    try:
+                        if unlocked:
+                            lock.release()
+                        t0 = perf_counter_ns()
+                        _copy_chunks(staging, start, cursor.take(nbytes))
+                        t1 = perf_counter_ns()
+                        copy_ns += t1 - t0
+                    finally:
+                        if unlocked:
+                            t_lock = perf_counter_ns()
+                            lock.acquire()
+                            self._lock_wait_ns += perf_counter_ns() - t_lock
+                            self._extra_lock_takes += 1
+                            store.receive_ended(st, resv, 0)
+                            st.settled(resv)
+                if st.removed:  # a removal latches ``removed``, then waits for the copy
+                    raise TransportError(f"unknown shuffle {st.shuffle_id}")
+            except BaseException as e:
+                self._lost = True  # the map's retry writes the partition again
+                if pieces:  # the block's charge, taken with its first piece
+                    store.lose_extent(st, padded, None)
+                if isinstance(e, TransportError) or not isinstance(e, Exception):
+                    raise  # an interrupt stays an interrupt
+                raise TransportError(
+                    f"partition ({self.map_id},{reduce_id}) lost its copy into staging: {e!r}"
+                ) from e
+            passed = store.record_pieces(
+                st, (self.map_id, reduce_id), self._written, padded, pieces, rollovers
+            )
+        if TRACER.active:  # from clock marks, once the block is recorded: a block that failed records none
+            TRACER.record_spans(None, (("store.block_split", t_first, perf_counter_ns(), {
+                "map_id": self.map_id, "reduce_id": reduce_id, "executor": store.executor_id,
+                "pieces": len(pieces), "bytes": self._written, "rollovers": rollovers,
+            }),))
+        self._copy_ns += copy_ns
+        self._extra_copies += len(pieces) - 1
+        if unlocked:
+            self._unlocked_blocks += 1
+            self._unlocked_bytes += self._written
+        return t_first, t_first + copy_ns, passed
 
     # -- receive in place (a partition fed from a socket) -------------------
 
@@ -308,9 +420,10 @@ class MapWriter:
         Under the store's lock, before a byte is read, this is the buffered
         close's first step (``take_extent``: ``check_memory_pressure``
         before the lock; the sealed / device-mode checks, the tenant charge,
-        the rollover when the region cannot take the block); the region-size
-        check comes first, so a body larger than a region fails typed with
-        nothing allocated.
+        the rollover when the region cannot take the block).  A partition
+        that outgrows a whole region goes back to the buffered path before
+        any of that (``inplace_fallbacks``): the buffered close stages it in
+        pieces.
         The first frame of a partition takes its extent at the region's tail;
         a further frame grows it while that tail is still the extent's end
         and the region has room, and otherwise the partition goes back to the
@@ -325,10 +438,16 @@ class MapWriter:
         st, store = self._state, self._store
         total = self._written + nbytes
         if total > st.region_size:
-            raise TransportError(
-                f"single partition ({self.map_id},{self._open_reduce}) exceeds a "
-                f"whole region ({st.region_size} B) — raise stagingCapacity"
-            )
+            # longer than a region: no one extent takes it.  The buffered
+            # close stages it in pieces (``_close_split``); what was received
+            # in place so far goes with it
+            if self._resv is None:
+                self._inplace_fallbacks += 1
+            else:
+                with store.lock:
+                    self._extra_lock_takes += 1
+                    self._unreserve()
+            return None
         padded = -(-total // st.alignment) * st.alignment
         held = self._resv.padded if self._resv is not None else 0
         store.check_memory_pressure("reserve_partition", padded - held)
@@ -496,8 +615,10 @@ class MapWriter:
         if not self._discard:
             if max(nrows) * align > st.region_size:
                 raise TransportError(
-                    f"single partition of map {self.map_id} exceeds a "
-                    f"whole region ({st.region_size} B) — raise stagingCapacity"
+                    f"a partition of map {self.map_id} exceeds a whole region "
+                    f"({st.region_size} B) on the device write, which places a task's blocks "
+                    "in one scatter and stages none in pieces — raise stagingCapacity, or "
+                    "write this map task through the host path"
                 )
             self._store.place_device_blocks(
                 st, self.map_id, packed, zip(reduce_ids, peers, lengths, nrows), total
@@ -518,6 +639,7 @@ class MapWriter:
         t_commit = perf_counter_ns() if self._t_open else 0
         st = self._state
         parts, rounds = [], []
+        splits = None  # partition -> pieces, of the blocks staged in more than one
         blocks = nbytes = 0
         for r in range(st.num_reducers):
             e = st.blocks.get((self.map_id, r))
@@ -529,6 +651,10 @@ class MapWriter:
                 rounds.append(e.round)
                 blocks += 1
                 nbytes += e.length
+                if e.pieces is not None:
+                    if splits is None:
+                        splits = {}
+                    splits[r] = e.pieces
         adds, largest = None, 0
         # once a writer; a retry's table is the first attempt's, counted then
         if not (self._discard or self._counted):
@@ -557,7 +683,7 @@ class MapWriter:
         self._copy_ns = self._lock_wait_ns = 0
         return MapperInfo(
             st.shuffle_id, self.map_id, tuple(parts),
-            tuple(rounds) if any(rounds) else None,
+            tuple(rounds) if any(rounds) else None, splits,
         )
 
     def end_task(self) -> None:

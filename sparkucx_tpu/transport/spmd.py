@@ -31,7 +31,7 @@ import numpy as np
 
 from sparkucx_tpu.config import TpuShuffleConf
 from sparkucx_tpu.core.definitions import MapperInfo
-from sparkucx_tpu.core.operation import ExecutorLostError, TransportError
+from sparkucx_tpu.core.operation import ExecutorLostError, SplitBlockError, TransportError
 from sparkucx_tpu.core.transport import ExecutorId
 from sparkucx_tpu.ops.exchange import bucket_send_rows
 from sparkucx_tpu.ops.planner import PlanContext, PlanSignals, make_planner
@@ -490,6 +490,11 @@ class SpmdShuffleExecutor:
         abs_offset, length = info.partitions[reduce_id]
         if length == 0:
             return b""
+        if info.splits is not None and reduce_id in info.splits:
+            raise SplitBlockError(
+                shuffle_id, map_id, reduce_id, len(info.splits[reduce_id]),
+                "the SPMD executor reads a block out of one round's received shard",
+            )
         rnd = info.round_of(reduce_id)
         sender = self.map_owner(map_id)
         region_bytes = self.store.region_bytes(shuffle_id)

@@ -51,6 +51,7 @@ from sparkucx_tpu.core.definitions import MapperInfo
 from sparkucx_tpu.core.operation import (
     BlockNotFoundError,
     ExecutorLostError,
+    SplitBlockError,
     OperationCallback,
     OperationResult,
     OperationStats,
@@ -1512,7 +1513,9 @@ class TpuShuffleCluster:
     def _restage_dead(self, meta, sealed, dead, alive_set):
         """Rebuild each dead executor's sealed rounds bit-identically from
         replicas: zeros staging (padding rows are zero by construction),
-        replica block bodies at their MapperInfo absolute offsets, per-region
+        replica block bodies at their MapperInfo absolute offsets (a block
+        staged in pieces: each piece in its own round, cut from the replica's
+        whole body), per-region
         used-row counts rebuilt from the padded lengths (allocation was
         contiguous, so the padded sum IS the region's used prefix).  The dead
         executors' entries of ``sealed`` are dropped: their memory died with
@@ -1538,7 +1541,18 @@ class TpuShuffleCluster:
                     if meta.map_owner[map_id] != d:
                         continue
                     for r, (off, ln) in enumerate(info.partitions):
-                        if not ln or info.round_of(r) != rnd:
+                        # (staging offset, offset in the block, bytes) of what
+                        # the block has in this round: all of it, or one of
+                        # the pieces of a block longer than a region
+                        if info.splits is not None and r in info.splits:
+                            extents, at = [], 0
+                            for piece_round, piece_off, piece_bytes in info.splits[r]:
+                                if piece_round == rnd:
+                                    extents.append((piece_off, at, piece_bytes))
+                                at += piece_bytes
+                        else:
+                            extents = [(off, 0, ln)] if ln and info.round_of(r) == rnd else []
+                        if not extents:
                             continue
                         body = None
                         for c in live_cands:
@@ -1555,10 +1569,12 @@ class TpuShuffleCluster:
                                 f"{live_cands}) — shuffle {shuffle_id} is "
                                 "unrecoverable",
                             )
-                        flat[off : off + ln] = body  # a view of the replica: one copy
-                        sizes[off // meta.region_bytes] += -(-ln // self.row_bytes)
-                        blocks += 1
-                        nbytes += ln
+                        for piece_off, at, piece_bytes in extents:
+                            # a view of the replica: one copy
+                            flat[piece_off : piece_off + piece_bytes] = body[at : at + piece_bytes]
+                            sizes[piece_off // meta.region_bytes] += -(-piece_bytes // self.row_bytes)
+                            blocks += not at  # a block once, with its first byte
+                            nbytes += piece_bytes
                 rounds_out.append((payload, sizes.astype(np.int32)))
             restaged[d] = rounds_out
         return restaged, blocks, nbytes
@@ -1734,7 +1750,8 @@ class TpuShuffleCluster:
         )
 
     def resident_blocks(
-        self, consumer: ExecutorId, block_ids: Sequence[ShuffleBlockId]
+        self, consumer: ExecutorId, block_ids: Sequence[ShuffleBlockId],
+        assembled: Optional[List[int]] = None,
     ) -> List[np.ndarray]:
         """``locate_received_block`` for a batch of one shuffle's blocks that
         ``consumer`` received: one look-up of the meta, each (round, sender)
@@ -1744,6 +1761,12 @@ class TpuShuffleCluster:
         ``'device'``).  Nothing is copied and nothing allocated: a view keeps
         its shard alive, by reference count, for as long as it is held — past
         ``remove_shuffle`` too, which drops references and deletes nothing.
+        The one exception is a block staged in pieces (longer than a peer
+        region; ``MapperInfo.splits``): it lies in several rounds' shards, so
+        it is handed out as ONE read-only array put together from its pieces'
+        views in order — one copy of that block, under the span
+        ``read.block_assemble`` — and its length is appended to ``assembled``
+        where the caller passes a list (the reader's counters).
         Raises the typed errors of ``locate_received_block`` at the first
         block that has none."""
         if not block_ids:
@@ -1754,7 +1777,7 @@ class TpuShuffleCluster:
         for bid in block_ids:
             if bid.shuffle_id != meta.shuffle_id:
                 raise TransportError(f"block {bid} not from shuffle {meta.shuffle_id}")
-            view, _ = self._received_block(meta, consumer, bid.map_id, bid.reduce_id, starts)
+            view, _ = self._received_block(meta, consumer, bid.map_id, bid.reduce_id, starts, assembled)
             view.flags.writeable = False
             views.append(view)
         return views
@@ -1772,13 +1795,16 @@ class TpuShuffleCluster:
         map_id: int,
         reduce_id: int,
         starts: Optional[Dict[Tuple[int, int], int]] = None,
+        assembled: Optional[List[int]] = None,
     ) -> Tuple[np.ndarray, int]:
         """(view, length) of one block of an exchanged shuffle; ``starts`` as
-        in ``_locate_rows``."""
-        rnd, src_row, rows = self._locate_rows(meta, consumer, map_id, reduce_id, starts)
+        in ``_locate_rows``, ``assembled`` as in ``resident_blocks``."""
+        rnd, src_row, rows, pieces = self._locate_rows(meta, consumer, map_id, reduce_id, starts)
         if rows == 0:
             return np.empty(0, dtype=np.uint8), 0
         length = meta.mapper_infos[map_id].partitions[reduce_id][1]
+        if pieces is not None:
+            return self._assembled_block(meta, consumer, map_id, reduce_id, pieces, length, assembled), length
         if meta.recv_shards is None:
             # host_recv_mode='device': no host copy exists — slice the block's
             # rows out of the HBM-resident shard and D2H just those bytes.
@@ -1801,6 +1827,43 @@ class TpuShuffleCluster:
             )
         return shard[start : start + length], length
 
+    def _assembled_block(
+        self, meta: _ShuffleMeta, consumer: ExecutorId, map_id: int, reduce_id: int,
+        pieces: Sequence[Tuple[int, int, int]], length: int, assembled: Optional[List[int]],
+    ) -> np.ndarray:
+        """A block staged in pieces, whole: ONE array filled from its pieces'
+        views of ``consumer``'s received shards in order (``pieces`` as
+        ``_locate_pieces`` gives them) — the one copy of that block.  The
+        same two arms and the same guards a piece as ``_received_block`` has
+        a block (which keeps them inline: it runs once a block of every job).
+        Span ``read.block_assemble``."""
+        row = self.row_bytes
+        with span(
+            "read.block_assemble", shuffle_id=meta.shuffle_id, map_id=map_id,
+            reduce_id=reduce_id, executor=consumer, pieces=len(pieces), bytes=length,
+        ):
+            whole = np.empty(length, dtype=np.uint8)
+            at = 0
+            for rnd, src_row, nbytes in pieces:
+                shards = meta.recv_device if meta.recv_shards is None else meta.recv_shards
+                shard = shards[rnd][consumer]
+                if shard is None:  # went with its executor
+                    raise self._received_shards_lost(meta, consumer, reduce_id)
+                if meta.recv_shards is None:  # host_recv_mode='device': D2H just the piece's rows
+                    piece = np.asarray(shard[src_row : src_row + -(-nbytes // row)]).reshape(-1).view(np.uint8)
+                else:
+                    piece = shard[src_row * row :]
+                if piece.size < nbytes:  # past the received prefix: never a short block
+                    raise TransportError(
+                        f"block ({meta.shuffle_id},{map_id},{reduce_id}): its piece at row {src_row} of "
+                        f"round {rnd} ({nbytes} B) lies past what executor {consumer} received"
+                    )
+                whole[at : at + nbytes] = piece[:nbytes]
+                at += nbytes
+        if assembled is not None:
+            assembled.append(length)
+        return whole
+
     def _received_shards_lost(self, meta: _ShuffleMeta, consumer: ExecutorId, reduce_id: int) -> ExecutorLostError:
         return ExecutorLostError(
             consumer, self.membership.epoch,
@@ -1815,12 +1878,17 @@ class TpuShuffleCluster:
         map_id: int,
         reduce_id: int,
         starts: Optional[Dict[Tuple[int, int], int]] = None,
-    ) -> Tuple[int, int, int]:
+    ) -> Tuple[int, int, int, Optional[List[Tuple[int, int, int]]]]:
         """Row-granular location of a block inside ``consumer``'s received shard:
-        (round, src_row, row_count).  Same offset math as
+        (round, src_row, row_count, pieces).  Same offset math as
         ``locate_received_block`` in rows of ``row_bytes``.  A batch passes
         one ``starts`` dict for all its blocks: the (round, sender) chunk
-        starts it has summed so far."""
+        starts it has summed so far.  ``pieces`` is None for the block of one
+        extent that every block but one longer than a peer region is; for
+        such a block (``MapperInfo.splits``) it lists every piece in order,
+        ``(round, src_row, bytes)`` — the first three figures are then its
+        first piece's round and row and the rows of the WHOLE block — and a
+        caller that cannot put pieces together refuses the block by name."""
         if meta.owner_of_reduce(reduce_id) != consumer:
             raise TransportError(
                 f"reducer {reduce_id} is owned by executor "
@@ -1833,7 +1901,7 @@ class TpuShuffleCluster:
             raise TransportError(f"map {map_id} never committed")
         abs_offset, length = info.partitions[reduce_id]
         if length == 0:
-            return 0, 0, 0
+            return 0, 0, 0, None
         rnd = info.round_of(reduce_id)
         sender = meta.map_owner[map_id]
         region_bytes = meta.region_bytes
@@ -1849,7 +1917,38 @@ class TpuShuffleCluster:
             chunk_start = int(meta.recv_sizes[rnd][consumer, :sender].sum())
             if starts is not None:
                 starts[rnd, sender] = chunk_start
-        return rnd, chunk_start + region_rel // row, -(-length // row)
+        if info.splits is not None and reduce_id in info.splits:
+            return rnd, chunk_start + region_rel // row, -(-length // row), self._locate_pieces(
+                meta, consumer, sender, info.splits[reduce_id], length, (map_id, reduce_id)
+            )
+        return rnd, chunk_start + region_rel // row, -(-length // row), None
+
+    def _locate_pieces(
+        self, meta: _ShuffleMeta, consumer: ExecutorId, sender: ExecutorId,
+        pieces: Sequence[Tuple[int, int, int]], length: int, key: Tuple[int, int],
+    ) -> List[Tuple[int, int, int]]:
+        """``(round, src_row, bytes)`` of every piece of a split block, in
+        order, inside ``consumer``'s received shards.  A commit record whose
+        pieces do not add up to the block, or lie outside the consumer's
+        region, is refused typed: a block is never handed out short."""
+        region_bytes, row = meta.region_bytes, self.row_bytes
+        base = consumer * region_bytes
+        located = []
+        for rnd, offset, nbytes in pieces:
+            if not (0 < nbytes and base <= offset and offset + nbytes <= base + region_bytes
+                    and 0 <= rnd < len(meta.recv_sizes)):
+                raise TransportError(
+                    f"block ({meta.shuffle_id},{key[0]},{key[1]}): piece (round {rnd}, offset "
+                    f"{offset}, {nbytes} B) not in consumer {consumer}'s region"
+                )
+            chunk_start = int(meta.recv_sizes[rnd][consumer, :sender].sum())
+            located.append((rnd, chunk_start + (offset - base) // row, nbytes))
+        if sum(nbytes for _, _, nbytes in located) != length:
+            raise TransportError(
+                f"block ({meta.shuffle_id},{key[0]},{key[1]}): its {len(located)} pieces "
+                f"hold {sum(nbytes for _, _, nbytes in located)} B of {length}"
+            )
+        return located
 
     def _gather_fn(self, impl: Optional[str], num_blocks: int, out_rows: int, exact: bool = False):
         """Cache compiled gathers; shapes are bucketed to powers of two (blocks
@@ -1942,7 +2041,13 @@ class TpuShuffleCluster:
         for bid in block_ids:
             if bid.shuffle_id != shuffle_id:
                 raise TransportError(f"block {bid} not from shuffle {shuffle_id}")
-            located.append(self._locate_rows(meta, consumer, bid.map_id, bid.reduce_id))
+            rnd, src_row, rows, pieces = self._locate_rows(meta, consumer, bid.map_id, bid.reduce_id)
+            if pieces is not None:
+                raise SplitBlockError(
+                    shuffle_id, bid.map_id, bid.reduce_id, len(pieces),
+                    "the device fetch gathers a block out of one round's shard — read it on the host",
+                )
+            located.append((rnd, src_row, rows))
 
         entries = np.zeros((len(located), 2), dtype=np.int64)
         plans = []
@@ -2339,15 +2444,18 @@ class TpuShuffleTransport(ShuffleTransport):
             requests.append(req)
         return requests
 
-    def resident_blocks(self, block_ids: Sequence[ShuffleBlockId]) -> List[np.ndarray]:
+    def resident_blocks(
+        self, block_ids: Sequence[ShuffleBlockId], assembled: Optional[List[int]] = None
+    ) -> List[np.ndarray]:
         """The blocks this executor received, where they lie: a read-only
         uint8 view a block, in order, of its received shard
         (``TpuShuffleCluster.resident_blocks``) — no buffer, no copy, no
-        ``Request``.  What ``TpuShuffleReader`` takes in place of
+        ``Request`` (a block staged in pieces: one array put together from
+        them, its length appended to ``assembled``).  What ``TpuShuffleReader`` takes in place of
         ``fetch_blocks_by_block_ids`` for a window addressed to its own
         executor; raises ``TransportError`` where that fetch would have
         completed a block with ``FAILURE``."""
-        return self.cluster.resident_blocks(self.executor_id, block_ids)
+        return self.cluster.resident_blocks(self.executor_id, block_ids, assembled)
 
     def fetch_blocks_device(
         self,

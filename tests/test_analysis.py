@@ -1568,7 +1568,7 @@ class TestErrorTaxonomy:
         findings = run_source(
             src(
                 """
-                _FF = (TenantQuotaExceededError, UnknownTenantError, ExecutorLostError)
+                _FF = (TenantQuotaExceededError, UnknownTenantError, ExecutorLostError, SplitBlockError)
 
                 def _retry_fetch(self):
                     try:

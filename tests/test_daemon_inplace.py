@@ -336,7 +336,8 @@ def test_discarded_empty_and_oversized_bodies(make_daemon, plane, rng):
     """(e) A frame to a ``discard`` writer (a retry after a commit) is read
     and dropped; a zero-length body is a block of no bytes, and a frame of no
     bytes before a partition's data changes nothing; a body larger than a
-    region is the typed error with nothing allocated and the connection kept."""
+    region goes back to the buffered path with nothing reserved, is staged
+    in pieces there and fetched whole, the connection kept."""
     daemon = make_daemon(plane, staging_capacity_per_executor=1 << 20)
     store = store_of(daemon)
     first, second = (rng.integers(0, 256, size=n, dtype=np.uint8).tobytes() for n in (4099, 2111))
@@ -354,16 +355,19 @@ def test_discarded_empty_and_oversized_bodies(make_daemon, plane, rng):
         stats = store.write_stats()
         assert stats["staged_blocks"] == 3 and stats["inplace_blocks"] == 3
         assert stats["inplace_bytes"] == len(first) + len(second) and stats["inplace_fallbacks"] == 0
-        used = int(store._state(0).region_used.sum())
         w1 = client.open_map_writer(0, 1)
-        with pytest.raises(RuntimeError, match="exceeds a whole region"):
-            client.write_partition(w1, 0, bytes((1 << 20) + 1))
-        assert int(store._state(0).region_used.sum()) == used and not store._state(0).inflight
+        oversized = rng.integers(0, 256, size=(1 << 20) + 1, dtype=np.uint8).tobytes()
+        client.write_partition(w1, 0, oversized)
+        assert not store._state(0).inflight
         client.write_partition(w1, 1, second)  # the same connection, the same writer
         client.commit_map(w1)
+        stats = store.write_stats()
+        assert (stats["inplace_blocks"], stats["inplace_fallbacks"]) == (4, 1)
+        assert (stats["split_blocks"], stats["split_pieces"], stats["split_bytes"]) == (1, 2, len(oversized))
         client.run_exchange(0)
-        bids = [ShuffleBlockId(0, 0, 0), ShuffleBlockId(0, 0, 1), ShuffleBlockId(0, 0, 2), ShuffleBlockId(0, 1, 1)]
-        assert client.fetch_blocks(bids) == [first, b"", second, second]
+        bids = [ShuffleBlockId(0, 0, 0), ShuffleBlockId(0, 0, 1), ShuffleBlockId(0, 0, 2), ShuffleBlockId(0, 1, 0),
+                ShuffleBlockId(0, 1, 1)]
+        assert client.fetch_blocks(bids) == [first, b"", second, oversized, second]
         client.remove_shuffle(0)
 
 
@@ -535,9 +539,10 @@ def test_a_lost_body_loses_the_partition_and_gives_everything_back(store):
 def test_reserve_refuses_what_close_partition_refuses(store):
     w = store.map_writer(0, 0)
     w.open_partition(0)
-    with pytest.raises(TransportError, match="exceeds a whole region"):
-        w.reserve((1 << 16) + 1)
-    assert not store._state(0).host_staging_allocated  # nothing allocated
+    # a body over a region is no refusal any more: it goes to the buffered
+    # path, which stages it in pieces, and nothing is reserved for it
+    assert w.reserve((1 << 16) + 1) is None
+    assert not store._state(0).host_staging_allocated and not store._state(0).inflight  # nothing allocated
     store.seal(0)
     with pytest.raises(TransportError, match="already sealed"):
         w.reserve(10)

@@ -4,8 +4,9 @@ four executors at 8 MiB staging — unequal blocks, peer regions that roll with
 free tails, received shards of any length — is byte-exact against the plain
 GroupBy in every host receive mode; what skew does to staging, the exchange
 and the read is counted, once a round, a job or a window, and the counts are
-what the blocks' sizes say; and a block that outgrows a whole peer region is
-refused typed, with nothing allocated.
+what the blocks' sizes say; a block that outgrows a whole peer region is
+staged in pieces and read back exact (``tests/test_block_over_region.py`` has
+the paths); and a job with a failed map task ends non-zero, soon.
 
 Sizes and counts on the CPU mesh; no rate."""
 
@@ -21,7 +22,6 @@ import pytest
 
 from benchmark.cells import ROOT, load_benchmark, load_module
 from sparkucx_tpu.config import TpuShuffleConf
-from sparkucx_tpu.core.operation import TransportError
 from sparkucx_tpu.shuffle.manager import TpuShuffleManager
 from sparkucx_tpu.utils.trace import TRACER
 
@@ -171,38 +171,39 @@ def test_the_counters_fire_once_a_round_a_job_or_a_window(records, groupbytest, 
 STEEP = {**CONFIG, "zipf_s": 3.0}
 
 
-def test_a_block_over_a_whole_region_is_refused_typed_with_nothing_allocated():
+def test_a_block_over_a_whole_region_is_staged_in_pieces_and_read_back_exact(groupbytest):
     made = zipf.make_records(STEEP, seed=7)
     m, (hot, payload) = max(((m, max(parts, key=lambda p: len(p[1]))) for m, parts in enumerate(made.blocks)),
                             key=lambda found: len(found[1][1]))
     assert len(payload) > REGION
+    over = [len(p) for parts in made.blocks for _, p in parts if len(p) > REGION]
     conf = TpuShuffleConf(staging_capacity_per_executor=STAGING)
     with TpuShuffleManager(conf, num_executors=N) as mgr:
-        mgr.register_shuffle(0, made.num_mappers, made.reducers)
+        groupbytest.write_and_exchange(mgr, 0, made)  # no limit at ``write``: the close stages the block, a region a round
         store = mgr.cluster.transport(mgr.cluster.meta(0).map_owner[m]).store
-        writer = mgr.get_writer(0, m)
-        stream = writer.get_partition_writer(hot).open_stream()
-        with pytest.raises(TransportError, match=r"exceeds a whole region \(2097152 B\).*raise stagingCapacity"):
-            stream.write(payload)
-        assert not store.host_staging_allocated(0)
-        stats = store.write_stats()
-        assert (stats["staged_blocks"], stats["rollovers"], stats["largest_block_bytes"]) == (0, 0, 0)
-        # the job cannot go on without that block: the exchange refuses, typed
-        with pytest.raises(TransportError, match="before all maps committed"):
-            mgr.run_exchange(0)
+        entry = store._state(0).blocks[(m, hot)]
+        assert len(entry.pieces) >= -(-len(payload) // REGION) and sum(n for _, _, n in entry.pieces) == len(payload)
+        stats = [t.store.write_stats() for t in mgr.cluster.transports]
+        assert sum(s["split_blocks"] for s in stats) == len(over) and sum(s["split_bytes"] for s in stats) == sum(over)
+        assert store.write_stats()["largest_block_bytes"] == len(payload)
+        read_and_check(mgr, 0, made)
         mgr.unregister_shuffle(0)
 
 
-def test_rehearsal_of_a_block_over_a_region_exits_nonzero_and_does_not_hang(tmp_path):
-    """``run.py`` on a configuration whose hottest block outgrows a region: the
-    map task fails typed, the exchange refuses a job with an uncommitted map,
-    and the process ends non-zero within seconds — no result line."""
+def test_rehearsal_of_a_job_with_a_failed_map_task_exits_nonzero_and_does_not_hang(tmp_path):
+    """``run.py`` on a job one of whose map tasks cannot be written — a region
+    of shared-memory staging overflows, and shm staging has no next round
+    (a block over a region was the refusal here until it was staged in
+    pieces): the map task fails typed, the exchange refuses a job with an
+    uncommitted map, and the process ends non-zero within seconds — no
+    result line."""
     shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(ROOT, "benchmark", "configs", "groupbytest-25k-zipf-4chip.json")) as f:
         config = json.load(f)
     config.update(zipf_s=3.0, rehearse={"mappers": 4, "pairs_per_mapper": 300,
-                                        "conf": {"staging_capacity_per_executor": STAGING}})
+                                        "conf": {"staging_capacity_per_executor": STAGING, "use_shm_staging": True,
+                                                 "shm_namespace": f"sparkucx_tpu_test_{os.getpid()}"}})
     (tmp_path / "benchmark" / "configs" / "steep.json").write_text(json.dumps(config))
     bench = load_benchmark()
     bench["configs"].append({"name": "steep", "source": config["source"], "file": "benchmark/configs/steep.json",
@@ -221,6 +222,6 @@ def test_rehearsal_of_a_block_over_a_region_exits_nonzero_and_does_not_hang(tmp_
     )
     assert out.returncode not in (0, 4), out.stdout[-2000:]
     assert time.monotonic() - t0 < 60
-    assert "exceeds a whole region" in out.stdout and "raise stagingCapacity" in out.stdout
+    assert "region overflow with shm staging" in out.stdout and "raise stagingCapacity" in out.stdout
     assert "before all maps committed" in out.stderr
     assert not out.stdout.strip().splitlines()[-1].startswith("{"), "no result line"

@@ -69,13 +69,23 @@ class TestWriteReadback:
         with pytest.raises(TransportError, match="still open"):
             w.open_partition(1)
 
-    def test_partition_exceeding_region_rejected(self):
+    def test_partition_exceeding_region_is_staged_in_pieces(self):
+        # A block longer than a region takes the room its region has, rolls
+        # the round, takes the next: one entry that names the pieces in order.
         s = HbmBlockStore(TpuShuffleConf(staging_capacity_per_executor=4096, block_alignment=ALIGN))
         s.create_shuffle(0, 1, 2, peer_ranges=default_peer_ranges(2, 2))
+        region = s._state(0).region_size
+        payload = bytes(range(256)) * 16 + b"tail"
         w = s.map_writer(0, 0)
         w.open_partition(0)
-        with pytest.raises(TransportError, match="exceeds a whole region"):
-            w.write(b"x" * 4096)
+        w.write(payload[:1000])
+        w.write(payload[1000:])
+        w.close_partition()
+        info = w.commit()
+        assert info.partitions[0] == (0, len(payload)) and info.round_of(0) == 0
+        assert info.splits == {0: ((0, 0, region), (1, 0, region), (2, 0, len(payload) - 2 * region))}
+        assert s.num_rounds(0) == 3 and s.read_block(0, 0, 0) == payload
+        assert s._state(0).blocks[(0, 0)].padded == -(-len(payload) // ALIGN) * ALIGN
 
     def test_region_overflow_rolls_over(self):
         # Overflow across partitions spills into a new staging round instead of
