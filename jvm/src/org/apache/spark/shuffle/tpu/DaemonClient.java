@@ -31,6 +31,12 @@ public final class DaemonClient implements AutoCloseable {
    * frame claims more; fixture 10_oversized_frame.bin pins both sides). */
   public static final long MAX_FRAME_BYTES = 1L << 31;
 
+  /** Bytes of one map writer's blocks that go out as ONE WritePartition frame
+   * (writePartitions); the value of WRITE_BATCH_BYTES in
+   * sparkucx_tpu/shuffle/daemon.py, fixed there by a probe on the chip's host.
+   * The daemon knows no bound: a frame may carry more or less. */
+  public static final long WRITE_BATCH_BYTES = 64L << 20;
+
   /** True when a frame header's declared sizes exceed the shared ceiling —
    * the reject condition both the daemon and this client apply before
    * allocating anything.  Written without the naive sum so two huge positive
@@ -81,6 +87,37 @@ public final class DaemonClient implements AutoCloseable {
 
   static String headerWritePartition(int writer, int reduceId) {
     return String.format("{\"writer\": %d, \"reduce_id\": %d}", writer, reduceId);
+  }
+
+  /** The several-block form of WritePartition: blocks of ONE writer, reduce
+   * ids non-decreasing (consecutive equal ids continue one partition), the
+   * body the blocks back to back in this order. */
+  static String headerWritePartitions(int writer, int[] reduceIds, int[] lengths) {
+    return String.format("{\"writer\": %d, \"reduce_ids\": %s, \"lengths\": %s}",
+        writer, jsonInts(reduceIds), jsonInts(lengths));
+  }
+
+  /** json.dumps of a list of ints: "[1, 2, 3]". */
+  static String jsonInts(int[] values) {
+    StringBuilder sb = new StringBuilder("[");
+    for (int i = 0; i < values.length; i++) {
+      if (i > 0) sb.append(", ");
+      sb.append(values[i]);
+    }
+    return sb.append("]").toString();
+  }
+
+  /** The blocks back to back: the body of a several-block WritePartition. */
+  static byte[] joinBlocks(byte[][] blocks) {
+    int total = 0;
+    for (byte[] b : blocks) total += b.length;
+    byte[] body = new byte[total];
+    int pos = 0;
+    for (byte[] b : blocks) {
+      System.arraycopy(b, 0, body, pos, b.length);
+      pos += b.length;
+    }
+    return body;
   }
 
   static String headerCommitMap(int writer) {
@@ -162,6 +199,26 @@ public final class DaemonClient implements AutoCloseable {
     byte[] chunk = new byte[len];
     System.arraycopy(data, off, chunk, 0, len);
     controlCall(OP_WRITE_PARTITION, headerWritePartition(writer, reduceId), chunk);
+  }
+
+  /**
+   * Several blocks of one map writer in ONE frame and one round trip (the
+   * daemon still acknowledges every block: the ack's "written" list, checked
+   * here against the lengths sent).  A block the daemon refuses ends the frame
+   * there: the IOException carries its ack — the error, the refused
+   * "reduce_id" and the blocks "written" before it — and the map task fails
+   * before its commit, to be retried whole.
+   */
+  public void writePartitions(int writer, int[] reduceIds, byte[][] blocks) throws IOException {
+    int[] lengths = new int[blocks.length];
+    for (int i = 0; i < blocks.length; i++) lengths[i] = blocks[i].length;
+    byte[][] reply = controlCall(OP_WRITE_PARTITION,
+        headerWritePartitions(writer, reduceIds, lengths), joinBlocks(blocks));
+    String ack = new String(reply[0], StandardCharsets.UTF_8);
+    String want = "\"written\": " + jsonInts(lengths);
+    if (!ack.contains(want)) {
+      throw new IOException("daemon acked other blocks than were sent: " + ack + " for " + want);
+    }
   }
 
   public long[] commitMap(int writer) throws IOException {

@@ -30,11 +30,21 @@ public final class FixtureCheck {
   // some of the cells are empty in the behavioral replay (tests/test_daemon.py)
   static final int[] COALESCE_MAPS = {0, 0, 1, 1, 2, 2, 3, 3};
   static final int[] COALESCE_REDUCES = {5, 6, 5, 6, 5, 6, 5, 6};
+  // 11: several blocks of one writer in one WritePartition frame — reduce 5
+  // in two entries (one partition continued), an empty block, reduce 6
+  static final int[] BATCH_REDUCES = {1, 5, 5, 6};
 
   static byte[] writeBody() {
     byte[] b = new byte[256];
     for (int i = 0; i < 256; i++) b[i] = (byte) i;
     return b;
+  }
+
+  static byte[][] batchBodies() {
+    byte[] first = new byte[16], last = new byte[32];
+    for (int i = 0; i < 16; i++) first[i] = (byte) i;
+    for (int i = 0; i < 32; i++) last[i] = (byte) (255 - i);
+    return new byte[][] {first, writeBody(), new byte[0], last};
   }
 
   static Map<String, byte[]> frames() {
@@ -63,6 +73,13 @@ public final class FixtureCheck {
     m.put("09_fetch_coalesced_empty.bin", DaemonClient.encodeFrame(
         DaemonClient.OP_FETCH, null,
         DaemonClient.fetchRequestBody(FETCH_TAG, SHUFFLE_ID, COALESCE_MAPS, COALESCE_REDUCES)));
+    byte[][] batch = batchBodies();
+    int[] batchLengths = new int[batch.length];
+    for (int i = 0; i < batch.length; i++) batchLengths[i] = batch[i].length;
+    m.put("11_write_partitions.bin", DaemonClient.encodeFrame(
+        DaemonClient.OP_WRITE_PARTITION,
+        DaemonClient.headerWritePartitions(WRITER, BATCH_REDUCES, batchLengths),
+        DaemonClient.joinBlocks(batch)));
     return m;
   }
 

@@ -2,7 +2,9 @@ package org.apache.spark.shuffle.tpu;
 
 import java.io.ByteArrayOutputStream;
 import java.io.IOException;
+import java.util.ArrayList;
 import java.util.Iterator;
+import java.util.List;
 
 import org.apache.spark.scheduler.MapStatus;
 import org.apache.spark.scheduler.MapStatus$;
@@ -19,10 +21,13 @@ import scala.collection.JavaConverters;
 
 /**
  * Map-side writer: partitions records with the dependency's partitioner,
- * serializes each bucket with the dependency's serializer, and streams buckets
+ * serializes each bucket with the dependency's serializer, and ships buckets
  * to the daemon in increasing partition order (the staged store enforces the
  * same sequential protocol the reference writer does,
- * NvkvShuffleMapOutputWriter.scala:108).
+ * NvkvShuffleMapOutputWriter.scala:108) — a batch a frame: the buckets ride
+ * together in WritePartition frames of DaemonClient.WRITE_BATCH_BYTES
+ * (DaemonClient.writePartitions), as the Python twin's write_partition sends
+ * them, and the commit follows the last batch's ack.
  */
 public class TpuShuffleWriter<K, V> extends ShuffleWriter<K, V> {
   private final DaemonClient daemon;
@@ -68,14 +73,35 @@ public class TpuShuffleWriter<K, V> extends ShuffleWriter<K, V> {
     }
 
     int writer = daemon.openMapWriter(handle.shuffleId(), mapIndex);
+    List<Integer> ids = new ArrayList<>();
+    List<byte[]> blocks = new ArrayList<>();
+    long pending = 0;
     for (int p = 0; p < numPartitions; p++) {
       if (buckets[p] == null) continue;
       streams[p].close();
       byte[] data = buckets[p].toByteArray();
-      daemon.writePartition(writer, p, data, 0, data.length);
+      buckets[p] = null;  // the bucket's copy is the frame's from here on
+      ids.add(p);
+      blocks.add(data);
+      pending += data.length;
       metrics.incBytesWritten(data.length);
+      if (pending >= DaemonClient.WRITE_BATCH_BYTES) {
+        ship(writer, ids, blocks);
+        pending = 0;
+      }
     }
+    ship(writer, ids, blocks);
     partitionLengths = daemon.commitMap(writer);
+  }
+
+  /** One WritePartition frame of the pending buckets; empties the lists. */
+  private void ship(int writer, List<Integer> ids, List<byte[]> blocks) throws IOException {
+    if (ids.isEmpty()) return;
+    int[] reduceIds = new int[ids.size()];
+    for (int i = 0; i < reduceIds.length; i++) reduceIds[i] = ids.get(i);
+    daemon.writePartitions(writer, reduceIds, blocks.toArray(new byte[0][]));
+    ids.clear();
+    blocks.clear();
   }
 
   @Override

@@ -8,7 +8,8 @@ docs/SHIM_PROTOCOL.md).  Three parties assert against these bytes:
 * ``jvm/src/.../FixtureCheck.java`` re-encodes every frame with the Java
   builders and compares (run by CI after javac);
 * ``tests/test_daemon.py`` regenerates them here (drift guard) and feeds the
-  raw bytes to a live daemon (decode interop);
+  raw bytes to a live daemon (decode interop; 11, the several-block
+  WritePartition, in a replay of its own);
 * a human diffing a protocol change sees exactly which bytes moved.
 
 Java's String.format JSON headers and Python's ``json.dumps`` agree
@@ -63,6 +64,16 @@ OVERSIZED_HEADER = struct.pack(
 )
 
 
+#: 11: SEVERAL BLOCKS of one writer in one WritePartition frame (the second
+#: header form): reduce 5 in two entries (one partition continued, as repeated
+#: frames continue it), an empty block, then reduce 6 — what
+#: TpuShuffleWriter.java ships its buckets in and what the Python
+#: ``DaemonClient.write_partition`` sends when its batch is full or the map
+#: commits.  Replayed against a live daemon in tests/test_daemon.py.
+BATCH_REDUCE_IDS = (1, 5, 5, 6)
+BATCH_BODIES = (bytes(range(16)), WRITE_BODY, b"", bytes(range(255, 223, -1)))
+
+
 def fetch_frame(maps=FETCH_MAPS, reduces=FETCH_REDUCES) -> bytes:
     body = struct.pack("<QI", FETCH_TAG, len(maps))
     for m, r in zip(maps, reduces):
@@ -89,6 +100,11 @@ def fixtures() -> dict:
         "08_fetch_aqe_maprange.bin": fetch_frame(AQE_MAPS, AQE_REDUCES),
         "09_fetch_coalesced_empty.bin": fetch_frame(COALESCE_MAPS, COALESCE_REDUCES),
         "10_oversized_frame.bin": OVERSIZED_HEADER,
+        "11_write_partitions.bin": _frame(
+            DaemonOp.WRITE_PARTITION,
+            {"writer": WRITER, "reduce_ids": list(BATCH_REDUCE_IDS), "lengths": [len(b) for b in BATCH_BODIES]},
+            b"".join(BATCH_BODIES),
+        ),
     }
 
 

@@ -105,6 +105,7 @@ client.commit_map(w)
 m2 = map_ids[1]
 w2 = client.open_map_writer({sid}, m2)
 client.write_partition(w2, 0, b"GARBAGE-HALF-WRITTEN" * 50)
+client.flush()  # a held block would die with this process unsent: the garbage has to reach staging
 print("crashing mapper: committed", m, "dying inside", m2, flush=True)
 os.kill(os.getpid(), signal.SIGKILL)
 """

@@ -18,6 +18,7 @@ ShuffleManager SPI rather than the wire, so the reference has no AM ids for it):
 CreateShuffle       16  header: json {shuffle_id, num_mappers, num_reducers}
 OpenMapWriter       17  header: json {shuffle_id, map_id} -> writer handle
 WritePartition      18  header: json {writer, reduce_id}; body: bytes (repeat ok)
+                        or json {writer, reduce_ids, lengths}; body: the blocks
 CommitMap           19  header: json {writer} -> partition lengths
 RunExchange         20  header: json {shuffle_id} (optional: see the stage boundary)
 FetchBlock           3  AM FetchBlockReq (batched form, peer.py framing)
@@ -62,6 +63,30 @@ before its body is read (unknown writer, sealed shuffle, a body larger than a
 region) is acked with the error after the body is dropped unread-into-memory:
 the connection stays in step.  The bytes on the wire are what they were.
 
+A batch a frame (PR 59): a ``WritePartition`` header may instead name several
+blocks of its one writer, ``{"writer", "reduce_ids": [...], "lengths":
+[...]}``, the body the blocks back to back in that order; consecutive equal
+reduce ids continue one partition, as repeated frames do.  The daemon parses
+the header once and checks the frame whole before a byte of body is read
+(writer known, reduce ids non-decreasing, as many lengths as ids, none
+negative, their sum the body's length: a frame that fails is dropped unread
+and refused whole), then does block by block what a one-block frame does —
+the partition's stream, ``reserve``, the socket received straight into the
+extent, ``end_receive`` — and sends **one ack that still names every block**:
+``{"ok": true, "written": [n, ...]}``.  A block refused at admission ends the
+frame there: the rest of the body is dropped unread, the ack carries
+``error``, the refused ``reduce_id`` and the blocks ``written`` before it
+(they stay recorded), and the connection stays in step.  A body that stalls
+or a sender that dies is what it is for a one-block frame, for the block in
+flight.  ``DaemonClient.write_partition`` fills such frames: nothing reads a
+block before its map commits, so a ``bytes`` block waits on the connection —
+by reference, never copied — until ``WRITE_BATCH_BYTES`` are pending, a block
+of another writer arrives or any other op is made on the connection
+(``commit_map`` first of all), and the pending blocks then go out as one
+vectored ``sendmsg``.  What a map task is acknowledged is its commit, and
+``commit_map`` returns only after the daemon has acked every block of the map
+by its byte count and then the commit.
+
 The body of a ``FetchBlock`` reply lands once too: the daemon sends views of
 the received shards with one ``sendmsg`` (``_serve_fetch``), and
 ``DaemonClient.fetch_blocks`` receives the body straight into a landing
@@ -71,7 +96,9 @@ a caller still holds views of is never written again.
 
 Telemetry: every served frame is counted per op (``frames``, ``body_bytes``,
 ``serve_ns`` from the frame header's arrival to the reply sent, ``ack_ns`` the
-reply's send, from its start to the frame's end; always on) — the ``daemon``
+reply's send, from its start to the frame's end, ``blocks`` the blocks a
+``write_partition`` frame recorded — ``blocks / frames`` is the batch depth;
+always on) — the ``daemon``
 family of the cluster's metrics registry.  A span ``daemon.<op>`` over the
 ``serve_ns`` interval is recorded only while full tracing is on
 (``TRACER.enabled``), not under the flight recorder's ``recording``.  The
@@ -87,7 +114,8 @@ clock reads while the frame runs: the site hands its marks to
 take of the ring's lock (``utils/trace.py``), so the children partition their
 parent.  ``daemon.write_partition.meta`` / ``.admit`` / ``.body`` / ``.record``
 / ``.ack`` on one ``write_partition`` frame in ``WRITE_PHASES_EVERY`` of a
-connection; ``daemon.fetch_block.locate`` / ``.send`` on every ``fetch_block``
+connection (a frame's ``admit`` / ``body`` / ``record`` are the sums of its
+blocks' shares, laid end to end); ``daemon.fetch_block.locate`` / ``.send`` on every ``fetch_block``
 frame; and the root ``daemon.client_turn.<op>`` of those same frames: the
 connection's wait for its client, from the end of the frame before on this
 connection to this frame's begin (``docs/OBSERVABILITY.md`` has the table).
@@ -144,8 +172,9 @@ _STAGE_WAIT_S = 600.0
 #: under full tracing, the ``write_partition`` frames of a connection that are
 #: recorded by phase: numbers 1, 10, 19, ... counted from 0 since tracing came
 #: on (the first sampled frame has a frame before it to end its client's
-#: turn).  A neighbour of 8 that does not divide the 200 frames of a map task
-#: at GroupByTest width, so the sampled reduce ids rotate from task to task.
+#: turn).  Odd, so that of a 25k map task's two frames (108 and 92 blocks at
+#: ``WRITE_BATCH_BYTES``) both kinds are sampled in turn; at 1k, where a map
+#: task is one frame, it samples 11 of a job's 100.
 WRITE_PHASES_EVERY = 9
 _WRITE_PHASES = tuple(
     "daemon.write_partition." + phase for phase in ("meta", "admit", "body", "record", "ack")
@@ -232,6 +261,14 @@ def _drop_body(sock: socket.socket, n: int, timeout_ms: int) -> None:
         n -= len(part)
 
 
+#: pending bytes at which ``DaemonClient.write_partition`` sends its batch: one
+#: ``WritePartition`` frame carries the blocks of one map writer up to here (a
+#: block that alone reaches it goes alone).  Fixed by
+#: ``scripts/probe_wire_batches.py`` on the chip's host at 1.6 KB and 625 KB
+#: blocks, one and four connections (its docstring has the table: 1.6 KB
+#: blocks are level from 1 MiB up, 625 KB blocks still gain 22% from 8 MiB to
+#: here); not a conf key: no deployment has a reason to set it
+WRITE_BATCH_BYTES = 64 << 20
 #: a kept landing buffer longer than this many times the reply at hand is let
 #: go (``DaemonClient.fetch_blocks``): "much larger" by what the client sees
 LANDING_SLACK = 4
@@ -328,10 +365,11 @@ class ShuffleDaemon:
         # through _lock — a second connection's OPEN/COMMIT must never race a
         # stream rebinding mid-dispatch (analysis: lock-discipline pass).
         self._writers: Dict[int, object] = {}  #: guarded by self._lock
-        self._streams: Dict[Tuple[int, int], object] = {}  #: guarded by self._lock
+        #: writer handle -> its one open partition stream (the sequential protocol)
+        self._streams: Dict[int, object] = {}  #: guarded by self._lock
         self._next_writer = 0  #: guarded by self._lock
         #: per-op frame counters, always on: op id -> [frames, body_bytes,
-        #: serve_ns, ack_ns]; the ``daemon`` family of the cluster's registry
+        #: serve_ns, ack_ns, blocks]; the ``daemon`` family of the cluster's registry
         self._op_stats: Dict[int, List[int]] = {}  #: guarded by self._lock
         #: shuffle id -> its exchange at the stage boundary, from the frame
         #: that claims it to ``RemoveShuffle``: the per-shuffle guard
@@ -348,7 +386,8 @@ class ShuffleDaemon:
         #: time on either plane, so an entry has one writer, and each dict
         #: operation is atomic
         self._turns: Dict[socket.socket, _ConnTurn] = {}
-        #: ``t_ack``: when the calling thread last began to send a reply
+        #: ``t_ack``: when the calling thread last began to send a reply;
+        #: ``blocks``: how many blocks its ``write_partition`` frame recorded
         self._tls = threading.local()
         self.manager.cluster.metrics.register(
             "daemon", labelled_counter_provider("daemon", "op", self.op_stats)
@@ -408,12 +447,12 @@ class ShuffleDaemon:
         rows: Dict[str, List[int]] = {}
         with self._lock:
             for op, counts in self._op_stats.items():
-                row = rows.setdefault(OP_NAMES.get(op, "unknown"), [0, 0, 0, 0])
+                row = rows.setdefault(OP_NAMES.get(op, "unknown"), [0, 0, 0, 0, 0])
                 for i, value in enumerate(counts):
                     row[i] += value
         return [
-            {"op": name, "frames": f, "body_bytes": b, "serve_ns": s, "ack_ns": a}
-            for name, (f, b, s, a) in sorted(rows.items())
+            {"op": name, "frames": f, "body_bytes": b, "serve_ns": s, "ack_ns": a, "blocks": n}
+            for name, (f, b, s, a, n) in sorted(rows.items())
         ]
 
     def stage_stats(self) -> Dict[str, int]:
@@ -443,8 +482,8 @@ class ShuffleDaemon:
         reply sent (``serve_ns``; span ``daemon.<op>`` under full tracing
         only — one a frame would cost every untraced run and flush the
         flight recorder's tail); waiting for the next frame's header is
-        outside.  This runs once a block: it reads the clock three times and
-        adds four ints, and anything more shows in the job's time.  What full
+        outside.  This runs once a frame: it reads the clock three times and
+        adds five ints, and anything more shows in the job's time.  What full
         tracing adds to the span is in ``_serve_traced``."""
         if not self._running:
             return False
@@ -454,6 +493,8 @@ class ShuffleDaemon:
                 return False
             t0 = perf_counter_ns()
             op, hlen, body_bytes = struct.unpack("<IQQ", hdr)
+            tls = self._tls
+            tls.blocks = 0
             if TRACER.enabled:
                 served = self._serve_traced(conn, op, hlen, body_bytes)
             else:
@@ -463,16 +504,17 @@ class ShuffleDaemon:
             if not served:
                 return False
             t1 = perf_counter_ns()
-            t_ack = getattr(self._tls, "t_ack", 0)
+            t_ack = getattr(tls, "t_ack", 0)
             with self._lock:
                 row = self._op_stats.get(op)
                 if row is None:
-                    row = self._op_stats[op] = [0, 0, 0, 0]
+                    row = self._op_stats[op] = [0, 0, 0, 0, 0]
                 row[0] += 1
                 row[1] += body_bytes
                 row[2] += t1 - t0
                 if t_ack > t0:
                     row[3] += t1 - t_ack
+                row[4] += tls.blocks
             return True
         except (OSError, ValueError):
             # dead socket or an unparseable/oversized frame: drop THIS
@@ -538,66 +580,123 @@ class ShuffleDaemon:
     def _serve_write(
         self, conn: socket.socket, hlen: int, blen: int, marks: Optional[List[int]] = None
     ) -> bool:
-        """A ``WritePartition`` frame: the JSON header is read as any op's,
-        the body is not — the store reserves its extent and the socket is
-        received straight into it (``PartitionWriterStream.reserve``), outside
-        every lock.  An error of admission (unknown writer, sealed shuffle,
-        quota, a body larger than a region) is raised before a byte of the
-        body is read: the body is dropped unread-into-memory, the error
-        acked, the connection kept.  A body that stalls past
-        ``conf.wire_timeout_ms`` or whose sender closes ends this connection
-        only, as a dead socket always did; the stream gives the round's
-        in-flight count back on the way out.  ``marks`` (a sampled frame
-        under full tracing, else None) gets the clock after the JSON header
-        is parsed, after the extent is reserved and after the body is in."""
+        """A ``WritePartition`` frame, of one block (``reduce_id``; the body
+        is the block) or of several of one writer (``reduce_ids`` and
+        ``lengths``; the body is the blocks back to back).  The JSON header
+        is read as any op's and checked whole (``_frame_blocks``) before a
+        byte of the body is read; the body is not read as any op's — block by
+        block the store reserves its extent and the socket is received
+        straight into it (``PartitionWriterStream.reserve``), outside every
+        lock.  An error of admission (unknown writer, sealed shuffle, quota,
+        a body larger than a region) is raised before a byte of that block is
+        read: the rest of the body is dropped unread-into-memory, the error
+        acked — with the refused ``reduce_id`` and the blocks ``written``
+        before it where the frame named several — and the connection kept.
+        A body that stalls past ``conf.wire_timeout_ms`` or whose sender
+        closes ends this connection only, as a dead socket always did; the
+        stream of the block in flight gives the round's in-flight count back
+        on the way out.  One ack a frame: ``written`` is the body's length
+        for the one-block form, the list of every block's for the other.
+
+        ``marks`` (a sampled frame under full tracing, else None) gets three
+        cuts once the frame is served: the clock after the JSON header is
+        parsed, that plus the blocks' admissions (stream found, extent
+        reserved), that plus their receives; what is left before the ack is
+        their records."""
         meta = _read_meta(conn, hlen, blen)
         if meta is None:
             return False
-        if marks is not None:
-            marks.append(perf_counter_ns())
+        timed = marks is not None
+        t_meta = t_last = perf_counter_ns() if timed else 0
+        admit_ns = body_ns = 0
         timeout_ms = self.conf.wire_timeout_ms
+        batch = "reduce_ids" in meta
         try:
-            stream = self._partition_stream(int(meta["writer"]), int(meta["reduce_id"]))
-            view = stream.reserve(blen)
-        except Exception as e:
+            handle, reduce_ids, lengths = self._frame_blocks(meta, blen)
+        except (KeyError, TypeError, ValueError) as e:
             _drop_body(conn, blen, timeout_ms)
             self._ack(conn, False, error=f"{type(e).__name__}: {e}")
             return True
-        if marks is not None:
-            marks.append(perf_counter_ns())
-        try:
-            _recv_body(conn, view, timeout_ms)
-        except BaseException:  # the socket's own failure: the connection's end
-            stream.end_receive(blen, False)
-            raise
-        if marks is not None:
-            marks.append(perf_counter_ns())
-        try:
-            stream.end_receive(blen, True)
-        except Exception as e:  # the buffered path refuses at its ``write``
-            self._ack(conn, False, error=f"{type(e).__name__}: {e}")
+        written: List[int] = []
+        left = blen  # of the body, not yet read
+        refused: Optional[Exception] = None
+        for reduce_id, nbytes in zip(reduce_ids, lengths):
+            try:
+                stream = self._partition_stream(handle, reduce_id)
+                view = stream.reserve(nbytes)
+            except Exception as e:
+                refused = e
+                break
+            if timed:
+                t_admit = perf_counter_ns()
+            try:
+                _recv_body(conn, view, timeout_ms)
+            except BaseException:  # the socket's own failure: the connection's end
+                stream.end_receive(nbytes, False)
+                raise
+            left -= nbytes
+            if timed:
+                t_body = perf_counter_ns()
+            try:
+                stream.end_receive(nbytes, True)
+            except Exception as e:  # the buffered path refuses at its ``write``
+                refused = e
+                break
+            written.append(nbytes)
+            if timed:
+                admit_ns += t_admit - t_last
+                body_ns += t_body - t_admit
+                t_last = perf_counter_ns()
+        self._tls.blocks = len(written)
+        if refused is not None:
+            _drop_body(conn, left, timeout_ms)
+            named = {"reduce_id": reduce_id, "written": written} if batch else {}
+            self._ack(conn, False, error=f"{type(refused).__name__}: {refused}", **named)
             return True
-        self._ack(conn, True, written=blen)
+        if timed:
+            marks += (t_meta, t_meta + admit_ns, t_meta + admit_ns + body_ns)
+        self._ack(conn, True, written=written if batch else blen)
         return True
 
+    def _frame_blocks(self, meta: dict, blen: int) -> Tuple[int, List[int], List[int]]:
+        """``(writer, reduce ids, lengths)`` of a ``WritePartition`` header,
+        either form.  The several-block form is checked whole here, before a
+        byte of its body is read: the writer is open, the reduce ids do not
+        go backwards, every block has a length, none negative, and together
+        they are the body."""
+        handle = int(meta["writer"])
+        if "reduce_ids" not in meta:
+            return handle, [int(meta["reduce_id"])], [blen]
+        reduce_ids = [int(r) for r in meta["reduce_ids"]]
+        lengths = [int(n) for n in meta["lengths"]]
+        with self._lock:
+            if handle not in self._writers:
+                raise KeyError(handle)
+        if len(lengths) != len(reduce_ids):
+            raise ValueError(f"{len(reduce_ids)} reduce ids but {len(lengths)} lengths")
+        if any(b < a for a, b in zip(reduce_ids, reduce_ids[1:])):
+            raise ValueError(f"reduce ids go backwards: {reduce_ids}")
+        if lengths and min(lengths) < 0:
+            raise ValueError(f"a negative length: {min(lengths)}")
+        if sum(lengths) != blen:
+            raise ValueError(f"lengths sum to {sum(lengths)} B, the body is {blen} B")
+        return handle, reduce_ids, lengths
+
     def _partition_stream(self, handle: int, reduce_id: int):
-        """The open stream of ``(writer, reduce_id)``; opening it closes any
-        other open stream of the writer (the sequential protocol)."""
-        key = (handle, reduce_id)
-        stale = []
+        """The open stream of ``(writer, reduce_id)``; opening it closes the
+        writer's open stream of another partition (the sequential protocol).
+        Once a block: two takes of the daemon's lock where a new partition
+        opens, one where the last one goes on."""
         with self._lock:
             writer = self._writers[handle]
-            stream = self._streams.get(key)
-            if stream is None:
-                # pop under the lock, close outside it (close takes the store's lock)
-                for k in [k for k in self._streams if k[0] == handle]:
-                    stale.append(self._streams.pop(k))
-        for s in stale:
-            s.close()
-        if stream is None:
-            stream = writer.get_partition_writer(reduce_id).open_stream()
-            with self._lock:
-                self._streams[key] = stream
+            stream = self._streams.get(handle)
+        if stream is not None:
+            if stream.reduce_id == reduce_id:
+                return stream
+            stream.close()  # outside the lock: it takes the store's
+        stream = writer.get_partition_writer(reduce_id).open_stream()
+        with self._lock:
+            self._streams[handle] = stream
         return stream
 
     def _serve(self, conn: socket.socket) -> None:
@@ -684,13 +783,10 @@ class ShuffleDaemon:
         elif op == DaemonOp.COMMIT_MAP:
             handle = int(meta["writer"])
             with self._lock:
-                stale = [
-                    self._streams.pop(k)
-                    for k in [k for k in self._streams if k[0] == handle]
-                ]
+                stream = self._streams.pop(handle, None)
                 writer = self._writers.pop(handle)
-            for s in stale:
-                s.close()
+            if stream is not None:
+                stream.close()
             lengths = writer.commit_all_partitions()
             self._ack(conn, True, body=np.asarray(lengths, dtype="<i8").tobytes())
         elif op == DaemonOp.RUN_EXCHANGE:
@@ -790,29 +886,81 @@ class DaemonClient:
         self._fetch_stats: Dict[str, int] = dict.fromkeys(
             ("fetch_replies", "landed_reused", "landed_fresh", "view_blocks", "view_bytes"), 0
         )  #: guarded by self._lock
+        #: blocks ``write_partition`` holds back, all of ``_pending_writer``,
+        #: with their reduce ids and their bytes in all
+        self._pending: List[bytes] = []  #: guarded by self._lock
+        self._pending_ids: List[int] = []  #: guarded by self._lock
+        self._pending_bytes = 0  #: guarded by self._lock
+        self._pending_writer = -1  #: guarded by self._lock
+        #: writers a frame of whose blocks was refused, lost or acked short:
+        #: ``commit_map`` refuses them (empty while every flush is acked whole)
+        self._unacked: set = set()  #: guarded by self._lock
+        #: always on (``write_stats()``)
+        self._write_stats: Dict[str, int] = dict.fromkeys(
+            ("write_frames", "write_blocks", "flushes_full", "flushes_forced", "sent_at_once"), 0
+        )  #: guarded by self._lock
 
     def _call(self, op: int, header: dict, body: bytes = b"") -> Tuple[dict, bytes]:
-        # the frame's bytes in the frame's order, the body never joined to
-        # its header: one vectored send, and the rest of a short one in a loop
-        payload = json.dumps(header).encode()
-        prefix = struct.pack("<IQQ", op, len(payload), len(body)) + payload
         with self._lock:
-            sock = self._sock
-            sent = sock.sendmsg((prefix, body))
-            if sent < len(prefix) + len(body):
-                rest = (
-                    [memoryview(prefix)[sent:], body]
-                    if sent < len(prefix)
-                    else [memoryview(body)[sent - len(prefix) :]]
-                )
-                BlockServer._sendmsg_all(sock, rest)
-            frame = _read_frame(sock)
+            self._flush("flushes_forced")  # the daemon sees this connection's ops in the caller's order
+            return self._exchange(op, header, (body,))
+
+    def _exchange(self, op: int, header: dict, bodies) -> Tuple[dict, bytes]:
+        """One frame out, its ack in (caller holds the lock).  The frame's
+        bytes in the frame's order, no body joined to its header or to
+        another: one vectored send (1,024 buffers a call), and the rest of a
+        short one in a loop."""
+        payload = json.dumps(header).encode()
+        prefix = struct.pack("<IQQ", op, len(payload), sum(map(len, bodies))) + payload
+        sock = self._sock
+        BlockServer._sendmsg_all(sock, [prefix, *bodies])
+        frame = _read_frame(sock)
         if frame is None:
             raise ConnectionError("daemon closed connection")
         _, meta, ack_body = frame
         if not meta.get("ok"):
-            raise RuntimeError(meta.get("error", "daemon error"))
+            error = meta.get("error", "daemon error")
+            if "reduce_id" in meta:  # a batch refused at one of its blocks
+                error += (
+                    f" (block of reduce partition {meta['reduce_id']}, after "
+                    f"{len(meta.get('written', ()))} blocks of the frame were recorded)"
+                )
+            raise RuntimeError(error)
         return meta, ack_body
+
+    def _flush(self, why: str) -> None:
+        """Send the pending blocks as one ``WritePartition`` frame and check
+        its ack block by block (caller holds the lock).  The list is empty
+        again whatever the outcome: a map whose frame was refused or lost is
+        retried whole, never resumed."""
+        blocks = self._pending
+        if not blocks:
+            return
+        writer, reduce_ids = self._pending_writer, self._pending_ids
+        self._pending, self._pending_ids, self._pending_bytes = [], [], 0
+        self._write_stats[why] += 1
+        lengths = [len(b) for b in blocks]
+        self._write_frame(writer, {"writer": writer, "reduce_ids": reduce_ids, "lengths": lengths}, blocks, lengths)
+
+    def _write_frame(self, writer: int, header: dict, blocks, want) -> None:
+        """One ``WritePartition`` frame out and its ack checked against
+        ``want``, the byte count (or counts) sent (caller holds the lock).
+        The writer is ``_unacked`` from the send until the ack says so."""
+        stats = self._write_stats
+        stats["write_frames"] += 1
+        stats["write_blocks"] += len(blocks)
+        self._unacked.add(writer)
+        meta, _ = self._exchange(DaemonOp.WRITE_PARTITION, header, blocks)
+        if meta.get("written") != want:
+            raise RuntimeError(
+                f"daemon {self._peer} acked other blocks than were sent: {meta.get('written')} for {want}"
+            )
+        self._unacked.discard(writer)
+
+    def flush(self) -> None:
+        """Send what ``write_partition`` holds back, now."""
+        with self._lock:
+            self._flush("flushes_forced")
 
     def create_shuffle(self, shuffle_id: int, num_mappers: int, num_reducers: int) -> None:
         self._call(DaemonOp.CREATE_SHUFFLE, {
@@ -824,10 +972,62 @@ class DaemonClient:
         return int(meta["writer"])
 
     def write_partition(self, writer: int, reduce_id: int, data: bytes) -> None:
-        self._call(DaemonOp.WRITE_PARTITION, {"writer": writer, "reduce_id": reduce_id}, data)
+        """One block (or a further piece of the partition last written) of a
+        map writer.  ``bytes`` are not sent yet: the block waits on this
+        connection, by reference, and goes out with its writer's other
+        pending blocks as ONE ``WritePartition`` frame when
+        ``WRITE_BATCH_BYTES`` are pending, when a block of another writer
+        arrives, and before any other op on this connection — ``commit_map``
+        first of all, which therefore returns only after the daemon has acked
+        every block of the map by its byte count and then the commit;
+        ``flush()`` does it on demand.  So the daemon's refusal of a block
+        (quota, a sealed shuffle, memory pressure) is raised, as the same
+        ``RuntimeError``, by the call that flushed it — this one, a later
+        ``write_partition`` or the map's ``commit_map`` — and a map whose
+        flush raised is retried whole.  A block nothing flushed before
+        ``close()`` is never sent: its map did not commit.  Commit a map on
+        the connection that wrote it (or ``flush()`` that one first).
+
+        Data that is not ``bytes`` (a ``bytearray``, a ``memoryview``: the
+        caller may change it) is sent at once in a frame of its own, after
+        what was pending.
+
+        Counters (``write_stats()``): ``write_frames`` / ``write_blocks``
+        sent; ``flushes_full`` (the byte bound) / ``flushes_forced``
+        (another writer, another op, ``flush()``); ``sent_at_once``."""
+        if type(data) is not bytes:
+            with self._lock:
+                self._flush("flushes_forced")
+                self._write_stats["sent_at_once"] += 1
+                self._write_frame(writer, {"writer": writer, "reduce_id": reduce_id}, (data,), len(data))
+            return
+        with self._lock:
+            if writer != self._pending_writer:
+                self._flush("flushes_forced")
+                self._pending_writer = writer
+            self._pending.append(data)
+            self._pending_ids.append(reduce_id)
+            self._pending_bytes += len(data)
+            if self._pending_bytes >= WRITE_BATCH_BYTES:
+                self._flush("flushes_full")
+
+    def write_stats(self) -> Dict[str, int]:
+        """The write leg's counters (``write_partition`` names them)."""
+        with self._lock:
+            return dict(self._write_stats)
 
     def commit_map(self, writer: int) -> np.ndarray:
-        _, body = self._call(DaemonOp.COMMIT_MAP, {"writer": writer})
+        """Commit the map: its pending blocks first, each acked by its byte
+        count, then the commit.  A writer that lost a block on the way (a
+        frame refused, lost or acked short, here or at an earlier flush) is
+        not committed: the map task is retried whole, under a new writer."""
+        with self._lock:
+            self._flush("flushes_forced")
+            if writer in self._unacked:
+                raise RuntimeError(
+                    f"map writer {writer} was not acked every block it sent: not committed; retry the map task"
+                )
+            _, body = self._exchange(DaemonOp.COMMIT_MAP, {"writer": writer}, (b"",))
         return np.frombuffer(body, dtype="<i8")
 
     def run_exchange(self, shuffle_id: int) -> None:
@@ -861,6 +1061,7 @@ class DaemonClient:
         ``view_blocks`` / ``view_bytes``, the views handed out and their bytes."""
         req = pack_frame(AmId.FETCH_BLOCK_REQ, b"", pack_batch_fetch_req(0, block_ids))
         with self._lock:
+            self._flush("flushes_forced")
             sock, peer = self._sock, self._peer
             sock.sendall(req)
             hdr = recv_exact(sock, FRAME_HEADER_SIZE, idle_ok=True, peer=peer)
@@ -915,7 +1116,10 @@ class DaemonClient:
         """The ``daemonclient`` family of ``registry``.  A client lives in the
         engine's process, which has no registry of its own: one that has adds
         the counters here, beside its other always-on ones."""
-        registry.register("daemonclient", counter_dict_provider("daemonclient", self.fetch_stats))
+        registry.register(
+            "daemonclient",
+            counter_dict_provider("daemonclient", lambda: {**self.fetch_stats(), **self.write_stats()}),
+        )
 
     def remove_shuffle(self, shuffle_id: int) -> None:
         self._call(DaemonOp.REMOVE_SHUFFLE, {"shuffle_id": shuffle_id})
@@ -942,6 +1146,8 @@ class DaemonClient:
             pass
 
     def close(self) -> None:
+        """Close the connection.  Blocks ``write_partition`` still holds back
+        are let go unsent: no commit covered them."""
         try:
             self._sock.close()
         except OSError:

@@ -264,8 +264,10 @@ def test_a_partition_of_several_frames(make_daemon, plane, rng):
         a.create_shuffle(0, 2, 2)
         wa, wb = a.open_map_writer(0, 0), b.open_map_writer(0, 1)
         a.write_partition(wa, 0, a0[:1000])
+        a.flush()
         b.write_partition(wb, 0, b0)  # lands at the tail a's extent ended at
-        a.write_partition(wa, 0, a0[1000:2000])
+        b.flush()
+        a.write_partition(wa, 0, a0[1000:2000])  # this and the next three: one frame at the commit
         a.write_partition(wa, 0, a0[2000:])
         a.write_partition(wa, 1, a1[:4000])
         a.write_partition(wa, 1, a1[4000:])
@@ -389,10 +391,13 @@ class ShortSends:
 
 
 @PLANES
+@pytest.mark.parametrize("held", [True, False], ids=["batched", "at-once"])
 @pytest.mark.parametrize("at_most", [1, 7, 4096])
-def test_the_client_sends_the_same_bytes_over_short_sends(make_daemon, plane, at_most, rng):
-    """(f) ``DaemonClient._call`` loops on a short ``sendmsg``: the bytes on
-    the wire are the joined frame's, and the daemon serves them."""
+def test_the_client_sends_the_same_bytes_over_short_sends(make_daemon, plane, at_most, held, rng):
+    """(f) ``DaemonClient`` loops on a short ``sendmsg``: the bytes on the
+    wire are the joined frame's, and the daemon serves them — the frame of
+    several blocks a ``bytes`` block waits for, and the one-block frame that
+    data the caller may change goes out in at once."""
     daemon = make_daemon(plane, staging_capacity_per_executor=1 << 20)
     payload = rng.integers(0, 256, size=9001 if at_most > 1 else 301, dtype=np.uint8).tobytes()
     with closing(DaemonClient(daemon.address)) as client:
@@ -400,12 +405,13 @@ def test_the_client_sends_the_same_bytes_over_short_sends(make_daemon, plane, at
         w = client.open_map_writer(0, 0)
         real = client._sock
         client._sock = stub = ShortSends(real, at_most)
-        client.write_partition(w, 0, payload)
+        client.write_partition(w, 0, payload if held else memoryview(payload))
+        assert bool(stub.sent) is not held
         lengths = client.commit_map(w)
         client._sock = real
+        header = {"writer": w, "reduce_ids": [0], "lengths": [len(payload)]} if held else {"writer": w, "reduce_id": 0}
         assert bytes(stub.sent) == (
-            _frame(DaemonOp.WRITE_PARTITION, {"writer": w, "reduce_id": 0}, payload)
-            + _frame(DaemonOp.COMMIT_MAP, {"writer": w})
+            _frame(DaemonOp.WRITE_PARTITION, header, payload) + _frame(DaemonOp.COMMIT_MAP, {"writer": w})
         )
         assert lengths.tolist() == [len(payload)]
         client.run_exchange(0)
@@ -418,7 +424,7 @@ def test_the_wire_format_of_a_call_is_the_joined_frame():
     a, b = socket.socketpair()
     with closing(a), closing(b):
         client = DaemonClient.__new__(DaemonClient)
-        client._sock, client._lock = a, threading.Lock()
+        client._sock, client._lock, client._pending = a, threading.Lock(), []
         body = b"\x01\x02\x03" * 1000
         b.sendall(_frame(DaemonOp.ACK, {"ok": True}))
         client._call(DaemonOp.WRITE_PARTITION, {"writer": 3, "reduce_id": 5}, body)

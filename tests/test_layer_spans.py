@@ -295,8 +295,11 @@ def test_concurrent_writers_lose_no_count(tier):
 FRAMES = 24  # blocks a daemon job writes: 4 mappers x 6 reducers
 
 
-def daemon_job(client, shuffle_id, block_bytes=700, fetch=False):
-    """One job over the socket; returns the bytes written."""
+def daemon_job(client, shuffle_id, block_bytes=700, fetch=False, frame_a_block=True):
+    """One job over the socket; returns the bytes written.  ``frame_a_block``
+    flushes the client after every block, so that the job is ``FRAMES``
+    ``write_partition`` frames; without it a map task's six blocks ride in
+    one frame, sent at its commit."""
     mappers, reducers = 4, FRAMES // 4
     client.create_shuffle(shuffle_id, mappers, reducers)
     written = 0
@@ -305,6 +308,8 @@ def daemon_job(client, shuffle_id, block_bytes=700, fetch=False):
         for r in range(reducers):
             data = bytes([(m * reducers + r) % 251]) * block_bytes
             client.write_partition(writer, r, data)
+            if frame_a_block:
+                client.flush()
             written += len(data)
         client.commit_map(writer)
     if fetch:  # the reduce side as the JVM shim runs it: fetches only, one a reducer
@@ -324,6 +329,11 @@ def daemon():
     client = DaemonClient(served.address)
     yield served, client
     client.close()
+    # the serving thread closes a frame's span some time after the client has
+    # its reply: let it end, or that span lands in the next test's ring
+    deadline = time.monotonic() + 10
+    while served.stage_stats()["connections"] and time.monotonic() < deadline:
+        time.sleep(0.001)
     served.close()
 
 
@@ -542,6 +552,30 @@ def test_write_phases_partition_a_sampled_frame_and_skip_the_rest(daemon, tracer
                 and not n.startswith(("daemon.write_partition.", "daemon.client_turn."))]
 
 
+def test_the_phases_of_a_frame_of_several_blocks_partition_it_too(daemon, tracer):
+    """A map task's six blocks in one frame: four ``write_partition`` frames
+    a job, the sampled one still cut in five — ``admit`` / ``body`` /
+    ``record`` the sums of its blocks' shares — and the always-on row counts
+    the blocks beside the frames."""
+    served, client = daemon
+    tracer.enable()
+    written = daemon_job(client, 0, frame_a_block=False)
+    tracer.disable()
+    writes = [f for f in frames_of(tracer) if f["name"] == "daemon.write_partition"]
+    assert len(writes) == 4  # one a map task, sent at its commit
+    for i, frame in enumerate(writes):
+        children = [c for c in children_of(tracer, frame) if c["name"] != "store.round_buffer.fresh"]
+        if i % WRITE_PHASES_EVERY == 1:
+            assert_partition(frame, children, WRITE_PHASES)
+            # six blocks were admitted, received and recorded inside: none of the three is empty
+            assert all(c["dur"] > 0 for c in children[1:4])
+        else:
+            assert not children
+    assert len(spans(tracer, "daemon.client_turn.write_partition")) == 1
+    row = {r["op"]: r for r in served.op_stats()}["write_partition"]
+    assert (row["frames"], row["blocks"], row["body_bytes"]) == (4, FRAMES, written)
+
+
 def test_fetch_phases_partition_every_frame(daemon, tracer):
     served, client = daemon
     tracer.enable()
@@ -596,6 +630,7 @@ def test_client_turns_and_write_samples_are_kept_per_connection(plane, tracer):
     for r in range(reducers):  # frame about: 22 frames, 11 a connection
         for client, writer in zip((a, b), writers):
             client.write_partition(writer, r, bytes([r]) * 300)
+            client.flush()
     for client, writer in zip((a, b), writers):
         client.commit_map(writer)
     block = [ShuffleBlockId(0, 0, 0)]
