@@ -9,7 +9,8 @@ docs/SHIM_PROTOCOL.md).  Three parties assert against these bytes:
   builders and compares (run by CI after javac);
 * ``tests/test_daemon.py`` regenerates them here (drift guard) and feeds the
   raw bytes to a live daemon (decode interop; 11, the several-block
-  WritePartition, in a replay of its own);
+  WritePartition, in a replay of its own; 12, the landing's offer, in
+  ``tests/test_daemon_mapped_landing.py``);
 * a human diffing a protocol change sees exactly which bytes moved.
 
 Java's String.format JSON headers and Python's ``json.dumps`` agree
@@ -74,6 +75,17 @@ BATCH_REDUCE_IDS = (1, 5, 5, 6)
 BATCH_BODIES = (bytes(range(16)), WRITE_BODY, b"", bytes(range(255, 223, -1)))
 
 
+#: 12: a same-host client's OFFER of a landing for its fetch replies (PR 60):
+#: the name of a file under /dev/shm the client made and mapped, and its
+#: capacity.  Only the Python ``DaemonClient`` sends it; the Java client does
+#: not map shared memory and stays on the socket (jvm/README.md), so
+#: FixtureCheck.java has no builder for it — the fixture pins the frame for
+#: the day it does.  Replayed raw in tests/test_daemon_mapped_landing.py
+#: (nobody made that name: the daemon refuses it and keeps the connection).
+LANDING_NAME = "sparkucx-landing-00112233445566778899aabbccddeeff"
+LANDING_CAPACITY = 1 << 20
+
+
 def fetch_frame(maps=FETCH_MAPS, reduces=FETCH_REDUCES) -> bytes:
     body = struct.pack("<QI", FETCH_TAG, len(maps))
     for m, r in zip(maps, reduces):
@@ -104,6 +116,9 @@ def fixtures() -> dict:
             DaemonOp.WRITE_PARTITION,
             {"writer": WRITER, "reduce_ids": list(BATCH_REDUCE_IDS), "lengths": [len(b) for b in BATCH_BODIES]},
             b"".join(BATCH_BODIES),
+        ),
+        "12_offer_landing.bin": _frame(
+            DaemonOp.OFFER_LANDING, {"name": LANDING_NAME, "capacity": LANDING_CAPACITY}
         ),
     }
 

@@ -25,6 +25,7 @@ FetchBlock           3  AM FetchBlockReq (batched form, peer.py framing)
 RemoveShuffle       21  header: json {shuffle_id}
 Stats               22  header: json {shuffle_id}
 Shutdown            23  —
+OfferLanding        27  header: json {name, capacity} (a same-host client's landing)
 ==================  ==  =======================================================
 
 Every control op gets an ``Ack`` (id 24) with ``{ok, error?, ...result}``.
@@ -94,6 +95,29 @@ buffer the connection keeps and hands each block out as a read-only view of
 where it landed — no frame-sized buffer a reply, no copy a block; a buffer
 a caller still holds views of is never written again.
 
+A same-host client's landing (PR 60): the daemon holds the host's chips, so
+its clients are on its host, and the body of a reply need not cross the
+loopback socket — one copy into the socket and one out of it, through one TCP
+stream.  A ``DaemonClient`` that connected to a loopback address makes a
+mapping after its first reply has told it the size (``/dev/shm``, an
+unguessable name, mode 0600, created exclusively), offers ``(name,
+capacity)`` in ``OfferLanding`` and unlinks the name on the ack; the daemon
+opens it (no link followed, its own user's file, long enough, no larger than
+a frame: ``attach_landing``) and keeps it for the connection.  Every fetch
+request then says in its ``tag`` whether the landing is free
+(``TAG_LANDING_FREE``: no view of it lives — ``fetch_blocks``' own rule for
+its ``bytearray``); where it is and the reply fits, ``_serve_fetch`` copies
+the located blocks into the mapping back to back and sends the reply's two
+headers alone, marked ``TAG_BODY_MAPPED``, with a body length of 0.  Not
+free, too large, nothing offered: the reply it always was, byte for byte.
+The header on the socket is the hand-over — the daemon writes the mapping
+only between such a request and its reply, the client reads it only after
+the header — and no mapping is unmapped under a live view: both sides hold
+an ``mmap`` through Python's buffer protocol, which unmaps with the last
+holder.  A refused offer leaves the socket for the connection's life.
+``docs/SHIM_PROTOCOL.md`` has the frames; a peer that never offers (the JVM
+shim, a remote client) sees none of it.
+
 Telemetry: every served frame is counted per op (``frames``, ``body_bytes``,
 ``serve_ns`` from the frame header's arrival to the reply sent, ``ack_ns`` the
 reply's send, from its start to the frame's end, ``blocks`` the blocks a
@@ -124,8 +148,13 @@ Untraced, a frame pays one dict's truth test and a ``None`` check a phase.
 
 from __future__ import annotations
 
+import ipaddress
 import json
+import mmap
+import os
+import secrets
 import socket
+import stat
 import sys
 import threading
 from contextlib import nullcontext
@@ -197,6 +226,8 @@ class DaemonOp:
     # obs plane (PR 14): control-plane pulls of the daemon-side telemetry
     EXPORT_TRACE = 25
     METRICS = 26
+    # a same-host client's landing for its fetch replies (PR 60)
+    OFFER_LANDING = 27
 
 
 #: op id -> the name it is counted and traced under (``daemon.<name>``)
@@ -211,6 +242,7 @@ OP_NAMES = {
     DaemonOp.SHUTDOWN: "shutdown",
     DaemonOp.EXPORT_TRACE: "export_trace",
     DaemonOp.METRICS: "metrics",
+    DaemonOp.OFFER_LANDING: "offer_landing",
     int(AmId.FETCH_BLOCK_REQ): "fetch_block",
 }
 
@@ -274,6 +306,79 @@ WRITE_BATCH_BYTES = 64 << 20
 LANDING_SLACK = 4
 #: what ``fetch_blocks`` hands out for an empty block of a reply without a body
 _NO_BYTES = memoryview(b"")
+#: bit 0 of a fetch request's ``tag``: the landing this connection offered is
+#: free — no view of it lives, the daemon may write it
+TAG_LANDING_FREE = 1
+#: bit 1 of a fetch reply's ``tag``: the body is in the landing, none follows
+TAG_BODY_MAPPED = 2
+#: a landing is offered at this many times the reply at hand (in whole pages):
+#: inside PR 41's rule — at least the reply, at most ``LANDING_SLACK`` times
+#: it — with room both ways, so the replies of one stage (a reduce task's
+#: blocks differ by a few percent at 25k, by a third at 1k) find it fits
+LANDING_HEADROOM = 2
+#: where a landing's name lives until both processes hold its pages: POSIX
+#: shared memory's directory (``shm_open``'s)
+_SHM_DIR = "/dev/shm"
+
+
+def attach_landing(name: str, capacity: int) -> np.ndarray:
+    """The daemon's side of ``OfferLanding``: the pages a same-host client
+    made under ``name``, as bytes this process may write.  Refused (raises;
+    the op's ack says why) unless the name is a plain one that opens without
+    following a link, the file is a regular one **of this process's own
+    user** and at least ``capacity`` long, and ``capacity`` is one a frame
+    may have.  Every page is there before the first copy (``posix_fallocate``:
+    no work on a file its creator allocated): a full ``/dev/shm`` is an
+    ``ENOSPC`` here, never a ``SIGBUS`` under a reply.  The mapping lives as
+    long as the array does."""
+    if not name or name.startswith(".") or "/" in name:
+        raise ValueError(f"not a landing's name: {name!r}")
+    if not 0 < capacity <= MAX_FRAME_BYTES:
+        raise ValueError(f"a landing of {capacity} B")
+    fd = os.open(os.path.join(_SHM_DIR, name), os.O_RDWR | os.O_NOFOLLOW | os.O_CLOEXEC)
+    try:
+        st = os.fstat(fd)
+        if not stat.S_ISREG(st.st_mode):
+            raise ValueError(f"landing {name!r} is not a regular file")
+        if st.st_uid != os.geteuid():
+            raise PermissionError(f"landing {name!r} belongs to user {st.st_uid}, not to the daemon's")
+        if st.st_size < capacity:
+            raise ValueError(f"landing {name!r} is {st.st_size} B, offered as {capacity} B")
+        os.posix_fallocate(fd, 0, capacity)
+        return np.frombuffer(mmap.mmap(fd, capacity), dtype=np.uint8)
+    finally:
+        os.close(fd)
+
+
+def make_landing(path: str, capacity: int) -> Optional[mmap.mmap]:
+    """The client's side of ``OfferLanding``: a new file at ``path`` — created
+    exclusively, mode 0600, no link followed — of ``capacity`` bytes, every
+    page allocated now (none is missing under the daemon's copy later), and
+    mapped.  ``None``, and no file left, where this host gives none (no
+    ``/dev/shm``, no room in it).  The caller unlinks ``path``."""
+    try:
+        fd = os.open(path, os.O_CREAT | os.O_EXCL | os.O_RDWR | os.O_NOFOLLOW | os.O_CLOEXEC, 0o600)
+    except OSError:
+        return None
+    try:
+        os.posix_fallocate(fd, 0, capacity)
+        return mmap.mmap(fd, capacity)
+    except (OSError, ValueError):
+        os.unlink(path)
+        return None
+    finally:
+        os.close(fd)
+
+
+def _is_loopback(sock: socket.socket) -> bool:
+    """Did this socket connect to a loopback address?  Then its peer is a
+    process of this host."""
+    if sock.family not in (socket.AF_INET, socket.AF_INET6):
+        return False
+    try:
+        return ipaddress.ip_address(sock.getpeername()[0]).is_loopback
+    except (OSError, ValueError):
+        return False
 
 
 def _recv_landing(sock: socket.socket, view: memoryview, peer: str) -> None:
@@ -369,7 +474,7 @@ class ShuffleDaemon:
         self._streams: Dict[int, object] = {}  #: guarded by self._lock
         self._next_writer = 0  #: guarded by self._lock
         #: per-op frame counters, always on: op id -> [frames, body_bytes,
-        #: serve_ns, ack_ns, blocks]; the ``daemon`` family of the cluster's registry
+        #: serve_ns, ack_ns, blocks, mapped]; the ``daemon`` family of the cluster's registry
         self._op_stats: Dict[int, List[int]] = {}  #: guarded by self._lock
         #: shuffle id -> its exchange at the stage boundary, from the frame
         #: that claims it to ``RemoveShuffle``: the per-shuffle guard
@@ -386,8 +491,15 @@ class ShuffleDaemon:
         #: time on either plane, so an entry has one writer, and each dict
         #: operation is atomic
         self._turns: Dict[socket.socket, _ConnTurn] = {}
+        #: connection -> the landing its client offered (``OfferLanding``), as
+        #: bytes of the mapping both processes hold; dropped — and with it
+        #: this side of the mapping — when the connection closes or offers
+        #: anew.  No lock, for ``_turns``' reason
+        self._landings: Dict[socket.socket, np.ndarray] = {}
         #: ``t_ack``: when the calling thread last began to send a reply;
-        #: ``blocks``: how many blocks its ``write_partition`` frame recorded
+        #: ``blocks``: how many blocks its ``write_partition`` frame recorded;
+        #: ``t_mapped``: when its ``fetch_block`` frame had copied its reply's
+        #: body into the connection's landing (0: the body went over the socket)
         self._tls = threading.local()
         self.manager.cluster.metrics.register(
             "daemon", labelled_counter_provider("daemon", "op", self.op_stats)
@@ -439,6 +551,7 @@ class ShuffleDaemon:
 
     def _connection_closed(self, conn=None) -> None:
         self._turns.pop(conn, None)
+        self._landings.pop(conn, None)
         with self._lock:
             self._stage_stats["connections"] -= 1
 
@@ -447,12 +560,12 @@ class ShuffleDaemon:
         rows: Dict[str, List[int]] = {}
         with self._lock:
             for op, counts in self._op_stats.items():
-                row = rows.setdefault(OP_NAMES.get(op, "unknown"), [0, 0, 0, 0, 0])
+                row = rows.setdefault(OP_NAMES.get(op, "unknown"), [0, 0, 0, 0, 0, 0])
                 for i, value in enumerate(counts):
                     row[i] += value
         return [
-            {"op": name, "frames": f, "body_bytes": b, "serve_ns": s, "ack_ns": a, "blocks": n}
-            for name, (f, b, s, a, n) in sorted(rows.items())
+            {"op": name, "frames": f, "body_bytes": b, "serve_ns": s, "ack_ns": a, "blocks": n, "mapped": m}
+            for name, (f, b, s, a, n, m) in sorted(rows.items())
         ]
 
     def stage_stats(self) -> Dict[str, int]:
@@ -483,7 +596,7 @@ class ShuffleDaemon:
         only — one a frame would cost every untraced run and flush the
         flight recorder's tail); waiting for the next frame's header is
         outside.  This runs once a frame: it reads the clock three times and
-        adds five ints, and anything more shows in the job's time.  What full
+        adds six ints, and anything more shows in the job's time.  What full
         tracing adds to the span is in ``_serve_traced``."""
         if not self._running:
             return False
@@ -494,7 +607,7 @@ class ShuffleDaemon:
             t0 = perf_counter_ns()
             op, hlen, body_bytes = struct.unpack("<IQQ", hdr)
             tls = self._tls
-            tls.blocks = 0
+            tls.blocks = tls.t_mapped = 0
             if TRACER.enabled:
                 served = self._serve_traced(conn, op, hlen, body_bytes)
             else:
@@ -508,13 +621,15 @@ class ShuffleDaemon:
             with self._lock:
                 row = self._op_stats.get(op)
                 if row is None:
-                    row = self._op_stats[op] = [0, 0, 0, 0, 0]
+                    row = self._op_stats[op] = [0, 0, 0, 0, 0, 0]
                 row[0] += 1
                 row[1] += body_bytes
                 row[2] += t1 - t0
                 if t_ack > t0:
                     row[3] += t1 - t_ack
                 row[4] += tls.blocks
+                if tls.t_mapped:
+                    row[5] += 1
             return True
         except (OSError, ValueError):
             # dead socket or an unparseable/oversized frame: drop THIS
@@ -546,14 +661,17 @@ class ShuffleDaemon:
         if not served or t_ack <= t_begin:
             return served
         if op == int(AmId.FETCH_BLOCK_REQ):
-            cuts = (t_begin, t_ack, t_end)
-            names = ("daemon.fetch_block.locate", "daemon.fetch_block.send")
+            send = ("daemon.fetch_block.send", t_ack, t_end)
+            t_mapped = self._tls.t_mapped
+            if t_mapped:  # the body went into the landing: the copy, inside the send
+                send += (None, (("daemon.fetch_block.send.mapped", t_ack, t_mapped),))
+            phases = (("daemon.fetch_block.locate", t_begin, t_ack), send)
         elif marks is not None and len(marks) == 3:  # a frame refused on the way has fewer
             cuts = (t_begin, *marks, t_ack, t_end)
-            names = _WRITE_PHASES
+            phases = zip(_WRITE_PHASES, cuts, cuts[1:])
         else:
             return served
-        TRACER.record_spans(ctx, zip(names, cuts, cuts[1:]))
+        TRACER.record_spans(ctx, phases)
         if waited_from:
             TRACER.record_spans(None, (("daemon.client_turn." + OP_NAMES[op], waited_from, t_begin),))
         return served
@@ -816,6 +934,12 @@ class ShuffleDaemon:
             self._ack(conn, True, events=count)
         elif op == DaemonOp.METRICS:
             self._ack(conn, True, body=mgr.cluster.metrics_text().encode())
+        elif op == DaemonOp.OFFER_LANDING:
+            # a refusal is the end of it for the connection: the landing it
+            # had goes first, and what ``attach_landing`` raises is acked
+            self._landings.pop(conn, None)
+            self._landings[conn] = attach_landing(str(meta["name"]), int(meta["capacity"]))
+            self._ack(conn, True)
         elif op == int(AmId.FETCH_BLOCK_REQ):
             # data-plane fetch: batched AM form (binary batch header travels in
             # the body so the JSON control framing stays uniform)
@@ -832,6 +956,13 @@ class ShuffleDaemon:
         # vectored sendmsg over the views — the wire bytes are identical to
         # the historical [sizes | data...] frame, but no monolithic reply
         # body is ever assembled (and no per-block bytes() copies are paid).
+        # Where the connection offered a landing, the request says it is free
+        # (``TAG_LANDING_FREE``) and the body fits, the views are copied into
+        # it back to back instead (numpy's slice assignment: off the
+        # interpreter lock) and the reply is its two headers, marked
+        # ``TAG_BODY_MAPPED``, with a body length of 0.  The header on the
+        # socket is the hand-over: the landing is written only here, between
+        # such a request and its reply.
         for shuffle_id in {bid.shuffle_id for bid in bids}:
             self._exchange_at_first_fetch(shuffle_id)
         parts, sizes = [], []
@@ -844,16 +975,25 @@ class ShuffleDaemon:
                 )
                 seg = np.ascontiguousarray(view[:length]).reshape(-1).view(np.uint8)
                 if length:
-                    parts.append(memoryview(seg))
+                    parts.append(seg)
                 sizes.append(int(length))
             except Exception:
                 sizes.append(-1)
         blob = b"".join(_SIZE.pack(s) for s in sizes)
-        reply_hdr = _TAG.pack(tag) + _COUNT.pack(len(bids)) + blob
         total = sum(p.nbytes for p in parts)
-        prefix = pack_frame_prefix(AmId.FETCH_BLOCK_REQ_ACK, reply_hdr, total)
+        landing = self._landings.get(conn) if tag & TAG_LANDING_FREE else None
+        mapped = landing is not None and 0 < total <= len(landing)
+        reply_hdr = _TAG.pack(tag | TAG_BODY_MAPPED if mapped else tag) + _COUNT.pack(len(bids)) + blob
+        prefix = pack_frame_prefix(AmId.FETCH_BLOCK_REQ_ACK, reply_hdr, 0 if mapped else total)
         self._tls.t_ack = perf_counter_ns()  # the reply is this op's ack
-        if hasattr(conn, "sendmsg"):
+        if mapped:
+            pos = 0
+            for seg in parts:
+                landing[pos : pos + seg.nbytes] = seg
+                pos += seg.nbytes
+            self._tls.t_mapped = perf_counter_ns()
+            conn.sendall(prefix)
+        elif hasattr(conn, "sendmsg"):
             BlockServer._sendmsg_all(conn, [prefix] + parts)
         else:
             conn.sendall(b"".join([prefix] + [bytes(p) for p in parts]))
@@ -882,9 +1022,18 @@ class DaemonClient:
         #: this connection keeps across calls.  Written again only while no
         #: view of it lives; replaced by the buffer of a reply it could not take
         self._landing: Optional[bytearray] = None  #: guarded by self._lock
+        #: the landing this connection offered and the daemon took: a mapping
+        #: both processes hold, its name gone.  The daemon writes it only
+        #: between a request that says it is free and that request's reply
+        self._mapped: Optional[mmap.mmap] = None  #: guarded by self._lock
+        #: may this connection (still) offer one?  Its daemon is on this host
+        #: (a loopback address; the proof is the daemon's attach) and has
+        #: refused none
+        self._may_offer = _is_loopback(self._sock)  #: guarded by self._lock
         #: always on, bumped once a reply (``fetch_stats()``)
         self._fetch_stats: Dict[str, int] = dict.fromkeys(
-            ("fetch_replies", "landed_reused", "landed_fresh", "view_blocks", "view_bytes"), 0
+            ("fetch_replies", "landed_reused", "landed_fresh", "landed_mapped", "mapped_bytes",
+             "landings_offered", "landings_refused", "view_blocks", "view_bytes"), 0
         )  #: guarded by self._lock
         #: blocks ``write_partition`` holds back, all of ``_pending_writer``,
         #: with their reduce ids and their bytes in all
@@ -1042,8 +1191,28 @@ class DaemonClient:
         it; a value that must outlive it is copied (``bytes(view[a:b])``, as
         ``default_deserializer`` does).
 
-        The reply's body is received once, straight into this connection's
-        landing buffer, and nothing is copied out of it.  The buffer is kept
+        Where the daemon is on this host the body does not cross the socket
+        (PR 60).  After the first reply with a body the client makes a mapping
+        of ``LANDING_HEADROOM`` times that reply (``/dev/shm``, an unguessable
+        name, mode 0600, created exclusively, its pages allocated), offers
+        name and capacity in one ``OfferLanding`` op and unlinks the name on
+        the ack: both processes hold the pages, no name is left.  From then
+        on every request says whether that landing is free — the rule below,
+        applied to the mapping — and a reply that finds it free and fits
+        comes as its two headers alone, marked ``TAG_BODY_MAPPED``: the
+        daemon has copied the blocks into the landing, and the views handed
+        out are of the mapping.  Not free, or too large: the reply comes over
+        the socket as ever.  A reply outside the landing's range (longer than
+        it, or under a ``LANDING_SLACK``-th of a landing of more than a few
+        pages) is followed by a new offer at its size; the old mapping lives
+        as long as its views (Python unmaps an ``mmap`` with its last holder,
+        never under one), past ``close()`` too.  A refused offer (``ok:
+        false``; no ``/dev/shm``) is the end of it for this connection: the
+        socket serves it.  A daemon elsewhere, or a peer that never offers,
+        sees the wire it always saw.
+
+        Over the socket the reply's body is received once, straight into this
+        connection's landing buffer, and nothing is copied out of it.  The buffer is kept
         across calls and written again only when nothing else refers to it (a
         view holds its owner, so ``sys.getrefcount`` sees every holder — the
         rule of ``HbmBlockStore._recycle_rounds``) and it is long enough;
@@ -1058,12 +1227,18 @@ class DaemonClient:
         counts in neither) — ``landed_reused / fetch_replies`` is the share of
         replies that cost no allocation and no first touch of new pages, near
         1 for a caller that lets a task's blocks go before its next fetch;
-        ``view_blocks`` / ``view_bytes``, the views handed out and their bytes."""
-        req = pack_frame(AmId.FETCH_BLOCK_REQ, b"", pack_batch_fetch_req(0, block_ids))
+        ``view_blocks`` / ``view_bytes``, the views handed out and their bytes;
+        ``landed_mapped`` / ``mapped_bytes``, replies whose body was in the
+        mapping and their bytes; ``landings_offered`` / ``landings_refused``."""
         with self._lock:
             self._flush("flushes_forced")
             sock, peer = self._sock, self._peer
-            sock.sendall(req)
+            mapped = self._mapped
+            # (3 = the attribute, ``mapped`` and getrefcount's argument)
+            free = mapped is not None and sys.getrefcount(mapped) == 3
+            sock.sendall(pack_frame(
+                AmId.FETCH_BLOCK_REQ, b"", pack_batch_fetch_req(TAG_LANDING_FREE if free else 0, block_ids)
+            ))
             hdr = recv_exact(sock, FRAME_HEADER_SIZE, idle_ok=True, peer=peer)
             if hdr is None:
                 raise ConnectionError("daemon closed connection")
@@ -1073,12 +1248,23 @@ class DaemonClient:
             header = recv_exact(sock, hlen, peer=peer)
             if header is None:
                 raise ConnectionError("daemon closed connection")
+            (tag,) = _TAG.unpack_from(header)
             (count,) = _COUNT.unpack_from(header, _TAG.size)
             sizes = struct.unpack_from(f"<{count}q", header, _TAG.size + _COUNT.size)
-            if sum(s for s in sizes if s > 0) != blen:
-                raise ValueError(f"fetch reply from peer {peer} names other sizes than its body's {blen} B")
+            total = sum(s for s in sizes if s > 0)
             stats = self._fetch_stats
-            if blen:
+            if tag & TAG_BODY_MAPPED:
+                if blen or not (free and 0 < total <= len(mapped)):
+                    raise ValueError(
+                        f"fetch reply from peer {peer} puts {total} B into a landing this request did not give it"
+                    )
+                view = memoryview(mapped)[:total].toreadonly()
+                self._landing = None  # the socket's buffer is not kept beside a landing that serves
+                stats["landed_mapped"] += 1
+                stats["mapped_bytes"] += total
+            elif total != blen:
+                raise ValueError(f"fetch reply from peer {peer} names other sizes than its body's {blen} B")
+            elif blen:
                 buf = self._landing
                 # (3 = the attribute, ``buf`` and getrefcount's argument)
                 reused = (
@@ -1104,8 +1290,40 @@ class DaemonClient:
                     pos += s
             stats["fetch_replies"] += 1
             stats["view_blocks"] += count - missing
-            stats["view_bytes"] += blen
+            stats["view_bytes"] += total
+            # (a landing is whole pages: one of a page is not "much larger" than any reply)
+            if self._may_offer and total and not (
+                mapped is not None and total <= len(mapped) <= LANDING_SLACK * max(total, mmap.PAGESIZE)
+            ):
+                self._offer_landing(total)
         return out
+
+    def _offer_landing(self, reply_bytes: int) -> None:
+        """Make a landing for replies like the one at hand and offer it
+        (caller holds the lock; ``fetch_blocks`` has the rule).  The name
+        lives from the exclusive create to the daemon's ack — one round
+        trip — and is unlinked whatever the answer.  What this host cannot
+        give (no ``/dev/shm``, no room in it) and what the daemon refuses end
+        the offers of this connection; a connection that dies under the offer
+        raises as under any op."""
+        stats = self._fetch_stats
+        stats["landings_offered"] += 1
+        page = mmap.PAGESIZE
+        capacity = -(-min(LANDING_HEADROOM * reply_bytes, MAX_FRAME_BYTES) // page) * page
+        name = "sparkucx-landing-" + secrets.token_hex(16)
+        path = os.path.join(_SHM_DIR, name)
+        mapping = make_landing(path, capacity)
+        if mapping is not None:
+            try:
+                self._exchange(DaemonOp.OFFER_LANDING, {"name": name, "capacity": capacity}, (b"",))
+            except RuntimeError:  # ``ok: false``: the daemon could not attach, or is an older one and knows no such op
+                mapping = None
+            finally:
+                os.unlink(path)
+        if mapping is None:
+            stats["landings_refused"] += 1
+            self._may_offer = False
+        self._mapped = mapping
 
     def fetch_stats(self) -> Dict[str, int]:
         """The receive's counters (``fetch_blocks`` names them)."""
@@ -1147,7 +1365,8 @@ class DaemonClient:
 
     def close(self) -> None:
         """Close the connection.  Blocks ``write_partition`` still holds back
-        are let go unsent: no commit covered them."""
+        are let go unsent: no commit covered them.  The landing is this
+        object's and its views': it goes with the last of them, not here."""
         try:
             self._sock.close()
         except OSError:

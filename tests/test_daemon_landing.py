@@ -6,7 +6,12 @@ where it landed.  What has to hold: the same bytes, ``None`` and request
 order as ever; a buffer with live views is never written again; the kept
 buffer follows the sizes of the replies; a reply that breaks off leaves
 nothing handed out.  A real ``ShuffleDaemon`` on loopback, but for the
-failure paths, which need a daemon that misbehaves."""
+failure paths, which need a daemon that misbehaves.
+
+This file is the socket's landing: its clients never offer a mapping, as a
+client on another host never does (``_may_offer``), so every reply here takes
+the path that stays the one fallback.  ``test_daemon_mapped_landing.py`` has
+the same-host client's."""
 
 import socket
 import struct
@@ -26,6 +31,8 @@ from sparkucx_tpu.shuffle.reader import default_deserializer
 
 #: every join and wait of this file
 TIMEOUT = 60
+#: the counters of the mapped landing, on a client that never offers
+NOT_MAPPED = dict.fromkeys(("landed_mapped", "mapped_bytes", "landings_offered", "landings_refused"), 0)
 
 
 @pytest.fixture(scope="module")
@@ -38,6 +45,7 @@ def daemon():
 @pytest.fixture
 def client(daemon):
     with closing(DaemonClient(daemon.address)) as c:
+        c._may_offer = False
         yield c
 
 
@@ -84,7 +92,7 @@ def test_a_reply_is_the_written_bytes_in_request_order(client, rng, blocks):
     stats = client.fetch_stats()
     assert stats == {
         "fetch_replies": 1, "landed_reused": 0, "landed_fresh": 1,
-        "view_blocks": blocks, "view_bytes": sum(len(w) for w in written),
+        "view_blocks": blocks, "view_bytes": sum(len(w) for w in written), **NOT_MAPPED,
     }
     client.remove_shuffle(sid)
 
@@ -94,7 +102,7 @@ def test_a_reply_without_a_body_lands_nowhere(client):
     got = client.fetch_blocks([ShuffleBlockId(120, 0, 0), ShuffleBlockId(120, 7, 0), ShuffleBlockId(120, 1, 0)])
     assert got == [b"", None, b""] and got[0].readonly and len(got[2]) == 0
     assert client.fetch_stats() == {
-        "fetch_replies": 1, "landed_reused": 0, "landed_fresh": 0, "view_blocks": 2, "view_bytes": 0,
+        "fetch_replies": 1, "landed_reused": 0, "landed_fresh": 0, "view_blocks": 2, "view_bytes": 0, **NOT_MAPPED,
     }
     client.remove_shuffle(120)
 
@@ -299,7 +307,7 @@ def test_a_reply_that_breaks_off_hands_nothing_out(reply, hang, error, words):
             with pytest.raises(error, match=words):
                 client.fetch_blocks([ShuffleBlockId(0, 0, 0), ShuffleBlockId(0, 1, 0), ShuffleBlockId(0, 2, 0)])
             assert client.fetch_stats() == dict.fromkeys(
-                ("fetch_replies", "landed_reused", "landed_fresh", "view_blocks", "view_bytes"), 0
+                ("fetch_replies", "landed_reused", "landed_fresh", "view_blocks", "view_bytes", *NOT_MAPPED), 0
             )
             if error is ValueError:  # refused before any allocation
                 assert client._landing is None

@@ -623,6 +623,9 @@ def test_client_turns_and_write_samples_are_kept_per_connection(plane, tracer):
     waited (7 ms under six busy workers), and only a frame's begin is ordered
     against another connection's calls."""
     served, (a, b) = plane
+    # the turns below are judged fetch to fetch: no ``offer_landing`` frame
+    # (a same-host client's, after its first reply) between them
+    a._may_offer = b._may_offer = False
     mappers, reducers = 2, 11
     a.create_shuffle(0, mappers, reducers)
     writers = [a.open_map_writer(0, 0), b.open_map_writer(0, 1)]
