@@ -21,6 +21,12 @@ reduce side is the next stage's map side.  Stage-2 reducers join shuffles B
 and C per partition and emit the final q18 rows; the driver compares the
 merged result against a full numpy oracle over the regenerated inputs.
 
+Which path this is: the DAEMON's wire (OS processes, ``DaemonClient``) with
+the aggregate and the join in numpy ON THE HOST, at 200,000 rows — it proves
+the L7 surface, not the operators.  The served path with the stages after each
+exchange run on the chip at SF=10 is the benchmark's cell
+``q18sf10-queryjobs-1chip`` (``QueryRunner``'s batch lane, ``query/batch.py``).
+
 Reference gate analogue: buildlib/test.sh:196's gate composition;
 BASELINE.json configs[2] (TPC-H SF=10 plan shapes).
 Knobs via env: EXECUTORS, MAPPERS, REDUCERS, ROWS (lineitem), ORDERS.

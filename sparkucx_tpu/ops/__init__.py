@@ -42,7 +42,9 @@ from sparkucx_tpu.ops.relational import (
     JoinSpec,
     build_grouped_aggregate,
     build_hash_join,
+    grouped_sum_records,
     hash_owners_host,
+    merge_join_records,
     oracle_aggregate,
     oracle_join,
     plan_join_capacities,
@@ -94,6 +96,8 @@ __all__ = [
     "JoinSpec",
     "build_grouped_aggregate",
     "build_hash_join",
+    "grouped_sum_records",
+    "merge_join_records",
     "hash_owners_host",
     "oracle_aggregate",
     "oracle_join",

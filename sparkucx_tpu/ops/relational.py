@@ -42,14 +42,14 @@ from sparkucx_tpu.ops.columnar import (
     unpack_shard_prefixes,
 )
 from sparkucx_tpu.ops.compress import QuantizeSpec, dequantize_rows, quantize_rows
-from sparkucx_tpu.ops.exchange import exclusive_cumsum, resolve_collective_impl
+from sparkucx_tpu.ops.exchange import exclusive_cumsum, gather_rows, resolve_collective_impl
 
 #: Padding sort key (sorts last) — ops/sort.py's sentinel, same discipline:
 #: valid rows may legitimately carry this key; because received rows are a
 #: tight valid prefix, a *stable* sort keeps valid sentinel-keyed rows ahead of
 #: padding within the tie, and validity masks do the rest (x64 stays off; no
 #: int64 composite keys anywhere).
-from sparkucx_tpu.ops.sort import KEY_MAX  # noqa: E402  (re-export)
+from sparkucx_tpu.ops.sort import KEY_MAX, comparable_lanes, key_lanes_of  # noqa: E402  (KEY_MAX re-exported)
 
 #: Multiplicative hash constant (Knuth); uint32 wraparound is the mixing step.
 _HASH_MULT = np.uint32(2654435761)
@@ -759,6 +759,31 @@ def build_grouped_aggregate(mesh: Mesh, spec: AggregateSpec):
     return fn
 
 
+def expand_counts(out_capacity: int, cnt: jnp.ndarray, method: str = "scan"):
+    """Output place -> the input row that emits it: row ``i`` of ``cnt`` (int32,
+    >= 0) emits ``cnt[i]`` consecutive output rows, in row order.  Returns
+    ``(j, within, ok, total)`` over ``out_capacity`` places: ``j[p]`` the
+    emitting row (clipped into range), ``within[p]`` the place's rank among
+    that row's emissions, ``ok`` the places under the true emission count and
+    ``total`` that count, wrap-guarded: the int32 cumsum wraps at ~2.1e9, so a
+    float32 shadow sum (exact enough for detection) saturates it at int32 max
+    and a caller's ``total > out_capacity`` check cannot pass silently.
+    ``method`` is ``jnp.searchsorted``'s (``"sort"`` where the places
+    outnumber the rows' logarithm many times over)."""
+    offs = exclusive_cumsum(cnt)
+    cum = jnp.cumsum(cnt)
+    total = jnp.where(
+        jnp.sum(cnt.astype(jnp.float32)) > jnp.float32(2**31 - 1),
+        jnp.int32(np.iinfo(np.int32).max),
+        cum[-1].astype(jnp.int32),
+    )
+    pos = jnp.arange(out_capacity, dtype=jnp.int32)
+    j = jnp.clip(
+        jnp.searchsorted(cum, pos, side="right", method=method).astype(jnp.int32), 0, cnt.shape[0] - 1
+    )
+    return j, pos - offs[j], pos < total, total
+
+
 def expand_matches(
     out_capacity: int,
     sbk: jnp.ndarray,
@@ -793,19 +818,8 @@ def expand_matches(
     hi = jnp.minimum(jnp.searchsorted(sbk, probe_keys, side="right").astype(jnp.int32), btotal)
     matched = jnp.where(probe_valid, jnp.maximum(hi - lo, 0), 0)
     cnt = jnp.where(probe_valid, _join_emit(join_type)(matched, jnp), 0)
-    offs = exclusive_cumsum(cnt)
-    cum = jnp.cumsum(cnt)
-    total = jnp.where(
-        jnp.sum(cnt.astype(jnp.float32)) > jnp.float32(2**31 - 1),
-        jnp.int32(np.iinfo(np.int32).max),
-        cum[-1].astype(jnp.int32),
-    )
-    pos = jnp.arange(out_capacity, dtype=jnp.int32)
-    j = jnp.clip(
-        jnp.searchsorted(cum, pos, side="right").astype(jnp.int32), 0, probe_cap - 1
-    )
-    li = jnp.clip(lo[j] + (pos - offs[j]), 0, build_cap - 1)
-    ok = pos < total
+    j, within, ok, total = expand_counts(out_capacity, cnt)
+    li = jnp.clip(lo[j] + within, 0, build_cap - 1)
     # semantically all-False for inner/semi (their emitted rows always have a
     # match) — computed uniformly, the caller's null-substitution masks on it
     unmatched = ok & (matched[j] == 0)
@@ -1571,3 +1585,225 @@ def oracle_join(
         return out + (np.zeros(0, bool),) if outer else out
     out = (np.array(keys, np.uint32), np.stack(brows), np.stack(prows))
     return out + (np.array(matched),) if outer else out
+
+
+# ----------------------------------------------------------------------------
+# Local operators over a reduce partition's key-ordered records
+# ----------------------------------------------------------------------------
+#
+# What ``sort_rows`` is to the distributed sort: the reduce side's GROUP BY and
+# sort-merge JOIN over ONE partition as the ordered device read hands it out
+# (``TpuShuffleReader.read_device()`` under ``key_ordering``: a ``(capacity,
+# lanes)`` int32 array whose first ``count`` rows are the task's fixed-width
+# records in the order of their first ``key_bytes`` bytes, the rows after them
+# zero).  No exchange and no mesh: the served shuffle has already put equal
+# keys into one partition.  Keys are byte strings of ``key_bytes`` bytes
+# compared WHOLE, in the ordered read's own order (``ops.sort.key_order``), so
+# an operator's output is key-ordered too and feeds the next one as it is.
+# Shapes are static, counts are runtime scalars: one executable a shuffle's
+# geometry, nothing compiles after a query's first task.
+
+#: rows an operator's limb sums stay exact at (8-bit limbs in uint32 lanes)
+RECORDS_MAX = 1 << 23
+#: ``info`` of an operator's result, an int32 vector: rows handed out, rows
+#: the operator made (more than handed out = ``out_capacity`` too small), 1
+#: where a sum left 63 bits or a value was negative, the input's groups
+INFO_ROWS, INFO_TOTAL, INFO_OVERFLOW, INFO_GROUPS = range(4)
+HAVING = (None, "gt")
+RECORD_JOINS = ("inner", "left_semi")
+
+
+def _comparable_keys(records: jnp.ndarray, key_bytes: int) -> jnp.ndarray:
+    """The records' key lanes as a ``(k, N)`` ``uint32`` array in
+    ``ops.sort.comparable_lanes``' form: its lexicographic order, lane 0
+    first, is the ordered read's, and two keys are equal iff every lane is."""
+    k = key_lanes_of(key_bytes)
+    if not 1 <= k <= records.shape[1]:
+        raise ValueError(f"a {key_bytes}-byte key in records of {records.shape[1]} lanes")
+    lanes = jax.lax.bitcast_convert_type(records[:, :k].T, jnp.uint32)
+    return jnp.stack(comparable_lanes(list(lanes), key_bytes)[0])
+
+
+def _row_count(count) -> jnp.ndarray:
+    """A records array's count: a scalar, or the ``info`` vector of the
+    operator that made the array (its ``INFO_ROWS``) — taken inside the
+    executable, so that chaining operators dispatches nothing between them."""
+    count = jnp.asarray(count)
+    return (count[INFO_ROWS] if count.ndim else count).astype(jnp.int32)
+
+
+def _lanes_differ(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """Where two ``(k, N)`` key arrays differ in any lane."""
+    return (a != b).any(axis=0)
+
+
+def _lex_before(a: jnp.ndarray, b: jnp.ndarray, or_equal: bool) -> jnp.ndarray:
+    """``a < b`` (``<=`` with ``or_equal``) over ``(k, N)`` comparable key
+    lanes, lane 0 most significant."""
+    out = (a[-1] <= b[-1]) if or_equal else (a[-1] < b[-1])
+    for lane in range(a.shape[0] - 2, -1, -1):
+        out = (a[lane] < b[lane]) | ((a[lane] == b[lane]) & out)
+    return out
+
+
+def _key_ranges(sorted_keys: jnp.ndarray, count: jnp.ndarray, queries: jnp.ndarray):
+    """``[lo, hi)``: the rows of the first ``count`` of ``sorted_keys`` (``(k,
+    N)``, ascending) that equal each of ``queries`` (``(k, M)``).  A binary
+    search over whole keys, both bounds in one loop of ``log2(N)`` steps of
+    ``M`` fetches a lane: cheap where the queries are the few."""
+    n = sorted_keys.shape[1]
+
+    def step(_, state):
+        out = []
+        for (lo, hi), or_equal in zip(state, (False, True)):
+            mid = (lo + hi) >> 1
+            probe = sorted_keys[:, jnp.clip(mid, 0, n - 1)]
+            right = (lo < hi) & _lex_before(probe, queries, or_equal)
+            out.append((jnp.where(right, mid + 1, lo), jnp.where((lo < hi) & ~right, mid, hi)))
+        return tuple(out)
+
+    zeros = jnp.zeros(queries.shape[1], jnp.int32)
+    ends = jnp.broadcast_to(count.astype(jnp.int32), zeros.shape)
+    (lo, _), (hi, _) = jax.lax.fori_loop(0, max(1, n).bit_length(), step, ((zeros, ends), (zeros, ends)))
+    return lo, hi
+
+
+def _compact_method(out_capacity: int, rows: int) -> str:
+    """``jnp.searchsorted``'s method for ``out_capacity`` places over ``rows``
+    counts: a scan of ``log2(rows)`` fetches a place where the places are few,
+    one sort where they are not."""
+    return "scan" if out_capacity * max(1, rows).bit_length() <= rows else "sort"
+
+
+@functools.partial(jax.jit, static_argnames=("key_bytes", "value_lane", "having", "out_capacity"))
+def grouped_sum_records(records, count, threshold, *, key_bytes: int, value_lane: int,
+                        having: Optional[str], out_capacity: int):
+    """GROUP BY key, SUM(value) [HAVING SUM(value) > threshold] over one
+    partition's key-ordered records (the executable ``jit_grouped_sum_records``
+    of a device trace).
+
+    ``records``: ``(capacity, lanes)`` int32, the first ``count`` rows ordered
+    by their first ``key_bytes`` bytes (``count``: a scalar, or the ``info``
+    of the operator whose ``rows`` these are).  The value is the 8 bytes at lanes
+    ``value_lane`` and ``value_lane + 1``: a little-endian non-negative
+    integer under 2**63.  ``threshold``: ``(2,)`` uint32, low word first,
+    read only under ``having="gt"`` (strictly greater).
+
+    Returns ``(rows, info)``: ``rows`` ``(out_capacity, lanes)`` — a row a
+    group that passed, in key order: the group's LAST record with its value
+    replaced by the group's sum; zero after them — and ``info`` (``INFO_*``).
+    Only the groups that passed are compacted.
+
+    The sum is exact: each value is split into eight 8-bit limbs, a limb's
+    running sum over a partition stays under 2**31 (``RECORDS_MAX`` rows), so
+    it never wraps and is non-decreasing — a group's sum is the running sum
+    at its last row less the one before its first, which a running maximum
+    over the group starts carries forward.  No scatter and no gather over the
+    partition: two cumulative passes over ``(8, capacity)``.  The limbs are
+    put together with carries; a sum past 63 bits, or a value with its top
+    bit set, sets ``info[INFO_OVERFLOW]`` — the caller raises, no wrapped sum
+    is handed on."""
+    capacity, lanes = records.shape
+    if capacity > RECORDS_MAX:
+        raise ValueError(f"{capacity} records a partition: the limb sums are exact up to {RECORDS_MAX}")
+    if having not in HAVING:
+        raise ValueError(f"unknown having {having!r} (valid: {HAVING})")
+    if not 0 <= value_lane <= lanes - 2:
+        raise ValueError(f"an 8-byte value at lane {value_lane} of {lanes}")
+    idx = jnp.arange(capacity, dtype=jnp.int32)
+    valid = idx < _row_count(count)
+    keys = _comparable_keys(records, key_bytes)
+    differs = _lanes_differ(keys[:, 1:], keys[:, :-1])
+    is_start = valid & jnp.concatenate([jnp.ones(1, bool), differs])
+    is_end = valid & jnp.concatenate([differs | ~valid[1:], jnp.ones(1, bool)])
+
+    words = jax.lax.bitcast_convert_type(records[:, value_lane : value_lane + 2].T, jnp.uint32)
+    words = jnp.where(valid[None, :], words, jnp.uint32(0))
+    limbs = jnp.stack([(words[w] >> (8 * b)) & jnp.uint32(0xFF) for w in (0, 1) for b in range(4)])
+    running = jnp.cumsum(limbs, axis=1)
+    before_group = jax.lax.cummax(jnp.where(is_start[None, :], running - limbs, jnp.uint32(0)), axis=1)
+    carry = jnp.zeros(capacity, jnp.uint32)
+    digits = []
+    for limb in running - before_group:
+        carry = carry + limb
+        digits.append(carry & jnp.uint32(0xFF))
+        carry = carry >> 8
+    low, high = (sum(d << (8 * b) for b, d in enumerate(digits[w : w + 4])) for w in (0, 4))
+    unfit = (carry != 0) | (high >> 31 != 0)
+    overflow = (is_end & unfit).any() | (words[1] >> 31 != 0).any()
+
+    keep = is_end
+    if having == "gt":
+        t_low, t_high = threshold[0].astype(jnp.uint32), threshold[1].astype(jnp.uint32)
+        keep = keep & ((high > t_high) | ((high == t_high) & (low > t_low)))
+    src, _, ok, total = expand_counts(out_capacity, keep.astype(jnp.int32), _compact_method(out_capacity, capacity))
+    rows = gather_rows(records, src)
+    sums = jax.lax.bitcast_convert_type(jnp.stack([low[src], high[src]], axis=1), records.dtype)
+    rows = jnp.where(ok[:, None], rows.at[:, value_lane : value_lane + 2].set(sums), 0)
+    info = jnp.stack([jnp.minimum(total, out_capacity), total, overflow.astype(jnp.int32),
+                      is_start.sum(dtype=jnp.int32)])
+    return rows, info
+
+
+@functools.partial(jax.jit, static_argnames=("key_bytes", "join_type", "out_capacity"))
+def merge_join_records(probe, probe_count, build, build_count, *, key_bytes: int, join_type: str,
+                       out_capacity: int):
+    """Sort-merge equi-join of one partition's key-ordered records (``probe``,
+    the SQL LEFT side: what the ordered device read handed out) against a
+    key-ordered ``build`` side — another read, or an operator's ``rows`` —
+    on their first ``key_bytes`` bytes, compared whole (the executable
+    ``jit_merge_join_records`` of a device trace).
+
+    Both are ``(capacity, lanes)`` int32 with their first ``*_count`` rows in
+    the one key order (a count is a scalar or the making operator's ``info``).  ``inner``: a row a matching (probe, build) pair — the
+    probe record's lanes, then the build row's lanes after its key — by build
+    row, then probe row: key order.  ``left_semi``: each probe record whose
+    key the build side holds, once, as it is, in key order.
+
+    Returns ``(rows, info)`` as ``grouped_sum_records`` does (``info``'s
+    overflow and groups are 0): ``(out_capacity, lanes)`` rows, zero after
+    the ``info[INFO_ROWS]`` handed out; ``info[INFO_TOTAL]`` above
+    ``out_capacity`` means the join made more rows than it could hand out.
+
+    Each BUILD key is looked up in the probe side (``_key_ranges``): the
+    work is ``build rows x log2(probe rows)`` fetches and ``out_capacity``
+    row gathers, whatever the probe side's size — the reduce side's joins
+    are a few survivors against a partition."""
+    if join_type not in RECORD_JOINS:
+        raise ValueError(f"unknown join_type {join_type!r} over records (valid: {RECORD_JOINS})")
+    probe_keys, build_keys = _comparable_keys(probe, key_bytes), _comparable_keys(build, key_bytes)
+    lo, hi = _key_ranges(probe_keys, _row_count(probe_count), build_keys)
+    emits = jnp.arange(build.shape[0], dtype=jnp.int32) < _row_count(build_count)
+    if join_type == "left_semi":  # a key the build side holds twice still passes a probe record once
+        emits = emits & jnp.concatenate(
+            [jnp.ones(1, bool), _lanes_differ(build_keys[:, 1:], build_keys[:, :-1])]
+        )
+    cnt = jnp.where(emits, hi - lo, 0)
+    j, within, ok, total = expand_counts(out_capacity, cnt, _compact_method(out_capacity, build.shape[0]))
+    rows = gather_rows(probe, jnp.clip(lo[j] + within, 0, probe.shape[0] - 1))
+    if join_type == "inner":
+        rows = jnp.concatenate([rows, gather_rows(build, j)[:, key_lanes_of(key_bytes):]], axis=1)
+    rows = jnp.where(ok[:, None], rows, 0)
+    zero = jnp.zeros((), jnp.int32)
+    return rows, jnp.stack([jnp.minimum(total, out_capacity), total, zero, zero])
+
+
+def oracle_grouped_sum_records(records: np.ndarray, key_bytes: int, value_byte: int,
+                               threshold: Optional[int] = None) -> np.ndarray:
+    """numpy reference of ``grouped_sum_records`` over ``(n, record_bytes)``
+    ``uint8`` records in ANY order: a row a group in key order (keys as byte
+    strings), the group's last record in the input's order with its 8-byte
+    value at ``value_byte`` replaced by the exact sum (Python integers)."""
+    groups: dict = {}
+    for row in records:
+        key = row[:key_bytes].tobytes()
+        total = groups.get(key, (0, None))[0] + int.from_bytes(row[value_byte : value_byte + 8].tobytes(), "little")
+        groups[key] = (total, row)
+    out = []
+    for key in sorted(groups):
+        total, row = groups[key]
+        if threshold is None or total > threshold:
+            row = row.copy()
+            row[value_byte : value_byte + 8] = np.frombuffer(total.to_bytes(8, "little"), np.uint8)
+            out.append(row)
+    return np.stack(out) if out else np.zeros((0, records.shape[1]), np.uint8)

@@ -127,6 +127,20 @@ def key_lanes_of(key_bytes: int) -> int:
     return -(-int(key_bytes) // 4)
 
 
+def comparable_lanes(lanes, key_bytes: int):
+    """A byte-string key's ``uint32`` lanes in the form whose lexicographic
+    order, lane 0 first, is the order of the keys as unsigned bytes, most
+    significant first: each lane byte-swapped, the last masked to the key's
+    bytes in it.  Returns ``(lanes, tail)``, ``tail`` the key bytes in the
+    last lane (1..4).  Two keys are equal iff every such lane is: what
+    ``key_order`` sorts by and ``ops.relational``'s local operators compare."""
+    lanes = [_byteswap32(lane) for lane in lanes]
+    tail = key_bytes - 4 * (len(lanes) - 1)
+    if tail < 4:
+        lanes[-1] = lanes[-1] & jnp.uint32((0xFFFFFFFF << (8 * (4 - tail))) & 0xFFFFFFFF)
+    return lanes, tail
+
+
 def key_order(keys, valid: jnp.ndarray, key_bytes: Optional[int] = None):
     """The permutation that sorts rows by their key lanes, and how many of
     them are valid: ``(order, count)`` — ``rows[order]`` has the valid rows
@@ -156,10 +170,7 @@ def key_order(keys, valid: jnp.ndarray, key_bytes: Optional[int] = None):
         valid = idx < valid
     tail = 4
     if key_bytes is not None:
-        lanes = [_byteswap32(lane) for lane in lanes]
-        tail = key_bytes - 4 * (len(lanes) - 1)  # key bytes in the last lane: 1..4
-        if tail < 4:
-            lanes[-1] = lanes[-1] & jnp.uint32((0xFFFFFFFF << (8 * (4 - tail))) & 0xFFFFFFFF)
+        lanes, tail = comparable_lanes(lanes, key_bytes)
     if tail < 4:  # the all-ones key is no row's: padding rows take it
         lanes = [jnp.where(valid, lane, KEY_MAX) for lane in lanes]
     else:

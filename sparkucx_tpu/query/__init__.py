@@ -6,8 +6,17 @@
   controlled LineageCache of sealed shuffles.
 * :mod:`sparkucx_tpu.query.runner` — QueryRunner, compiling DAGs onto the
   manager SPI / ExchangePlan executor, per tenant.
+* :mod:`sparkucx_tpu.query.batch` — the runner's batch lane: record arrays a
+  split, every exchange left on the device, the stages after it run there.
 """
 
+from sparkucx_tpu.query.batch import (
+    BatchLaneRefusedError,
+    BatchResult,
+    QueryCapacityError,
+    QuerySumOverflowError,
+    RecordSplit,
+)
 from sparkucx_tpu.query.dag import Stage, StageDag
 from sparkucx_tpu.query.lineage import (
     BYTE_AFFECTING_PLAN_FIELDS,
@@ -28,6 +37,11 @@ __all__ = [
     "LineageCache",
     "CacheEntry",
     "QueryRunner",
+    "RecordSplit",
+    "BatchResult",
+    "BatchLaneRefusedError",
+    "QuerySumOverflowError",
+    "QueryCapacityError",
     "BYTE_AFFECTING_PLAN_FIELDS",
     "SCHEDULE_ONLY_PLAN_FIELDS",
     "SERVE_ONLY_PLAN_FIELDS",

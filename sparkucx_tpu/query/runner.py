@@ -37,6 +37,7 @@ from sparkucx_tpu.core.operation import TenantQuotaExceededError
 from sparkucx_tpu.obs.metrics import counter_dict_provider
 from sparkucx_tpu.ops.relational import hash_owners_host, oracle_aggregate, oracle_join
 from sparkucx_tpu.ops.sort import oracle_sort
+from sparkucx_tpu.query import batch
 from sparkucx_tpu.query.dag import Stage, StageDag
 from sparkucx_tpu.query.lineage import (
     LineageCache,
@@ -97,11 +98,17 @@ class QueryRunner:
             "exchanges_reused": 0,
             "uncached_rounds": 0,
             "stale_invalidations": 0,
+            **dict.fromkeys(batch.COUNTERS, 0),  # the batch lane's (query/batch.py)
         }
         self._counters_lock = threading.Lock()
         metrics = getattr(manager.cluster, "metrics", None)
         if metrics is not None:
             metrics.register(f"query:{app_id}", counter_dict_provider("query", self._snapshot))
+
+    def counters(self) -> Dict[str, int]:
+        """The runner's ``query`` counter family as it stands (with the
+        lineage cache's, where one is attached)."""
+        return self._snapshot()
 
     def _snapshot(self) -> Dict[str, int]:
         with self._counters_lock:
@@ -111,19 +118,39 @@ class QueryRunner:
         return out
 
     def _bump(self, name: str, n: int = 1) -> None:
+        self._bump_many({name: n})
+
+    def _bump_many(self, rises: Dict[str, int]) -> None:
         with self._counters_lock:
-            self._counters[name] += n
+            for name, n in rises.items():
+                self._counters[name] += n
 
     # -- execution ----------------------------------------------------------
 
-    def run(self, dag: StageDag, inputs: Dict[str, List[Row]]):
+    def run(self, dag: StageDag, inputs: Dict[str, List[Row]], phases=None):
         """Execute the DAG; returns the sink stage's result.
 
         ``inputs`` maps each scan stage name to its rows ((key, value) int
         tuples).  Exchange results are lists of per-partition row lists;
         aggregate/join keep that partitioning; sort returns one flat,
         globally ordered row list.
+
+        Scan inputs that are record arrays a split (``batch.RecordSplit`` or
+        ``(n, record_bytes)`` ``uint8`` arrays) take the **batch lane**
+        (``query/batch.py``): every exchange one shuffle of fixed-width
+        batches left on the device, the stages after them run there a reduce
+        task at a time, the result a ``batch.BatchResult``.  ``phases`` is
+        that lane's only: ``phases(name, shuffle_ids)`` gives a context
+        manager entered round the query's ``write``, ``exchange``, ``read``
+        and ``release`` (the shuffles still registered) phases.
         """
+        scans = [inputs[st.name] for st in dag.stages if st.op == "scan"]
+        if any(batch.is_batch_input(rows) for rows in scans):
+            if not all(batch.is_batch_input(rows) for rows in scans):
+                raise ValueError("a query's scans are all record arrays (the batch lane) or all tuples")
+            result = batch.BatchQuery(self.manager, dag, inputs, self._bump_many, _next_sid, phases).run()
+            self._bump("queries")
+            return result
         results: Dict[str, object] = {}
         fingerprints: Dict[str, str] = {}
         ephemeral: List[int] = []  #: sids to unregister when the query ends

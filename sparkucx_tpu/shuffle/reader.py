@@ -1408,7 +1408,10 @@ class TpuShuffleReader:
         bytes and outlives the shuffle.
 
         ``aggregator`` over batches is not supported and raises here, rather
-        than fall into ``ExternalCombiner`` a record at a time.
+        than fall into ``ExternalCombiner`` a record at a time: batches are
+        combined on the DEVICE lane — ``read_device()`` under ``key_ordering``
+        and ``ops.relational.grouped_sum_records`` over what it hands out, as
+        the query runner's batch lane does (``query/batch.py``).
 
         Span ``read.batches``, once a task: a summed span of the reader's own
         turns — issuing and awaiting the windows, the look-ups, the hand-out
@@ -1423,8 +1426,9 @@ class TpuShuffleReader:
             )
         if self.aggregator is not None:
             raise NotImplementedError(
-                "an aggregator over record batches is not supported; "
-                "read() combines a record at a time"
+                "an aggregator over record batches is not supported on the host: read() combines a "
+                "record at a time, and batches are combined on the device lane (read_device() under "
+                "key_ordering, then ops.relational.grouped_sum_records: query/batch.py)"
             )
         if self.key_ordering:
             batch = self._read_ordered(to_host=True)

@@ -1154,3 +1154,43 @@ def test_compiled_for_the_chip_the_received_prefix_is_one_named_slice(v5e):
     assert len([l for l in text.splitlines() if " slice(" in l]) == 1
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == 16 << 20 and memory.temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("operator, module", [
+    ("aggregate", "jit_grouped_sum_records"), ("semi", "jit_merge_join_records"),
+    ("inner", "jit_merge_join_records"),
+])
+def test_compiled_for_the_chip_the_query_operators_keep_their_names(v5e, operator, module):
+    """The reduce task's operators at TPC-H Q18's SF=10 shapes (the
+    configuration's ``geometry``: 76,512 / 75,696 / 303,616 record places, 32
+    rows a task handed on) for the v5e: the chip's compiler takes them, under
+    the module names ``query_aggregate_roofline`` / ``query_join_roofline``
+    find their executables by."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from sparkucx_tpu.ops.relational import grouped_sum_records, merge_join_records
+
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def shape(*dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    count = shape()
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        if operator == "aggregate":
+            lowered = grouped_sum_records.lower(shape(76512, 4), count, shape(2, dtype=jnp.uint32), key_bytes=8,
+                                                value_lane=2, having="gt", out_capacity=32)
+        elif operator == "semi":
+            lowered = merge_join_records.lower(shape(75696, 8), count, shape(32, 4), count, key_bytes=8,
+                                               join_type="left_semi", out_capacity=32)
+        else:
+            lowered = merge_join_records.lower(shape(303616, 4), count, shape(32, 8), count, key_bytes=8,
+                                               join_type="inner", out_capacity=224)
+        text = lowered.compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert text.startswith(f"HloModule {module},")

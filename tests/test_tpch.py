@@ -5,6 +5,13 @@ run miniature versions of both physical plans — the same operator DAG at small
 scale — entirely through the device GROUP BY / hash-join primitives, with host
 stage boundaries where Spark would have its own (each stage's output is the
 next stage's shuffle input), verified against a numpy oracle.
+
+Which path this is: the operators' OWN SPMD exchange (``ops/relational.py``
+``build_grouped_aggregate`` / ``build_hash_join`` over eight virtual devices,
+128 rows a shard, ``uint32`` keys) — no manager, no store, no served shuffle.
+The served path with the operators on the chip at the source's sizes is the
+benchmark's cell ``q18sf10-queryjobs-1chip`` (``QueryRunner``'s batch lane,
+``query/batch.py``; ``tests/test_query_batch.py`` is its tier-1 form).
 """
 
 import jax
